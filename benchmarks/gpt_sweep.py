@@ -5,7 +5,11 @@ Runs the same honest-timing loop as bench.py across a grid of levers
 prints one JSON line per configuration.  Used to prove (or break) the
 box's MFU ceiling with committed numbers rather than journal claims.
 
-Run on the TPU chip:  python benchmarks/gpt_sweep.py [--steps 20]
+Runs on the TPU chip only (off-chip, or on a chip with no published
+peak in ray_tpu.util.accelerators.CHIP_PEAKS, it exits with an error):
+    python benchmarks/gpt_sweep.py [--steps 20]
+A configuration that does not fit the chip's HBM is recorded as such
+(RESOURCE_EXHAUSTED) and the sweep goes on; any other failure raises.
 """
 
 from __future__ import annotations
@@ -30,8 +34,9 @@ def run_one(name: str, *, batch: int, seq: int, remat, remat_policy,
 
     from ray_tpu.models import gpt
     from ray_tpu.train.step import make_train_step
+    from ray_tpu.util.accelerators import chip_peaks
 
-    dev = jax.devices()[0]
+    peak, _peak_hbm = chip_peaks(jax.devices()[0].device_kind)
     cfg = gpt.GPTConfig.gpt2_124m(max_seq=seq, remat=remat,
                                   remat_policy=remat_policy,
                                   attn_block_q=block_q,
@@ -57,9 +62,12 @@ def run_one(name: str, *, batch: int, seq: int, remat, remat_policy,
         for _ in range(warmup):
             state, metrics = step_fn(state, b)
         float(np.asarray(metrics["loss"]))
-    except Exception as e:   # compile/env limit: record, keep sweeping
-        return {"config": name, "error": f"{type(e).__name__}: "
-                                         f"{str(e)[:160]}"}
+    except jax.errors.JaxRuntimeError as e:
+        if "RESOURCE_EXHAUSTED" not in str(e):
+            raise
+        # does not fit this chip's HBM: a sweep result, not a fault
+        return {"config": name, "error": "RESOURCE_EXHAUSTED: "
+                                         + str(e)[:160]}
     compile_s = time.perf_counter() - t0
 
     t0 = time.perf_counter()
@@ -76,7 +84,6 @@ def run_one(name: str, *, batch: int, seq: int, remat, remat_policy,
     dt_sync = time.perf_counter() - t0
 
     flops_per_token = 6 * n_params + 12 * cfg.n_layers * cfg.d_model * seq
-    peak = 197e12 if "v5" in dev.device_kind.lower() else None
     tps = batch * seq * steps / dt
     return {"config": name, "batch": batch, "seq": seq,
             "remat": remat, "remat_policy": remat_policy,
@@ -85,7 +92,7 @@ def run_one(name: str, *, batch: int, seq: int, remat, remat_policy,
             "tokens_per_s_strict": round(batch * seq * steps / dt_sync, 1),
             "step_ms": round(1000 * dt / steps, 1),
             "step_ms_strict": round(1000 * dt_sync / steps, 1),
-            "mfu": round(flops_per_token * tps / peak, 4) if peak else None,
+            "mfu": round(flops_per_token * tps / peak, 4),
             "compile_s": round(compile_s, 1),
             "final_loss": round(last, 3)}
 
@@ -140,6 +147,16 @@ def main() -> int:
                     help="comma-separated config names")
     args = ap.parse_args()
     names = set(args.only.split(",")) if args.only else None
+
+    import jax
+
+    from ray_tpu._compile_cache import enable_compile_cache
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(
+            f"gpt_sweep measures the TPU; jax found platform "
+            f"{dev.platform!r} ({dev.device_kind})")
+    enable_compile_cache()
     for name, kw in GRID:
         if names and name not in names:
             continue

@@ -320,7 +320,11 @@ def run_sharded_ab(q, phase):
         "note": "tp=2 over virtual CPU devices on ONE host: no "
                 "extra silicon, collectives are pure overhead — "
                 "gates pin exactness + per-device accounting, "
-                "not speed (real-chip receipt is ROADMAP item 1)",
+                "not speed.  This arm ALWAYS runs in a forced-CPU "
+                "child process and can never see a chip, whatever "
+                "the parent runs on; the on-chip tp=2 check is "
+                "`python chip_smoke.py --chips 4`",
+        "platform": jax.devices()[0].platform,
         "single_device": sh_single,
         "tp2": sh_tp2,
         "token_exact": out_a == out_b,
@@ -349,8 +353,10 @@ def main():
 
     import jax
 
+    from ray_tpu._compile_cache import enable_compile_cache
     from ray_tpu.inference import EngineConfig
 
+    enable_compile_cache()
     cfg, params = _bench_model()
 
     phases = {}

@@ -749,6 +749,8 @@ def main():
                          "SERVE_r18.json (cold-replica adoption A/B "
                          "+ kill/drain chaos pass)")
     args = ap.parse_args()
+    from ray_tpu._compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.prefix_cluster:
         return prefix_cluster_main(args)
 

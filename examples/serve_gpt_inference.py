@@ -1,7 +1,12 @@
 """Serve a GPT with the continuous-batching inference engine and stream
 a generation over HTTP.
 
-Run:  JAX_PLATFORMS=cpu python examples/serve_gpt_inference.py
+Run (CPU, tiny config):  JAX_PLATFORMS=cpu python examples/serve_gpt_inference.py
+On a TPU host the same script serves from the chip: the engine runs in
+THIS process (``use_actors=False`` — one process holds the chip), the
+HTTP proxy is a CPU-pinned worker.  The full-width GPT-2 124M proof is
+``python chip_smoke.py``.  Compiled programs are cached in
+$JAX_COMPILATION_CACHE_DIR, else in <checkout>/.jax_cache.
 (see ARCHITECTURE.md "Inference engine" for the slot lifecycle)."""
 
 import json
@@ -21,6 +26,8 @@ from ray_tpu.models import gpt
 
 
 def main():
+    from ray_tpu._compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = gpt.GPTConfig.tiny(dtype=jnp.float32)   # swap for gpt2_124m()
     serve.run(build_gpt_deployment(
         cfg=cfg, engine_cfg=EngineConfig(max_slots=8), seed=0),

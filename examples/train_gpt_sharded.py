@@ -1,8 +1,14 @@
-"""Train GPT-2 on a dp/tp/sp device mesh with ray_tpu.train.JaxTrainer.
+"""Train GPT-2 on a device mesh with ray_tpu.train.JaxTrainer.
 
-Run on a TPU host (uses all local chips), or on CPU for a smoke test:
+On a TPU host this process drives ALL local chips itself (one process
+holds the chips; ``mesh={"dp": -1}`` spans them, flash attention runs
+per shard under shard_map).  On CPU it is a smoke test over 8 virtual
+devices on a dp/tp/sp mesh:
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/train_gpt_sharded.py
+The full-width GPT-2 124M proof on the chip is ``python chip_smoke.py``.
+Compiled programs are cached in $JAX_COMPILATION_CACHE_DIR, else in
+<checkout>/.jax_cache.
 """
 import os as _os
 import sys as _sys
@@ -29,6 +35,8 @@ def batches(steps: int = 10, batch: int = 8):
 
 
 if __name__ == "__main__":
+    from ray_tpu._compile_cache import enable_compile_cache
+    enable_compile_cache()
     on_cpu = jax.devices()[0].platform != "tpu"
     trainer = JaxTrainer(
         loss_fn=lambda p, b, mesh=None, rules=None: gpt.loss_fn(

@@ -102,15 +102,12 @@ class NodeService(NodeWorkersMixin, NodeTransferMixin, NodeSchedMixin,
             self.total_resources["TPU"] = float(num_tpus)
             # advertise the generation so accelerator_type constraints
             # can pin placement (util/accelerators.accelerator_resource)
-            try:
-                from ray_tpu.util.accelerators import (
-                    accelerator_resource, detect_tpu_type)
-                tpu_type = detect_tpu_type()
-                if tpu_type:
-                    self.total_resources[
-                        accelerator_resource(tpu_type)] = float(num_tpus)
-            except Exception:   # noqa: BLE001 - detection is best-effort
-                pass
+            from ray_tpu.util.accelerators import (accelerator_resource,
+                                                   detect_tpu_type)
+            tpu_type = detect_tpu_type()
+            if tpu_type:
+                self.total_resources[
+                    accelerator_resource(tpu_type)] = float(num_tpus)
         if resources:
             self.total_resources.update(resources)
         self.available = dict(self.total_resources)

@@ -451,13 +451,12 @@ def attach_worker_runtime(client: NodeClient, executor: Executor) -> Runtime:
 
 
 def _detect_tpu_chips() -> int:
-    """Count local TPU chips without initializing jax on them twice."""
-    try:
-        import jax
-        devs = jax.devices()
-        return sum(1 for d in devs if d.platform != "cpu")
-    except Exception:
-        return 0
+    """Count local accelerator chips.  This process becomes their owner
+    (one process per chip), so a backend that fails to initialize
+    raises: answering 0 would silently leave ``num_tpus`` work with
+    nowhere to run."""
+    import jax
+    return sum(1 for d in jax.local_devices() if d.platform != "cpu")
 
 
 def init(*, num_cpus: Optional[float] = None, num_tpus: Optional[float] = None,

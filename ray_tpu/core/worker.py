@@ -13,58 +13,15 @@ import argparse
 import sys
 
 
-def _install_jax_cpu_pin() -> None:
-    """Meta-path hook: pin jax to the CPU platform as soon as it finishes
-    importing, no matter what platform plugins do with JAX_PLATFORMS."""
-    import importlib.util
-    import types
-
-    class _JaxCpuPin:
-        _busy = False
-
-        def find_spec(self, name, path=None, target=None):
-            if name != "jax" or _JaxCpuPin._busy:
-                return None
-            _JaxCpuPin._busy = True
-            try:
-                spec = importlib.util.find_spec(name)
-            finally:
-                _JaxCpuPin._busy = False
-            if spec is None or spec.loader is None:
-                return None
-            orig = spec.loader
-
-            def exec_module(module):
-                orig.exec_module(module)
-                try:
-                    module.config.update("jax_platforms", "cpu")
-                except Exception:
-                    pass
-
-            spec.loader = types.SimpleNamespace(
-                create_module=orig.create_module, exec_module=exec_module)
-            return spec
-
-    sys.meta_path.insert(0, _JaxCpuPin())
-
-
 def run_worker(address: str) -> None:
     """Connect to the node service and block in the execution loop.
     Shared by the cold-spawn path (main below) and the fork-server
     children (core/prefork.py)."""
-    # Workers must not touch the TPU (the driver owns it).  The spawner
-    # sets JAX_PLATFORMS=cpu, but ambient platform plugins can override
-    # the env var, so pin via jax.config too: immediately if jax is
-    # already imported (sitecustomize pre-import), else via a post-import
-    # hook the moment user code imports it.  Avoid importing jax
-    # ourselves: it adds ~1-2s spawn latency for pure-CPU workloads.
-    if "jax" in sys.modules:
-        try:
-            sys.modules["jax"].config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
-    else:
-        _install_jax_cpu_pin()
+    # Workers must not touch the TPU (the driver owns it — one process
+    # per chip).  The spawner sets JAX_PLATFORMS=cpu in this process's
+    # environment (node_workers._worker_env), which jax reads when user
+    # code first imports it; jax is not imported here (it would add
+    # ~1-2s spawn latency for pure-CPU workloads).
 
     # on-demand stack dumps (reference: `ray stack` /
     # dashboard/modules/reporter/profile_manager.py): SIGUSR1 makes the

@@ -515,11 +515,15 @@ class InferenceEngine:
             # (waiting alone must not spin when the pool is handed out;
             # paged admission retries at the idle tick because block
             # availability also depends on evictable cached prefixes)
-            while (not self._stopped and not self._ops
-                   and not self._active.any()
-                   and not (self._paged and self._prefilling)
-                   and not (self._waiting and self._admission_possible())):
+            if (not self._stopped and not self._ops
+                    and not self._active.any()
+                    and not (self._paged and self._prefilling)
+                    and not (self._waiting and self._admission_possible())):
+                # ONE bounded wait, then back out to _engine_loop: an
+                # idle engine must drop the loop thread's strong
+                # reference every tick, or it is never collectable
                 self._cond.wait(self.engine_cfg.idle_wait_s)
+                return not self._stopped
             if self._stopped:
                 return False
             if self._ops:

@@ -156,6 +156,9 @@ def encode(params, tokens, cfg: BERTConfig, *,
     if attention_mask is not None:
         attn_mask = attention_mask[:, None, None, :].astype(bool)
 
+    attn_spec = (spec_for(("batch", "heads", "seq", "kv"), rules, mesh)
+                 if mesh is not None else None)
+
     def layer(x, lp):
         bx, sx = x.shape[0], x.shape[1]  # microbatched under pp
         qkv = jnp.einsum("bsd,de->bse", x, lp["wqkv"].astype(cfg.dtype))
@@ -169,7 +172,8 @@ def encode(params, tokens, cfg: BERTConfig, *,
         # mask; the masked path needs the reference impl
         impl = "reference" if attn_mask is not None else cfg.attn_impl
         o = attention(heads(q), heads(k), heads(v), causal=False,
-                      mask=attn_mask, impl=impl)
+                      mask=attn_mask, impl=impl, mesh=mesh,
+                      spec=attn_spec)
         o = o.transpose(0, 2, 1, 3).reshape(bx, sx, cfg.d_model)
         o = jnp.einsum("bsd,de->bse", o, lp["wo"].astype(cfg.dtype)) \
             + lp["bo"].astype(cfg.dtype)

@@ -34,14 +34,12 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import (DEFAULT_LLM_RULES, Rules, spec_for)
-
-from ray_tpu.parallel.jax_compat import shard_map
 
 
 @dataclass(frozen=True)
@@ -230,27 +228,20 @@ def _constrain(x, logical, mesh, rules):
 
 def _attend(q, k, v, cfg: GPTConfig, mesh: Optional[Mesh], rules: Rules):
     """[b, h, s, hd] attention; ring attention when seq is sp-sharded."""
+    spec = (spec_for(("batch", "heads", "seq", "kv"), rules, mesh)
+            if mesh is not None else None)
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
-        spec = spec_for(("batch", "heads", "seq", "kv"), rules, mesh)
         ring = partial(ring_attention, axis_name="sp", causal=True)
         return shard_map(ring, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
-    if cfg.remat_policy == "dots_flash":
-        # lse-exposing flash variant: the kernel outputs are named
-        # (flash_out/flash_lse) inside its vjp, so the scan's checkpoint
-        # policy saves them and the backward pass reconstructs the layer
-        # without re-running the attention forward kernel
-        from ray_tpu.ops.flash_attention import flash_attention_with_lse
-        tile_ok = (q.shape[-2] % 128 == 0 and k.shape[-2] % 128 == 0
-                   and q.shape[-1] in (64, 128, 256))
-        on_tpu = jax.default_backend() == "tpu"
-        if on_tpu and tile_ok and cfg.attn_impl in (None, "flash"):
-            out, _lse = flash_attention_with_lse(
-                q, k, v, causal=True,
-                block_q=cfg.attn_block_q, block_k=cfg.attn_block_k)
-            return out
+    # dots_flash: the lse-exposing flash variant names its outputs
+    # (flash_out/flash_lse) inside its vjp, so the scan's checkpoint
+    # policy saves them and the backward pass reconstructs the layer
+    # without re-running the attention forward kernel
     return attention(q, k, v, causal=True, impl=cfg.attn_impl,
-                     block_q=cfg.attn_block_q, block_k=cfg.attn_block_k)
+                     block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
+                     mesh=mesh, spec=spec,
+                     save_lse=cfg.remat_policy == "dots_flash")
 
 
 def _moe_mlp(y, lp, cfg: GPTConfig, mesh: Optional[Mesh], rules: Rules):

@@ -9,10 +9,12 @@ differentiable and numerically interchangeable (tests assert allclose).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec
 
 
 def _scale_for(q, scale):
@@ -95,11 +97,38 @@ def paged_attention(q, k_pool, v_pool, block_tables, *,
                          scale=scale, mask=mask, kv_lengths=kv_lengths)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+def on_tpu() -> bool:
+    """THE definition of "this process computes on a TPU" for kernel
+    dispatch (flash vs reference) and interpret-mode selection.  A
+    broken backend raises here instead of quietly answering "no"."""
+    return jax.default_backend() == "tpu"
+
+
+def _per_shard(fn, mesh, spec):
+    """Mosaic kernels cannot be partitioned by GSPMD, so under a
+    multi-device mesh the flash call runs per shard inside shard_map:
+    manual over every mesh axis not already manual in the enclosing
+    context (the pp pipeline binds ``pp`` itself), q/k/v and the output
+    laid out by ``spec`` — the mesh axes that shard batch and heads."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    if spec is None:
+        raise ValueError("flash attention under a mesh needs the "
+                         "[batch, heads, seq, kv] PartitionSpec")
+    if len(spec) > 2 and spec[2] is not None:
+        raise ValueError(
+            f"flash attention needs whole sequences per shard, got seq "
+            f"sharded over {spec[2]!r}; use ring attention for sp meshes")
+    ctx = jax.sharding.get_abstract_mesh()
+    bound = set(ctx.manual_axes)
+    free = frozenset(a for a in mesh.axis_names if a not in bound)
+    if not free:
+        return fn
+    # nested under a manual axis, shard_map must be given the context's
+    # own (abstract) mesh: the concrete Mesh no longer matches it
+    return jax.shard_map(fn, mesh=ctx if bound else mesh, axis_names=free,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)
 
 
 def attention(q, k, v, *, causal: bool = True,
@@ -107,7 +136,10 @@ def attention(q, k, v, *, causal: bool = True,
               mask: Optional[jax.Array] = None,
               kv_lengths: Optional[jax.Array] = None,
               impl: Optional[str] = None,
-              block_q: int = 512, block_k: int = 512) -> jax.Array:
+              block_q: int = 512, block_k: int = 512,
+              mesh: Optional[Mesh] = None,
+              spec: Optional[PartitionSpec] = None,
+              save_lse: bool = False) -> jax.Array:
     """Dispatching multi-head attention, [batch, heads, seq, head_dim].
 
     impl: "flash" (pallas TPU kernel), "reference", or None = auto
@@ -115,13 +147,20 @@ def attention(q, k, v, *, causal: bool = True,
     mask or per-row kv_lengths, reference otherwise).  ``kv_lengths``
     [b] limits each batch row to its own valid kv prefix (slot-batched
     decode; see mha_reference).
+
+    ``mesh`` + ``spec`` (the PartitionSpec of q/k/v on that mesh, seq
+    unsharded): the flash kernel then runs per shard under shard_map —
+    the reference and xla_fused impls are plain XLA and partition on
+    their own.  ``save_lse`` picks the lse-exposing flash variant whose
+    named outputs a ``dots_flash`` checkpoint policy saves.
     """
-    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.ops.flash_attention import (flash_attention,
+                                             flash_attention_with_lse)
 
     if impl is None:
         tile_ok = (q.shape[-2] % 128 == 0 and k.shape[-2] % 128 == 0
                    and q.shape[-1] in (64, 128, 256))
-        impl = ("flash" if _on_tpu() and tile_ok and mask is None
+        impl = ("flash" if on_tpu() and tile_ok and mask is None
                 and kv_lengths is None
                 else "reference")
     if impl == "flash":
@@ -129,8 +168,16 @@ def attention(q, k, v, *, causal: bool = True,
             raise ValueError(
                 "flash impl has no custom-mask / kv_lengths support; use "
                 "impl='reference' (causal masking is built in)")
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               block_q=block_q, block_k=block_k)
+        kw = dict(causal=causal, scale=scale, block_q=block_q,
+                  block_k=block_k)
+        if save_lse:
+            # the lse itself is only a checkpoint-policy save target
+            # (named inside the kernel's vjp); callers get ``out``
+            def fn(q, k, v):
+                return flash_attention_with_lse(q, k, v, **kw)[0]
+        else:
+            fn = functools.partial(flash_attention, **kw)
+        return _per_shard(fn, mesh, spec)(q, k, v)
     if impl == "reference":
         return mha_reference(q, k, v, causal=causal, scale=scale, mask=mask,
                              kv_lengths=kv_lengths)

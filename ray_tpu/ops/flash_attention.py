@@ -138,9 +138,17 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, need_lse=False):
 
 
 def _interpret_mode() -> bool:
-    # pallas TPU lowering needs a TPU; tests exercise the kernel on CPU
-    # through the interpreter.
-    return jax.default_backend() != "tpu"
+    """Compile for the TPU; interpret only on the CPU platform (the
+    tests' path).  Any other backend has no lowering for these kernels
+    and must not be served by the interpreter in silence."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise NotImplementedError(
+        f"pallas flash attention targets TPU (interpreted on CPU for "
+        f"tests); backend {backend!r} is unsupported")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

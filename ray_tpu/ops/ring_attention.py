@@ -63,18 +63,11 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = True,
         v_nxt = jax.lax.ppermute(v_cur, axis_name, perm)
         return (acc, m_next, l_next, k_nxt, v_nxt), None
 
-    if hasattr(jax.lax, "pcast"):  # jax>=0.9 spelling of pvary
-        def _pvary(x, axes):
-            return jax.lax.pcast(x, axes, to="varying")
-    elif hasattr(jax.lax, "pvary"):  # pragma: no cover - 0.5/0.6 jax
-        _pvary = jax.lax.pvary
-    else:  # pragma: no cover - pre-varying-types jax: shard_map has no
-        def _pvary(x, axes):  # rep/vma tracking, the cast is an identity
-            return x
-    acc0, m0, l0 = _pvary(
+    acc0, m0, l0 = jax.lax.pcast(
         (jnp.zeros((b, h, sl, d), jnp.float32),
          jnp.full((b, h, sl, 1), NEG_INF, jnp.float32),
-         jnp.zeros((b, h, sl, 1), jnp.float32)), (axis_name,))
+         jnp.zeros((b, h, sl, 1), jnp.float32)), (axis_name,),
+        to="varying")
     (acc, m, l, _, _), _ = jax.lax.scan(
         step, (acc0, m0, l0, k, v), jnp.arange(axis_size))
     l = jnp.maximum(l, 1e-30)

@@ -23,8 +23,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
-from ray_tpu.parallel.jax_compat import shard_map
 
 # ---------------------------------------------------------------------------
 # compiled plane — use inside shard_map'd / pjit'd functions

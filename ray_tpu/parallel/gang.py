@@ -12,6 +12,12 @@ _setup_torch_process_group) with slice-native formation:
     ``jax.distributed.initialize`` (coordinator = rank-0 member), each
     running the same compiled program (SPMD).  Members are actors with
     ``num_tpus`` resources so the scheduler places them on TPU hosts.
+    Members are WORKER processes, and the node service starts every
+    worker with ``JAX_PLATFORMS=cpu`` (one process holds a host's
+    chips: the driver) — so ``num_tpus_per_member`` reserves chips for
+    placement but a member computes on CPU devices.  Several chips on
+    ONE host are the single-host path above: one process, one mesh
+    over all local chips.  There is deliberately no second path.
 
 The gang is the unit of fault tolerance: a member death breaks the ICI
 mesh, so recovery = rebuild the gang and restore from checkpoint
@@ -179,20 +185,13 @@ class GangMember:
         if self.cpu_backend:
             # must land before first backend touch in this fresh process
             _jax.config.update("jax_platforms", "cpu")
-            from ray_tpu.parallel.jax_compat import \
-                enable_cpu_gloo_collectives
-            enable_cpu_gloo_collectives()
+            # real cross-process CPU collectives (the multi-host test
+            # shape); also before backend init
+            _jax.config.update("jax_cpu_collectives_implementation",
+                               "gloo")
             if self.local_device_count:
-                try:
-                    _jax.config.update("jax_num_cpu_devices",
-                                       self.local_device_count)
-                except AttributeError:
-                    # pre-0.5 jax spelling; same pre-backend-init timing
-                    import os as _os
-                    _os.environ["XLA_FLAGS"] = (
-                        _os.environ.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count="
-                        + str(self.local_device_count))
+                _jax.config.update("jax_num_cpu_devices",
+                                   self.local_device_count)
 
     def _info(self) -> dict:
         import jax as _jax
@@ -218,8 +217,9 @@ class GangMember:
         world size/rank.  The elastic re-gang step: abandon (no
         collective barrier), drop cached backends so the global device
         view shrinks/grows, re-initialize."""
-        from ray_tpu.parallel.jax_compat import (clear_backends,
-                                                 distributed_abandon,
+        from jax.extend.backend import clear_backends
+
+        from ray_tpu.parallel.jax_compat import (distributed_abandon,
                                                  distributed_initialize)
         self._await_idle()
         if self._initialized:

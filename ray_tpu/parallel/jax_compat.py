@@ -1,52 +1,18 @@
-"""Version-compat shims for jax APIs with moved/renamed surfaces.
+"""Private-surface access for the elastic gang's jax.distributed world.
 
-The toolchain image pins an older jax where ``shard_map`` lives in
-``jax.experimental.shard_map``, its replication check is spelled
-``check_rep`` (newer: top-level ``jax.shard_map`` with ``check_vma``),
-and partial-manual meshes use ``auto=`` (newer: ``axis_names=``).
-Callers write the NEW spelling and import from here; the shim
-translates downward when running on the older jax.
+Written against the one installed stack (jax/jaxlib 0.9.0): the
+coordination-service factories live in ``jax._src.lib._jax`` and take a
+single ``heartbeat_timeout``.  Nothing here falls back when that
+private surface moves — an ImportError/TypeError is the signal to port
+this file, and tests/test_elastic_gang.py pins the "resilient" result.
 """
 
 from __future__ import annotations
 
-import inspect
-
-try:                                    # jax >= 0.6 top-level export
-    from jax import shard_map as _shard_map
-except ImportError:                     # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f=None, **kw):
-    if "check_vma" in kw and "check_vma" not in _PARAMS:
-        kw["check_rep"] = kw.pop("check_vma")
-    if "axis_names" in kw and "axis_names" not in _PARAMS:
-        # old spelling is the complement: `auto` lists the mesh axes
-        # shard_map must NOT bind manually
-        axis_names = kw.pop("axis_names")
-        mesh = kw.get("mesh")
-        auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-                if mesh is not None else frozenset())
-        if auto:
-            if "auto" not in _PARAMS:
-                # dropping the restriction would silently bind every
-                # mesh axis manually — wrong collectives, not an error
-                raise NotImplementedError(
-                    "this jax's shard_map supports neither axis_names "
-                    "nor auto; partial-manual meshes are unavailable")
-            kw["auto"] = auto
-    if f is None:
-        return lambda g: shard_map(g, **kw)
-    return _shard_map(f, **kw)
-
-
 # ---------------------------------------------------------------------------
 # elastic jax.distributed (parallel/gang.py)
 #
-# Three version-gated capabilities the elastic gang needs that the public
+# Two capabilities the elastic gang needs that the public
 # jax.distributed surface doesn't expose:
 #
 #   * SURVIVABLE membership: the stock DistributedRuntimeClient's
@@ -61,7 +27,7 @@ def shard_map(f=None, **kw):
 #     thread terminates the process (uncatchable std::bad_cast inside
 #     the C++->Python callback hop) when it hands the error over — so
 #     heartbeat-miss detection is effectively disabled on both sides
-#     (``max_missing_heartbeats`` ~ 10^7) and membership health belongs
+#     (``heartbeat_timeout`` ~ 10^7 s) and membership health belongs
 #     to the gang layer alone (actor death watch + ping probes; a dead
 #     peer still poisons in-flight collectives via gloo's own TCP
 #     errors, which surface as ordinary Python exceptions).
@@ -73,72 +39,47 @@ def shard_map(f=None, **kw):
 #     above.  The old client/service are instead parked in a
 #     module-level list (a deliberate, bounded leak: one pair per
 #     re-gang) so not even a destructor runs against the old world;
-#     ``clear_backends()`` then drops the cached global-device view so
-#     the next initialize sees the NEW world.
+#     the gang then clears jax's cached backends so the next initialize
+#     sees the NEW world's global-device view.
 
 
 def distributed_initialize(coordinator_address: str, num_processes: int,
                            process_id: int, *, resilient: bool = True,
-                           heartbeat_interval_s: int = 1,
-                           max_missing_heartbeats: int = 10_000_000,
+                           heartbeat_timeout_s: int = 10_000_000,
                            init_timeout_s: int = 120) -> str:
     """Initialize jax.distributed; returns "resilient" when the
-    peer-death-survivable client was installed, "plain" when this jax's
-    private surface moved and we fell back to the public API (elastic
-    shrink then degrades to full-restart recovery)."""
+    peer-death-survivable client was installed, "plain" only when the
+    caller asked for the public API (``resilient=False``).  A moved
+    private surface raises — elastic shrink must not silently degrade
+    to a client that kills surviving members."""
     import jax
     if not resilient:
         jax.distributed.initialize(coordinator_address=coordinator_address,
                                    num_processes=num_processes,
                                    process_id=process_id)
         return "plain"
-    try:
-        from jax._src import distributed
-        from jax._src.lib import xla_extension
-        st = distributed.global_state
-        if st.client is not None:
-            raise RuntimeError("jax.distributed already initialized")
-        port = coordinator_address.rsplit(":", 1)[1]
-        if process_id == 0:
-            st.service = xla_extension.get_distributed_runtime_service(
-                "[::]:" + port, num_processes,
-                heartbeat_interval=heartbeat_interval_s,
-                max_missing_heartbeats=max_missing_heartbeats)
-        client = xla_extension.get_distributed_runtime_client(
-            coordinator_address, process_id,
-            init_timeout=init_timeout_s, shutdown_timeout=5,
-            heartbeat_interval=heartbeat_interval_s,
-            max_missing_heartbeats=max_missing_heartbeats,
-            missed_heartbeat_callback=lambda *a, **k: None,
-            shutdown_on_destruction=False, use_compression=True)
-        client.connect()
-        st.client = client
-        st.process_id = process_id
-        st.num_processes = num_processes
-        st.coordinator_address = coordinator_address
-        return "resilient"
-    except (ImportError, AttributeError, TypeError):
-        # moved private surface: correctness over elasticity.  A
-        # partially-built resilient setup (e.g. the service came up but
-        # the client factory's signature changed) must be torn down
-        # first, or the public-API fallback re-binds the same port.
-        try:
-            from jax._src import distributed as _dist
-            st = _dist.global_state
-            for attr in ("client", "service"):
-                obj = getattr(st, attr, None)
-                if obj is not None:
-                    setattr(st, attr, None)
-                    try:
-                        obj.shutdown()
-                    except Exception:
-                        pass
-        except ImportError:
-            pass
-        jax.distributed.initialize(coordinator_address=coordinator_address,
-                                   num_processes=num_processes,
-                                   process_id=process_id)
-        return "plain"
+    from jax._src import distributed
+    from jax._src.lib import _jax
+    st = distributed.global_state
+    if st.client is not None:
+        raise RuntimeError("jax.distributed already initialized")
+    port = coordinator_address.rsplit(":", 1)[1]
+    if process_id == 0:
+        st.service = _jax.get_distributed_runtime_service(
+            "[::]:" + port, num_processes,
+            heartbeat_timeout=heartbeat_timeout_s)
+    client = _jax.get_distributed_runtime_client(
+        coordinator_address, process_id,
+        init_timeout=init_timeout_s, shutdown_timeout=5,
+        heartbeat_timeout=heartbeat_timeout_s,
+        missed_heartbeat_callback=lambda *a, **k: None,
+        shutdown_on_destruction=False, use_compression=True)
+    client.connect()
+    st.client = client
+    st.process_id = process_id
+    st.num_processes = num_processes
+    st.coordinator_address = coordinator_address
+    return "resilient"
 
 
 # worlds left behind by distributed_abandon().  Holding the references
@@ -160,13 +101,8 @@ def distributed_abandon(timeout_s: float = 20.0) -> None:
     client/service pair is parked (never shut down, never destroyed) so
     the old world stays silent; the global_state slots are cleared so
     the next distributed_initialize builds a fresh world."""
-    try:
-        from jax._src import distributed
-        st = distributed.global_state
-    except ImportError:
-        import jax
-        jax.distributed.shutdown()
-        return
+    from jax._src import distributed
+    st = distributed.global_state
     if st.client is not None or st.service is not None:
         _abandoned_worlds.append((st.client, st.service))
     st.client = None
@@ -175,30 +111,3 @@ def distributed_abandon(timeout_s: float = 20.0) -> None:
     st.process_id = None
     st.num_processes = None
     st.coordinator_address = None
-
-
-def clear_backends() -> None:
-    """Drop cached XLA backends (and with them the stale global-device
-    view) so the next backend touch re-initializes against the CURRENT
-    jax.distributed world."""
-    import jax
-    f = getattr(jax, "clear_backends", None)
-    if f is None:
-        from jax.extend import backend as _xb
-        f = _xb.clear_backends
-    f()
-
-
-def enable_cpu_gloo_collectives() -> None:
-    """Make CPU-backend cross-process collectives real (the multi-host
-    test shape): newer jax spells it jax_cpu_collectives_implementation,
-    older jax_cpu_enable_gloo_collectives.  Must run before the CPU
-    backend initializes."""
-    import jax
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        try:
-            jax.config.update("jax_cpu_enable_gloo_collectives", True)
-        except (AttributeError, ValueError):
-            pass   # very old jax: single-host only

@@ -103,7 +103,10 @@ def create_hybrid_mesh(ici_axes: dict[str, int], dcn_size: int,
             tuple(resolved.values()),
             dcn_mesh_shape=(dcn_size,) + (1,) * (len(resolved) - 1),
             devices=devices)
-    except Exception:
+    except (ValueError, AssertionError):
+        # devices without a slice_index (virtual CPU devices, one real
+        # slice) are what mesh_utils refuses; lay the slices out in
+        # device order
         dev_array = np.asarray(devices).reshape((dcn_size,)
                                                 + tuple(resolved.values()))
     return Mesh(dev_array, axis_names=("dcn",) + tuple(resolved.keys()))

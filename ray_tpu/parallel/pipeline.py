@@ -24,10 +24,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ray_tpu.parallel.jax_compat import shard_map
 
 
 def num_stages(mesh: Mesh) -> int:

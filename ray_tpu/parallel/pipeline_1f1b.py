@@ -42,10 +42,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ray_tpu.parallel.jax_compat import shard_map
 
 
 class Schedule(NamedTuple):

@@ -46,17 +46,46 @@ def is_known_accelerator(accelerator_type: str) -> bool:
     return accelerator_type in _ALL
 
 
-def detect_tpu_type() -> str:
-    """Best-effort TPU generation of the locally visible chip
-    (device_kind → constant; None-safe on CPU-only hosts)."""
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 - no backend
-        return ""
-    for key, const in (("v5 lite", TPU_V5E), ("v5e", TPU_V5E),
-                       ("v5p", TPU_V5P), ("v6", TPU_V6E),
-                       ("v4", TPU_V4), ("v3", TPU_V3), ("v2", TPU_V2)):
+_TPU_KINDS = (("v5 lite", TPU_V5E), ("v5e", TPU_V5E), ("v5p", TPU_V5P),
+              ("v6", TPU_V6E), ("v4", TPU_V4), ("v3", TPU_V3),
+              ("v2", TPU_V2))
+
+
+def tpu_type_of(device_kind: str) -> str:
+    """``jax.Device.device_kind`` → accelerator constant ("" when the
+    kind names no TPU generation, e.g. "cpu")."""
+    kind = device_kind.lower()
+    for key, const in _TPU_KINDS:
         if key in kind:
             return const
     return ""
+
+
+def detect_tpu_type() -> str:
+    """TPU generation of the locally visible chip ("" on CPU-only
+    hosts).  A backend that fails to initialize raises — a node must
+    not advertise "no TPU" because the TPU runtime is broken."""
+    import jax
+    return tpu_type_of(jax.devices()[0].device_kind)
+
+
+# Published per-chip peaks: (bf16 FLOP/s, HBM bytes/s).  THE table every
+# MFU / roofline figure in the repo divides by (bench.py,
+# benchmarks/gpt_sweep.py).  Source: Google Cloud documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM).
+CHIP_PEAKS = {
+    TPU_V5E: (197e12, 819e9),
+}
+
+
+def chip_peaks(device_kind: str) -> tuple[float, float]:
+    """(peak bf16 FLOP/s, peak HBM bytes/s) for a jax ``device_kind``.
+    An unknown device is an error, never a default: a utilization
+    computed against a guessed peak is not a measurement."""
+    tpu_type = tpu_type_of(device_kind)
+    if tpu_type not in CHIP_PEAKS:
+        raise ValueError(
+            f"no published peak rates for device_kind {device_kind!r}; "
+            f"known: {sorted(CHIP_PEAKS)} — add the chip (with its "
+            "source) to ray_tpu.util.accelerators.CHIP_PEAKS")
+    return CHIP_PEAKS[tpu_type]

@@ -57,6 +57,65 @@ def _gang_actor_states(client) -> list[str]:
             if "Member" in a.get("class_name", "")]
 
 
+_RESILIENT_CHILD = """
+import os, sys
+import jax
+from ray_tpu.parallel.jax_compat import (distributed_abandon,
+                                         distributed_initialize)
+rank = int(sys.argv[2])
+mode = distributed_initialize(sys.argv[1], 2, rank, resilient=True)
+from jax._src import distributed
+assert distributed.global_state.client is not None
+if rank == 0:
+    assert distributed.global_state.service is not None
+print("MODE", mode, jax.process_count(), len(jax.devices()), flush=True)
+distributed_abandon()
+assert distributed.global_state.client is None
+os._exit(0)   # parked world: no shutdown handshake, by design
+"""
+
+
+def test_distributed_initialize_installs_the_resilient_client():
+    """jax_compat reaches into jax's PRIVATE coordination-service
+    surface (jax._src.lib._jax) for the peer-death-survivable client.
+    On the installed jax it must return "resilient" — a jax that moves
+    that surface fails HERE instead of the gang silently running with
+    the stock client that kills surviving members.  Two real local CPU
+    processes form one world."""
+    import subprocess
+    import sys
+
+    from ray_tpu.parallel.gang import _free_port
+    addr = f"127.0.0.1:{_free_port()}"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS="")
+    procs = [subprocess.Popen(
+        [sys.executable, "-c", _RESILIENT_CHILD, addr, str(rank)],
+        env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        for rank in range(2)]
+    for rank, p in enumerate(procs):
+        out, err = p.communicate(timeout=120)
+        assert p.returncode == 0, f"rank {rank} failed:\n{err[-2000:]}"
+        # world of 2 processes x 1 cpu device each
+        assert "MODE resilient 2 2" in out, out
+
+
+def test_distributed_initialize_has_no_silent_fallback(monkeypatch):
+    """A private surface that no longer takes the installed arguments
+    is an error, not a quiet downgrade to the public client."""
+    from jax._src.lib import _jax
+
+    from ray_tpu.parallel.jax_compat import distributed_initialize
+
+    def moved(*a, **k):
+        raise TypeError("get_distributed_runtime_service() got an "
+                        "unexpected keyword argument")
+    monkeypatch.setattr(_jax, "get_distributed_runtime_service", moved)
+    with pytest.raises(TypeError):
+        distributed_initialize("127.0.0.1:1", 1, 0, resilient=True)
+    from jax._src import distributed
+    assert distributed.global_state.client is None
+
+
 def test_partial_formation_kills_all_members(rt):
     """One member's setup failing must not leak the other member
     actors (they used to stay alive — and hold their reservations —

@@ -57,7 +57,7 @@ def test_compiled_allreduce(devices):
     def f(x):
         def inner(x):
             return col.allreduce(x, "dp")
-        from ray_tpu.parallel.jax_compat import shard_map
+        from jax import shard_map
         return shard_map(inner, mesh=mesh, in_specs=PartitionSpec("dp"),
                          out_specs=PartitionSpec("dp"))(x)
 
@@ -68,7 +68,7 @@ def test_compiled_allreduce(devices):
 
 def test_compiled_allgather_and_scatter(devices):
     mesh = create_mesh({"dp": 8}, devices=devices[:8])
-    from ray_tpu.parallel.jax_compat import shard_map
+    from jax import shard_map
 
     @jax.jit
     def gather(x):
@@ -91,7 +91,7 @@ def test_compiled_allgather_and_scatter(devices):
 
 def test_compiled_broadcast_and_permute(devices):
     mesh = create_mesh({"dp": 8}, devices=devices[:8])
-    from ray_tpu.parallel.jax_compat import shard_map
+    from jax import shard_map
 
     @jax.jit
     def bc(x):
