@@ -33,10 +33,10 @@ _NULL_SPAN = contextlib.nullcontext()
 
 
 def _task_span(name: str, spec: dict):
-    from ray_tpu.util.tracing import start_span, tracing_enabled
+    from ray_tpu.util.tracing import span, tracing_enabled
     if not tracing_enabled():
         return _NULL_SPAN
-    return start_span(name, kind="server", remote_ctx=spec.get("trace_ctx"))
+    return span(name, kind="server", parent=spec.get("trace_ctx"))
 
 
 class _ArgSlot:
@@ -465,13 +465,12 @@ class Executor:
         st = self._actor_loop_state(spec["actor_id"])
 
         async def runner():
-            from ray_tpu.util.tracing import start_span
+            from ray_tpu.util.tracing import span
             with task_context(TaskID(spec["task_id"])), \
                     applied_env(self._actor_envs.get(spec["actor_id"]),
                                 self.client), \
-                    start_span(f"actor::{spec.get('name', '?')}.execute",
-                               kind="server",
-                               remote_ctx=spec.get("trace_ctx")):
+                    span(f"actor::{spec.get('name', '?')}.execute",
+                         kind="server", parent=spec.get("trace_ctx")):
                 if limit is not None:
                     sem = st.group_sem(
                         spec.get("concurrency_group") or "", limit)

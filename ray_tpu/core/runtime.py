@@ -227,16 +227,15 @@ class Runtime:
             _fr._active.start(spec)
         from ray_tpu.util.tracing import tracing_enabled
         if tracing_enabled():
-            from ray_tpu.util.tracing import start_span
+            from ray_tpu.util.tracing import span
             # the submit span is the PARENT of the worker's execute span
             # (reference: tracing_helper injects the client span's
             # context), so its context — not the ambient one — goes
             # into the spec
-            with start_span(f"task::{spec['name']}.remote", kind="client",
-                            attributes={"task_id": task_id.hex()}) as sp:
+            with span(f"task::{spec['name']}.remote", kind="client",
+                      task_id=task_id.hex()) as sp:
                 if sp:
-                    spec["trace_ctx"] = {"trace_id": sp["trace_id"],
-                                         "span_id": sp["span_id"]}
+                    spec["trace_ctx"] = sp.context()
                 self._prepare_args(args, kwargs, spec)
                 if _fr._active is not None:
                     _fr._active.stamp(spec, "encode")
@@ -325,8 +324,9 @@ class Runtime:
         }
         if concurrency_group:
             spec["concurrency_group"] = concurrency_group
-        from ray_tpu.util.tracing import inject_context
-        tctx = inject_context()
+        from ray_tpu.util import tracing
+        # only while tracing is on: the serve front's span is always on
+        tctx = tracing.inject_context() if tracing.active() else None
         if tctx is not None:
             spec["trace_ctx"] = tctx
         if _fr._active is not None:

@@ -68,6 +68,7 @@ compiled step; logits [n_slots, vocab] is a small transfer.
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 import weakref
@@ -93,6 +94,7 @@ from ray_tpu.models import gpt
 from ray_tpu.models.gpt import GPTConfig
 from ray_tpu.parallel.sharding import (DEFAULT_LLM_RULES, Rules,
                                        tree_shardings)
+from ray_tpu.util import tracing
 
 
 @dataclass
@@ -169,13 +171,25 @@ class GenerationRequest:
         self.cancelled = False
         self.error: Optional[BaseException] = None
         self._cond = threading.Condition()
-        self.created_s = time.perf_counter()
-        self.created_wall = time.time()   # timeline slices need wall time
+        # stamps are time.monotonic() seconds, the clock of the spans
+        # (util/tracing.py) and of the benchmark's own stamps
+        self.created_s = time.monotonic()
+        # the request got a row (after a preemption BEFORE its first
+        # token: the last admission; a later preemption is decode time)
+        self.admitted_s: Optional[float] = None
         self.first_token_s: Optional[float] = None
         self.finished_s: Optional[float] = None
-        # per-token arrival stamps (perf_counter): consecutive diffs are
-        # the request's ITLs — the latency series speculation moves
+        # per-token arrival stamps: consecutive diffs are the request's
+        # ITLs — the latency series speculation moves
         self.token_times: list[float] = []
+        # what the lifecycle spans report (_record_spans); the caller's
+        # span (the serve front's) is their parent, where there is one
+        self._trace_ctx = tracing.inject_context()
+        self.prompt_tokens = int(prompt.size)
+        self.prefix_hit_tokens = 0
+        self.preemptions = 0
+        self.chunk_passes = 0
+        self.full_width_prefill = False
         # per-request speculation accounting (accept-rate per stream)
         self.spec_drafted = 0
         self.spec_accepted = 0
@@ -184,19 +198,60 @@ class GenerationRequest:
 
     def _emit(self, token: int) -> None:
         with self._cond:
-            now = time.perf_counter()
+            now = time.monotonic()
             if self.first_token_s is None:
                 self.first_token_s = now
             self.token_times.append(now)
             self.tokens.append(int(token))
             self._cond.notify_all()
 
+    def _admitted(self) -> None:
+        if self.first_token_s is None:
+            self.admitted_s = time.monotonic()
+
     def _finish(self, error: Optional[BaseException] = None) -> None:
         with self._cond:
+            first = not self.done
             self.error = error
             self.done = True
-            self.finished_s = time.perf_counter()
+            self.finished_s = time.monotonic()
             self._cond.notify_all()
+        if first:
+            self._record_spans()
+
+    def _record_spans(self) -> None:
+        """The request's lifecycle as spans, built once from its stamps
+        (always on): ``request.queue`` (submit -> a row),
+        ``request.prefill`` (-> first token), ``request.decode``
+        (-> finish) partition submit -> finish exactly; a request that
+        never reached a stage ends in the stage it died in."""
+        def ns(t):
+            return int(t * 1e9)
+        end = ns(self.finished_s)
+        admitted = ns(self.admitted_s) if self.admitted_s is not None \
+            else end
+        first = ns(self.first_token_s) if self.first_token_s is not None \
+            else end
+        status = {} if self.error is None else {
+            "error": type(self.error).__name__}
+        queue = tracing.record_span(
+            "request.queue", ns(self.created_s), admitted,
+            parent=self._trace_ctx, req=self.id,
+            prompt_tokens=self.prompt_tokens,
+            prefix_hit_tokens=self.prefix_hit_tokens,
+            preemptions=self.preemptions, **status)
+        # submitted outside any span: the three are roots of one trace
+        ctx = self._trace_ctx or {"trace_id": queue.trace_id,
+                                  "span_id": None}
+        if self.admitted_s is not None:
+            tracing.record_span(
+                "request.prefill", admitted, first, parent=ctx,
+                req=self.id, chunk_passes=self.chunk_passes,
+                full_width=self.full_width_prefill)
+        if self.first_token_s is not None:
+            tracing.record_span(
+                "request.decode", first, end, parent=ctx,
+                req=self.id, output_tokens=len(self.tokens))
 
     def _next_rng(self) -> Optional[jax.Array]:
         if self._rng is None:
@@ -218,14 +273,14 @@ class GenerationRequest:
         """Yield generated tokens as they arrive; returns at completion,
         raises the engine-side error if the request failed."""
         i = 0
-        deadline = (time.perf_counter() + timeout
+        deadline = (time.monotonic() + timeout
                     if timeout is not None else None)
         while True:
             with self._cond:
                 while len(self.tokens) <= i and not self.done:
                     remain = 0.5
                     if deadline is not None:
-                        remain = min(remain, deadline - time.perf_counter())
+                        remain = min(remain, deadline - time.monotonic())
                         if remain <= 0:
                             raise TimeoutError(
                                 f"request {self.id}: no token within "
@@ -242,13 +297,13 @@ class GenerationRequest:
 
     def result(self, timeout: Optional[float] = None) -> list[int]:
         """Block until completion; returns the full generated-token list."""
-        deadline = (time.perf_counter() + timeout
+        deadline = (time.monotonic() + timeout
                     if timeout is not None else None)
         with self._cond:
             while not self.done:
                 remain = 0.5
                 if deadline is not None:
-                    remain = min(remain, deadline - time.perf_counter())
+                    remain = min(remain, deadline - time.monotonic())
                     if remain <= 0:
                         raise TimeoutError(
                             f"request {self.id} not done within {timeout}s")
@@ -412,6 +467,11 @@ class InferenceEngine:
         self._prefix_hit_tokens = 0
         self._prefix_lookup_tokens = 0
         self._preemptions = 0
+        # written by the loop thread alone, so without the lock:
+        self._admissions = 0           # requests given a row
+        self._chunk_passes = 0         # chunk-prefill programs run
+        self._prefill_tokens = 0       # prompt tokens run through a
+        #                                prefill program (hits excluded)
         self._peak_active = 0
         self._spec_drafted = 0         # drafted tokens offered to verify
         self._spec_accepted = 0        # drafted tokens accepted
@@ -508,24 +568,72 @@ class InferenceEngine:
         stopped.  Runs on the loop thread, which holds the engine only
         WEAKLY between passes (_engine_loop) so an engine abandoned
         without shutdown() is still collectable."""
-        with self._cond:
-            # park unless there is work a pass can make progress
-            # on: an active row to decode, a prefill to advance, or a
-            # waiting request AND a free slot/row to admit it into
-            # (waiting alone must not spin when the pool is handed out;
-            # paged admission retries at the idle tick because block
-            # availability also depends on evictable cached prefixes)
-            if (not self._stopped and not self._ops
-                    and not self._active.any()
-                    and not (self._paged and self._prefilling)
-                    and not (self._waiting and self._admission_possible())):
-                # ONE bounded wait, then back out to _engine_loop: an
-                # idle engine must drop the loop thread's strong
-                # reference every tick, or it is never collectable
-                self._cond.wait(self.engine_cfg.idle_wait_s)
-                return not self._stopped
-            if self._stopped:
-                return False
+        # engine.pass opens once the park check finds work, under that
+        # check's lock, and closes with the pass, the lock long released
+        sp = tracing.NOOP
+        try:
+            with self._cond:
+                # park unless there is work a pass can make progress
+                # on: an active row to decode, a prefill to advance, or
+                # a waiting request AND a free slot/row to admit it into
+                # (waiting alone must not spin when the pool is handed
+                # out; paged admission retries at the idle tick because
+                # block availability also depends on evictable cached
+                # prefixes)
+                if (not self._stopped and not self._ops
+                        and not self._active.any()
+                        and not (self._paged and self._prefilling)
+                        and not (self._waiting
+                                 and self._admission_possible())):
+                    # ONE bounded wait, then back out to _engine_loop:
+                    # an idle engine must drop the loop thread's strong
+                    # reference every tick, or it is never collectable
+                    self._cond.wait(self.engine_cfg.idle_wait_s)
+                    return not self._stopped
+                if self._stopped:
+                    return False
+                sp = tracing.span("engine.pass").__enter__()
+                if sp:
+                    sp.set(active=int(self._active.sum()),
+                           prefilling=(len(self._prefilling)
+                                       if self._paged else 0),
+                           waiting=len(self._waiting))
+                admits = self._schedule_locked()
+            for slot, req in admits:
+                # per-admit isolation: one bad prefill fails ONE
+                # request and returns its slot; neighbors proceed
+                try:
+                    self._admit(slot, req)
+                except Exception as e:
+                    try:
+                        self.cache.free(slot)
+                    except ValueError:        # _admit already returned it
+                        pass
+                    req._finish(e)
+            try:
+                if self._paged:
+                    if self._prefilling:
+                        # at most ONE chunk per pass: prefill progress is
+                        # interleaved with decode so a long prompt cannot
+                        # stall its neighbors' token cadence
+                        self._prefill_chunk_pass()
+                    if self._active.any():
+                        self._paged_decode_iteration()
+                elif self._active.any():
+                    self._decode_iteration()
+            except Exception as e:            # step failure: fail the
+                self._fail_all(e)             # in-flight requests, keep serving
+            return True
+        finally:
+            sp.__exit__(*sys.exc_info())
+
+    def _schedule_locked(self) -> list:
+        """The pass's scheduling under ``_cond``: cross-thread ops,
+        reaping, admission.  Returns the slot engine's (slot, request)
+        admits, whose prefill runs outside the lock."""
+        with tracing.span("engine.schedule") as sp:
+            # only this thread writes the two counters
+            admitted0, preempted0 = self._admissions, self._preemptions
             if self._ops:
                 self._run_ops_locked()
             # reap cancelled waiters even when the pool is full:
@@ -551,31 +659,10 @@ class InferenceEngine:
                 while self._waiting and self.cache.n_free > 0:
                     req = self._waiting.pop(0)
                     admits.append((self.cache.alloc(), req))
-        for slot, req in admits:
-            # per-admit isolation: one bad prefill fails ONE
-            # request and returns its slot; neighbors proceed
-            try:
-                self._admit(slot, req)
-            except Exception as e:
-                try:
-                    self.cache.free(slot)
-                except ValueError:            # _admit already returned it
-                    pass
-                req._finish(e)
-        try:
-            if self._paged:
-                if self._prefilling:
-                    # at most ONE chunk per pass: prefill progress is
-                    # interleaved with decode so a long prompt cannot
-                    # stall its neighbors' token cadence
-                    self._prefill_chunk_pass()
-                if self._active.any():
-                    self._paged_decode_iteration()
-            elif self._active.any():
-                self._decode_iteration()
-        except Exception as e:                # step failure: fail the
-            self._fail_all(e)                 # in-flight requests, keep serving
-        return True
+            if sp:
+                sp.set(admitted=self._admissions - admitted0 + len(admits),
+                       preempted=self._preemptions - preempted0)
+        return admits
 
     def _admission_possible(self) -> bool:
         """Cheap park-predicate check; the real budget decision happens
@@ -614,13 +701,18 @@ class InferenceEngine:
             return
         S = self.cache.max_seq
         n = int(req.prompt.size)
-        padded = np.zeros((1, S), np.int32)
-        padded[0, :n] = req.prompt
-        logits, k_new, v_new = self._prefill(self.params, padded)
-        self.cache.write_prefill(slot, k_new[:, 0], v_new[:, 0])
-        tok = int(gpt.sample_token(logits[0, n - 1],
-                                   temperature=req.temperature,
-                                   rng=req._next_rng()))
+        req._admitted()
+        req.full_width_prefill = True
+        self._admissions += 1
+        self._prefill_tokens += n
+        with tracing.span("engine.prefill_chunk", row=slot, tokens=n,
+                          full_width=True):
+            padded = np.zeros((1, S), np.int32)
+            padded[0, :n] = req.prompt
+            with tracing.span("engine.dispatch"):
+                logits, k_new, v_new = self._prefill(self.params, padded)
+                self.cache.write_prefill(slot, k_new[:, 0], v_new[:, 0])
+            tok = self._first_token(req, logits[0, n - 1])
         req._emit(tok)
         if self._request_finished(req, tok):
             self.cache.free(slot)
@@ -665,7 +757,7 @@ class InferenceEngine:
         ev = {
             "t": time.time(), "kind": "engine_request",
             "engine": self.name, "req": req.id,
-            "start_t": req.created_wall,
+            "start_t": tracing.wall_time(int(req.created_s * 1e9)),
             "tokens": len(req.tokens),
             "spec_accepted": req.spec_accepted,
             "spec_rejected": req.spec_drafted - req.spec_accepted,
@@ -731,7 +823,10 @@ class InferenceEngine:
         self._row_blocks[row] = blocks
         self._slot_req[row] = req
         self._prefilling[row] = hit          # prefill resumes past the hit
+        req._admitted()
+        req.prefix_hit_tokens = hit
         occupied = self.engine_cfg.max_slots - len(self._free_rows)
+        self._admissions += 1
         with self._mlock:
             self._prefix_hit_tokens += hit
             self._prefix_lookup_tokens += n_prompt
@@ -783,6 +878,7 @@ class InferenceEngine:
         self._release_row(row)
         req.prompt = seq
         req._consumed = len(req.tokens)
+        req.preemptions += 1
         with self._mlock:
             self._preemptions += 1
         with self._cond:
@@ -858,6 +954,10 @@ class InferenceEngine:
         duplicates so none publishes until nearly everyone has paid.)
         On prompt completion the last real row's logits sample the
         request's first token and the row turns active."""
+        with tracing.span("engine.prefill_chunk") as sp:
+            self._advance_prefill(sp)
+
+    def _advance_prefill(self, sp) -> None:
         row = min(self._prefilling,
                   key=lambda r: (int(self._slot_req[r].prompt.size)
                                  - self._prefilling[r],
@@ -909,11 +1009,15 @@ class InferenceEngine:
             # path — bounded stall wins; short prompts always chunk
             # (one cheap window beats an S-wide forward).  (pos == 0
             # also means no adopted blocks — the table is exclusive.)
+            sp.set(row=row, tokens=n, full_width=True)
+            req.full_width_prefill = True
+            self._prefill_tokens += n
             padded = np.zeros((1, self.max_seq), np.int32)
             padded[0, :n] = prompt
-            logits, k_new, v_new = self._prefill(self.params, padded)
-            self.pool.write_prefill(self._tables[row], k_new[:, 0],
-                                    v_new[:, 0])
+            with tracing.span("engine.dispatch"):
+                logits, k_new, v_new = self._prefill(self.params, padded)
+                self.pool.write_prefill(self._tables[row], k_new[:, 0],
+                                        v_new[:, 0])
             self._finish_prefill(row, req, logits[0, n - 1])
             return
         # the write window [pos, pos+C) must only touch exclusively
@@ -925,13 +1029,21 @@ class InferenceEngine:
             if not self._cow_block(row, bidx):
                 return                     # row preempted under pressure
         n_q = min(C, n - pos)
+        sp.set(row=row, tokens=n_q, full_width=False)
+        req.chunk_passes += 1
+        self._chunk_passes += 1
+        self._prefill_tokens += n_q
         chunk_toks = np.zeros(C, np.int32)
         chunk_toks[:n_q] = prompt[pos:pos + n_q]
-        logits, k, v = self._chunk(
-            self.params, self.pool.k, self.pool.v,
-            jnp.asarray(self._tables[row]), jnp.asarray(chunk_toks),
-            jnp.int32(pos))
-        self.pool.swap(k, v)
+        with tracing.span("engine.upload") as up:
+            table, toks, at = (jnp.asarray(self._tables[row]),
+                               jnp.asarray(chunk_toks), jnp.int32(pos))
+            if up:
+                up.set(bytes=table.nbytes + toks.nbytes + at.nbytes)
+        with tracing.span("engine.dispatch"):
+            logits, k, v = self._chunk(self.params, self.pool.k,
+                                       self.pool.v, table, toks, at)
+            self.pool.swap(k, v)
         new_pos = pos + n_q
         if new_pos < n:
             self._prefilling[row] = new_pos
@@ -957,9 +1069,7 @@ class InferenceEngine:
                 self._note_prefix_published(
                     req.prompt[:full],
                     self._row_blocks[row][:full // self.pool.block_size])
-        tok = int(gpt.sample_token(last_logits,
-                                   temperature=req.temperature,
-                                   rng=req._next_rng()))
+        tok = self._first_token(req, last_logits)
         req._emit(tok)
         if self._request_finished(req, tok):
             self._paged_evict(row)
@@ -967,6 +1077,18 @@ class InferenceEngine:
         self._tokens[row] = tok
         self._positions[row] = int(req.prompt.size)
         self._active[row] = True
+
+    def _first_token(self, req: GenerationRequest, last_logits) -> int:
+        """A request's first token from its last prompt position's
+        logits, which are still on the device: the sampling is one more
+        dispatch (``engine.sample``), reading the token is the wait for
+        the prefill program (``engine.fetch``)."""
+        with tracing.span("engine.sample", rows=1):
+            tok = gpt.sample_token(last_logits,
+                                   temperature=req.temperature,
+                                   rng=req._next_rng())
+        with tracing.span("engine.fetch", bytes=4):
+            return int(tok)
 
     def _grow_row(self, row: int) -> bool:
         """Pre-step: make the row's write-target block exist and be
@@ -1095,12 +1217,15 @@ class InferenceEngine:
         identical to what the full model writes there, and the verify
         pass rewrites all drafted positions at all layers anyway."""
         w = np.where(self._active, want, 0).astype(np.int32)
-        toks, kp, vp = self._draft(
-            self.params, self.pool.k, self.pool.v,
-            jnp.asarray(self._tables), jnp.asarray(self._tokens),
-            jnp.asarray(self._positions), jnp.asarray(w))
-        self.pool.swap(kp, vp)
-        toks = np.asarray(toks)
+        with tracing.span("engine.dispatch"):
+            toks, kp, vp = self._draft(
+                self.params, self.pool.k, self.pool.v,
+                jnp.asarray(self._tables), jnp.asarray(self._tokens),
+                jnp.asarray(self._positions), jnp.asarray(w))
+            self.pool.swap(kp, vp)
+        with tracing.span("engine.fetch") as fetch:
+            toks = np.asarray(toks)
+            fetch.set(bytes=toks.nbytes)
         m = np.arange(toks.shape[1])[None, :] < w[:, None]
         drafts[:, :toks.shape[1]][m] = toks[m]
 
@@ -1117,6 +1242,15 @@ class InferenceEngine:
         drafts, want = self._spec_propose()
         if not want.any():
             return False
+        with tracing.span("engine.decode", speculative=True) as sp:
+            if sp:
+                sp.set(active=int(self._active.sum()))
+            self._spec_verify(drafts, want)
+        return True
+
+    def _spec_verify(self, drafts: np.ndarray, want: np.ndarray) -> None:
+        """The widened verify step over this pass's drafts, then the
+        accept/reject walk."""
         force_reject = False
         ctx = self._chaos("infer_speculate",
                           rows=int((want > 0).sum()),
@@ -1133,18 +1267,37 @@ class InferenceEngine:
         tok_mat[:, 0] = self._tokens
         tok_mat[:, 1:] = drafts
         n_tok = np.where(self._active, want + 1, 1).astype(np.int32)
-        logits, k, v = self._verify(
-            self.params, self.pool.k, self.pool.v,
-            jnp.asarray(self._tables), jnp.asarray(tok_mat),
-            jnp.asarray(self._positions), jnp.asarray(self._active),
-            jnp.asarray(n_tok))
-        self.pool.swap(k, v)
-        logits = np.asarray(logits)               # [n, W, V]
+        with tracing.span("engine.upload") as up:
+            args = (jnp.asarray(self._tables), jnp.asarray(tok_mat),
+                    jnp.asarray(self._positions), jnp.asarray(self._active),
+                    jnp.asarray(n_tok))
+            if up:
+                up.set(bytes=sum(a.nbytes for a in args))
+        with tracing.span("engine.dispatch"):
+            logits, k, v = self._verify(self.params, self.pool.k,
+                                        self.pool.v, *args)
+            self.pool.swap(k, v)
+        with tracing.span("engine.fetch") as fetch:
+            logits = np.asarray(logits)           # [n, W, V]
+            fetch.set(bytes=logits.nbytes)
         with self._mlock:
             self._decode_iterations += 1
             self._spec_passes += 1
             self._occupancy_sum += (float(self._active.sum())
                                     / self.engine_cfg.max_slots)
+        with tracing.span("engine.sample") as sample:
+            stepped, emitted = self._spec_accept(logits, drafts, want,
+                                                 force_reject)
+            sample.set(rows=stepped)
+        with self._mlock:
+            self._row_steps += stepped
+            self._row_tokens += emitted
+
+    def _spec_accept(self, logits: np.ndarray, drafts: np.ndarray,
+                     want: np.ndarray, force_reject: bool) -> tuple:
+        """The greedy accept/reject walk over a verify pass's logits
+        ``[n, W, V]``; returns (rows stepped, tokens emitted)."""
+        n, W = logits.shape[:2]
         greedy = np.asarray(gpt.sample_token(
             logits.reshape(n * W, -1), temperature=0.0)).reshape(n, W)
         stepped = emitted = 0
@@ -1193,21 +1346,21 @@ class InferenceEngine:
                 self._paged_evict(row)    # releases the whole chain
             else:
                 self._spec_rollback(row)
-        with self._mlock:
-            self._row_steps += stepped
-            self._row_tokens += emitted
-        return True
+        return stepped, emitted
 
     def _paged_decode_iteration(self) -> None:
-        for row in [r for r in list(self._slot_req) if self._active[r]]:
-            req = self._slot_req.get(row)
-            if req is None or not self._active[row]:
-                continue                  # preempted by an earlier row's
-            #                               block hunt this very pass
-            if req.cancelled:             # abandoned: free for live work
-                self._paged_evict(row, cache_prefix=False)
-                continue
-            self._grow_row(row)           # False = row preempted; skip
+        with tracing.span("engine.schedule") as sp:
+            preempted0 = self._preemptions
+            for row in [r for r in list(self._slot_req) if self._active[r]]:
+                req = self._slot_req.get(row)
+                if req is None or not self._active[row]:
+                    continue              # preempted by an earlier row's
+                #                           block hunt this very pass
+                if req.cancelled:         # abandoned: free for live work
+                    self._paged_evict(row, cache_prefix=False)
+                    continue
+                self._grow_row(row)       # False = row preempted; skip
+            sp.set(admitted=0, preempted=self._preemptions - preempted0)
         if not self._active.any():
             return
         # draft-then-verify when configured; False = no row produced a
@@ -1227,40 +1380,53 @@ class InferenceEngine:
                     break
                 self._prefill_one_chunk()
             return
-        logits, k, v = self._step(
-            self.params, self.pool.k, self.pool.v,
-            jnp.asarray(self._tables), jnp.asarray(self._tokens),
-            jnp.asarray(self._positions), jnp.asarray(self._active))
-        self.pool.swap(k, v)
-        if self._mesh is not None:
-            # every shard just committed its slice of the donated
-            # scatter — the point where a multi-host straggler or
-            # mid-commit death would bite, so it is chaos-testable
-            self._chaos("infer_shard_commit",
-                        tp_shards=self.pool.heads_shards)
-        logits = np.asarray(logits)
-        with self._mlock:
-            self._decode_iterations += 1
-            self._occupancy_sum += (float(self._active.sum())
-                                    / self.engine_cfg.max_slots)
-        greedy = np.asarray(gpt.sample_token(logits, temperature=0.0))
-        stepped = 0
-        for row in list(self._slot_req):
-            if not self._active[row]:     # prefilling rows ride along
-                continue
-            req = self._slot_req[row]
-            if req.temperature == 0.0:
-                tok = int(greedy[row])
-            else:
-                tok = int(gpt.sample_token(logits[row],
-                                           temperature=req.temperature,
-                                           rng=req._next_rng()))
-            req._emit(tok)
-            stepped += 1
-            self._positions[row] += 1
-            self._tokens[row] = tok
-            if self._request_finished(req, tok):
-                self._paged_evict(row)
+        with tracing.span("engine.decode", speculative=False) as sp:
+            if sp:
+                sp.set(active=int(self._active.sum()))
+            with tracing.span("engine.upload") as up:
+                args = (jnp.asarray(self._tables), jnp.asarray(self._tokens),
+                        jnp.asarray(self._positions),
+                        jnp.asarray(self._active))
+                if up:
+                    up.set(bytes=sum(a.nbytes for a in args))
+            with tracing.span("engine.dispatch"):
+                logits, k, v = self._step(self.params, self.pool.k,
+                                          self.pool.v, *args)
+                self.pool.swap(k, v)
+            if self._mesh is not None:
+                # every shard just committed its slice of the donated
+                # scatter — the point where a multi-host straggler or
+                # mid-commit death would bite, so it is chaos-testable
+                self._chaos("infer_shard_commit",
+                            tp_shards=self.pool.heads_shards)
+            with tracing.span("engine.fetch") as fetch:
+                logits = np.asarray(logits)
+                fetch.set(bytes=logits.nbytes)
+            with self._mlock:
+                self._decode_iterations += 1
+                self._occupancy_sum += (float(self._active.sum())
+                                        / self.engine_cfg.max_slots)
+            with tracing.span("engine.sample") as sample:
+                greedy = np.asarray(gpt.sample_token(logits,
+                                                     temperature=0.0))
+                stepped = 0
+                for row in list(self._slot_req):
+                    if not self._active[row]:   # prefilling rows ride along
+                        continue
+                    req = self._slot_req[row]
+                    if req.temperature == 0.0:
+                        tok = int(greedy[row])
+                    else:
+                        tok = int(gpt.sample_token(
+                            logits[row], temperature=req.temperature,
+                            rng=req._next_rng()))
+                    req._emit(tok)
+                    stepped += 1
+                    self._positions[row] += 1
+                    self._tokens[row] = tok
+                    if self._request_finished(req, tok):
+                        self._paged_evict(row)
+                sample.set(rows=stepped)
         with self._mlock:
             self._row_steps += stepped
             self._row_tokens += stepped
@@ -1283,38 +1449,52 @@ class InferenceEngine:
     # ------------------------------------------------------------ slot path
 
     def _decode_iteration(self) -> None:
-        logits, k, v = self._step(
-            self.params, self.cache.k, self.cache.v,
-            jnp.asarray(self._tokens), jnp.asarray(self._positions),
-            jnp.asarray(self._active))
-        self.cache.swap(k, v)
-        logits = np.asarray(logits)
-        with self._mlock:
-            self._decode_iterations += 1
-            self._occupancy_sum += (float(self._active.sum())
-                                    / self.engine_cfg.max_slots)
-        # greedy rows sample in ONE vectorized call (the common/benchmark
-        # path: one argmax over [n_slots, vocab], not one dispatch per
-        # slot); temperature rows keep their per-request rng
-        greedy = np.asarray(gpt.sample_token(logits, temperature=0.0))
-        stepped = 0
-        for slot in list(self._slot_req):
-            req = self._slot_req[slot]
-            if req.cancelled:             # abandoned (timeout/disconnect):
-                self._evict(slot)         # free the slot for live work
-                continue
-            if req.temperature == 0.0:
-                tok = int(greedy[slot])
-            else:
-                tok = int(gpt.sample_token(logits[slot],
-                                           temperature=req.temperature,
-                                           rng=req._next_rng()))
-            req._emit(tok)
-            stepped += 1
-            self._positions[slot] += 1
-            self._tokens[slot] = tok
-            if self._request_finished(req, tok):
-                self._evict(slot)
+        with tracing.span("engine.decode", speculative=False) as sp:
+            if sp:
+                sp.set(active=int(self._active.sum()))
+            with tracing.span("engine.upload") as up:
+                args = (jnp.asarray(self._tokens),
+                        jnp.asarray(self._positions),
+                        jnp.asarray(self._active))
+                if up:
+                    up.set(bytes=sum(a.nbytes for a in args))
+            with tracing.span("engine.dispatch"):
+                logits, k, v = self._step(self.params, self.cache.k,
+                                          self.cache.v, *args)
+                self.cache.swap(k, v)
+            with tracing.span("engine.fetch") as fetch:
+                logits = np.asarray(logits)
+                fetch.set(bytes=logits.nbytes)
+            with self._mlock:
+                self._decode_iterations += 1
+                self._occupancy_sum += (float(self._active.sum())
+                                        / self.engine_cfg.max_slots)
+            with tracing.span("engine.sample") as sample:
+                # greedy rows sample in ONE vectorized call (the common/
+                # benchmark path: one argmax over [n_slots, vocab], not
+                # one dispatch per slot); temperature rows keep their
+                # per-request rng
+                greedy = np.asarray(gpt.sample_token(logits,
+                                                     temperature=0.0))
+                stepped = 0
+                for slot in list(self._slot_req):
+                    req = self._slot_req[slot]
+                    if req.cancelled:     # abandoned (timeout/disconnect):
+                        self._evict(slot)   # free the slot for live work
+                        continue
+                    if req.temperature == 0.0:
+                        tok = int(greedy[slot])
+                    else:
+                        tok = int(gpt.sample_token(
+                            logits[slot], temperature=req.temperature,
+                            rng=req._next_rng()))
+                    req._emit(tok)
+                    stepped += 1
+                    self._positions[slot] += 1
+                    self._tokens[slot] = tok
+                    if self._request_finished(req, tok):
+                        self._evict(slot)
+                sample.set(rows=stepped)
         with self._mlock:
             self._row_steps += stepped
             self._row_tokens += stepped
@@ -1587,6 +1767,9 @@ class InferenceEngine:
             hit_toks = self._prefix_hit_tokens
             lookup_toks = self._prefix_lookup_tokens
             preemptions = self._preemptions
+            admissions = self._admissions
+            chunk_passes = self._chunk_passes
+            prefill_tokens = self._prefill_tokens
             peak = self._peak_active
             drafted = self._spec_drafted
             accepted = self._spec_accepted
@@ -1603,6 +1786,11 @@ class InferenceEngine:
             "generated_tokens": generated,
             "requests_completed": completed,
             "decode_iterations": iters,
+            # counters at the span boundaries (util/tracing.py); /metrics
+            # exports them (metrics_snapshot)
+            "admissions": admissions,
+            "chunk_passes": chunk_passes,
+            "prefill_tokens": prefill_tokens,
             # tokens emitted per (row, compiled call) pair: exactly 1.0
             # for plain decode by construction, 1 + accepted-per-pass
             # under speculation — batch width cancels out
@@ -1687,6 +1875,7 @@ def metrics_snapshot() -> list:
         engines = dict(_ENGINES)
     active, waiting, occ, gen, comp = {}, {}, {}, {}, {}
     butil, phit, pcached, preempt = {}, {}, {}, {}
+    admits, chunks, ptoks = {}, {}, {}
     tps, arate, saccept = {}, {}, {}
     meshdev, tpsh = {}, {}
     for name, eng in sorted(engines.items()):
@@ -1707,6 +1896,12 @@ def metrics_snapshot() -> list:
         phit[key] = float(st.get("prefix_hit_rate", 0.0))
         pcached[key] = float(st.get("prefix_cached_blocks", 0))
         preempt[key] = float(st.get("preemptions", 0))
+        # the prefill side of the load: prefill against generated tokens
+        # says which of the two a replica's passes go to, chunk passes
+        # over admissions how many prefill programs a prompt costs
+        admits[key] = float(st["admissions"])
+        chunks[key] = float(st["chunk_passes"])
+        ptoks[key] = float(st["prefill_tokens"])
         # speculation signal, per replica: accept-rate is the drafter's
         # quality gauge, tokens/step the latency win it buys
         tps[key] = float(st.get("tokens_per_step", 0.0))
@@ -1737,6 +1932,14 @@ def metrics_snapshot() -> list:
          "Blocks held by the radix prefix index", pcached or zero),
         ("ray_tpu_inference_preemptions_total", "counter",
          "Requests requeued by block-pressure preemption", preempt or zero),
+        ("ray_tpu_inference_admissions_total", "counter",
+         "Requests given a cache row (a preempted request counts again)",
+         admits or zero),
+        ("ray_tpu_inference_chunk_passes_total", "counter",
+         "Chunk-prefill programs run", chunks or zero),
+        ("ray_tpu_inference_prefill_tokens_total", "counter",
+         "Prompt tokens run through a prefill program (prefix-cache "
+         "hits excluded)", ptoks or zero),
         ("ray_tpu_inference_tokens_per_step", "gauge",
          "Tokens emitted per compiled decode/verify call (speculative "
          "decoding pushes this above 1)", tps or zero),

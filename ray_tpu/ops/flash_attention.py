@@ -132,6 +132,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, need_lse=False):
         out_specs=[o_spec, lse_spec] if need_lse else [o_spec],
         out_shape=[o_shape, lse_shape] if need_lse else [o_shape],
         interpret=_interpret_mode(),
+        name="flash_fwd",
     )(qf, kf, vf)
     out = res[0].reshape(b, h, sq, d)
     return (out, res[1]) if need_lse else (out, None)
@@ -322,6 +323,7 @@ def _bwd_pallas(scale, causal, bq, bk, res, do):
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
         **params,
     )(qf, dof, lse, delta, kf, vf)
 
@@ -339,6 +341,7 @@ def _bwd_pallas(scale, causal, bq, bk, res, do):
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
         **params,
     )(qf, dof, lse, delta, kf, vf)
 

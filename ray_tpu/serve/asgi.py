@@ -18,12 +18,14 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 from typing import Any, Optional
 from urllib.parse import unquote, urlparse
 
 from ray_tpu.serve.deployment import Deployment, DeploymentOptions
 from ray_tpu.serve.http_proxy import _jsonable
 from ray_tpu.serve.long_poll import LongPollClient
+from ray_tpu.util import tracing
 
 
 class _ASGIReplica:
@@ -250,7 +252,19 @@ class AsyncHttpProxy:
             await self._respond_json(writer, 404,
                                      {"error": f"no route /{name}"})
             return True
+        # always on: the root of the request's trace, from the parsed
+        # body to the last byte written
+        with tracing.span("front.request", kind="server", always=True,
+                          route=name) as front:
+            return await self._call_replica(writer, state, method, path,
+                                            parsed, headers, body, front)
 
+    async def _call_replica(self, writer, state, method, path, parsed,
+                            headers, body, front) -> bool:
+        """Call the deployment and write its answer.  The call crosses
+        to an executor thread, which does not carry contextvars: the
+        span's context goes with it explicitly."""
+        ctx = front.context()
         loop = asyncio.get_running_loop()
         if getattr(state.deployment, "is_asgi", False):
             scope = {
@@ -264,7 +278,7 @@ class AsyncHttpProxy:
             handle = DeploymentHandle(state, "handle_asgi")
             try:
                 out = await loop.run_in_executor(
-                    self._executor,
+                    self._executor, tracing.call_in_context, ctx,
                     lambda: handle.remote(scope, body).result(timeout=120))
             except Exception as e:
                 # same contract as the JSON path: app errors become 500s,
@@ -292,7 +306,7 @@ class AsyncHttpProxy:
         handle = DeploymentHandle(state)
         try:
             out = await loop.run_in_executor(
-                self._executor,
+                self._executor, tracing.call_in_context, ctx,
                 lambda: handle.remote(arg).result(timeout=120))
         except Exception as e:
             retry_after = _shed_retry_after(e)
@@ -310,7 +324,7 @@ class AsyncHttpProxy:
             return True
         if hasattr(out, "__next__") or hasattr(out, "__anext__"):
             try:
-                await self._respond_stream(writer, out, loop)
+                await self._respond_stream(writer, out, loop, front)
             except (ConnectionError, asyncio.IncompleteReadError):
                 raise
             except Exception:
@@ -358,10 +372,11 @@ class AsyncHttpProxy:
         writer.write(b"\r\n".join(lines) + b"\r\n\r\n" + body)
         await writer.drain()
 
-    async def _respond_stream(self, writer, it, loop) -> None:
+    async def _respond_stream(self, writer, it, loop, front) -> None:
         """Chunked transfer-encoding over a (sync) iterator result —
         each chunk flushes as the replica produces it (reference:
-        StreamingResponse through the proxy)."""
+        StreamingResponse through the proxy).  ``front`` (the request's
+        span) is told when the first chunk is written and drained."""
         writer.write(b"HTTP/1.1 200 X\r\n"
                      b"Content-Type: application/octet-stream\r\n"
                      b"Transfer-Encoding: chunked\r\n"
@@ -373,6 +388,8 @@ class AsyncHttpProxy:
                     else json.dumps(_jsonable(chunk)).encode())
             writer.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
             await writer.drain()
+            if "first_chunk_ns" not in front.attributes:
+                front.set(first_chunk_ns=time.monotonic_ns())
 
         if hasattr(it, "__anext__"):
             # async generator results drive directly on this loop

@@ -14,6 +14,7 @@ from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Optional
 
 from ray_tpu.serve.controller import DeploymentState, ReplicaHandle
+from ray_tpu.util import tracing
 
 
 def _is_timeout(e: BaseException) -> bool:
@@ -115,7 +116,10 @@ class DeploymentHandle:
                 # in-process Future path) — do not invent a deadline
                 return ray_tpu.get(ref, timeout=timeout)
         else:
+            # the pool's thread does not carry contextvars: the
+            # caller's span context (the serve front's) goes explicitly
             fut: Future = self._ensure_pool().submit(
+                tracing.call_in_context, tracing.inject_context(),
                 replica.impl.handle_request, method, args, kwargs)
 
             def resolve_inner(timeout):

@@ -23,6 +23,7 @@ from ray_tpu.train import session
 from ray_tpu.train.config import RunConfig, ScalingConfig
 from ray_tpu.train.step import TrainState, make_train_step, shard_batch
 from ray_tpu.train.trainer import DataParallelTrainer
+from ray_tpu.util import tracing
 
 
 class JaxTrainer(DataParallelTrainer):
@@ -101,27 +102,46 @@ class JaxTrainer(DataParallelTrainer):
             next(data_iter)
         t0 = time.perf_counter()
         tokens_done = 0
+        # spans (recorded while tracing is on or a profiler session
+        # runs): siblings that carry the step number; train.step, the
+        # profiler's step marker, covers the dispatch only, train.fetch
+        # (inside train.report) the wait for the device
         for i in range(start_step, o["num_steps"]):
-            batch = next(data_iter)
-            batch = shard_batch(batch, mesh)
-            state, metrics = step_fn(state, batch)
+            with tracing.span("train.next_batch", step=i):
+                batch = next(data_iter)
+            with tracing.span("train.shard_batch", step=i):
+                batch = shard_batch(batch, mesh)
+            with tracing.span("train.step", step_num=i):
+                state, metrics = step_fn(state, batch)
             leaf = jax.tree.leaves(batch)[0]
             tokens_done += int(leaf.shape[0]) * (
                 int(leaf.shape[1]) if leaf.ndim > 1 else 1)
 
             is_last = i + 1 == o["num_steps"]
             if (i + 1) % o["report_every"] == 0 or is_last:
-                m = {k: float(v) for k, v in metrics.items()}
-                dt = time.perf_counter() - t0
-                m.update(step=i + 1, throughput=tokens_done / max(dt, 1e-9))
-                if (o["eval_fn"] is not None and o["eval_every"]
-                        and (i + 1) % o["eval_every"] == 0):
-                    m["eval"] = float(o["eval_fn"](state.params))
-                ckpt = None
-                if (o["checkpoint_every"]
-                        and (i + 1) % o["checkpoint_every"] == 0) or is_last:
-                    ckpt = {"params": state.params,
-                            "opt_state": state.opt_state,
-                            "step": i + 1}
-                session.report(m, checkpoint=ckpt)
+                with tracing.span("train.report", step=i):
+                    # the float() waits for every step dispatched so far
+                    with tracing.span("train.fetch", step=i):
+                        m = {k: float(v) for k, v in metrics.items()}
+                    dt = time.perf_counter() - t0
+                    m.update(step=i + 1,
+                             throughput=tokens_done / max(dt, 1e-9))
+                    if (o["eval_fn"] is not None and o["eval_every"]
+                            and (i + 1) % o["eval_every"] == 0):
+                        m["eval"] = float(o["eval_fn"](state.params))
+                    ckpt = None
+                    if (o["checkpoint_every"]
+                            and (i + 1) % o["checkpoint_every"] == 0) \
+                            or is_last:
+                        ckpt = {"params": state.params,
+                                "opt_state": state.opt_state,
+                                "step": i + 1}
+                    # a report that saves is train.checkpoint as well
+                    with (tracing.span("train.checkpoint", step=i)
+                          if ckpt else tracing.NOOP) as sp:
+                        if sp:
+                            sp.set(bytes=sum(
+                                x.nbytes for x in jax.tree.leaves(ckpt)
+                                if hasattr(x, "nbytes")))
+                        session.report(m, checkpoint=ckpt)
         self.final_state = state

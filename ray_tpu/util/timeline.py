@@ -7,7 +7,9 @@ python/ray/experimental/state + _private/profiling.py):
   * task state events        → ``X`` slices (RUNNING→FINISHED pairs)
   * flight-recorder records  → one ``X`` slice PER LIFECYCLE STAGE, so
     "where do the milliseconds go" is visible per task
-  * tracing spans            → ``X`` slices grouped by emitting pid
+  * tracing spans            → ``X`` slices grouped by emitting pid,
+    a span's attributes in the slice args (engine passes, request
+    lifecycle, serve front, trainer loop: util/tracing.py)
   * chaos (fault-injection)  → ``i`` instant events, so injected faults
     show up attributed in the same view as the latency they caused
   * serve-fleet ingress      → admission/shed/route/resume/scale events
@@ -70,8 +72,10 @@ def build_trace(task_events: Iterable = (), records: Iterable = (),
             "dur": max(0.0, (s["end"] - s["start"]) * 1e6),
             "pid": f"pid {s.get('pid', '?')}",
             "tid": s.get("kind", "span"),
-            "args": {"trace_id": s.get("trace_id"),
+            "args": {**(s.get("attributes") or {}),
+                     "trace_id": s.get("trace_id"),
                      "span_id": s.get("span_id"),
+                     "parent_id": s.get("parent_id"),
                      "status": s.get("status")},
         })
 

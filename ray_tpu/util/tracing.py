@@ -1,52 +1,117 @@
-"""Distributed tracing: spans around task/actor submission + execution.
+"""Spans: the one tracing primitive of every layer.
 
 Reference capability: python/ray/util/tracing/tracing_helper.py — when
 tracing is enabled, every ``.remote()`` call opens a client span whose
 context is injected into the task spec, and the executing worker opens
 a server span as its child, so cross-process traces stitch together in
-one trace id.
+one trace id.  The same primitive marks the serving engine's passes,
+a request's lifecycle, the serve front and the trainer's loop.
 
-Dependency-light redesign (no opentelemetry wheel in this image): spans
-are plain dicts with W3C-style ids (128-bit trace id, 64-bit span id);
-context propagates in-process via a contextvar and cross-process inside
-the task spec (``trace_ctx``).  Finished spans land in an in-process
-buffer and, when ``RAY_TPU_TRACE_DIR`` is set, one JSONL file per
-process — ``collect_spans()`` merges them for analysis/tests.
+Dependency-light (no opentelemetry wheel in this image): a span is a
+small object with W3C-style ids (128-bit trace id, 64-bit span id: a
+per-process random prefix plus a counter); context propagates
+in-process via a contextvar and across threads or processes explicitly
+(``inject_context()`` -> ``span(..., parent=ctx)``).
 
-Emission is batched: ``_emit`` appends to a pending list under the
-span-buffer lock and the actual ``write+flush`` runs under a separate
+The off/on contract:
+
+  * **Off** (no ``enable_tracing()`` / ``RAY_TPU_TRACING``, no
+    ``jax.profiler`` session): ``span()`` is a flag test and returns
+    the shared ``NOOP``: no allocation, no lock, no id, no ring append.
+    ``NOOP`` is falsy, so a site guards attributes that cost something
+    to compute with ``if sp:``.
+  * **On**: ``enable_tracing()`` / ``RAY_TPU_TRACING`` / a set
+    ``RAY_TPU_TRACE_DIR``, OR a ``jax.profiler`` session is active.
+    While a session is active every span is also entered as a
+    ``jax.profiler.TraceAnnotation`` of the same name (a span with
+    ``step_num=`` as a ``StepTraceAnnotation``), so it lies on the host
+    plane of the same ``.xplane.pb`` as the device's ``XLA Ops`` line,
+    on the profiler's own timeline.  A process that has not imported
+    ``jax`` never imports it here.
+  * **Always on**: ``span(..., always=True)`` and ``record_span()``
+    (a span built from stamps already taken) record whatever the flag
+    says: the serve front's request span and a request's three
+    lifecycle spans, a handful of ring appends per request.  They are
+    not entered as profiler annotations (they cross ``await``s and
+    threads).
+
+Stamps are ``time.monotonic_ns()``, the clock of the benchmark's own
+stamps.  Finished spans go to a bounded in-memory ring;
+``get_finished_spans()`` exports them as dicts whose ``t0_ns`` /
+``t1_ns`` are the stamps and whose wall-clock ``start`` / ``end`` are
+derived from one per-process anchor (wall minus monotonic, taken
+once).  With ``RAY_TPU_TRACE_DIR`` unset no span touches a file or a
+lock.  With it set, every finished span is also written to one JSONL
+file per process, batched: ``_emit`` appends to a pending list under
+the buffer lock and the actual ``write+flush`` runs under a separate
 I/O lock, draining everything pending in one write.  Threads that find
-the I/O lock busy just leave their span pending for the current writer
-— the hot path never blocks on disk (the previous design held the one
-global lock across ``write``+``flush`` per span, serializing every
-tracer behind the disk).  ``flush_spans()`` (also run at exit and by
-``collect_spans``) force-drains.
+the I/O lock busy leave their span pending for the current writer —
+the hot path never blocks on disk, and no lock is held across a file
+write but the I/O lock itself.  ``flush_spans()`` (also run at exit
+and by ``collect_spans``) force-drains; ``collect_spans()`` merges
+every process's file.
 """
 
 from __future__ import annotations
 
 import atexit
-import contextlib
+import collections
 import contextvars
 import glob
+import itertools
 import json
 import os
 import secrets
+import sys
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, List, Optional
 
 _current: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
     "ray_tpu_trace_ctx", default=None)
 
-_lock = threading.Lock()          # span buffer + pending list
+# what a reader needs: a request leaves four spans while tracing is off
+# (the last ~2,000 requests), a traced pass ~20 (~400 passes)
+RING_SIZE = 8_192
+_ring: "collections.deque[Span]" = collections.deque(maxlen=RING_SIZE)
+_lock = threading.Lock()          # pending list
 _io_lock = threading.Lock()       # file open/write/flush
-_finished: List[dict] = []
-_pending: List[dict] = []         # spans awaiting a file write
-_MAX_BUFFER = 10_000
+_pending: List["Span"] = []       # spans awaiting a file write
 _file = None
 _file_dir: Optional[str] = None   # dir _file was opened in (reset on change)
 _enabled: Optional[bool] = None
+
+# wall clock minus monotonic clock, once: export derives start/end
+_ANCHOR_NS = time.time_ns() - time.monotonic_ns()
+
+_counter = itertools.count(1)
+_span_prefix = secrets.token_hex(4)
+_trace_prefix = secrets.token_hex(12)
+
+
+def _reseed() -> None:
+    """A forked child must not repeat its parent's ids."""
+    global _span_prefix, _trace_prefix
+    _span_prefix, _trace_prefix = secrets.token_hex(4), secrets.token_hex(12)
+
+
+os.register_at_fork(after_in_child=_reseed)
+
+# jax.profiler's annotation classes, looked up once jax is imported
+_annotation = None
+_step_annotation = None
+
+
+def _profiling() -> bool:
+    """True while a ``jax.profiler`` session is active in this process."""
+    global _annotation, _step_annotation
+    if _annotation is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        if prof is None:
+            return False
+        _annotation, _step_annotation = (prof.TraceAnnotation,
+                                         prof.StepTraceAnnotation)
+    return _annotation.is_enabled()
 
 
 def tracing_enabled() -> bool:
@@ -57,6 +122,16 @@ def tracing_enabled() -> bool:
         _enabled = os.environ.get("RAY_TPU_TRACING", "").lower() in (
             "1", "true", "yes") or bool(os.environ.get("RAY_TPU_TRACE_DIR"))
     return _enabled
+
+
+def active() -> bool:
+    """Would ``span()`` record now: the flag, or a profiler session."""
+    return tracing_enabled() or _profiling()
+
+
+def wall_time(t_ns: int) -> float:
+    """Wall-clock seconds of a ``time.monotonic_ns()`` stamp."""
+    return (t_ns + _ANCHOR_NS) / 1e9
 
 
 def enable_tracing(trace_dir: Optional[str] = None) -> None:
@@ -82,14 +157,154 @@ def disable_tracing() -> None:
             _file_dir = None
 
 
-def _emit(span: dict) -> None:
+class _NoopSpan:
+    """What a span site gets while tracing is off: one shared, falsy,
+    inert object."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def __bool__(self):
+        return False
+
+    def set(self, **attributes) -> None:
+        pass
+
+
+NOOP = _NoopSpan()
+
+
+class Span:
+    """One recording span; a context manager.  ``set()`` adds
+    attributes while it is open."""
+    __slots__ = ("name", "kind", "attributes", "trace_id", "span_id",
+                 "parent_id", "t0_ns", "t1_ns", "status", "_token", "_ann")
+
+    def __init__(self, name: str, kind: str, parent: Optional[dict],
+                 attributes: dict, annotate: bool = False,
+                 step_num: Optional[int] = None):
+        if parent is None:
+            parent = _current.get()
+        self.name, self.kind, self.attributes = name, kind, attributes
+        if step_num is not None:
+            attributes["step"] = step_num
+        n = next(_counter)
+        if parent:
+            self.trace_id = parent["trace_id"]
+            self.parent_id = parent.get("span_id")
+        else:
+            self.trace_id = f"{_trace_prefix}{n & 0xffffffff:08x}"
+            self.parent_id = None
+        self.span_id = f"{_span_prefix}{n & 0xffffffff:08x}"
+        self.status = "ok"
+        self.t0_ns = self.t1_ns = 0
+        self._token = None
+        self._ann = None
+        if annotate:
+            self._ann = (_annotation(name, **attributes)
+                         if step_num is None else
+                         _step_annotation(name, step_num=step_num,
+                                          **attributes))
+
+    def context(self) -> dict:
+        return {"trace_id": self.trace_id, "span_id": self.span_id}
+
+    def set(self, **attributes) -> None:
+        self.attributes.update(attributes)
+        if self._ann is not None:
+            self._ann.set_metadata(**attributes)
+
+    def __bool__(self):
+        return True
+
+    def __enter__(self):
+        self._token = _current.set(self.context())
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0_ns = time.monotonic_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t1_ns = time.monotonic_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        _current.reset(self._token)
+        if exc_type is not None:
+            self.status = f"error: {exc_type.__name__}"
+        _emit(self)
+        return False
+
+    def export(self) -> dict:
+        return {"name": self.name, "kind": self.kind,
+                "trace_id": self.trace_id, "span_id": self.span_id,
+                "parent_id": self.parent_id,
+                "start": wall_time(self.t0_ns),
+                "end": wall_time(self.t1_ns),
+                "t0_ns": self.t0_ns, "t1_ns": self.t1_ns,
+                "pid": os.getpid(), "attributes": dict(self.attributes),
+                "status": self.status}
+
+
+def span(name: str, *, kind: str = "internal",
+         parent: Optional[dict] = None, always: bool = False,
+         step_num: Optional[int] = None, **attributes: Any):
+    """Open a span: ``with span("engine.decode", rows=3) as sp``.
+
+    Parent = ``parent`` (a context from ``inject_context()``, carried
+    across a thread or a process) or the current in-process span.
+    Returns ``NOOP`` unless tracing is on (see the module docstring);
+    ``always=True`` records regardless.  ``step_num=`` marks a
+    training step (attribute ``step``; a ``StepTraceAnnotation`` in a
+    profiler session)."""
+    if not (always or active()):
+        return NOOP
+    return Span(name, kind, parent, attributes,
+                annotate=not always and _profiling(), step_num=step_num)
+
+
+def record_span(name: str, t0_ns: int, t1_ns: int, *,
+                parent: Optional[dict] = None, kind: str = "internal",
+                **attributes: Any) -> Span:
+    """A finished span from two ``time.monotonic_ns()`` stamps taken
+    earlier; recorded whatever the flag says."""
+    s = Span(name, kind, parent, attributes)
+    s.t0_ns, s.t1_ns = int(t0_ns), int(t1_ns)
+    _emit(s)
+    return s
+
+
+def inject_context() -> Optional[dict]:
+    """The current span's context, for carrying across a thread or in a
+    task spec (reference: tracing_helper.py
+    _inject_tracing_into_function); None outside any span.  The serve
+    front's span is always on, so a caller that ships the context
+    elsewhere only while tracing is on tests ``active()`` itself."""
+    return _current.get()
+
+
+def call_in_context(ctx: Optional[dict], fn, *args, **kwargs):
+    """Run ``fn`` with ``ctx`` as the current span context: the
+    receiving end of a thread hop (an executor does not carry
+    contextvars)."""
+    if ctx is None:
+        return fn(*args, **kwargs)
+    token = _current.set(ctx)
+    try:
+        return fn(*args, **kwargs)
+    finally:
+        _current.reset(token)
+
+
+def _emit(span_: Span) -> None:
+    _ring.append(span_)
+    if not os.environ.get("RAY_TPU_TRACE_DIR"):
+        return
     with _lock:
-        _finished.append(span)
-        if len(_finished) > _MAX_BUFFER:
-            del _finished[:len(_finished) - _MAX_BUFFER]
-        if not os.environ.get("RAY_TPU_TRACE_DIR"):
-            return
-        _pending.append(span)
+        _pending.append(span_)
     # opportunistic drain: whoever gets the I/O lock writes the whole
     # batch; a contended emitter's span is picked up by a retry here —
     # the in-flight writer popped its batch BEFORE this append landed,
@@ -135,63 +350,20 @@ def _drain_locked() -> None:
         os.makedirs(d, exist_ok=True)
         _file = open(os.path.join(d, f"spans-{os.getpid()}.jsonl"), "a")
         _file_dir = d
-    _file.write("".join(json.dumps(s) + "\n" for s in batch))
+    _file.write("".join(json.dumps(s.export(), default=str) + "\n"
+                        for s in batch))
     _file.flush()
 
 
-@contextlib.contextmanager
-def start_span(name: str, kind: str = "internal",
-               attributes: Optional[Dict[str, Any]] = None,
-               remote_ctx: Optional[dict] = None) -> Iterator[dict]:
-    """Open a span; parent = remote_ctx (cross-process) or the current
-    in-process span. Yields the mutable span dict (add attributes)."""
-    if not tracing_enabled():
-        yield {}
-        return
-    parent = remote_ctx if remote_ctx is not None else _current.get()
-    span = {
-        "name": name,
-        "kind": kind,
-        "trace_id": (parent or {}).get("trace_id") or secrets.token_hex(16),
-        "span_id": secrets.token_hex(8),
-        "parent_id": (parent or {}).get("span_id"),
-        "start": time.time(),
-        "pid": os.getpid(),
-        "attributes": dict(attributes or {}),
-        "status": "ok",
-    }
-    token = _current.set({"trace_id": span["trace_id"],
-                          "span_id": span["span_id"]})
-    try:
-        yield span
-    except BaseException as e:
-        span["status"] = f"error: {type(e).__name__}"
-        raise
-    finally:
-        _current.reset(token)
-        span["end"] = time.time()
-        _emit(span)
-
-
-def inject_context() -> Optional[dict]:
-    """Current span context for embedding in a task spec (reference:
-    tracing_helper.py _inject_tracing_into_function)."""
-    if not tracing_enabled():
-        return None
-    return _current.get()
-
-
 def get_finished_spans(name: Optional[str] = None) -> List[dict]:
-    with _lock:
-        spans = list(_finished)
-    if name:
-        spans = [s for s in spans if s["name"] == name]
-    return spans
+    """The ring's spans, oldest first, exported as dicts."""
+    return [s.export() for s in _ring.copy()
+            if name is None or s.name == name]
 
 
 def clear() -> None:
+    _ring.clear()
     with _lock:
-        _finished.clear()
         _pending.clear()
 
 

@@ -2,6 +2,9 @@
 python/ray/tests/test_tracing.py — task/actor spans, context
 propagation, trace stitching)."""
 
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -19,8 +22,8 @@ def traced(tmp_path):
 
 
 def test_span_nesting_and_ids(traced):
-    with tracing.start_span("outer") as outer:
-        with tracing.start_span("inner") as inner:
+    with tracing.span("outer") as outer:
+        with tracing.span("inner") as inner:
             pass
     spans = tracing.get_finished_spans()
     by_name = {s["name"]: s for s in spans}
@@ -32,7 +35,7 @@ def test_span_nesting_and_ids(traced):
 
 def test_span_error_status(traced):
     with pytest.raises(ValueError):
-        with tracing.start_span("boom"):
+        with tracing.span("boom"):
             raise ValueError("x")
     (span,) = tracing.get_finished_spans("boom")
     assert span["status"].startswith("error")
@@ -41,8 +44,8 @@ def test_span_error_status(traced):
 def test_disabled_is_noop():
     tracing.disable_tracing()
     tracing.clear()
-    with tracing.start_span("nothing") as s:
-        assert s == {}
+    with tracing.span("nothing") as s:
+        assert s is tracing.NOOP and not s
     assert tracing.get_finished_spans() == []
 
 
@@ -51,7 +54,7 @@ def test_task_spans_stitch_across_processes(traced, rt_init):
     def work(x):
         return x + 1
 
-    with tracing.start_span("driver_root"):
+    with tracing.span("driver_root"):
         ref = work.remote(1)
         assert ray_tpu.get(ref, timeout=60) == 2
 
@@ -91,11 +94,11 @@ def test_trace_dir_change_after_disable_reopens_file(tmp_path):
     cached span file at the NEW dir (the old cached handle is stale)."""
     a, b = str(tmp_path / "a"), str(tmp_path / "b")
     tracing.enable_tracing(a)
-    with tracing.start_span("in_a"):
+    with tracing.span("in_a"):
         pass
     tracing.disable_tracing()
     tracing.enable_tracing(b)
-    with tracing.start_span("in_b"):
+    with tracing.span("in_b"):
         pass
     tracing.flush_spans()
     names_a = {s["name"] for s in tracing.collect_spans(a)}
@@ -112,9 +115,332 @@ def test_emit_batches_are_flushed_by_collect(tmp_path):
     d = str(tmp_path / "traces")
     tracing.enable_tracing(d)
     for i in range(5):
-        with tracing.start_span(f"s{i}"):
+        with tracing.span(f"s{i}"):
             pass
     spans = tracing.collect_spans(d)
     tracing.disable_tracing()
     tracing.clear()
     assert {s["name"] for s in spans} >= {f"s{i}" for i in range(5)}
+
+
+# -- the off/on contract, ids, clock ----------------------------------------
+
+def test_off_span_site_is_the_shared_noop_and_leaves_the_ring():
+    tracing.disable_tracing()
+    tracing.clear()
+    assert not tracing.active()
+    a = tracing.span("engine.pass", rows=3)
+    with a as sp:
+        sp.set(anything=1)
+    assert a is tracing.NOOP and tracing.span("other") is a
+    assert tracing.get_finished_spans() == []
+    # always-on spans record whatever the flag says
+    with tracing.span("front.request", always=True, route="v1") as front:
+        assert tracing.inject_context() == front.context()
+        # core/runtime.py ships a context in a task spec only if so
+        assert not tracing.active()
+    assert [s["name"] for s in tracing.get_finished_spans()] \
+        == ["front.request"]
+    tracing.clear()
+
+
+def test_ids_parents_and_wall_clock_export(traced):
+    before = time.time()
+    with tracing.span("outer", rows=2) as outer:
+        ctx = tracing.inject_context()
+        with tracing.span("inner"):
+            pass
+        # the receiving end of a thread hop: the context goes explicitly
+        def hop():
+            with tracing.span("hop"):
+                pass
+        t = threading.Thread(target=tracing.call_in_context,
+                             args=(ctx, hop))
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    stamped = tracing.record_span("request.queue", outer.t0_ns,
+                                  outer.t1_ns, parent=outer.context(),
+                                  prompt_tokens=5)
+    after = time.time()
+    spans = {s["name"]: s for s in tracing.get_finished_spans()}
+    o = spans["outer"]
+    assert o["parent_id"] is None and o["attributes"] == {"rows": 2}
+    for child in ("inner", "hop", "request.queue"):
+        assert spans[child]["parent_id"] == o["span_id"]
+        assert spans[child]["trace_id"] == o["trace_id"]
+    assert len(o["trace_id"]) == 32 and len(o["span_id"]) == 16
+    assert len({s["span_id"] for s in spans.values()}) == len(spans)
+    # stamps are the monotonic clock, start/end the wall clock
+    assert o["t0_ns"] == outer.t0_ns <= o["t1_ns"]
+    assert abs((o["t1_ns"] - o["t0_ns"]) / 1e9
+               - (o["end"] - o["start"])) < 1e-6
+    assert before - 0.01 <= o["start"] <= o["end"] <= after + 0.01
+    assert stamped.attributes == {"prompt_tokens": 5}
+    assert tracing.inject_context() is None      # outside any span
+
+
+def test_timeline_renders_span_names_and_attributes(traced):
+    from ray_tpu.util.timeline import build_trace
+    with tracing.span("engine.pass", active=3):
+        with tracing.span("engine.fetch", bytes=128):
+            pass
+    trace = build_trace(spans=tracing.collect_spans(traced))
+    by_name = {e["name"]: e for e in trace["traceEvents"]}
+    assert by_name["engine.fetch"]["args"]["bytes"] == 128
+    assert by_name["engine.pass"]["args"]["active"] == 3
+    assert by_name["engine.fetch"]["args"]["parent_id"] \
+        == by_name["engine.pass"]["args"]["span_id"]
+    # wall-clock microseconds, as every other source of the timeline
+    assert abs(by_name["engine.pass"]["ts"] / 1e6 - time.time()) < 60
+
+
+# -- spans where the work happens: engine, serve front, trainer -------------
+# tiny CPU models, one engine for the module, no cluster, no subprocess
+
+PASS_CHILDREN = ("engine.schedule", "engine.prefill_chunk", "engine.decode")
+LEAVES = ("engine.upload", "engine.dispatch", "engine.fetch",
+          "engine.sample")
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import gpt
+    cfg = gpt.GPTConfig.tiny(dtype=jnp.float32, max_seq=64)
+    return cfg, gpt.init_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def engine(tiny):
+    from ray_tpu.inference import EngineConfig, InferenceEngine
+    cfg, params = tiny
+    # 6 blocks of 8 under 6 concurrent requests: some are preempted
+    eng = InferenceEngine(params, cfg, EngineConfig(
+        max_slots=4, max_seq=32, kv_block_size=8, n_blocks=6,
+        prefill_chunk=16))
+    yield eng
+    eng.shutdown()
+
+
+def _burst(eng, cfg, n=6, max_new=12, seed=1):
+    rng = np.random.default_rng(seed)
+    reqs = [eng.submit(rng.integers(0, cfg.vocab_size,
+                                    int(rng.integers(6, 20))).tolist(),
+                       max_new=max_new) for _ in range(n)]
+    for r in reqs:
+        r.result(timeout=300)
+    return reqs
+
+
+def _children(spans, parent):
+    return sorted((s for s in spans if s["parent_id"] == parent["span_id"]),
+                  key=lambda s: s["t0_ns"])
+
+
+def test_request_spans_partition_submit_to_finish(engine, tiny):
+    """Always on: queue + prefill + decode = finish - submit for every
+    request of a burst, a preempted one included; with tracing off the
+    ring holds nothing else."""
+    tracing.disable_tracing()
+    tracing.clear()
+    before = engine.stats()
+    reqs = _burst(engine, tiny[0])
+    after = engine.stats()
+    spans = tracing.get_finished_spans()
+    assert {s["name"] for s in spans} \
+        == {"request.queue", "request.prefill", "request.decode"}
+    by_req = {}
+    for s in spans:
+        by_req.setdefault(s["attributes"]["req"], {})[s["name"]] = s
+    assert len(by_req) == len(reqs)
+    for r in reqs:
+        q, p, d = (by_req[r.id]["request." + k]
+                   for k in ("queue", "prefill", "decode"))
+        assert q["trace_id"] == p["trace_id"] == d["trace_id"]
+        assert q["t1_ns"] == p["t0_ns"] and p["t1_ns"] == d["t0_ns"]
+        assert sum(s["t1_ns"] - s["t0_ns"] for s in (q, p, d)) \
+            == int(r.finished_s * 1e9) - int(r.created_s * 1e9)
+        assert q["t0_ns"] <= q["t1_ns"] <= p["t1_ns"] <= d["t1_ns"]
+        assert d["attributes"]["output_tokens"] == 12
+        assert q["attributes"]["preemptions"] == r.preemptions
+        assert q["attributes"]["prompt_tokens"] == r.prompt_tokens
+    assert sum(r.preemptions for r in reqs) \
+        == after["preemptions"] - before["preemptions"] > 0
+    # the counters at the same boundaries
+    assert after["admissions"] - before["admissions"] \
+        == len(reqs) + sum(r.preemptions for r in reqs)
+    assert after["chunk_passes"] - before["chunk_passes"] \
+        == sum(p["request.prefill"]["attributes"]["chunk_passes"]
+               for p in by_req.values()) > 0
+    assert after["prefill_tokens"] > before["prefill_tokens"]
+    # ... which an operator reads on /metrics
+    from ray_tpu import inference
+    snap = {n: series for n, _kind, _help, series
+            in inference.metrics_snapshot()}
+    key = (("engine", engine.name),)
+    for counter in ("admissions", "chunk_passes", "prefill_tokens"):
+        assert snap[f"ray_tpu_inference_{counter}_total"][key] \
+            == after[counter]
+    # submitted outside any span: roots of one trace
+    assert all(s["parent_id"] is None for s in spans)
+
+
+def test_engine_pass_spans_nest_and_do_not_overlap(engine, tiny, traced):
+    _burst(engine, tiny[0], n=3, max_new=6, seed=2)
+    tracing.disable_tracing()          # passes after this record nothing
+    spans = tracing.get_finished_spans()
+    passes = [s for s in spans if s["name"] == "engine.pass"]
+    assert passes and all(s["parent_id"] is None for s in passes)
+    seen = set()
+    for p in passes:
+        kids = _children(spans, p)
+        assert {k["name"] for k in kids} <= set(PASS_CHILDREN)
+        for k in kids:
+            leaves = _children(spans, k)
+            assert {s["name"] for s in leaves} <= set(LEAVES)
+            seen.update(s["name"] for s in leaves)
+            for inner, outer in ((leaves, k), (kids, p)):
+                assert all(outer["t0_ns"] <= s["t0_ns"] and
+                           s["t1_ns"] <= outer["t1_ns"] for s in inner)
+                assert all(a["t1_ns"] <= b["t0_ns"]
+                           for a, b in zip(inner, inner[1:]))
+        seen.update(k["name"] for k in kids)
+    assert seen == set(PASS_CHILDREN) | set(LEAVES)
+    decode = next(s for s in spans if s["name"] == "engine.decode")
+    assert decode["attributes"]["speculative"] is False
+    assert decode["attributes"]["active"] >= 1
+    fetch = next(s for s in _children(spans, decode)
+                 if s["name"] == "engine.fetch")
+    assert fetch["attributes"]["bytes"] == 4 * 4 * tiny[0].vocab_size
+
+
+def test_front_request_is_the_root_of_a_request_trace(tiny):
+    """proxy loop -> executor -> handle pool -> engine loop thread: one
+    trace id from front.request down to request.decode."""
+    import json
+    import socket
+    from ray_tpu import serve
+    from ray_tpu.inference import (EngineConfig, build_gpt_deployment,
+                                   parse_stream_chunks)
+    cfg, params = tiny
+    tracing.disable_tracing()
+    tracing.clear()
+    serve.run(build_gpt_deployment(
+        cfg=cfg, engine_cfg=EngineConfig(max_slots=2), params=params),
+        use_actors=False, http=True)
+    try:
+        host, port = serve.proxy_address()[len("http://"):].split(":")
+        body = json.dumps({"prompt": [9, 2, 6], "max_tokens": 5,
+                           "stream": True}).encode()
+        with socket.create_connection((host, int(port)), timeout=120) as s:
+            s.sendall(b"POST /v1/generate HTTP/1.1\r\nHost: x\r\n"
+                      + f"Content-Length: {len(body)}\r\n\r\n".encode()
+                      + body)
+            s.settimeout(120)
+            buf = b""
+            while b"0\r\n\r\n" not in buf:
+                data = s.recv(4096)
+                assert data, "connection closed before the last chunk"
+                buf += data
+        chunks = parse_stream_chunks(buf.split(b"\r\n\r\n", 1)[1])
+        assert chunks[-1]["done"] is True
+        # the span closes after the last chunk is written
+        deadline = time.time() + 10
+        while time.time() < deadline \
+                and not tracing.get_finished_spans("front.request"):
+            time.sleep(0.01)
+    finally:
+        serve.shutdown()
+    (front,) = tracing.get_finished_spans("front.request")
+    spans = tracing.get_finished_spans()
+    mine = [s for s in spans if s["name"].startswith("request.")]
+    assert [s["name"] for s in mine] \
+        == ["request.queue", "request.prefill", "request.decode"]
+    for s in mine:
+        assert s["trace_id"] == front["trace_id"]
+        assert s["parent_id"] == front["span_id"]
+        assert front["t0_ns"] <= s["t0_ns"] and s["t1_ns"] <= front["t1_ns"]
+    assert front["attributes"]["route"] == "v1"
+    first_token = mine[1]["t1_ns"]
+    assert first_token <= front["attributes"]["first_chunk_ns"] \
+        <= front["t1_ns"]
+
+
+def _fit(tmp_path, steps, report_every):
+    import optax
+    from ray_tpu.models import mlp
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+    cfg = mlp.MLPConfig(in_dim=8, hidden=(16,), out_dim=4)
+    rng = np.random.default_rng(0)
+    batch = {"x": rng.normal(size=(8, 8)).astype(np.float32),
+             "y": rng.integers(0, 4, 8).astype(np.int32)}
+
+    def batches():
+        while True:
+            yield batch
+    return JaxTrainer(
+        loss_fn=lambda p, b: mlp.loss_fn(p, b, cfg),
+        init_params=lambda r: mlp.init_params(cfg, r),
+        optimizer=optax.sgd(1e-2), train_data=batches(), num_steps=steps,
+        report_every=report_every,
+        scaling_config=ScalingConfig(mesh={"dp": 1}, use_cpu_devices=True),
+        run_config=RunConfig(name="spans", storage_path=str(tmp_path))
+    ).fit()
+
+
+def test_trainer_spans_one_step_span_a_step(tmp_path, traced):
+    _fit(tmp_path, steps=6, report_every=2)
+    spans = tracing.get_finished_spans()
+
+    def steps_of(name):
+        return [s["attributes"]["step"] for s in spans if s["name"] == name]
+    for name in ("train.next_batch", "train.shard_batch", "train.step"):
+        assert steps_of(name) == list(range(6))
+    assert steps_of("train.report") == steps_of("train.fetch") == [1, 3, 5]
+    assert steps_of("train.checkpoint") == [5]      # the last step's
+    (ckpt,) = tracing.get_finished_spans("train.checkpoint")
+    assert ckpt["attributes"]["bytes"] > 0
+    by_id = {s["span_id"]: s for s in spans}
+    for s in spans:
+        if s["name"] in ("train.fetch", "train.checkpoint"):
+            assert by_id[s["parent_id"]]["name"] == "train.report"
+        elif s["name"].startswith("train."):
+            assert s["parent_id"] is None
+
+
+def test_profiler_session_switches_spans_on_and_carries_them(
+        engine, tiny, tmp_path):
+    """No flag: a jax.profiler session alone records pass-level spans,
+    and the same spans lie on the xplane's host plane."""
+    import jax
+    from jax.profiler import ProfileData, ProfileOptions
+    tracing.disable_tracing()
+    tracing.clear()
+    opts = ProfileOptions()
+    opts.python_tracer_level = 0       # the spans, not every Python call
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=opts)
+    try:
+        assert tracing.active()
+        _burst(engine, tiny[0], n=2, max_new=4, seed=3)
+        _fit(tmp_path, steps=2, report_every=1)
+    finally:
+        jax.profiler.stop_trace()
+    assert not tracing.active()
+    ring = {s["name"] for s in tracing.get_finished_spans()}
+    (path,) = (tmp_path / "prof").glob("plugins/profile/*/*.xplane.pb")
+    host = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(("engine.", "train.")):
+                        host.setdefault(ev.name, dict(ev.stats))
+    wanted = {"engine.pass", "engine.decode", "engine.fetch", "train.step",
+              "train.next_batch"}
+    assert wanted <= set(host) and wanted <= ring
+    assert set(host) == {n for n in ring
+                         if n.startswith(("engine.", "train."))}
+    assert host["train.step"]["step_num"] in (0, 1)
+    assert host["engine.fetch"]["bytes"] > 0
