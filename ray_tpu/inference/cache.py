@@ -6,9 +6,9 @@ by a PREALLOCATED pool handed out in fixed-size units and reclaimed on
 sequence exit — never grown per request.  Two unit granularities live
 here:
 
-  * ``BlockPool`` — the paged pool: fixed-size TOKEN BLOCKS
-    (``[n_layers, n_blocks, n_heads, block_size, head_dim]`` ×2), a
-    per-request BLOCK TABLE mapping sequence positions to blocks, and
+  * ``BlockPool`` — the paged pool: fixed-size TOKEN BLOCKS (two
+    arrays stored as ``PoolLayout`` defines, once, for every program
+    that reads or writes them), a per-request BLOCK TABLE mapping sequence positions to blocks, and
     per-block REFCOUNTS so blocks are shared across requests (prefix
     reuse) with copy-on-write on a shared partially-filled tail.  The
     decode step stays compiled-once because the table width and batch
@@ -37,6 +37,7 @@ the host.
 from __future__ import annotations
 
 import threading
+from dataclasses import dataclass
 from functools import partial
 from typing import Optional
 
@@ -45,7 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.gpt import GPTConfig
-from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES, Rules, spec_for
+from ray_tpu.parallel.sharding import (DEFAULT_LLM_RULES, Rules,
+                                       sharding_for, spec_for)
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -166,52 +168,194 @@ class KVCacheManager:
 # paged pool
 
 
-@partial(jax.jit, donate_argnums=(0,))
-def _copy_block(pool: jax.Array, src: jax.Array, dst: jax.Array):
-    """pool [L, N, h, bs, hd] <- pool[:, src] at dst (copy-on-write)."""
-    return pool.at[:, dst].set(pool[:, src])
+# logical axes of a paged pool array [L*(N+1), bs, W]: the WIDTH dim (a
+# token's heads side by side) is the sharded one (Megatron-style tensor
+# parallelism — every device holds ALL blocks with h/tp of each token's
+# heads, so the host-side table/refcount/CoW logic is shard-oblivious).
+# Layers fold into the leading dim, which is never sharded: the pool
+# must not split over pp (every layer reads its own rows of it).
+POOL_AXES = (None, None, "heads")
+
+_LANES = 128        # the TPU tiles an array's minor dim in 128 lanes
 
 
-@partial(jax.jit, donate_argnums=(0,))
-def _write_blocks(pool: jax.Array, table: jax.Array, new: jax.Array):
-    """pool [L, N, h, bs, hd] <- new [L, h, T*bs, hd] scattered through
-    table [T] (position p lands at (table[p//bs], p%bs)).  Duplicate
-    scratch entries collide harmlessly — their content is masked."""
-    L, _, h, bs, hd = pool.shape
-    T = table.shape[0]
-    n = new.reshape(L, h, T, bs, hd).transpose(0, 2, 1, 3, 4)
-    return pool.at[:, table].set(n.astype(pool.dtype))
+def heads_shards(mesh, rules: Rules = DEFAULT_LLM_RULES) -> int:
+    """Number of shards the pool's width dim is split into (1 when
+    unmeshed) — the ``tp`` degree of the serving hot path."""
+    if mesh is None:
+        return 1
+    spec = spec_for(POOL_AXES, rules, mesh)[-1]
+    if spec is None:
+        return 1
+    return int(np.prod([mesh.shape[a] for a in
+                        ((spec,) if isinstance(spec, str) else spec)]))
 
 
-@jax.jit
-def _gather_blocks(pool_k: jax.Array, pool_v: jax.Array,
-                   table: jax.Array):
+@dataclass(frozen=True)
+class PoolLayout:
+    """THE stored layout of a paged K/V pool, and the two operations
+    every program performs on it.
+
+    A pool is ONE array ``[n_layers * n_rows, block_size, width]``:
+    layer ``l``'s block ``i`` is row ``l * n_rows + i`` (``n_rows`` =
+    usable blocks + the scratch block, id 0), and a token's heads lie
+    side by side in the minor dim.  ``width`` is ``n_heads * head_dim``
+    rounded up to a multiple of 128 lanes PER SHARD (1600 -> 1664 for
+    GPT-2 XL, 768 unpadded for 124M), so the TPU tiles the trailing
+    ``(block_size, width)`` without padding and keeps the buffer in the
+    plain row-major layout every program computes in: a program reads
+    (``read``) and writes (``commit``) the rows its tables name and
+    never re-tiles or copies the pool.  With a mesh the width is split
+    over the heads axis (``POOL_AXES``): each shard holds whole heads,
+    then its own padding lanes.  Padding lanes are written as zeros
+    and never read.
+    """
+    n_layers: int
+    n_rows: int             # blocks per layer, scratch block included
+    block_size: int
+    n_heads: int
+    head_dim: int
+    shards: int = 1         # heads_shards(mesh, rules)
+
+    @classmethod
+    def of(cls, cfg: GPTConfig, pool: jax.Array,
+           shards: int = 1) -> "PoolLayout":
+        """The layout of ``pool`` as a compiled program sees it."""
+        lay = cls(cfg.n_layers, pool.shape[0] // cfg.n_layers,
+                  pool.shape[1], cfg.n_heads, cfg.head_dim, shards)
+        if lay.shape != pool.shape:
+            raise ValueError(f"pool {pool.shape} is not a {lay.shape} "
+                             f"pool of {cfg.n_layers} layers x "
+                             f"{cfg.n_heads} heads of {cfg.head_dim}")
+        return lay
+
+    @property
+    def lanes(self) -> int:
+        """Lanes of one shard's heads, before padding."""
+        return self.n_heads // self.shards * self.head_dim
+
+    @property
+    def width(self) -> int:
+        return self.shards * -(-self.lanes // _LANES) * _LANES
+
+    @property
+    def shape(self) -> tuple:
+        return (self.n_layers * self.n_rows, self.block_size, self.width)
+
+    def rows(self, layer, blocks):
+        """Leading-dim index of layer ``layer``'s blocks ``blocks``."""
+        return layer * self.n_rows + blocks
+
+    def pack(self, x: jax.Array) -> jax.Array:
+        """[..., n_heads, head_dim] -> [..., width]."""
+        lead, pad = x.shape[:-2], self.width // self.shards - self.lanes
+        x = x.reshape(*lead, self.shards, self.lanes)
+        if pad:
+            x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+        return x.reshape(*lead, self.width)
+
+    def unpack(self, x: jax.Array) -> jax.Array:
+        """[..., width] -> [..., n_heads, head_dim]."""
+        lead = x.shape[:-1]
+        x = x.reshape(*lead, self.shards, -1)
+        return x[..., :self.lanes].reshape(*lead, self.n_heads,
+                                           self.head_dim)
+
+    def read(self, pool: jax.Array, layer, tables: jax.Array) -> jax.Array:
+        """Gather layer ``layer``'s blocks ``tables`` [..., T] as keys in
+        position order, as stored: [..., T * block_size, width]
+        (ops/attention.packed_attention attends them so; ``unpack``
+        splits the heads out)."""
+        g = pool[self.rows(layer, tables)]            # [..., T, bs, W]
+        return g.reshape(*tables.shape[:-1], -1, self.width)
+
+    def commit(self, pool: jax.Array, layer, blocks: jax.Array,
+               offsets: Optional[jax.Array], new: jax.Array) -> jax.Array:
+        """Write tokens' K/V ``new`` [..., n_heads, head_dim] at
+        ``(blocks, offsets)`` [...] of layer ``layer`` — or, with
+        ``offsets`` None, whole blocks ``new`` [..., block_size,
+        n_heads, head_dim] at ``blocks`` [...].  Colliding writes (the
+        scratch block) land in any order."""
+        rows = self.rows(layer, blocks)
+        new = self.pack(new.astype(pool.dtype))
+        if offsets is None:
+            return pool.at[rows].set(new)
+        return pool.at[rows, offsets].set(new)
+
+    # all layers of a block chain at once — cache.py's own programs
+    def read_chain(self, pool: jax.Array, table: jax.Array) -> jax.Array:
+        """Blocks ``table`` [T] of every layer in the interchange format
+        [L, T, n_heads, block_size, head_dim]."""
+        L, T = self.n_layers, table.shape[0]
+        g = self.unpack(self.read(pool, jnp.arange(L)[:, None],
+                                  table[None, :]))
+        return g.reshape(L, T, self.block_size, self.n_heads,
+                         self.head_dim).transpose(0, 1, 3, 2, 4)
+
+    def commit_chain(self, pool: jax.Array, table: jax.Array,
+                     new: jax.Array) -> jax.Array:
+        """Inverse of ``read_chain``: ``new`` [L, T, n_heads,
+        block_size, head_dim] lands at blocks ``table`` [T]."""
+        return self.commit(pool, jnp.arange(self.n_layers)[:, None],
+                           table[None, :], None,
+                           new.transpose(0, 1, 3, 2, 4))
+
+
+# the pool programs below are jitted per layout (static argument 0) and
+# donate the pools: a block chain moves, never the pool
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
+def _copy_block(lay: PoolLayout, pool_k, pool_v, src, dst):
+    """Both pools <- block src at dst, every layer (copy-on-write)."""
+    layers = jnp.arange(lay.n_layers)
+    s, d = lay.rows(layers, src), lay.rows(layers, dst)
+    return pool_k.at[d].set(pool_k[s]), pool_v.at[d].set(pool_v[s])
+
+
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
+def _write_blocks(lay: PoolLayout, pool_k, pool_v, table, new_k, new_v):
+    """Both pools <- a full prefill's K/V [L, h, T*bs, hd] scattered
+    through table [T] (position p lands at (table[p//bs], p%bs)).
+    Duplicate scratch entries collide harmlessly — their content is
+    masked."""
+    def chain(new):                       # -> [L, T, h, bs, hd]
+        L, h, _, hd = new.shape
+        return new.reshape(L, h, table.shape[0], lay.block_size,
+                           hd).transpose(0, 2, 1, 3, 4)
+    return (lay.commit_chain(pool_k, table, chain(new_k)),
+            lay.commit_chain(pool_v, table, chain(new_v)))
+
+
+@partial(jax.jit, static_argnums=(0,))
+def _gather_blocks(lay: PoolLayout, pool_k, pool_v, table):
     """Both pools' block chains in ONE fused call — the eager two-step
     (k then v, each its own dispatch + device_get) dominated prefix
     extraction latency, not the bytes."""
-    return pool_k[:, table], pool_v[:, table]
+    return lay.read_chain(pool_k, table), lay.read_chain(pool_v, table)
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def _install_blocks(pool_k: jax.Array, pool_v: jax.Array,
-                    table: jax.Array, new_k: jax.Array,
-                    new_v: jax.Array):
-    """pools [L, N, h, bs, hd] <- new [L, T, h, bs, hd] at table [T]:
-    the adopted-prefix scatter, taking the transfer payload's layout
-    directly (no eager transpose/reshape copies) and landing both
-    pools in ONE dispatch.  The caller owns ``table``'s ids
-    exclusively (refcount 1, freshly alloc'd), so no CoW is needed."""
-    return (pool_k.at[:, table].set(new_k.astype(pool_k.dtype)),
-            pool_v.at[:, table].set(new_v.astype(pool_v.dtype)))
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
+def _install_blocks(lay: PoolLayout, pool_k, pool_v, table, new_k, new_v):
+    """Both pools <- new [L, T, h, bs, hd] at table [T]: the
+    adopted-prefix scatter, landing both pools in ONE dispatch.  The
+    caller owns ``table``'s ids exclusively (refcount 1, freshly
+    alloc'd), so no CoW is needed."""
+    return (lay.commit_chain(pool_k, table, new_k),
+            lay.commit_chain(pool_v, table, new_v))
 
 
 class BlockPool:
     """Refcounted fixed-size token-block pool (the paged KV cache).
 
-    Arrays are ``[n_layers, n_blocks + 1, n_heads, block_size,
-    head_dim]`` ×2 — index 0 is the reserved scratch block (never
+    The K and V arrays are stored as ``PoolLayout`` says
+    (``self.layout``; ``[n_layers * (n_blocks + 1), block_size,
+    width]`` each) — block id 0 is the reserved scratch block (never
     allocated; inactive/out-of-range writes in the compiled step are
     redirected there), usable blocks are ids ``1..n_blocks``.
+    ``read_blocks`` / ``write_blocks_at`` speak the interchange format
+    ``[L, T, n_heads, block_size, head_dim]`` and convert at the
+    boundary.
 
     Reference rules: ``alloc()`` returns a block with refcount 1;
     every additional holder (a sharing request, the prefix trie)
@@ -224,10 +368,10 @@ class BlockPool:
     swaps happen on the engine loop thread; ``stats()`` may be read
     from any thread (the lock only guards the free list + refcounts).
 
-    With a ``mesh``, the pool arrays are sharded over the HEADS dim
-    (decode.POOL_AXES — Megatron-style tensor parallelism): every
-    device holds all ``n_blocks + 1`` blocks with ``n_heads / tp`` of
-    each block's heads, so block ids, tables, refcounts, the radix trie
+    With a ``mesh``, the pool arrays are sharded over the heads in
+    their width dim (POOL_AXES — Megatron-style tensor parallelism):
+    every device holds all ``n_blocks + 1`` blocks with ``n_heads / tp``
+    of each token's heads, so block ids, tables, refcounts, the radix trie
     and copy-on-write are shard-oblivious and ``n_blocks`` is both the
     global admission budget AND the per-device block count (per-device
     bytes are ``bytes_total() / tp``).
@@ -255,8 +399,6 @@ class BlockPool:
                 f"sequence ({self.blocks_per_seq} blocks of {block_size})")
         self.n_blocks = int(n_blocks)             # usable (excludes scratch)
         self.dtype = dtype or cfg.dtype
-        self._shape = (cfg.n_layers, self.n_blocks + 1, cfg.n_heads,
-                       self.block_size, cfg.head_dim)
         shards = self.heads_shards
         if cfg.n_heads % shards:
             raise ValueError(
@@ -264,6 +406,9 @@ class BlockPool:
                 f"(tp) shard count {shards} of mesh "
                 f"{dict(zip(mesh.axis_names, mesh.devices.shape))} — "
                 f"the pool shards the heads dim evenly per device")
+        self.layout = PoolLayout(cfg.n_layers, self.n_blocks + 1,
+                                 self.block_size, cfg.n_heads,
+                                 cfg.head_dim, shards)
         self.k = self._zeros()
         self.v = self._zeros()
         self._lock = threading.Lock()
@@ -277,23 +422,9 @@ class BlockPool:
 
     @property
     def heads_shards(self) -> int:
-        """Number of shards the pool's heads dim is split into (1 when
+        """Number of shards the pool's heads are split into (1 when
         unmeshed) — the ``tp`` degree of the serving hot path."""
-        if self.mesh is None:
-            return 1
-        spec = self._pool_spec()[2]
-        if spec is None:
-            return 1
-        axes = (spec,) if isinstance(spec, str) else spec
-        n = 1
-        for a in axes:
-            n *= dict(zip(self.mesh.axis_names,
-                          self.mesh.devices.shape))[a]
-        return n
-
-    def _pool_spec(self):
-        from ray_tpu.inference.decode import POOL_AXES
-        return spec_for(POOL_AXES, self.rules, self.mesh)
+        return heads_shards(self.mesh, self.rules)
 
     def _zeros(self) -> jax.Array:
         """Allocate one zeroed pool array — heads-sharded across the
@@ -302,10 +433,9 @@ class BlockPool:
         by __init__ AND reset() so donated-pool recovery reallocates
         every device's shard, not just the addressable default."""
         if self.mesh is None:
-            return jnp.zeros(self._shape, self.dtype)
-        from jax.sharding import NamedSharding
-        sh = NamedSharding(self.mesh, self._pool_spec())
-        return jax.jit(partial(jnp.zeros, self._shape, self.dtype),
+            return jnp.zeros(self.layout.shape, self.dtype)
+        sh = sharding_for(POOL_AXES, self.rules, self.mesh)
+        return jax.jit(partial(jnp.zeros, self.layout.shape, self.dtype),
                        out_shardings=sh)()
 
     # ------------------------------------------------------------- blocks
@@ -374,9 +504,8 @@ class BlockPool:
 
     def copy_block(self, src: int, dst: int) -> None:
         """Copy-on-write: duplicate src's K/V into dst (both pools)."""
-        s, d = jnp.int32(src), jnp.int32(dst)
-        self.k = _copy_block(self.k, s, d)
-        self.v = _copy_block(self.v, s, d)
+        self.k, self.v = _copy_block(self.layout, self.k, self.v,
+                                     jnp.int32(src), jnp.int32(dst))
 
     def read_blocks(self, ids) -> tuple:
         """Gather a block chain's K/V to host arrays — the EXPORT side
@@ -385,7 +514,8 @@ class BlockPool:
         host-side so the bytes can ride the object plane regardless of
         the holder's mesh layout."""
         t = jnp.asarray(list(ids), jnp.int32)
-        k, v = jax.device_get(_gather_blocks(self.k, self.v, t))
+        k, v = jax.device_get(_gather_blocks(self.layout, self.k,
+                                             self.v, t))
         return np.asarray(k), np.asarray(v)
 
     def write_blocks_at(self, ids, k_new, v_new) -> None:
@@ -396,11 +526,12 @@ class BlockPool:
         with a mesh the ``.at[].set`` lands sharded through the pool's
         own sharding."""
         t = jnp.asarray(list(ids), jnp.int32)
-        L, T = self.k.shape[0], t.shape[0]
-        h, bs, hd = self.k.shape[2], self.k.shape[3], self.k.shape[4]
-        k_new = jnp.asarray(k_new, self.dtype).reshape(L, T, h, bs, hd)
-        v_new = jnp.asarray(v_new, self.dtype).reshape(L, T, h, bs, hd)
-        self.k, self.v = _install_blocks(self.k, self.v, t,
+        lay = self.layout
+        chain = (lay.n_layers, t.shape[0], lay.n_heads, lay.block_size,
+                 lay.head_dim)
+        k_new = jnp.asarray(k_new, self.dtype).reshape(chain)
+        v_new = jnp.asarray(v_new, self.dtype).reshape(chain)
+        self.k, self.v = _install_blocks(lay, self.k, self.v, t,
                                          k_new, v_new)
 
     def write_prefill(self, table, k_new: jax.Array,
@@ -417,9 +548,9 @@ class BlockPool:
             pad = [(0, 0), (0, 0), (0, span - s), (0, 0)]
             k_new = jnp.pad(k_new, pad)
             v_new = jnp.pad(v_new, pad)
-        t = jnp.asarray(table, jnp.int32)
-        self.k = _write_blocks(self.k, t, k_new)
-        self.v = _write_blocks(self.v, t, v_new)
+        self.k, self.v = _write_blocks(self.layout, self.k, self.v,
+                                       jnp.asarray(table, jnp.int32),
+                                       k_new, v_new)
 
     def swap(self, k: jax.Array, v: jax.Array) -> None:
         """Install the compiled step's updated pool arrays."""
@@ -445,8 +576,9 @@ class BlockPool:
     # ------------------------------------------------------------- stats
 
     def bytes_total(self) -> int:
+        """Bytes of both pools as stored, padding lanes included."""
         itemsize = np.dtype(jnp.zeros((), self.dtype).dtype).itemsize
-        return 2 * int(np.prod(self.k.shape)) * itemsize
+        return 2 * int(np.prod(self.layout.shape)) * itemsize
 
     def stats(self) -> dict:
         with self._lock:

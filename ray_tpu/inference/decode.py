@@ -18,20 +18,20 @@ Paged path (cache.BlockPool):
     Long prompts therefore prefill as a sequence of bounded-cost steps
     the engine interleaves with decode iterations — a long prompt
     stops stalling neighbors' token cadence.
-  * paged_decode_step — one token for EVERY row at once; the cache
-    write is a per-row (block, offset) scatter into the pool (inactive
-    rows redirected to the scratch block), attention gathers each
-    row's block table and masks to its valid prefix
-    (ops/attention.paged_attention).
+  * paged_decode_step — one token for EVERY row at once; each layer
+    writes the rows' K/V at their (block, offset) in the pool (inactive
+    rows redirected to the scratch block), then gathers each row's
+    block table and masks to its valid prefix (the formulation of
+    ops/attention.paged_attention).
   * spec_verify_step — the decode step widened to a [b, W] token
     window (W = speculate_k + 1): column 0 is each row's current input
     token, columns 1.. are DRAFTED continuations.  One call scores all
     W positions per row (each query masked to its own causal horizon,
     exactly the chunk-prefill formulation batched over rows) and lands
-    every position's K/V in ONE donated scatter — draft-then-verify
+    every position's K/V in the donated pool — draft-then-verify
     speculation's verify pass (Leviathan et al. 2023).  Lanes past a
-    row's real draft count are redirected to the scratch block / dummy
-    context column so a short draft can ride a fixed-width program.
+    row's real draft count are redirected to the scratch block so a
+    short draft can ride a fixed-width program.
   * paged_draft_step — the truncated-layer self-draft BURST: k
     autoregressive draft tokens in one compiled call (a lax.scan over
     draft positions, each scanning only the FIRST ``draft_layers``
@@ -61,7 +61,9 @@ only — the slot path stays the frozen dense baseline).  With a mesh the
 paged bodies are sharding-annotated for Megatron-style tensor
 parallelism: pools heads-sharded per POOL_AXES, per-device attention
 over local heads, one collective at the output projection, the donated
-one-scatter commit preserved per shard.  Greedy token-parity with
+pool committed per shard.  The pools' stored layout is defined once, in
+cache.PoolLayout; the programs here touch them through its ``read`` and
+``commit`` only.  Greedy token-parity with
 full-recompute ``generate()`` is pinned by tests/test_inference.py +
 tests/test_paged_cache.py (mesh=None) and tests/test_sharded_decode.py
 (multi-device CPU meshes).
@@ -77,9 +79,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ray_tpu.inference.cache import POOL_AXES, PoolLayout, heads_shards
 from ray_tpu.models import gpt
 from ray_tpu.models.gpt import GPTConfig
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import attention, packed_attention
 from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES, Rules
 
 
@@ -134,15 +137,6 @@ def _cached(kind: str, cfg: GPTConfig, mesh, rules, build):
     if fn is None:
         fn = _FN_CACHE[key] = build()
     return fn
-
-
-# logical axes of the paged pool arrays [L, N+1, heads, bs, hd]: the
-# HEADS dim is the sharded one (Megatron-style tensor parallelism —
-# every device holds ALL blocks with h/tp of each block's heads, so the
-# host-side table/refcount/CoW logic is shard-oblivious).  The layers
-# dim is deliberately NOT "layers": the pool must never shard over pp
-# (the scan body dynamic-slices it per layer).
-POOL_AXES = (None, None, "heads", None, "kv")
 
 
 def _mlp_block(y, lp, cfg, mesh, rules):
@@ -260,6 +254,36 @@ def _make_step(cfg, mesh, rules, h, hd):
 
 # ---------------------------------------------------------------------------
 # paged path
+#
+# The K and V pools are stored as cache.PoolLayout says ([L*(N+1), bs,
+# W], see there) and every program below touches them through its two
+# operations only.  The pools ride the layer scan as its CARRY: each
+# layer commits its window's new K/V into the carried pool and THEN
+# reads its rows' tables from it, so the attended context holds the new
+# tokens at their own positions with no insertion step.  What the chip
+# does with that: a carried, donated buffer is updated in place — the
+# compiled programs hold no copy of a pool nor of a layer's share of
+# one, and the pools enter and leave in the layout the scan computes in
+# (tests/test_chip_compile.py pins all three on a described v5e).  The
+# formulations this replaced each cost a pass ~75 ms at GPT-2 XL on the
+# chip: pools closed over by the scan body with one scatter after it
+# (two whole-pool re-tilings in, two out, and a copy of the layer's
+# slice per layer), and pools as scan xs/ys (a copy of the whole pool).
+
+
+def _write_then_read(lay: PoolLayout, pools, li, blocks, offsets, new,
+                     tables, mesh, rules):
+    """One layer's traffic with the (K, V) ``pools``: commit the
+    window's ``new`` (K, V) [..., h, hd] at ``(blocks, offsets)``, then
+    gather the rows' ``tables`` [b, T] as attention contexts
+    [b, T*bs, W] — keys in position order, the window's own among them,
+    heads still packed as stored (packed_attention takes them so).
+    Returns (pools, contexts)."""
+    pools = tuple(lay.commit(p, li, blocks, offsets, x)
+                  for p, x in zip(pools, new))
+    return pools, tuple(
+        gpt._constrain(lay.read(p, li, tables), ("batch", None, "heads"),
+                       mesh, rules) for p in pools)
 
 
 def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
@@ -267,35 +291,36 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
                            rules: Rules = DEFAULT_LLM_RULES):
     """jitted one-token step over the whole row batch, block-pool cache.
 
-    (params, k_pool, v_pool [L, N, h, bs, hd], tables [b, T] int32,
+    (params, k_pool, v_pool [cache.PoolLayout], tables [b, T] int32,
      tokens [b] int32, positions [b] int32, active [b] bool)
         -> (logits [b, vocab] f32, k_pool, v_pool)
 
-    Each row's current token K/V scatters into the pool at
+    Each layer commits every row's current token K/V to the pool at
     ``(tables[row, pos // bs], pos % bs)`` — inactive rows are
     redirected to the scratch block (id 0) so the scatter needs no
-    conditional — and attention gathers the row's table, masked to its
-    valid prefix (ops/attention.paged_attention).  Tail blocks are
-    per-row exclusive (the engine copy-on-writes shared tails before
-    the step), so active rows never collide in the scatter.
+    conditional — then gathers the row's table and attends, masked to
+    the row's valid prefix (the formulation of
+    ops/attention.paged_attention).  Tail blocks are per-row exclusive
+    (the engine copy-on-writes shared tails before the step), so active
+    rows never collide in the scatter.
 
     With a mesh, the pools are heads-sharded (POOL_AXES) and the body
     carries sharding constraints mirroring gpt._transformer_layer:
     qkv projection, gathered context, and attention run per-device
     over local heads with ONE collective at the output/head projection
-    (Megatron TP); the donated one-scatter commit stays per-shard
-    (the scatter's advanced axes — block, offset — are unsharded).
+    (Megatron TP); the donated commit stays per-shard (the scatter's
+    indexed dims — row, offset — are unsharded).
     MoE configs dispatch through gpt._moe_mlp per decode window.
     """
     h, hd, bs = cfg.n_heads, cfg.head_dim, int(block_size)
+    shards = heads_shards(mesh, rules)
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
         def step(params, k_pool, v_pool, tables, tokens, positions,
                  active):
             b = tokens.shape[0]
-            L = k_pool.shape[0]
-            T = tables.shape[1]
+            lay = PoolLayout.of(cfg, k_pool, shards)
             k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
             v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
             x = (params["wte"][tokens] + params["wpe"][positions])
@@ -305,16 +330,9 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
             off = jnp.where(active, positions % bs, 0)
             kv_len = jnp.where(active, positions + 1, 1)      # >=1: no NaN
 
-            # the pools are CLOSED OVER by the scan body and read with a
-            # per-layer dynamic slice + table gather; the new K/V come
-            # back as stacked scan outputs and land in ONE donated
-            # scatter after the scan.  (Carrying the pools through the
-            # scan as xs/ys — the obvious formulation — copies the
-            # ENTIRE pool every call, a fixed ~2x-pool-bytes tax per
-            # decode step that dwarfs the actual compute.)
-            def layer(x, xs):
+            def layer(carry, xs):
+                x, pools = carry
                 lp, li = xs
-                ck, cv = k_pool[li], v_pool[li]    # [N, h, bs, hd]
                 y = gpt._layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
                 qkv = jnp.einsum("bsd,de->bse", y,
                                  lp["wqkv"].astype(cfg.dtype))
@@ -325,27 +343,12 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
                 def heads(t):                      # [b,1,d]->[b,h,1,hd]
                     return t.reshape(b, 1, h, hd).transpose(0, 2, 1, 3)
 
-                def gather(pool):                  # -> [b, h, S, hd]
-                    g = pool[tables]               # [b, T, h, bs, hd]
-                    return g.transpose(0, 2, 1, 3, 4).reshape(
-                        b, h, T * bs, hd)
-
-                kh = k.reshape(b, h, hd)
-                vh = v.reshape(b, h, hd)
-                # insert the current token's K/V at its own position in
-                # the gathered context — key ORDER stays position-major,
-                # so the masked softmax is numerically identical to the
-                # write-then-gather formulation (and to the slot step)
-                ctx_k = gather(ck).at[rows, :, positions, :].set(
-                    kh.astype(ck.dtype))
-                ctx_v = gather(cv).at[rows, :, positions, :].set(
-                    vh.astype(cv.dtype))
-                ctx_k = gpt._constrain(
-                    ctx_k, ("batch", "heads", None, "kv"), mesh, rules)
-                ctx_v = gpt._constrain(
-                    ctx_v, ("batch", "heads", None, "kv"), mesh, rules)
-                o = attention(heads(q), ctx_k, ctx_v, causal=False,
-                              kv_lengths=kv_len, impl="reference")
+                pools, (ctx_k, ctx_v) = _write_then_read(
+                    lay, pools, li, bidx, off,
+                    (k.reshape(b, h, hd), v.reshape(b, h, hd)),
+                    tables, mesh, rules)
+                o = packed_attention(heads(q), ctx_k, ctx_v,
+                                     groups=shards, kv_lengths=kv_len)
                 o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.d_model)
                 o = jnp.einsum("bsd,de->bse", o,
                                lp["wo"].astype(cfg.dtype)) \
@@ -355,17 +358,11 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
                                    mesh, rules)
                 y = gpt._layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
                 dn = _mlp_block(y, lp, cfg, mesh, rules)
-                return x + dn, (kh, vh)
+                return (x + dn, pools), None
 
-            x, (ks, vs) = lax.scan(
-                layer, x, (params["layers"], jnp.arange(L)))
-            # ks/vs [L, b, h, hd] -> one in-place scatter on the donated
-            # pools at each row's (block, offset); inactive rows hit the
-            # scratch block
-            k_pool = k_pool.at[:, bidx, :, off, :].set(
-                ks.transpose(1, 0, 2, 3).astype(k_pool.dtype))
-            v_pool = v_pool.at[:, bidx, :, off, :].set(
-                vs.transpose(1, 0, 2, 3).astype(v_pool.dtype))
+            (x, (k_pool, v_pool)), _ = lax.scan(
+                layer, (x, (k_pool, v_pool)),
+                (params["layers"], jnp.arange(cfg.n_layers)))
             k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
             v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
             logits = gpt._head(params, x, cfg, mesh, rules)[:, 0, :]
@@ -382,7 +379,7 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
                           rules: Rules = DEFAULT_LLM_RULES):
     """jitted fixed-width prefill chunk against the block pool.
 
-    (params, k_pool, v_pool [L, N, h, bs, hd], table [T] int32,
+    (params, k_pool, v_pool [cache.PoolLayout], table [T] int32,
      tokens [C] int32, start int32)
         -> (logits [C, vocab] f32, k_pool, v_pool)
 
@@ -405,11 +402,12 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
     h, hd = cfg.n_heads, cfg.head_dim
     bs, C, T = int(block_size), int(chunk), int(n_table)
     S = T * bs
+    shards = heads_shards(mesh, rules)
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
         def chunk_fn(params, k_pool, v_pool, table, tokens, start):
-            L = k_pool.shape[0]
+            lay = PoolLayout.of(cfg, k_pool, shards)
             k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
             v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
             pos = start + jnp.arange(C, dtype=jnp.int32)       # [C]
@@ -420,20 +418,14 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
             safe = jnp.where(oob, 0, pos)
             bidx = jnp.where(oob, 0, table[safe // bs])
             off = jnp.where(oob, 0, pos % bs)
-            # out-of-range rows write to a DUMMY context column (S) so
-            # they cannot corrupt position 0 of the in-flight context;
-            # each query row's mask is its own causal horizon, which
-            # also excludes the dummy column for every real row
-            wcol = jnp.where(oob, S, pos)
-            mask = (jnp.arange(S + 1)[None, :] <= pos[:, None])  # [C, S+1]
+            # each query row's mask is its own causal horizon; an
+            # out-of-range row's K/V went to the scratch block, which
+            # no table position of a real row names
+            mask = (jnp.arange(S)[None, :] <= pos[:, None])    # [C, S]
 
-            # pools are closed over, read per layer (slice + gather);
-            # the chunk's K/V return as scan outputs and land in one
-            # donated scatter — NOT carried through the scan, which
-            # would copy the whole pool per chunk (see the step above)
-            def layer(x, xs):
+            def layer(carry, xs):
+                x, pools = carry
                 lp, li = xs
-                ck, cv = k_pool[li], v_pool[li]    # [N, h, bs, hd]
                 y = gpt._layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
                 qkv = jnp.einsum("bsd,de->bse", y,
                                  lp["wqkv"].astype(cfg.dtype))
@@ -444,23 +436,12 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
                 def heads(t):                      # [1,C,d]->[1,h,C,hd]
                     return t.reshape(1, C, h, hd).transpose(0, 2, 1, 3)
 
-                def gather(pool):                  # -> [1, h, S+1, hd]
-                    g = pool[table]                # [T, h, bs, hd]
-                    g = g.transpose(1, 0, 2, 3).reshape(h, S, hd)
-                    return jnp.pad(g, [(0, 0), (0, 1), (0, 0)])[None]
-
-                kh = k.reshape(C, h, hd).transpose(1, 0, 2)   # [h, C, hd]
-                vh = v.reshape(C, h, hd).transpose(1, 0, 2)
-                ctx_k = gather(ck).at[:, :, wcol, :].set(
-                    kh.astype(ck.dtype))
-                ctx_v = gather(cv).at[:, :, wcol, :].set(
-                    vh.astype(cv.dtype))
-                ctx_k = gpt._constrain(
-                    ctx_k, ("batch", "heads", None, "kv"), mesh, rules)
-                ctx_v = gpt._constrain(
-                    ctx_v, ("batch", "heads", None, "kv"), mesh, rules)
-                o = attention(heads(q), ctx_k, ctx_v, causal=False,
-                              mask=mask[None, None], impl="reference")
+                pools, (ctx_k, ctx_v) = _write_then_read(
+                    lay, pools, li, bidx, off,
+                    (k.reshape(C, h, hd), v.reshape(C, h, hd)),
+                    table[None], mesh, rules)
+                o = packed_attention(heads(q), ctx_k, ctx_v,
+                                     groups=shards, mask=mask[None, None])
                 o = o.transpose(0, 2, 1, 3).reshape(1, C, cfg.d_model)
                 o = jnp.einsum("bsd,de->bse", o,
                                lp["wo"].astype(cfg.dtype)) \
@@ -470,16 +451,11 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
                                    mesh, rules)
                 y = gpt._layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
                 dn = _mlp_block(y, lp, cfg, mesh, rules)
-                return x + dn, (kh, vh)
+                return (x + dn, pools), None
 
-            x, (ks, vs) = lax.scan(
-                layer, x, (params["layers"], jnp.arange(L)))
-            # ks/vs [L, h, C, hd] -> [C, L, h, hd] scatter through the
-            # table (oob rows land in the scratch block)
-            k_pool = k_pool.at[:, bidx, :, off, :].set(
-                ks.transpose(2, 0, 1, 3).astype(k_pool.dtype))
-            v_pool = v_pool.at[:, bidx, :, off, :].set(
-                vs.transpose(2, 0, 1, 3).astype(v_pool.dtype))
+            (x, (k_pool, v_pool)), _ = lax.scan(
+                layer, (x, (k_pool, v_pool)),
+                (params["layers"], jnp.arange(cfg.n_layers)))
             k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
             v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
             logits = gpt._head(params, x, cfg, mesh, rules)[0]  # [C, V]
@@ -496,7 +472,7 @@ def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,
     """jitted speculative VERIFY step: the paged decode step widened to
     score W = ``width`` positions per row in one call.
 
-    (params, k_pool, v_pool [L, N, h, bs, hd], tables [b, T] int32,
+    (params, k_pool, v_pool [cache.PoolLayout], tables [b, T] int32,
      tokens [b, W] int32, positions [b] int32, active [b] bool,
      n_tokens [b] int32)
         -> (logits [b, W, vocab] f32, k_pool, v_pool)
@@ -505,20 +481,20 @@ def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,
     ``positions[row]`` — exactly the plain step's input); columns 1..
     are drafted continuations at positions+1, +2, ...  ``n_tokens`` in
     [1, W] says how many leading columns are real; lanes past it (and
-    all lanes of inactive rows) write to the scratch block / dummy
-    context column and attend key 0 only, so their logits are garbage
-    the caller ignores — never NaN, never corruption.
+    all lanes of inactive rows) write to the scratch block and attend
+    key 0 only, so their logits are garbage the caller ignores — never
+    NaN, never corruption.
 
-    Each real lane j's K/V is inserted into the gathered context at its
-    own position and its query masked to keys <= positions[row]+j (the
-    chunk-prefill causal-horizon mask batched over rows), so lane 0's
-    logits are the plain decode step's logits and lane j's are exact
-    next-token logits GIVEN the drafted prefix — greedy accept/reject
-    on the host is therefore token-identical to non-speculative decode
-    by construction.  All W positions land in ONE donated scatter;
-    rejected lanes leave garbage K/V beyond the row's committed length,
-    which the kv-length masks hide until decode overwrites it (same
-    rule as prefill padding).
+    Each layer commits every real lane's K/V at its own position, then
+    gathers the row's table, each query masked to keys <=
+    positions[row]+j (the chunk-prefill causal-horizon mask batched
+    over rows), so lane 0's logits are the plain decode step's logits
+    and lane j's are exact next-token logits GIVEN the drafted prefix —
+    greedy accept/reject on the host is therefore token-identical to
+    non-speculative decode by construction.  Rejected lanes leave
+    garbage K/V beyond the row's committed length, which the kv-length
+    masks hide until decode overwrites it (same rule as prefill
+    padding).
 
     Sharding and MoE follow the decode step: heads-sharded pools +
     per-device attention with one collective at the output projection,
@@ -527,13 +503,14 @@ def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,
     h, hd = cfg.n_heads, cfg.head_dim
     bs, W, T = int(block_size), int(width), int(n_table)
     S = T * bs
+    shards = heads_shards(mesh, rules)
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
         def verify(params, k_pool, v_pool, tables, tokens, positions,
                    active, n_tokens):
             b = tokens.shape[0]
-            L = k_pool.shape[0]
+            lay = PoolLayout.of(cfg, k_pool, shards)
             k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
             v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
             rows = jnp.arange(b)
@@ -546,26 +523,13 @@ def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,
             safe = jnp.where(live, pos, 0)
             bidx = jnp.where(live, tables[rows[:, None], safe // bs], 0)
             off = jnp.where(live, pos % bs, 0)
-            # dead lanes write a dummy context column (S — the first
-            # slot of the appended SCRATCH-block table entry below);
-            # every real query's causal horizon (<= S-1) excludes the
-            # whole scratch region.  Appending a table column instead
-            # of jnp.pad-ing the gathered context avoids a full-context
-            # copy per layer per pool — the pad was ~half the verify
-            # step's fixed cost.
-            wcol = jnp.where(live, pos, S)
             hor = jnp.where(live, pos, 0)                 # >=1 key: no NaN
-            tbl = jnp.concatenate(
-                [tables, jnp.zeros((b, 1), tables.dtype)], axis=1)
-            mask = (jnp.arange(S + bs)[None, None, :]
-                    <= hor[:, :, None])[:, None]          # [b, 1, W, S+bs]
+            mask = (jnp.arange(S)[None, None, :]
+                    <= hor[:, :, None])[:, None]          # [b, 1, W, S]
 
-            # pools closed over, read per layer; the window's K/V come
-            # back as scan outputs and land in one donated scatter (see
-            # the plain step above for why they are not scan carries)
-            def layer(x, xs):
+            def layer(carry, xs):
+                x, pools = carry
                 lp, li = xs
-                ck, cv = k_pool[li], v_pool[li]    # [N, h, bs, hd]
                 y = gpt._layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
                 qkv = jnp.einsum("bsd,de->bse", y,
                                  lp["wqkv"].astype(cfg.dtype))
@@ -576,26 +540,13 @@ def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,
                 def heads(t):                      # [b,W,d]->[b,h,W,hd]
                     return t.reshape(b, W, h, hd).transpose(0, 2, 1, 3)
 
-                def gather(pool):                  # -> [b, h, S+bs, hd]
-                    g = pool[tbl]                  # [b, T+1, h, bs, hd]
-                    return g.transpose(0, 2, 1, 3, 4).reshape(
-                        b, h, S + bs, hd)
-
-                kh = k.reshape(b, W, h, hd)
-                vh = v.reshape(b, W, h, hd)
-                # insert the window's K/V at their own positions in the
-                # gathered context (position-major key order preserved;
-                # dead lanes collide harmlessly in the dummy column)
-                ctx_k = gather(ck).at[rows[:, None], :, wcol, :].set(
-                    kh.astype(ck.dtype))
-                ctx_v = gather(cv).at[rows[:, None], :, wcol, :].set(
-                    vh.astype(cv.dtype))
-                ctx_k = gpt._constrain(
-                    ctx_k, ("batch", "heads", None, "kv"), mesh, rules)
-                ctx_v = gpt._constrain(
-                    ctx_v, ("batch", "heads", None, "kv"), mesh, rules)
-                o = attention(heads(q), ctx_k, ctx_v, causal=False,
-                              mask=mask, impl="reference")
+                # dead lanes collide harmlessly in the scratch block
+                pools, (ctx_k, ctx_v) = _write_then_read(
+                    lay, pools, li, bidx, off,
+                    (k.reshape(b, W, h, hd), v.reshape(b, W, h, hd)),
+                    tables, mesh, rules)
+                o = packed_attention(heads(q), ctx_k, ctx_v,
+                                     groups=shards, mask=mask)
                 o = o.transpose(0, 2, 1, 3).reshape(b, W, cfg.d_model)
                 o = jnp.einsum("bsd,de->bse", o,
                                lp["wo"].astype(cfg.dtype)) \
@@ -605,17 +556,11 @@ def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,
                                    mesh, rules)
                 y = gpt._layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
                 dn = _mlp_block(y, lp, cfg, mesh, rules)
-                return x + dn, (kh, vh)
+                return (x + dn, pools), None
 
-            x, (ks, vs) = lax.scan(
-                layer, x, (params["layers"], jnp.arange(L)))
-            # ks/vs [L, b, W, h, hd] -> [b, W, L, h, hd]: ONE scatter
-            # commits every lane's K/V through the table (dead lanes
-            # hit the scratch block)
-            k_pool = k_pool.at[:, bidx, :, off, :].set(
-                ks.transpose(1, 2, 0, 3, 4).astype(k_pool.dtype))
-            v_pool = v_pool.at[:, bidx, :, off, :].set(
-                vs.transpose(1, 2, 0, 3, 4).astype(v_pool.dtype))
+            (x, (k_pool, v_pool)), _ = lax.scan(
+                layer, (x, (k_pool, v_pool)),
+                (params["layers"], jnp.arange(cfg.n_layers)))
             k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
             v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
             logits = gpt._head(params, x, cfg, mesh, rules)  # [b, W, V]
@@ -634,7 +579,7 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
     draft positions, each scanning only the first ``draft_layers``
     layers, then the head and a greedy argmax feeding the next step.
 
-    (params, k_pool, v_pool [L, N, h, bs, hd], tables [b, T] int32,
+    (params, k_pool, v_pool [cache.PoolLayout], tables [b, T] int32,
      tokens [b] int32, positions [b] int32, want [b] int32)
         -> (drafts [b, k] int32, k_pool, v_pool)
 
@@ -644,19 +589,16 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
     loop — on small models the dispatch + logits transfer per step
     costs as much as the truncated forward itself.
 
-    The burst's K/V cannot go through the pool between steps (one
-    donated scatter at the end, same discipline as every other step
-    body), so step j's attention reads earlier burst tokens from a
-    carried side-buffer inserted into the gathered context at their
-    true positions — the verify step's scratch-column trick, batched
-    over the burst window.  Only layers < draft_layers land in the
-    pool, and those K/V are bit-identical to the full model's at the
-    same (layer, position) because layer l depends only on layers
-    below it, so drafting straight through the REAL pool is safe:
-    committed positions are unchanged, and the verify pass rewrites
-    every drafted position at all layers regardless of the accept
-    outcome.  Cost per draft token ~ draft_layers / n_layers of a full
-    step, with zero extra weights.
+    Every draft step is the decode step over the first ``draft_layers``
+    layers: it commits its token's K/V to the pool, which both scans
+    carry, and the next step reads it back through the table.  Only
+    layers < draft_layers are written, and those K/V are bit-identical
+    to the full model's at the same (layer, position) because layer l
+    depends only on layers below it, so drafting straight through the
+    REAL pool is safe: committed positions are unchanged, and the
+    verify pass rewrites every drafted position at all layers
+    regardless of the accept outcome.  Cost per draft token ~
+    draft_layers / n_layers of a full step, with zero extra weights.
 
     Sharding and MoE follow the decode step (heads-sharded pools,
     gpt._moe_mlp dispatch per draft token); the truncated-layer trunk
@@ -666,6 +608,7 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
     h, hd, bs = cfg.n_heads, cfg.head_dim, int(block_size)
     D, K, T = int(draft_layers), int(k), int(n_table)
     S = T * bs
+    shards = heads_shards(mesh, rules)
     if not (1 <= D < cfg.n_layers):
         raise SpeculationUnsupported(
             f"draft_layers must be in [1, n_layers) = [1, "
@@ -679,35 +622,27 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
         def draft(params, k_pool, v_pool, tables, tokens, positions,
                   want):
             b = tokens.shape[0]
+            lay = PoolLayout.of(cfg, k_pool, shards)
             k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
             v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
             rows = jnp.arange(b)
-            lanes = jnp.arange(K, dtype=jnp.int32)
-            # one scratch table column (id 0 = the pool's scratch
-            # block): dead lanes write context column S, which every
-            # live query's kv-length horizon (<= S) can include only
-            # as its own position — see wcol below
-            tbl = jnp.concatenate(
-                [tables, jnp.zeros((b, 1), tables.dtype)], axis=1)
+            trunk = jax.tree_util.tree_map(lambda a: a[:D],
+                                           params["layers"])
 
             def step(carry, j):
-                cur, pos, bk, bv = carry          # bk/bv [D, b, K, h, hd]
+                cur, pos, pools = carry
                 live = (want > j) & (pos < S)
                 x = (params["wte"][cur]
                      + params["wpe"][jnp.clip(pos, 0, cfg.max_seq - 1)])
                 x = x[:, None, :].astype(cfg.dtype)           # [b, 1, d]
-                # burst columns: token i of the burst sits at
-                # positions0 + i; steps not yet drafted (i >= j) and
-                # dead rows land in the scratch column S
-                bpos = (pos - j)[:, None] + lanes[None, :]    # [b, K]
-                bvalid = (lanes[None, :] <= j) & live[:, None] \
-                    & (bpos < S)
-                wcol = jnp.where(bvalid, bpos, S)
+                safe = jnp.where(live, pos, 0)
+                bidx = jnp.where(live, tables[rows, safe // bs], 0)
+                off = jnp.where(live, safe % bs, 0)
                 kv_len = jnp.where(live, pos + 1, 1)
 
-                def layer(x, xs):
-                    lp, li, bk_l, bv_l = xs
-                    ck, cv = k_pool[li], v_pool[li]
+                def layer(carry, xs):
+                    x, pools = carry
+                    lp, li = xs
                     y = gpt._layer_norm(x, lp["ln1_scale"],
                                         lp["ln1_bias"])
                     qkv = jnp.einsum("bsd,de->bse", y,
@@ -720,31 +655,12 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
                         return t.reshape(b, 1, h, hd).transpose(
                             0, 2, 1, 3)
 
-                    def gather(pool):              # -> [b, h, S+bs, hd]
-                        g = pool[tbl]              # [b, T+1, h, bs, hd]
-                        return g.transpose(0, 2, 1, 3, 4).reshape(
-                            b, h, S + bs, hd)
-
-                    kh = kk.reshape(b, h, hd)
-                    vh = v.reshape(b, h, hd)
-                    # current token joins the burst buffer, then the
-                    # whole window is inserted at its true positions —
-                    # steps < j come from the carry, the pool knows
-                    # nothing of the burst yet
-                    bk_l = bk_l.at[:, j].set(kh.astype(bk_l.dtype))
-                    bv_l = bv_l.at[:, j].set(vh.astype(bv_l.dtype))
-                    ctx_k = gather(ck).at[rows[:, None], :, wcol, :] \
-                        .set(bk_l)
-                    ctx_v = gather(cv).at[rows[:, None], :, wcol, :] \
-                        .set(bv_l)
-                    ctx_k = gpt._constrain(
-                        ctx_k, ("batch", "heads", None, "kv"),
-                        mesh, rules)
-                    ctx_v = gpt._constrain(
-                        ctx_v, ("batch", "heads", None, "kv"),
-                        mesh, rules)
-                    o = attention(heads(q), ctx_k, ctx_v, causal=False,
-                                  kv_lengths=kv_len, impl="reference")
+                    pools, (ctx_k, ctx_v) = _write_then_read(
+                        lay, pools, li, bidx, off,
+                        (kk.reshape(b, h, hd), v.reshape(b, h, hd)),
+                        tables, mesh, rules)
+                    o = packed_attention(heads(q), ctx_k, ctx_v,
+                                         groups=shards, kv_lengths=kv_len)
                     o = o.transpose(0, 2, 1, 3).reshape(
                         b, 1, cfg.d_model)
                     o = jnp.einsum("bsd,de->bse", o,
@@ -756,40 +672,19 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
                     y = gpt._layer_norm(x, lp["ln2_scale"],
                                         lp["ln2_bias"])
                     dn = _mlp_block(y, lp, cfg, mesh, rules)
-                    return x + dn, (bk_l, bv_l)
+                    return (x + dn, pools), None
 
-                trunk = jax.tree_util.tree_map(lambda a: a[:D],
-                                               params["layers"])
-                x, (bk, bv) = lax.scan(
-                    layer, x, (trunk, jnp.arange(D), bk, bv))
+                (x, pools), _ = lax.scan(
+                    layer, (x, pools), (trunk, jnp.arange(D)))
                 logits = gpt._head(params, x, cfg, mesh, rules)[:, 0, :]
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 cur = jnp.where(live, nxt, cur)
                 pos = pos + live.astype(jnp.int32)
-                return (cur, pos, bk, bv), nxt
+                return (cur, pos, pools), nxt
 
-            bk0 = jnp.zeros((D, b, K, h, hd), cfg.dtype)
-            (_, _, bk, bv), toks = lax.scan(
-                step, (tokens, positions, bk0, bk0), jnp.arange(K))
-            # ONE donated scatter commits the whole burst's K/V for
-            # layers < D (dead lanes collide harmlessly in the scratch
-            # block); layers >= D keep their committed content
-            bpos = positions[:, None] + lanes[None, :]        # [b, K]
-            valid = (lanes[None, :] < want[:, None]) & (bpos < S)
-            safe = jnp.where(valid, bpos, 0)
-            bidx = jnp.where(valid, tbl[rows[:, None], safe // bs], 0)
-            off = jnp.where(valid, safe % bs, 0)
-            # update layout [b*K, D, h, hd]: the two advanced indices
-            # (block, offset) are separated by sliced dims, so their
-            # broadcast axis leads
-            flat = lambda a: a.transpose(1, 2, 0, 3, 4).reshape(
-                b * K, D, h, hd)
-            k_pool = k_pool.at[:D, bidx.reshape(-1), :,
-                               off.reshape(-1), :].set(
-                flat(bk).astype(k_pool.dtype))
-            v_pool = v_pool.at[:D, bidx.reshape(-1), :,
-                               off.reshape(-1), :].set(
-                flat(bv).astype(v_pool.dtype))
+            (_, _, (k_pool, v_pool)), toks = lax.scan(
+                step, (tokens, positions, (k_pool, v_pool)),
+                jnp.arange(K))
             k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
             v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
             return toks.T, k_pool, v_pool     # drafts [b, K]

@@ -47,14 +47,67 @@ def mha_reference(q, k, v, *, causal: bool = True,
         idx_k = jnp.arange(k_len)[None, :]
         causal_mask = idx_q >= idx_k
         logits = jnp.where(causal_mask, logits, -jnp.inf)
+    probs = _masked_softmax(logits, kv_lengths, mask)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+
+
+def _masked_softmax(logits, kv_lengths, mask):
+    """softmax over keys of f32 ``logits`` [b, h, q, k], each batch row
+    limited to its ``kv_lengths`` [b] and to ``mask``."""
     if kv_lengths is not None:
-        valid = (jnp.arange(k.shape[-2])[None, :]
+        valid = (jnp.arange(logits.shape[-1])[None, :]
                  < kv_lengths[:, None])                   # [b, k]
         logits = jnp.where(valid[:, None, None, :], logits, -jnp.inf)
     if mask is not None:
         logits = jnp.where(mask, logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def packed_attention(q, k, v, *, groups: int = 1,
+                     scale: Optional[float] = None,
+                     mask: Optional[jax.Array] = None,
+                     kv_lengths: Optional[jax.Array] = None) -> jax.Array:
+    """``mha_reference`` over keys and values stored TOKEN-MAJOR, a
+    token's heads side by side in the minor dim — the paged KV pool's
+    stored form (inference/cache.PoolLayout), attended as it is
+    gathered.
+
+    q    [b, h, q_len, hd]
+    k, v [b, S, W]: W is ``groups`` equal runs of lanes (one per heads
+         shard); a run holds its ``h / groups`` heads of ``hd`` lanes
+         each, then padding lanes that are ignored.
+    -> [b, h, q_len, hd]
+
+    Heads of 64 lanes fill half a TPU tile, so splitting them out
+    ([b, h, S, hd]) re-tiles the whole context before it can be
+    multiplied.  Here every head's query is instead placed in its own
+    lanes of a [W, heads] matrix that is zero elsewhere: ONE matmul over
+    the full width gives all heads' logits, and one matmul of the
+    probabilities with V gives every (head, lane) pair, of which each
+    lane keeps its own head's.  The extra products are exact zeros, so
+    the sums are mha_reference's (same float32 logits, same rounding of
+    the probabilities to ``v.dtype``); the MXU does ``h / groups`` times
+    the arithmetic and the context is read once, where it lies.
+    """
+    b, h, nq, hd = q.shape
+    hg, wg = h // groups, k.shape[-1] // groups
+    s = _scale_for(q, scale)
+    own = (jnp.arange(wg)[:, None] // hd
+           == jnp.arange(hg)[None, :])                    # [wg, hg]
+    ql = q.transpose(0, 2, 1, 3).reshape(b, nq, groups, hg * hd)
+    ql = jnp.pad(ql, [(0, 0)] * 3 + [(0, wg - hg * hd)])
+    qx = jnp.where(own, ql[..., None], 0).astype(q.dtype)  # [b,q,g,wg,hg]
+    kg = k.reshape(b, -1, groups, wg)
+    vg = v.reshape(b, -1, groups, wg)
+    logits = jnp.einsum("bkgw,bqgwh->bghqk", kg, qx,
+                        preferred_element_type=jnp.float32) * s
+    probs = _masked_softmax(logits.reshape(b, h, nq, -1), kv_lengths, mask)
+    probs = probs.astype(v.dtype).reshape(b, groups, hg, nq, -1)
+    full = jnp.einsum("bghqk,bkgw->bqgwh", probs, vg,
+                      preferred_element_type=jnp.float32)
+    o = jnp.where(own, full, 0).sum(-1).astype(v.dtype)    # [b,q,g,wg]
+    o = o[..., :hg * hd].reshape(b, nq, h, hd)
+    return o.transpose(0, 2, 1, 3)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, *,
@@ -79,10 +132,13 @@ def paged_attention(q, k_pool, v_pool, block_tables, *,
     slot-granular design deferred; block granularity buys pool sharing
     across mixed-length sequences in exchange.
 
-    This is the REFERENCE formulation; the compiled step bodies in
-    inference/decode.py inline the same gather so they can insert the
-    current window's K/V into the gathered context before attending
-    (and scatter it back to the pool once, outside the layer scan).
+    This is the REFERENCE formulation, the oracle of the tests, kept
+    in the head-major ``[n_blocks, h, block_size, hd]`` form.  The
+    compiled step bodies in inference/decode.py store the pool as
+    inference/cache.PoolLayout says (token-major, all layers in one
+    array), write the current window's K/V into it and then gather the
+    same tables, attending the gathered rows as stored
+    (``packed_attention``): same keys at the same positions.
     """
     b = q.shape[0]
     n_tab = block_tables.shape[1]

@@ -2,9 +2,11 @@
 
 The TPU compiler is installed here and compiles for a topology that is
 described, not attached — so the main path's kernels are checked at
-GPT-2 124M widths on every PR at no chip time: what Mosaic or the
-partitioner would refuse on the chip, it refuses here.  A compile that
-passes is not a chip run (``chip_smoke.py`` is).
+GPT-2 124M widths, and the serving programs at the benchmark's GPT-2 XL
+shapes, on every PR at no chip time: what Mosaic or the partitioner
+would refuse on the chip, it refuses here, and a copy of the KV pool
+that the compiler would put into a program shows in its text.  A
+compile that passes is not a chip run (``chip_smoke.py`` is).
 
 Everything that touches the topology — the description itself, the
 shardings, meshes and shapes built from it — lives in module-scoped,
@@ -14,6 +16,7 @@ imports every test file.
 """
 
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,12 +24,15 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
-from ray_tpu.inference.decode import make_paged_decode_step
+from ray_tpu.inference.cache import BlockPool, PoolLayout
+from ray_tpu.inference.decode import (make_chunk_prefill_fn,
+                                      make_paged_decode_step)
 from ray_tpu.models import gpt
 from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES, spec_for
 
 # the modules, not the same-named functions ray_tpu.ops re-exports
 attention_mod = importlib.import_module("ray_tpu.ops.attention")
+cache_mod = importlib.import_module("ray_tpu.inference.cache")
 flash_mod = importlib.import_module("ray_tpu.ops.flash_attention")
 
 QKV = (16, 12, 1024, 64)      # GPT-2 124M training attention, bf16
@@ -149,30 +155,151 @@ def test_loss_grad_compiles_under_pp_dp_mesh(topo, for_tpu):
     assert "bf16[2,12,1024,64]" in text
 
 
-def test_paged_decode_step_compiles_at_124m(one_chip):
-    """One engine program at full width: the paged decode step over the
-    default serving geometry (8 rows, 16-token blocks, 1024-token
-    tables).  Its attention is the reference by design (per-row kv
-    lengths), so no Mosaic kernel is expected — only that the TPU
-    compiler takes the program and it fits the chip."""
+HBM = 15.75 * 2 ** 30         # what one v5e chip gives a program
+
+
+def _on(sharding):
+    def on_chip(shape, dtype, axes=None):       # one chip: axes unused
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    return on_chip
+
+
+def _pool_of(cfg, n_blocks, bs, on_chip):
+    """The pool array as ``BlockPool`` itself shapes it (traced, never
+    allocated), with its layout."""
+    lay = jax.eval_shape(lambda: BlockPool(cfg, n_blocks, bs).k)
+    return on_chip(lay.shape, lay.dtype), PoolLayout.of(cfg, lay)
+
+
+def _params_of(cfg, place):
+    """The model's parameters as shapes, each put by ``place(shape,
+    dtype, logical axes)``."""
+    return jax.tree.map(
+        lambda axes, s: place(s.shape, s.dtype, axes),
+        gpt.param_logical_axes(cfg),
+        jax.eval_shape(lambda: gpt.init_params(cfg, jax.random.PRNGKey(0))),
+        is_leaf=lambda x: isinstance(x, tuple))
+
+
+def _assert_pool_stays_put(compiled, lay, n_pools=2, own=()):
+    """What ISSUE 27 bought, on the compiled program: (a) no ``copy``
+    of K/V (bf16; the weights are f32) whose result is as large as one
+    layer's share of a pool — of a pool, a slice, or a context, (b) the
+    pools enter, ride the layer scan and leave in ONE layout, the plain
+    row-major one, (c) arguments + scratch fit the chip.  ``own``:
+    shapes of K/V the program is GIVEN in another layout, whose
+    re-tiling is theirs and not the pool's."""
+    text = compiled.as_text()
+    share = int(np.prod(lay.shape)) * 2 // lay.n_layers
+    big = []
+    for m in re.finditer(r"= \(?bf16\[([\d,]*)\]\S* copy(?:-start)?\(", text):
+        n = int(np.prod([int(d) for d in m.group(1).split(",") if d] or [1]))
+        if n * 2 >= share and m.group(1) not in own:
+            big.append(m.group(0))
+    assert not big, f"pool-sized copies in the program: {big}"
+    pool = "bf16[" + ",".join(map(str, lay.shape)) + "]"
+    layouts = set(re.findall(re.escape(pool) + r"\{([^}]*)\}", text))
+    assert len(layouts) == 1 and layouts.pop().startswith("2,1,0"), layouts
+    entry = re.search(r"entry_computation_layout=.*", text).group(0)
+    assert entry.count(pool) >= 2 * n_pools         # in and out
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < HBM, f"program needs {used / 2**30:.1f} GiB"
+    # the donated pools are updated in place: no second pool in scratch
+    assert mem.alias_size_in_bytes >= n_pools * int(np.prod(lay.shape)) * 2
+
+
+@pytest.fixture(scope="module")
+def xl(one_chip):
+    """The serve cell's shapes: gpt2-xl, 32 rows, 768 + 1 blocks of 16,
+    64-block tables (chipbench/configs/gpt2-xl.json)."""
+    cfg = gpt.GPTConfig(d_model=1600, n_heads=25, n_layers=48, d_ff=6400)
+    on_chip = _on(one_chip)
+    pool, lay = _pool_of(cfg, 768, 16, on_chip)
+    assert lay.shape == (48 * 769, 16, 1664)        # 1600 -> 13 x 128
+    return cfg, on_chip, pool, lay
+
+
+def test_xl_decode_step_moves_no_pool(xl):
+    cfg, on_chip, pool, lay = xl
+    rows, T = 32, cfg.max_seq // lay.block_size
+    step = make_paged_decode_step(cfg, block_size=lay.block_size, n_table=T)
+    _assert_pool_stays_put(step.lower(
+        _params_of(cfg, on_chip), pool, pool, on_chip((rows, T), jnp.int32),
+        on_chip((rows,), jnp.int32), on_chip((rows,), jnp.int32),
+        on_chip((rows,), jnp.bool_)).compile(), lay)
+
+
+def test_xl_chunk_prefill_moves_no_pool(xl):
+    cfg, on_chip, pool, lay = xl
+    T = cfg.max_seq // lay.block_size
+    chunk = make_chunk_prefill_fn(cfg, chunk=32, block_size=lay.block_size,
+                                  n_table=T)
+    _assert_pool_stays_put(chunk.lower(
+        _params_of(cfg, on_chip), pool, pool, on_chip((T,), jnp.int32),
+        on_chip((32,), jnp.int32), on_chip((), jnp.int32)).compile(), lay)
+
+
+def test_xl_write_blocks_moves_no_pool(xl):
+    """The full-width prefill's table scatter: its scratch was as large
+    as both pools, which is what held ``n_blocks`` at 768."""
+    cfg, on_chip, pool, lay = xl
+    T = cfg.max_seq // lay.block_size
+    kv = on_chip((cfg.n_layers, cfg.n_heads, cfg.max_seq, cfg.head_dim),
+                 cfg.dtype)
+    compiled = cache_mod._write_blocks.lower(
+        lay, pool, pool, on_chip((T,), jnp.int32), kv, kv).compile()
+    _assert_pool_stays_put(compiled, lay,
+                           own=(",".join(map(str, kv.shape)),))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_paged_decode_step_keeps_its_pool_shard_under_dp_tp(mesh_2x2):
+    """tp=2: every device holds whole heads (6 of 12: 384 lanes, no
+    padding) of every block, in the same row-major layout, and the
+    program copies none of it."""
     cfg = gpt.GPTConfig.gpt2_124m()
     rows, bs = 8, 16
     n_table = cfg.max_seq // bs
-    n_blocks = rows * n_table + 1
 
-    def on_chip(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    def on_mesh(shape, dtype, axes=None):
+        spec = spec_for(axes or (None,) * len(shape), DEFAULT_LLM_RULES,
+                        mesh_2x2)
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh_2x2, spec))
 
-    params = jax.tree.map(
-        lambda s: on_chip(s.shape, s.dtype),
-        jax.eval_shape(lambda: gpt.init_params(cfg, jax.random.PRNGKey(0))))
-    pool = on_chip((cfg.n_layers, n_blocks, cfg.n_heads, bs, cfg.head_dim),
-                   cfg.dtype)
-    step = make_paged_decode_step(cfg, block_size=bs, n_table=n_table)
+    lay = PoolLayout(cfg.n_layers, rows * n_table + 1, bs, cfg.n_heads,
+                     cfg.head_dim, cache_mod.heads_shards(mesh_2x2))
+    assert (lay.shards, lay.width) == (2, cfg.d_model)
+    pool = on_mesh(lay.shape, cfg.dtype, cache_mod.POOL_AXES)
+    step = make_paged_decode_step(cfg, block_size=bs, n_table=n_table,
+                                  mesh=mesh_2x2)
     compiled = step.lower(
-        params, pool, pool, on_chip((rows, n_table), jnp.int32),
+        _params_of(cfg, on_mesh), pool, pool, on_mesh((rows, n_table), jnp.int32),
+        on_mesh((rows,), jnp.int32), on_mesh((rows,), jnp.int32),
+        on_mesh((rows,), jnp.bool_)).compile()
+    shard = PoolLayout(lay.n_layers, lay.n_rows, bs, cfg.n_heads // 2,
+                       cfg.head_dim)
+    assert shard.shape == (*lay.shape[:2], 384)
+    _assert_pool_stays_put(compiled, shard)
+
+
+def test_paged_decode_step_compiles_at_124m(one_chip):
+    """One engine program at full width: the paged decode step over the
+    default serving geometry (8 rows, 16-token blocks, 1024-token
+    tables).  Its attention is plain XLA by design (per-row kv
+    lengths), so no Mosaic kernel is expected — only that the TPU
+    compiler takes the program, leaves the pool where it is (768 lanes:
+    no padding) and it fits the chip."""
+    cfg = gpt.GPTConfig.gpt2_124m()
+    rows, bs = 8, 16
+    n_table = cfg.max_seq // bs
+    on_chip = _on(one_chip)
+    pool, lay = _pool_of(cfg, rows * n_table, bs, on_chip)
+    assert lay.width == cfg.d_model
+    step = make_paged_decode_step(cfg, block_size=bs, n_table=n_table)
+    _assert_pool_stays_put(step.lower(
+        _params_of(cfg, on_chip), pool, pool,
+        on_chip((rows, n_table), jnp.int32),
         on_chip((rows,), jnp.int32), on_chip((rows,), jnp.int32),
-        on_chip((rows,), jnp.bool_)).compile()
-    mem = compiled.memory_analysis()
-    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    assert used < 15.75 * 2 ** 30, f"decode step needs {used / 2**30:.1f} GiB"
+        on_chip((rows,), jnp.bool_)).compile(), lay)

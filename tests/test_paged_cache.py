@@ -74,10 +74,12 @@ def test_block_pool_refcount_and_cow_copy(cfg):
         pool.incref(5)
     # copy_block duplicates content (the CoW primitive)
     src, dst = pool.alloc(), pool.alloc()
-    pool.k = pool.k.at[:, src].set(1.5)
+    k, v = pool.read_blocks([src])
+    pool.write_blocks_at([src], k + 1.5, v - 2.5)
     pool.copy_block(src, dst)
-    np.testing.assert_array_equal(np.asarray(pool.k[:, dst]),
-                                  np.asarray(pool.k[:, src]))
+    k, v = pool.read_blocks([src, dst])
+    np.testing.assert_array_equal(k, np.full_like(k, 1.5))
+    np.testing.assert_array_equal(v, np.full_like(v, -2.5))
 
 
 def test_block_pool_bounds(cfg):

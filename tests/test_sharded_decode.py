@@ -120,8 +120,14 @@ def test_dense_no_mesh_builds_are_annotation_free(cfg, params):
     from ray_tpu.inference.decode import (make_chunk_prefill_fn,
                                           make_paged_decode_step)
     step = make_paged_decode_step(cfg, block_size=8, n_table=8)
-    L, h, bs, hd = cfg.n_layers, cfg.n_heads, 8, cfg.head_dim
-    pool = jnp.zeros((L, 17, h, bs, hd), jnp.float32)
+    from ray_tpu.inference.cache import PoolLayout
+
+    def zeros_pool(shards):
+        return jnp.zeros(PoolLayout(cfg.n_layers, 17, 8, cfg.n_heads,
+                                    cfg.head_dim, shards).shape,
+                         jnp.float32)
+
+    pool = zeros_pool(1)
     jaxpr = str(jax.make_jaxpr(step)(
         params, pool, pool, jnp.zeros((2, 8), jnp.int32),
         jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
@@ -141,7 +147,7 @@ def test_dense_no_mesh_builds_are_annotation_free(cfg, params):
     mesh = _tp_mesh(2)
     step_sh = make_paged_decode_step(cfg, block_size=8, n_table=8,
                                      mesh=mesh)
-    sh_pool = jax.device_put(pool)
+    sh_pool = zeros_pool(2)
     jaxpr_sh = str(jax.make_jaxpr(step_sh)(
         params, sh_pool, sh_pool, jnp.zeros((2, 8), jnp.int32),
         jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
@@ -170,8 +176,8 @@ def test_sharded_parity_prefix_and_chunked(n, cfg, params, mesh2, mesh4):
         assert st["blocks_per_device"] == st["blocks_total"]
         assert st["cache_bytes_per_device"] == st["cache_bytes"] // n
         spec = eng.pool.k.sharding.spec
-        assert "tp" in str(spec[2]), \
-            f"pool heads dim is not tp-sharded: {spec}"
+        assert "tp" in str(spec[-1]), \
+            f"pool heads are not tp-sharded: {spec}"
 
         warm = [7, 3, 1, 4, 1, 5, 9, 2, 6]
         got = eng.generate(warm, max_new=6, timeout=300)
