@@ -16,8 +16,14 @@ end-to-end inference product over the sharded GPT —
                  dispatch.
   * cache.py   — BlockPool (refcounted token blocks, copy-on-write
                  tails, scratch-block scatter discipline) + RadixIndex
-                 (prefix reuse trie, LRU eviction); KVCacheManager is
+                 (prefix reuse trie, LRU eviction) + StatePool (the
+                 per-row recurrent state of a model's state-space
+                 layers, owned by the BlockPool); KVCacheManager is
                  the legacy slot pool (A/B baseline).
+  * recurrent.py — the decode and chunk-prefill programs of the second
+                 model family (models/hybrid.py: Mamba-2 + attention
+                 mixers, routed experts): the model's one layer
+                 function over both kinds of pool.
   * engine.py  — the Orca-style iteration-level scheduler over the
                  paged cache: block-budget admission with prefix-hit
                  credit, occupancy-aware chunked prefill, block-
@@ -42,7 +48,8 @@ same-run A/B).
 
 from __future__ import annotations
 
-from ray_tpu.inference.cache import BlockPool, KVCacheManager, RadixIndex
+from ray_tpu.inference.cache import (BlockPool, KVCacheManager, RadixIndex,
+                                     StatePool)
 from ray_tpu.inference.decode import (MoEDecodeUnsupported,
                                       SpeculationUnsupported,
                                       make_chunk_prefill_fn,
@@ -61,7 +68,7 @@ from ray_tpu.inference.serving import (GPTServer, build_gpt_deployment,
                                        encode_prompt, parse_stream_chunks)
 
 __all__ = [
-    "BlockPool", "KVCacheManager", "RadixIndex",
+    "BlockPool", "KVCacheManager", "RadixIndex", "StatePool",
     "MoEDecodeUnsupported", "SpeculationUnsupported",
     "make_chunk_prefill_fn", "make_decode_step",
     "make_paged_decode_step", "make_paged_draft_step", "make_prefill_fn",

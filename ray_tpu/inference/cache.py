@@ -218,15 +218,18 @@ class PoolLayout:
     shards: int = 1         # heads_shards(mesh, rules)
 
     @classmethod
-    def of(cls, cfg: GPTConfig, pool: jax.Array,
-           shards: int = 1) -> "PoolLayout":
-        """The layout of ``pool`` as a compiled program sees it."""
-        lay = cls(cfg.n_layers, pool.shape[0] // cfg.n_layers,
-                  pool.shape[1], cfg.n_heads, cfg.head_dim, shards)
+    def of(cls, cfg, pool: jax.Array, shards: int = 1) -> "PoolLayout":
+        """The layout of ``pool`` as a compiled program sees it, from
+        the model's ``kv_geometry``: the layers that keep K/V (not every
+        layer of a hybrid model does), the K/V heads (fewer than the
+        query heads under grouped queries) and the head size."""
+        layers, heads, head_dim = cfg.kv_geometry
+        lay = cls(layers, pool.shape[0] // layers, pool.shape[1], heads,
+                  head_dim, shards)
         if lay.shape != pool.shape:
             raise ValueError(f"pool {pool.shape} is not a {lay.shape} "
-                             f"pool of {cfg.n_layers} layers x "
-                             f"{cfg.n_heads} heads of {cfg.head_dim}")
+                             f"pool of {layers} layers x "
+                             f"{heads} heads of {head_dim}")
         return lay
 
     @property
@@ -377,9 +380,9 @@ class BlockPool:
     bytes are ``bytes_total() / tp``).
     """
 
-    def __init__(self, cfg: GPTConfig, n_blocks: int, block_size: int,
+    def __init__(self, cfg, n_blocks: int, block_size: int,
                  max_seq: Optional[int] = None, dtype=None, mesh=None,
-                 rules: Rules = DEFAULT_LLM_RULES):
+                 rules: Rules = DEFAULT_LLM_RULES, state_rows: int = 0):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.cfg = cfg
@@ -400,17 +403,23 @@ class BlockPool:
         self.n_blocks = int(n_blocks)             # usable (excludes scratch)
         self.dtype = dtype or cfg.dtype
         shards = self.heads_shards
-        if cfg.n_heads % shards:
+        kv_layers, kv_heads, head_dim = cfg.kv_geometry
+        if kv_heads % shards:
             raise ValueError(
-                f"n_heads {cfg.n_heads} is not divisible by the heads "
+                f"n_heads {kv_heads} is not divisible by the heads "
                 f"(tp) shard count {shards} of mesh "
                 f"{dict(zip(mesh.axis_names, mesh.devices.shape))} — "
                 f"the pool shards the heads dim evenly per device")
-        self.layout = PoolLayout(cfg.n_layers, self.n_blocks + 1,
-                                 self.block_size, cfg.n_heads,
-                                 cfg.head_dim, shards)
+        self.layout = PoolLayout(kv_layers, self.n_blocks + 1,
+                                 self.block_size, kv_heads, head_dim,
+                                 shards)
         self.k = self._zeros()
         self.v = self._zeros()
+        # the second kind of state: what a model's recurrent layers keep
+        # per decode row, beside the row's blocks (None for a model whose
+        # whole past is K/V)
+        self.state = (StatePool(cfg, state_rows)
+                      if cfg.state_geometry is not None else None)
         self._lock = threading.Lock()
         # pop() -> block 1 first; id 0 (scratch) is never in the list
         self._free = list(range(self.n_blocks, 0, -1))
@@ -568,6 +577,8 @@ class BlockPool:
         steps donate-commit into."""
         self.k = self._zeros()
         self.v = self._zeros()
+        if self.state is not None:
+            self.state.reset()
         with self._lock:
             self._free = list(range(self.n_blocks, 0, -1))
             self._rc = [0] * (self.n_blocks + 1)
@@ -576,15 +587,26 @@ class BlockPool:
     # ------------------------------------------------------------- stats
 
     def bytes_total(self) -> int:
-        """Bytes of both pools as stored, padding lanes included."""
+        """Bytes of both pools as stored, padding lanes included, and of
+        the recurrent-state pool where there is one."""
         itemsize = np.dtype(jnp.zeros((), self.dtype).dtype).itemsize
-        return 2 * int(np.prod(self.layout.shape)) * itemsize
+        return (2 * int(np.prod(self.layout.shape)) * itemsize
+                + self.state_bytes())
+
+    def state_bytes(self) -> int:
+        return self.state.bytes_total() if self.state is not None else 0
+
+    @property
+    def state_rows_in_use(self) -> int:
+        return self.state.rows_in_use if self.state is not None else 0
 
     def stats(self) -> dict:
         with self._lock:
             free = len(self._free)
         shards = self.heads_shards
         return {
+            "state_bytes": self.state_bytes(),
+            "state_rows_in_use": self.state_rows_in_use,
             "block_size": self.block_size,
             # blocks are replicated in COUNT across tp shards (heads are
             # what's split), so blocks_total is simultaneously the
@@ -601,6 +623,76 @@ class BlockPool:
             "tp_shards": shards,
             "generation": self.generation,
         }
+
+
+# ---------------------------------------------------------------------------
+# recurrent state
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def _zero_row(conv, ssm, row):
+    """Both state arrays <- zeros at decode row ``row``, every layer."""
+    return (conv.at[:, row].set(jnp.zeros((), conv.dtype)),
+            ssm.at[:, row].set(jnp.zeros((), ssm.dtype)))
+
+
+class StatePool:
+    """Per-row recurrent state of a model's state-space layers: the
+    second kind of serving state, owned by the ``BlockPool`` whose blocks
+    hold the attention layers' K/V.
+
+    Two preallocated arrays ``[recurrent layers, rows, ...]`` —
+    ``conv`` (the causal convolution's last inputs, in the activation
+    dtype) and ``ssm`` (the selective-scan state, float32) — indexed by
+    DECODE ROW: a row's state is the fixed-size summary of everything
+    the row has read, so there is nothing to page.  Like the K/V pools
+    they are donated to every compiled program and updated in place.  A
+    program leaves the state of a row it does not advance exactly as it
+    was (ops/ssm.py: ``n_valid`` 0), so no scratch row is needed.
+
+    Lifecycle, with the row's blocks: ``admit(row)`` zeroes the row's
+    state (a prompt starts from nothing), ``release(row)`` drops it —
+    at natural exit and at preemption alike: unlike K/V blocks a state
+    cannot be kept for a prefix (it is the state AFTER the row's last
+    token, not a position-addressed record), so a preempted request
+    re-prefills from zero.
+    """
+
+    def __init__(self, cfg, n_rows: int):
+        if n_rows < 1:
+            raise ValueError(f"a state pool needs >= 1 row, got {n_rows}")
+        layers, conv, ssm = cfg.state_geometry
+        self._shapes = (((layers, n_rows, *conv), cfg.dtype),
+                        ((layers, n_rows, *ssm), jnp.float32))
+        self._rows: set = set()
+        self.reset()
+
+    def reset(self) -> None:
+        """(Re)allocate zeroed arrays and forget every row (also the
+        recovery after a failed program invalidated the donated ones)."""
+        (cs, cd), (ss, sd) = self._shapes
+        self.conv, self.ssm = jnp.zeros(cs, cd), jnp.zeros(ss, sd)
+        self._rows.clear()
+
+    def admit(self, row: int) -> None:
+        self.conv, self.ssm = _zero_row(self.conv, self.ssm,
+                                        np.int32(row))
+        self._rows.add(row)
+
+    def release(self, row: int) -> None:
+        self._rows.discard(row)
+
+    def swap(self, conv: jax.Array, ssm: jax.Array) -> None:
+        """Install a compiled program's updated state arrays."""
+        self.conv, self.ssm = conv, ssm
+
+    @property
+    def rows_in_use(self) -> int:
+        return len(self._rows)
+
+    def bytes_total(self) -> int:
+        return sum(int(np.prod(shape)) * np.dtype(dt).itemsize
+                   for shape, dt in self._shapes)
 
 
 # ---------------------------------------------------------------------------

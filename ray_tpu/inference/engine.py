@@ -90,8 +90,10 @@ from ray_tpu.inference.decode import (SpeculationUnsupported,
                                       make_prefill_fn,
                                       make_spec_verify_step,
                                       ngram_propose)
-from ray_tpu.models import gpt
-from ray_tpu.models.gpt import GPTConfig
+from ray_tpu.inference.recurrent import (make_recurrent_chunk_fn,
+                                         make_recurrent_decode_step,
+                                         pack_chunk, pack_step)
+from ray_tpu.models import gpt, hybrid
 from ray_tpu.parallel.sharding import (DEFAULT_LLM_RULES, Rules,
                                        tree_shardings)
 from ray_tpu.util import tracing
@@ -316,6 +318,12 @@ class GenerationRequest:
 # engine registry for /metrics export (weak: an engine dies with its
 # replica, the gauge series just disappears — the loop thread also only
 # holds its engine weakly, see _engine_loop)
+def init_params_for(cfg, key):
+    """Seeded parameters of ``cfg``'s model family."""
+    family = hybrid if cfg.state_geometry is not None else gpt
+    return family.init_params(cfg, key)
+
+
 _ENGINES: "weakref.WeakValueDictionary[str, InferenceEngine]" = \
     weakref.WeakValueDictionary()
 _engine_seq = itertools.count()
@@ -343,6 +351,224 @@ def _engine_loop(ref: "weakref.ref[InferenceEngine]") -> None:
         del eng
 
 
+class _KVOnly:
+    """The engine's model seam for a model whose whole past is K/V
+    blocks (models/gpt.py): what programs a pass runs, and what they
+    carry.  A namespace of functions of the engine, never instantiated
+    (a bound method kept on the engine would be a reference cycle, and
+    an abandoned engine must die by reference count alone)."""
+
+    @staticmethod
+    def build(eng, bs: int) -> None:
+        cfg, ec, mesh, rules = (eng.cfg, eng.engine_cfg, eng._mesh,
+                                eng._rules)
+        # the full-width prefill stays: a COLD prompt on an idle
+        # engine seeds all its blocks from one training-forward call
+        # (chunking pays a full-table gather per chunk — it earns
+        # its keep on prefix hits and under load, not cold+idle)
+        eng._prefill = make_prefill_fn(cfg, mesh=mesh, rules=rules)
+        eng._full_width_over = eng.max_seq // 2
+        eng._step = make_paged_decode_step(
+            cfg, block_size=bs, n_table=eng.pool.blocks_per_seq,
+            mesh=mesh, rules=rules)
+        eng._chunk = make_chunk_prefill_fn(
+            cfg, chunk=ec.prefill_chunk, block_size=bs,
+            n_table=eng.pool.blocks_per_seq, mesh=mesh, rules=rules)
+
+    @staticmethod
+    def step_args(eng) -> tuple:
+        """What the host sends the decode step this pass."""
+        return (jnp.asarray(eng._tables), jnp.asarray(eng._tokens),
+                jnp.asarray(eng._positions), jnp.asarray(eng._active))
+
+    @staticmethod
+    def step(eng, args):
+        logits, k, v = eng._step(eng.params, eng.pool.k, eng.pool.v, *args)
+        eng.pool.swap(k, v)
+        return logits
+
+    @staticmethod
+    def chunk_args(eng, row, toks, at, n_q) -> tuple:
+        return (jnp.asarray(eng._tables[row]), jnp.asarray(toks),
+                jnp.int32(at))
+
+    @staticmethod
+    def chunk(eng, args):
+        logits, k, v = eng._chunk(eng.params, eng.pool.k, eng.pool.v,
+                                  *args)
+        eng.pool.swap(k, v)
+        return logits
+
+    @staticmethod
+    def first_token(eng, row, req, logits, idx) -> None:
+        """The prompt's last chunk is dispatched: sample the first
+        token, emit it, and let the row decode."""
+        tok = eng._first_token(req, logits[idx])
+        req._emit(tok)
+        eng._start_decoding(row, req, tok)
+
+    @staticmethod
+    def fetch(eng, logits):
+        """-> (logits as the sampling needs them, bytes fetched)."""
+        logits = np.asarray(logits)
+        return logits, logits.nbytes
+
+    @staticmethod
+    def greedy(eng, logits):
+        # greedy rows sample in ONE vectorized call; temperature rows
+        # keep their per-request rng
+        return np.asarray(gpt.sample_token(logits, temperature=0.0))
+
+    @staticmethod
+    def row_admitted(eng, row) -> None:
+        pass
+
+    row_released = row_admitted
+
+    @staticmethod
+    def pass_done(eng) -> None:
+        pass
+
+
+class _KVAndState:
+    """The seam for a model that also keeps a recurrent state per row
+    (models/hybrid.py): its programs carry the state pool beside the K/V
+    pools and report the routed experts' load."""
+
+    @staticmethod
+    def refuse(ec: "EngineConfig", mesh) -> None:
+        """What a recurrent state makes impossible today, refused at
+        construction: speculation (a rejected draft cannot be rolled
+        back out of a state), the slot cache, a mesh."""
+        if ec.speculate is not None:
+            raise SpeculationUnsupported(
+                "speculative decoding needs a cache that can roll "
+                "rejected tokens back; a recurrent state cannot (no "
+                "snapshot at the draft's start yet)")
+        if not ec.paged:
+            raise ValueError("a model with recurrent layers is served "
+                             "by the paged engine only")
+        if mesh is not None:
+            raise ValueError("a model with recurrent layers is served "
+                             "on one device (no sharding rules yet)")
+
+    @staticmethod
+    def build(eng, bs: int) -> None:
+        """Prompts take the chunk path only (a state is built window by
+        window; there is no one-pass scatter of it), so no prompt is
+        ever over the full-width threshold."""
+        cfg, ec = eng.cfg, eng.engine_cfg
+        eng._prefill = None
+        eng._full_width_over = eng.max_seq
+        eng._step = make_recurrent_decode_step(
+            cfg, block_size=bs, n_table=eng.pool.blocks_per_seq)
+        eng._chunk = make_recurrent_chunk_fn(
+            cfg, chunk=ec.prefill_chunk, block_size=bs,
+            n_table=eng.pool.blocks_per_seq)
+
+    @staticmethod
+    def _ran(eng, out):
+        logits, load, k, v, conv, ssm = out
+        eng.pool.swap(k, v)
+        eng.pool.state.swap(conv, ssm)
+        # stays on the device until the next decode pass fetches it
+        eng._load.append(load)
+        return logits
+
+    # one fresh numpy array a program, converted where the program is
+    # called: no ``jnp.asarray`` of its own (inference/recurrent.py)
+    @staticmethod
+    def step_args(eng) -> tuple:
+        return (pack_step(eng._tables, eng._tokens, eng._positions,
+                          eng._active),)
+
+    @staticmethod
+    def step(eng, args):
+        st = eng.pool.state
+        return _KVAndState._ran(eng, eng._step(
+            eng.params, eng.pool.k, eng.pool.v, st.conv, st.ssm, *args))
+
+    @staticmethod
+    def chunk_args(eng, row, toks, at, n_q) -> tuple:
+        return (pack_chunk(eng._tables[row], toks, at, row, n_q),)
+
+    @staticmethod
+    def chunk(eng, args):
+        st = eng.pool.state
+        return _KVAndState._ran(eng, eng._chunk(
+            eng.params, eng.pool.k, eng.pool.v, st.conv, st.ssm, *args))
+
+    @staticmethod
+    def first_token(eng, row, req, logits, idx) -> None:
+        """A greedy first token is the chunk program's own argmax, read
+        with its load vector: no slice and no sampling dispatched.  And
+        not waited for here while other rows decode: the pass's decode
+        step is dispatched behind the chunk first (the device runs the
+        two back to back), the token is emitted as soon as the chunk
+        has ended (``_emit_first``, from ``fetch``), and the row joins
+        the decode batch at the end of the pass (``pass_done``)."""
+        if req.temperature != 0.0:
+            tok = eng._first_token(req, logits[idx])
+            req._emit(tok)
+            eng._start_decoding(row, req, tok)
+            return
+        eng._first_pending.append([row, req, eng._load[-1], None])
+        if not eng._active.any():           # no decode to run behind
+            _KVAndState.pass_done(eng)
+
+    @staticmethod
+    def _emit_first(eng) -> None:
+        for pend in eng._first_pending:
+            row, req, load, tok = pend
+            # a row preempted since (the block hunt of this pass's
+            # decode) re-prefills and gets its first token then
+            if tok is None and eng._slot_req.get(row) is req:
+                with tracing.span("engine.fetch", bytes=16):
+                    pend[3] = tok = int(jax.device_get(load)[3])
+                req._emit(tok)
+
+    @staticmethod
+    def pass_done(eng) -> None:
+        """The pass's decode step has been sampled (or there was none):
+        rows whose prompt ended in this pass start decoding."""
+        if not eng._first_pending:
+            return
+        _KVAndState._emit_first(eng)
+        pending, eng._first_pending = eng._first_pending, []
+        for row, req, _, tok in pending:
+            if tok is not None and eng._slot_req.get(row) is req:
+                eng._start_decoding(row, req, tok)
+
+    @staticmethod
+    def fetch(eng, logits):
+        """The rows' greedy tokens and the expert load of this pass and
+        of the chunks before it, in ONE small transfer; the logits stay
+        on the device (a sampled row indexes them there).  First tokens
+        that this pass's chunks owe go out first: their programs ended
+        a decode step ago."""
+        _KVAndState._emit_first(eng)
+        loads = jax.device_get(eng._load)
+        eng._load = []
+        eng._greedy = loads[-1][3:]           # the decode step's own
+        for load in loads:
+            eng._expert_held += int(load[0])
+            eng._expert_total += int(load[1])
+            eng._expert_load_max += int(load[2])
+        return logits, sum(load.nbytes for load in loads)
+
+    @staticmethod
+    def greedy(eng, logits):
+        return eng._greedy
+
+    @staticmethod
+    def row_admitted(eng, row) -> None:
+        eng.pool.state.admit(row)
+
+    @staticmethod
+    def row_released(eng, row) -> None:
+        eng.pool.state.release(row)
+
+
 class InferenceEngine:
     """Continuous-batching engine over one parameter set.
 
@@ -351,7 +577,7 @@ class InferenceEngine:
     >>> for tok in req.stream(): ...
     """
 
-    def __init__(self, params, cfg: GPTConfig,
+    def __init__(self, params, cfg,
                  engine_cfg: Optional[EngineConfig] = None, *,
                  mesh=None, rules: Rules = DEFAULT_LLM_RULES,
                  name: Optional[str] = None,
@@ -365,6 +591,17 @@ class InferenceEngine:
         ec = self.engine_cfg
         self._mesh = mesh
         self._rules = rules
+        # THE seam between the scheduler and a model family: what
+        # programs a pass runs and what pools they carry follows from
+        # what the model keeps of a row's past — K/V blocks alone
+        # (models/gpt.py), or K/V blocks for its attention layers and a
+        # recurrent state for the others (models/hybrid.py,
+        # ``cfg.state_geometry``).  Chosen once, here; a pass calls
+        # ``self._seam``'s functions and branches on nothing.
+        recurrent = cfg.state_geometry is not None
+        self._seam = _KVAndState if recurrent else _KVOnly
+        if recurrent:
+            _KVAndState.refuse(ec, mesh)
         if mesh is not None:
             # shard the weights to match the annotated step bodies
             # (heads/mlp/qkv/vocab over tp per the rules) so the first
@@ -398,21 +635,15 @@ class InferenceEngine:
             per_seq = -(-int(ec.max_seq or cfg.max_seq) // bs)
             n_blocks = ec.n_blocks if ec.n_blocks is not None else n * per_seq
             self.pool = BlockPool(cfg, n_blocks, bs, max_seq=ec.max_seq,
-                                  mesh=mesh, rules=rules)
+                                  mesh=mesh, rules=rules, state_rows=n)
             self.cache = None
             self.max_seq = self.pool.max_seq
-            self.trie = (RadixIndex(self.pool) if ec.prefix_cache else None)
-            # the full-width prefill stays: a COLD prompt on an idle
-            # engine seeds all its blocks from one training-forward call
-            # (chunking pays a full-table gather per chunk — it earns
-            # its keep on prefix hits and under load, not cold+idle)
-            self._prefill = make_prefill_fn(cfg, mesh=mesh, rules=rules)
-            self._step = make_paged_decode_step(
-                cfg, block_size=bs, n_table=self.pool.blocks_per_seq,
-                mesh=mesh, rules=rules)
-            self._chunk = make_chunk_prefill_fn(
-                cfg, chunk=ec.prefill_chunk, block_size=bs,
-                n_table=self.pool.blocks_per_seq, mesh=mesh, rules=rules)
+            # a cached prefix is its K/V blocks: with recurrent layers
+            # that is no longer the whole of a prefix, so nothing is
+            # adopted (no index) — by derivation, not by an option
+            self.trie = (RadixIndex(self.pool)
+                         if ec.prefix_cache and not recurrent else None)
+            self._seam.build(self, bs)
             if self._spec is not None:
                 self._verify = make_spec_verify_step(
                     cfg, width=ec.speculate_k + 1, block_size=bs,
@@ -426,6 +657,9 @@ class InferenceEngine:
                     k=ec.speculate_k, block_size=bs,
                     n_table=self.pool.blocks_per_seq, mesh=mesh,
                     rules=rules) if self._spec == "self" else None)
+            self._load = []            # recurrent programs' load vectors,
+            self._greedy = None        # the last step's greedy tokens and
+            self._first_pending = []   # [row, request, load, token] owed
             self._tables = np.zeros((n, self.pool.blocks_per_seq), np.int32)
             self._row_blocks: dict[int, list[int]] = {}
             self._free_rows = list(range(n - 1, -1, -1))
@@ -482,6 +716,13 @@ class InferenceEngine:
         # width cancels out, so the gauge isolates speculation's win
         self._row_steps = 0            # (row, compiled-call) pairs
         self._row_tokens = 0           # tokens those pairs emitted
+        # routed-expert load of the programs that report one (loop
+        # thread only): assignments to the experts held here, to all
+        # experts, and the busiest held expert's summed over layers and
+        # passes (max / (held / experts held) = that pass's imbalance)
+        self._expert_held = 0
+        self._expert_total = 0
+        self._expert_load_max = 0
 
         with _registry_lock:
             self.name = name or f"engine-{next(_engine_seq)}"
@@ -619,6 +860,7 @@ class InferenceEngine:
                         self._prefill_chunk_pass()
                     if self._active.any():
                         self._paged_decode_iteration()
+                    self._seam.pass_done(self)
                 elif self._active.any():
                     self._decode_iteration()
             except Exception as e:            # step failure: fail the
@@ -823,6 +1065,7 @@ class InferenceEngine:
         self._row_blocks[row] = blocks
         self._slot_req[row] = req
         self._prefilling[row] = hit          # prefill resumes past the hit
+        self._seam.row_admitted(self, row)
         req._admitted()
         req.prefix_hit_tokens = hit
         occupied = self.engine_cfg.max_slots - len(self._free_rows)
@@ -900,6 +1143,7 @@ class InferenceEngine:
         self._slot_req.pop(row, None)
         self._active[row] = False
         self._prefilling.pop(row, None)
+        self._seam.row_released(self, row)
         for bid in self._row_blocks.pop(row, []):
             self.pool.decref(bid)
         self._tables[row, :] = 0
@@ -997,7 +1241,7 @@ class InferenceEngine:
             else:
                 for bid in ids2:
                     self.pool.decref(bid)
-        if (pos == 0 and 2 * n > self.max_seq
+        if (pos == 0 and n > self._full_width_over
                 and 2 * int(self._active.sum())
                 < self.engine_cfg.max_slots):
             # cold LONG prompt at low decode occupancy: ONE full-width
@@ -1018,7 +1262,7 @@ class InferenceEngine:
                 logits, k_new, v_new = self._prefill(self.params, padded)
                 self.pool.write_prefill(self._tables[row], k_new[:, 0],
                                         v_new[:, 0])
-            self._finish_prefill(row, req, logits[0, n - 1])
+            self._finish_prefill(row, req, logits, (0, n - 1))
             return
         # the write window [pos, pos+C) must only touch exclusively
         # owned blocks — only the FIRST can be shared (an adopted
@@ -1029,32 +1273,31 @@ class InferenceEngine:
             if not self._cow_block(row, bidx):
                 return                     # row preempted under pressure
         n_q = min(C, n - pos)
-        sp.set(row=row, tokens=n_q, full_width=False)
+        if sp:
+            sp.set(row=row, tokens=n_q, full_width=False,
+                   state_rows=self.pool.state_rows_in_use)
         req.chunk_passes += 1
         self._chunk_passes += 1
         self._prefill_tokens += n_q
         chunk_toks = np.zeros(C, np.int32)
         chunk_toks[:n_q] = prompt[pos:pos + n_q]
         with tracing.span("engine.upload") as up:
-            table, toks, at = (jnp.asarray(self._tables[row]),
-                               jnp.asarray(chunk_toks), jnp.int32(pos))
+            args = self._seam.chunk_args(self, row, chunk_toks, pos, n_q)
             if up:
-                up.set(bytes=table.nbytes + toks.nbytes + at.nbytes)
+                up.set(bytes=sum(a.nbytes for a in args))
         with tracing.span("engine.dispatch"):
-            logits, k, v = self._chunk(self.params, self.pool.k,
-                                       self.pool.v, table, toks, at)
-            self.pool.swap(k, v)
+            logits = self._seam.chunk(self, args)
         new_pos = pos + n_q
         if new_pos < n:
             self._prefilling[row] = new_pos
             return
-        self._finish_prefill(row, req, logits[n_q - 1])
+        self._finish_prefill(row, req, logits, n_q - 1)
 
     def _finish_prefill(self, row: int, req: GenerationRequest,
-                        last_logits) -> None:
+                        logits, idx) -> None:
         """Prompt fully in cache: sample the first token from the last
-        prompt position's logits; the row turns active (or evicts
-        immediately on EOS / max_new == 1)."""
+        prompt position's logits (``logits[idx]``); the row turns active
+        (or evicts immediately on EOS / max_new == 1)."""
         del self._prefilling[row]
         if self.trie is not None:
             # publish the prompt's full blocks NOW (not at finish):
@@ -1069,8 +1312,12 @@ class InferenceEngine:
                 self._note_prefix_published(
                     req.prompt[:full],
                     self._row_blocks[row][:full // self.pool.block_size])
-        tok = self._first_token(req, last_logits)
-        req._emit(tok)
+        self._seam.first_token(self, row, req, logits, idx)
+
+    def _start_decoding(self, row: int, req: GenerationRequest,
+                        tok: int) -> None:
+        """``tok``, the request's first token, has been emitted: the row
+        turns active, or is evicted if that token ended the request."""
         if self._request_finished(req, tok):
             self._paged_evict(row)
             return
@@ -1382,17 +1629,14 @@ class InferenceEngine:
             return
         with tracing.span("engine.decode", speculative=False) as sp:
             if sp:
-                sp.set(active=int(self._active.sum()))
+                sp.set(active=int(self._active.sum()),
+                       state_rows=self.pool.state_rows_in_use)
             with tracing.span("engine.upload") as up:
-                args = (jnp.asarray(self._tables), jnp.asarray(self._tokens),
-                        jnp.asarray(self._positions),
-                        jnp.asarray(self._active))
+                args = self._seam.step_args(self)
                 if up:
                     up.set(bytes=sum(a.nbytes for a in args))
             with tracing.span("engine.dispatch"):
-                logits, k, v = self._step(self.params, self.pool.k,
-                                          self.pool.v, *args)
-                self.pool.swap(k, v)
+                logits = self._seam.step(self, args)
             if self._mesh is not None:
                 # every shard just committed its slice of the donated
                 # scatter — the point where a multi-host straggler or
@@ -1400,15 +1644,14 @@ class InferenceEngine:
                 self._chaos("infer_shard_commit",
                             tp_shards=self.pool.heads_shards)
             with tracing.span("engine.fetch") as fetch:
-                logits = np.asarray(logits)
-                fetch.set(bytes=logits.nbytes)
+                logits, n_bytes = self._seam.fetch(self, logits)
+                fetch.set(bytes=n_bytes)
             with self._mlock:
                 self._decode_iterations += 1
                 self._occupancy_sum += (float(self._active.sum())
                                         / self.engine_cfg.max_slots)
             with tracing.span("engine.sample") as sample:
-                greedy = np.asarray(gpt.sample_token(logits,
-                                                     temperature=0.0))
+                greedy = self._seam.greedy(self, logits)
                 stepped = 0
                 for row in list(self._slot_req):
                     if not self._active[row]:   # prefilling rows ride along
@@ -1846,6 +2089,14 @@ class InferenceEngine:
                                     if lookup_toks else 0.0),
                 "preemptions": preemptions,
                 "peak_active_requests": peak,
+                # the second kind of state (zeros for a model that keeps
+                # none) and the routed experts' load (zeros for a model
+                # whose programs report none)
+                "state_bytes": pool["state_bytes"],
+                "state_rows_in_use": pool["state_rows_in_use"],
+                "expert_assignments_held": self._expert_held,
+                "expert_assignments_total": self._expert_total,
+                "expert_load_max": self._expert_load_max,
                 # fences remotely-advertised block ids across donated-
                 # pool recoveries (cluster prefix plane)
                 "pool_generation": pool["generation"],
@@ -1878,6 +2129,7 @@ def metrics_snapshot() -> list:
     admits, chunks, ptoks = {}, {}, {}
     tps, arate, saccept = {}, {}, {}
     meshdev, tpsh = {}, {}
+    sbytes, srows, eheld, etotal, emax = {}, {}, {}, {}, {}
     for name, eng in sorted(engines.items()):
         st = eng.stats()
         # per-replica/per-model labels (serve fleet sets them) keep a
@@ -1911,6 +2163,13 @@ def metrics_snapshot() -> list:
         # always exists and a sharded rollout shows up as a step change
         meshdev[key] = float(st.get("mesh_devices", 1))
         tpsh[key] = float(st.get("tp_shards", 1))
+        # the recurrent-state pool and the routed experts' load (zeros
+        # for a model with neither)
+        sbytes[key] = float(st.get("state_bytes", 0))
+        srows[key] = float(st.get("state_rows_in_use", 0))
+        eheld[key] = float(st.get("expert_assignments_held", 0))
+        etotal[key] = float(st.get("expert_assignments_total", 0))
+        emax[key] = float(st.get("expert_load_max", 0))
     zero = {(("engine", "none"),): 0.0}
     return [
         ("ray_tpu_inference_active_slots", "gauge",
@@ -1955,4 +2214,18 @@ def metrics_snapshot() -> list:
          "Tensor-parallel shards of the paged KV pool's heads dim "
          "(block counts are per-device AND global — heads are what's "
          "split)", tpsh or zero),
+        ("ray_tpu_inference_state_bytes", "gauge",
+         "Bytes of the per-row recurrent-state pool (0 = the model "
+         "keeps K/V only)", sbytes or zero),
+        ("ray_tpu_inference_state_rows_in_use", "gauge",
+         "Decode rows holding a recurrent state", srows or zero),
+        ("ray_tpu_inference_expert_assignments_held_total", "counter",
+         "(token, expert) assignments routed to experts held here",
+         eheld or zero),
+        ("ray_tpu_inference_expert_assignments_total", "counter",
+         "(token, expert) assignments routed to any expert",
+         etotal or zero),
+        ("ray_tpu_inference_expert_load_max_total", "counter",
+         "Assignments of the busiest held expert, summed over layers "
+         "and passes", emax or zero),
     ]

@@ -1,0 +1,205 @@
+"""Paged serving programs of a model with recurrent layers: the decode
+step and the chunk-prefill window of ``models/hybrid.py``.
+
+Both are the model's ONE layer function (``hybrid.run_layers`` over
+``hybrid.block``) at a serving shape — ``[rows, 1]`` and ``[1,
+chunk]`` — with the past supplied from the two kinds of pool the cache
+manager owns (inference/cache.py):
+
+  * an attention layer's keys come from the K/V ``BlockPool`` through
+    the row's block table, exactly as in decode.py's GPT programs
+    (``_write_then_read``: commit the window's K/V, then gather the
+    table; ``packed_attention`` attends the rows as stored, grouped
+    queries sharing their K/V head's lanes);
+  * a Mamba layer's convolution and SSM state come from the
+    ``StatePool`` at the window's decode rows and go back there.
+
+All four pool arrays are donated and updated in place.  Besides the
+logits each program returns ``load`` int32 — held expert assignments,
+all assignments, and the busiest held expert's assignments summed over
+layers, of the real tokens only, then the greedy tokens (the argmax of
+the logits, taken on the device): every row's from the decode step, the
+last real position's from the chunk.  A greedy pass then fetches ``3 +
+rows`` integers and a greedy first token one vector of 4; the ``[rows,
+vocab]`` logits never leave the device (12.8 MB a pass at 64 rows x
+50,176, to the host and back for the argmax: 8 of the 13.5 ms of host
+time a pass that the first chip runs of PR 29 read).
+
+What the host sends a pass is ONE int32 array (``pack_step`` /
+``pack_chunk``), handed to the program as the fresh numpy array it is:
+four ``jnp.asarray`` calls were 1.9 of a decode pass's 4.5 ms of host
+time on the chip, and a chunk's five 1.2 more, all of it with the device
+idle.
+
+The jitted functions are named ``step`` and ``chunk_fn`` like decode.py's
+(a device trace's program names are ``jit_step`` / ``jit_chunk_fn`` for
+either model family).  No mesh: one device holds one chip's share of the
+deployment (experts over an ``ep`` axis are future work).
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.inference.cache import PoolLayout
+from ray_tpu.inference.decode import _cached, _write_then_read
+from ray_tpu.models import hybrid
+from ray_tpu.models.hybrid import HybridConfig
+from ray_tpu.ops.attention import packed_attention
+
+
+def _paged_attend(cfg, lay, pools, bidx, off, tables, **mask):
+    """``attend_for(ai)`` over the K/V pools: returns the function and
+    the holder it leaves the updated pools in."""
+    held = {"pools": pools}
+
+    def attend_for(ai):
+        def attend(q, k, v):
+            held["pools"], (ctx_k, ctx_v) = _write_then_read(
+                lay, held["pools"], ai, bidx, off,
+                (k.reshape(*bidx.shape, *k.shape[2:]),
+                 v.reshape(*bidx.shape, *v.shape[2:])),
+                tables, None, None)
+            return packed_attention(
+                q, ctx_k, ctx_v, q_per_kv=cfg.n_heads // cfg.n_kv_heads,
+                scale=cfg.attention_multiplier, **mask)
+        return attend
+    return attend_for, held
+
+
+def pack_step(tables, tokens, positions, active) -> np.ndarray:
+    """The decode step's host inputs as one fresh int32 ``[b, T + 3]``:
+    a row's block table, then its token, position and whether it is
+    active."""
+    return np.concatenate(
+        [tables, tokens[:, None], positions[:, None], active[:, None]],
+        axis=1, dtype=np.int32)
+
+
+def pack_chunk(table, tokens, start: int, row: int,
+               n_valid: int) -> np.ndarray:
+    """The chunk program's host inputs as one fresh int32 ``[T + C +
+    3]``: the row's block table, the window's tokens, then the window's
+    first position, the decode row and the count of real tokens."""
+    return np.concatenate([table, tokens, (start, row, n_valid)],
+                          dtype=np.int32)
+
+
+def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
+                               n_table: int):
+    """jitted one-token step over the whole row batch.
+
+    (params, k_pool, v_pool, conv, ssm, packed [b, T + 3] int32
+     (``pack_step``: tables | tokens | positions | active))
+        -> (logits [b, vocab] f32, load + greedy [3 + b] int32,
+            k_pool, v_pool, conv, ssm)
+
+    Decode row r's state is row r of the state arrays.  An inactive row
+    (free, or still prefilling) is a window of 0 real tokens: its K/V
+    write goes to the scratch block and its state comes back unchanged.
+    """
+    bs, T = int(block_size), int(n_table)
+
+    def build():
+        @partial(jax.jit, donate_argnums=(1, 2, 3, 4))
+        def step(params, k_pool, v_pool, conv, ssm, packed):
+            tables, tokens, positions, active = (
+                packed[:, :T], packed[:, T], packed[:, T + 1],
+                packed[:, T + 2] != 0)
+            b = tokens.shape[0]
+            lay = PoolLayout.of(cfg, k_pool)
+            rows = jnp.arange(b)
+            bidx = jnp.where(active, tables[rows, positions // bs], 0)
+            off = jnp.where(active, positions % bs, 0)
+            kv_len = jnp.where(active, positions + 1, 1)      # >=1: no NaN
+            attend_for, kv = _paged_attend(
+                cfg, lay, (k_pool, v_pool), bidx, off, tables,
+                kv_lengths=kv_len)
+            state = {"conv": conv, "ssm": ssm}
+
+            def state_out(mi, new):
+                state["conv"] = state["conv"].at[mi].set(new[0])
+                state["ssm"] = state["ssm"].at[mi].set(new[1])
+
+            x, load = hybrid.run_layers(
+                cfg, params, hybrid.embed(cfg, params, tokens[:, None]),
+                active.astype(jnp.int32),
+                state_in=lambda mi: (state["conv"][mi], state["ssm"][mi]),
+                state_out=state_out, attend_for=attend_for)
+            logits = hybrid.head(cfg, params, x[:, 0])
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (logits, jnp.concatenate([load, greedy]), *kv["pools"],
+                    state["conv"], state["ssm"])
+
+        return step
+
+    return _cached(("recurrent_step", bs, T), cfg, None, None, build)
+
+
+def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
+                            block_size: int, n_table: int):
+    """jitted fixed-width prefill chunk of ONE row.
+
+    (params, k_pool, v_pool, conv, ssm, packed [T + C + 3] int32
+     (``pack_chunk``: table | tokens | start, row, n_valid))
+        -> (logits [C, vocab] f32, load + greedy [4] int32,
+            k_pool, v_pool, conv, ssm)
+
+    Prompt positions ``start .. start + n_valid`` of decode row ``row``:
+    attention as decode.py's chunk program (each query masked to its own
+    causal horizon over the gathered table), the Mamba layers as one
+    window from the row's state, which advances by the ``n_valid`` real
+    tokens only — the padding of a partial last chunk is the identity
+    on it.
+    """
+    bs, C, T = int(block_size), int(chunk), int(n_table)
+    S = T * bs
+
+    def build():
+        @partial(jax.jit, donate_argnums=(1, 2, 3, 4))
+        def chunk_fn(params, k_pool, v_pool, conv, ssm, packed):
+            table, tokens = packed[:T], packed[T:T + C]
+            start, row, n_valid = packed[T + C], packed[T + C + 1], \
+                packed[T + C + 2]
+            lay = PoolLayout.of(cfg, k_pool)
+            pos = start + jnp.arange(C, dtype=jnp.int32)
+            oob = pos >= S
+            safe = jnp.where(oob, 0, pos)
+            bidx = jnp.where(oob, 0, table[safe // bs])[None]   # [1, C]
+            off = jnp.where(oob, 0, pos % bs)[None]
+            mask = (jnp.arange(S)[None, :] <= pos[:, None])[None, None]
+            attend_for, kv = _paged_attend(
+                cfg, lay, (k_pool, v_pool), bidx, off, table[None],
+                mask=mask)
+            state = {"conv": conv, "ssm": ssm}
+
+            def state_in(mi):
+                # ONE dynamic slice of the pool: a static slice of the
+                # layer first is a 268 MB copy a layer on the chip
+                def row_of(pool):
+                    return jax.lax.dynamic_slice(
+                        pool, (mi, row) + (0,) * (pool.ndim - 2),
+                        (1, 1) + pool.shape[2:])[0]
+                return row_of(state["conv"]), row_of(state["ssm"])
+
+            def state_out(mi, new):
+                state["conv"] = state["conv"].at[mi, row].set(new[0][0])
+                state["ssm"] = state["ssm"].at[mi, row].set(new[1][0])
+
+            x, load = hybrid.run_layers(
+                cfg, params, hybrid.embed(cfg, params, tokens[None]),
+                n_valid[None], state_in=state_in, state_out=state_out,
+                attend_for=attend_for)
+            logits = hybrid.head(cfg, params, x[0])             # [C, V]
+            greedy = jnp.argmax(logits[jnp.maximum(n_valid, 1) - 1]
+                                ).astype(jnp.int32)
+            return (logits, jnp.append(load, greedy), *kv["pools"],
+                    state["conv"], state["ssm"])
+
+        return chunk_fn
+
+    return _cached(("recurrent_chunk", bs, T, C), cfg, None, None, build)

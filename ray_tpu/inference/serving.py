@@ -37,8 +37,7 @@ import jax
 
 from ray_tpu.inference.engine import (EngineConfig, EngineDrainingError,
                                       EngineStoppedError, InferenceEngine,
-                                      parse_priority)
-from ray_tpu.models import gpt
+                                      init_params_for, parse_priority)
 from ray_tpu.models.gpt import GPTConfig
 from ray_tpu.serve.deployment import (AutoscalingConfig, Deployment,
                                       DeploymentOptions)
@@ -71,7 +70,7 @@ class GPTServer:
     replica tag names the engine(s) and labels their /metrics series.
     """
 
-    def __init__(self, cfg: Optional[GPTConfig] = None,
+    def __init__(self, cfg=None,
                  engine_cfg: Optional[EngineConfig] = None,
                  seed: int = 0, params=None,
                  engine_name: Optional[str] = None,
@@ -127,7 +126,7 @@ class GPTServer:
     def _build_engine(self, model_id: Optional[str], seed: int,
                       params=None, name_override=None) -> InferenceEngine:
         if params is None:
-            params = gpt.init_params(self.cfg, jax.random.PRNGKey(seed))
+            params = init_params_for(self.cfg, jax.random.PRNGKey(seed))
         name = name_override
         if name is None and self.replica_tag:
             name = self.replica_tag + (f":{model_id}" if model_id else "")
@@ -356,7 +355,7 @@ class GPTServer:
 
 
 def build_gpt_deployment(*, name: str = DEFAULT_ROUTE,
-                         cfg: Optional[GPTConfig] = None,
+                         cfg=None,
                          engine_cfg: Optional[EngineConfig] = None,
                          seed: int = 0,
                          num_replicas: int = 1,

@@ -11,9 +11,11 @@ attention, bf16 matmuls on the MXU.
 
 from ray_tpu.models.bert import (BERT, BERTConfig)
 from ray_tpu.models.gpt import (GPT, GPTConfig)
+from ray_tpu.models.hybrid import HybridConfig
 from ray_tpu.models.mlp import (MLP, MLPConfig)
 from ray_tpu.models.resnet import (ResNet, ResNetConfig)
 from ray_tpu.models.zoo import (ActorCritic, ModelConfig)
 
-__all__ = ["BERT", "BERTConfig", "GPT", "GPTConfig", "MLP", "MLPConfig",
+__all__ = ["BERT", "BERTConfig", "GPT", "GPTConfig", "HybridConfig", "MLP",
+           "MLPConfig",
            "ResNet", "ResNetConfig", "ActorCritic", "ModelConfig"]

@@ -90,6 +90,14 @@ class GPTConfig:
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
 
+    # what a serving cache holds for this model (inference/cache.py)
+    @property
+    def kv_geometry(self) -> tuple:
+        """(layers that keep K/V, K/V heads, head size)."""
+        return (self.n_layers, self.n_heads, self.head_dim)
+
+    state_geometry = None            # no recurrent state beside the K/V
+
     @staticmethod
     def gpt2_124m(**kw) -> "GPTConfig":
         return GPTConfig(**{**dict(d_model=768, n_heads=12, n_layers=12,

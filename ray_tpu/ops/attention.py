@@ -63,7 +63,7 @@ def _masked_softmax(logits, kv_lengths, mask):
     return jax.nn.softmax(logits, axis=-1)
 
 
-def packed_attention(q, k, v, *, groups: int = 1,
+def packed_attention(q, k, v, *, groups: int = 1, q_per_kv: int = 1,
                      scale: Optional[float] = None,
                      mask: Optional[jax.Array] = None,
                      kv_lengths: Optional[jax.Array] = None) -> jax.Array:
@@ -88,15 +88,27 @@ def packed_attention(q, k, v, *, groups: int = 1,
     the sums are mha_reference's (same float32 logits, same rounding of
     the probabilities to ``v.dtype``); the MXU does ``h / groups`` times
     the arithmetic and the context is read once, where it lies.
+
+    Grouped queries (``q_per_kv`` > 1): k and v hold ``h / q_per_kv``
+    heads, each attended by ``q_per_kv`` consecutive query heads, whose
+    queries are all placed in that K/V head's lanes.
     """
     b, h, nq, hd = q.shape
     hg, wg = h // groups, k.shape[-1] // groups
     s = _scale_for(q, scale)
+    heads = jnp.arange(hg)[None, :]
     own = (jnp.arange(wg)[:, None] // hd
-           == jnp.arange(hg)[None, :])                    # [wg, hg]
-    ql = q.transpose(0, 2, 1, 3).reshape(b, nq, groups, hg * hd)
-    ql = jnp.pad(ql, [(0, 0)] * 3 + [(0, wg - hg * hd)])
-    qx = jnp.where(own, ql[..., None], 0).astype(q.dtype)  # [b,q,g,wg,hg]
+           == (heads if q_per_kv == 1 else heads // q_per_kv))  # [wg, hg]
+    if q_per_kv == 1:
+        ql = q.transpose(0, 2, 1, 3).reshape(b, nq, groups, hg * hd)
+        ql = jnp.pad(ql, [(0, 0)] * 3 + [(0, wg - hg * hd)])[..., None]
+    else:
+        # every K/V head's lanes hold a copy of each query; ``own`` keeps
+        # the copies that lie in the query's own K/V head
+        ql = q.reshape(b, groups, hg, nq, hd).transpose(0, 3, 1, 4, 2)
+        ql = jnp.tile(ql, (1, 1, 1, wg // hd, 1))         # [b,q,g,~wg,hg]
+        ql = jnp.pad(ql, [(0, 0)] * 3 + [(0, wg - ql.shape[3]), (0, 0)])
+    qx = jnp.where(own, ql, 0).astype(q.dtype)            # [b,q,g,wg,hg]
     kg = k.reshape(b, -1, groups, wg)
     vg = v.reshape(b, -1, groups, wg)
     logits = jnp.einsum("bkgw,bqgwh->bghqk", kg, qx,
@@ -105,9 +117,16 @@ def packed_attention(q, k, v, *, groups: int = 1,
     probs = probs.astype(v.dtype).reshape(b, groups, hg, nq, -1)
     full = jnp.einsum("bghqk,bkgw->bqgwh", probs, vg,
                       preferred_element_type=jnp.float32)
-    o = jnp.where(own, full, 0).sum(-1).astype(v.dtype)    # [b,q,g,wg]
-    o = o[..., :hg * hd].reshape(b, nq, h, hd)
-    return o.transpose(0, 2, 1, 3)
+    if q_per_kv == 1:
+        o = jnp.where(own, full, 0).sum(-1).astype(v.dtype)  # [b,q,g,wg]
+        o = o[..., :hg * hd].reshape(b, nq, h, hd)
+        return o.transpose(0, 2, 1, 3)
+    # head j's output lies in its K/V head's lanes of column j
+    kv = hg // q_per_kv
+    o = full[..., :kv * hd, :].reshape(b, nq, groups, kv, hd, kv, q_per_kv)
+    o = jnp.diagonal(o, axis1=3, axis2=5)                 # [b,q,g,hd,r,kv]
+    o = o.transpose(0, 2, 5, 4, 1, 3).reshape(b, h, nq, hd)
+    return o.astype(v.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, *,

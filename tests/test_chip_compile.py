@@ -303,3 +303,71 @@ def test_paged_decode_step_compiles_at_124m(one_chip):
         on_chip((rows, n_table), jnp.int32),
         on_chip((rows,), jnp.int32), on_chip((rows,), jnp.int32),
         on_chip((rows,), jnp.bool_)).compile(), lay)
+
+
+# ---- the second model family: K/V blocks AND a recurrent state pool
+
+@pytest.fixture(scope="module")
+def hybrid_cell(one_chip):
+    """The shapes of ``serve-granite-h-chat2k-r80``
+    (chipbench/configs/granite-4.0-h-small-10L-e36.json): one period of
+    published widths, 36 of 72 experts and half the vocabulary held, 64
+    rows, 9,216 + 1 blocks of 16, 144-block tables."""
+    from ray_tpu.models import hybrid
+    cfg = hybrid.HybridConfig(vocab_size=50176, experts_held=(0, 36),
+                              max_seq=2304)
+    on_chip = _on(one_chip)
+    rows, bs = 64, 16
+    lay = PoolLayout(*cfg.kv_geometry[:1], 9216 + 1, bs,
+                     *cfg.kv_geometry[1:])
+    assert lay.shape == (9217, 16, 1024)        # ONE K/V layer, 8 x 128
+    layers, conv, ssm = cfg.state_geometry
+    params = jax.tree.map(
+        lambda s: on_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda: hybrid.init_params(cfg,
+                                                  jax.random.PRNGKey(0))))
+    n_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                  for s in jax.tree.leaves(params))
+    assert 9.50e9 < n_bytes < 9.53e9            # 4,757 M bf16 parameters
+    return (cfg, on_chip, params, on_chip(lay.shape, cfg.dtype), lay,
+            on_chip((layers, rows, *conv), cfg.dtype),
+            on_chip((layers, rows, *ssm), jnp.float32), rows)
+
+
+def _assert_state_stays_put(compiled, ssm):
+    """The state pool is updated in place like the K/V pools: never
+    copied or re-laid-out whole, and donated through."""
+    text = compiled.as_text()
+    shape = "f32[" + ",".join(map(str, ssm.shape)) + "]"
+    assert not re.findall(re.escape(shape) + r"\S* copy(?:-start)?\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= int(np.prod(ssm.shape)) * 4
+    # a stacked expert tensor sliced per layer cost 432 MB of scratch a
+    # layer; per-layer arrays and in-place pools leave well under 1 GiB
+    assert mem.temp_size_in_bytes < 2 ** 30
+
+
+def test_hybrid_decode_step_fits_and_moves_no_pool(hybrid_cell):
+    from ray_tpu.inference.recurrent import make_recurrent_decode_step
+    cfg, on_chip, params, pool, lay, conv, ssm, rows = hybrid_cell
+    T = cfg.max_seq // lay.block_size
+    step = make_recurrent_decode_step(cfg, block_size=lay.block_size,
+                                      n_table=T)
+    compiled = step.lower(
+        params, pool, pool, conv, ssm,
+        on_chip((rows, T + 3), jnp.int32)).compile()
+    _assert_pool_stays_put(compiled, lay)
+    _assert_state_stays_put(compiled, ssm)
+
+
+def test_hybrid_chunk_prefill_fits_and_moves_no_pool(hybrid_cell):
+    from ray_tpu.inference.recurrent import make_recurrent_chunk_fn
+    cfg, on_chip, params, pool, lay, conv, ssm, rows = hybrid_cell
+    T = cfg.max_seq // lay.block_size
+    chunk = make_recurrent_chunk_fn(cfg, chunk=cfg.ssm_chunk,
+                                    block_size=lay.block_size, n_table=T)
+    compiled = chunk.lower(
+        params, pool, pool, conv, ssm,
+        on_chip((T + cfg.ssm_chunk + 3,), jnp.int32)).compile()
+    _assert_pool_stays_put(compiled, lay)
+    _assert_state_stays_put(compiled, ssm)

@@ -1,0 +1,501 @@
+"""The hybrid Mamba-2 / attention MoE family (models/hybrid.py) against
+its plain reference (chipbench/reference/hybrid_ssm_moe.py), at tiny
+widths on the CPU: pattern mamba-attention-mamba, 8 experts top-3, d 64.
+
+The ops agree with each other and with the definition; the model's one
+layer function agrees with the reference as a full forward, and as
+chunked prefill + decode through both caches of the engine; the expert
+layer's shares add up to the uncut layer; what a recurrent state makes
+impossible is refused by derivation.
+"""
+
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.reference import hybrid_ssm_moe as ref
+from ray_tpu.inference import (EngineConfig, InferenceEngine,
+                               SpeculationUnsupported, metrics_snapshot)
+from ray_tpu.inference import recurrent
+from ray_tpu.inference.cache import BlockPool, PoolLayout, StatePool
+from ray_tpu.models import hybrid
+from ray_tpu.ops import routed_experts as rx
+from ray_tpu.ops import ssm
+from ray_tpu.ops.attention import mha_reference, packed_attention
+
+CFG = hybrid.HybridConfig.tiny()
+# the same model under the published config's own key names (what the
+# reference reads)
+PUBLISHED = dict(
+    hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+    attention_multiplier=1 / 16, residual_multiplier=0.22,
+    embedding_multiplier=12.0, logits_scaling=16.0, rms_norm_eps=1e-5,
+    mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16, mamba_n_groups=1,
+    mamba_d_conv=4, mamba_chunk_size=8, mamba_expand=2,
+    num_local_experts=8, num_experts_per_tok=3, intermediate_size=32,
+    shared_intermediate_size=48, vocab_size=256,
+    max_position_embeddings=128,
+    layer_types=["mamba", "attention", "mamba"], num_hidden_layers=3)
+HELD = (0, 8)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return jax.jit(lambda k: hybrid.init_params(CFG, k))(
+        jax.random.PRNGKey(1))
+
+
+def _ssm_inputs(b, s, seed=0):
+    H, P, N = 4, 8, 16
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    return dict(
+        x=jax.random.normal(k[0], (b, s, H, P)),
+        dt=jax.nn.softplus(jax.random.normal(k[1], (b, s, H)) - 2),
+        A=-jnp.exp(jax.random.normal(k[2], (H,))),
+        B=jax.random.normal(k[3], (b, s, N)),
+        C=jax.random.normal(k[4], (b, s, N)), D=jnp.ones((H,)),
+        state=jax.random.normal(k[5], (b, H, P, N)))
+
+
+# ---------------------------------------------------------------- ops/ssm
+
+@pytest.mark.parametrize("s", [8, 13, 37])      # one chunk / partial / 4+5
+def test_window_scan_equals_recurrence(s):
+    a = _ssm_inputs(2, s)
+    y0, s0 = ssm.ssd_recurrence(**a)
+    y1, s1 = ssm.ssd_window(**a, n_valid=jnp.full((2,), s), chunk=8)
+    np.testing.assert_allclose(y1, y0, atol=2e-5)
+    np.testing.assert_allclose(s1, s0, atol=2e-5)
+
+
+def test_one_step_form_equals_recurrence():
+    a = _ssm_inputs(2, 11)
+    y0, s0 = ssm.ssd_recurrence(**a)
+    state, ys = a["state"], []
+    for t in range(11):
+        y, state = ssm.ssd(a["x"][:, t:t + 1], a["dt"][:, t:t + 1], a["A"],
+                           a["B"][:, t:t + 1], a["C"][:, t:t + 1], a["D"],
+                           state, jnp.ones((2,), jnp.int32), chunk=8)
+        ys.append(y)
+    np.testing.assert_allclose(jnp.concatenate(ys, 1), y0, atol=2e-5)
+    np.testing.assert_allclose(state, s0, atol=2e-5)
+
+
+def test_tokens_past_n_valid_leave_the_state_alone():
+    a = _ssm_inputs(2, 21)
+    n_valid = jnp.array([0, 13])
+    y, state = ssm.ssd_window(**a, n_valid=n_valid, chunk=8)
+    np.testing.assert_array_equal(state[0], a["state"][0])
+    cut = {k: (v[1:, :13] if k in ("x", "dt", "B", "C") else v)
+           for k, v in a.items()}
+    cut["state"] = a["state"][1:]
+    y13, s13 = ssm.ssd_recurrence(**cut)
+    np.testing.assert_allclose(y[1, :13], y13[0], atol=2e-5)
+    np.testing.assert_allclose(state[1], s13[0], atol=2e-5)
+    # the one-token form: a row that sits the pass out
+    _, st = ssm.ssd_step(a["x"][:, :1], a["dt"][:, :1], a["A"],
+                         a["B"][:, :1], a["C"][:, :1], a["D"], a["state"],
+                         jnp.array([0, 1]))
+    np.testing.assert_array_equal(st[0], a["state"][0])
+    assert not np.allclose(st[1], a["state"][1])
+
+
+def test_causal_conv_window_equals_token_by_token():
+    k = jax.random.split(jax.random.PRNGKey(3), 4)
+    b, s, C, K = 2, 9, 6, 4
+    x = jax.random.normal(k[0], (b, s, C))
+    w, bias = jax.random.normal(k[1], (K, C)), jax.random.normal(k[2], (C,))
+    st0 = jax.random.normal(k[3], (b, K - 1, C))
+    n_valid = jnp.array([9, 4])
+    y, st = ssm.causal_conv(x, st0, w, bias, n_valid)
+    state, ys = st0, []
+    for t in range(s):
+        yt, new = ssm.causal_conv(x[:, t:t + 1], state, w, bias,
+                                  (t < n_valid).astype(jnp.int32))
+        state = new
+        ys.append(yt)
+    np.testing.assert_allclose(jnp.concatenate(ys, 1)[0], y[0], atol=1e-6)
+    np.testing.assert_allclose(jnp.concatenate(ys, 1)[1, :4], y[1, :4],
+                               atol=1e-6)
+    np.testing.assert_allclose(state, st, atol=1e-6)
+    # the state after 4 real tokens is the last 3 of them
+    np.testing.assert_allclose(st[1], x[1, 1:4], atol=1e-6)
+
+
+# ------------------------------------------------------ ops/routed_experts
+
+def _expert_weights(seed=5, E=8, d=64, f=32):
+    k = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(k[0], (d, E)) * 0.1,
+            jax.random.normal(k[1], (E, d, 2 * f)) * 0.1,
+            jax.random.normal(k[2], (E, f, d)) * 0.1,
+            jax.random.normal(k[3], (40, d)))
+
+
+def test_every_token_to_one_expert_loses_none():
+    w_r, w_in, w_out, h = _expert_weights()
+    # the router sends everything to expert 5: dropless means all 40
+    # tokens are computed, none capped
+    w_r = jnp.zeros_like(w_r).at[:, 5].set(jnp.sign(h.sum(0)))
+    h = jnp.abs(h) * jnp.sign(h.sum(0))
+    out, counts, total = rx.routed_experts(h, w_r, w_in, w_out, top_k=1,
+                                           held=(0, 8))
+    assert counts.tolist() == [0, 0, 0, 0, 0, 40, 0, 0] and int(total) == 40
+    np.testing.assert_allclose(out, rx.gated_mlp(h, w_in[5], w_out[5]),
+                               atol=1e-5)
+
+
+def test_shares_add_up_to_the_uncut_layer(params):
+    """The share test: the routed parts of the two halves of the
+    experts, plus the shared expert counted ONCE, are the reference's
+    whole layer."""
+    fp = params["layers"][0]["ffn"]
+    h = jax.random.normal(jax.random.PRNGKey(9), (29, CFG.d_model))
+    parts, held_counts, totals = [], [], []
+    for lo, hi in ((0, 4), (4, 8)):
+        out, counts, total = rx.routed_experts(
+            h, fp["router"], fp["w_in"][lo:hi], fp["w_out"][lo:hi],
+            top_k=CFG.experts_per_token, held=(lo, hi))
+        parts.append(out)
+        held_counts.append(int(counts.sum()))
+        totals.append(int(total))
+    shared = rx.gated_mlp(h, fp["shared_in"], fp["shared_out"])
+    with jax.default_matmul_precision("highest"):
+        whole = ref._experts(PUBLISHED, fp, h, HELD, None)
+    np.testing.assert_allclose(parts[0] + parts[1] + shared, whole,
+                               atol=1e-5)
+    assert sum(held_counts) == totals[0] == totals[1] == 29 * 3
+    # and a share alone is the reference given the same share
+    with jax.default_matmul_precision("highest"):
+        half = ref._experts(
+            PUBLISHED, {**fp, "w_in": fp["w_in"][:4],
+                        "w_out": fp["w_out"][:4]}, h, (0, 4), None)
+    np.testing.assert_allclose(parts[0] + shared, half, atol=1e-5)
+
+
+def test_padding_routes_but_is_not_counted():
+    w_r, w_in, w_out, h = _expert_weights()
+    valid = jnp.arange(40) < 25
+    out, counts, total = rx.routed_experts(h, w_r, w_in[2:6], w_out[2:6],
+                                           top_k=3, held=(2, 6),
+                                           valid=valid)
+    experts, _ = rx.route(h[:25], w_r, 3)
+    want = [(np.asarray(experts) == e).sum() for e in range(2, 6)]
+    assert counts.tolist() == want and int(total) == 75
+    assert out.shape == h.shape
+
+
+# ------------------------------------------------------------ attention
+
+def test_packed_attention_grouped_queries():
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    b, h, kv, nq, S, hd = 2, 8, 2, 3, 20, 16
+    q = jax.random.normal(k[0], (b, h, nq, hd))
+    K = jax.random.normal(k[1], (b, S, kv * hd))
+    V = jax.random.normal(k[2], (b, S, kv * hd))
+    lens = jnp.array([7, 20])
+    got = packed_attention(q, K, V, q_per_kv=h // kv, scale=0.1,
+                           kv_lengths=lens)
+
+    def heads(t):
+        return jnp.repeat(t.reshape(b, S, kv, hd).transpose(0, 2, 1, 3),
+                          h // kv, 1)
+    want = mha_reference(q, heads(K), heads(V), causal=False, scale=0.1,
+                         kv_lengths=lens)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+# ------------------------------------------------------------- the caches
+
+def test_pool_layout_from_kv_geometry():
+    big = hybrid.HybridConfig(vocab_size=512, experts_held=(0, 1))
+    assert big.kv_geometry == (1, 8, 128)
+    lay = PoolLayout(*big.kv_geometry[:1], 5, 16, *big.kv_geometry[1:])
+    assert lay.width == 1024 and lay.shape == (5, 16, 1024)
+    pool = BlockPool(CFG, n_blocks=12, block_size=8, max_seq=96,
+                     state_rows=3)
+    # K/V on the ONE attention layer, 2 K/V heads of 16
+    assert pool.layout.n_layers == 1 and pool.layout.n_heads == 2
+    assert PoolLayout.of(CFG, pool.k) == pool.layout
+
+
+def test_state_pool_lifecycle_and_bytes():
+    pool = BlockPool(CFG, n_blocks=12, block_size=8, max_seq=96,
+                     state_rows=3)
+    st = pool.state
+    assert isinstance(st, StatePool)
+    assert st.conv.shape == (2, 3, 3, 8 * 16 + 2 * 16)
+    assert st.ssm.shape == (2, 3, 8 * 16, 16) and st.ssm.dtype == jnp.float32
+    kv_bytes = 2 * int(np.prod(pool.layout.shape)) * 4
+    assert pool.bytes_total() == kv_bytes + st.bytes_total()
+    assert st.bytes_total() == st.conv.nbytes + st.ssm.nbytes
+    st.swap(st.conv + 1, st.ssm + 1)
+    st.admit(1)
+    assert st.rows_in_use == 1 and pool.stats()["state_rows_in_use"] == 1
+    assert float(jnp.abs(st.ssm[:, 1]).max()) == 0.0
+    assert float(jnp.abs(st.conv[:, 1]).max()) == 0.0
+    assert float(st.ssm[:, 0].min()) == 1.0          # neighbours untouched
+    st.release(1)
+    assert st.rows_in_use == 0
+    st.admit(2)
+    pool.reset()
+    assert pool.state.rows_in_use == 0
+    assert float(jnp.abs(pool.state.ssm).max()) == 0.0
+    # a model that keeps K/V only has no state pool
+    from ray_tpu.models.gpt import GPTConfig
+    assert BlockPool(GPTConfig.tiny(), 16, 8).state is None
+
+
+# ---------------------------------------------------- model vs reference
+
+def _ref_logits(params, seq, **kw):
+    return np.asarray(ref.logits(params, np.asarray(seq), PUBLISHED, HELD,
+                                 **kw))
+
+
+def test_from_published_keys():
+    cfg = hybrid.HybridConfig.from_published(
+        PUBLISHED, dtype=jnp.float32, param_dtype=jnp.float32)
+    assert cfg == CFG
+
+
+def test_forward_logits_equal_reference(params):
+    toks = jax.random.randint(jax.random.PRNGKey(2), (2, 37), 0, 256)
+    got = np.asarray(hybrid.forward(params, toks, CFG))
+    for b in range(2):
+        np.testing.assert_allclose(got[b], _ref_logits(params, toks[b]),
+                                   atol=2e-6)
+
+
+def test_reference_in_lower_precision_differs(params):
+    """``round_to`` is the reading a serving tolerance has to reject."""
+    toks = np.arange(40) % 256
+    full = _ref_logits(params, toks)
+    low = _ref_logits(params, toks, round_to=jnp.float8_e4m3fn)
+    assert np.abs(full - low).max() > 50 * np.abs(
+        full - np.asarray(hybrid.forward(params, toks[None], CFG))[0]).max()
+
+
+def test_programs_chunks_then_decode_equal_reference_logits(params):
+    """The chunk program over a prompt (a partial last chunk), then the
+    decode program token by token, both through the K/V pool and the
+    state pool: every position's logits are the reference's."""
+    bs, C, n_rows = 8, 8, 3
+    pool = BlockPool(CFG, n_blocks=12, block_size=bs, max_seq=96,
+                     state_rows=n_rows)
+    T = pool.blocks_per_seq
+    step = recurrent.make_recurrent_decode_step(CFG, block_size=bs,
+                                                n_table=T)
+    chunk = recurrent.make_recurrent_chunk_fn(CFG, chunk=C, block_size=bs,
+                                              n_table=T)
+    seq = np.asarray(jax.random.randint(jax.random.PRNGKey(4), (30,), 0,
+                                        256))
+    n_prompt, row = 21, 1
+    want = _ref_logits(params, seq)
+    table = np.zeros(T, np.int32)
+    table[:4] = [3, 7, 2, 9]
+    k, v, conv, s_ = pool.k, pool.v, pool.state.conv, pool.state.ssm
+    # neighbours' state must come back untouched
+    s_ = s_.at[:, 0].set(1.5)
+    for pos in range(0, n_prompt, C):
+        n_q = min(C, n_prompt - pos)
+        toks = np.zeros(C, np.int32)
+        toks[:n_q] = seq[pos:pos + n_q]
+        logits, load, k, v, conv, s_ = chunk(
+            params, k, v, conv, s_,
+            recurrent.pack_chunk(table, toks, pos, row, n_q))
+        np.testing.assert_allclose(np.asarray(logits)[:n_q],
+                                   want[pos:pos + n_q], atol=1e-5)
+        assert load.tolist()[:2] == [n_q * 3 * 3] * 2
+        # the last REAL position's greedy token rides with the load
+        assert int(load[3]) == int(np.asarray(logits)[n_q - 1].argmax())
+    tables = np.zeros((n_rows, T), np.int32)
+    tables[row] = table
+    active = np.zeros(n_rows, bool)
+    active[row] = True
+    for pos in range(n_prompt, 30):
+        tokens = np.zeros(n_rows, np.int32)
+        positions = np.zeros(n_rows, np.int32)
+        tokens[row], positions[row] = seq[pos], pos
+        logits, load, k, v, conv, s_ = step(
+            params, k, v, conv, s_,
+            recurrent.pack_step(tables, tokens, positions, active))
+        np.testing.assert_allclose(np.asarray(logits)[row], want[pos],
+                                   atol=1e-5)
+        assert load.tolist()[:2] == [9, 9]       # 1 token x top-3 x 3 layers
+    np.testing.assert_array_equal(np.asarray(s_[:, 0]), 1.5)
+    assert float(jnp.abs(s_[:, 2]).max()) == 0.0
+
+
+# ------------------------------------------------------ through the engine
+
+def _margins(params, prompt, emitted):
+    """How far each emitted token's reference logit lies below that
+    position's maximum (teacher-forced full forward)."""
+    seq = np.asarray(list(prompt) + list(emitted))
+    step = _ref_logits(params, seq)[len(prompt) - 1:len(seq) - 1]
+    return step.max(-1) - step[np.arange(len(emitted)), emitted]
+
+
+def _engine(params, **kw):
+    ec = dict(max_slots=3, max_seq=96, n_blocks=14, kv_block_size=8,
+              prefill_chunk=8)
+    return InferenceEngine(params, CFG, EngineConfig(**{**ec, **kw}))
+
+
+def test_engine_rows_admitted_at_different_times(params):
+    """Continuous batching: rows join while others decode, one finishes
+    mid-batch; every emitted token is the reference's argmax."""
+    eng = _engine(params)
+    rng = np.random.default_rng(0)
+    plan = [(5, 6), (19, 10), (33, 3), (8, 12), (27, 7)]
+    prompts = [rng.integers(0, 256, n).tolist() for n, _ in plan]
+    reqs = []
+    for p, (_, m) in zip(prompts, plan):
+        reqs.append(eng.submit(p, max_new=m))
+        time.sleep(0.05)
+    outs = [r.result(timeout=300) for r in reqs]
+    st = eng.stats()
+    eng.shutdown()
+    for p, o, (_, m) in zip(prompts, outs, plan):
+        assert len(o) == m
+        assert _margins(params, p, o).max() <= 1e-5
+    tokens = sum(n + m - 1 for n, m in plan)
+    assert st["expert_assignments_total"] == tokens * 3 * 3
+    assert st["expert_assignments_held"] == st["expert_assignments_total"]
+    assert st["expert_load_max"] >= st["expert_assignments_held"] / 8
+    assert st["state_rows_in_use"] == 0 and st["state_bytes"] > 0
+    assert st["cache_bytes"] > st["state_bytes"]
+    assert st["prefix_hit_tokens"] == 0 and st["chunk_passes"] >= 12
+
+
+def test_first_token_behind_a_running_decode(params):
+    """A prompt that ends while other rows decode: its first token is
+    not waited for before the pass's decode step is dispatched, the row
+    joins the batch a pass later, and a request that its first token
+    ends never decodes.  Streams are the reference's, token for token."""
+    eng = _engine(params)
+    rng = np.random.default_rng(3)
+    long_ = rng.integers(0, 256, 6).tolist()
+    first = eng.submit(long_, max_new=40)
+    it = first.stream(timeout=300)
+    head = [next(it) for _ in range(3)]           # it is decoding now
+    plan = [(11, 1), (17, 5), (4, 1)]
+    prompts = [rng.integers(0, 256, n).tolist() for n, _ in plan]
+    reqs = [eng.submit(p, max_new=m) for p, (_, m) in zip(prompts, plan)]
+    outs = [r.result(timeout=300) for r in reqs]
+    whole = head + list(it)
+    assert eng._first_pending == [] and eng.stats()["active_slots"] == 0
+    eng.shutdown()
+    assert len(whole) == 40 and _margins(params, long_, whole).max() <= 1e-5
+    for p, o, (_, m) in zip(prompts, outs, plan):
+        assert len(o) == m and _margins(params, p, o).max() <= 1e-5
+
+
+def test_engine_preemption_and_re_prefill(params):
+    """A pool too small for all rows: the youngest is preempted, drops
+    its state with its blocks, re-prefills from zero and continues its
+    stream exactly."""
+    eng = _engine(params, n_blocks=12, max_slots=3)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (30, 28, 26)]
+    reqs = [eng.submit(p, max_new=24) for p in prompts]
+    outs = [r.result(timeout=300) for r in reqs]
+    st = eng.stats()
+    eng.shutdown()
+    assert st["preemptions"] >= 1
+    for p, o in zip(prompts, outs):
+        assert len(o) == 24 and _margins(params, p, o).max() <= 1e-5
+
+
+def test_recurrent_family_refuses_by_derivation(params):
+    eng = _engine(params, prefix_cache=True)
+    try:
+        assert eng.trie is None            # nothing is ever adopted
+        a = list(range(40))
+        eng.generate(a, max_new=2, timeout=300)
+        eng.generate(a, max_new=2, timeout=300)
+        assert eng.stats()["prefix_hit_tokens"] == 0
+    finally:
+        eng.shutdown()
+    for mode in ("ngram", "self"):
+        with pytest.raises(SpeculationUnsupported):
+            _engine(params, speculate=mode)
+    with pytest.raises(ValueError):
+        _engine(params, paged=False)
+
+
+def test_new_counters_are_exported(params):
+    eng = _engine(params)
+    try:
+        eng.generate([1, 2, 3], max_new=3, timeout=300)
+        names = {m[0]: m for m in metrics_snapshot()}
+        for name in ("ray_tpu_inference_state_bytes",
+                     "ray_tpu_inference_state_rows_in_use",
+                     "ray_tpu_inference_expert_assignments_held_total",
+                     "ray_tpu_inference_expert_assignments_total",
+                     "ray_tpu_inference_expert_load_max_total"):
+            assert name in names
+        key = next(k for k in
+                   names["ray_tpu_inference_expert_assignments_total"][3]
+                   if dict(k).get("engine") == eng.name)
+        assert names["ray_tpu_inference_expert_assignments_total"][3][key] \
+            == 5 * 3 * 3
+    finally:
+        eng.shutdown()
+    # a model that keeps K/V only reports zeros under the same keys
+    from ray_tpu.models import gpt
+    cfg = gpt.GPTConfig.tiny()
+    eng = InferenceEngine(gpt.init_params(cfg, jax.random.PRNGKey(0)), cfg,
+                          EngineConfig(max_slots=2))
+    try:
+        eng.generate([1, 2, 3], max_new=2, timeout=300)
+        st = eng.stats()
+        assert st["state_bytes"] == st["expert_assignments_total"] == 0
+    finally:
+        eng.shutdown()
+
+
+def test_served_through_the_deployment(params):
+    """The same server class and builder as GPT: ``serve.run`` of
+    ``build_gpt_deployment(cfg=<hybrid>)``."""
+    from ray_tpu import serve
+    from ray_tpu.inference import build_gpt_deployment
+    handle = serve.run(
+        build_gpt_deployment(
+            name="hy", cfg=CFG, params=params, warm_on_init=True,
+            engine_cfg=EngineConfig(max_slots=2, max_seq=96, n_blocks=12,
+                                    kv_block_size=8, prefill_chunk=8)),
+        use_actors=False)
+    try:
+        prompt = list(range(3, 20))
+        got = handle.remote({"prompt": prompt, "max_tokens": 5}).result(
+            timeout=300)
+        assert _margins(params, prompt, got["tokens"]).max() <= 1e-5
+        st = handle.options(method_name="engine_stats").remote().result(
+            timeout=30)
+        assert st["state_bytes"] > 0
+    finally:
+        serve.shutdown()
+
+
+def test_sampled_rows_beside_greedy_rows(params):
+    """A greedy pass fetches tokens, not logits (they stay on the
+    device); a sampled row indexes them there with its own rng: the same
+    seed gives the same stream, and its greedy neighbour stays exact."""
+    outs = []
+    for _ in range(2):
+        eng = _engine(params)
+        try:
+            hot = eng.submit(list(range(9)), max_new=8, temperature=0.9,
+                             seed=5)
+            cold = eng.submit(list(range(20, 31)), max_new=8)
+            outs.append((hot.result(timeout=300), cold.result(timeout=300)))
+        finally:
+            eng.shutdown()
+    assert outs[0] == outs[1]
+    assert _margins(params, list(range(20, 31)), outs[0][1]).max() <= 1e-5
+    assert _margins(params, list(range(9)), outs[0][0]).max() > 1e-5
