@@ -208,7 +208,8 @@ def _make_step(cfg, mesh, rules, h, hd):
     def step(params, k_cache, v_cache, tokens, positions, active):
         b = tokens.shape[0]
         S = k_cache.shape[3]
-        x = (params["wte"][tokens] + params["wpe"][positions])
+        x = (gpt._token_rows(params, tokens, cfg)
+             + params["wpe"][positions])
         x = x[:, None, :].astype(cfg.dtype)               # [b, 1, d]
         # one-hot write mask on the position axis, zeroed for parked slots
         write = ((jnp.arange(S)[None, :] == positions[:, None])
@@ -323,7 +324,8 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
             lay = PoolLayout.of(cfg, k_pool, shards)
             k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
             v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
-            x = (params["wte"][tokens] + params["wpe"][positions])
+            x = (gpt._token_rows(params, tokens, cfg)
+                 + params["wpe"][positions])
             x = x[:, None, :].astype(cfg.dtype)               # [b, 1, d]
             rows = jnp.arange(b)
             bidx = jnp.where(active, tables[rows, positions // bs], 0)
@@ -413,7 +415,8 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
             pos = start + jnp.arange(C, dtype=jnp.int32)       # [C]
             oob = pos >= S
             wpe_pos = jnp.clip(pos, 0, cfg.max_seq - 1)
-            x = (params["wte"][tokens] + params["wpe"][wpe_pos])
+            x = (gpt._token_rows(params, tokens, cfg)
+                 + params["wpe"][wpe_pos])
             x = x[None, :, :].astype(cfg.dtype)                # [1, C, d]
             safe = jnp.where(oob, 0, pos)
             bidx = jnp.where(oob, 0, table[safe // bs])
@@ -518,7 +521,8 @@ def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,
             live = ((jnp.arange(W)[None, :] < n_tokens[:, None])
                     & active[:, None] & (pos < S))        # real lanes
             wpe_pos = jnp.clip(pos, 0, cfg.max_seq - 1)
-            x = (params["wte"][tokens] + params["wpe"][wpe_pos])
+            x = (gpt._token_rows(params, tokens, cfg)
+                 + params["wpe"][wpe_pos])
             x = x.astype(cfg.dtype)                       # [b, W, d]
             safe = jnp.where(live, pos, 0)
             bidx = jnp.where(live, tables[rows[:, None], safe // bs], 0)
@@ -632,7 +636,7 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
             def step(carry, j):
                 cur, pos, pools = carry
                 live = (want > j) & (pos < S)
-                x = (params["wte"][cur]
+                x = (gpt._token_rows(params, cur, cfg)
                      + params["wpe"][jnp.clip(pos, 0, cfg.max_seq - 1)])
                 x = x[:, None, :].astype(cfg.dtype)           # [b, 1, d]
                 safe = jnp.where(live, pos, 0)

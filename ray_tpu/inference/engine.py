@@ -359,6 +359,15 @@ class _KVOnly:
     an abandoned engine must die by reference count alone)."""
 
     @staticmethod
+    def serve(params, cfg):
+        """-> (the tree the programs are handed every pass, its logical
+        axes, the leaves of it that a program casts to ``cfg.dtype`` at
+        use).  Float32 masters are cast ONCE, here, not once a pass."""
+        served = gpt.serving_params(params, cfg)
+        return (served, gpt.param_logical_axes(cfg, served=True),
+                gpt.cast_at_use(served))
+
+    @staticmethod
     def build(eng, bs: int) -> None:
         cfg, ec, mesh, rules = (eng.cfg, eng.engine_cfg, eng._mesh,
                                 eng._rules)
@@ -451,6 +460,13 @@ class _KVAndState:
         if mesh is not None:
             raise ValueError("a model with recurrent layers is served "
                              "on one device (no sharding rules yet)")
+
+    @staticmethod
+    def serve(params, cfg):
+        """The family's weights are published and held in ``cfg.dtype``
+        and fill the chip: the tree is served as given, every leaf the
+        array it was (no mesh, so no axes)."""
+        return params, None, hybrid.cast_at_use(params)
 
     @staticmethod
     def build(eng, bs: int) -> None:
@@ -602,15 +618,19 @@ class InferenceEngine:
         self._seam = _KVAndState if recurrent else _KVOnly
         if recurrent:
             _KVAndState.refuse(ec, mesh)
+        # what the programs are handed every pass; the engine keeps no
+        # reference to a leaf that ``serve`` replaced
+        self.params, axes, cast = self._seam.serve(params, cfg)
+        self._weight_bytes = sum(
+            p.nbytes for p in jax.tree.leaves(self.params))
+        self._weight_bytes_cast = sum(
+            p.nbytes for p in cast if p.dtype != jnp.dtype(cfg.dtype))
         if mesh is not None:
             # shard the weights to match the annotated step bodies
             # (heads/mlp/qkv/vocab over tp per the rules) so the first
             # compiled call doesn't start from fully-replicated params
             self.params = jax.device_put(
-                params, tree_shardings(gpt.param_logical_axes(cfg),
-                                       rules, mesh))
-        else:
-            self.params = params
+                self.params, tree_shardings(axes, rules, mesh))
         n = ec.max_slots
         self._paged = bool(ec.paged)
         self._spec = ec.speculate
@@ -2060,6 +2080,11 @@ class InferenceEngine:
                           if self._mesh is not None else {}),
             "tp_shards": (self.pool.heads_shards
                           if self._paged and self.pool is not None else 1),
+            # the tree the programs are handed, and how much of it they
+            # cast to the dtype they compute in EVERY pass (0: each such
+            # leaf is stored in it; by shapes and dtypes at construction)
+            "weight_bytes": self._weight_bytes,
+            "weight_bytes_cast_per_pass": self._weight_bytes_cast,
         }
         if self._paged:
             pool = self.pool.stats()
@@ -2130,6 +2155,7 @@ def metrics_snapshot() -> list:
     tps, arate, saccept = {}, {}, {}
     meshdev, tpsh = {}, {}
     sbytes, srows, eheld, etotal, emax = {}, {}, {}, {}, {}
+    wbytes, wcast = {}, {}
     for name, eng in sorted(engines.items()):
         st = eng.stats()
         # per-replica/per-model labels (serve fleet sets them) keep a
@@ -2170,6 +2196,8 @@ def metrics_snapshot() -> list:
         eheld[key] = float(st.get("expert_assignments_held", 0))
         etotal[key] = float(st.get("expert_assignments_total", 0))
         emax[key] = float(st.get("expert_load_max", 0))
+        wbytes[key] = float(st["weight_bytes"])
+        wcast[key] = float(st["weight_bytes_cast_per_pass"])
     zero = {(("engine", "none"),): 0.0}
     return [
         ("ray_tpu_inference_active_slots", "gauge",
@@ -2228,4 +2256,10 @@ def metrics_snapshot() -> list:
         ("ray_tpu_inference_expert_load_max_total", "counter",
          "Assignments of the busiest held expert, summed over layers "
          "and passes", emax or zero),
+        ("ray_tpu_inference_weight_bytes", "gauge",
+         "Bytes of the parameter tree the programs are handed",
+         wbytes or zero),
+        ("ray_tpu_inference_weight_bytes_cast_per_pass", "gauge",
+         "Bytes of weights a program casts to its compute dtype every "
+         "pass (0 = each is stored in it)", wcast or zero),
     ]

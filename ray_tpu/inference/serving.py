@@ -125,8 +125,6 @@ class GPTServer:
 
     def _build_engine(self, model_id: Optional[str], seed: int,
                       params=None, name_override=None) -> InferenceEngine:
-        if params is None:
-            params = init_params_for(self.cfg, jax.random.PRNGKey(seed))
         name = name_override
         if name is None and self.replica_tag:
             name = self.replica_tag + (f":{model_id}" if model_id else "")
@@ -138,8 +136,12 @@ class GPTServer:
             kw["mesh"] = self.mesh
             if self.rules is not None:
                 kw["rules"] = self.rules
-        eng = InferenceEngine(params, self.cfg, self.engine_cfg,
-                              name=name, labels=labels, **kw)
+        # masters made here are held by nobody once the engine has
+        # derived the tree it serves (gpt.serving_params)
+        eng = InferenceEngine(
+            params if params is not None
+            else init_params_for(self.cfg, jax.random.PRNGKey(seed)),
+            self.cfg, self.engine_cfg, name=name, labels=labels, **kw)
         if self._warm:
             # compile prefill+decode off the request path, so a freshly
             # scaled-up replica doesn't serve its first requests cold

@@ -38,6 +38,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from ray_tpu.ops.attention import attention
+from ray_tpu.ops.flash_attention import LANES
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import (DEFAULT_LLM_RULES, Rules, spec_for)
 
@@ -154,11 +155,13 @@ MOE_MLP_AXES = {
 }
 
 
-def param_logical_axes(cfg: GPTConfig):
+def param_logical_axes(cfg: GPTConfig, *, served: bool = False):
+    """Logical axes of ``init_params``' tree, or with ``served`` of
+    ``serving_params``' (which always carries the head's matrix)."""
     axes = dict(PARAM_AXES)
     if cfg.n_experts:
         axes["layers"] = {**axes["layers"], **MOE_MLP_AXES}
-    if not cfg.tie_embeddings:
+    if served or not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
 
@@ -210,6 +213,62 @@ def init_params(cfg: GPTConfig, rng: jax.Array):
     if not cfg.tie_embeddings:
         params["lm_head"] = norm(next(k), (d, cfg.vocab_size))
     return params
+
+
+# the per-layer leaves every program multiplies or adds in ``cfg.dtype``
+# (``.astype(cfg.dtype)`` at use, in _transformer_layer, _moe_mlp and
+# inference/decode.py's bodies); with the head's matrix, all of them
+CAST_AT_USE = ("wqkv", "wo", "bo", "w_up", "b_up", "w_down", "b_down")
+
+
+def cast_at_use(params) -> list:
+    """The leaves of a tree that a serving program casts to
+    ``cfg.dtype`` where it uses them (a no-op on a leaf stored so): the
+    ``CAST_AT_USE`` leaves and the head's matrix, which without a leaf
+    of its own is the whole embedding table."""
+    head = params["lm_head"] if "lm_head" in params else params["wte"]
+    return [params["layers"][name] for name in CAST_AT_USE] + [head]
+
+
+def serving_params(params, cfg: GPTConfig):
+    """The tree a serving engine hands its programs every pass, derived
+    ONCE: each ``cast_at_use`` leaf stored in ``cfg.dtype``, so that no
+    program begins by casting the stacked weights (at GPT-2 XL 6.1 GB
+    of float32 read and 3.1 GB of bfloat16 written, every decode and
+    every chunk program).  The products take the same rounding of the
+    same weight either way: same bits out.
+
+    Kept in their own precision: ``wte`` and ``wpe`` (the programs add
+    them in it and THEN round; a rounded table would round twice), the
+    LayerNorm leaves (``_layer_norm`` computes in float32), ``w_router``
+    (cast to float32 at use); all but ``wte`` by identity.  A leaf
+    already in ``cfg.dtype`` is returned as the same array.
+
+    The head's matrix is always a leaf of its own, ``lm_head`` [d, V]
+    (``_head`` takes it where a tree has one): with a tied embedding
+    ``wte`` transposed and rounded.  ``wte`` is then only the table the
+    programs gather token rows from, and its rows are padded with zeros
+    to whole lanes (``_token_rows`` drops them): the chip keeps a
+    ``[V, 1600]`` array column-major, a width of 1600 being no multiple
+    of its 128 lanes, and every program began by re-tiling the table
+    (322 MB in, 322 MB out) to gather 32 rows of it.  A width of whole
+    lanes (768, or 1600 -> 1664) has the one row-major layout; such a
+    ``wte`` is returned as the same array."""
+    dt = jnp.dtype(cfg.dtype)
+
+    def stored(a):
+        return a if a.dtype == dt else a.astype(dt)
+
+    layers = dict(params["layers"])
+    for name in CAST_AT_USE:
+        layers[name] = stored(layers[name])
+    wte = params["wte"]
+    head = (stored(params["lm_head"]) if "lm_head" in params
+            else jax.jit(lambda w: w.T.astype(dt))(wte))
+    pad = -wte.shape[1] % LANES
+    if pad:
+        wte = jnp.pad(wte, ((0, 0), (0, pad)))
+    return {**params, "wte": wte, "layers": layers, "lm_head": head}
 
 
 def num_params(params) -> int:
@@ -401,16 +460,23 @@ def _layer_scan_body(cfg: GPTConfig, mesh, rules, return_kv: bool = False):
     return layer
 
 
+def _token_rows(params, tokens, cfg: GPTConfig):
+    """``wte``'s rows for ``tokens``, in the table's precision (a served
+    table's rows are padded to whole lanes, see ``serving_params``)."""
+    return params["wte"][tokens][..., :cfg.d_model]
+
+
 def _embed(params, tokens, cfg: GPTConfig, mesh, rules):
     s = tokens.shape[1]
-    x = params["wte"][tokens] + params["wpe"][:s][None, :, :]
+    x = _token_rows(params, tokens, cfg) + params["wpe"][:s][None, :, :]
     x = x.astype(cfg.dtype)
     return _constrain(x, ("batch", "seq", "embed"), mesh, rules)
 
 
 def _head(params, x, cfg: GPTConfig, mesh, rules):
     x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-    w_out = (params["wte"].T if cfg.tie_embeddings else params["lm_head"])
+    # a served tree (serving_params) brings the tied head's matrix too
+    w_out = (params["lm_head"] if "lm_head" in params else params["wte"].T)
     logits = jnp.einsum("bsd,dv->bsv", x, w_out.astype(cfg.dtype))
     logits = _constrain(logits, ("batch", "seq", "vocab"), mesh, rules)
     return logits.astype(jnp.float32)

@@ -249,6 +249,20 @@ def num_params(params) -> int:
     return sum(int(math.prod(p.shape)) for p in jax.tree.leaves(params))
 
 
+def cast_at_use(params) -> list:
+    """The leaves a program casts to the activations' dtype where it
+    multiplies by them: every matrix (the convolution's taps are used in
+    float32).  The family is published and held in that dtype, so the
+    casts are no-ops and the tree is served as it is."""
+    out = [params["wte"]]
+    for lp in params["layers"]:
+        out += [lp["mixer"][n] for n in ("in_proj", "out_proj", "wqkv", "wo")
+                if n in lp["mixer"]]
+        out += [lp["ffn"][n] for n in ("router", "shared_in", "shared_out",
+                                       "w_in", "w_out")]
+    return out
+
+
 # -- the layer ---------------------------------------------------------------
 
 def _rms_norm(x, w, eps):

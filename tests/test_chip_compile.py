@@ -172,12 +172,14 @@ def _pool_of(cfg, n_blocks, bs, on_chip):
 
 
 def _params_of(cfg, place):
-    """The model's parameters as shapes, each put by ``place(shape,
-    dtype, logical axes)``."""
+    """The parameters an engine hands its programs (float32 masters
+    through ``gpt.serving_params``) as shapes, each put by
+    ``place(shape, dtype, logical axes)``."""
     return jax.tree.map(
         lambda axes, s: place(s.shape, s.dtype, axes),
-        gpt.param_logical_axes(cfg),
-        jax.eval_shape(lambda: gpt.init_params(cfg, jax.random.PRNGKey(0))),
+        gpt.param_logical_axes(cfg, served=True),
+        jax.eval_shape(lambda: gpt.serving_params(
+            gpt.init_params(cfg, jax.random.PRNGKey(0)), cfg)),
         is_leaf=lambda x: isinstance(x, tuple))
 
 
@@ -220,24 +222,68 @@ def xl(one_chip):
     return cfg, on_chip, pool, lay
 
 
-def test_xl_decode_step_moves_no_pool(xl):
+@pytest.fixture(scope="module")
+def xl_compiled(xl):
+    """``program -> `` the cell's decode step or chunk prefill, compiled
+    once with the served tree's shapes."""
     cfg, on_chip, pool, lay = xl
     rows, T = 32, cfg.max_seq // lay.block_size
-    step = make_paged_decode_step(cfg, block_size=lay.block_size, n_table=T)
-    _assert_pool_stays_put(step.lower(
-        _params_of(cfg, on_chip), pool, pool, on_chip((rows, T), jnp.int32),
-        on_chip((rows,), jnp.int32), on_chip((rows,), jnp.int32),
-        on_chip((rows,), jnp.bool_)).compile(), lay)
+    params = _params_of(cfg, on_chip)
+    assert params["layers"]["w_up"].dtype == cfg.dtype
+    assert params["wte"].dtype == jnp.float32       # added, THEN rounded
+    done = {}
+
+    def compiled(program):
+        if program not in done:
+            if program == "decode":
+                lowered = make_paged_decode_step(
+                    cfg, block_size=lay.block_size, n_table=T).lower(
+                    params, pool, pool, on_chip((rows, T), jnp.int32),
+                    on_chip((rows,), jnp.int32), on_chip((rows,), jnp.int32),
+                    on_chip((rows,), jnp.bool_))
+            else:
+                lowered = make_chunk_prefill_fn(
+                    cfg, chunk=32, block_size=lay.block_size,
+                    n_table=T).lower(
+                    params, pool, pool, on_chip((T,), jnp.int32),
+                    on_chip((32,), jnp.int32), on_chip((), jnp.int32))
+            done[program] = lowered.compile()
+        return done[program]
+    return compiled
 
 
-def test_xl_chunk_prefill_moves_no_pool(xl):
-    cfg, on_chip, pool, lay = xl
-    T = cfg.max_seq // lay.block_size
-    chunk = make_chunk_prefill_fn(cfg, chunk=32, block_size=lay.block_size,
-                                  n_table=T)
-    _assert_pool_stays_put(chunk.lower(
-        _params_of(cfg, on_chip), pool, pool, on_chip((T,), jnp.int32),
-        on_chip((32,), jnp.int32), on_chip((), jnp.int32)).compile(), lay)
+def test_xl_decode_step_moves_no_pool(xl, xl_compiled):
+    _assert_pool_stays_put(xl_compiled("decode"), xl[3])
+
+
+def test_xl_chunk_prefill_moves_no_pool(xl, xl_compiled):
+    _assert_pool_stays_put(xl_compiled("chunk"), xl[3])
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_xl_program_casts_and_retiles_no_weights(xl, xl_compiled, program):
+    """What ISSUE 30 bought: handed the served tree, a program holds no
+    ``convert`` whose result is a stacked layer weight (from float32
+    masters every decode and chunk program began with six, 6.1 GB read
+    and 3.1 GB written, and kept the results as 2.97 GB of scratch) and
+    no ``copy`` of the embedding table for the head."""
+    cfg = xl[0]
+    text = xl_compiled(program).as_text()
+    stacked = {",".join(map(str, s.shape)) for s in jax.tree.leaves(
+        jax.eval_shape(lambda: gpt.init_params(
+            cfg, jax.random.PRNGKey(0))["layers"])) if len(s.shape) == 3}
+    assert f"{cfg.n_layers},{cfg.d_model},{cfg.d_ff}" in stacked
+    casts = [m.group(0) for m in re.finditer(
+        r"= \(?bf16\[([\d,]*)\]\S* convert\(", text)
+        if m.group(1) in stacked]
+    assert not casts, f"stacked weights cast inside the program: {casts}"
+    table = (f"{cfg.vocab_size},{cfg.d_model}",
+             f"{cfg.d_model},{cfg.vocab_size}")
+    copies = [m.group(0) for m in re.finditer(
+        r"= \(?\w+\[([\d,]*)\]\S* copy(?:-start)?\(", text)
+        if m.group(1) in table]
+    assert not copies, f"the embedding re-tiled for the head: {copies}"
+    assert xl_compiled(program).memory_analysis().temp_size_in_bytes < 5e8
 
 
 def test_xl_write_blocks_moves_no_pool(xl):
