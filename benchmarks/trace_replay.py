@@ -766,7 +766,7 @@ def main():
     from ray_tpu.serve.deployment import AutoscalingConfig
 
     out_path = args.out or f"SERVE_r{perf.ROUND}.json"
-    # the serve_bench (r10) model size: big enough that the ENGINE, not
+    # a model size big enough that the ENGINE, not
     # the HTTP stack, is the bottleneck — otherwise offered load never
     # reaches the admission/occupancy machinery under test
     cfg = gpt.GPTConfig(vocab_size=512, max_seq=64, d_model=128,

@@ -1,27 +1,20 @@
-"""KV-cache pools: the paged block pool (production) and the legacy
-slot pool (A/B baseline).
+"""KV-cache pools: the paged block pool and what lives beside it.
 
 vLLM's insight (PagedAttention) is that serving memory must be bounded
 by a PREALLOCATED pool handed out in fixed-size units and reclaimed on
-sequence exit — never grown per request.  Two unit granularities live
-here:
+sequence exit — never grown per request.
 
-  * ``BlockPool`` — the paged pool: fixed-size TOKEN BLOCKS (two
-    arrays stored as ``PoolLayout`` defines, once, for every program
-    that reads or writes them), a per-request BLOCK TABLE mapping sequence positions to blocks, and
-    per-block REFCOUNTS so blocks are shared across requests (prefix
-    reuse) with copy-on-write on a shared partially-filled tail.  The
-    decode step stays compiled-once because the table width and batch
-    width are static; the price is one gather per step (the trade the
-    slot design deferred — now paid, because block granularity lets
-    long and short sequences share one pool with near-zero waste).
-    Block id 0 is a reserved SCRATCH block: masked rows and
-    out-of-range writes are redirected there so the compiled step never
-    needs a conditional scatter.
-  * ``KVCacheManager`` — the round-10 slot pool (one ``[max_seq]``
-    stripe per sequence).  Kept as the ``paged=False`` engine mode so
-    the serving benchmark can A/B the paged path against the exact
-    engine that shipped in SERVE_r10/r14.
+``BlockPool`` is that pool: fixed-size TOKEN BLOCKS (two arrays stored
+as ``PoolLayout`` defines, once, for every program that reads or writes
+them), a per-request BLOCK TABLE mapping sequence positions to blocks,
+and per-block REFCOUNTS so blocks are shared across requests (prefix
+reuse) with copy-on-write on a shared partially-filled tail.  The
+decode step stays compiled-once because the table width and batch width
+are static; the price is one gather per step, paid because block
+granularity lets long and short sequences share one pool with near-zero
+waste.  Block id 0 is a reserved SCRATCH block: masked rows and
+out-of-range writes are redirected there so the compiled step never
+needs a conditional scatter.
 
 ``RadixIndex`` is the prefix cache over the block pool: a trie keyed on
 block-sized token chunks (plus partial-tail leaves), so a new request
@@ -29,7 +22,7 @@ whose prompt head matches a cached prefix ADOPTS those blocks by
 refcount instead of re-running prefill (SGLang's RadixAttention shape).
 Unreferenced cached prefixes are LRU-evicted under pool pressure.
 
-Array updates go through jitted helpers (slot/block write, block copy,
+Array updates go through jitted helpers (block write, block copy,
 pool swap) so the engine loop never materializes a second full pool on
 the host.
 """
@@ -48,124 +41,6 @@ import numpy as np
 from ray_tpu.models.gpt import GPTConfig
 from ray_tpu.parallel.sharding import (DEFAULT_LLM_RULES, Rules,
                                        sharding_for, spec_for)
-
-
-@partial(jax.jit, donate_argnums=(0,))
-def _write_slot(pool: jax.Array, slot: jax.Array, new: jax.Array):
-    """pool [L, B, h, S, hd] <- new [L, h, S, hd] at slot (dynamic)."""
-    return pool.at[:, slot].set(new.astype(pool.dtype))
-
-
-class KVCacheManager:
-    """Owns the preallocated K/V pool and the slot free-list.
-
-    Thread contract: `alloc`/`free`/array swaps happen on the engine
-    loop thread; `stats()` may be read from any thread (metrics export)
-    — the lock only guards the free-list and counters.
-    """
-
-    def __init__(self, cfg: GPTConfig, n_slots: int,
-                 max_seq: Optional[int] = None,
-                 dtype=None):
-        if n_slots < 1:
-            raise ValueError(f"n_slots must be >= 1, got {n_slots}")
-        self.cfg = cfg
-        self.n_slots = n_slots
-        self.max_seq = int(max_seq or cfg.max_seq)
-        if self.max_seq > cfg.max_seq:
-            raise ValueError(
-                f"cache max_seq {self.max_seq} exceeds model max_seq "
-                f"{cfg.max_seq} (wpe table bound)")
-        self.dtype = dtype or cfg.dtype
-        shape = (cfg.n_layers, n_slots, cfg.n_heads, self.max_seq,
-                 cfg.head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
-        self._lock = threading.Lock()
-        self._free = list(range(n_slots - 1, -1, -1))   # pop() -> slot 0 first
-        self._allocated: set[int] = set()
-
-    # ------------------------------------------------------------- slots
-
-    def alloc(self) -> Optional[int]:
-        """Hand out a slot, or None when the pool is exhausted (caller
-        queues the request — memory never grows)."""
-        with self._lock:
-            if not self._free:
-                return None
-            slot = self._free.pop()
-            self._allocated.add(slot)
-            return slot
-
-    def free(self, slot: int) -> None:
-        with self._lock:
-            if slot not in self._allocated:
-                raise ValueError(f"slot {slot} is not allocated "
-                                 "(double free or never alloc'd)")
-            self._allocated.remove(slot)
-            self._free.append(slot)
-
-    @property
-    def n_free(self) -> int:
-        with self._lock:
-            return len(self._free)
-
-    @property
-    def n_active(self) -> int:
-        with self._lock:
-            return len(self._allocated)
-
-    # ------------------------------------------------------------- arrays
-
-    def write_prefill(self, slot: int, k_new: jax.Array,
-                      v_new: jax.Array) -> None:
-        """Seed a slot from prefill output ([L, h, S, hd] each; S may be
-        shorter than the pool stripe — zero-padded on the right, the
-        padded tail is masked by kv_lengths and overwritten by decode)."""
-        s = k_new.shape[2]
-        if s < self.max_seq:
-            pad = [(0, 0), (0, 0), (0, self.max_seq - s), (0, 0)]
-            k_new = jnp.pad(k_new, pad)
-            v_new = jnp.pad(v_new, pad)
-        self.k = _write_slot(self.k, jnp.int32(slot), k_new)
-        self.v = _write_slot(self.v, jnp.int32(slot), v_new)
-
-    def swap(self, k: jax.Array, v: jax.Array) -> None:
-        """Install the decode step's updated pool arrays."""
-        self.k, self.v = k, v
-
-    def reset_arrays(self) -> None:
-        """Reallocate the pool.  Needed after a FAILED decode step: the
-        step donates the cache buffers (donate_argnums), so an exception
-        mid-step can leave self.k/v pointing at invalidated storage —
-        every later use would raise 'buffer was donated'.  All in-flight
-        requests are failed by the caller, so zeros are the right
-        content."""
-        shape = self.k.shape
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
-
-    # ------------------------------------------------------------- stats
-
-    def bytes_total(self) -> int:
-        itemsize = np.dtype(
-            jnp.zeros((), self.dtype).dtype).itemsize
-        return 2 * int(np.prod(self.k.shape)) * itemsize
-
-    def stats(self) -> dict:
-        with self._lock:
-            active = len(self._allocated)
-        return {
-            "n_slots": self.n_slots,
-            "active_slots": active,
-            "free_slots": self.n_slots - active,
-            "max_seq": self.max_seq,
-            "bytes_total": self.bytes_total(),
-        }
-
-
-# ---------------------------------------------------------------------------
-# paged pool
 
 
 # logical axes of a paged pool array [L*(N+1), bs, W]: the WIDTH dim (a
@@ -367,7 +242,7 @@ class BlockPool:
     exclusively (refcount 1) — otherwise copy-on-write first
     (``copy_block`` into a fresh block, drop the shared reference).
 
-    Thread contract mirrors KVCacheManager: alloc/incref/decref/array
+    Thread contract: alloc/incref/decref/array
     swaps happen on the engine loop thread; ``stats()`` may be read
     from any thread (the lock only guards the free list + refcounts).
 

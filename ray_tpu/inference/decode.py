@@ -1,6 +1,5 @@
-"""Incremental (KV-cache) decode for the GPT: paged decode + chunked
-prefill + speculative verify (production), slot decode + full prefill
-(legacy baseline).
+"""Incremental (KV-cache) decode for the GPT: paged decode, chunked
+prefill, speculative verify and draft, and the full-width prefill.
 
 All programs have STATIC shapes so each compiles exactly once
 regardless of request mix — and (no-mesh path) once per (config,
@@ -8,21 +7,25 @@ rules, geometry) across ALL engines, so a fleet scaling out replicas
 or multiplexing model variants reuses the compiled set instead of
 paying a per-engine recompile.
 
-Paged path (cache.BlockPool):
+Every program runs the model's ONE layer function
+(``gpt._transformer_layer``) on its window of tokens and hands it the
+attention step.  The four paged programs hand it ``paged_attend``: the
+window's K/V is committed to the block pool (cache.BlockPool) at the
+window's (block, offset) pairs, the rows' block tables are read back,
+and each query attends what its mask or length admits.  What a program
+states itself is what differs: the index arithmetic of its window (dead
+lanes go to the scratch block), the embedding, the head.
 
-  * chunk_prefill — a fixed-width window of the prompt ([C] tokens at
-    positions start..start+C) runs one forward layer-by-layer against
-    the BLOCK POOL: each layer writes the window's K/V through the
-    block table, then attends over the gathered table (earlier chunks'
-    K/V included), each query row masked to its OWN causal horizon.
+  * chunk_prefill — a fixed-width window of ONE prompt ([C] tokens at
+    positions start..start+C), each query row masked to its OWN causal
+    horizon over the gathered table (earlier chunks' K/V included).
     Long prompts therefore prefill as a sequence of bounded-cost steps
     the engine interleaves with decode iterations — a long prompt
     stops stalling neighbors' token cadence.
-  * paged_decode_step — one token for EVERY row at once; each layer
-    writes the rows' K/V at their (block, offset) in the pool (inactive
-    rows redirected to the scratch block), then gathers each row's
-    block table and masks to its valid prefix (the formulation of
-    ops/attention.paged_attention).
+  * paged_decode_step — one token for EVERY row at once, each row
+    masked to its valid prefix (the formulation of
+    ops/attention.paged_attention); inactive rows write to the scratch
+    block.
   * spec_verify_step — the decode step widened to a [b, W] token
     window (W = speculate_k + 1): column 0 is each row's current input
     token, columns 1.. are DRAFTED continuations.  One call scores all
@@ -41,6 +44,9 @@ Paged path (cache.BlockPool):
     l only depends on layers < l), so drafting through the real pool
     corrupts nothing, and the verify pass overwrites every drafted
     position at all layers anyway.
+  * prefill — the training forward with ``return_kv=True`` over a
+    prompt padded to the cache width: the engine seeds a cold long
+    prompt's blocks from one call of it.
 
 The host-side n-gram drafter (``ngram_propose`` — prompt-lookup
 decoding, Saxena 2023) lives here too: it proposes the continuation
@@ -48,23 +54,15 @@ that followed the most recent earlier occurrence of the sequence's
 trailing n-gram.  Zero weights, zero device work — repetitive
 generations (and shared-prefix serving mixes) accept most of it.
 
-Legacy slot path (cache.KVCacheManager, engine ``paged=False``):
-
-  * prefill — the ordinary training forward with ``return_kv=True``
-    (models/gpt.py) over the prompt padded to the cache width.
-  * decode_step — one-hot scatter on the position axis of the
-    ``[L, n_slots, h, S, hd]`` cache, per-row kv_lengths masking.
-
-All step bodies mirror gpt._transformer_layer's einsums exactly; MoE
-configs dispatch through gpt._moe_mlp per token window (paged path
-only — the slot path stays the frozen dense baseline).  With a mesh the
-paged bodies are sharding-annotated for Megatron-style tensor
-parallelism: pools heads-sharded per POOL_AXES, per-device attention
-over local heads, one collective at the output projection, the donated
-pool committed per shard.  The pools' stored layout is defined once, in
-cache.PoolLayout; the programs here touch them through its ``read`` and
-``commit`` only.  Greedy token-parity with
-full-recompute ``generate()`` is pinned by tests/test_inference.py +
+With a mesh the programs are sharding-annotated for Megatron-style
+tensor parallelism: pools heads-sharded per POOL_AXES, per-device
+attention over local heads, one collective at the output projection,
+the donated pool committed per shard (the scatter's indexed dims — row,
+offset — are unsharded).  MoE configs dispatch through gpt._moe_mlp per
+token window.  The pools' stored layout is defined once, in
+cache.PoolLayout; the programs touch them through its ``read`` and
+``commit`` only.  Greedy token-parity with full-recompute
+``generate()`` is pinned by tests/test_inference.py +
 tests/test_paged_cache.py (mesh=None) and tests/test_sharded_decode.py
 (multi-device CPU meshes).
 """
@@ -72,7 +70,6 @@ tests/test_paged_cache.py (mesh=None) and tests/test_sharded_decode.py
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -82,34 +79,18 @@ from jax import lax
 from ray_tpu.inference.cache import POOL_AXES, PoolLayout, heads_shards
 from ray_tpu.models import gpt
 from ray_tpu.models.gpt import GPTConfig
-from ray_tpu.ops.attention import attention, packed_attention
+from ray_tpu.ops.attention import packed_attention
 from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES, Rules
 
-
-class MoEDecodeUnsupported(NotImplementedError):
-    """The legacy SLOT decode path has no MoE support (it is the frozen
-    dense A/B baseline; the paged engine serves MoE via gpt._moe_mlp).
-    Typed so the gap fails EARLY and clearly — at step construction
-    time, never mid-decode with slots already held — and so callers can
-    distinguish the known capability gap from a generic failure."""
-
-    def __init__(self, cfg: GPTConfig):
-        super().__init__(
-            f"the legacy slot decode path has no MoE support "
-            f"(n_experts={cfg.n_experts}); serve this config with the "
-            f"paged engine (EngineConfig.paged=True — it dispatches "
-            f"experts per token window via gpt._moe_mlp), or with a "
-            f"dense MLP (n_experts=0), or the training forward")
 
 class SpeculationUnsupported(ValueError):
     """Speculative decoding was requested for a configuration that has
     no speculation path.  Typed and raised at engine CONSTRUCTION time
-    (like MoEDecodeUnsupported) so the gap fails early and callers can
-    tell the known capability boundary from a generic failure.  The
-    supported surface: the PAGED engine only (the slot engine is the
-    frozen A/B baseline), and the self-drafter needs
+    so the gap fails early and callers can tell the known capability
+    boundary from a generic failure: the self-drafter needs
     ``1 <= draft_layers < n_layers`` (a full-depth draft is just the
-    model twice).  ``temperature > 0`` requests are NOT an error — they
+    model twice), and a model with a recurrent state has no rollback.
+    ``temperature > 0`` requests are NOT an error — they
     transparently fall back to non-speculative decode per row (see
     InferenceEngine.submit)."""
 
@@ -139,29 +120,6 @@ def _cached(kind: str, cfg: GPTConfig, mesh, rules, build):
     return fn
 
 
-def _mlp_block(y, lp, cfg, mesh, rules):
-    """The step bodies' MLP: the dense einsums mirroring
-    gpt._transformer_layer, or — when the config is MoE — the training
-    forward's expert dispatch (gpt._moe_mlp) applied to the step's
-    token window, the load-balance aux loss discarded (inference).
-    Per-token routing is position-independent, so incremental windows
-    route exactly like the full forward; expert CAPACITY is per window
-    (C = ceil(cf·k·s_window/E)), so token-exact parity with the
-    full-sequence oracle holds whenever capacity never binds
-    (capacity_factor >= n_experts / expert_top_k guarantees it; a
-    single-token decode window can never drop regardless).
-    y [b, s, d] -> [b, s, d]."""
-    if cfg.n_experts:
-        dn, _ = gpt._moe_mlp(y, lp, cfg, mesh, rules)
-        return dn
-    u = jnp.einsum("bsd,df->bsf", y, lp["w_up"].astype(cfg.dtype)) \
-        + lp["b_up"].astype(cfg.dtype)
-    u = gpt._constrain(u, ("batch", "seq", "mlp"), mesh, rules)
-    u = jax.nn.gelu(u)
-    return jnp.einsum("bsf,fd->bsd", u, lp["w_down"].astype(cfg.dtype)) \
-        + lp["b_down"].astype(cfg.dtype)
-
-
 def make_prefill_fn(cfg: GPTConfig, *, mesh=None,
                     rules: Rules = DEFAULT_LLM_RULES):
     """jitted (params, tokens [b, S]) -> (logits [b, S, V], k, v
@@ -180,111 +138,82 @@ def make_prefill_fn(cfg: GPTConfig, *, mesh=None,
     return _cached("prefill", cfg, mesh, rules, build)
 
 
-def make_decode_step(cfg: GPTConfig, *, mesh=None,
-                     rules: Rules = DEFAULT_LLM_RULES):
-    """jitted one-token step over the whole slot batch.
-
-    (params, k_cache, v_cache [L, b, h, S, hd], tokens [b] int32,
-     positions [b] int32, active [b] bool)
-        -> (logits [b, vocab] f32, k_cache, v_cache)
-
-    ``tokens`` are the slots' current input tokens, each sitting at
-    ``positions[slot]``; the step writes that token's K/V into the cache
-    (masked by ``active`` so parked slots stay untouched), attends over
-    positions [0, positions[slot]] and returns next-token logits.
-    """
-    if cfg.n_experts:
-        raise MoEDecodeUnsupported(cfg)
-    h, hd = cfg.n_heads, cfg.head_dim
-
-    def build():
-        return _make_step(cfg, mesh, rules, h, hd)
-
-    return _cached("step", cfg, mesh, rules, build)
-
-
-def _make_step(cfg, mesh, rules, h, hd):
-    @partial(jax.jit, donate_argnums=(1, 2))
-    def step(params, k_cache, v_cache, tokens, positions, active):
-        b = tokens.shape[0]
-        S = k_cache.shape[3]
-        x = (gpt._token_rows(params, tokens, cfg)
-             + params["wpe"][positions])
-        x = x[:, None, :].astype(cfg.dtype)               # [b, 1, d]
-        # one-hot write mask on the position axis, zeroed for parked slots
-        write = ((jnp.arange(S)[None, :] == positions[:, None])
-                 & active[:, None])                       # [b, S]
-        kv_len = jnp.where(active, positions + 1, 1)      # >=1: no NaN rows
-
-        def layer(x, xs):
-            lp, ck, cv = xs                               # ck/cv [b,h,S,hd]
-            y = gpt._layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
-            qkv = jnp.einsum("bsd,de->bse", y,
-                             lp["wqkv"].astype(cfg.dtype))
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-
-            def heads(t):                                 # [b,1,d]->[b,h,1,hd]
-                return t.reshape(b, 1, h, hd).transpose(0, 2, 1, 3)
-
-            kh, vh = heads(k), heads(v)                   # [b, h, 1, hd]
-            ck = jnp.where(write[:, None, :, None], kh, ck)
-            cv = jnp.where(write[:, None, :, None], vh, cv)
-            o = attention(heads(q), ck, cv, causal=False,
-                          kv_lengths=kv_len, impl="reference")
-            o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.d_model)
-            o = jnp.einsum("bsd,de->bse", o, lp["wo"].astype(cfg.dtype)) \
-                + lp["bo"].astype(cfg.dtype)
-            x = x + o
-            y = gpt._layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
-            u = jnp.einsum("bsd,df->bsf", y,
-                           lp["w_up"].astype(cfg.dtype)) \
-                + lp["b_up"].astype(cfg.dtype)
-            u = jax.nn.gelu(u)
-            dn = jnp.einsum("bsf,fd->bsd", u,
-                            lp["w_down"].astype(cfg.dtype)) \
-                + lp["b_down"].astype(cfg.dtype)
-            return x + dn, (ck, cv)
-
-        x, (k_cache, v_cache) = lax.scan(
-            layer, x, (params["layers"], k_cache, v_cache))
-        logits = gpt._head(params, x, cfg, mesh, rules)[:, 0, :]
-        return logits, k_cache, v_cache
-
-    return step
-
-
 # ---------------------------------------------------------------------------
 # paged path
 #
 # The K and V pools are stored as cache.PoolLayout says ([L*(N+1), bs,
 # W], see there) and every program below touches them through its two
 # operations only.  The pools ride the layer scan as its CARRY: each
-# layer commits its window's new K/V into the carried pool and THEN
-# reads its rows' tables from it, so the attended context holds the new
-# tokens at their own positions with no insertion step.  What the chip
-# does with that: a carried, donated buffer is updated in place — the
-# compiled programs hold no copy of a pool nor of a layer's share of
-# one, and the pools enter and leave in the layout the scan computes in
-# (tests/test_chip_compile.py pins all three on a described v5e).  The
-# formulations this replaced each cost a pass ~75 ms at GPT-2 XL on the
-# chip: pools closed over by the scan body with one scatter after it
-# (two whole-pool re-tilings in, two out, and a copy of the layer's
-# slice per layer), and pools as scan xs/ys (a copy of the whole pool).
+# layer's ``attend`` commits its window's new K/V into the carried pool
+# and THEN reads its rows' tables from it, so the attended context
+# holds the new tokens at their own positions with no insertion step.
+# What the chip does with that: a carried, donated buffer is updated in
+# place — the compiled programs hold no copy of a pool nor of a layer's
+# share of one, and the pools enter and leave in the layout the scan
+# computes in (tests/test_chip_compile.py pins all three on a described
+# v5e).  The formulations this replaced each cost a pass ~75 ms at
+# GPT-2 XL on the chip: pools closed over by the scan body with one
+# scatter after it (two whole-pool re-tilings in, two out, and a copy
+# of the layer's slice per layer), and pools as scan xs/ys (a copy of
+# the whole pool).
 
 
-def _write_then_read(lay: PoolLayout, pools, li, blocks, offsets, new,
-                     tables, mesh, rules):
-    """One layer's traffic with the (K, V) ``pools``: commit the
-    window's ``new`` (K, V) [..., h, hd] at ``(blocks, offsets)``, then
-    gather the rows' ``tables`` [b, T] as attention contexts
-    [b, T*bs, W] — keys in position order, the window's own among them,
-    heads still packed as stored (packed_attention takes them so).
-    Returns (pools, contexts)."""
-    pools = tuple(lay.commit(p, li, blocks, offsets, x)
-                  for p, x in zip(pools, new))
-    return pools, tuple(
-        gpt._constrain(lay.read(p, li, tables), ("batch", None, "heads"),
-                       mesh, rules) for p in pools)
+def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
+                 mesh=None, rules=None, **attn):
+    """Where a window meets the pool, for every model family:
+    ``attend_for(layer)`` gives that layer's ``attend(q [b, h, w, hd],
+    k, v [b, w, h_kv, hd]) -> o [b, h, w, hd]`` over the (K, V)
+    ``pools``, and ``held["pools"]`` is what the last ``attend`` left.
+
+    ``attend`` commits the window's K/V at ``(blocks, offsets)``, then
+    gathers the rows' ``tables`` [b, T] as contexts [b, T*bs, W] — keys
+    in position order, the window's own among them, heads still packed
+    as stored — and attends them so (``packed_attention``, which
+    ``attn`` goes to: ``mask`` or ``kv_lengths``, ``groups``,
+    ``q_per_kv``, ``scale``).  With a mesh the contexts are
+    constrained to the pool's heads sharding."""
+    held = {"pools": pools}
+
+    def attend_for(layer):
+        def attend(q, k, v):
+            new = (k.reshape(*blocks.shape, *k.shape[2:]),
+                   v.reshape(*blocks.shape, *v.shape[2:]))
+            held["pools"] = tuple(
+                lay.commit(p, layer, blocks, offsets, x)
+                for p, x in zip(held["pools"], new))
+            ctx_k, ctx_v = (
+                gpt._constrain(lay.read(p, layer, tables),
+                               ("batch", None, "heads"), mesh, rules)
+                for p in held["pools"])
+            return packed_attention(q, ctx_k, ctx_v, **attn)
+        return attend
+    return attend_for, held
+
+
+def _pools_in(cfg, k_pool, v_pool, mesh, rules):
+    """-> (the pools' layout, the (K, V) pair constrained to it)."""
+    lay = PoolLayout.of(cfg, k_pool, heads_shards(mesh, rules))
+    return lay, tuple(gpt._constrain(p, POOL_AXES, mesh, rules)
+                      for p in (k_pool, v_pool))
+
+
+def _paged_layers(cfg, mesh, rules, layers, x, pools, attend_over):
+    """The stacked ``layers`` on the window x [b, w, d], the pools their
+    scan's carry.  ``attend_over(pools)`` is the program's
+    ``paged_attend`` over the pools it is given: the closure is built
+    inside the scan body from the carry, and the body returns what the
+    closure left.  -> (x, pools)."""
+    def layer(carry, xs):
+        x, pools = carry
+        lp, li = xs
+        attend_for, held = attend_over(pools)
+        x, _ = gpt._transformer_layer(x, lp, cfg, mesh, rules,
+                                      attend_for(li))
+        return (x, held["pools"]), None
+
+    n = layers["wqkv"].shape[0]
+    (x, pools), _ = lax.scan(layer, (x, pools), (layers, jnp.arange(n)))
+    return x, pools
 
 
 def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
@@ -296,34 +225,21 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
      tokens [b] int32, positions [b] int32, active [b] bool)
         -> (logits [b, vocab] f32, k_pool, v_pool)
 
-    Each layer commits every row's current token K/V to the pool at
+    Every row's current token K/V goes to the pool at
     ``(tables[row, pos // bs], pos % bs)`` — inactive rows are
     redirected to the scratch block (id 0) so the scatter needs no
-    conditional — then gathers the row's table and attends, masked to
-    the row's valid prefix (the formulation of
-    ops/attention.paged_attention).  Tail blocks are per-row exclusive
-    (the engine copy-on-writes shared tails before the step), so active
-    rows never collide in the scatter.
-
-    With a mesh, the pools are heads-sharded (POOL_AXES) and the body
-    carries sharding constraints mirroring gpt._transformer_layer:
-    qkv projection, gathered context, and attention run per-device
-    over local heads with ONE collective at the output/head projection
-    (Megatron TP); the donated commit stays per-shard (the scatter's
-    indexed dims — row, offset — are unsharded).
-    MoE configs dispatch through gpt._moe_mlp per decode window.
+    conditional — and the row attends its valid prefix.  Tail blocks
+    are per-row exclusive (the engine copy-on-writes shared tails
+    before the step), so active rows never collide in the scatter.
     """
-    h, hd, bs = cfg.n_heads, cfg.head_dim, int(block_size)
-    shards = heads_shards(mesh, rules)
+    bs = int(block_size)
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
         def step(params, k_pool, v_pool, tables, tokens, positions,
                  active):
             b = tokens.shape[0]
-            lay = PoolLayout.of(cfg, k_pool, shards)
-            k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
-            v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
+            lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
             x = (gpt._token_rows(params, tokens, cfg)
                  + params["wpe"][positions])
             x = x[:, None, :].astype(cfg.dtype)               # [b, 1, d]
@@ -331,42 +247,13 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
             bidx = jnp.where(active, tables[rows, positions // bs], 0)
             off = jnp.where(active, positions % bs, 0)
             kv_len = jnp.where(active, positions + 1, 1)      # >=1: no NaN
-
-            def layer(carry, xs):
-                x, pools = carry
-                lp, li = xs
-                y = gpt._layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
-                qkv = jnp.einsum("bsd,de->bse", y,
-                                 lp["wqkv"].astype(cfg.dtype))
-                qkv = gpt._constrain(qkv, ("batch", "seq", "qkv"),
-                                     mesh, rules)
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-
-                def heads(t):                      # [b,1,d]->[b,h,1,hd]
-                    return t.reshape(b, 1, h, hd).transpose(0, 2, 1, 3)
-
-                pools, (ctx_k, ctx_v) = _write_then_read(
-                    lay, pools, li, bidx, off,
-                    (k.reshape(b, h, hd), v.reshape(b, h, hd)),
-                    tables, mesh, rules)
-                o = packed_attention(heads(q), ctx_k, ctx_v,
-                                     groups=shards, kv_lengths=kv_len)
-                o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.d_model)
-                o = jnp.einsum("bsd,de->bse", o,
-                               lp["wo"].astype(cfg.dtype)) \
-                    + lp["bo"].astype(cfg.dtype)
-                x = x + o
-                x = gpt._constrain(x, ("batch", "seq", "embed"),
-                                   mesh, rules)
-                y = gpt._layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
-                dn = _mlp_block(y, lp, cfg, mesh, rules)
-                return (x + dn, pools), None
-
-            (x, (k_pool, v_pool)), _ = lax.scan(
-                layer, (x, (k_pool, v_pool)),
-                (params["layers"], jnp.arange(cfg.n_layers)))
-            k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
-            v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
+            x, pools = _paged_layers(
+                cfg, mesh, rules, params["layers"], x, pools,
+                lambda pools: paged_attend(
+                    lay, pools, bidx, off, tables, mesh=mesh, rules=rules,
+                    groups=lay.shards, kv_lengths=kv_len))
+            k_pool, v_pool = (gpt._constrain(p, POOL_AXES, mesh, rules)
+                              for p in pools)
             logits = gpt._head(params, x, cfg, mesh, rules)[:, 0, :]
             return logits, k_pool, v_pool
 
@@ -385,33 +272,25 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
      tokens [C] int32, start int32)
         -> (logits [C, vocab] f32, k_pool, v_pool)
 
-    Processes prompt positions ``start .. start+C``: each layer writes
-    the window's K/V through the block table (rows past the table's
-    span are redirected to the scratch block), then attends over the
-    gathered table with each query row masked to its OWN causal horizon
-    (key position <= query position) — so earlier chunks' cached K/V,
-    including an adopted prefix from the radix index, participates
-    exactly as in a full forward.  Pad rows past the prompt compute
-    garbage that lands in masked positions and is overwritten by
-    decode; the caller reads only the rows it needs.  The engine
-    interleaves one chunk per scheduler pass with decode iterations
-    (chunked prefill: bounded prefill cost per token cadence).
-
-    Sharding and MoE follow the decode step: heads-sharded pools +
-    per-device attention with one collective at the output projection,
-    and gpt._moe_mlp expert dispatch over the chunk window.
+    Processes prompt positions ``start .. start+C``: the window's K/V
+    goes through the block table (rows past the table's span are
+    redirected to the scratch block), and each query row attends the
+    gathered table masked to its OWN causal horizon (key position <=
+    query position) — so earlier chunks' cached K/V, including an
+    adopted prefix from the radix index, participates exactly as in a
+    full forward.  Pad rows past the prompt compute garbage that lands
+    in masked positions and is overwritten by decode; the caller reads
+    only the rows it needs.  The engine interleaves one chunk per
+    scheduler pass with decode iterations (chunked prefill: bounded
+    prefill cost per token cadence).
     """
-    h, hd = cfg.n_heads, cfg.head_dim
     bs, C, T = int(block_size), int(chunk), int(n_table)
     S = T * bs
-    shards = heads_shards(mesh, rules)
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
         def chunk_fn(params, k_pool, v_pool, table, tokens, start):
-            lay = PoolLayout.of(cfg, k_pool, shards)
-            k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
-            v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
+            lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
             pos = start + jnp.arange(C, dtype=jnp.int32)       # [C]
             oob = pos >= S
             wpe_pos = jnp.clip(pos, 0, cfg.max_seq - 1)
@@ -425,42 +304,13 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
             # out-of-range row's K/V went to the scratch block, which
             # no table position of a real row names
             mask = (jnp.arange(S)[None, :] <= pos[:, None])    # [C, S]
-
-            def layer(carry, xs):
-                x, pools = carry
-                lp, li = xs
-                y = gpt._layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
-                qkv = jnp.einsum("bsd,de->bse", y,
-                                 lp["wqkv"].astype(cfg.dtype))
-                qkv = gpt._constrain(qkv, ("batch", "seq", "qkv"),
-                                     mesh, rules)
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-
-                def heads(t):                      # [1,C,d]->[1,h,C,hd]
-                    return t.reshape(1, C, h, hd).transpose(0, 2, 1, 3)
-
-                pools, (ctx_k, ctx_v) = _write_then_read(
-                    lay, pools, li, bidx, off,
-                    (k.reshape(C, h, hd), v.reshape(C, h, hd)),
-                    table[None], mesh, rules)
-                o = packed_attention(heads(q), ctx_k, ctx_v,
-                                     groups=shards, mask=mask[None, None])
-                o = o.transpose(0, 2, 1, 3).reshape(1, C, cfg.d_model)
-                o = jnp.einsum("bsd,de->bse", o,
-                               lp["wo"].astype(cfg.dtype)) \
-                    + lp["bo"].astype(cfg.dtype)
-                x = x + o
-                x = gpt._constrain(x, ("batch", "seq", "embed"),
-                                   mesh, rules)
-                y = gpt._layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
-                dn = _mlp_block(y, lp, cfg, mesh, rules)
-                return (x + dn, pools), None
-
-            (x, (k_pool, v_pool)), _ = lax.scan(
-                layer, (x, (k_pool, v_pool)),
-                (params["layers"], jnp.arange(cfg.n_layers)))
-            k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
-            v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
+            x, pools = _paged_layers(
+                cfg, mesh, rules, params["layers"], x, pools,
+                lambda pools: paged_attend(
+                    lay, pools, bidx, off, table[None], mesh=mesh,
+                    rules=rules, groups=lay.shards, mask=mask[None, None]))
+            k_pool, v_pool = (gpt._constrain(p, POOL_AXES, mesh, rules)
+                              for p in pools)
             logits = gpt._head(params, x, cfg, mesh, rules)[0]  # [C, V]
             return logits, k_pool, v_pool
 
@@ -488,34 +338,25 @@ def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,
     key 0 only, so their logits are garbage the caller ignores — never
     NaN, never corruption.
 
-    Each layer commits every real lane's K/V at its own position, then
-    gathers the row's table, each query masked to keys <=
-    positions[row]+j (the chunk-prefill causal-horizon mask batched
-    over rows), so lane 0's logits are the plain decode step's logits
-    and lane j's are exact next-token logits GIVEN the drafted prefix —
-    greedy accept/reject on the host is therefore token-identical to
-    non-speculative decode by construction.  Rejected lanes leave
-    garbage K/V beyond the row's committed length, which the kv-length
-    masks hide until decode overwrites it (same rule as prefill
-    padding).
-
-    Sharding and MoE follow the decode step: heads-sharded pools +
-    per-device attention with one collective at the output projection,
-    and gpt._moe_mlp expert dispatch over the W-lane window.
+    Every real lane's K/V goes to its own position and each query is
+    masked to keys <= positions[row]+j (the chunk-prefill causal-horizon
+    mask batched over rows), so lane 0's logits are the plain decode
+    step's logits and lane j's are exact next-token logits GIVEN the
+    drafted prefix — greedy accept/reject on the host is therefore
+    token-identical to non-speculative decode by construction.
+    Rejected lanes leave garbage K/V beyond the row's committed length,
+    which the kv-length masks hide until decode overwrites it (same
+    rule as prefill padding).
     """
-    h, hd = cfg.n_heads, cfg.head_dim
     bs, W, T = int(block_size), int(width), int(n_table)
     S = T * bs
-    shards = heads_shards(mesh, rules)
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
         def verify(params, k_pool, v_pool, tables, tokens, positions,
                    active, n_tokens):
             b = tokens.shape[0]
-            lay = PoolLayout.of(cfg, k_pool, shards)
-            k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
-            v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
+            lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
             rows = jnp.arange(b)
             pos = positions[:, None] + jnp.arange(W, dtype=jnp.int32)  # [b,W]
             live = ((jnp.arange(W)[None, :] < n_tokens[:, None])
@@ -525,48 +366,19 @@ def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,
                  + params["wpe"][wpe_pos])
             x = x.astype(cfg.dtype)                       # [b, W, d]
             safe = jnp.where(live, pos, 0)
+            # dead lanes collide harmlessly in the scratch block
             bidx = jnp.where(live, tables[rows[:, None], safe // bs], 0)
             off = jnp.where(live, pos % bs, 0)
             hor = jnp.where(live, pos, 0)                 # >=1 key: no NaN
             mask = (jnp.arange(S)[None, None, :]
                     <= hor[:, :, None])[:, None]          # [b, 1, W, S]
-
-            def layer(carry, xs):
-                x, pools = carry
-                lp, li = xs
-                y = gpt._layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
-                qkv = jnp.einsum("bsd,de->bse", y,
-                                 lp["wqkv"].astype(cfg.dtype))
-                qkv = gpt._constrain(qkv, ("batch", "seq", "qkv"),
-                                     mesh, rules)
-                q, k, v = jnp.split(qkv, 3, axis=-1)
-
-                def heads(t):                      # [b,W,d]->[b,h,W,hd]
-                    return t.reshape(b, W, h, hd).transpose(0, 2, 1, 3)
-
-                # dead lanes collide harmlessly in the scratch block
-                pools, (ctx_k, ctx_v) = _write_then_read(
-                    lay, pools, li, bidx, off,
-                    (k.reshape(b, W, h, hd), v.reshape(b, W, h, hd)),
-                    tables, mesh, rules)
-                o = packed_attention(heads(q), ctx_k, ctx_v,
-                                     groups=shards, mask=mask)
-                o = o.transpose(0, 2, 1, 3).reshape(b, W, cfg.d_model)
-                o = jnp.einsum("bsd,de->bse", o,
-                               lp["wo"].astype(cfg.dtype)) \
-                    + lp["bo"].astype(cfg.dtype)
-                x = x + o
-                x = gpt._constrain(x, ("batch", "seq", "embed"),
-                                   mesh, rules)
-                y = gpt._layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
-                dn = _mlp_block(y, lp, cfg, mesh, rules)
-                return (x + dn, pools), None
-
-            (x, (k_pool, v_pool)), _ = lax.scan(
-                layer, (x, (k_pool, v_pool)),
-                (params["layers"], jnp.arange(cfg.n_layers)))
-            k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
-            v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
+            x, pools = _paged_layers(
+                cfg, mesh, rules, params["layers"], x, pools,
+                lambda pools: paged_attend(
+                    lay, pools, bidx, off, tables, mesh=mesh, rules=rules,
+                    groups=lay.shards, mask=mask))
+            k_pool, v_pool = (gpt._constrain(p, POOL_AXES, mesh, rules)
+                              for p in pools)
             logits = gpt._head(params, x, cfg, mesh, rules)  # [b, W, V]
             return logits, k_pool, v_pool
 
@@ -603,16 +415,12 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
     verify pass rewrites every drafted position at all layers
     regardless of the accept outcome.  Cost per draft token ~
     draft_layers / n_layers of a full step, with zero extra weights.
-
-    Sharding and MoE follow the decode step (heads-sharded pools,
-    gpt._moe_mlp dispatch per draft token); the truncated-layer trunk
-    slice composes with MoE leaves because tree_map slices every
-    per-layer leaf, expert weights included.
+    The truncated-layer trunk slice composes with MoE leaves because
+    tree_map slices every per-layer leaf, expert weights included.
     """
-    h, hd, bs = cfg.n_heads, cfg.head_dim, int(block_size)
+    bs = int(block_size)
     D, K, T = int(draft_layers), int(k), int(n_table)
     S = T * bs
-    shards = heads_shards(mesh, rules)
     if not (1 <= D < cfg.n_layers):
         raise SpeculationUnsupported(
             f"draft_layers must be in [1, n_layers) = [1, "
@@ -626,9 +434,7 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
         def draft(params, k_pool, v_pool, tables, tokens, positions,
                   want):
             b = tokens.shape[0]
-            lay = PoolLayout.of(cfg, k_pool, shards)
-            k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
-            v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
+            lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
             rows = jnp.arange(b)
             trunk = jax.tree_util.tree_map(lambda a: a[:D],
                                            params["layers"])
@@ -643,54 +449,21 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
                 bidx = jnp.where(live, tables[rows, safe // bs], 0)
                 off = jnp.where(live, safe % bs, 0)
                 kv_len = jnp.where(live, pos + 1, 1)
-
-                def layer(carry, xs):
-                    x, pools = carry
-                    lp, li = xs
-                    y = gpt._layer_norm(x, lp["ln1_scale"],
-                                        lp["ln1_bias"])
-                    qkv = jnp.einsum("bsd,de->bse", y,
-                                     lp["wqkv"].astype(cfg.dtype))
-                    qkv = gpt._constrain(qkv, ("batch", "seq", "qkv"),
-                                         mesh, rules)
-                    q, kk, v = jnp.split(qkv, 3, axis=-1)
-
-                    def heads(t):                  # [b,1,d]->[b,h,1,hd]
-                        return t.reshape(b, 1, h, hd).transpose(
-                            0, 2, 1, 3)
-
-                    pools, (ctx_k, ctx_v) = _write_then_read(
-                        lay, pools, li, bidx, off,
-                        (kk.reshape(b, h, hd), v.reshape(b, h, hd)),
-                        tables, mesh, rules)
-                    o = packed_attention(heads(q), ctx_k, ctx_v,
-                                         groups=shards, kv_lengths=kv_len)
-                    o = o.transpose(0, 2, 1, 3).reshape(
-                        b, 1, cfg.d_model)
-                    o = jnp.einsum("bsd,de->bse", o,
-                                   lp["wo"].astype(cfg.dtype)) \
-                        + lp["bo"].astype(cfg.dtype)
-                    x = x + o
-                    x = gpt._constrain(x, ("batch", "seq", "embed"),
-                                       mesh, rules)
-                    y = gpt._layer_norm(x, lp["ln2_scale"],
-                                        lp["ln2_bias"])
-                    dn = _mlp_block(y, lp, cfg, mesh, rules)
-                    return (x + dn, pools), None
-
-                (x, pools), _ = lax.scan(
-                    layer, (x, pools), (trunk, jnp.arange(D)))
+                x, pools = _paged_layers(
+                    cfg, mesh, rules, trunk, x, pools,
+                    lambda pools: paged_attend(
+                        lay, pools, bidx, off, tables, mesh=mesh,
+                        rules=rules, groups=lay.shards, kv_lengths=kv_len))
                 logits = gpt._head(params, x, cfg, mesh, rules)[:, 0, :]
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 cur = jnp.where(live, nxt, cur)
                 pos = pos + live.astype(jnp.int32)
                 return (cur, pos, pools), nxt
 
-            (_, _, (k_pool, v_pool)), toks = lax.scan(
-                step, (tokens, positions, (k_pool, v_pool)),
-                jnp.arange(K))
-            k_pool = gpt._constrain(k_pool, POOL_AXES, mesh, rules)
-            v_pool = gpt._constrain(v_pool, POOL_AXES, mesh, rules)
+            (_, _, pools), toks = lax.scan(
+                step, (tokens, positions, pools), jnp.arange(K))
+            k_pool, v_pool = (gpt._constrain(p, POOL_AXES, mesh, rules)
+                              for p in pools)
             return toks.T, k_pool, v_pool     # drafts [b, K]
 
         return draft

@@ -7,10 +7,9 @@ boundary — it admits waiting requests, advances prefills, and evicts
 finished requests (EOS / max-tokens).  Requests therefore join and
 leave MID-DECODE of their neighbors: a long generation never blocks a
 short one behind it, and the decode batch stays as full as the offered
-load allows — the throughput lever the naive sequential baseline lacks
-(benchmarks/serve_bench.py is the A/B receipt).
+load allows — the throughput lever a sequential server lacks.
 
-The default cache is the paged BlockPool (``EngineConfig.paged``):
+The cache is the paged BlockPool (inference/cache.py):
 
   * admission is BLOCK-BUDGET accounting, not slot counting — a request
     is admitted when a decode row is free AND the pool can cover its
@@ -48,9 +47,6 @@ The default cache is the paged BlockPool (``EngineConfig.paged``):
     refunds any speculative charge automatically: granted blocks live
     in the row chain, and preemption releases the chain.
 
-``paged=False`` keeps the round-10/14 slot engine (one ``[max_seq]``
-stripe per request) as the same-run A/B baseline.
-
 Tokens stream out per request as they are sampled: GenerationRequest is
 a tiny condition-variable mailbox whose ``stream()`` generator the serve
 layer turns into chunked transfer-encoding.  All waits are bounded
@@ -81,10 +77,9 @@ import numpy as np
 
 from ray_tpu.core import fault_injection as _fi
 from ray_tpu.core import flight_recorder as _fr
-from ray_tpu.inference.cache import BlockPool, KVCacheManager, RadixIndex
+from ray_tpu.inference.cache import BlockPool, RadixIndex
 from ray_tpu.inference.decode import (SpeculationUnsupported,
                                       make_chunk_prefill_fn,
-                                      make_decode_step,
                                       make_paged_decode_step,
                                       make_paged_draft_step,
                                       make_prefill_fn,
@@ -102,26 +97,23 @@ from ray_tpu.util import tracing
 @dataclass
 class EngineConfig:
     """Engine knobs.  ``max_slots`` is the decode-batch width (the
-    concurrency cap); memory is ``n_blocks`` × ``kv_block_size`` tokens
-    when paged (decoupled from the row count — the mixed-length sharing
-    win), or ``max_slots`` × ``max_seq`` tokens in slot mode."""
+    concurrency cap); memory is ``n_blocks`` × ``kv_block_size`` tokens,
+    decoupled from the row count (short and long sequences share one
+    pool)."""
     max_slots: int = 8
     max_seq: Optional[int] = None        # cache width; None = model max_seq
     eos_token: Optional[int] = None      # None = never stop early
     default_max_new: int = 64
     max_waiting: int = 1024              # admission-queue bound (backpressure)
     idle_wait_s: float = 0.05            # loop park interval when empty
-    # ---- paged cache (the production path; False = r14 slot engine,
-    # kept in-tree as the benchmark's same-run A/B baseline)
-    paged: bool = True
+    # ---- paged cache
     kv_block_size: int = 16              # tokens per block
     n_blocks: Optional[int] = None       # usable blocks; None = max_slots
-    #                                      * ceil(max_seq/block) (same
-    #                                      bytes as the slot pool)
+    #                                      * ceil(max_seq/block)
     prefill_chunk: int = 32              # chunked-prefill window width
     prefix_cache: bool = True            # radix prefix reuse on/off
-    # ---- speculative decoding (draft-then-verify; paged engine only).
-    # None = off (the same-run A/B baseline); "ngram" = host-side
+    # ---- speculative decoding (draft-then-verify).
+    # None = off; "ngram" = host-side
     # prompt-lookup drafting against the request's own prompt+history;
     # "self" = truncated-layer self-draft (the first ``draft_layers``
     # layers straight into the head).  Greedy requests emit the EXACT
@@ -448,15 +440,12 @@ class _KVAndState:
     def refuse(ec: "EngineConfig", mesh) -> None:
         """What a recurrent state makes impossible today, refused at
         construction: speculation (a rejected draft cannot be rolled
-        back out of a state), the slot cache, a mesh."""
+        back out of a state), a mesh."""
         if ec.speculate is not None:
             raise SpeculationUnsupported(
                 "speculative decoding needs a cache that can roll "
                 "rejected tokens back; a recurrent state cannot (no "
                 "snapshot at the draft's start yet)")
-        if not ec.paged:
-            raise ValueError("a model with recurrent layers is served "
-                             "by the paged engine only")
         if mesh is not None:
             raise ValueError("a model with recurrent layers is served "
                              "on one device (no sharding rules yet)")
@@ -632,65 +621,47 @@ class InferenceEngine:
             self.params = jax.device_put(
                 self.params, tree_shardings(axes, rules, mesh))
         n = ec.max_slots
-        self._paged = bool(ec.paged)
         self._spec = ec.speculate
         if self._spec is not None:
-            # the typed capability boundary, at CONSTRUCTION time like
-            # MoEDecodeUnsupported: the slot engine is the frozen A/B
-            # baseline and grows no speculation path
             if self._spec not in ("ngram", "self"):
                 raise ValueError(
                     f"speculate must be None, 'ngram' or 'self', got "
                     f"{self._spec!r}")
-            if not self._paged:
-                raise SpeculationUnsupported(
-                    "speculative decoding needs the paged engine "
-                    "(EngineConfig.paged=True); the slot engine is the "
-                    "non-speculative A/B baseline")
             if ec.speculate_k < 1:
                 raise ValueError(
                     f"speculate_k must be >= 1, got {ec.speculate_k}")
-        if self._paged:
-            bs = ec.kv_block_size
-            per_seq = -(-int(ec.max_seq or cfg.max_seq) // bs)
-            n_blocks = ec.n_blocks if ec.n_blocks is not None else n * per_seq
-            self.pool = BlockPool(cfg, n_blocks, bs, max_seq=ec.max_seq,
-                                  mesh=mesh, rules=rules, state_rows=n)
-            self.cache = None
-            self.max_seq = self.pool.max_seq
-            # a cached prefix is its K/V blocks: with recurrent layers
-            # that is no longer the whole of a prefix, so nothing is
-            # adopted (no index) — by derivation, not by an option
-            self.trie = (RadixIndex(self.pool)
-                         if ec.prefix_cache and not recurrent else None)
-            self._seam.build(self, bs)
-            if self._spec is not None:
-                self._verify = make_spec_verify_step(
-                    cfg, width=ec.speculate_k + 1, block_size=bs,
-                    n_table=self.pool.blocks_per_seq, mesh=mesh,
-                    rules=rules)
-                # "self" additionally compiles the truncated-layer
-                # drafter (raises SpeculationUnsupported on a bad
-                # draft_layers — still construction time)
-                self._draft = (make_paged_draft_step(
-                    cfg, draft_layers=ec.draft_layers,
-                    k=ec.speculate_k, block_size=bs,
-                    n_table=self.pool.blocks_per_seq, mesh=mesh,
-                    rules=rules) if self._spec == "self" else None)
-            self._load = []            # recurrent programs' load vectors,
-            self._greedy = None        # the last step's greedy tokens and
-            self._first_pending = []   # [row, request, load, token] owed
-            self._tables = np.zeros((n, self.pool.blocks_per_seq), np.int32)
-            self._row_blocks: dict[int, list[int]] = {}
-            self._free_rows = list(range(n - 1, -1, -1))
-            self._prefilling: dict[int, int] = {}   # row -> next prefill pos
-        else:
-            self.pool = None
-            self.trie = None
-            self.cache = KVCacheManager(cfg, n, max_seq=ec.max_seq)
-            self.max_seq = self.cache.max_seq
-            self._prefill = make_prefill_fn(cfg, mesh=mesh, rules=rules)
-            self._step = make_decode_step(cfg, mesh=mesh, rules=rules)
+        bs = ec.kv_block_size
+        per_seq = -(-int(ec.max_seq or cfg.max_seq) // bs)
+        n_blocks = ec.n_blocks if ec.n_blocks is not None else n * per_seq
+        self.pool = BlockPool(cfg, n_blocks, bs, max_seq=ec.max_seq,
+                              mesh=mesh, rules=rules, state_rows=n)
+        self.max_seq = self.pool.max_seq
+        # a cached prefix is its K/V blocks: with recurrent layers
+        # that is no longer the whole of a prefix, so nothing is
+        # adopted (no index) — by derivation, not by an option
+        self.trie = (RadixIndex(self.pool)
+                     if ec.prefix_cache and not recurrent else None)
+        self._seam.build(self, bs)
+        if self._spec is not None:
+            self._verify = make_spec_verify_step(
+                cfg, width=ec.speculate_k + 1, block_size=bs,
+                n_table=self.pool.blocks_per_seq, mesh=mesh,
+                rules=rules)
+            # "self" additionally compiles the truncated-layer
+            # drafter (raises SpeculationUnsupported on a bad
+            # draft_layers — still construction time)
+            self._draft = (make_paged_draft_step(
+                cfg, draft_layers=ec.draft_layers,
+                k=ec.speculate_k, block_size=bs,
+                n_table=self.pool.blocks_per_seq, mesh=mesh,
+                rules=rules) if self._spec == "self" else None)
+        self._load = []            # recurrent programs' load vectors,
+        self._greedy = None        # the last step's greedy tokens and
+        self._first_pending = []   # [row, request, load, token] owed
+        self._tables = np.zeros((n, self.pool.blocks_per_seq), np.int32)
+        self._row_blocks: dict[int, list[int]] = {}
+        self._free_rows = list(range(n - 1, -1, -1))
+        self._prefilling: dict[int, int] = {}   # row -> next prefill pos
 
         self._slot_req: dict[int, GenerationRequest] = {}
         self._tokens = np.zeros(n, np.int32)      # current input token
@@ -778,8 +749,8 @@ class InferenceEngine:
         mixed batches are the serving norm, and a sampled request on a
         speculating engine is valid work, not an error).  The typed
         ``SpeculationUnsupported`` is reserved for configurations with
-        no speculation path at all (slot engine, bad draft depth) and
-        raised at engine construction."""
+        no speculation path at all (a recurrent state, bad draft depth)
+        and raised at engine construction."""
         ec = self.engine_cfg
         prompt = np.asarray(list(prompt), np.int32)
         max_new = int(max_new if max_new is not None else ec.default_max_new)
@@ -836,14 +807,14 @@ class InferenceEngine:
             with self._cond:
                 # park unless there is work a pass can make progress
                 # on: an active row to decode, a prefill to advance, or
-                # a waiting request AND a free slot/row to admit it into
+                # a waiting request AND a free row to admit it into
                 # (waiting alone must not spin when the pool is handed
-                # out; paged admission retries at the idle tick because
+                # out; admission retries at the idle tick because
                 # block availability also depends on evictable cached
                 # prefixes)
                 if (not self._stopped and not self._ops
                         and not self._active.any()
-                        and not (self._paged and self._prefilling)
+                        and not self._prefilling
                         and not (self._waiting
                                  and self._admission_possible())):
                     # ONE bounded wait, then back out to _engine_loop:
@@ -856,43 +827,27 @@ class InferenceEngine:
                 sp = tracing.span("engine.pass").__enter__()
                 if sp:
                     sp.set(active=int(self._active.sum()),
-                           prefilling=(len(self._prefilling)
-                                       if self._paged else 0),
+                           prefilling=len(self._prefilling),
                            waiting=len(self._waiting))
-                admits = self._schedule_locked()
-            for slot, req in admits:
-                # per-admit isolation: one bad prefill fails ONE
-                # request and returns its slot; neighbors proceed
-                try:
-                    self._admit(slot, req)
-                except Exception as e:
-                    try:
-                        self.cache.free(slot)
-                    except ValueError:        # _admit already returned it
-                        pass
-                    req._finish(e)
+                self._schedule_locked()
             try:
-                if self._paged:
-                    if self._prefilling:
-                        # at most ONE chunk per pass: prefill progress is
-                        # interleaved with decode so a long prompt cannot
-                        # stall its neighbors' token cadence
-                        self._prefill_chunk_pass()
-                    if self._active.any():
-                        self._paged_decode_iteration()
-                    self._seam.pass_done(self)
-                elif self._active.any():
-                    self._decode_iteration()
+                if self._prefilling:
+                    # at most ONE chunk per pass: prefill progress is
+                    # interleaved with decode so a long prompt cannot
+                    # stall its neighbors' token cadence
+                    self._prefill_chunk_pass()
+                if self._active.any():
+                    self._paged_decode_iteration()
+                self._seam.pass_done(self)
             except Exception as e:            # step failure: fail the
                 self._fail_all(e)             # in-flight requests, keep serving
             return True
         finally:
             sp.__exit__(*sys.exc_info())
 
-    def _schedule_locked(self) -> list:
+    def _schedule_locked(self) -> None:
         """The pass's scheduling under ``_cond``: cross-thread ops,
-        reaping, admission.  Returns the slot engine's (slot, request)
-        admits, whose prefill runs outside the lock."""
+        reaping, admission."""
         with tracing.span("engine.schedule") as sp:
             # only this thread writes the two counters
             admitted0, preempted0 = self._admissions, self._preemptions
@@ -909,28 +864,14 @@ class InferenceEngine:
                 else:
                     live.append(r)
             self._waiting = live
-            admits = []
-            if self._paged:
-                self._paged_admit_locked()
-            else:
-                if self._waiting and self.cache.n_free > 0:
-                    # prefill-boundary preemption: freed slots go to the
-                    # most urgent class first (stable within a class —
-                    # the sort key is (priority, submit id))
-                    self._waiting.sort(key=lambda r: (r.priority, r.id))
-                while self._waiting and self.cache.n_free > 0:
-                    req = self._waiting.pop(0)
-                    admits.append((self.cache.alloc(), req))
+            self._paged_admit_locked()
             if sp:
-                sp.set(admitted=self._admissions - admitted0 + len(admits),
+                sp.set(admitted=self._admissions - admitted0,
                        preempted=self._preemptions - preempted0)
-        return admits
 
     def _admission_possible(self) -> bool:
         """Cheap park-predicate check; the real budget decision happens
         in the admission pass."""
-        if not self._paged:
-            return self.cache.n_free > 0
         return bool(self._free_rows) and (
             self.pool.n_free > 0
             or (self.trie is not None and self.trie.cached_blocks > 0))
@@ -954,40 +895,6 @@ class InferenceEngine:
         for r in pending:
             if not r.done:
                 r._finish(err)
-
-    def _admit(self, slot: int, req: GenerationRequest) -> None:
-        """Prefill boundary: seed the slot's cache, emit the first token."""
-        if req.cancelled:                 # abandoned while queued
-            self.cache.free(slot)
-            req._finish()
-            return
-        S = self.cache.max_seq
-        n = int(req.prompt.size)
-        req._admitted()
-        req.full_width_prefill = True
-        self._admissions += 1
-        self._prefill_tokens += n
-        with tracing.span("engine.prefill_chunk", row=slot, tokens=n,
-                          full_width=True):
-            padded = np.zeros((1, S), np.int32)
-            padded[0, :n] = req.prompt
-            with tracing.span("engine.dispatch"):
-                logits, k_new, v_new = self._prefill(self.params, padded)
-                self.cache.write_prefill(slot, k_new[:, 0], v_new[:, 0])
-            tok = self._first_token(req, logits[0, n - 1])
-        req._emit(tok)
-        if self._request_finished(req, tok):
-            self.cache.free(slot)
-            req._finish()
-            self._note_done(req)
-            return
-        self._slot_req[slot] = req
-        self._tokens[slot] = tok
-        self._positions[slot] = n
-        self._active[slot] = True
-        with self._mlock:
-            self._peak_active = max(self._peak_active,
-                                    self.cache.n_active)
 
     # ----------------------------------------------------------- paged path
 
@@ -1029,8 +936,7 @@ class InferenceEngine:
             # a sharded fleet says WHICH mesh served each request
             ev["mesh_devices"] = int(np.prod(
                 list(self._mesh.devices.shape)))
-            ev["tp_shards"] = (self.pool.heads_shards
-                               if self.pool is not None else 1)
+            ev["tp_shards"] = self.pool.heads_shards
         rec.note_ingress(ev)
 
     def _paged_admit_locked(self) -> None:
@@ -1038,7 +944,8 @@ class InferenceEngine:
         a decode row is free AND the pool covers the prompt after
         prefix-hit credit.  Head-of-line within (priority, arrival)
         order — a large request that does not fit yet is not overtaken
-        (no starvation)."""
+        (no starvation), and a freed row goes to the most urgent class
+        first."""
         if not (self._waiting and self._free_rows):
             return
         self._waiting.sort(key=lambda r: (r.priority, r.id))
@@ -1709,59 +1616,6 @@ class InferenceEngine:
         req._finish()
         self._note_done(req)
 
-    # ------------------------------------------------------------ slot path
-
-    def _decode_iteration(self) -> None:
-        with tracing.span("engine.decode", speculative=False) as sp:
-            if sp:
-                sp.set(active=int(self._active.sum()))
-            with tracing.span("engine.upload") as up:
-                args = (jnp.asarray(self._tokens),
-                        jnp.asarray(self._positions),
-                        jnp.asarray(self._active))
-                if up:
-                    up.set(bytes=sum(a.nbytes for a in args))
-            with tracing.span("engine.dispatch"):
-                logits, k, v = self._step(self.params, self.cache.k,
-                                          self.cache.v, *args)
-                self.cache.swap(k, v)
-            with tracing.span("engine.fetch") as fetch:
-                logits = np.asarray(logits)
-                fetch.set(bytes=logits.nbytes)
-            with self._mlock:
-                self._decode_iterations += 1
-                self._occupancy_sum += (float(self._active.sum())
-                                        / self.engine_cfg.max_slots)
-            with tracing.span("engine.sample") as sample:
-                # greedy rows sample in ONE vectorized call (the common/
-                # benchmark path: one argmax over [n_slots, vocab], not
-                # one dispatch per slot); temperature rows keep their
-                # per-request rng
-                greedy = np.asarray(gpt.sample_token(logits,
-                                                     temperature=0.0))
-                stepped = 0
-                for slot in list(self._slot_req):
-                    req = self._slot_req[slot]
-                    if req.cancelled:     # abandoned (timeout/disconnect):
-                        self._evict(slot)   # free the slot for live work
-                        continue
-                    if req.temperature == 0.0:
-                        tok = int(greedy[slot])
-                    else:
-                        tok = int(gpt.sample_token(
-                            logits[slot], temperature=req.temperature,
-                            rng=req._next_rng()))
-                    req._emit(tok)
-                    stepped += 1
-                    self._positions[slot] += 1
-                    self._tokens[slot] = tok
-                    if self._request_finished(req, tok):
-                        self._evict(slot)
-                sample.set(rows=stepped)
-        with self._mlock:
-            self._row_steps += stepped
-            self._row_tokens += stepped
-
     def _request_finished(self, req: GenerationRequest, tok: int) -> bool:
         with self._mlock:
             self._generated_tokens += 1
@@ -1769,56 +1623,35 @@ class InferenceEngine:
         return (len(req.tokens) >= req.max_new
                 or (eos is not None and tok == eos))
 
-    def _evict(self, slot: int) -> None:
-        req = self._slot_req.pop(slot)
-        self._active[slot] = False
-        self.cache.free(slot)
-        req._finish()
-        self._note_done(req)
-        with self._cond:
-            self._cond.notify_all()   # wake loop in case admits are waiting
-
     def _note_done(self, req: GenerationRequest) -> None:
         with self._mlock:
             self._requests_completed += 1
         self._fr_note(req)
 
     def _fail_all(self, e: BaseException) -> None:
-        if self._paged:
-            # a failed chunk/step may have invalidated the DONATED pool
-            # buffers; reallocate the pool, drop every reference, and —
-            # critically — clear the prefix index: cached prefixes would
-            # otherwise point at zeroed blocks and silently corrupt
-            # every later prefix hit (the r10 recovery rule generalized
-            # to blocks)
-            failed = [self._slot_req.pop(row)
-                      for row in list(self._slot_req)]
-            self._active[:] = False
-            self._prefilling.clear()
-            self._row_blocks.clear()
-            self._tables[:, :] = 0
-            if self.trie is not None:
-                self.trie.clear()
-            self.pool.reset()
-            with self._cond:
-                self._free_rows = list(
-                    range(self.engine_cfg.max_slots - 1, -1, -1))
-                self._cond.notify_all()
-            # unblock the waiters only AFTER the pool/index are
-            # consistent again, so a result() caller reading stats sees
-            # the recovered state, not the mid-teardown one
-            for req in failed:
-                req._finish(e)
-            return
-        for slot in list(self._slot_req):
-            req = self._slot_req.pop(slot)
-            self._active[slot] = False
-            self.cache.free(slot)
+        # a failed chunk/step may have invalidated the DONATED pool
+        # buffers; reallocate the pool so the engine keeps serving, drop
+        # every reference, and — critically — clear the prefix index:
+        # cached prefixes would otherwise point at zeroed blocks and
+        # silently corrupt every later prefix hit
+        failed = [self._slot_req.pop(row)
+                  for row in list(self._slot_req)]
+        self._active[:] = False
+        self._prefilling.clear()
+        self._row_blocks.clear()
+        self._tables[:, :] = 0
+        if self.trie is not None:
+            self.trie.clear()
+        self.pool.reset()
+        with self._cond:
+            self._free_rows = list(
+                range(self.engine_cfg.max_slots - 1, -1, -1))
+            self._cond.notify_all()
+        # unblock the waiters only AFTER the pool/index are
+        # consistent again, so a result() caller reading stats sees
+        # the recovered state, not the mid-teardown one
+        for req in failed:
             req._finish(e)
-        # the failed step may have invalidated the donated cache buffers
-        # (decode_step donates them); reallocate so the engine actually
-        # keeps serving instead of poisoning every later request
-        self.cache.reset_arrays()
 
     # ------------------------------------------------------------- admin
 
@@ -1903,9 +1736,9 @@ class InferenceEngine:
 
     def prefix_export(self) -> list:
         """Drain the prefix publication outbox (cluster-directory feed).
-        Empty on non-paged / no-trie engines — the plane then has
+        Empty on an engine with no prefix index — the plane then has
         nothing to advertise for this replica."""
-        if not self._paged or self.trie is None:
+        if self.trie is None:
             return []
         with self._mlock:
             out, self._prefix_outbox = self._prefix_outbox, []
@@ -1921,7 +1754,7 @@ class InferenceEngine:
         loop thread via the op queue; a dying engine resolves the op as
         EngineStoppedError.  All three are PrefixTransferError /
         ReplicaDeadError shapes the adopter maps to local recompute."""
-        if not self._paged or self.trie is None:
+        if self.trie is None:
             raise PrefixUnavailable("engine has no prefix index")
         toks = np.asarray(list(tokens), np.int32)
         bs = self.pool.block_size
@@ -1963,7 +1796,7 @@ class InferenceEngine:
         pressure it evicts unreferenced cached prefixes only, then
         gives up with PrefixInstallPressure (adoption is an
         optimization; real work is not)."""
-        if not self._paged or self.trie is None:
+        if self.trie is None:
             raise PrefixUnavailable("engine has no prefix index")
         toks = np.asarray(list(tokens), np.int32)
         bs = self.pool.block_size
@@ -2020,8 +1853,7 @@ class InferenceEngine:
                               if r.priority <= PRIORITY_INTERACTIVE)
             stopped = self._stopped
             draining = self._draining
-            occupied = (self.engine_cfg.max_slots - len(self._free_rows)
-                        if self._paged else None)
+            occupied = self.engine_cfg.max_slots - len(self._free_rows)
         with self._mlock:
             iters = self._decode_iterations
             occ = (self._occupancy_sum / iters) if iters else 0.0
@@ -2063,9 +1895,7 @@ class InferenceEngine:
             # can reduce exactly instead of averaging averages
             "row_steps": row_steps,
             "row_tokens": row_tokens,
-            "paged": self._paged,
-            # ---- speculative decoding (zeros when speculate=None /
-            # slot engine — the same-run baselines stay comparable)
+            # ---- speculative decoding (zeros when speculate=None)
             "speculate": self._spec,
             "spec_drafted_tokens": drafted,
             "spec_accepted_tokens": accepted,
@@ -2078,62 +1908,51 @@ class InferenceEngine:
             "mesh_axes": (dict(zip(self._mesh.axis_names,
                                    self._mesh.devices.shape))
                           if self._mesh is not None else {}),
-            "tp_shards": (self.pool.heads_shards
-                          if self._paged and self.pool is not None else 1),
+            "tp_shards": self.pool.heads_shards,
             # the tree the programs are handed, and how much of it they
             # cast to the dtype they compute in EVERY pass (0: each such
             # leaf is stored in it; by shapes and dtypes at construction)
             "weight_bytes": self._weight_bytes,
             "weight_bytes_cast_per_pass": self._weight_bytes_cast,
         }
-        if self._paged:
-            pool = self.pool.stats()
-            total = pool["blocks_total"]
-            out.update({
-                # occupied rows (decoding + prefilling): the same
-                # concurrency meaning the slot engine reported
-                "active_slots": occupied,
-                "free_slots": self.engine_cfg.max_slots - occupied,
-                "cache_bytes": pool["bytes_total"],
-                "cache_bytes_per_device": pool["bytes_per_device"],
-                "block_size": pool["block_size"],
-                # block COUNTS are replicated across tp shards (heads
-                # are what's split): blocks_total is the global
-                # admission budget AND the per-device count — both
-                # keys reported so neither meaning is silently guessed
-                "blocks_total": total,
-                "blocks_per_device": pool["blocks_per_device"],
-                "blocks_free": pool["blocks_free"],
-                "block_utilization": (pool["blocks_used"] / total
-                                      if total else 0.0),
-                "prefix_cached_blocks": (self.trie.cached_blocks
-                                         if self.trie is not None else 0),
-                "prefix_hit_tokens": hit_toks,
-                "prefix_lookup_tokens": lookup_toks,
-                "prefix_hit_rate": (hit_toks / lookup_toks
-                                    if lookup_toks else 0.0),
-                "preemptions": preemptions,
-                "peak_active_requests": peak,
-                # the second kind of state (zeros for a model that keeps
-                # none) and the routed experts' load (zeros for a model
-                # whose programs report none)
-                "state_bytes": pool["state_bytes"],
-                "state_rows_in_use": pool["state_rows_in_use"],
-                "expert_assignments_held": self._expert_held,
-                "expert_assignments_total": self._expert_total,
-                "expert_load_max": self._expert_load_max,
-                # fences remotely-advertised block ids across donated-
-                # pool recoveries (cluster prefix plane)
-                "pool_generation": pool["generation"],
-            })
-        else:
-            cache = self.cache.stats()
-            out.update({
-                "active_slots": cache["active_slots"],
-                "free_slots": cache["free_slots"],
-                "cache_bytes": cache["bytes_total"],
-                "peak_active_requests": peak,
-            })
+        pool = self.pool.stats()
+        total = pool["blocks_total"]
+        out.update({
+            # occupied rows (decoding + prefilling)
+            "active_slots": occupied,
+            "free_slots": self.engine_cfg.max_slots - occupied,
+            "cache_bytes": pool["bytes_total"],
+            "cache_bytes_per_device": pool["bytes_per_device"],
+            "block_size": pool["block_size"],
+            # block COUNTS are replicated across tp shards (heads
+            # are what's split): blocks_total is the global
+            # admission budget AND the per-device count — both
+            # keys reported so neither meaning is silently guessed
+            "blocks_total": total,
+            "blocks_per_device": pool["blocks_per_device"],
+            "blocks_free": pool["blocks_free"],
+            "block_utilization": (pool["blocks_used"] / total
+                                  if total else 0.0),
+            "prefix_cached_blocks": (self.trie.cached_blocks
+                                     if self.trie is not None else 0),
+            "prefix_hit_tokens": hit_toks,
+            "prefix_lookup_tokens": lookup_toks,
+            "prefix_hit_rate": (hit_toks / lookup_toks
+                                if lookup_toks else 0.0),
+            "preemptions": preemptions,
+            "peak_active_requests": peak,
+            # the second kind of state (zeros for a model that keeps
+            # none) and the routed experts' load (zeros for a model
+            # whose programs report none)
+            "state_bytes": pool["state_bytes"],
+            "state_rows_in_use": pool["state_rows_in_use"],
+            "expert_assignments_held": self._expert_held,
+            "expert_assignments_total": self._expert_total,
+            "expert_load_max": self._expert_load_max,
+            # fences remotely-advertised block ids across donated-
+            # pool recoveries (cluster prefix plane)
+            "pool_generation": pool["generation"],
+        })
         return out
 
     def shutdown(self, timeout: float = 5.0) -> None:
@@ -2167,9 +1986,8 @@ def metrics_snapshot() -> list:
         occ[key] = float(st["batch_occupancy"])
         gen[key] = float(st["generated_tokens"])
         comp[key] = float(st["requests_completed"])
-        # paged-cache capacity signal (slot engines report 0): the
-        # router/autoscaler read these through fleet_stats, operators
-        # through /metrics
+        # paged-cache capacity signal: the router/autoscaler read these
+        # through fleet_stats, operators through /metrics
         butil[key] = float(st.get("block_utilization", 0.0))
         phit[key] = float(st.get("prefix_hit_rate", 0.0))
         pcached[key] = float(st.get("prefix_cached_blocks", 0))

@@ -7,10 +7,10 @@ chunk]`` — with the past supplied from the two kinds of pool the cache
 manager owns (inference/cache.py):
 
   * an attention layer's keys come from the K/V ``BlockPool`` through
-    the row's block table, exactly as in decode.py's GPT programs
-    (``_write_then_read``: commit the window's K/V, then gather the
-    table; ``packed_attention`` attends the rows as stored, grouped
-    queries sharing their K/V head's lanes);
+    the row's block table through the ``attend`` decode.py's GPT
+    programs use (``decode.paged_attend``: commit the window's K/V,
+    then gather the table; ``packed_attention`` attends the rows as
+    stored, grouped queries sharing their K/V head's lanes);
   * a Mamba layer's convolution and SSM state come from the
     ``StatePool`` at the window's decode rows and go back there.
 
@@ -46,29 +46,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.inference.cache import PoolLayout
-from ray_tpu.inference.decode import _cached, _write_then_read
+from ray_tpu.inference.decode import _cached, paged_attend
 from ray_tpu.models import hybrid
 from ray_tpu.models.hybrid import HybridConfig
-from ray_tpu.ops.attention import packed_attention
-
-
-def _paged_attend(cfg, lay, pools, bidx, off, tables, **mask):
-    """``attend_for(ai)`` over the K/V pools: returns the function and
-    the holder it leaves the updated pools in."""
-    held = {"pools": pools}
-
-    def attend_for(ai):
-        def attend(q, k, v):
-            held["pools"], (ctx_k, ctx_v) = _write_then_read(
-                lay, held["pools"], ai, bidx, off,
-                (k.reshape(*bidx.shape, *k.shape[2:]),
-                 v.reshape(*bidx.shape, *v.shape[2:])),
-                tables, None, None)
-            return packed_attention(
-                q, ctx_k, ctx_v, q_per_kv=cfg.n_heads // cfg.n_kv_heads,
-                scale=cfg.attention_multiplier, **mask)
-        return attend
-    return attend_for, held
 
 
 def pack_step(tables, tokens, positions, active) -> np.ndarray:
@@ -116,9 +96,10 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
             bidx = jnp.where(active, tables[rows, positions // bs], 0)
             off = jnp.where(active, positions % bs, 0)
             kv_len = jnp.where(active, positions + 1, 1)      # >=1: no NaN
-            attend_for, kv = _paged_attend(
-                cfg, lay, (k_pool, v_pool), bidx, off, tables,
-                kv_lengths=kv_len)
+            attend_for, kv = paged_attend(
+                lay, (k_pool, v_pool), bidx, off, tables,
+                q_per_kv=cfg.n_heads // cfg.n_kv_heads,
+                scale=cfg.attention_multiplier, kv_lengths=kv_len)
             state = {"conv": conv, "ssm": ssm}
 
             def state_out(mi, new):
@@ -172,9 +153,10 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
             bidx = jnp.where(oob, 0, table[safe // bs])[None]   # [1, C]
             off = jnp.where(oob, 0, pos % bs)[None]
             mask = (jnp.arange(S)[None, :] <= pos[:, None])[None, None]
-            attend_for, kv = _paged_attend(
-                cfg, lay, (k_pool, v_pool), bidx, off, table[None],
-                mask=mask)
+            attend_for, kv = paged_attend(
+                lay, (k_pool, v_pool), bidx, off, table[None],
+                q_per_kv=cfg.n_heads // cfg.n_kv_heads,
+                scale=cfg.attention_multiplier, mask=mask)
             state = {"conv": conv, "ssm": ssm}
 
             def state_in(mi):

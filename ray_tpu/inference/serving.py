@@ -242,8 +242,7 @@ class GPTServer:
             # paged-cache capacity signal: the occupancy router and the
             # autoscaler see BLOCK pressure, not just row counts — a
             # replica whose rows are free but whose pool is nearly full
-            # is not actually spare capacity (0s when every engine runs
-            # the legacy slot pool)
+            # is not actually spare capacity
             # block counts are GLOBAL admission budgets (replicated in
             # count across tp shards — heads are what's split), so
             # summing across engines needs no per-shard correction

@@ -216,8 +216,8 @@ def init_params(cfg: GPTConfig, rng: jax.Array):
 
 
 # the per-layer leaves every program multiplies or adds in ``cfg.dtype``
-# (``.astype(cfg.dtype)`` at use, in _transformer_layer, _moe_mlp and
-# inference/decode.py's bodies); with the head's matrix, all of them
+# (``.astype(cfg.dtype)`` at use, in _transformer_layer and _moe_mlp);
+# with the head's matrix, all of them
 CAST_AT_USE = ("wqkv", "wo", "bo", "w_up", "b_up", "w_down", "b_down")
 
 
@@ -381,27 +381,42 @@ def _moe_mlp(y, lp, cfg: GPTConfig, mesh: Optional[Mesh], rules: Rules):
     return out, aux
 
 
+def causal_attend(cfg: GPTConfig, mesh: Optional[Mesh], rules: Rules):
+    """``attend`` over the window's own keys: a whole sequence, causal
+    (training, the oracle, the full-width prefill)."""
+    def attend(q, k, v):
+        return _attend(q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                       cfg, mesh, rules)
+    return attend
+
+
 def _transformer_layer(x, lp, cfg: GPTConfig, mesh: Optional[Mesh],
-                       rules: Rules, return_kv: bool = False):
-    """One pre-LN transformer block; x [b, s, d], lp = one layer's params
-    (no leading layers dim).  Returns (x, moe aux loss — 0 when dense);
-    with ``return_kv`` also the per-head K/V ([b, h, s, hd] each) so a
-    prefill pass can seed an incremental-decode cache
-    (ray_tpu.inference.decode)."""
-    b, s, _ = x.shape
+                       rules: Rules, attend, return_kv: bool = False):
+    """One pre-LN transformer block, THE statement of it: training and
+    every serving program run this function on their window.  x
+    [b, w, d], lp = one layer's params (no leading layers dim).
+    ``attend(q [b, h, w, hd], k [b, w, h, hd], v [b, w, h, hd]) ->
+    o [b, h, w, hd]`` supplies the keys of the past: ``causal_attend``
+    has none but the window's own, a paged program's commits the
+    window's K/V to its pool and reads the rows' tables back
+    (inference/decode.paged_attend).  Returns (x, moe aux loss — 0 when
+    dense); with ``return_kv`` also the per-head K/V ([b, h, w, hd]
+    each) with which a full-width prefill seeds a cache.
+
+    A MoE layer routes each token on its own, so a serving window routes
+    as the whole sequence does; expert CAPACITY is per window (C =
+    ceil(cf·k·w/E)), so a window's tokens equal the full forward's
+    whenever capacity never binds (capacity_factor >= n_experts /
+    expert_top_k guarantees it; a one-token window can never drop)."""
+    b, w, _ = x.shape
     h, hd = cfg.n_heads, cfg.head_dim
 
     y = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
     qkv = jnp.einsum("bsd,de->bse", y, lp["wqkv"].astype(cfg.dtype))
     qkv = _constrain(qkv, ("batch", "seq", "qkv"), mesh, rules)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-
-    def heads(t):  # [b, s, d] -> [b, h, s, hd]
-        return t.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-
-    kh, vh = heads(k), heads(v)
-    o = _attend(heads(q), kh, vh, cfg, mesh, rules)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.d_model)
+    q, k, v = (t.reshape(b, w, h, hd) for t in jnp.split(qkv, 3, axis=-1))
+    o = attend(q.transpose(0, 2, 1, 3), k, v)
+    o = o.transpose(0, 2, 1, 3).reshape(b, w, cfg.d_model)
     o = jnp.einsum("bsd,de->bse", o, lp["wo"].astype(cfg.dtype)) \
         + lp["bo"].astype(cfg.dtype)
     x = x + o
@@ -421,22 +436,25 @@ def _transformer_layer(x, lp, cfg: GPTConfig, mesh: Optional[Mesh],
     x = x + dn
     x = _constrain(x, ("batch", "seq", "embed"), mesh, rules)
     if return_kv:
-        return x, aux, (kh, vh)
+        return x, aux, (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
     return x, aux
 
 
 def _layer_scan_body(cfg: GPTConfig, mesh, rules, return_kv: bool = False):
-    """Scan body over a stacked layer dim, rematerialized per cfg.
-    Carry is (x, accumulated moe aux loss); with ``return_kv`` each step
-    also emits that layer's K/V heads (stacked to [L, b, h, s, hd] by the
-    scan — the prefill cache layout)."""
+    """Scan body over a stacked layer dim, rematerialized per cfg: the
+    block over a whole sequence.  Carry is (x, accumulated moe aux
+    loss); with ``return_kv`` each step also emits that layer's K/V
+    heads (stacked to [L, b, h, s, hd] by the scan — the prefill cache
+    layout)."""
+    attend = causal_attend(cfg, mesh, rules)
+
     def layer(carry, lp):
         x, aux = carry
         if return_kv:
-            x, a, kv = _transformer_layer(x, lp, cfg, mesh, rules,
+            x, a, kv = _transformer_layer(x, lp, cfg, mesh, rules, attend,
                                           return_kv=True)
             return (x, aux + a), kv
-        x, a = _transformer_layer(x, lp, cfg, mesh, rules)
+        x, a = _transformer_layer(x, lp, cfg, mesh, rules, attend)
         return (x, aux + a), None
 
     if cfg.remat:

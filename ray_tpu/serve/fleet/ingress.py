@@ -255,8 +255,8 @@ class Fleet:
             "engine_waiting": waiting,
             "ingress_queued": self.admission.queue_depth(),
             "occupancy": (active / slots) if slots else 0.0,
-            # paged-cache capacity across the fleet (0s when replicas
-            # run the legacy slot pool): the REAL memory signal behind
+            # paged-cache capacity across the fleet: the REAL memory
+            # signal behind
             # the row counts, exported at /metrics for the autoscaler's
             # operators and dashboards
             "total_blocks": blocks_total,

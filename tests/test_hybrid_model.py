@@ -424,8 +424,6 @@ def test_recurrent_family_refuses_by_derivation(params):
     for mode in ("ngram", "self"):
         with pytest.raises(SpeculationUnsupported):
             _engine(params, speculate=mode)
-    with pytest.raises(ValueError):
-        _engine(params, paged=False)
 
 
 def test_new_counters_are_exported(params):

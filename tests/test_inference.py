@@ -1,6 +1,7 @@
 """Inference-engine tests: KV-cache decode parity against the
 full-recompute oracle, continuous-batching admission/eviction semantics,
-slot-pool bounds, and metrics well-formedness.
+and metrics well-formedness (the block pool's own bounds:
+tests/test_paged_cache.py).
 
 Everything runs on CPU with GPTConfig.tiny (f32 activations so greedy
 argmax parity is not at the mercy of bf16 ties)."""
@@ -13,8 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.inference import (EngineConfig, InferenceEngine,
-                               KVCacheManager)
+from ray_tpu.inference import EngineConfig, InferenceEngine
 from ray_tpu.models import gpt
 
 
@@ -40,33 +40,6 @@ def engine(params, cfg):
     eng = InferenceEngine(params, cfg, EngineConfig(max_slots=2))
     yield eng
     eng.shutdown()
-
-
-# --------------------------------------------------------------- cache pool
-
-def test_cache_manager_alloc_free_exhaustion(cfg):
-    mgr = KVCacheManager(cfg, n_slots=2, max_seq=32)
-    a, b = mgr.alloc(), mgr.alloc()
-    assert {a, b} == {0, 1}
-    assert mgr.alloc() is None          # exhausted: caller must queue
-    assert mgr.n_free == 0
-    mgr.free(a)
-    assert mgr.n_free == 1
-    assert mgr.alloc() == a
-    mgr.free(b)
-    with pytest.raises(ValueError):     # double free
-        mgr.free(b)
-
-
-def test_cache_manager_bounds(cfg):
-    with pytest.raises(ValueError):
-        KVCacheManager(cfg, n_slots=0)
-    with pytest.raises(ValueError):     # wider than the wpe table
-        KVCacheManager(cfg, n_slots=1, max_seq=cfg.max_seq + 1)
-    mgr = KVCacheManager(cfg, n_slots=4, max_seq=32)
-    st = mgr.stats()
-    assert st["bytes_total"] == 2 * int(np.prod(mgr.k.shape)) * 4  # f32
-    assert st["free_slots"] == 4
 
 
 # ------------------------------------------------------------------ parity
@@ -207,9 +180,6 @@ def test_submit_validation(engine, cfg):
         engine.submit([cfg.vocab_size + 5], max_new=4)
     with pytest.raises(ValueError):                 # overflows the cache
         engine.submit([1] * 60, max_new=60)
-    with pytest.raises(NotImplementedError):        # no MoE decode path
-        from ray_tpu.inference.decode import make_decode_step
-        make_decode_step(gpt.GPTConfig.tiny_moe())
 
 
 def test_shutdown_fails_pending(params, cfg):
@@ -247,12 +217,11 @@ def test_cancel_waiting_and_active_frees_slots(params, cfg):
 
 
 def test_admit_failure_isolated_no_slot_leak(params, cfg):
-    """Slot mode: a prefill failure fails ONE request, returns its slot,
-    and the engine keeps serving (no pool shrinkage, no busy-spin).
-    (The paged path's prefill DONATES the pool, so its failure semantics
-    are recovery, not isolation — test_paged_cache.py covers that.)"""
-    eng = InferenceEngine(params, cfg,
-                          EngineConfig(max_slots=2, paged=False))
+    """A failure of the full-width prefill (a cold prompt longer than
+    half the cache width, on an idle engine) fails the request with the
+    error it raised, hands every row and block back, and the engine
+    keeps serving."""
+    eng = InferenceEngine(params, cfg, EngineConfig(max_slots=2))
     try:
         real_prefill = eng._prefill
         boom = {"armed": True}
@@ -263,28 +232,19 @@ def test_admit_failure_isolated_no_slot_leak(params, cfg):
             return real_prefill(params_, tokens)
 
         eng._prefill = failing_prefill
-        bad = eng.submit([1, 2], max_new=4)
+        cold = list(range(1, cfg.max_seq // 2 + 9))
+        bad = eng.submit(cold, max_new=4)
         with pytest.raises(RuntimeError, match="injected"):
             bad.result(timeout=60)
-        assert eng.stats()["free_slots"] == 2      # slot came back
+        assert bad.full_width_prefill and not boom
+        st = eng.stats()
+        assert st["free_slots"] == 2                # the row came back
+        assert st["blocks_free"] == st["blocks_total"]
         out = eng.generate([3, 4], max_new=4, timeout=120)
         assert out == _ref_tokens(params, cfg, [3, 4], 4)
-    finally:
-        eng.shutdown()
-
-
-def test_slot_mode_parity_and_reuse(params, cfg):
-    """The legacy slot engine (paged=False — the serving benchmark's
-    same-run A/B baseline) keeps oracle parity and slot recycling."""
-    eng = InferenceEngine(params, cfg,
-                          EngineConfig(max_slots=2, paged=False))
-    try:
-        assert eng.stats()["paged"] is False
-        prompts = [[3, 1, 4, 1, 5], [9, 2, 6], [11, 12]]
-        reqs = [eng.submit(p, max_new=6) for p in prompts]
-        for p, r in zip(prompts, reqs):
-            assert r.result(timeout=120) == _ref_tokens(params, cfg, p, 6)
-        assert eng.stats()["free_slots"] == 2
+        # and the full-width path itself still works
+        out = eng.generate(cold, max_new=4, timeout=120)
+        assert out == _ref_tokens(params, cfg, cold, 4)
     finally:
         eng.shutdown()
 

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.inference import (BlockPool, EngineConfig, InferenceEngine,
-                               MoEDecodeUnsupported, RadixIndex)
+                               RadixIndex)
 from ray_tpu.models import gpt
 
 
@@ -357,11 +357,11 @@ def test_chaos_block_alloc_failure_recovers(params, cfg):
 
 def test_block_budget_concurrency_beats_slot_count(params, cfg):
     """The memory-sharing win: at EQUAL pool tokens, block-granular
-    admission runs more concurrent short requests than the slot pool's
-    worst-case stripes allow (the mixed-length acceptance claim in
-    miniature)."""
-    # pool = 2 x max_seq(64) tokens -> slot engine: 2 concurrent max;
-    # paged engine: 4 rows over the same 128 tokens (16 blocks of 8)
+    admission runs more concurrent short requests than one worst-case
+    ``max_seq`` stripe a request would allow (the mixed-length
+    acceptance claim in miniature)."""
+    # pool = 2 x max_seq(64) tokens: 2 whole stripes, but 4 rows of
+    # short requests over the same 128 tokens (16 blocks of 8)
     eng = InferenceEngine(params, cfg, EngineConfig(
         max_slots=4, kv_block_size=8, n_blocks=16, prefill_chunk=16))
     try:
@@ -383,7 +383,7 @@ def test_moe_paged_decode_parity():
     (gpt.generate runs the same expert dispatch).  capacity_factor=4.0
     = n_experts/top_k·2, so expert capacity never binds — the regime
     where incremental windows and the full-sequence oracle route
-    identically (see decode._mlp_block)."""
+    identically (see gpt._transformer_layer)."""
     moe_cfg = gpt.GPTConfig.tiny_moe(capacity_factor=4.0)
     moe_params = gpt.init_params(moe_cfg, jax.random.PRNGKey(0))
     eng = InferenceEngine(moe_params, moe_cfg, EngineConfig(
@@ -394,26 +394,6 @@ def test_moe_paged_decode_parity():
         assert got == _ref_tokens(moe_params, moe_cfg, prompt, 8)
     finally:
         eng.shutdown()
-
-
-def test_moe_slot_path_still_fails_early_and_typed():
-    """The legacy SLOT path stays the frozen dense A/B baseline: a slot
-    engine over an MoE config still fails with the typed error at
-    CONSTRUCTION time (make_decode_step raises before any submit), and
-    the error points at the paged engine."""
-    moe_cfg = gpt.GPTConfig.tiny_moe()
-    moe_params = gpt.init_params(moe_cfg, jax.random.PRNGKey(0))
-    with pytest.raises(MoEDecodeUnsupported) as ei:
-        InferenceEngine(moe_params, moe_cfg,
-                        EngineConfig(max_slots=2, paged=False))
-    msg = str(ei.value)
-    assert "slot" in msg and "paged" in msg
-    # the typed error is still a NotImplementedError (compat), and the
-    # slot step builder is the raising site
-    assert issubclass(MoEDecodeUnsupported, NotImplementedError)
-    from ray_tpu.inference.decode import make_decode_step
-    with pytest.raises(MoEDecodeUnsupported):
-        make_decode_step(moe_cfg)
 
 
 # -------------------------------------------------------------- metrics

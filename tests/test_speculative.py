@@ -243,11 +243,8 @@ def test_chaos_speculate_raise_takes_recovery_path(params, cfg):
 
 def test_speculation_unsupported_is_typed_and_construction_time(params,
                                                                 cfg):
-    """The capability boundary raises at engine CONSTRUCTION, like
-    MoEDecodeUnsupported — never mid-decode with slots held."""
-    with pytest.raises(SpeculationUnsupported):
-        InferenceEngine(params, cfg, EngineConfig(
-            max_slots=2, paged=False, speculate="ngram"))
+    """The capability boundary raises at engine CONSTRUCTION — never
+    mid-decode with slots held."""
     # bad draft_layers: 0 and >= n_layers have no truncated model
     with pytest.raises(SpeculationUnsupported):
         InferenceEngine(params, cfg, _spec_cfg("self", draft_layers=0))
