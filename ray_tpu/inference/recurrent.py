@@ -100,21 +100,29 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
                 lay, (k_pool, v_pool), bidx, off, tables,
                 q_per_kv=cfg.n_heads // cfg.n_kv_heads,
                 scale=cfg.attention_multiplier, kv_lengths=kv_len)
-            state = {"conv": conv, "ssm": ssm}
+            state = {"conv": [], "ssm": ssm}
 
             def state_out(mi, new):
-                state["conv"] = state["conv"].at[mi].set(new[0])
-                state["ssm"] = state["ssm"].at[mi].set(new[1])
+                state["conv"].append(new[0])
+                state["ssm"] = new[1][0]
 
+            # The SSM state goes in as the whole pool and the layer's
+            # index: the one-token form advances the live rows' state
+            # where it lies (``ssm[mi]`` handed to its kernel would be
+            # a 268 MB copy a layer).  Every row's convolution state is
+            # made anew by a pass, so the layers' are stacked into the
+            # pool once at the end: a pool updated layer by layer is
+            # kept in VMEM by the compiler and moved out and back
+            # around each layer's kernel, 29 MB each way.
             x, load = hybrid.run_layers(
                 cfg, params, hybrid.embed(cfg, params, tokens[:, None]),
                 active.astype(jnp.int32),
-                state_in=lambda mi: (state["conv"][mi], state["ssm"][mi]),
+                state_in=lambda mi: (conv[mi], (state["ssm"], mi)),
                 state_out=state_out, attend_for=attend_for)
             logits = hybrid.head(cfg, params, x[:, 0])
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (logits, jnp.concatenate([load, greedy]), *kv["pools"],
-                    state["conv"], state["ssm"])
+                    jnp.stack(state["conv"]), state["ssm"])
 
         return step
 
@@ -165,12 +173,13 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
                 def row_of(pool):
                     return jax.lax.dynamic_slice(
                         pool, (mi, row) + (0,) * (pool.ndim - 2),
-                        (1, 1) + pool.shape[2:])[0]
-                return row_of(state["conv"]), row_of(state["ssm"])
+                        (1, 1) + pool.shape[2:])
+                # the row's SSM state as a pool of one layer and one row
+                return row_of(state["conv"])[0], (row_of(state["ssm"]), 0)
 
             def state_out(mi, new):
                 state["conv"] = state["conv"].at[mi, row].set(new[0][0])
-                state["ssm"] = state["ssm"].at[mi, row].set(new[1][0])
+                state["ssm"] = state["ssm"].at[mi, row].set(new[1][0][0, 0])
 
             x, load = hybrid.run_layers(
                 cfg, params, hybrid.embed(cfg, params, tokens[None]),

@@ -272,9 +272,10 @@ def _rms_norm(x, w, eps):
 
 
 def _mamba_mixer(cfg, mp, h, state, n_valid):
-    """h [b, w, d]; state (conv [b, K-1, C], ssm [b, H * P, N] f32)
+    """h [b, w, d]; state (conv [b, K-1, C], ssm): the rows' SSM state
+    as ``ops/ssm.ssd`` addresses it, (pool [L, b, H * P, N] f32, layer).
     -> (out [b, w, d], state)."""
-    conv_state, ssm_state = state
+    conv_state, (ssm_pool, ssm_layer) = state
     b, w, _ = h.shape
     di, H, P, N = (cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_head_dim,
                    cfg.ssm_state)
@@ -286,16 +287,14 @@ def _mamba_mixer(cfg, mp, h, state, n_valid):
                                           mp["conv_b"], n_valid)
         x, B, C = jnp.split(xBC, [di, di + N], axis=-1)
         dt = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"])
-        y, ssm_state = ssm.ssd(x.reshape(b, w, H, P), dt,
-                               -jnp.exp(mp["A_log"]), B, C, mp["D"],
-                               ssm_state.reshape(b, H, P, N), n_valid,
-                               cfg.ssm_chunk)
-        ssm_state = ssm_state.reshape(b, H * P, N)
+        y, ssm_pool = ssm.ssd(x.reshape(b, w, H, P), dt,
+                              -jnp.exp(mp["A_log"]), B, C, mp["D"],
+                              ssm_pool, ssm_layer, n_valid, cfg.ssm_chunk)
         y = y.reshape(b, w, di) * jax.nn.silu(z.astype(jnp.float32))
         y = _rms_norm(y, mp["gnorm"], cfg.rms_eps).astype(h.dtype)
     with jax.named_scope("mixer_ssm_proj"):
         out = jnp.dot(y, mp["out_proj"].astype(h.dtype))
-    return out, (conv_state, ssm_state)
+    return out, (conv_state, (ssm_pool, ssm_layer))
 
 
 def _attention_mixer(cfg, ap, h, attend):
@@ -363,8 +362,9 @@ def head(cfg: HybridConfig, params, x):
 def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
                state_out: Callable, attend_for: Callable):
     """The unrolled layer loop over a window.  ``state_in(mi)`` gives
-    Mamba layer ``mi``'s (conv, ssm) state for the window's rows and
-    ``state_out(mi, state)`` takes it back; ``attend_for(ai)`` gives
+    Mamba layer ``mi``'s state for the window's rows, (conv, (ssm pool,
+    layer)) as ``_mamba_mixer`` takes it, and ``state_out(mi, state)``
+    takes it back; ``attend_for(ai)`` gives
     attention layer ``ai``'s ``attend``.
     -> (x, load [3] int32: held assignments, all assignments, and the
         busiest held expert's assignments, each summed over layers)."""
@@ -386,10 +386,11 @@ def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
 
 
 def zero_state(cfg: HybridConfig, rows: int):
-    """(conv, ssm) state of ``rows`` rows that have seen nothing."""
+    """(conv, ssm) state of ``rows`` rows that have seen nothing, the
+    SSM state as a pool of this one layer."""
     _, conv, ssm_shape = cfg.state_geometry
     return (jnp.zeros((rows, *conv), cfg.dtype),
-            jnp.zeros((rows, *ssm_shape), jnp.float32))
+            (jnp.zeros((1, rows, *ssm_shape), jnp.float32), 0))
 
 
 def causal_attend(cfg: HybridConfig):

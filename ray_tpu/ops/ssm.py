@@ -15,15 +15,17 @@ Two forms of the same recurrence, chosen by the window's static width:
     chunk body.  It takes the state the row arrives with and returns
     the state it leaves with.
   * ONE token (``ssd_step``): the recurrence itself, elementwise on the
-    state — a decode pass reads and writes each row's state once.
+    state, as a Pallas kernel over the state POOL where it is stored:
+    a decode pass reads a live row's state once and writes it once, in
+    place, and leaves a row that sits the pass out untouched.
 
 Both take ``n_valid`` [b]: only the first ``n_valid`` tokens of a row's
-window are real.  A token past it has its ``dt`` set to 0, which makes
-it the identity on the state (decay exp(0) = 1, input 0), so a padded
-last chunk of a prompt, and a row that sits a decode pass out, leave
-their state exactly as it was.  The convolution's state (the last
-``K - 1`` inputs) is likewise taken at ``n_valid``, not at the window's
-end.
+window are real.  In the window form a token past it has its ``dt`` set
+to 0, which makes it the identity on the state (decay exp(0) = 1, input
+0), so a padded last chunk of a prompt leaves the state exactly as it
+was; the one-token form does not visit a row with ``n_valid`` 0 at all.
+The convolution's state (the last ``K - 1`` inputs) is likewise taken
+at ``n_valid``, not at the window's end.
 
 The state is float32 and every product that touches it is computed in
 float32: the one-token form on the vector unit (multiply + sum), the
@@ -34,9 +36,12 @@ of 256 tokens that is ~6 GFLOP a layer, nothing beside the projections.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
 
 
 def causal_conv(x, state, w, b, n_valid):
@@ -65,18 +70,141 @@ def _valid_dt(dt, n_valid):
     return jnp.where(live[..., None], dt, 0.0)
 
 
-def ssd_step(x, dt, A, B, C, D, state, n_valid):
-    """One token.  x [b, 1, H, P], dt [b, 1, H] (after softplus), A, D
-    [H], B, C [b, 1, N] (one group), state [b, H, P, N] float32.
-    -> (y [b, 1, H, P] float32, state)."""
-    dt = _valid_dt(dt.astype(jnp.float32), n_valid)[:, 0]       # [b, H]
-    xf = x[:, 0].astype(jnp.float32)                            # [b, H, P]
-    Bf, Cf = B[:, 0].astype(jnp.float32), C[:, 0].astype(jnp.float32)
-    decay = jnp.exp(dt * A)                                     # [b, H]
-    state = (decay[:, :, None, None] * state
-             + (dt[..., None] * xf)[..., None] * Bf[:, None, None, :])
-    y = (state * Cf[:, None, None, :]).sum(-1) + D[:, None] * xf
-    return y[:, None], state
+# rows of a [H * P, N] state that one grid step holds in VMEM (2 MB of
+# float32 at N = 128, in and out each double-buffered, inside the 16 MB
+# a kernel has without asking; read on the chip, PR 32, nine layers of
+# 12 live rows: 1,024 / 2,048 / 4,096 / 8,192 rows took 2.77 / 2.53 /
+# 2.44 / 2.42 ms with the first body and 4,096 / 8,192 take 1.69 / 1.64
+# with this one), the rows one pass of the body's loop takes through
+# the vector unit, and a vector register's lanes
+TILE_ROWS, CHUNK_ROWS, LANES = 4096, 128, 128
+
+
+def _step_kernel(layer_ref, count_ref, rows_ref, decay_ref,
+                 s_ref, dtx_ref, b_ref, c_ref, o_ref, y_ref, *,
+                 head_rows: int):
+    """One tile [TR, N] of one live row's state: read, advanced, reduced
+    to ``y`` and written, in one visit, a chunk of ``ck`` rows at a
+    time.
+
+    ``dtx`` (in) and ``y`` (out) hold one number a state row and travel
+    dense, the state's rows along LANES: block [chunks, ck].  The update
+    needs them along the tile's SUBLANES.  One transpose a tile turns
+    ``dtx`` to [ck, chunks]; chunk c's column is picked out by a lane
+    mask and a lane sum, and ``y``'s column is put into lane c of an
+    accumulator the same way round, which one transpose a tile turns
+    back.  (Two 128 x 128 transposes a CHUNK made the kernel compute
+    bound at 20 us a row and layer; this way it runs at the DMA's 12.)
+    """
+    f32 = jnp.float32
+    slot, t = pl.program_id(0), pl.program_id(1)
+    count = count_ref[0]
+    n_chunks, ck = dtx_ref.shape[1:]
+    heads = ck // head_rows
+
+    @pl.when(slot < count)
+    def _():
+        row = rows_ref[slot]
+        Bv, Cv = b_ref[0], c_ref[0]                             # [1, N]
+        lane = lax.broadcasted_iota(jnp.int32, (ck, LANES), 1)
+        dtx_t = jnp.concatenate(
+            [dtx_ref[0], jnp.zeros((LANES - n_chunks, ck), f32)]).T
+
+        def chunk(c, y_t):
+            r0 = pl.multiple_of(c * ck, ck)
+            head0 = (t * n_chunks + c) * heads
+            dtx = jnp.sum(jnp.where(lane == c, dtx_t, 0.0), axis=1,
+                          keepdims=True)                        # [ck, 1]
+            new = jnp.concatenate([
+                decay_ref[row, head0 + h]
+                * s_ref[0, 0, pl.ds(r0 + h * head_rows, head_rows), :]
+                + dtx[h * head_rows:(h + 1) * head_rows] * Bv
+                for h in range(heads)])                         # [ck, N]
+            o_ref[0, 0, pl.ds(r0, ck), :] = new
+            y = jnp.sum(new * Cv, axis=1, keepdims=True)        # [ck, 1]
+            return jnp.where(lane == c, y, y_t)
+
+        y_t = lax.fori_loop(0, n_chunks, chunk, jnp.zeros((ck, LANES), f32))
+        y_ref[0] = y_t.T[:n_chunks]
+
+    # no row advances: every slot maps to ONE block, which the pipeline
+    # writes back at the end — give it back the bits it was read with
+    @pl.when((count == 0) & (slot == 0) & (t == 0))
+    def _():
+        o_ref[...] = s_ref[...]
+
+
+def ssd_step(x, dt, A, B, C, D, pool, layer, n_valid):
+    """One token, on the state where it is stored.  x [b, 1, H, P], dt
+    [b, 1, H] (after softplus), A, D [H], B, C [b, 1, N] (one group);
+    ``pool`` [L, b, H * P, N] float32 holds the rows' state of L layers
+    and ``layer`` (an int32 scalar, traced or not) says which one this
+    is.  -> (y [b, 1, H, P] float32, pool).
+
+    A Pallas kernel walks the rows that advance (``n_valid > 0``),
+    compacted on the device into a list and a count that it is handed
+    as scalars.  A live row's state is read once, tile by tile, ``y``
+    is reduced from the updated tile while it is in VMEM, and the tile
+    goes back to the place it came from (the pool operand IS the pool
+    result); a row that sits the pass out is neither read nor written
+    (its ``y`` is ``D x``), and no other layer of the pool is touched.
+    The grid is static, row slots x tiles: a slot past the count maps
+    to the block the pipeline already holds, so it moves nothing."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops.flash_attention import _interpret_mode
+
+    f32 = jnp.float32
+    b, _, H, P = x.shape
+    L, _, HP, N = pool.shape
+    ck = CHUNK_ROWS if HP % CHUNK_ROWS == 0 and CHUNK_ROWS % P == 0 else HP
+    tr = TILE_ROWS if HP % TILE_ROWS == 0 and TILE_ROWS % ck == 0 else HP
+    n_tiles, n_chunks = HP // tr, tr // ck
+    if n_chunks > LANES:        # a tile's y: one lane of a register a chunk
+        raise ValueError(f"a tile of {tr} rows is more than {LANES} chunks "
+                         f"of {ck}")
+
+    active = n_valid > 0
+    dt, xf = dt[:, 0].astype(f32), x[:, 0].astype(f32)   # [b, H] [b, H, P]
+    rows = jnp.argsort(~active, stable=True).astype(jnp.int32)  # live first
+    count = active.sum(dtype=jnp.int32)[None]
+
+    def at(slot, t, refs):
+        """(row, tile) of a grid step: a slot past the count stays on
+        the last live row's last tile."""
+        _, count_ref, rows_ref, _ = refs
+        live = slot < count_ref[0]
+        last = jnp.maximum(count_ref[0] - 1, 0)
+        return (rows_ref[jnp.where(live, slot, last)],
+                jnp.where(live, t, n_tiles - 1))
+
+    state = pl.BlockSpec((1, 1, tr, N), lambda slot, t, *refs:
+                         (refs[0][0], *at(slot, t, refs), 0))
+    per_chunk = pl.BlockSpec((1, n_chunks, ck), lambda slot, t, *refs:
+                             (*at(slot, t, refs), 0))
+    per_row = pl.BlockSpec((1, 1, N), lambda slot, t, *refs:
+                           (at(slot, t, refs)[0], 0, 0))
+    interpret = _interpret_mode()
+    params = {} if interpret else dict(compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary")))
+    pool, y = pl.pallas_call(
+        functools.partial(_step_kernel, head_rows=P),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(b, n_tiles),
+            in_specs=[state, per_chunk, per_row, per_row],
+            out_specs=[state, per_chunk]),
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, f32),
+                   jax.ShapeDtypeStruct((b, HP // ck, ck), f32)],
+        input_output_aliases={4: 0},            # the pool, after 4 scalars
+        interpret=interpret,
+        name="ssd_step",
+        **params,
+    )(jnp.asarray(layer, jnp.int32)[None], count, rows, jnp.exp(dt * A),
+      pool, (dt[..., None] * xf).reshape(b, HP // ck, ck),
+      B.astype(f32), C.astype(f32))
+    y = jnp.where(active[:, None, None], y.reshape(b, H, P), 0.0) \
+        + D[:, None] * xf
+    return y[:, None], pool
 
 
 def _ssd_chunk(x, dt, A, B, C, state):
@@ -134,12 +262,20 @@ def ssd_window(x, dt, A, B, C, D, state, n_valid, chunk: int):
     return y + D[:, None] * xf, state
 
 
-def ssd(x, dt, A, B, C, D, state, n_valid, chunk: int):
-    """The recurrence over a window, in the form its static width
-    calls for."""
+def ssd(x, dt, A, B, C, D, pool, layer, n_valid, chunk: int):
+    """The recurrence over a window, in the form its static width calls
+    for.  The rows' state is addressed as it is stored: ``pool`` [L, b,
+    H * P, N] float32 and the ``layer`` of it that is this one's.  The
+    one-token form works on the pool in place; the window form takes
+    the layer out and puts it back, which costs nothing for a pool of
+    one layer (what a caller with the rows' state in hand passes, with
+    layer 0).  -> (y [b, s, H, P] float32, pool)."""
     if x.shape[1] == 1:
-        return ssd_step(x, dt, A, B, C, D, state, n_valid)
-    return ssd_window(x, dt, A, B, C, D, state, n_valid, chunk)
+        return ssd_step(x, dt, A, B, C, D, pool, layer, n_valid)
+    b, _, H, P = x.shape
+    y, state = ssd_window(x, dt, A, B, C, D,
+                          pool[layer].reshape(b, H, P, -1), n_valid, chunk)
+    return y, pool.at[layer].set(state.reshape(pool.shape[1:]))
 
 
 def ssd_recurrence(x, dt, A, B, C, D, state):

@@ -71,17 +71,26 @@ def test_window_scan_equals_recurrence(s):
     np.testing.assert_allclose(s1, s0, atol=2e-5)
 
 
+def _pool_of(state, layers=1, layer=0):
+    """[b, H, P, N] state as ``layer`` of a pool [layers, b, H * P, N]
+    whose other layers hold other numbers."""
+    b, H, P, N = state.shape
+    pool = jax.random.normal(jax.random.PRNGKey(7), (layers, b, H * P, N))
+    return pool.at[layer].set(state.reshape(b, H * P, N))
+
+
 def test_one_step_form_equals_recurrence():
     a = _ssm_inputs(2, 11)
     y0, s0 = ssm.ssd_recurrence(**a)
-    state, ys = a["state"], []
+    pool, ys = _pool_of(a["state"]), []
+    step = jax.jit(ssm.ssd, static_argnames="chunk")
     for t in range(11):
-        y, state = ssm.ssd(a["x"][:, t:t + 1], a["dt"][:, t:t + 1], a["A"],
-                           a["B"][:, t:t + 1], a["C"][:, t:t + 1], a["D"],
-                           state, jnp.ones((2,), jnp.int32), chunk=8)
+        y, pool = step(a["x"][:, t:t + 1], a["dt"][:, t:t + 1], a["A"],
+                       a["B"][:, t:t + 1], a["C"][:, t:t + 1], a["D"],
+                       pool, 0, jnp.ones((2,), jnp.int32), chunk=8)
         ys.append(y)
     np.testing.assert_allclose(jnp.concatenate(ys, 1), y0, atol=2e-5)
-    np.testing.assert_allclose(state, s0, atol=2e-5)
+    np.testing.assert_allclose(pool[0].reshape(s0.shape), s0, atol=2e-5)
 
 
 def test_tokens_past_n_valid_leave_the_state_alone():
@@ -96,11 +105,37 @@ def test_tokens_past_n_valid_leave_the_state_alone():
     np.testing.assert_allclose(y[1, :13], y13[0], atol=2e-5)
     np.testing.assert_allclose(state[1], s13[0], atol=2e-5)
     # the one-token form: a row that sits the pass out
+    pool = _pool_of(a["state"])
     _, st = ssm.ssd_step(a["x"][:, :1], a["dt"][:, :1], a["A"],
-                         a["B"][:, :1], a["C"][:, :1], a["D"], a["state"],
+                         a["B"][:, :1], a["C"][:, :1], a["D"], pool, 0,
                          jnp.array([0, 1]))
-    np.testing.assert_array_equal(st[0], a["state"][0])
-    assert not np.allclose(st[1], a["state"][1])
+    np.testing.assert_array_equal(st[0, 0], pool[0, 0])
+    assert not np.allclose(st[0, 1], pool[0, 1])
+
+
+@pytest.mark.parametrize("live", [
+    (1, 1, 1, 1, 1), (0, 1, 1, 1, 1), (1, 1, 0, 1, 1), (1, 1, 1, 1, 0),
+    (0, 0, 0, 1, 0), (0, 0, 0, 0, 0)],
+    ids=["all", "first-idle", "middle-idle", "last-idle", "one", "none"])
+def test_one_step_kernel_touches_live_rows_of_its_layer_only(live):
+    """The one-token kernel on a pool of three layers: the live rows of
+    ITS layer advance as the definition says; idle rows and the other
+    layers come back bit for bit — also from a pass in which no row
+    advances at all."""
+    a = _ssm_inputs(5, 1, seed=2)
+    state = a.pop("state")
+    pool = _pool_of(state, layers=3, layer=1)
+    n_valid = jnp.array(live, jnp.int32)
+    y, new = jax.jit(ssm.ssd_step)(**a, pool=pool, layer=jnp.int32(1),
+                                   n_valid=n_valid)
+    y0, s0 = ssm.ssd_recurrence(**a, state=state)
+    on = np.array(live, bool)
+    np.testing.assert_allclose(y[on], y0[on], atol=2e-5)
+    np.testing.assert_allclose(new[1][on], s0.reshape(pool.shape[1:])[on],
+                               atol=2e-5)
+    np.testing.assert_array_equal(new[1][~on], pool[1][~on])
+    np.testing.assert_array_equal(new[0], pool[0])
+    np.testing.assert_array_equal(new[2], pool[2])
 
 
 def test_causal_conv_window_equals_token_by_token():
