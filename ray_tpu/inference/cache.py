@@ -10,9 +10,11 @@ them), a per-request BLOCK TABLE mapping sequence positions to blocks,
 and per-block REFCOUNTS so blocks are shared across requests (prefix
 reuse) with copy-on-write on a shared partially-filled tail.  The
 decode step stays compiled-once because the table width and batch width
-are static; the price is one gather per step, paid because block
-granularity lets long and short sequences share one pool with near-zero
-waste.  Block id 0 is a reserved SCRATCH block: masked rows and
+are static; the price is an indirection per step (the decode programs'
+attention kernel walks each row's table and copies the blocks that hold
+a key; the window programs gather their rows' tables), paid because
+block granularity lets long and short sequences share one pool with
+near-zero waste.  Block id 0 is a reserved SCRATCH block: masked rows and
 out-of-range writes are redirected there so the compiled step never
 needs a conditional scatter.
 
@@ -79,8 +81,10 @@ class PoolLayout:
     GPT-2 XL, 768 unpadded for 124M), so the TPU tiles the trailing
     ``(block_size, width)`` without padding and keeps the buffer in the
     plain row-major layout every program computes in: a program reads
-    (``read``) and writes (``commit``) the rows its tables name and
-    never re-tiles or copies the pool.  With a mesh the width is split
+    (``read``, or ops/attention.paged_decode_attention's copies of
+    single blocks, each one contiguous ``[block_size, width]`` run at
+    ``rows(layer, block)``) and writes (``commit``) the rows its tables
+    name and never re-tiles or copies the pool.  With a mesh the width is split
     over the heads axis (``POOL_AXES``): each shard holds whole heads,
     then its own padding lanes.  Padding lanes are written as zeros
     and never read.
@@ -143,7 +147,9 @@ class PoolLayout:
         """Gather layer ``layer``'s blocks ``tables`` [..., T] as keys in
         position order, as stored: [..., T * block_size, width]
         (ops/attention.packed_attention attends them so; ``unpack``
-        splits the heads out)."""
+        splits the heads out).  For a window of queries and for
+        cache.py's own programs: a one-token pass reads through the
+        kernel, which takes only the blocks a row holds."""
         g = pool[self.rows(layer, tables)]            # [..., T, bs, W]
         return g.reshape(*tables.shape[:-1], -1, self.width)
 

@@ -11,10 +11,17 @@ Every program runs the model's ONE layer function
 (``gpt._transformer_layer``) on its window of tokens and hands it the
 attention step.  The four paged programs hand it ``paged_attend``: the
 window's K/V is committed to the block pool (cache.BlockPool) at the
-window's (block, offset) pairs, the rows' block tables are read back,
-and each query attends what its mask or length admits.  What a program
-states itself is what differs: the index arithmetic of its window (dead
-lanes go to the scratch block), the embedding, the head.
+window's (block, offset) pairs, and each query then attends what its
+length or mask admits of the committed pool.  The ONE-TOKEN programs
+(decode step, draft burst; the hybrid family's decode step in
+recurrent.py) give a length a row and attend through one Pallas kernel
+that walks each live row's block table over the pool as stored and
+reads the blocks that hold a key, no other
+(ops/attention.paged_decode_attention); the WINDOW programs (chunk
+prefill, verify) give a mask, gather their rows' tables and attend them
+as stored (ops/attention.packed_attention).  What a program states
+itself is what differs: the index arithmetic of its window (dead lanes
+go to the scratch block), the embedding, the head.
 
   * chunk_prefill — a fixed-width window of ONE prompt ([C] tokens at
     positions start..start+C), each query row masked to its OWN causal
@@ -22,10 +29,11 @@ lanes go to the scratch block), the embedding, the head.
     Long prompts therefore prefill as a sequence of bounded-cost steps
     the engine interleaves with decode iterations — a long prompt
     stops stalling neighbors' token cadence.
-  * paged_decode_step — one token for EVERY row at once, each row
-    masked to its valid prefix (the formulation of
-    ops/attention.paged_attention); inactive rows write to the scratch
-    block.
+  * paged_decode_step — one token for EVERY row at once, each live
+    row over the blocks of its valid prefix (the table-walking kernel;
+    ops/attention.paged_attention is the formulation it is held to);
+    inactive rows write to the scratch block, attend nothing and get
+    zeros.
   * spec_verify_step — the decode step widened to a [b, W] token
     window (W = speculate_k + 1): column 0 is each row's current input
     token, columns 1.. are DRAFTED continuations.  One call scores all
@@ -56,12 +64,15 @@ generations (and shared-prefix serving mixes) accept most of it.
 
 With a mesh the programs are sharding-annotated for Megatron-style
 tensor parallelism: pools heads-sharded per POOL_AXES, per-device
-attention over local heads, one collective at the output projection,
+attention over local heads (the one-token kernel inside ``shard_map``,
+each shard on its own slice of the pool's width and its share of the
+rows), one collective at the output projection,
 the donated pool committed per shard (the scatter's indexed dims — row,
 offset — are unsharded).  MoE configs dispatch through gpt._moe_mlp per
 token window.  The pools' stored layout is defined once, in
-cache.PoolLayout; the programs touch them through its ``read`` and
-``commit`` only.  Greedy token-parity with full-recompute
+cache.PoolLayout; the programs touch them through its ``commit``, its
+``read`` and the kernel (which takes ``PoolLayout.rows`` of the layer's
+block 0 and adds the table's ids) only.  Greedy token-parity with full-recompute
 ``generate()`` is pinned by tests/test_inference.py +
 tests/test_paged_cache.py (mesh=None) and tests/test_sharded_decode.py
 (multi-device CPU meshes).
@@ -79,8 +90,9 @@ from jax import lax
 from ray_tpu.inference.cache import POOL_AXES, PoolLayout, heads_shards
 from ray_tpu.models import gpt
 from ray_tpu.models.gpt import GPTConfig
-from ray_tpu.ops.attention import packed_attention
-from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES, Rules
+from ray_tpu.ops.attention import (_per_shard, packed_attention,
+                                   paged_decode_attention)
+from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES, Rules, spec_for
 
 
 class SpeculationUnsupported(ValueError):
@@ -142,11 +154,11 @@ def make_prefill_fn(cfg: GPTConfig, *, mesh=None,
 # paged path
 #
 # The K and V pools are stored as cache.PoolLayout says ([L*(N+1), bs,
-# W], see there) and every program below touches them through its two
-# operations only.  The pools ride the layer scan as its CARRY: each
+# W], see there).  The pools ride the layer scan as its CARRY: each
 # layer's ``attend`` commits its window's new K/V into the carried pool
-# and THEN reads its rows' tables from it, so the attended context
-# holds the new tokens at their own positions with no insertion step.
+# and THEN attends the committed pool through the rows' tables, so the
+# attended context holds the new tokens at their own positions with no
+# insertion step.
 # What the chip does with that: a carried, donated buffer is updated in
 # place — the compiled programs hold no copy of a pool nor of a layer's
 # share of one, and the pools enter and leave in the layout the scan
@@ -159,20 +171,40 @@ def make_prefill_fn(cfg: GPTConfig, *, mesh=None,
 
 
 def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
-                 mesh=None, rules=None, **attn):
+                 mesh=None, rules=None, kv_lengths=None, mask=None,
+                 q_per_kv: int = 1, scale=None):
     """Where a window meets the pool, for every model family:
     ``attend_for(layer)`` gives that layer's ``attend(q [b, h, w, hd],
     k, v [b, w, h_kv, hd]) -> o [b, h, w, hd]`` over the (K, V)
     ``pools``, and ``held["pools"]`` is what the last ``attend`` left.
 
-    ``attend`` commits the window's K/V at ``(blocks, offsets)``, then
-    gathers the rows' ``tables`` [b, T] as contexts [b, T*bs, W] — keys
-    in position order, the window's own among them, heads still packed
-    as stored — and attends them so (``packed_attention``, which
-    ``attn`` goes to: ``mask`` or ``kv_lengths``, ``groups``,
-    ``q_per_kv``, ``scale``).  With a mesh the contexts are
-    constrained to the pool's heads sharding."""
+    ``attend`` commits the window's K/V at ``(blocks, offsets)`` and
+    then attends the committed pools, the window's own keys among them
+    at their positions, in the form the call has:
+
+      * ``kv_lengths`` [b] — ONE token a row, which attends its first
+        ``kv_lengths`` keys (0: the row sits the pass out and gets
+        zeros).  One Pallas kernel (``paged_decode_attention``) walks
+        each live row's table over the pools as stored and reads the
+        blocks that hold a key, no other.  Under a mesh that splits
+        the pool's width it runs per shard (whole heads, then the
+        shard's padding), the rows split as the batch is.
+      * ``mask`` — a window of queries, each with its own horizon:
+        the rows' ``tables`` [b, T] are gathered as contexts [b, T*bs,
+        W], keys in position order, heads still packed as stored, and
+        attended so (``packed_attention``); with a mesh the contexts
+        are constrained to the pool's heads sharding."""
     held = {"pools": pools}
+    if kv_lengths is not None:
+        def sp(*axes):
+            return spec_for(axes, rules, mesh)
+        walk = partial(paged_decode_attention, q_per_kv=q_per_kv,
+                       scale=scale)
+        if mesh is not None:
+            q_spec = sp("batch", "heads", None, None)
+            walk = _per_shard(
+                walk, mesh, (q_spec, sp(*POOL_AXES), sp(*POOL_AXES), sp(),
+                             sp("batch", None), sp("batch")), q_spec)
 
     def attend_for(layer):
         def attend(q, k, v):
@@ -181,11 +213,16 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
             held["pools"] = tuple(
                 lay.commit(p, layer, blocks, offsets, x)
                 for p, x in zip(held["pools"], new))
+            if kv_lengths is not None:
+                return walk(q, *held["pools"], lay.rows(layer, 0), tables,
+                            kv_lengths)
             ctx_k, ctx_v = (
                 gpt._constrain(lay.read(p, layer, tables),
                                ("batch", None, "heads"), mesh, rules)
                 for p in held["pools"])
-            return packed_attention(q, ctx_k, ctx_v, **attn)
+            return packed_attention(q, ctx_k, ctx_v, groups=lay.shards,
+                                    q_per_kv=q_per_kv, scale=scale,
+                                    mask=mask)
         return attend
     return attend_for, held
 
@@ -246,12 +283,12 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
             rows = jnp.arange(b)
             bidx = jnp.where(active, tables[rows, positions // bs], 0)
             off = jnp.where(active, positions % bs, 0)
-            kv_len = jnp.where(active, positions + 1, 1)      # >=1: no NaN
+            kv_len = jnp.where(active, positions + 1, 0)      # 0: sits out
             x, pools = _paged_layers(
                 cfg, mesh, rules, params["layers"], x, pools,
                 lambda pools: paged_attend(
                     lay, pools, bidx, off, tables, mesh=mesh, rules=rules,
-                    groups=lay.shards, kv_lengths=kv_len))
+                    kv_lengths=kv_len))
             k_pool, v_pool = (gpt._constrain(p, POOL_AXES, mesh, rules)
                               for p in pools)
             logits = gpt._head(params, x, cfg, mesh, rules)[:, 0, :]
@@ -308,7 +345,7 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
                 cfg, mesh, rules, params["layers"], x, pools,
                 lambda pools: paged_attend(
                     lay, pools, bidx, off, table[None], mesh=mesh,
-                    rules=rules, groups=lay.shards, mask=mask[None, None]))
+                    rules=rules, mask=mask[None, None]))
             k_pool, v_pool = (gpt._constrain(p, POOL_AXES, mesh, rules)
                               for p in pools)
             logits = gpt._head(params, x, cfg, mesh, rules)[0]  # [C, V]
@@ -376,7 +413,7 @@ def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,
                 cfg, mesh, rules, params["layers"], x, pools,
                 lambda pools: paged_attend(
                     lay, pools, bidx, off, tables, mesh=mesh, rules=rules,
-                    groups=lay.shards, mask=mask))
+                    mask=mask))
             k_pool, v_pool = (gpt._constrain(p, POOL_AXES, mesh, rules)
                               for p in pools)
             logits = gpt._head(params, x, cfg, mesh, rules)  # [b, W, V]
@@ -448,12 +485,12 @@ def make_paged_draft_step(cfg: GPTConfig, *, draft_layers: int, k: int,
                 safe = jnp.where(live, pos, 0)
                 bidx = jnp.where(live, tables[rows, safe // bs], 0)
                 off = jnp.where(live, safe % bs, 0)
-                kv_len = jnp.where(live, pos + 1, 1)
+                kv_len = jnp.where(live, pos + 1, 0)
                 x, pools = _paged_layers(
                     cfg, mesh, rules, trunk, x, pools,
                     lambda pools: paged_attend(
                         lay, pools, bidx, off, tables, mesh=mesh,
-                        rules=rules, groups=lay.shards, kv_lengths=kv_len))
+                        rules=rules, kv_lengths=kv_len))
                 logits = gpt._head(params, x, cfg, mesh, rules)[:, 0, :]
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 cur = jnp.where(live, nxt, cur)

@@ -697,6 +697,11 @@ class InferenceEngine:
         self._chunk_passes = 0         # chunk-prefill programs run
         self._prefill_tokens = 0       # prompt tokens run through a
         #                                prefill program (hits excluded)
+        # per one-token decode pass: the blocks that hold a key of a
+        # live row (what the attention kernel reads of each pool and
+        # layer) and the rows' whole tables (what a gather would read)
+        self._kv_blocks_attended = 0
+        self._kv_blocks_tabled = 0
         self._peak_active = 0
         self._spec_drafted = 0         # drafted tokens offered to verify
         self._spec_accepted = 0        # drafted tokens accepted
@@ -1577,6 +1582,10 @@ class InferenceEngine:
                 self._decode_iterations += 1
                 self._occupancy_sum += (float(self._active.sum())
                                         / self.engine_cfg.max_slots)
+            self._kv_blocks_attended += int(
+                (self._positions[self._active]
+                 // self.engine_cfg.kv_block_size + 1).sum())
+            self._kv_blocks_tabled += self._tables.size
             with tracing.span("engine.sample") as sample:
                 greedy = self._seam.greedy(self, logits)
                 stepped = 0
@@ -1865,6 +1874,8 @@ class InferenceEngine:
             admissions = self._admissions
             chunk_passes = self._chunk_passes
             prefill_tokens = self._prefill_tokens
+            kv_attended = self._kv_blocks_attended
+            kv_tabled = self._kv_blocks_tabled
             peak = self._peak_active
             drafted = self._spec_drafted
             accepted = self._spec_accepted
@@ -1886,6 +1897,8 @@ class InferenceEngine:
             "admissions": admissions,
             "chunk_passes": chunk_passes,
             "prefill_tokens": prefill_tokens,
+            "kv_blocks_attended": kv_attended,
+            "kv_blocks_tabled": kv_tabled,
             # tokens emitted per (row, compiled call) pair: exactly 1.0
             # for plain decode by construction, 1 + accepted-per-pass
             # under speculation — batch width cancels out
@@ -1971,6 +1984,7 @@ def metrics_snapshot() -> list:
     active, waiting, occ, gen, comp = {}, {}, {}, {}, {}
     butil, phit, pcached, preempt = {}, {}, {}, {}
     admits, chunks, ptoks = {}, {}, {}
+    kv_att, kv_tab = {}, {}
     tps, arate, saccept = {}, {}, {}
     meshdev, tpsh = {}, {}
     sbytes, srows, eheld, etotal, emax = {}, {}, {}, {}, {}
@@ -1998,6 +2012,8 @@ def metrics_snapshot() -> list:
         admits[key] = float(st["admissions"])
         chunks[key] = float(st["chunk_passes"])
         ptoks[key] = float(st["prefill_tokens"])
+        kv_att[key] = float(st["kv_blocks_attended"])
+        kv_tab[key] = float(st["kv_blocks_tabled"])
         # speculation signal, per replica: accept-rate is the drafter's
         # quality gauge, tokens/step the latency win it buys
         tps[key] = float(st.get("tokens_per_step", 0.0))
@@ -2045,6 +2061,12 @@ def metrics_snapshot() -> list:
         ("ray_tpu_inference_prefill_tokens_total", "counter",
          "Prompt tokens run through a prefill program (prefix-cache "
          "hits excluded)", ptoks or zero),
+        ("ray_tpu_inference_kv_blocks_attended_total", "counter",
+         "KV blocks holding a key of a live row, summed over one-token "
+         "decode passes (read once a pool and layer)", kv_att or zero),
+        ("ray_tpu_inference_kv_blocks_tabled_total", "counter",
+         "Block-table entries of all rows, summed over one-token decode "
+         "passes (what a whole-table gather reads)", kv_tab or zero),
         ("ray_tpu_inference_tokens_per_step", "gauge",
          "Tokens emitted per compiled decode/verify call (speculative "
          "decoding pushes this above 1)", tps or zero),

@@ -95,7 +95,7 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
             rows = jnp.arange(b)
             bidx = jnp.where(active, tables[rows, positions // bs], 0)
             off = jnp.where(active, positions % bs, 0)
-            kv_len = jnp.where(active, positions + 1, 1)      # >=1: no NaN
+            kv_len = jnp.where(active, positions + 1, 0)      # 0: sits out
             attend_for, kv = paged_attend(
                 lay, (k_pool, v_pool), bidx, off, tables,
                 q_per_kv=cfg.n_heads // cfg.n_kv_heads,

@@ -14,6 +14,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
 from jax.sharding import Mesh, PartitionSpec
 
 
@@ -172,6 +174,214 @@ def paged_attention(q, k_pool, v_pool, block_tables, *,
                          scale=scale, mask=mask, kv_lengths=kv_lengths)
 
 
+# VMEM the decode kernel's wave buffers take together (K and V, each
+# double-buffered): a wave is the largest power of two of blocks that
+# fits, 16 blocks of 16 x 1664 bf16 (GPT-2 XL), 32 of 16 x 1024 (the
+# hybrid's one K/V layer)
+WAVE_BYTES = 4 << 20
+
+
+def _decode_kernel(base_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   rows_ref, k_buf, v_buf, sem, qx_ref, acc_ref, *,
+                   wave: int, n_table: int, n_kv: int, head_dim: int,
+                   scale: float):
+    """Every live row's one query over the blocks its table names, a
+    wave of blocks at a time: wave w + 1 (or the next live row's first)
+    is on its way into one half of the buffers while wave w is attended
+    in the other.  The live rows (``len`` > 0) are listed first, so a
+    row that sits out costs one scalar comparison.
+
+    A row's R = ``q_per_kv`` queries a K/V head arrive as R vectors of
+    the pool's width, query r of K/V head g in g's lanes of vector r.
+    ``qx`` [R * gp, W] is ``packed_attention``'s query matrix, one row a
+    query head (row r * gp + g, the K/V heads padded to gp rows): its
+    own lanes hold the query, the rest are zero, so one product over
+    the full width gives every head's logits [heads, tokens] and one
+    product with V every (head, lane) pair, of which each lane keeps
+    its own head's."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    bs, width = k_buf.shape[1] // wave, k_buf.shape[2]
+    tokens = wave * bs
+    reps, gp = q_ref.shape[1], qx_ref.shape[0] // q_ref.shape[1]
+    base = base_ref[0]
+
+    def list_live(row, n):
+        live = len_ref[row] > 0
+
+        @pl.when(live)
+        def _():
+            rows_ref[n] = row
+        return n + live.astype(jnp.int32)
+
+    count = lax.fori_loop(0, rows_ref.shape[0], list_live, 0)
+
+    def blocks_of(row, w):
+        """Blocks of the row's wave w that hold a key: 0 .. wave."""
+        return jnp.clip(pl.cdiv(len_ref[row], bs) - w * wave, 0, wave)
+
+    def copies(row, w, half, i):
+        at = pl.ds(pl.multiple_of(i * bs, bs), bs)
+        src = base + tab_ref[row * n_table + w * wave + i]
+        return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[half, at],
+                                      sem.at[0, half]),
+                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[half, at],
+                                      sem.at[1, half]))
+
+    def each_copy(row, w, half, do):
+        def one(i, _):
+            for c in copies(row, w, half, i):
+                do(c)
+        lax.fori_loop(0, blocks_of(row, w), one, None)
+
+    def start(row, w, half):
+        each_copy(row, w, half, lambda c: c.start())
+
+    def wait(row, w, half):
+        each_copy(row, w, half, lambda c: c.wait())
+
+    def own():
+        """[gp, W]: lane belongs to the K/V head of this sublane."""
+        head = lax.broadcasted_iota(jnp.int32, (gp, width), 0)
+        lane = lax.broadcasted_iota(jnp.int32, (gp, width), 1)
+        return ((lane >= head * head_dim) & (lane < (head + 1) * head_dim)
+                & (head < n_kv))
+
+    @pl.when(count > 0)
+    def _():
+        start(rows_ref[0], 0, 0)
+
+    def row_body(slot, done):
+        """``done``: waves attended so far, whose parity says which
+        half of the buffers this row's first wave is arriving in."""
+        row = rows_ref[slot]
+        kv_len = len_ref[row]
+        n_waves = pl.cdiv(kv_len, tokens)
+        q = q_ref[row]                                          # [R, W]
+        for r in range(reps):
+            qx_ref[r * gp:(r + 1) * gp, :] = jnp.where(
+                own(), q[r:r + 1, :], 0.0).astype(qx_ref.dtype)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def wave_body(w, carry):
+            m_prev, l_prev = carry
+            half = (done + w) % 2
+            more = w + 1 < n_waves
+
+            @pl.when(more | (slot + 1 < count))
+            def _():
+                start(jnp.where(more, row, rows_ref[
+                    jnp.minimum(slot + 1, rows_ref.shape[0] - 1)]),
+                    jnp.where(more, w + 1, 0), 1 - half)
+
+            wait(row, w, half)
+            s = lax.dot_general(
+                qx_ref[...], k_buf[half], (((1,), (1,)), ((), ())),
+                preferred_element_type=f32) * scale     # [heads, tokens]
+            key = w * tokens + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(key < kv_len, s, -jnp.inf)
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(s - m_next)
+
+            # a key past kv_len weighs exactly 0, and what lies there
+            # (a block not copied, a block's unwritten tail) may be
+            # anything: 0 x NaN is NaN
+            @pl.when((w + 1) * tokens > kv_len)
+            def _():
+                pos = w * tokens + lax.broadcasted_iota(
+                    jnp.int32, (tokens, width), 0)
+                v = v_buf[half]
+                v_buf[half] = jnp.where(pos < kv_len, v, jnp.zeros_like(v))
+
+            acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+                p.astype(v_buf.dtype), v_buf[half],
+                preferred_element_type=f32)
+            return m_next, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+
+        heads = qx_ref.shape[0]
+        _, l = lax.fori_loop(
+            0, n_waves, wave_body,
+            (jnp.full((heads, 1), -jnp.inf, f32), jnp.zeros((heads, 1), f32)))
+        out = acc_ref[...] / l                                  # [heads, W]
+        for r in range(reps):
+            o_ref[row, r:r + 1, :] = jnp.sum(
+                jnp.where(own(), out[r * gp:(r + 1) * gp], 0.0),
+                axis=0, keepdims=True)
+        return done + n_waves
+
+    lax.fori_loop(0, count, row_body, 0)
+
+
+def paged_decode_attention(q, k_pool, v_pool, base, tables, kv_lengths, *,
+                           q_per_kv: int = 1,
+                           scale: Optional[float] = None) -> jax.Array:
+    """One query a row over the paged pools AS STORED, reading only the
+    blocks a row holds.
+
+    q            [b, h, 1, hd]
+    k/v_pool     [rows, bs, W] (inference/cache.PoolLayout, one shard:
+                 ``h / q_per_kv`` heads of hd lanes, then padding); the
+                 kernel takes them where they lie in HBM
+    base         int32 scalar: the pools' row of this layer's block 0
+    tables       [b, T] int32 block ids in position order
+    kv_lengths   [b] int32: keys the row attends; 0 = the row sits the
+                 pass out
+    -> [b, h, 1, hd]; a row that sits out gets zeros.
+
+    ONE Pallas kernel walks the live rows.  For each it copies the
+    ``ceil(kv_len / bs)`` blocks its table names, each one contiguous
+    [bs, W] run, into VMEM a wave at a time, and does there
+    ``packed_attention``'s arithmetic with a running softmax across
+    waves: float32 logits over the full width for all heads at once,
+    keys past ``kv_len`` masked, the (unnormalised) probabilities
+    rounded to the pool's dtype before the product with V, float32
+    sums.  A row that sits out is not visited and no other block is
+    read."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops.flash_attention import _interpret_mode
+
+    b, h, _, hd = q.shape
+    n_table = tables.shape[1]
+    _, bs, width = k_pool.shape
+    n_kv = h // q_per_kv
+    gp = -(-n_kv // 8) * 8                  # a float32 tile's sublanes
+    wave = max(1, WAVE_BYTES // (4 * bs * width * k_pool.dtype.itemsize))
+    wave = min(1 << (wave.bit_length() - 1), n_table)
+
+    # query r of K/V head g: head g * q_per_kv + r -> vector r, g's lanes
+    qr = q.reshape(b, n_kv, q_per_kv, hd).transpose(0, 2, 1, 3)
+    qr = qr.reshape(b, q_per_kv, n_kv * hd).astype(jnp.float32)
+    qr = jnp.pad(qr, [(0, 0), (0, 0), (0, width - n_kv * hd)])
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, wave=wave, n_table=n_table,
+                          n_kv=n_kv, head_dim=hd,
+                          scale=_scale_for(q, scale)),
+        in_specs=[smem] * 3 + [vmem, hbm, hbm],
+        out_specs=vmem,
+        out_shape=jax.ShapeDtypeStruct(qr.shape, jnp.float32),
+        scratch_shapes=[
+            pltpu.SMEM((b,), jnp.int32),
+            pltpu.VMEM((2, wave * bs, width), k_pool.dtype),
+            pltpu.VMEM((2, wave * bs, width), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((q_per_kv * gp, width), k_pool.dtype),
+            pltpu.VMEM((q_per_kv * gp, width), jnp.float32)],
+        interpret=_interpret_mode(),
+        name="paged_decode_attention",
+    )(jnp.asarray(base, jnp.int32)[None], kv_lengths.astype(jnp.int32),
+      tables.reshape(-1), qr, k_pool, v_pool)
+    out = jnp.where((kv_lengths > 0)[:, None, None], out[..., :n_kv * hd], 0.0)
+    out = out.reshape(b, q_per_kv, n_kv, hd).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, 1, hd).astype(v_pool.dtype)
+
+
 def on_tpu() -> bool:
     """THE definition of "this process computes on a TPU" for kernel
     dispatch (flash vs reference) and interpret-mode selection.  A
@@ -179,21 +389,14 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _per_shard(fn, mesh, spec):
+def _per_shard(fn, mesh, in_specs, out_specs):
     """Mosaic kernels cannot be partitioned by GSPMD, so under a
-    multi-device mesh the flash call runs per shard inside shard_map:
-    manual over every mesh axis not already manual in the enclosing
-    context (the pp pipeline binds ``pp`` itself), q/k/v and the output
-    laid out by ``spec`` — the mesh axes that shard batch and heads."""
+    multi-device mesh a kernel runs per shard inside shard_map: manual
+    over every mesh axis not already manual in the enclosing context
+    (the pp pipeline binds ``pp`` itself), its operands and result laid
+    out by ``in_specs`` / ``out_specs``."""
     if mesh is None or mesh.size == 1:
         return fn
-    if spec is None:
-        raise ValueError("flash attention under a mesh needs the "
-                         "[batch, heads, seq, kv] PartitionSpec")
-    if len(spec) > 2 and spec[2] is not None:
-        raise ValueError(
-            f"flash attention needs whole sequences per shard, got seq "
-            f"sharded over {spec[2]!r}; use ring attention for sp meshes")
     ctx = jax.sharding.get_abstract_mesh()
     bound = set(ctx.manual_axes)
     free = frozenset(a for a in mesh.axis_names if a not in bound)
@@ -202,7 +405,7 @@ def _per_shard(fn, mesh, spec):
     # nested under a manual axis, shard_map must be given the context's
     # own (abstract) mesh: the concrete Mesh no longer matches it
     return jax.shard_map(fn, mesh=ctx if bound else mesh, axis_names=free,
-                         in_specs=(spec, spec, spec), out_specs=spec,
+                         in_specs=in_specs, out_specs=out_specs,
                          check_vma=False)
 
 
@@ -252,7 +455,16 @@ def attention(q, k, v, *, causal: bool = True,
                 return flash_attention_with_lse(q, k, v, **kw)[0]
         else:
             fn = functools.partial(flash_attention, **kw)
-        return _per_shard(fn, mesh, spec)(q, k, v)
+        if mesh is not None and mesh.size > 1:
+            if spec is None:
+                raise ValueError("flash attention under a mesh needs the "
+                                 "[batch, heads, seq, kv] PartitionSpec")
+            if len(spec) > 2 and spec[2] is not None:
+                raise ValueError(
+                    f"flash attention needs whole sequences per shard, got "
+                    f"seq sharded over {spec[2]!r}; use ring attention for "
+                    f"sp meshes")
+        return _per_shard(fn, mesh, (spec, spec, spec), spec)(q, k, v)
     if impl == "reference":
         return mha_reference(q, k, v, causal=causal, scale=scale, mask=mask,
                              kv_lengths=kv_lengths)

@@ -191,7 +191,9 @@ def _assert_pool_stays_put(compiled, lay, n_pools=2, own=()):
     row-major one, (c) arguments + scratch fit the chip.  ``own``:
     shapes of K/V the program is GIVEN in another layout, whose
     re-tiling is theirs and not the pool's."""
-    text = compiled.as_text()
+    # a custom call repeats its operands' shapes as CONSTRAINTS, untiled
+    text = re.sub(r"operand_layout_constraints=\{.*?\}\}", "",
+                  compiled.as_text())
     share = int(np.prod(lay.shape)) * 2 // lay.n_layers
     big = []
     for m in re.finditer(r"= \(?bf16\[([\d,]*)\]\S* copy(?:-start)?\(", text):
@@ -260,6 +262,31 @@ def test_xl_chunk_prefill_moves_no_pool(xl, xl_compiled):
     _assert_pool_stays_put(xl_compiled("chunk"), xl[3])
 
 
+def _kernel_calls(text, name="paged_decode_attention"):
+    return [line.strip() for line in text.splitlines()
+            if " custom-call(" in line and "tpu_custom_call" in line
+            and line.strip().startswith(f"%{name}")]
+
+
+def test_xl_decode_step_walks_the_tables_in_one_kernel(xl, xl_compiled):
+    """What ISSUE 35 bought: the decode step gathers no row's table
+    (32 rows x 64 columns of [16, 1664] blocks, one a pool a layer, were
+    two thirds of the program) and holds the table-walking kernel ONCE,
+    inside the layer scan, on both pools as stored."""
+    lay = xl[3]
+    text = xl_compiled("decode").as_text()
+    assert "bf16[2048,16,1664]" not in text
+    assert "bf16[32,1024,1664]" not in text
+    pool = "bf16[" + ",".join(map(str, lay.shape)) + "]"
+    call, = _kernel_calls(text)
+    assert call.count(pool) == 2
+    body = re.search(r"body=(%[\w.]+)", next(
+        line for line in text.splitlines() if " while(" in line
+        and pool in line)).group(1)
+    scan = text.split(f"\n{body} ", 1)[1].split("\n}\n", 1)[0]
+    assert call in scan
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_xl_program_casts_and_retiles_no_weights(xl, xl_compiled, program):
     """What ISSUE 30 bought: handed the served tree, a program holds no
@@ -303,7 +330,7 @@ def test_xl_write_blocks_moves_no_pool(xl):
 def test_paged_decode_step_keeps_its_pool_shard_under_dp_tp(mesh_2x2):
     """tp=2: every device holds whole heads (6 of 12: 384 lanes, no
     padding) of every block, in the same row-major layout, and the
-    program copies none of it."""
+    program copies none of it; its attention kernel runs per shard."""
     cfg = gpt.GPTConfig.gpt2_124m()
     rows, bs = 8, 16
     n_table = cfg.max_seq // bs
@@ -328,15 +355,20 @@ def test_paged_decode_step_keeps_its_pool_shard_under_dp_tp(mesh_2x2):
                        cfg.head_dim)
     assert shard.shape == (*lay.shape[:2], 384)
     _assert_pool_stays_put(compiled, shard)
+    # the table-walking kernel per shard: its 4 of the 8 rows (dp), its
+    # 6 heads' slice of both pools (tp)
+    call, = _kernel_calls(compiled.as_text())
+    assert call.startswith("%paged_decode_attention") \
+        and " = f32[4,1,384]" in call
+    assert call.count("bf16[" + ",".join(map(str, shard.shape)) + "]") == 2
 
 
 def test_paged_decode_step_compiles_at_124m(one_chip):
     """One engine program at full width: the paged decode step over the
     default serving geometry (8 rows, 16-token blocks, 1024-token
-    tables).  Its attention is plain XLA by design (per-row kv
-    lengths), so no Mosaic kernel is expected — only that the TPU
-    compiler takes the program, leaves the pool where it is (768 lanes:
-    no padding) and it fits the chip."""
+    tables): Mosaic takes the table-walking kernel at 12 heads of 64 in
+    768 lanes (no padding), the program leaves the pool where it is and
+    fits the chip."""
     cfg = gpt.GPTConfig.gpt2_124m()
     rows, bs = 8, 16
     n_table = cfg.max_seq // bs
@@ -344,11 +376,13 @@ def test_paged_decode_step_compiles_at_124m(one_chip):
     pool, lay = _pool_of(cfg, rows * n_table, bs, on_chip)
     assert lay.width == cfg.d_model
     step = make_paged_decode_step(cfg, block_size=bs, n_table=n_table)
-    _assert_pool_stays_put(step.lower(
+    compiled = step.lower(
         _params_of(cfg, on_chip), pool, pool,
         on_chip((rows, n_table), jnp.int32),
         on_chip((rows,), jnp.int32), on_chip((rows,), jnp.int32),
-        on_chip((rows,), jnp.bool_)).compile(), lay)
+        on_chip((rows,), jnp.bool_)).compile()
+    _assert_pool_stays_put(compiled, lay)
+    assert len(_kernel_calls(compiled.as_text())) == 1
 
 
 # ---- the second model family: K/V blocks AND a recurrent state pool
@@ -434,6 +468,18 @@ def test_hybrid_decode_updates_the_state_in_one_kernel_a_layer(
     fusions = [line for line in text.splitlines() if " fusion(" in line]
     assert not [f for f in fusions if f.split(" = ")[1].startswith(pool)]
     assert not re.findall(r"multiply_reduce_fusion\S* = f32\[64,8192\]", text)
+
+
+def test_hybrid_decode_walks_the_tables_in_one_kernel(
+        hybrid_cell, hybrid_decode_compiled):
+    """The one K/V layer's tables (64 rows x 144 columns) are not
+    gathered, and the kernel that walks them belongs to no mechanism
+    the benchmark's trace reduction counts by its text."""
+    from chipbench.scoped_trace import label_of
+    text = hybrid_decode_compiled.as_text()
+    assert "bf16[9216,16,1024]" not in text
+    call, = _kernel_calls(text)
+    assert label_of(call, (640, 2560)) == "other"
 
 
 def test_hybrid_chunk_prefill_fits_and_moves_no_pool(hybrid_cell):

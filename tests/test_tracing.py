@@ -287,6 +287,34 @@ def test_request_spans_partition_submit_to_finish(engine, tiny):
     assert all(s["parent_id"] is None for s in spans)
 
 
+def test_decode_passes_count_the_blocks_they_attend(engine, tiny):
+    """Two requests, one after the other: every one-token pass has ONE
+    live row, which attends the blocks that hold its ``position + 1``
+    keys, while the rows' tables name 4 rows x 4 blocks a pass."""
+    rng = np.random.default_rng(5)
+    before = engine.stats()
+    attended = 0
+    for n_prompt, max_new in ((6, 5), (13, 9)):
+        engine.submit(rng.integers(0, tiny[0].vocab_size, n_prompt).tolist(),
+                      max_new=max_new).result(timeout=300)
+        # the prefill emits the first token; pass t writes position
+        # n_prompt + t and attends the blocks of 8 up to it
+        attended += sum((n_prompt + t) // 8 + 1 for t in range(max_new - 1))
+    after = engine.stats()
+    passes = after["decode_iterations"] - before["decode_iterations"]
+    assert passes == 4 + 8
+    assert after["kv_blocks_attended"] - before["kv_blocks_attended"] \
+        == attended == 2 * 1 + 5 * 2 + 5 * 3
+    assert after["kv_blocks_tabled"] - before["kv_blocks_tabled"] \
+        == passes * 4 * 4
+    from ray_tpu import inference
+    snap = {n: series for n, _kind, _help, series
+            in inference.metrics_snapshot()}
+    for counter in ("kv_blocks_attended", "kv_blocks_tabled"):
+        assert snap[f"ray_tpu_inference_{counter}_total"][
+            (("engine", engine.name),)] == after[counter]
+
+
 def test_engine_pass_spans_nest_and_do_not_overlap(engine, tiny, traced):
     _burst(engine, tiny[0], n=3, max_new=6, seed=2)
     tracing.disable_tracing()          # passes after this record nothing
