@@ -29,6 +29,34 @@ def jitted_init(cfg):
     return jax.jit(lambda key: gpt.init_params(cfg, key))
 
 
-def make_params(cfg, seed31: int):
+def make_params(cfg, seed31: int, served_as=None):
+    """The weights from the seed.  ``served_as`` (a configuration's
+    ``weights_served_as``: {dtype name: [per-layer leaf names]}) stores
+    those leaves in the type they are served in, inside the same jitted
+    call, so the float32 form of a leaf is never held."""
     import jax
-    return jitted_init(cfg)(jax.random.PRNGKey(seed31))
+    import jax.numpy as jnp
+    from ray_tpu.models import gpt
+    dtype_of = {leaf: jnp.dtype(name)
+                for name, leaves in (served_as or {}).items()
+                for leaf in leaves}
+
+    def init(key):
+        params = gpt.init_params(cfg, key)
+        layers = {k: v.astype(dtype_of.get(k, v.dtype))
+                  for k, v in params["layers"].items()}
+        return {**params, "layers": layers}
+    return jax.jit(init)(jax.random.PRNGKey(seed31))
+
+
+def device_memory_peak(devs) -> int:
+    """Peak bytes on the fullest chip so far.  The TPU runtime counts
+    buffers (``peak_bytes_in_use``) and the scratch memory of running
+    programs (``peak_bytes_reserved``) apart, and their peaks need not
+    coincide: the larger of the two is a lower bound of the true peak."""
+    peak = 0
+    for d in devs:
+        stats = d.memory_stats() or {}
+        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)),
+                   int(stats.get("peak_bytes_reserved", 0)))
+    return peak

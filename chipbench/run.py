@@ -22,7 +22,10 @@ No TPU, a ``device_kind`` without an entry in ``chipbench/peaks.json``,
 or fewer chips than the cell asks for: exit code 2 and nothing on the
 standard output.  ``--rehearse`` (the driver never passes it) runs the
 same code on the CPU at the fixture size of ``chipbench/tests/``; its
-line says ``"correct": false`` and carries no metric.
+line says ``"correct": false`` and carries no metric (what the checks
+came to is under ``rehearsal_verdict_not_a_result``).
+``--mix-override`` lays a JSON object over the traffic mix: for
+``chipbench/sweep.py`` alone, never passed by the driver.
 """
 
 from __future__ import annotations
@@ -73,16 +76,7 @@ def metrics_of_cell(bench: dict, section: str, cell: str) -> list:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def device_report(devs, trace_summary) -> dict:
-    # the TPU runtime counts buffers (peak_bytes_in_use) and the
-    # scratch memory of running programs (peak_bytes_reserved) apart,
-    # and their peaks need not coincide: the larger of the two is a
-    # lower bound of the true peak on that chip
-    peak = 0
-    for d in devs:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)),
-                   int(stats.get("peak_bytes_reserved", 0)))
+def device_report(devs, trace_summary, peak: int) -> dict:
     out = {"platform": devs[0].platform, "kind": devs[0].device_kind,
            "count": len(devs), "memory_peak_bytes": peak}
     if trace_summary is not None:
@@ -99,6 +93,9 @@ def main() -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal at fixture size; never a result")
+    ap.add_argument("--mix-override", type=json.loads, default={},
+                    help="JSON object laid over the traffic mix, for "
+                    "chipbench/sweep.py alone; the driver never passes it")
     args = ap.parse_args()
 
     # fd 1 -> stderr: nothing a library or a child prints can land on
@@ -123,6 +120,7 @@ def main() -> int:
         fixture = load_json("chipbench", "tests", "rehearse.json")
         config = {**config, **fixture["config"]}
         mix = {**mix, **fixture["traffic"].get(mix["kind"], {})}
+    mix = {**mix, **args.mix_override}
 
     import jax
     devs = jax.devices()
@@ -152,7 +150,9 @@ def main() -> int:
     kind_mod = importlib.import_module("chipbench.traffic." + mix["kind"])
     res = kind_mod.run(ctx)
     # res: correct, attempted, failed, setup_s, end_to_end {name: value},
-    # obs {what the per-layer readers read}, notes {..}
+    # obs {what the per-layer readers read}, notes {..}, checks {name:
+    # {value, limit}}: every number `correct` was decided from, and
+    # memory_peak_bytes as read when the window closed
 
     declared_e2e = metrics_of_cell(bench, "end_to_end", cell["name"])
     values = {**res["end_to_end"], "setup_s": res["setup_s"]}
@@ -177,16 +177,26 @@ def main() -> int:
     line = {"correct": bool(res["correct"]) and not args.rehearse,
             "attempted": res["attempted"], "failed": res["failed"],
             "metrics": {} if args.rehearse else metrics,
-            "device": device_report(devs, summary),
+            "device": device_report(devs, summary, res["memory_peak_bytes"]),
             "notes": {**res.get("notes", {}),
+                      "mix_override": args.mix_override,
                       "memory_stats": devs[0].memory_stats()}}
     if args.rehearse:
         line["rehearsal_metrics_not_device_numbers"] = metrics
+        line["rehearsal_verdict_not_a_result"] = bool(res["correct"])
     if args.trace and summary is not None:
         from chipbench.trace_reduce import breakdown
         line["breakdown"] = breakdown(summary)
+    # what was compared, each number beside its limit: the line's last
+    # key and the last lines of the standard error
+    line["checks"] = res["checks"]
     out.write(json.dumps(line) + "\n")
     out.flush()
+    log(f"correct: {line['correct']}")
+    for name, c in res["checks"].items():
+        sys.stderr.write(f"check {name}: " + " ".join(
+            f"{k} {v}" for k, v in c.items()) + "\n")
+    sys.stderr.flush()
     return 0      # the run reached its end; `correct` is in the line
 
 

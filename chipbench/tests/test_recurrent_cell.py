@@ -201,6 +201,10 @@ def test_rehearsal_of_the_cell_at_its_own_fixture(tmp_path):
     assert line["failed"] == 0 and line["device"]["platform"] == "cpu"
     assert line["notes"]["worst_margin"] <= line["notes"]["tie_tolerance"]
     assert line["notes"]["compiles_in_window"] == 0
+    assert line["rehearsal_verdict_not_a_result"] is True, line["checks"]
+    assert set(line["notes"]["setup_stamps"]) >= {
+        "import_s", "weights_s", "programs_s", "warmup_s", "lead_in_s",
+        "reference_check_s"}
     seen = line["rehearsal_metrics_not_device_numbers"]
     assert "expert_load_max_over_mean.serve" in seen
     assert "state_rows_share.serve" in seen

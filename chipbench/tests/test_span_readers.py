@@ -199,7 +199,7 @@ def test_no_spans_no_window_no_metric(name):
 
 @pytest.mark.parametrize("cell,seconds,names", [
     ("train-124m-b16s1024", "1", TRAIN),
-    ("serve-xl-chat-r80", "2", SERVE)])
+    ("serve-xl-chat-r80-v2", "2", SERVE)])
 def test_rehearsal_prints_the_new_metrics(cell, seconds, names, tmp_path):
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")}
@@ -214,6 +214,11 @@ def test_rehearsal_prints_the_new_metrics(cell, seconds, names, tmp_path):
     assert p.returncode == 0, p.stderr[-2000:]
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert line["correct"] is False and line["metrics"] == {}
+    # the sound path at the fixture size passes every check, and the
+    # numbers compared end the line
+    assert line["rehearsal_verdict_not_a_result"] is True, line["checks"]
+    assert list(line)[-1] == "checks"
+    assert set(line["notes"]["setup_stamps"]) >= {"import_s"}
     seen = line["rehearsal_metrics_not_device_numbers"]
     for name in names:
         assert seen[name]["value"] is not None, (name, seen)

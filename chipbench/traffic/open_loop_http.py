@@ -8,7 +8,10 @@ never imports JAX.  Greedy decoding, ``eos`` off, so ``max_tokens``
 fixes every output length.
 
 Set-up: weights made on the device in one jitted call from the seed,
-the deployment built, then five warm-up requests that visit every
+in the type they are served in (the configuration's
+``weights_served_as``: the engine then holds the very arrays it was
+handed, and no float32 copy of them lives through the window),
+the deployment built, then four warm-up requests that visit every
 program the window can use (full-width prefill, chunk prefill with a
 partial last chunk, decode, prefix adoption with a copy-on-write tail).
 Then a lead-in of ``lead_s`` (still set-up): the same cycle of requests
@@ -16,9 +19,16 @@ at the same rate, so that the window opens on a system in its steady
 state.  The window: requests due over ``--seconds`` at the mix's fixed
 rate; after it, a drain of ``drain_s``; what is unfinished then,
 refused or failed counts as ``failed``.  Times are taken at the client,
-from when each request was DUE.  After the engine is shut down and its pool
-freed, a seeded sample of completed requests is teacher-forced through
-the plain reference.
+from when each request was DUE.  After the engine is shut down, its pool
+freed and the peak memory read, a seeded sample of completed requests
+(the longest among them) is teacher-forced through the plain reference.
+
+``notes.setup_stamps`` splits ``setup_s`` into ``import_s`` (process
+start to this kind's imports done), ``weights_s``, ``programs_s``
+(``serve.run``: the engine built and, with ``warm_on_init``, its
+programs compiled or loaded; compile-cache hits and misses beside it),
+``warmup_s`` and ``lead_in_s``; ``reference_check_s`` follows the window
+and is not part of ``setup_s``.
 """
 
 from __future__ import annotations
@@ -108,8 +118,36 @@ def client_metrics(requests: list, by_id: dict, seconds: float) -> dict:
     return out
 
 
+def pick_checked(done: list, seed: int, n: int) -> list:
+    """The requests the reference follows: the longest finished one
+    (prompt + served tokens) and ``n - 1`` others drawn from the seed."""
+    import numpy as np
+    if not done:
+        return []
+    longest = max(range(len(done)), key=lambda i: (
+        len(done[i]["prompt"]) + done[i]["max_tokens"], -i))
+    rng = np.random.default_rng([int(seed), 11])
+    rest = [int(i) for i in rng.permutation(len(done)) if int(i) != longest]
+    return [done[i] for i in [longest] + rest[:max(0, n - 1)]]
+
+
+def verdict(failed: int, compiles_in_window: int, worst: float,
+            tolerance: float, checked: int, measured: bool):
+    """``correct`` and the numbers it was decided from, each beside its
+    limit (``run.py`` prints them last)."""
+    checks = {
+        "worst_margin": {"value": worst, "limit": tolerance},
+        "failed_requests": {"value": failed, "limit": 0},
+        "compiles_in_window": {"value": compiles_in_window, "limit": 0},
+        "checked_requests": {"value": checked, "at_least": 1},
+    }
+    correct = (failed == 0 and checked > 0 and worst <= tolerance
+               and compiles_in_window == 0 and measured)
+    return correct, checks
+
+
 def warm_up(send, cfg, seed: int) -> None:
-    """Five requests, one after another (set-up), that visit every
+    """Four requests, one after another (set-up), that visit every
     program the window can use.  ``send(prompt, max_tokens)`` returns
     the emitted tokens."""
     import numpy as np
@@ -131,22 +169,26 @@ def warm_up(send, cfg, seed: int) -> None:
 
 def run(ctx) -> dict:
     import jax
-    import numpy as np
 
     from ray_tpu import serve
     from ray_tpu._compile_cache import compile_cache_stats
     from ray_tpu.inference import EngineConfig, build_gpt_deployment
 
     from chipbench import stats, trace_reduce
-    from chipbench.model import fold_seed, gpt_config, make_params
+    from chipbench.model import (device_memory_peak, fold_seed, gpt_config,
+                                 make_params)
     from chipbench.reference import gpt2 as ref
     from chipbench.traffic_gen import chat_requests
 
     mix, config = ctx.mix, ctx.config
     cfg = gpt_config(config)
     engine_cfg = EngineConfig(**config["engine"])
-    params = make_params(cfg, fold_seed(ctx.seed, 0))
+    stamps = stats.Stamps(ctx.t_start)
+    stamps.mark("import_s")
+    params = make_params(cfg, fold_seed(ctx.seed, 0),
+                         config.get("weights_served_as"))
     jax.block_until_ready(params)
+    stamps.mark("weights_s")
     ctx.log("weights on the device")
     handle = serve.run(
         build_gpt_deployment(name=ROUTE, cfg=cfg, engine_cfg=engine_cfg,
@@ -155,6 +197,8 @@ def run(ctx) -> dict:
     addr = serve.proxy_address()
     host, port = addr[len("http://"):].split(":")
     port = int(port)
+    stamps.mark("programs_s")
+    stamps.cache("after_programs", compile_cache_stats())
     ctx.log(f"deployment up at {addr}")
     trace = {}
     try:
@@ -167,6 +211,7 @@ def run(ctx) -> dict:
                 raise RuntimeError(f"warm-up request failed: {got}")
             return got["tokens"]
         warm_up(send, cfg, ctx.seed)
+        stamps.mark("warmup_s")
         ctx.log("warm-up done")
 
         # ---- the window
@@ -177,6 +222,7 @@ def run(ctx) -> dict:
         # the child needs a moment to start; the lead-in is set-up
         t0 = time.monotonic() + 1.0 + lead_s
         setup_s = t0 - ctx.t_start
+        stamps.mark("lead_in_s", at=t0)
 
         def mid():
             """Runs here while the child offers the load."""
@@ -202,8 +248,11 @@ def run(ctx) -> dict:
     after, c1 = trace["at_window_end"], trace["compiles_at_end"]
     compiles_in_window = (c1["hits"] + c1["misses"]
                           - c0["hits"] - c0["misses"])
+    stamps.cache("at_window_start", c0)
     del handle
     gc.collect()
+    # the window's peak, read before the reference puts anything on the chip
+    memory_peak = device_memory_peak(jax.devices())
 
     # ---- reduction (client side)
     by_id = {r["id"]: r for r in recs}
@@ -224,19 +273,22 @@ def run(ctx) -> dict:
     # now that the engine's pool is freed
     done = [r for r in requests if by_id.get(r["id"], {}).get("ended")
             == "done"]
-    rng = np.random.default_rng([int(ctx.seed), 11])
-    picks = rng.permutation(len(done))[:mix["checked_requests"]]
-    worst = 0.0
-    for i in picks:
-        r = done[int(i)]
-        m = ref.margins(params, r["prompt"], by_id[r["id"]]["tokens"],
-                        config["n_head"], cfg.max_seq)
+    t_ref = time.monotonic()
+    picks = pick_checked(done, ctx.seed, mix["checked_requests"])
+    worst, checked_tokens = 0.0, 0
+    for r in picks:
+        emitted = by_id[r["id"]]["tokens"]
+        m = ref.margins(params, r["prompt"], emitted, config["n_head"],
+                        cfg.max_seq)
         worst = max(worst, float(m.max()))
+        checked_tokens += len(emitted)
+    stamps.notes["reference_check_s"] = time.monotonic() - t_ref
     ctx.log(f"reference: worst margin {worst:.5f} over {len(picks)} "
-            f"requests (tolerance {mix['tie_tolerance']})")
-    correct = (failed == 0 and len(picks) > 0
-               and worst <= mix["tie_tolerance"]
-               and compiles_in_window == 0 and bool(end_to_end))
+            f"requests, {checked_tokens} tokens "
+            f"(tolerance {mix['tie_tolerance']})")
+    correct, checks = verdict(failed, compiles_in_window, worst,
+                              mix["tie_tolerance"], len(picks),
+                              bool(end_to_end))
 
     counters = {k: after[k] - before[k] for k in COUNTERS}
     counters["occupancy_sum"] = after["occupancy_sum"] \
@@ -268,8 +320,10 @@ def run(ctx) -> dict:
         "cache_bytes": after["cache_bytes"],
         "counters": counters, "compiles_in_window": compiles_in_window,
         "worst_margin": worst, "tie_tolerance": mix["tie_tolerance"],
-        "checked_requests": len(picks),
+        "checked_requests": len(picks), "checked_tokens": checked_tokens,
+        "setup_stamps": stamps.notes,
     }
     return {"correct": correct, "attempted": len(requests),
             "failed": failed, "setup_s": setup_s,
-            "end_to_end": end_to_end, "obs": obs, "notes": notes}
+            "end_to_end": end_to_end, "obs": obs, "notes": notes,
+            "checks": checks, "memory_peak_bytes": memory_peak}

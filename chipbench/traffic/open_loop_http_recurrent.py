@@ -36,7 +36,8 @@ import time                                 # noqa: E402
 
 from chipbench.traffic.open_loop_http import (COUNTERS, ROUTE,    # noqa: E402
                                               client_metrics,
-                                              engine_counters, run_loadgen)
+                                              engine_counters, pick_checked,
+                                              run_loadgen, verdict)
 
 EXPERT_COUNTERS = ("expert_assignments_held", "expert_assignments_total",
                    "expert_load_max")
@@ -76,7 +77,7 @@ def run(ctx) -> dict:
     from ray_tpu.inference import EngineConfig, build_gpt_deployment
 
     from chipbench import scoped_trace, stats, trace_reduce
-    from chipbench.model import fold_seed
+    from chipbench.model import device_memory_peak, fold_seed
     from chipbench.reference import hybrid_ssm_moe as ref
     from chipbench.traffic_gen import chat_requests
 
@@ -91,9 +92,12 @@ def run(ctx) -> dict:
         mix = {**mix, **own["traffic"]}
     cfg, published, held = model_config(config)
     engine_cfg = EngineConfig(**config["engine"])
+    stamps = stats.Stamps(ctx.t_start)
+    stamps.mark("import_s")
     params = jax.jit(lambda key: hybrid.init_params(cfg, key))(
         jax.random.PRNGKey(fold_seed(ctx.seed, 0)))
     jax.block_until_ready(params)
+    stamps.mark("weights_s")
     ctx.log(f"weights on the device: {hybrid.num_params(params) / 1e6:.0f} M "
             f"parameters")
     handle = serve.run(
@@ -103,6 +107,8 @@ def run(ctx) -> dict:
     addr = serve.proxy_address()
     host, port = addr[len("http://"):].split(":")
     port = int(port)
+    stamps.mark("programs_s")
+    stamps.cache("after_programs", compile_cache_stats())
     ctx.log(f"deployment up at {addr}")
     trace = {"polls": []}
     try:
@@ -115,6 +121,7 @@ def run(ctx) -> dict:
             time.monotonic(), 600.0)[0]
         if got["ended"] != "done":
             raise RuntimeError(f"warm-up request failed: {got}")
+        stamps.mark("warmup_s")
         ctx.log("warm-up done")
 
         # ---- the window
@@ -124,6 +131,7 @@ def run(ctx) -> dict:
         lead_s = max([0.0] + [-r["due_s"] for r in requests])
         t0 = time.monotonic() + 1.0 + lead_s
         setup_s = t0 - ctx.t_start
+        stamps.mark("lead_in_s", at=t0)
 
         def counters():
             return {**engine_counters(handle), **expert_counters(handle)}
@@ -164,8 +172,11 @@ def run(ctx) -> dict:
     after, c1 = trace["at_window_end"], trace["compiles_at_end"]
     compiles_in_window = (c1["hits"] + c1["misses"]
                           - c0["hits"] - c0["misses"])
+    stamps.cache("at_window_start", c0)
     del handle
     gc.collect()
+    # the window's peak, read before the reference puts anything on the chip
+    memory_peak = device_memory_peak(jax.devices())
 
     # ---- reduction (client side)
     by_id = {r["id"]: r for r in recs}
@@ -186,23 +197,23 @@ def run(ctx) -> dict:
     # now that the engine's pools are freed
     done = [r for r in requests if by_id.get(r["id"], {}).get("ended")
             == "done"]
-    rng = np.random.default_rng([int(ctx.seed), 11])
-    picks = rng.permutation(len(done))[:mix["checked_requests"]]
+    t_ref = time.monotonic()
+    picks = pick_checked(done, ctx.seed, mix["checked_requests"])
     worst, disagreed, checked_tokens = 0.0, 0, 0
-    for i in picks:
-        r = done[int(i)]
+    for r in picks:
         emitted = by_id[r["id"]]["tokens"]
         m, best = ref.margins(params, r["prompt"], emitted, published, held,
                               engine_cfg.max_seq)
         worst = max(worst, float(m.max()))
         disagreed += int((best != np.asarray(emitted)).sum())
         checked_tokens += len(emitted)
+    stamps.notes["reference_check_s"] = time.monotonic() - t_ref
     ctx.log(f"reference: worst margin {worst:.6f} over {len(picks)} "
             f"requests (tolerance {mix['tie_tolerance']}); {disagreed} of "
             f"{checked_tokens} tokens are not the reference's argmax")
-    correct = (failed == 0 and len(picks) > 0
-               and worst <= mix["tie_tolerance"]
-               and compiles_in_window == 0 and bool(end_to_end))
+    correct, checks = verdict(failed, compiles_in_window, worst,
+                              mix["tie_tolerance"], len(picks),
+                              bool(end_to_end))
 
     counters = {k: after[k] - before[k]
                 for k in COUNTERS + EXPERT_COUNTERS}
@@ -244,7 +255,9 @@ def run(ctx) -> dict:
         "worst_margin": worst, "tie_tolerance": mix["tie_tolerance"],
         "checked_requests": len(picks), "checked_tokens": checked_tokens,
         "tokens_not_reference_argmax": disagreed,
+        "setup_stamps": stamps.notes,
     }
     return {"correct": correct, "attempted": len(requests),
             "failed": failed, "setup_s": setup_s,
-            "end_to_end": end_to_end, "obs": obs, "notes": notes}
+            "end_to_end": end_to_end, "obs": obs, "notes": notes,
+            "checks": checks, "memory_peak_bytes": memory_peak}

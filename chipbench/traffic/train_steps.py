@@ -12,6 +12,15 @@ fetch bound whole groups of k finished steps; tokens/s is all the
 tokens of the steps between the first and the last of those stamps over
 the time between them.  The last step's checkpoint (the trainer writes
 one unconditionally) falls after the last stamp.
+
+``notes.setup_stamps`` splits ``setup_s`` into ``import_s`` (process
+start to this kind's imports done), ``warm_fit_s`` (the first ``fit``:
+weights, every program compiled or loaded, ``warm_steps`` steps and the
+checkpoint the trainer writes on the last of them), ``reference_check_s``
+and ``measured_fit_start_s`` (the second ``fit`` up to the stamp that
+opens the window: trainer built, weights made again, the first k steps
+and their fetch), with the compile-cache hits and misses after the warm
+fit and at the window's start.
 """
 
 from __future__ import annotations
@@ -113,8 +122,10 @@ def reference_loss(cfg, config: dict, seed31: int, tokens, rows: int,
 def run(ctx) -> dict:
     import jax
 
+    from ray_tpu._compile_cache import compile_cache_stats
+
     from chipbench import flops, stats, trace_reduce
-    from chipbench.model import fold_seed, gpt_config
+    from chipbench.model import device_memory_peak, fold_seed, gpt_config
 
     mix, config = ctx.mix, ctx.config
     cfg = gpt_config(config)
@@ -122,11 +133,15 @@ def run(ctx) -> dict:
     seed31 = fold_seed(ctx.seed, 0)
     run_dir = tempfile.mkdtemp(prefix="chipbench_train_")
     trainer_cfg = config["trainer"]
+    stamps = stats.Stamps(ctx.t_start)
+    stamps.mark("import_s")
     try:
         # ---- set-up: warm every program, step time, reference check
         warm = Feed(ctx.seed, 1, cfg.vocab_size, B, S + 1)
         warm_losses = fit(cfg, warm, mix["warm_steps"], 1, seed31,
                           trainer_cfg, run_dir, "warm")
+        stamps.mark("warm_fit_s")
+        stamps.cache("after_warm_fit", compile_cache_stats())
         # the first step compiles or loads: leave it out
         step_s = min(stats.gaps(warm.stamps[1:]))
         ctx.log(f"warm fit: losses {warm_losses}, fastest step {step_s:.4f}s")
@@ -134,6 +149,7 @@ def run(ctx) -> dict:
         ref_loss = reference_loss(cfg, config, seed31, warm.first["tokens"],
                                   rows, mix["loss_rows_per_call"])
         loss_diff = abs(warm_losses[0] - ref_loss)
+        stamps.mark("reference_check_s")
         ctx.log(f"first loss {warm_losses[0]:.5f} vs reference "
                 f"{ref_loss:.5f} on {rows} rows (diff {loss_diff:.5f})")
 
@@ -171,7 +187,16 @@ def run(ctx) -> dict:
     steps = last - first
     tokens_per_s = steps * B * S / window_s
     compiles_in_window = feed.compiles[last] - feed.compiles[first]
+    stamps.mark("measured_fit_start_s", at=feed.stamps[first])
+    memory_peak = device_memory_peak(jax.devices())
     finite = all(math.isfinite(x) for x in losses + warm_losses)
+    checks = {
+        "loss_diff": {"value": loss_diff, "limit": mix["loss_tolerance"]},
+        "compiles_in_window": {"value": compiles_in_window, "limit": 0},
+        "losses_not_finite": {"value": sum(
+            not math.isfinite(x) for x in losses + warm_losses), "limit": 0},
+        "loss_fetches": {"value": len(losses), "at_least": n_groups},
+    }
     correct = (finite and loss_diff <= mix["loss_tolerance"]
                and compiles_in_window == 0 and len(losses) >= n_groups)
     ctx.log(f"{steps} steps in {window_s:.3f}s = {tokens_per_s:.1f} "
@@ -201,5 +226,9 @@ def run(ctx) -> dict:
                   "compiles_in_window": compiles_in_window,
                   "warm_step_s": step_s,
                   "flops_per_token": flops.train_flops_per_token(
-                      flops.gpt2_param_count(config), config, S)},
+                      flops.gpt2_param_count(config), config, S),
+                  "setup_stamps": {
+                      **stamps.notes,
+                      "cache_requests_at_window_start": feed.compiles[first]}},
+        "checks": checks, "memory_peak_bytes": memory_peak,
     }
