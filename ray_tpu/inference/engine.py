@@ -529,7 +529,7 @@ class _KVAndState:
             # decode) re-prefills and gets its first token then
             if tok is None and eng._slot_req.get(row) is req:
                 with tracing.span("engine.fetch", bytes=16):
-                    pend[3] = tok = int(jax.device_get(load)[3])
+                    pend[3] = tok = int(jax.device_get(load)[hybrid.N_LOAD])
                 req._emit(tok)
 
     @staticmethod
@@ -554,11 +554,13 @@ class _KVAndState:
         _KVAndState._emit_first(eng)
         loads = jax.device_get(eng._load)
         eng._load = []
-        eng._greedy = loads[-1][3:]           # the decode step's own
+        eng._greedy = loads[-1][hybrid.N_LOAD:]   # the decode step's own
         for load in loads:
             eng._expert_held += int(load[0])
             eng._expert_total += int(load[1])
             eng._expert_load_max += int(load[2])
+            eng._expert_touched += int(load[3])
+        eng._expert_touched_decode += int(loads[-1][3])
         return logits, sum(load.nbytes for load in loads)
 
     @staticmethod
@@ -719,6 +721,8 @@ class InferenceEngine:
         self._expert_held = 0
         self._expert_total = 0
         self._expert_load_max = 0
+        self._expert_touched = 0          # held experts with >= 1 token
+        self._expert_touched_decode = 0   # ... in decode steps alone
 
         with _registry_lock:
             self.name = name or f"engine-{next(_engine_seq)}"
@@ -1962,6 +1966,8 @@ class InferenceEngine:
             "expert_assignments_held": self._expert_held,
             "expert_assignments_total": self._expert_total,
             "expert_load_max": self._expert_load_max,
+            "expert_touched_held": self._expert_touched,
+            "expert_touched_held_decode": self._expert_touched_decode,
             # fences remotely-advertised block ids across donated-
             # pool recoveries (cluster prefix plane)
             "pool_generation": pool["generation"],
@@ -1988,6 +1994,7 @@ def metrics_snapshot() -> list:
     tps, arate, saccept = {}, {}, {}
     meshdev, tpsh = {}, {}
     sbytes, srows, eheld, etotal, emax = {}, {}, {}, {}, {}
+    etouch, etouchd = {}, {}
     wbytes, wcast = {}, {}
     for name, eng in sorted(engines.items()):
         st = eng.stats()
@@ -2030,6 +2037,8 @@ def metrics_snapshot() -> list:
         eheld[key] = float(st.get("expert_assignments_held", 0))
         etotal[key] = float(st.get("expert_assignments_total", 0))
         emax[key] = float(st.get("expert_load_max", 0))
+        etouch[key] = float(st.get("expert_touched_held", 0))
+        etouchd[key] = float(st.get("expert_touched_held_decode", 0))
         wbytes[key] = float(st["weight_bytes"])
         wcast[key] = float(st["weight_bytes_cast_per_pass"])
     zero = {(("engine", "none"),): 0.0}
@@ -2096,6 +2105,13 @@ def metrics_snapshot() -> list:
         ("ray_tpu_inference_expert_load_max_total", "counter",
          "Assignments of the busiest held expert, summed over layers "
          "and passes", emax or zero),
+        ("ray_tpu_inference_expert_touched_held_total", "counter",
+         "Held experts with at least one assignment, summed over "
+         "expert layers and passes", etouch or zero),
+        ("ray_tpu_inference_expert_touched_held_decode_total", "counter",
+         "Held experts with at least one assignment, summed over "
+         "expert layers and decode steps (no prefill chunk)",
+         etouchd or zero),
         ("ray_tpu_inference_weight_bytes", "gauge",
          "Bytes of the parameter tree the programs are handed",
          wbytes or zero),

@@ -16,11 +16,12 @@ manager owns (inference/cache.py):
 
 All four pool arrays are donated and updated in place.  Besides the
 logits each program returns ``load`` int32 — held expert assignments,
-all assignments, and the busiest held expert's assignments summed over
-layers, of the real tokens only, then the greedy tokens (the argmax of
+all assignments, the busiest held expert's assignments and the held
+experts touched, summed over the experts sublayers, of the real tokens
+only (``hybrid.run_layers``), then the greedy tokens (the argmax of
 the logits, taken on the device): every row's from the decode step, the
-last real position's from the chunk.  A greedy pass then fetches ``3 +
-rows`` integers and a greedy first token one vector of 4; the ``[rows,
+last real position's from the chunk.  A greedy pass then fetches ``4 +
+rows`` integers and a greedy first token one vector of 5; the ``[rows,
 vocab]`` logits never leave the device (12.8 MB a pass at 64 rows x
 50,176, to the host and back for the argmax: 8 of the 13.5 ms of host
 time a pass that the first chip runs of PR 29 read).
@@ -75,7 +76,7 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
 
     (params, k_pool, v_pool, conv, ssm, packed [b, T + 3] int32
      (``pack_step``: tables | tokens | positions | active))
-        -> (logits [b, vocab] f32, load + greedy [3 + b] int32,
+        -> (logits [b, vocab] f32, load + greedy [4 + b] int32,
             k_pool, v_pool, conv, ssm)
 
     Decode row r's state is row r of the state arrays.  An inactive row
@@ -135,7 +136,7 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
 
     (params, k_pool, v_pool, conv, ssm, packed [T + C + 3] int32
      (``pack_chunk``: table | tokens | start, row, n_valid))
-        -> (logits [C, vocab] f32, load + greedy [4] int32,
+        -> (logits [C, vocab] f32, load + greedy [5] int32,
             k_pool, v_pool, conv, ssm)
 
     Prompt positions ``start .. start + n_valid`` of decode row ``row``:

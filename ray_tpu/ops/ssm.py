@@ -6,6 +6,10 @@ Per head (state ``S`` is ``[P, N]``, head width P, state width N)::
     S_t = exp(dt_t * A) * S_{t-1} + dt_t * x_t B_t^T
     y_t = S_t C_t + D * x_t
 
+``B`` and ``C`` come in G groups, ``[..., G, N]``: the H heads are split
+into G runs of ``H / G`` consecutive heads and head h reads group ``h //
+(H / G)`` (one group: every head reads the same B and C).
+
 Two forms of the same recurrence, chosen by the window's static width:
 
   * a WINDOW of tokens (``ssd_window``): the chunked scan.  Inside a
@@ -82,7 +86,7 @@ TILE_ROWS, CHUNK_ROWS, LANES = 4096, 128, 128
 
 def _step_kernel(layer_ref, count_ref, rows_ref, decay_ref,
                  s_ref, dtx_ref, b_ref, c_ref, o_ref, y_ref, *,
-                 head_rows: int):
+                 head_rows: int, group_rows: int):
     """One tile [TR, N] of one live row's state: read, advanced, reduced
     to ``y`` and written, in one visit, a chunk of ``ck`` rows at a
     time.
@@ -95,6 +99,10 @@ def _step_kernel(layer_ref, count_ref, rows_ref, decay_ref,
     accumulator the same way round, which one transpose a tile turns
     back.  (Two 128 x 128 transposes a CHUNK made the kernel compute
     bound at 20 us a row and layer; this way it runs at the DMA's 12.)
+
+    ``b_ref`` / ``c_ref`` hold the row's ``[G, N]`` B and C.  A chunk
+    lies inside ONE group's ``group_rows`` state rows (the caller sees
+    to it) and picks its group's row of them by its own index.
     """
     f32 = jnp.float32
     slot, t = pl.program_id(0), pl.program_id(1)
@@ -105,7 +113,9 @@ def _step_kernel(layer_ref, count_ref, rows_ref, decay_ref,
     @pl.when(slot < count)
     def _():
         row = rows_ref[slot]
-        Bv, Cv = b_ref[0], c_ref[0]                             # [1, N]
+        one_group = b_ref.shape[1] == 1
+        if one_group:
+            Bv, Cv = b_ref[0], c_ref[0]                         # [1, N]
         lane = lax.broadcasted_iota(jnp.int32, (ck, LANES), 1)
         dtx_t = jnp.concatenate(
             [dtx_ref[0], jnp.zeros((LANES - n_chunks, ck), f32)]).T
@@ -113,15 +123,20 @@ def _step_kernel(layer_ref, count_ref, rows_ref, decay_ref,
         def chunk(c, y_t):
             r0 = pl.multiple_of(c * ck, ck)
             head0 = (t * n_chunks + c) * heads
+            if one_group:
+                B, C = Bv, Cv
+            else:
+                g = (t * n_chunks + c) * ck // group_rows
+                B, C = b_ref[0, pl.ds(g, 1), :], c_ref[0, pl.ds(g, 1), :]
             dtx = jnp.sum(jnp.where(lane == c, dtx_t, 0.0), axis=1,
                           keepdims=True)                        # [ck, 1]
             new = jnp.concatenate([
                 decay_ref[row, head0 + h]
                 * s_ref[0, 0, pl.ds(r0 + h * head_rows, head_rows), :]
-                + dtx[h * head_rows:(h + 1) * head_rows] * Bv
+                + dtx[h * head_rows:(h + 1) * head_rows] * B
                 for h in range(heads)])                         # [ck, N]
             o_ref[0, 0, pl.ds(r0, ck), :] = new
-            y = jnp.sum(new * Cv, axis=1, keepdims=True)        # [ck, 1]
+            y = jnp.sum(new * C, axis=1, keepdims=True)         # [ck, 1]
             return jnp.where(lane == c, y, y_t)
 
         y_t = lax.fori_loop(0, n_chunks, chunk, jnp.zeros((ck, LANES), f32))
@@ -136,8 +151,8 @@ def _step_kernel(layer_ref, count_ref, rows_ref, decay_ref,
 
 def ssd_step(x, dt, A, B, C, D, pool, layer, n_valid):
     """One token, on the state where it is stored.  x [b, 1, H, P], dt
-    [b, 1, H] (after softplus), A, D [H], B, C [b, 1, N] (one group);
-    ``pool`` [L, b, H * P, N] float32 holds the rows' state of L layers
+    [b, 1, H] (after softplus), A, D [H], B, C [b, 1, G, N]; ``pool``
+    [L, b, H * P, N] float32 holds the rows' state of L layers
     and ``layer`` (an int32 scalar, traced or not) says which one this
     is.  -> (y [b, 1, H, P] float32, pool).
 
@@ -157,7 +172,10 @@ def ssd_step(x, dt, A, B, C, D, pool, layer, n_valid):
     f32 = jnp.float32
     b, _, H, P = x.shape
     L, _, HP, N = pool.shape
-    ck = CHUNK_ROWS if HP % CHUNK_ROWS == 0 and CHUNK_ROWS % P == 0 else HP
+    G = B.shape[2]
+    group_rows = HP // G        # a group's heads lie in one run of rows
+    ck = CHUNK_ROWS if (group_rows % CHUNK_ROWS == 0
+                        and CHUNK_ROWS % P == 0) else group_rows
     tr = TILE_ROWS if HP % TILE_ROWS == 0 and TILE_ROWS % ck == 0 else HP
     n_tiles, n_chunks = HP // tr, tr // ck
     if n_chunks > LANES:        # a tile's y: one lane of a register a chunk
@@ -182,13 +200,14 @@ def ssd_step(x, dt, A, B, C, D, pool, layer, n_valid):
                          (refs[0][0], *at(slot, t, refs), 0))
     per_chunk = pl.BlockSpec((1, n_chunks, ck), lambda slot, t, *refs:
                              (*at(slot, t, refs), 0))
-    per_row = pl.BlockSpec((1, 1, N), lambda slot, t, *refs:
+    per_row = pl.BlockSpec((1, G, N), lambda slot, t, *refs:
                            (at(slot, t, refs)[0], 0, 0))
     interpret = _interpret_mode()
     params = {} if interpret else dict(compiler_params=pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary")))
     pool, y = pl.pallas_call(
-        functools.partial(_step_kernel, head_rows=P),
+        functools.partial(_step_kernel, head_rows=P,
+                          group_rows=group_rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(b, n_tiles),
             in_specs=[state, per_chunk, per_row, per_row],
@@ -201,7 +220,7 @@ def ssd_step(x, dt, A, B, C, D, pool, layer, n_valid):
         **params,
     )(jnp.asarray(layer, jnp.int32)[None], count, rows, jnp.exp(dt * A),
       pool, (dt[..., None] * xf).reshape(b, HP // ck, ck),
-      B.astype(f32), C.astype(f32))
+      B[:, 0].astype(f32), C[:, 0].astype(f32))
     y = jnp.where(active[:, None, None], y.reshape(b, H, P), 0.0) \
         + D[:, None] * xf
     return y[:, None], pool
@@ -209,27 +228,32 @@ def ssd_step(x, dt, A, B, C, D, pool, layer, n_valid):
 
 def _ssd_chunk(x, dt, A, B, C, state):
     """One chunk of Q tokens in the quadratic form.  x [b, Q, H, P]
-    float32, dt [b, Q, H] float32 (0 = identity token), B, C [b, Q, N],
-    state [b, H, P, N] float32.  -> (y [b, Q, H, P] float32 without the
-    D term, state after the chunk)."""
+    float32, dt [b, Q, H] float32 (0 = identity token), B, C [b, Q, G,
+    N], state [b, H, P, N] float32.  -> (y [b, Q, H, P] float32 without
+    the D term, state after the chunk)."""
     hi = lax.Precision.HIGHEST       # float32 products, not bf16 passes
+    b, q, H, P = x.shape
+    G = B.shape[2]
+    R = H // G                       # heads that read one group
     dA = dt * A                                                 # [b, Q, H]
     cs = jnp.cumsum(dA, axis=1)                                 # inclusive
     # decay from token s (exclusive) to token l (inclusive), s <= l
     seg = cs[:, :, None, :] - cs[:, None, :, :]                 # [b, l, s, H]
-    q = x.shape[1]
     tri = jnp.tril(jnp.ones((q, q), bool))
     L = jnp.where(tri[None, :, :, None], jnp.exp(seg), 0.0)     # [b, l, s, H]
-    G = jnp.einsum("bln,bsn->bls", C, B, precision=hi)          # [b, l, s]
+    CB = jnp.einsum("blgn,bsgn->blsg", C, B, precision=hi)      # [b, l, s, G]
     xdt = x * dt[..., None]                                     # [b, Q, H, P]
-    y = jnp.einsum("blsh,bshp->blhp", G[..., None] * L, xdt, precision=hi)
+    M = (CB[..., None] * L.reshape(b, q, q, G, R)).reshape(b, q, q, H)
+    y = jnp.einsum("blsh,bshp->blhp", M, xdt, precision=hi)
     # what the incoming state adds: y_l += exp(cs_l) * (state C_l)
-    sc = jnp.einsum("bhpn,bln->blhp", state, C, precision=hi)
+    sc = jnp.einsum("bgrpn,blgn->blgrp", state.reshape(b, G, R, P, -1), C,
+                    precision=hi).reshape(b, q, H, P)
     y = y + jnp.exp(cs)[..., None] * sc
     # the state after the chunk
     to_end = jnp.exp(cs[:, -1:, :] - cs)                        # [b, Q, H]
-    add = jnp.einsum("bshp,bsn->bhpn", xdt * to_end[..., None], B,
-                     precision=hi)
+    add = jnp.einsum("bsgrp,bsgn->bgrpn",
+                     (xdt * to_end[..., None]).reshape(b, q, G, R, P), B,
+                     precision=hi).reshape(state.shape)
     state = jnp.exp(cs[:, -1])[:, :, None, None] * state + add
     return y, state
 
@@ -284,11 +308,13 @@ def ssd_recurrence(x, dt, A, B, C, D, state):
     f32 = jnp.float32
 
     def step(state, xs):
-        xt, dtt, Bt, Ct = xs                      # [b,H,P] [b,H] [b,N] [b,N]
+        xt, dtt, Bt, Ct = xs                # [b,H,P] [b,H] [b,G,N] [b,G,N]
+        rep = xt.shape[1] // Bt.shape[1]    # head h reads group h // rep
+        Bt, Ct = jnp.repeat(Bt, rep, axis=1), jnp.repeat(Ct, rep, axis=1)
         state = (jnp.exp(dtt * A)[:, :, None, None] * state
-                 + jnp.einsum("bhp,bn->bhpn", dtt[..., None] * xt, Bt,
+                 + jnp.einsum("bhp,bhn->bhpn", dtt[..., None] * xt, Bt,
                               precision="highest"))
-        y = jnp.einsum("bhpn,bn->bhp", state, Ct, precision="highest") \
+        y = jnp.einsum("bhpn,bhn->bhp", state, Ct, precision="highest") \
             + D[:, None] * xt
         return state, y
 

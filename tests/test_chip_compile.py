@@ -493,3 +493,163 @@ def test_hybrid_chunk_prefill_fits_and_moves_no_pool(hybrid_cell):
         on_chip((T + cfg.ssm_chunk + 3,), jnp.int32)).compile()
     _assert_pool_stays_put(compiled, lay)
     _assert_state_stays_put(compiled, ssm)
+
+
+# ---- the second layout of the hybrid layer function: single-mixer layers,
+# ---- 8 B/C groups, relu^2 experts (chipbench's nemotron-3-nano cell)
+
+def _no_expert_stack_is_copied(text, params):
+    """No stacked expert tensor is copied, sliced or re-laid-out on its
+    way to the grouped matmul: a slice of a stack was a 432 MB copy a layer
+    (PR 29), and a ``w_in`` whose minor dim is no whole number of lane
+    tiles is re-laid-out whole on every pass (660 MB a layer at 64 x
+    2688 x 1856: ``ops/routed_experts.lanes``)."""
+    stacks = {"bf16[" + ",".join(map(str, lp["ffn"][name].shape)) + "]"
+              for lp in params["layers"] if "ffn" in lp
+              for name in ("w_in", "w_out")}
+    assert len(stacks) == 2
+    for shape in stacks:
+        assert shape in text
+        assert not re.findall(
+            re.escape(shape) + r"\S* (?:copy|copy-start|transpose|slice|"
+            r"dynamic-slice)\(", text), shape
+        layouts = set(re.findall(re.escape(shape) + r"\{([\d,]*)", text))
+        assert layouts == {"2,1,0"}, (shape, layouts)
+
+
+def test_hybrid_decode_hands_the_grouped_matmul_no_copy_of_a_stack(
+        hybrid_cell, hybrid_decode_compiled):
+    _no_expert_stack_is_copied(hybrid_decode_compiled.as_text(),
+                               hybrid_cell[2])
+
+
+@pytest.fixture(scope="module")
+def nano_cell(one_chip):
+    """The shapes of ``serve-nemotron3-nano-reason1k-r80``, from the
+    cell's own configuration file through its traffic kind's
+    ``model_config``: 13 layers ``MEMEM*EMEMEM*`` at published widths,
+    64 of 128 experts and half the vocabulary held, 64 rows, 12,288 + 1
+    blocks of 16, 192-block tables."""
+    import json
+    import os
+    from chipbench.traffic.open_loop_http_nemotron_h import model_config
+    from ray_tpu.models import hybrid
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "nemotron-3-nano-30b-a3b-13L-e64.json")) as f:
+        config = json.load(f)
+    cfg, _, held = model_config(config)
+    assert held == (0, 64) and cfg.n_experts == 128
+    assert (cfg.n_mamba, cfg.n_attention, len(cfg.sublayers)) == (6, 2, 13)
+    on_chip = _on(one_chip)
+    rows, bs = config["engine"]["max_slots"], 16
+    lay = PoolLayout(*cfg.kv_geometry[:1], config["engine"]["n_blocks"] + 1,
+                     bs, *cfg.kv_geometry[1:])
+    assert lay.shape == (24578, 16, 256)        # TWO K/V layers, 2 x 128
+    layers, conv, ssm = cfg.state_geometry
+    params = jax.tree.map(
+        lambda s: on_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda: hybrid.init_params(cfg,
+                                                  jax.random.PRNGKey(0))))
+    n_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                  for s in jax.tree.leaves(params))
+    # 3,926 M published bf16 parameters + the zero columns of five
+    # stacked w_in (64 x 2688 x 64 each: 1856 -> 1920 lanes)
+    assert n_bytes - 5 * 64 * 2688 * 64 * 2 == 7_852_040_704
+    return (cfg, on_chip, params, on_chip(lay.shape, cfg.dtype), lay,
+            on_chip((layers, rows, *conv), cfg.dtype),
+            on_chip((layers, rows, *ssm), jnp.float32), rows,
+            config["engine"])
+
+
+@pytest.fixture(scope="module")
+def nano_decode_compiled(nano_cell):
+    from ray_tpu.inference.recurrent import make_recurrent_decode_step
+    cfg, on_chip, params, pool, lay, conv, ssm, rows, _ = nano_cell
+    T = cfg.max_seq // lay.block_size
+    step = make_recurrent_decode_step(cfg, block_size=lay.block_size,
+                                      n_table=T)
+    return step.lower(params, pool, pool, conv, ssm,
+                      on_chip((rows, T + 3), jnp.int32)).compile()
+
+
+def test_nano_decode_step_fits_and_moves_no_pool(nano_cell,
+                                                 nano_decode_compiled):
+    _assert_pool_stays_put(nano_decode_compiled, nano_cell[4])
+    _assert_state_stays_put(nano_decode_compiled, nano_cell[6])
+    _no_expert_stack_is_copied(nano_decode_compiled.as_text(), nano_cell[2])
+
+
+def test_nano_decode_updates_the_grouped_state_in_one_kernel_a_layer(
+        nano_cell, nano_decode_compiled):
+    """Mosaic takes the one-token kernel with EIGHT B/C groups (a row's
+    ``[8, 128]`` B and C, a chunk of 128 state rows picking its group's
+    row by its own index), once a Mamba layer, on the state pool as
+    stored; the cell's label table counts it as the grouped SSM's, the
+    first configuration's table does not know the shape."""
+    from chipbench import nemotron_trace, scoped_trace
+    cfg, ssm = nano_cell[0], nano_cell[6]
+    text = nano_decode_compiled.as_text()
+    pool = "f32[" + ",".join(map(str, ssm.shape)) + "]"
+    assert pool == "f32[6,64,4096,128]"
+    on_pool = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line
+               and pool in line]
+    assert len(on_pool) == cfg.n_mamba == 6
+    for line in on_pool:
+        result, operands = line.split(" custom-call(", 1)
+        assert pool in result and pool in operands
+        assert line.strip().startswith("%ssd_step")
+        assert nemotron_trace.label_of(line.strip()) == "grouped_ssm"
+        assert scoped_trace.label_of(line.strip()) == "other"
+    fusions = [line for line in text.splitlines() if " fusion(" in line]
+    assert not [f for f in fusions if f.split(" = ")[1].startswith(pool)]
+
+
+def test_nano_decode_walks_the_tables_with_sixteen_queries_a_kv_head(
+        nano_cell, nano_decode_compiled):
+    """The paged-attention kernel at 256 stored lanes and 16 query heads
+    a K/V head (it had run at 25 x 64 and 8 x 128 lanes): once an
+    attention layer, no table gathered."""
+    from chipbench import nemotron_trace
+    text = nano_decode_compiled.as_text()
+    assert "bf16[12288,16,256]" not in text
+    calls = _kernel_calls(text)
+    assert len(calls) == nano_cell[0].n_attention == 2
+    marks = nemotron_trace.marks_of(
+        {"num_experts_per_tok": 6, "n_routed_experts": 128,
+         "mamba_num_heads": 64, "mamba_head_dim": 64, "n_groups": 8}, 64,
+        128)
+    assert all(nemotron_trace.label_of(c, marks) == "other" for c in calls)
+
+
+def test_nano_chunk_prefill_fits_and_moves_no_pool(nano_cell):
+    from ray_tpu.inference.recurrent import make_recurrent_chunk_fn
+    cfg, on_chip, params, pool, lay, conv, ssm, rows, engine = nano_cell
+    T = cfg.max_seq // lay.block_size
+    C = engine["prefill_chunk"]
+    assert C == cfg.ssm_chunk == 128
+    chunk = make_recurrent_chunk_fn(cfg, chunk=C, block_size=lay.block_size,
+                                    n_table=T)
+    compiled = chunk.lower(params, pool, pool, conv, ssm,
+                           on_chip((T + C + 3,), jnp.int32)).compile()
+    _assert_pool_stays_put(compiled, lay)
+    _assert_state_stays_put(compiled, ssm)
+    _no_expert_stack_is_copied(compiled.as_text(), params)
+
+
+def test_grouped_matmul_is_one_kernel_at_both_layouts(hybrid_decode_compiled,
+                                                      nano_decode_compiled):
+    """``routed_experts.grouped_matmul`` is the Pallas grouped matmul at
+    both published layouts, twice an experts sublayer: the first's 4096
+    x 1536 / 768 x 4096 experts (ten sublayers) and the second's 2688 x
+    1920 / 1856 x 2688 (five); the compiler's own kernel, which read 50
+    % and 12 % of the roofline there, is in neither program."""
+    def calls(compiled, name):
+        return [line for line in compiled.as_text().splitlines()
+                if " custom-call(" in line and "tpu_custom_call" in line
+                and line.strip().startswith(name)]
+    assert len(calls(hybrid_decode_compiled, "%gmm")) == 20
+    assert len(calls(nano_decode_compiled, "%gmm")) == 10
+    for compiled in (hybrid_decode_compiled, nano_decode_compiled):
+        assert not calls(compiled, "%ragged-dot")
