@@ -34,6 +34,13 @@ go to the scratch block), the embedding, the head.
     ops/attention.paged_attention is the formulation it is held to);
     inactive rows write to the scratch block, attend nothing and get
     zeros.
+
+    These two are what a serving pass runs, and they exchange small
+    integers with the host: ONE packed int32 array in (``pack_step`` /
+    ``pack_chunk``), and beside the logits, which stay on the device,
+    the greedy tokens out — the argmax of the float32 logits taken
+    inside the program (every row's; the chunk's last real
+    position's).  recurrent.py's two programs do the same.
   * spec_verify_step — the decode step widened to a [b, W] token
     window (W = speculate_k + 1): column 0 is each row's current input
     token, columns 1.. are DRAFTED continuations.  One call scores all
@@ -253,14 +260,58 @@ def _paged_layers(cfg, mesh, rules, layers, x, pools, attend_over):
     return x, pools
 
 
+# What the host sends a decode step or a chunk, for either model family
+# (recurrent.py's programs take the same arrays): ONE fresh int32 numpy
+# array a program, handed over as it is.  Four ``jnp.asarray`` calls are
+# four tiny ``convert_element_type`` programs, 1.3-1.9 ms of a pass on
+# the chip with the device idle.
+
+def pack_step(tables, tokens, positions, active) -> np.ndarray:
+    """The decode step's host inputs as one fresh int32 ``[b, T + 3]``:
+    a row's block table, then its token, position and whether it is
+    active."""
+    return np.concatenate(
+        [tables, tokens[:, None], positions[:, None], active[:, None]],
+        axis=1, dtype=np.int32)
+
+
+def unpack_step(packed, T: int):
+    """-> (tables [b, T], tokens [b], positions [b], active [b] bool) of
+    a ``pack_step`` array, inside a program."""
+    return (packed[:, :T], packed[:, T], packed[:, T + 1],
+            packed[:, T + 2] != 0)
+
+
+def pack_chunk(table, tokens, start: int, row: int,
+               n_valid: int) -> np.ndarray:
+    """The chunk program's host inputs as one fresh int32 ``[T + C +
+    3]``: the row's block table, the window's tokens, then the window's
+    first position, the decode row and the count of real tokens."""
+    return np.concatenate([table, tokens, (start, row, n_valid)],
+                          dtype=np.int32)
+
+
+def unpack_chunk(packed, T: int, C: int):
+    """-> (table [T], tokens [C], start, row, n_valid) of a
+    ``pack_chunk`` array, inside a program."""
+    return (packed[:T], packed[T:T + C], packed[T + C],
+            packed[T + C + 1], packed[T + C + 2])
+
+
 def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
                            n_table: int, mesh=None,
                            rules: Rules = DEFAULT_LLM_RULES):
     """jitted one-token step over the whole row batch, block-pool cache.
 
-    (params, k_pool, v_pool [cache.PoolLayout], tables [b, T] int32,
-     tokens [b] int32, positions [b] int32, active [b] bool)
-        -> (logits [b, vocab] f32, k_pool, v_pool)
+    (params, k_pool, v_pool [cache.PoolLayout], packed [b, T + 3] int32
+     (``pack_step``: tables | tokens | positions | active))
+        -> (logits [b, vocab] f32, greedy [b] int32, k_pool, v_pool)
+
+    ``greedy`` is the argmax of the float32 logits, taken inside the
+    program (ties to the lowest index, what ``gpt.sample_token`` at
+    temperature 0 gives; under a mesh over the vocabulary-sharded
+    logits): a greedy pass fetches ``b`` integers and the logits stay
+    on the device, where a sampled row indexes them.
 
     Every row's current token K/V goes to the pool at
     ``(tables[row, pos // bs], pos % bs)`` — inactive rows are
@@ -269,12 +320,12 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
     are per-row exclusive (the engine copy-on-writes shared tails
     before the step), so active rows never collide in the scatter.
     """
-    bs = int(block_size)
+    bs, T = int(block_size), int(n_table)
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
-        def step(params, k_pool, v_pool, tables, tokens, positions,
-                 active):
+        def step(params, k_pool, v_pool, packed):
+            tables, tokens, positions, active = unpack_step(packed, T)
             b = tokens.shape[0]
             lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
             x = (gpt._token_rows(params, tokens, cfg)
@@ -292,12 +343,12 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
             k_pool, v_pool = (gpt._constrain(p, POOL_AXES, mesh, rules)
                               for p in pools)
             logits = gpt._head(params, x, cfg, mesh, rules)[:, 0, :]
-            return logits, k_pool, v_pool
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return logits, greedy, k_pool, v_pool
 
         return step
 
-    return _cached(("paged_step", bs, int(n_table)), cfg, mesh, rules,
-                   build)
+    return _cached(("paged_step", bs, T), cfg, mesh, rules, build)
 
 
 def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
@@ -305,9 +356,14 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
                           rules: Rules = DEFAULT_LLM_RULES):
     """jitted fixed-width prefill chunk against the block pool.
 
-    (params, k_pool, v_pool [cache.PoolLayout], table [T] int32,
-     tokens [C] int32, start int32)
-        -> (logits [C, vocab] f32, k_pool, v_pool)
+    (params, k_pool, v_pool [cache.PoolLayout], packed [T + C + 3] int32
+     (``pack_chunk``: table | tokens | start, row, n_valid))
+        -> (logits [C, vocab] f32, greedy [1] int32, k_pool, v_pool)
+
+    ``greedy`` is the argmax of the last REAL position's logits
+    (``n_valid - 1``; a prompt's first token when the chunk ends it),
+    taken inside the program like the decode step's; ``row`` is the
+    recurrent family's (recurrent.py) and not read here.
 
     Processes prompt positions ``start .. start+C``: the window's K/V
     goes through the block table (rows past the table's span are
@@ -326,7 +382,8 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
-        def chunk_fn(params, k_pool, v_pool, table, tokens, start):
+        def chunk_fn(params, k_pool, v_pool, packed):
+            table, tokens, start, _, n_valid = unpack_chunk(packed, T, C)
             lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
             pos = start + jnp.arange(C, dtype=jnp.int32)       # [C]
             oob = pos >= S
@@ -349,7 +406,9 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
             k_pool, v_pool = (gpt._constrain(p, POOL_AXES, mesh, rules)
                               for p in pools)
             logits = gpt._head(params, x, cfg, mesh, rules)[0]  # [C, V]
-            return logits, k_pool, v_pool
+            greedy = jnp.argmax(logits[jnp.maximum(n_valid, 1) - 1]
+                                ).astype(jnp.int32)
+            return logits, greedy[None], k_pool, v_pool
 
         return chunk_fn
 

@@ -54,11 +54,20 @@ condition waits (no bare ``Event.wait()`` / ``time.sleep`` polling — the
 control-plane lint's blocking rules are the house style even off the
 node event loop).
 
-Sampling runs on the host via models.gpt.sample_token — the SAME
-function the full-recompute oracle uses, so greedy decode is
-token-identical by construction (asserted in tests).  Per-request
-temperature/rng stay per-request because sampling is outside the
-compiled step; logits [n_slots, vocab] is a small transfer.
+What a pass exchanges with the device is small integers, for either
+model family: ONE packed int32 array up a program (decode.pack_step /
+pack_chunk), the greedy tokens down — the argmax each program takes
+itself over the float32 logits models.gpt.sample_token would read (ties
+to the lowest index, so greedy decode stays token-identical to the
+full-recompute oracle; asserted in tests).  The logits [n_slots, vocab]
+stay on the device (6.4 MB a pass at GPT-2 XL's 32 rows, which went to
+the host and back for an argmax program of its own until ISSUE 39); a
+row with temperature > 0 keeps its per-request rng and samples from its
+row of them there, one dispatch of its own.  A prompt's greedy first
+token is its last chunk's own argmax and is not waited for before the
+pass's decode step goes out behind the chunk (_finish_prefill).  The
+full-width prefill and the speculation programs have no greedy output:
+they sample on the logits as before.
 """
 
 from __future__ import annotations
@@ -84,10 +93,10 @@ from ray_tpu.inference.decode import (SpeculationUnsupported,
                                       make_paged_draft_step,
                                       make_prefill_fn,
                                       make_spec_verify_step,
-                                      ngram_propose)
+                                      ngram_propose, pack_chunk,
+                                      pack_step)
 from ray_tpu.inference.recurrent import (make_recurrent_chunk_fn,
-                                         make_recurrent_decode_step,
-                                         pack_chunk, pack_step)
+                                         make_recurrent_decode_step)
 from ray_tpu.models import gpt, hybrid
 from ray_tpu.parallel.sharding import (DEFAULT_LLM_RULES, Rules,
                                        tree_shardings)
@@ -343,12 +352,43 @@ def _engine_loop(ref: "weakref.ref[InferenceEngine]") -> None:
         del eng
 
 
-class _KVOnly:
-    """The engine's model seam for a model whose whole past is K/V
-    blocks (models/gpt.py): what programs a pass runs, and what they
-    carry.  A namespace of functions of the engine, never instantiated
-    (a bound method kept on the engine would be a reference cycle, and
-    an abandoned engine must die by reference count alone)."""
+class _Seam:
+    """What the engine's pass is handed a model family through: the
+    programs a pass runs and what they carry.  A namespace of functions
+    of the engine, never instantiated (a bound method kept on the engine
+    would be a reference cycle, and an abandoned engine must die by
+    reference count alone).
+
+    Both families' decode step and chunk program take ONE packed int32
+    array and return the logits, which stay on the device, and a small
+    int32 vector: ``N_LOAD`` counts of the routed experts' load, then
+    the greedy tokens (every row's from the step, the last real
+    position's from a chunk).  The pass's host algorithm over them is
+    the engine's own, stated once (``_fetch_step``, ``_emit_first``,
+    ``_pass_done``); a seam states what differs: the tree served, the
+    programs, the pools ``run`` hands them, the load."""
+
+    N_LOAD = 0
+
+    @staticmethod
+    def count(eng, loads) -> None:
+        """``loads``: the int32 vectors a decode pass fetched."""
+
+    @staticmethod
+    def greedy(eng, logits):
+        """The decode step's greedy tokens, one a row, as fetched."""
+        return eng._greedy
+
+    @staticmethod
+    def row_admitted(eng, row) -> None:
+        pass
+
+    row_released = row_admitted
+
+
+class _KVOnly(_Seam):
+    """The seam for a model whose whole past is K/V blocks
+    (models/gpt.py, the programs of decode.py)."""
 
     @staticmethod
     def serve(params, cfg):
@@ -377,64 +417,22 @@ class _KVOnly:
             n_table=eng.pool.blocks_per_seq, mesh=mesh, rules=rules)
 
     @staticmethod
-    def step_args(eng) -> tuple:
-        """What the host sends the decode step this pass."""
-        return (jnp.asarray(eng._tables), jnp.asarray(eng._tokens),
-                jnp.asarray(eng._positions), jnp.asarray(eng._active))
-
-    @staticmethod
-    def step(eng, args):
-        logits, k, v = eng._step(eng.params, eng.pool.k, eng.pool.v, *args)
+    def run(eng, program, packed):
+        logits, greedy, k, v = program(eng.params, eng.pool.k, eng.pool.v,
+                                       packed)
         eng.pool.swap(k, v)
+        # no load to count: only the newest program's tokens are owed
+        eng._load = [greedy]
         return logits
 
-    @staticmethod
-    def chunk_args(eng, row, toks, at, n_q) -> tuple:
-        return (jnp.asarray(eng._tables[row]), jnp.asarray(toks),
-                jnp.int32(at))
 
-    @staticmethod
-    def chunk(eng, args):
-        logits, k, v = eng._chunk(eng.params, eng.pool.k, eng.pool.v,
-                                  *args)
-        eng.pool.swap(k, v)
-        return logits
-
-    @staticmethod
-    def first_token(eng, row, req, logits, idx) -> None:
-        """The prompt's last chunk is dispatched: sample the first
-        token, emit it, and let the row decode."""
-        tok = eng._first_token(req, logits[idx])
-        req._emit(tok)
-        eng._start_decoding(row, req, tok)
-
-    @staticmethod
-    def fetch(eng, logits):
-        """-> (logits as the sampling needs them, bytes fetched)."""
-        logits = np.asarray(logits)
-        return logits, logits.nbytes
-
-    @staticmethod
-    def greedy(eng, logits):
-        # greedy rows sample in ONE vectorized call; temperature rows
-        # keep their per-request rng
-        return np.asarray(gpt.sample_token(logits, temperature=0.0))
-
-    @staticmethod
-    def row_admitted(eng, row) -> None:
-        pass
-
-    row_released = row_admitted
-
-    @staticmethod
-    def pass_done(eng) -> None:
-        pass
-
-
-class _KVAndState:
+class _KVAndState(_Seam):
     """The seam for a model that also keeps a recurrent state per row
-    (models/hybrid.py): its programs carry the state pool beside the K/V
-    pools and report the routed experts' load."""
+    (models/hybrid.py, the programs of recurrent.py): its programs carry
+    the state pool beside the K/V pools and report the routed experts'
+    load."""
+
+    N_LOAD = hybrid.N_LOAD
 
     @staticmethod
     def refuse(ec: "EngineConfig", mesh) -> None:
@@ -472,100 +470,25 @@ class _KVAndState:
             n_table=eng.pool.blocks_per_seq)
 
     @staticmethod
-    def _ran(eng, out):
-        logits, load, k, v, conv, ssm = out
+    def run(eng, program, packed):
+        st = eng.pool.state
+        logits, load, k, v, conv, ssm = program(
+            eng.params, eng.pool.k, eng.pool.v, st.conv, st.ssm, packed)
         eng.pool.swap(k, v)
-        eng.pool.state.swap(conv, ssm)
-        # stays on the device until the next decode pass fetches it
+        st.swap(conv, ssm)
+        # every program's load stays on the device until the next
+        # decode pass fetches them all
         eng._load.append(load)
         return logits
 
-    # one fresh numpy array a program, converted where the program is
-    # called: no ``jnp.asarray`` of its own (inference/recurrent.py)
     @staticmethod
-    def step_args(eng) -> tuple:
-        return (pack_step(eng._tables, eng._tokens, eng._positions,
-                          eng._active),)
-
-    @staticmethod
-    def step(eng, args):
-        st = eng.pool.state
-        return _KVAndState._ran(eng, eng._step(
-            eng.params, eng.pool.k, eng.pool.v, st.conv, st.ssm, *args))
-
-    @staticmethod
-    def chunk_args(eng, row, toks, at, n_q) -> tuple:
-        return (pack_chunk(eng._tables[row], toks, at, row, n_q),)
-
-    @staticmethod
-    def chunk(eng, args):
-        st = eng.pool.state
-        return _KVAndState._ran(eng, eng._chunk(
-            eng.params, eng.pool.k, eng.pool.v, st.conv, st.ssm, *args))
-
-    @staticmethod
-    def first_token(eng, row, req, logits, idx) -> None:
-        """A greedy first token is the chunk program's own argmax, read
-        with its load vector: no slice and no sampling dispatched.  And
-        not waited for here while other rows decode: the pass's decode
-        step is dispatched behind the chunk first (the device runs the
-        two back to back), the token is emitted as soon as the chunk
-        has ended (``_emit_first``, from ``fetch``), and the row joins
-        the decode batch at the end of the pass (``pass_done``)."""
-        if req.temperature != 0.0:
-            tok = eng._first_token(req, logits[idx])
-            req._emit(tok)
-            eng._start_decoding(row, req, tok)
-            return
-        eng._first_pending.append([row, req, eng._load[-1], None])
-        if not eng._active.any():           # no decode to run behind
-            _KVAndState.pass_done(eng)
-
-    @staticmethod
-    def _emit_first(eng) -> None:
-        for pend in eng._first_pending:
-            row, req, load, tok = pend
-            # a row preempted since (the block hunt of this pass's
-            # decode) re-prefills and gets its first token then
-            if tok is None and eng._slot_req.get(row) is req:
-                with tracing.span("engine.fetch", bytes=16):
-                    pend[3] = tok = int(jax.device_get(load)[hybrid.N_LOAD])
-                req._emit(tok)
-
-    @staticmethod
-    def pass_done(eng) -> None:
-        """The pass's decode step has been sampled (or there was none):
-        rows whose prompt ended in this pass start decoding."""
-        if not eng._first_pending:
-            return
-        _KVAndState._emit_first(eng)
-        pending, eng._first_pending = eng._first_pending, []
-        for row, req, _, tok in pending:
-            if tok is not None and eng._slot_req.get(row) is req:
-                eng._start_decoding(row, req, tok)
-
-    @staticmethod
-    def fetch(eng, logits):
-        """The rows' greedy tokens and the expert load of this pass and
-        of the chunks before it, in ONE small transfer; the logits stay
-        on the device (a sampled row indexes them there).  First tokens
-        that this pass's chunks owe go out first: their programs ended
-        a decode step ago."""
-        _KVAndState._emit_first(eng)
-        loads = jax.device_get(eng._load)
-        eng._load = []
-        eng._greedy = loads[-1][hybrid.N_LOAD:]   # the decode step's own
+    def count(eng, loads) -> None:
         for load in loads:
             eng._expert_held += int(load[0])
             eng._expert_total += int(load[1])
             eng._expert_load_max += int(load[2])
             eng._expert_touched += int(load[3])
         eng._expert_touched_decode += int(loads[-1][3])
-        return logits, sum(load.nbytes for load in loads)
-
-    @staticmethod
-    def greedy(eng, logits):
-        return eng._greedy
 
     @staticmethod
     def row_admitted(eng, row) -> None:
@@ -657,9 +580,13 @@ class InferenceEngine:
                 k=ec.speculate_k, block_size=bs,
                 n_table=self.pool.blocks_per_seq, mesh=mesh,
                 rules=rules) if self._spec == "self" else None)
-        self._load = []            # recurrent programs' load vectors,
-        self._greedy = None        # the last step's greedy tokens and
-        self._first_pending = []   # [row, request, load, token] owed
+        # the programs' int32 vectors (load counts, then greedy tokens)
+        # still on the device, the last decode step's greedy tokens as
+        # fetched, and the first tokens this pass's chunks owe:
+        # [row, request, its chunk's vector, the token once read]
+        self._load = []
+        self._greedy = None
+        self._first_pending = []
         self._tables = np.zeros((n, self.pool.blocks_per_seq), np.int32)
         self._row_blocks: dict[int, list[int]] = {}
         self._free_rows = list(range(n - 1, -1, -1))
@@ -723,6 +650,14 @@ class InferenceEngine:
         self._expert_load_max = 0
         self._expert_touched = 0          # held experts with >= 1 token
         self._expert_touched_decode = 0   # ... in decode steps alone
+        # where a decode or first token was chosen (loop thread only):
+        # by a program's own argmax, or by a dispatch of its own (a
+        # sampled row, a full-width prefill's first token, a
+        # speculative pass's accept walk); and the bytes every
+        # ``engine.fetch`` brought to the host
+        self._tokens_on_device = 0
+        self._tokens_sampled = 0
+        self._fetch_bytes = 0
 
         with _registry_lock:
             self.name = name or f"engine-{next(_engine_seq)}"
@@ -847,7 +782,7 @@ class InferenceEngine:
                     self._prefill_chunk_pass()
                 if self._active.any():
                     self._paged_decode_iteration()
-                self._seam.pass_done(self)
+                self._pass_done()
             except Exception as e:            # step failure: fail the
                 self._fail_all(e)             # in-flight requests, keep serving
             return True
@@ -1132,8 +1067,8 @@ class InferenceEngine:
         and publishes the chain, the rest re-match and jump instead of
         each paying the whole train.  (Round-robin interleaves the
         duplicates so none publishes until nearly everyone has paid.)
-        On prompt completion the last real row's logits sample the
-        request's first token and the row turns active."""
+        On prompt completion the request gets its first token and the
+        row turns active (``_finish_prefill``)."""
         with tracing.span("engine.prefill_chunk") as sp:
             self._advance_prefill(sp)
 
@@ -1218,22 +1153,32 @@ class InferenceEngine:
         chunk_toks = np.zeros(C, np.int32)
         chunk_toks[:n_q] = prompt[pos:pos + n_q]
         with tracing.span("engine.upload") as up:
-            args = self._seam.chunk_args(self, row, chunk_toks, pos, n_q)
-            if up:
-                up.set(bytes=sum(a.nbytes for a in args))
+            packed = pack_chunk(self._tables[row], chunk_toks, pos, row, n_q)
+            up.set(bytes=packed.nbytes)
         with tracing.span("engine.dispatch"):
-            logits = self._seam.chunk(self, args)
+            logits = self._seam.run(self, self._chunk, packed)
         new_pos = pos + n_q
         if new_pos < n:
             self._prefilling[row] = new_pos
             return
-        self._finish_prefill(row, req, logits, n_q - 1)
+        self._finish_prefill(row, req, logits, n_q - 1, self._load[-1])
 
     def _finish_prefill(self, row: int, req: GenerationRequest,
-                        logits, idx) -> None:
-        """Prompt fully in cache: sample the first token from the last
-        prompt position's logits (``logits[idx]``); the row turns active
-        (or evicts immediately on EOS / max_new == 1)."""
+                        logits, idx, owed=None) -> None:
+        """Prompt fully in cache: its first token is the last prompt
+        position's (``logits[idx]``), and the row then turns active (or
+        evicts immediately on EOS / max_new == 1).
+
+        A greedy first token is the chunk program's own argmax, the
+        last entry of ``owed`` (the program's int32 vector, still on the
+        device): no slice and no sampling dispatched.  And not waited
+        for here while other rows decode: the pass's decode step is
+        dispatched behind the chunk first (the device runs the two back
+        to back), the token is emitted as soon as the chunk has ended
+        (``_emit_first``, from ``_fetch_step``), and the row joins the
+        decode batch at the end of the pass (``_pass_done``).  A sampled
+        request, and a full-width prefill (no ``owed``), take one
+        sampling dispatch on the logits and wait for it."""
         del self._prefilling[row]
         if self.trie is not None:
             # publish the prompt's full blocks NOW (not at finish):
@@ -1248,7 +1193,57 @@ class InferenceEngine:
                 self._note_prefix_published(
                     req.prompt[:full],
                     self._row_blocks[row][:full // self.pool.block_size])
-        self._seam.first_token(self, row, req, logits, idx)
+        if owed is None or req.temperature != 0.0:
+            tok = self._first_token(req, logits[idx])
+            req._emit(tok)
+            self._start_decoding(row, req, tok)
+            return
+        self._first_pending.append([row, req, owed, None])
+        if not self._active.any():          # no decode to run behind
+            self._pass_done()
+
+    def _emit_first(self) -> None:
+        for pend in self._first_pending:
+            row, req, owed, tok = pend
+            # a row preempted since (the block hunt of this pass's
+            # decode) re-prefills and gets its first token then
+            if tok is None and self._slot_req.get(row) is req:
+                with tracing.span("engine.fetch") as fetch:
+                    pend[3] = tok = int(
+                        jax.device_get(owed)[self._seam.N_LOAD])
+                    self._fetched(fetch, owed.nbytes)
+                req._emit(tok)
+                self._tokens_on_device += 1
+
+    def _pass_done(self) -> None:
+        """The pass's decode step has been sampled (or there was none):
+        rows whose prompt ended in this pass start decoding."""
+        if not self._first_pending:
+            return
+        self._emit_first()
+        pending, self._first_pending = self._first_pending, []
+        for row, req, _, tok in pending:
+            if tok is not None and self._slot_req.get(row) is req:
+                self._start_decoding(row, req, tok)
+
+    def _fetched(self, fetch, n_bytes: int) -> None:
+        """An ``engine.fetch`` span brought ``n_bytes`` to the host."""
+        fetch.set(bytes=n_bytes)
+        self._fetch_bytes += n_bytes
+
+    def _fetch_step(self) -> int:
+        """The rows' greedy tokens of the decode step just dispatched
+        — and, of a model that reports one, the expert load of this
+        pass and of the chunks before it — in ONE small transfer; the
+        logits stay on the device (a sampled row indexes them there).
+        First tokens that this pass's chunks owe go out first: their
+        programs ended a decode step ago.  -> bytes fetched."""
+        self._emit_first()
+        loads = jax.device_get(self._load)
+        self._load = []
+        self._greedy = loads[-1][self._seam.N_LOAD:]   # the step's own
+        self._seam.count(self, loads)
+        return sum(load.nbytes for load in loads)
 
     def _start_decoding(self, row: int, req: GenerationRequest,
                         tok: int) -> None:
@@ -1262,15 +1257,18 @@ class InferenceEngine:
         self._active[row] = True
 
     def _first_token(self, req: GenerationRequest, last_logits) -> int:
-        """A request's first token from its last prompt position's
-        logits, which are still on the device: the sampling is one more
+        """A first token no program chose itself (a sampled request's,
+        a full-width prefill's), from the last prompt position's logits,
+        which are still on the device: the sampling is one more
         dispatch (``engine.sample``), reading the token is the wait for
         the prefill program (``engine.fetch``)."""
         with tracing.span("engine.sample", rows=1):
             tok = gpt.sample_token(last_logits,
                                    temperature=req.temperature,
                                    rng=req._next_rng())
-        with tracing.span("engine.fetch", bytes=4):
+        self._tokens_sampled += 1
+        with tracing.span("engine.fetch") as fetch:
+            self._fetched(fetch, 4)
             return int(tok)
 
     def _grow_row(self, row: int) -> bool:
@@ -1408,7 +1406,7 @@ class InferenceEngine:
             self.pool.swap(kp, vp)
         with tracing.span("engine.fetch") as fetch:
             toks = np.asarray(toks)
-            fetch.set(bytes=toks.nbytes)
+            self._fetched(fetch, toks.nbytes)
         m = np.arange(toks.shape[1])[None, :] < w[:, None]
         drafts[:, :toks.shape[1]][m] = toks[m]
 
@@ -1462,7 +1460,7 @@ class InferenceEngine:
             self.pool.swap(k, v)
         with tracing.span("engine.fetch") as fetch:
             logits = np.asarray(logits)           # [n, W, V]
-            fetch.set(bytes=logits.nbytes)
+            self._fetched(fetch, logits.nbytes)
         with self._mlock:
             self._decode_iterations += 1
             self._spec_passes += 1
@@ -1472,6 +1470,7 @@ class InferenceEngine:
             stepped, emitted = self._spec_accept(logits, drafts, want,
                                                  force_reject)
             sample.set(rows=stepped)
+        self._tokens_sampled += emitted
         with self._mlock:
             self._row_steps += stepped
             self._row_tokens += emitted
@@ -1568,11 +1567,11 @@ class InferenceEngine:
                 sp.set(active=int(self._active.sum()),
                        state_rows=self.pool.state_rows_in_use)
             with tracing.span("engine.upload") as up:
-                args = self._seam.step_args(self)
-                if up:
-                    up.set(bytes=sum(a.nbytes for a in args))
+                packed = pack_step(self._tables, self._tokens,
+                                   self._positions, self._active)
+                up.set(bytes=packed.nbytes)
             with tracing.span("engine.dispatch"):
-                logits = self._seam.step(self, args)
+                logits = self._seam.run(self, self._step, packed)
             if self._mesh is not None:
                 # every shard just committed its slice of the donated
                 # scatter — the point where a multi-host straggler or
@@ -1580,8 +1579,7 @@ class InferenceEngine:
                 self._chaos("infer_shard_commit",
                             tp_shards=self.pool.heads_shards)
             with tracing.span("engine.fetch") as fetch:
-                logits, n_bytes = self._seam.fetch(self, logits)
-                fetch.set(bytes=n_bytes)
+                self._fetched(fetch, self._fetch_step())
             with self._mlock:
                 self._decode_iterations += 1
                 self._occupancy_sum += (float(self._active.sum())
@@ -1599,10 +1597,13 @@ class InferenceEngine:
                     req = self._slot_req[row]
                     if req.temperature == 0.0:
                         tok = int(greedy[row])
+                        self._tokens_on_device += 1
                     else:
+                        # its own rng, on its logits where they lie
                         tok = int(gpt.sample_token(
                             logits[row], temperature=req.temperature,
                             rng=req._next_rng()))
+                        self._tokens_sampled += 1
                     req._emit(tok)
                     stepped += 1
                     self._positions[row] += 1
@@ -1651,6 +1652,8 @@ class InferenceEngine:
                   for row in list(self._slot_req)]
         self._active[:] = False
         self._prefilling.clear()
+        self._first_pending.clear()
+        self._load.clear()
         self._row_blocks.clear()
         self._tables[:, :] = 0
         if self.trie is not None:
@@ -1903,6 +1906,14 @@ class InferenceEngine:
             "prefill_tokens": prefill_tokens,
             "kv_blocks_attended": kv_attended,
             "kv_blocks_tabled": kv_tabled,
+            # decode and first tokens by where they were chosen: by a
+            # program's own argmax (the integers a pass fetches), or by
+            # a dispatch of their own on the logits (a sampled row, a
+            # full-width prefill's first token, a speculative pass's
+            # accept walk); and what every ``engine.fetch`` brought
+            "tokens_greedy_on_device": self._tokens_on_device,
+            "tokens_sampled": self._tokens_sampled,
+            "fetch_bytes": self._fetch_bytes,
             # tokens emitted per (row, compiled call) pair: exactly 1.0
             # for plain decode by construction, 1 + accepted-per-pass
             # under speculation — batch width cancels out
@@ -1991,6 +2002,7 @@ def metrics_snapshot() -> list:
     butil, phit, pcached, preempt = {}, {}, {}, {}
     admits, chunks, ptoks = {}, {}, {}
     kv_att, kv_tab = {}, {}
+    on_dev, sampled, fbytes = {}, {}, {}
     tps, arate, saccept = {}, {}, {}
     meshdev, tpsh = {}, {}
     sbytes, srows, eheld, etotal, emax = {}, {}, {}, {}, {}
@@ -2021,6 +2033,9 @@ def metrics_snapshot() -> list:
         ptoks[key] = float(st["prefill_tokens"])
         kv_att[key] = float(st["kv_blocks_attended"])
         kv_tab[key] = float(st["kv_blocks_tabled"])
+        on_dev[key] = float(st["tokens_greedy_on_device"])
+        sampled[key] = float(st["tokens_sampled"])
+        fbytes[key] = float(st["fetch_bytes"])
         # speculation signal, per replica: accept-rate is the drafter's
         # quality gauge, tokens/step the latency win it buys
         tps[key] = float(st.get("tokens_per_step", 0.0))
@@ -2076,6 +2091,17 @@ def metrics_snapshot() -> list:
         ("ray_tpu_inference_kv_blocks_tabled_total", "counter",
          "Block-table entries of all rows, summed over one-token decode "
          "passes (what a whole-table gather reads)", kv_tab or zero),
+        ("ray_tpu_inference_tokens_greedy_on_device_total", "counter",
+         "Decode and first tokens chosen by a serving program's own "
+         "argmax (a pass fetches the integers, not the logits)",
+         on_dev or zero),
+        ("ray_tpu_inference_tokens_sampled_total", "counter",
+         "Decode and first tokens chosen by a dispatch of their own on "
+         "the logits (temperature > 0, a full-width prefill's first "
+         "token, a speculative pass)", sampled or zero),
+        ("ray_tpu_inference_fetch_bytes_total", "counter",
+         "Bytes the engine's loop fetched from the device",
+         fbytes or zero),
         ("ray_tpu_inference_tokens_per_step", "gauge",
          "Tokens emitted per compiled decode/verify call (speculative "
          "decoding pushes this above 1)", tps or zero),

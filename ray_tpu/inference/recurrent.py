@@ -26,14 +26,20 @@ vocab]`` logits never leave the device (12.8 MB a pass at 64 rows x
 50,176, to the host and back for the argmax: 8 of the 13.5 ms of host
 time a pass that the first chip runs of PR 29 read).
 
-What the host sends a pass is ONE int32 array (``pack_step`` /
+What the host sends a pass is ONE int32 array (``decode.pack_step`` /
 ``pack_chunk``), handed to the program as the fresh numpy array it is:
 four ``jnp.asarray`` calls were 1.9 of a decode pass's 4.5 ms of host
 time on the chip, and a chunk's five 1.2 more, all of it with the device
 idle.
 
-The jitted functions are named ``step`` and ``chunk_fn`` like decode.py's
-(a device trace's program names are ``jit_step`` / ``jit_chunk_fn`` for
+decode.py's GPT programs exchange the same with the host — the packed
+array in, the greedy tokens out, the logits left on the device — so the
+engine's pass is ONE host algorithm for both families
+(``InferenceEngine._fetch_step`` / ``_emit_first`` / ``_pass_done``).
+What these two add is the state pools they carry and the ``load``
+counts in front of the greedy tokens (``hybrid.N_LOAD`` of them).  The
+jitted functions are named ``step`` and ``chunk_fn`` like decode.py's (a
+device trace's program names are ``jit_step`` / ``jit_chunk_fn`` for
 either model family).  No mesh: one device holds one chip's share of the
 deployment (experts over an ``ep`` axis are future work).
 """
@@ -44,30 +50,12 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ray_tpu.inference.cache import PoolLayout
-from ray_tpu.inference.decode import _cached, paged_attend
+from ray_tpu.inference.decode import (_cached, paged_attend, unpack_chunk,
+                                      unpack_step)
 from ray_tpu.models import hybrid
 from ray_tpu.models.hybrid import HybridConfig
-
-
-def pack_step(tables, tokens, positions, active) -> np.ndarray:
-    """The decode step's host inputs as one fresh int32 ``[b, T + 3]``:
-    a row's block table, then its token, position and whether it is
-    active."""
-    return np.concatenate(
-        [tables, tokens[:, None], positions[:, None], active[:, None]],
-        axis=1, dtype=np.int32)
-
-
-def pack_chunk(table, tokens, start: int, row: int,
-               n_valid: int) -> np.ndarray:
-    """The chunk program's host inputs as one fresh int32 ``[T + C +
-    3]``: the row's block table, the window's tokens, then the window's
-    first position, the decode row and the count of real tokens."""
-    return np.concatenate([table, tokens, (start, row, n_valid)],
-                          dtype=np.int32)
 
 
 def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
@@ -88,9 +76,7 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
     def build():
         @partial(jax.jit, donate_argnums=(1, 2, 3, 4))
         def step(params, k_pool, v_pool, conv, ssm, packed):
-            tables, tokens, positions, active = (
-                packed[:, :T], packed[:, T], packed[:, T + 1],
-                packed[:, T + 2] != 0)
+            tables, tokens, positions, active = unpack_step(packed, T)
             b = tokens.shape[0]
             lay = PoolLayout.of(cfg, k_pool)
             rows = jnp.arange(b)
@@ -152,9 +138,7 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
     def build():
         @partial(jax.jit, donate_argnums=(1, 2, 3, 4))
         def chunk_fn(params, k_pool, v_pool, conv, ssm, packed):
-            table, tokens = packed[:T], packed[T:T + C]
-            start, row, n_valid = packed[T + C], packed[T + C + 1], \
-                packed[T + C + 2]
+            table, tokens, start, row, n_valid = unpack_chunk(packed, T, C)
             lay = PoolLayout.of(cfg, k_pool)
             pos = start + jnp.arange(C, dtype=jnp.int32)
             oob = pos >= S

@@ -227,7 +227,8 @@ def xl(one_chip):
 @pytest.fixture(scope="module")
 def xl_compiled(xl):
     """``program -> `` the cell's decode step or chunk prefill, compiled
-    once with the served tree's shapes."""
+    once with the served tree's shapes (``out_info``: its results'
+    shapes instead)."""
     cfg, on_chip, pool, lay = xl
     rows, T = 32, cfg.max_seq // lay.block_size
     params = _params_of(cfg, on_chip)
@@ -235,22 +236,19 @@ def xl_compiled(xl):
     assert params["wte"].dtype == jnp.float32       # added, THEN rounded
     done = {}
 
-    def compiled(program):
+    def compiled(program, out_info=False):
         if program not in done:
             if program == "decode":
                 lowered = make_paged_decode_step(
                     cfg, block_size=lay.block_size, n_table=T).lower(
-                    params, pool, pool, on_chip((rows, T), jnp.int32),
-                    on_chip((rows,), jnp.int32), on_chip((rows,), jnp.int32),
-                    on_chip((rows,), jnp.bool_))
+                    params, pool, pool, on_chip((rows, T + 3), jnp.int32))
             else:
                 lowered = make_chunk_prefill_fn(
                     cfg, chunk=32, block_size=lay.block_size,
                     n_table=T).lower(
-                    params, pool, pool, on_chip((T,), jnp.int32),
-                    on_chip((32,), jnp.int32), on_chip((), jnp.int32))
-            done[program] = lowered.compile()
-        return done[program]
+                    params, pool, pool, on_chip((T + 32 + 3,), jnp.int32))
+            done[program] = (lowered.compile(), lowered.out_info)
+        return done[program][int(out_info)]
     return compiled
 
 
@@ -313,6 +311,29 @@ def test_xl_program_casts_and_retiles_no_weights(xl, xl_compiled, program):
     assert xl_compiled(program).memory_analysis().temp_size_in_bytes < 5e8
 
 
+@pytest.mark.parametrize("program, n", [("decode", 32), ("chunk", 1)])
+def test_xl_program_returns_its_own_greedy_tokens(xl, xl_compiled, program,
+                                                  n):
+    """What ISSUE 39 bought: beside the float32 logits, which stay on
+    the device, each program hands back the argmax it took itself, as
+    int32 (every row's from the step, the last real position's from the
+    chunk): a greedy pass fetches 128 bytes where it fetched 6.4 MB and
+    uploaded them again for an argmax program of its own.  The same
+    compiled programs still move no pool and cast no weight (above)."""
+    cfg = xl[0]
+    logits, greedy, k, v = xl_compiled(program, out_info=True)
+    assert (logits.dtype, logits.shape) == (jnp.float32,
+                                            (32, cfg.vocab_size))
+    assert (greedy.dtype, greedy.shape) == (jnp.int32, (n,))
+    assert k.shape == v.shape == xl[3].shape
+    entry = re.search(r"entry_computation_layout=.*",
+                      xl_compiled(program).as_text()).group(0)
+    results = entry.split("->", 1)[1]
+    assert f"s32[{n}]" in results and f"f32[32,{cfg.vocab_size}]" in results
+    # one packed int32 array in: no other integer argument
+    assert entry.split("->", 1)[0].count("s32[") == 1
+
+
 def test_xl_write_blocks_moves_no_pool(xl):
     """The full-width prefill's table scatter: its scratch was as large
     as both pools, which is what held ``n_blocks`` at 768."""
@@ -348,9 +369,8 @@ def test_paged_decode_step_keeps_its_pool_shard_under_dp_tp(mesh_2x2):
     step = make_paged_decode_step(cfg, block_size=bs, n_table=n_table,
                                   mesh=mesh_2x2)
     compiled = step.lower(
-        _params_of(cfg, on_mesh), pool, pool, on_mesh((rows, n_table), jnp.int32),
-        on_mesh((rows,), jnp.int32), on_mesh((rows,), jnp.int32),
-        on_mesh((rows,), jnp.bool_)).compile()
+        _params_of(cfg, on_mesh), pool, pool,
+        on_mesh((rows, n_table + 3), jnp.int32)).compile()
     shard = PoolLayout(lay.n_layers, lay.n_rows, bs, cfg.n_heads // 2,
                        cfg.head_dim)
     assert shard.shape == (*lay.shape[:2], 384)
@@ -378,9 +398,7 @@ def test_paged_decode_step_compiles_at_124m(one_chip):
     step = make_paged_decode_step(cfg, block_size=bs, n_table=n_table)
     compiled = step.lower(
         _params_of(cfg, on_chip), pool, pool,
-        on_chip((rows, n_table), jnp.int32),
-        on_chip((rows,), jnp.int32), on_chip((rows,), jnp.int32),
-        on_chip((rows,), jnp.bool_)).compile()
+        on_chip((rows, n_table + 3), jnp.int32)).compile()
     _assert_pool_stays_put(compiled, lay)
     assert len(_kernel_calls(compiled.as_text())) == 1
 

@@ -21,7 +21,8 @@ from ray_tpu.inference.cache import BlockPool, PoolLayout
 from ray_tpu.inference.decode import (make_chunk_prefill_fn,
                                       make_paged_decode_step,
                                       make_paged_draft_step,
-                                      make_spec_verify_step, paged_attend)
+                                      make_spec_verify_step, pack_chunk,
+                                      pack_step, paged_attend)
 from ray_tpu.models import gpt
 from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES
 
@@ -126,15 +127,20 @@ def _programs_and_forward():
     chunk = make_chunk_prefill_fn(CFG, chunk=CHUNK, **kw)
     rows = []
     for r in range(ROWS):
-        logits, k, v = chunk(params, k, v, tables[r], toks[r, :CHUNK],
-                             jnp.int32(0))
+        logits, first, k, v = chunk(params, k, v, pack_chunk(
+            tables[r], toks[r, :CHUNK], 0, r, CHUNK))
+        # the program's own greedy token: its last real position's
+        assert first.dtype == jnp.int32 \
+            and first.tolist() == [int(logits[CHUNK - 1].argmax())]
         rows.append(logits)
     got["chunk"] = jnp.stack(rows)
     at = jnp.full((ROWS,), CHUNK, jnp.int32)
     live = jnp.ones((ROWS,), bool)
     want_all = jnp.full((ROWS,), WIDTH, jnp.int32)
-    got["decode"], k, v = make_paged_decode_step(CFG, **kw)(
-        params, k, v, tables, toks[:, CHUNK], at, live)
+    got["decode"], greedy, k, v = make_paged_decode_step(CFG, **kw)(
+        params, k, v, pack_step(tables, toks[:, CHUNK], at, live))
+    assert greedy.dtype == jnp.int32 and greedy.tolist() \
+        == got["decode"].argmax(-1).tolist()
     got["verify"], k, v = make_spec_verify_step(CFG, width=WIDTH, **kw)(
         params, k, v, tables, toks[:, CHUNK:CHUNK + WIDTH], at, live,
         want_all)

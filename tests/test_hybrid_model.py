@@ -30,6 +30,7 @@ from ray_tpu.inference import (EngineConfig, InferenceEngine,
                                SpeculationUnsupported, metrics_snapshot)
 from ray_tpu.inference import recurrent
 from ray_tpu.inference.cache import BlockPool, PoolLayout, StatePool
+from ray_tpu.inference.decode import pack_chunk, pack_step
 from ray_tpu.models import hybrid
 from ray_tpu.ops import routed_experts as rx
 from ray_tpu.ops import ssm
@@ -527,7 +528,7 @@ def test_programs_chunks_then_decode_equal_reference_logits(fam):
         toks[:n_q] = seq[pos:pos + n_q]
         logits, load, k, v, conv, s_ = chunk(
             params, k, v, conv, s_,
-            recurrent.pack_chunk(table, toks, pos, row, n_q))
+            pack_chunk(table, toks, pos, row, n_q))
         np.testing.assert_allclose(np.asarray(logits)[:n_q],
                                    want[pos:pos + n_q], atol=fam.atol)
         assert load.tolist()[:2] == [n_q * per_token] * 2
@@ -547,7 +548,7 @@ def test_programs_chunks_then_decode_equal_reference_logits(fam):
         tokens[row], positions[row] = seq[pos], pos
         logits, load, k, v, conv, s_ = step(
             params, k, v, conv, s_,
-            recurrent.pack_step(tables, tokens, positions, active))
+            pack_step(tables, tokens, positions, active))
         np.testing.assert_allclose(np.asarray(logits)[row], want[pos],
                                    atol=fam.atol)
         # 1 token x top-3 x expert layers, each pick another expert

@@ -23,7 +23,8 @@ from ray_tpu.inference.decode import (make_chunk_prefill_fn,
                                       make_paged_decode_step,
                                       make_paged_draft_step,
                                       make_prefill_fn,
-                                      make_spec_verify_step)
+                                      make_spec_verify_step, pack_chunk,
+                                      pack_step)
 from ray_tpu.inference.engine import metrics_snapshot
 from ray_tpu.models import gpt, hybrid
 from ray_tpu.parallel.mesh import create_mesh
@@ -65,14 +66,19 @@ def _run_programs(cfg, params):
     chunk = make_chunk_prefill_fn(cfg, chunk=CHUNK, **kw)
     rows = []
     for r in range(ROWS):
-        logits, k, v = chunk(params, k, v, tables[r], toks[r, :CHUNK],
-                             jnp.int32(0))
+        logits, first, k, v = chunk(params, k, v, pack_chunk(
+            tables[r], toks[r, :CHUNK], 0, r, CHUNK))
+        # the program's own greedy token: its last real position's
+        assert first.dtype == jnp.int32 \
+            and first.tolist() == [int(logits[CHUNK - 1].argmax())]
         rows.append(logits)
     out["chunk"] = jnp.stack(rows)
     at = jnp.full((ROWS,), CHUNK, jnp.int32)
     live = jnp.ones((ROWS,), bool)
-    out["decode"], k, v = make_paged_decode_step(cfg, **kw)(
-        params, k, v, tables, toks[:, CHUNK], at, live)
+    out["decode"], greedy, k, v = make_paged_decode_step(cfg, **kw)(
+        params, k, v, pack_step(tables, toks[:, CHUNK], at, live))
+    assert greedy.dtype == jnp.int32 and greedy.tolist() \
+        == out["decode"].argmax(-1).tolist()
     out["verify"], k, v = make_spec_verify_step(cfg, width=WIDTH, **kw)(
         params, k, v, tables, toks[:, CHUNK:CHUNK + WIDTH], at, live,
         jnp.full((ROWS,), WIDTH, jnp.int32))

@@ -129,17 +129,14 @@ def test_dense_no_mesh_builds_are_annotation_free(cfg, params):
 
     pool = zeros_pool(1)
     jaxpr = str(jax.make_jaxpr(step)(
-        params, pool, pool, jnp.zeros((2, 8), jnp.int32),
-        jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
-        jnp.zeros(2, bool)))
+        params, pool, pool, jnp.zeros((2, 8 + 3), jnp.int32)))
     for prim in ("sharding_constraint", "psum", "all_gather",
                  "all_to_all"):
         assert prim not in jaxpr, \
             f"mesh=None decode step grew a {prim} equation"
     chunk = make_chunk_prefill_fn(cfg, chunk=16, block_size=8, n_table=8)
     jaxpr_c = str(jax.make_jaxpr(chunk)(
-        params, pool, pool, jnp.zeros(8, jnp.int32),
-        jnp.zeros(16, jnp.int32), jnp.int32(0)))
+        params, pool, pool, jnp.zeros(8 + 16 + 3, jnp.int32)))
     assert "sharding_constraint" not in jaxpr_c
     # positive control: the SAME builder with a mesh is annotated (the
     # assertion above is meaningful, not vacuously matching a renamed
@@ -149,9 +146,7 @@ def test_dense_no_mesh_builds_are_annotation_free(cfg, params):
                                      mesh=mesh)
     sh_pool = zeros_pool(2)
     jaxpr_sh = str(jax.make_jaxpr(step_sh)(
-        params, sh_pool, sh_pool, jnp.zeros((2, 8), jnp.int32),
-        jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
-        jnp.zeros(2, bool)))
+        params, sh_pool, sh_pool, jnp.zeros((2, 8 + 3), jnp.int32)))
     assert "sharding_constraint" in jaxpr_sh
 
 
@@ -259,6 +254,59 @@ def test_sharded_parity_speculative_self(cfg, params, mesh2):
         assert eng.stats()["spec_drafted_tokens"] > 0
     finally:
         eng.shutdown()
+
+
+# ------------------------------------- the programs' own greedy tokens
+
+def test_in_program_argmax_over_sharded_logits_dp_tp(cfg, params):
+    """dp2 x tp2: the decode step's and the chunk's greedy tokens are
+    taken inside the program, over logits whose vocabulary is split
+    across the tp shards, and equal the host's argmax of the gathered
+    logits, ties included.  Every vocabulary row has a twin in the
+    OTHER shard here (the tied head's upper half is a copy of its
+    lower), so every maximum is an exact tie across shards, and the
+    lowest index has to win it as ``np.argmax`` and ``gpt.sample_token``
+    have it."""
+    if jax.device_count() < 4:
+        pytest.skip(f"need 4 CPU devices, have {jax.device_count()}")
+    mesh = create_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    half = cfg.vocab_size // 2
+    wte = params["wte"]
+    twins = {**params, "wte": wte.at[half:].set(wte[:half])}
+    eng = InferenceEngine(twins, cfg, EngineConfig(
+        max_slots=4, kv_block_size=8, prefill_chunk=8), mesh=mesh)
+    seen = {"step": 0, "chunk": 0}
+
+    def checked(kind, program):
+        def run(params_, k, v, packed):
+            logits, greedy, k, v = program(params_, k, v, packed)
+            assert "tp" in str(logits.sharding.spec[-1])
+            host = np.asarray(logits)
+            np.testing.assert_array_equal(host[:, :half], host[:, half:])
+            if kind == "chunk":
+                host = host[packed[-1] - 1][None]     # n_valid's row
+            assert greedy.dtype == jnp.int32
+            assert np.asarray(greedy).tolist() \
+                == host.argmax(-1).tolist()
+            assert (np.asarray(greedy) < half).all()
+            seen[kind] += 1
+            return logits, greedy, k, v
+        return run
+    eng._step = checked("step", eng._step)
+    eng._chunk = checked("chunk", eng._chunk)
+    try:
+        rng = np.random.default_rng(2)
+        jobs = [(p := rng.integers(0, cfg.vocab_size, n).tolist(),
+                 eng.submit(p, max_new=6)) for n in (5, 13, 19)]
+        for p, handle in jobs:
+            assert handle.result(timeout=300) \
+                == _ref_tokens(twins, cfg, p, 6)
+        st = eng.stats()
+        assert st["tokens_sampled"] == 0
+        assert st["tokens_greedy_on_device"] == 18
+    finally:
+        eng.shutdown()
+    assert seen["step"] >= 5 and seen["chunk"] == 1 + 2 + 3
 
 
 # ----------------------------------------------------------- MoE decode

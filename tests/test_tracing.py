@@ -341,7 +341,50 @@ def test_engine_pass_spans_nest_and_do_not_overlap(engine, tiny, traced):
     assert decode["attributes"]["active"] >= 1
     fetch = next(s for s in _children(spans, decode)
                  if s["name"] == "engine.fetch")
-    assert fetch["attributes"]["bytes"] == 4 * 4 * tiny[0].vocab_size
+    # the step's own greedy tokens, one int32 a row of 4: the float32
+    # logits (4 x 4 x vocab bytes until ISSUE 39) stay on the device
+    assert fetch["attributes"]["bytes"] == 4 * 4
+
+
+def test_a_greedy_pass_dispatches_no_sampling_program(engine, tiny, traced,
+                                                      monkeypatch):
+    """Greedy tokens are the programs' own argmax: chunked prompts and
+    their decode passes call ``gpt.sample_token`` (``jit__argmax``, a
+    program of its own between two ``jit_step``s) not once, and an
+    ``engine.sample`` span is the host's loop over the rows alone, with
+    no dispatch under it: ``engine.dispatch`` spans are the chunk and
+    step programs, one each."""
+    from ray_tpu.inference import engine as engine_mod
+    from ray_tpu.models import gpt
+    calls = []
+    sound = gpt.sample_token
+    monkeypatch.setattr(
+        engine_mod.gpt, "sample_token",
+        lambda *a, **kw: calls.append(kw) or sound(*a, **kw))
+    rng = np.random.default_rng(9)
+    before = engine.stats()
+    # short enough for the chunk path (at most half the cache's 32) and
+    # for the 6 blocks of 8 to hold all three: nobody is preempted
+    reqs = [engine.submit(rng.integers(0, tiny[0].vocab_size, n).tolist(),
+                          max_new=5) for n in (3, 5, 7)]
+    for r in reqs:
+        r.result(timeout=300)
+    after = engine.stats()
+    tracing.disable_tracing()
+    assert calls == []
+    assert after["tokens_sampled"] == before["tokens_sampled"]
+    assert after["tokens_greedy_on_device"] \
+        - before["tokens_greedy_on_device"] == 15
+    spans = tracing.get_finished_spans()
+    programs = after["decode_iterations"] - before["decode_iterations"] \
+        + after["chunk_passes"] - before["chunk_passes"]
+    assert len([s for s in spans if s["name"] == "engine.dispatch"]) \
+        == programs
+    fetched = sum(s["attributes"]["bytes"] for s in spans
+                  if s["name"] == "engine.fetch")
+    assert fetched == after["fetch_bytes"] - before["fetch_bytes"] \
+        == 16 * (after["decode_iterations"] - before["decode_iterations"]) \
+        + 4 * 3
 
 
 def test_front_request_is_the_root_of_a_request_trace(tiny):
