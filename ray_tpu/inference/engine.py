@@ -331,6 +331,25 @@ _engine_seq = itertools.count()
 _registry_lock = threading.Lock()
 
 
+# The loop thread's time account (``tracing.Account``): a phase is a
+# span site of the pass, here beside the span it yields while tracing is
+# on.  The containers ``engine.pass`` / ``.decode`` / ``.prefill_chunk``
+# own no phase: what of them no site covers reads as ``unaccounted``.
+_LOOP_PHASES = {
+    "parked": None,                 # ``_cond.wait``: no work to do
+    "admit": "engine.schedule",     # under ``_cond``: ops, reaping, admission
+    "grow": "engine.schedule",      # ``_grow_row``: the block hunt
+    "prefill_host": None,           # a chunk's host part: re-match, publish
+    "pack": "engine.upload",
+    "dispatch": "engine.dispatch",  # a program launched where it ends
+    "wait": "engine.fetch",         # ... every launch landed where it ends
+    "emit": "engine.sample",        # the row loop, ``req._emit``, eviction
+}
+# the loop's time between two ``engine.account`` spans, checked where a
+# pass ends
+ACCOUNT_EVERY_NS = 1_000_000_000
+
+
 def _engine_loop(ref: "weakref.ref[InferenceEngine]") -> None:
     """Loop-thread driver.  A strong reference exists only DURING a
     pass; between passes the engine is collectable, and a collected
@@ -658,6 +677,12 @@ class InferenceEngine:
         self._tokens_on_device = 0
         self._tokens_sampled = 0
         self._fetch_bytes = 0
+        # the loop thread's time by phase, always on; ``engine.account``
+        # spans carry it to the ring (``_write_account``)
+        self._passes = 0               # passes that found work
+        self._acct = tracing.Account(_LOOP_PHASES, launch=("dispatch",),
+                                     land=("wait",))
+        self._account_t1_ns = self._acct.t_made_ns
 
         with _registry_lock:
             self.name = name or f"engine-{next(_engine_seq)}"
@@ -747,6 +772,7 @@ class InferenceEngine:
         # engine.pass opens once the park check finds work, under that
         # check's lock, and closes with the pass, the lock long released
         sp = tracing.NOOP
+        worked = False
         try:
             with self._cond:
                 # park unless there is work a pass can make progress
@@ -764,10 +790,13 @@ class InferenceEngine:
                     # ONE bounded wait, then back out to _engine_loop:
                     # an idle engine must drop the loop thread's strong
                     # reference every tick, or it is never collectable
-                    self._cond.wait(self.engine_cfg.idle_wait_s)
+                    with self._acct.phase("parked"):
+                        self._cond.wait(self.engine_cfg.idle_wait_s)
                     return not self._stopped
                 if self._stopped:
                     return False
+                worked = True
+                self._passes += 1
                 sp = tracing.span("engine.pass").__enter__()
                 if sp:
                     sp.set(active=int(self._active.sum()),
@@ -788,11 +817,32 @@ class InferenceEngine:
             return True
         finally:
             sp.__exit__(*sys.exc_info())
+            if worked:
+                self._write_account()
+
+    def _write_account(self) -> None:
+        """Where a pass ends, once the loop has spent ``ACCOUNT_EVERY_NS``
+        since the last one: the account so far as ONE always-on span
+        that starts where the last one ended.  The counters are
+        cumulative, so a reader differences two spans; ``profiling``
+        says whether a ``jax.profiler`` session touched the interval."""
+        acct = self._acct
+        t1_ns = acct.t_ns
+        if t1_ns - self._account_t1_ns < ACCOUNT_EVERY_NS:
+            return
+        tracing.record_span(
+            "engine.account", self._account_t1_ns, t1_ns,
+            engine=self.name, passes=self._passes,
+            decode_iterations=self._decode_iterations,
+            chunk_passes=self._chunk_passes,
+            profiling=acct.interval_profiled(),
+            ring_dropped=tracing.ring_dropped(), **acct.snapshot())
+        self._account_t1_ns = t1_ns
 
     def _schedule_locked(self) -> None:
         """The pass's scheduling under ``_cond``: cross-thread ops,
         reaping, admission."""
-        with tracing.span("engine.schedule") as sp:
+        with self._acct.phase("admit") as sp:
             # only this thread writes the two counters
             admitted0, preempted0 = self._admissions, self._preemptions
             if self._ops:
@@ -1069,7 +1119,8 @@ class InferenceEngine:
         duplicates so none publishes until nearly everyone has paid.)
         On prompt completion the request gets its first token and the
         row turns active (``_finish_prefill``)."""
-        with tracing.span("engine.prefill_chunk") as sp:
+        with tracing.span("engine.prefill_chunk") as sp, \
+                self._acct.phase("prefill_host"):
             self._advance_prefill(sp)
 
     def _advance_prefill(self, sp) -> None:
@@ -1129,7 +1180,7 @@ class InferenceEngine:
             self._prefill_tokens += n
             padded = np.zeros((1, self.max_seq), np.int32)
             padded[0, :n] = prompt
-            with tracing.span("engine.dispatch"):
+            with self._acct.phase("dispatch"):
                 logits, k_new, v_new = self._prefill(self.params, padded)
                 self.pool.write_prefill(self._tables[row], k_new[:, 0],
                                         v_new[:, 0])
@@ -1152,10 +1203,10 @@ class InferenceEngine:
         self._prefill_tokens += n_q
         chunk_toks = np.zeros(C, np.int32)
         chunk_toks[:n_q] = prompt[pos:pos + n_q]
-        with tracing.span("engine.upload") as up:
+        with self._acct.phase("pack") as up:
             packed = pack_chunk(self._tables[row], chunk_toks, pos, row, n_q)
             up.set(bytes=packed.nbytes)
-        with tracing.span("engine.dispatch"):
+        with self._acct.phase("dispatch"):
             logits = self._seam.run(self, self._chunk, packed)
         new_pos = pos + n_q
         if new_pos < n:
@@ -1202,18 +1253,29 @@ class InferenceEngine:
         if not self._active.any():          # no decode to run behind
             self._pass_done()
 
-    def _emit_first(self) -> None:
+    def _emit_first(self, in_step_fetch: bool = False) -> tuple:
+        """Read and emit the first tokens this pass's chunks owe.
+        Inside the decode step's fetch the reads are part of that wait
+        -> (tokens read, their bytes); with no step behind the chunk
+        (``_pass_done``) each is a wait of its own."""
+        n = n_bytes = 0
         for pend in self._first_pending:
             row, req, owed, tok = pend
             # a row preempted since (the block hunt of this pass's
             # decode) re-prefills and gets its first token then
-            if tok is None and self._slot_req.get(row) is req:
-                with tracing.span("engine.fetch") as fetch:
-                    pend[3] = tok = int(
-                        jax.device_get(owed)[self._seam.N_LOAD])
+            if tok is not None or self._slot_req.get(row) is not req:
+                continue
+            if in_step_fetch:
+                tok = int(jax.device_get(owed)[self._seam.N_LOAD])
+                n, n_bytes = n + 1, n_bytes + owed.nbytes
+            else:
+                with self._acct.phase("wait") as fetch:
+                    tok = int(jax.device_get(owed)[self._seam.N_LOAD])
                     self._fetched(fetch, owed.nbytes)
-                req._emit(tok)
-                self._tokens_on_device += 1
+            pend[3] = tok
+            req._emit(tok)
+            self._tokens_on_device += 1
+        return n, n_bytes
 
     def _pass_done(self) -> None:
         """The pass's decode step has been sampled (or there was none):
@@ -1226,24 +1288,27 @@ class InferenceEngine:
             if tok is not None and self._slot_req.get(row) is req:
                 self._start_decoding(row, req, tok)
 
-    def _fetched(self, fetch, n_bytes: int) -> None:
+    def _fetched(self, fetch, n_bytes: int, **attributes) -> None:
         """An ``engine.fetch`` span brought ``n_bytes`` to the host."""
-        fetch.set(bytes=n_bytes)
+        fetch.set(bytes=n_bytes, **attributes)
         self._fetch_bytes += n_bytes
 
-    def _fetch_step(self) -> int:
+    def _fetch_step(self, fetch) -> None:
         """The rows' greedy tokens of the decode step just dispatched
         — and, of a model that reports one, the expert load of this
         pass and of the chunks before it — in ONE small transfer; the
         logits stay on the device (a sampled row indexes them there).
         First tokens that this pass's chunks owe go out first: their
-        programs ended a decode step ago.  -> bytes fetched."""
-        self._emit_first()
+        programs ended a decode step ago, and their reads belong to
+        this wait (``fetch``, its span: ``first_tokens``, and their
+        bytes among its ``bytes``)."""
+        n_first, n_bytes = self._emit_first(in_step_fetch=True)
         loads = jax.device_get(self._load)
         self._load = []
         self._greedy = loads[-1][self._seam.N_LOAD:]   # the step's own
         self._seam.count(self, loads)
-        return sum(load.nbytes for load in loads)
+        self._fetched(fetch, n_bytes + sum(load.nbytes for load in loads),
+                      first_tokens=n_first)
 
     def _start_decoding(self, row: int, req: GenerationRequest,
                         tok: int) -> None:
@@ -1262,12 +1327,13 @@ class InferenceEngine:
         which are still on the device: the sampling is one more
         dispatch (``engine.sample``), reading the token is the wait for
         the prefill program (``engine.fetch``)."""
-        with tracing.span("engine.sample", rows=1):
+        with self._acct.phase("emit") as sample:
+            sample.set(rows=1)
             tok = gpt.sample_token(last_logits,
                                    temperature=req.temperature,
                                    rng=req._next_rng())
         self._tokens_sampled += 1
-        with tracing.span("engine.fetch") as fetch:
+        with self._acct.phase("wait") as fetch:
             self._fetched(fetch, 4)
             return int(tok)
 
@@ -1398,13 +1464,13 @@ class InferenceEngine:
         identical to what the full model writes there, and the verify
         pass rewrites all drafted positions at all layers anyway."""
         w = np.where(self._active, want, 0).astype(np.int32)
-        with tracing.span("engine.dispatch"):
+        with self._acct.phase("dispatch"):
             toks, kp, vp = self._draft(
                 self.params, self.pool.k, self.pool.v,
                 jnp.asarray(self._tables), jnp.asarray(self._tokens),
                 jnp.asarray(self._positions), jnp.asarray(w))
             self.pool.swap(kp, vp)
-        with tracing.span("engine.fetch") as fetch:
+        with self._acct.phase("wait") as fetch:
             toks = np.asarray(toks)
             self._fetched(fetch, toks.nbytes)
         m = np.arange(toks.shape[1])[None, :] < w[:, None]
@@ -1448,25 +1514,25 @@ class InferenceEngine:
         tok_mat[:, 0] = self._tokens
         tok_mat[:, 1:] = drafts
         n_tok = np.where(self._active, want + 1, 1).astype(np.int32)
-        with tracing.span("engine.upload") as up:
+        with self._acct.phase("pack") as up:
             args = (jnp.asarray(self._tables), jnp.asarray(tok_mat),
                     jnp.asarray(self._positions), jnp.asarray(self._active),
                     jnp.asarray(n_tok))
             if up:
                 up.set(bytes=sum(a.nbytes for a in args))
-        with tracing.span("engine.dispatch"):
+        with self._acct.phase("dispatch"):
             logits, k, v = self._verify(self.params, self.pool.k,
                                         self.pool.v, *args)
             self.pool.swap(k, v)
-        with tracing.span("engine.fetch") as fetch:
+        with self._acct.phase("wait") as fetch:
             logits = np.asarray(logits)           # [n, W, V]
             self._fetched(fetch, logits.nbytes)
-        with self._mlock:
-            self._decode_iterations += 1
-            self._spec_passes += 1
-            self._occupancy_sum += (float(self._active.sum())
-                                    / self.engine_cfg.max_slots)
-        with tracing.span("engine.sample") as sample:
+        with self._acct.phase("emit") as sample:
+            with self._mlock:
+                self._decode_iterations += 1
+                self._spec_passes += 1
+                self._occupancy_sum += (float(self._active.sum())
+                                        / self.engine_cfg.max_slots)
             stepped, emitted = self._spec_accept(logits, drafts, want,
                                                  force_reject)
             sample.set(rows=stepped)
@@ -1531,7 +1597,7 @@ class InferenceEngine:
         return stepped, emitted
 
     def _paged_decode_iteration(self) -> None:
-        with tracing.span("engine.schedule") as sp:
+        with self._acct.phase("grow") as sp:
             preempted0 = self._preemptions
             for row in [r for r in list(self._slot_req) if self._active[r]]:
                 req = self._slot_req.get(row)
@@ -1566,11 +1632,11 @@ class InferenceEngine:
             if sp:
                 sp.set(active=int(self._active.sum()),
                        state_rows=self.pool.state_rows_in_use)
-            with tracing.span("engine.upload") as up:
+            with self._acct.phase("pack") as up:
                 packed = pack_step(self._tables, self._tokens,
                                    self._positions, self._active)
                 up.set(bytes=packed.nbytes)
-            with tracing.span("engine.dispatch"):
+            with self._acct.phase("dispatch"):
                 logits = self._seam.run(self, self._step, packed)
             if self._mesh is not None:
                 # every shard just committed its slice of the donated
@@ -1578,17 +1644,17 @@ class InferenceEngine:
                 # mid-commit death would bite, so it is chaos-testable
                 self._chaos("infer_shard_commit",
                             tp_shards=self.pool.heads_shards)
-            with tracing.span("engine.fetch") as fetch:
-                self._fetched(fetch, self._fetch_step())
-            with self._mlock:
-                self._decode_iterations += 1
-                self._occupancy_sum += (float(self._active.sum())
-                                        / self.engine_cfg.max_slots)
-            self._kv_blocks_attended += int(
-                (self._positions[self._active]
-                 // self.engine_cfg.kv_block_size + 1).sum())
-            self._kv_blocks_tabled += self._tables.size
-            with tracing.span("engine.sample") as sample:
+            with self._acct.phase("wait") as fetch:
+                self._fetch_step(fetch)
+            with self._acct.phase("emit") as sample:
+                with self._mlock:
+                    self._decode_iterations += 1
+                    self._occupancy_sum += (float(self._active.sum())
+                                            / self.engine_cfg.max_slots)
+                self._kv_blocks_attended += int(
+                    (self._positions[self._active]
+                     // self.engine_cfg.kv_block_size + 1).sum())
+                self._kv_blocks_tabled += self._tables.size
                 greedy = self._seam.greedy(self, logits)
                 stepped = 0
                 for row in list(self._slot_req):
@@ -1611,6 +1677,12 @@ class InferenceEngine:
                     if self._request_finished(req, tok):
                         self._paged_evict(row)
                 sample.set(rows=stepped)
+                # released inside the phase: a device buffer's release
+                # is where the loop first lets go of the interpreter
+                # lock after the row loop, and every stream the loop has
+                # just woken then takes its turn (with the release 0.2 -
+                # 0.75 ms a pass on the chip: ``PERF.md`` section 5)
+                del logits
         with self._mlock:
             self._row_steps += stepped
             self._row_tokens += stepped
@@ -1654,6 +1726,7 @@ class InferenceEngine:
         self._prefilling.clear()
         self._first_pending.clear()
         self._load.clear()
+        self._acct.in_flight = 0        # what was launched has failed
         self._row_blocks.clear()
         self._tables[:, :] = 0
         if self.trie is not None:
@@ -1914,6 +1987,14 @@ class InferenceEngine:
             "tokens_greedy_on_device": self._tokens_on_device,
             "tokens_sampled": self._tokens_sampled,
             "fetch_bytes": self._fetch_bytes,
+            # the loop thread's wall time by phase (``_LOOP_PHASES``):
+            # self ``ns``, the part of it with no program in flight
+            # ``starved_ns``, entries ``count``; what no phase covers;
+            # from ``t_made_ns`` to ``t_ns``, the loop's newest stamp
+            "loop_account": {**self._acct.snapshot(),
+                             "passes": self._passes,
+                             "t_made_ns": self._acct.t_made_ns,
+                             "t_ns": self._acct.t_ns},
             # tokens emitted per (row, compiled call) pair: exactly 1.0
             # for plain decode by construction, 1 + accepted-per-pass
             # under speculation — batch width cancels out
@@ -2008,6 +2089,7 @@ def metrics_snapshot() -> list:
     sbytes, srows, eheld, etotal, emax = {}, {}, {}, {}, {}
     etouch, etouchd = {}, {}
     wbytes, wcast = {}, {}
+    loop_s, starved_s = {}, {}
     for name, eng in sorted(engines.items()):
         st = eng.stats()
         # per-replica/per-model labels (serve fleet sets them) keep a
@@ -2056,6 +2138,13 @@ def metrics_snapshot() -> list:
         etouchd[key] = float(st.get("expert_touched_held_decode", 0))
         wbytes[key] = float(st["weight_bytes"])
         wcast[key] = float(st["weight_bytes_cast_per_pass"])
+        # the loop thread's time by phase: over the phases the first
+        # adds up to the thread's wall time
+        acct = st["loop_account"]
+        for series, k in ((loop_s, "ns"), (starved_s, "starved_ns")):
+            for phase, ns in (*acct[k].items(), (
+                    tracing.UNACCOUNTED, acct["unaccounted_" + k])):
+                series[key + (("phase", phase),)] = ns / 1e9
     zero = {(("engine", "none"),): 0.0}
     return [
         ("ray_tpu_inference_active_slots", "gauge",
@@ -2144,4 +2233,11 @@ def metrics_snapshot() -> list:
         ("ray_tpu_inference_weight_bytes_cast_per_pass", "gauge",
          "Bytes of weights a program casts to its compute dtype every "
          "pass (0 = each is stored in it)", wcast or zero),
+        ("ray_tpu_inference_loop_seconds_total", "counter",
+         "The engine loop thread's wall time by phase (self time; "
+         "`wait` is the wait for the device, `parked` an engine with no "
+         "work, `unaccounted` what no span site covers)", loop_s or zero),
+        ("ray_tpu_inference_loop_starved_seconds_total", "counter",
+         "The part of each phase's time during which the loop had no "
+         "program in flight on the device", starved_s or zero),
     ]

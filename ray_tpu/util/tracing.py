@@ -34,17 +34,30 @@ The off/on contract:
     lifecycle spans, a handful of ring appends per request.  They are
     not entered as profiler annotations (they cross ``await``s and
     threads).
+  * **The account** (``Account``): one thread's wall time by phase,
+    counted at that thread's span sites whatever the flag says.  ``with
+    account.phase(name) as sp`` takes the two stamps always and adds
+    the phase's self time to plain integer counters; only while
+    tracing is on does it also yield the site's span, built from the
+    same two stamps, else ``NOOP``.  An account is written by its ONE
+    thread without a lock; other threads (``engine_stats()``) read its
+    counters as whole Python ints, each one sound, a snapshot of
+    several at most one phase boundary apart.
 
 Stamps are ``time.monotonic_ns()``, the clock of the benchmark's own
-stamps.  Finished spans go to a bounded in-memory ring;
+stamps; in a profiler session an annotation also carries its span's
+``t0_ns`` as metadata, which places that clock on the session's own
+(an event's ``start_ns`` counts from the session's start), and with it
+the spans that are no annotations.  Finished spans go to a bounded
+in-memory ring, which counts what it evicts (``ring_dropped()``);
 ``get_finished_spans()`` exports them as dicts whose ``t0_ns`` /
 ``t1_ns`` are the stamps and whose wall-clock ``start`` / ``end`` are
 derived from one per-process anchor (wall minus monotonic, taken
-once).  With ``RAY_TPU_TRACE_DIR`` unset no span touches a file or a
-lock.  With it set, every finished span is also written to one JSONL
+once).  With ``RAY_TPU_TRACE_DIR`` unset no span touches a file.  With
+it set, every finished span is also written to one JSONL
 file per process, batched: ``_emit`` appends to a pending list under
-the buffer lock and the actual ``write+flush`` runs under a separate
-I/O lock, draining everything pending in one write.  Threads that find
+the buffer lock (the one the ring's append and count are under) and
+the actual ``write+flush`` runs under a separate I/O lock, draining everything pending in one write.  Threads that find
 the I/O lock busy leave their span pending for the current writer —
 the hot path never blocks on disk, and no lock is held across a file
 write but the I/O lock itself.  ``flush_spans()`` (also run at exit
@@ -74,7 +87,8 @@ _current: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
 # (the last ~2,000 requests), a traced pass ~20 (~400 passes)
 RING_SIZE = 8_192
 _ring: "collections.deque[Span]" = collections.deque(maxlen=RING_SIZE)
-_lock = threading.Lock()          # pending list
+_lock = threading.Lock()          # ring append + count, pending list
+_ring_dropped = 0                 # spans the full ring has evicted
 _io_lock = threading.Lock()       # file open/write/flush
 _pending: List["Span"] = []       # spans awaiting a file write
 _file = None
@@ -222,14 +236,23 @@ class Span:
         return True
 
     def __enter__(self):
-        self._token = _current.set(self.context())
-        if self._ann is not None:
-            self._ann.__enter__()
-        self.t0_ns = time.monotonic_ns()
-        return self
+        return self.open(time.monotonic_ns())
 
     def __exit__(self, exc_type, exc, tb):
-        self.t1_ns = time.monotonic_ns()
+        return self.close(time.monotonic_ns(), exc_type, exc, tb)
+
+    def open(self, t0_ns: int) -> "Span":
+        """Enter with a stamp the caller took (``Account.phase``)."""
+        self._token = _current.set(self.context())
+        self.t0_ns = t0_ns
+        if self._ann is not None:
+            self._ann.__enter__()
+            # the clock map: this stamp beside the event's ``start_ns``
+            self._ann.set_metadata(t0_ns=t0_ns)
+        return self
+
+    def close(self, t1_ns: int, exc_type=None, exc=None, tb=None) -> bool:
+        self.t1_ns = t1_ns
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         _current.reset(self._token)
@@ -276,6 +299,123 @@ def record_span(name: str, t0_ns: int, t1_ns: int, *,
     _emit(s)
     return s
 
+UNACCOUNTED = "unaccounted"
+
+
+class Account:
+    """One thread's wall time by phase, counted at its span sites.
+
+    ``phases`` maps a phase to the span its site yields while tracing
+    is on (None: the site has no span of its own).  ``with
+    account.phase(name) as sp`` enters one; a phase entered inside
+    another suspends the outer one, so a phase's ``ns`` is its SELF
+    time, and what no phase covers is ``unaccounted``'s: over all of
+    them ``ns`` adds up to ``t_ns - t_made_ns`` exactly, ``t_ns`` being
+    the newest stamp taken.
+
+    Starved time: ``in_flight`` counts the programs the thread has
+    launched and not yet seen the end of: one more where a phase named
+    in ``launch`` ends, none where one named in ``land`` ends.  Time
+    that passes while it is 0 is ALSO added to the phase's
+    ``starved_ns``: the device had nothing queued then.
+
+    ``profiled`` is set where a phase starts inside a ``jax.profiler``
+    session (``interval_profiled`` reads and resets it): such time is
+    the program's under an instrument, not its own."""
+
+    __slots__ = ("phases", "phase", "unaccounted", "in_flight", "profiled",
+                 "t_made_ns", "t_ns", "_open")
+
+    def __init__(self, phases: dict, launch=(), land=()):
+        # one reusable entry a phase: it owns the phase's counters, and
+        # what an entry in progress holds is on the stack
+        self.phases = {
+            name: _Phase(self, name, span_name,
+                         (name in launch) - (name in land))
+            for name, span_name in phases.items()}
+        self.phase = self.phases.__getitem__
+        self.unaccounted = _Phase(self, UNACCOUNTED, None, 0)
+        self.in_flight = 0
+        self.profiled = False
+        # the open phases, innermost last, each followed by the span it
+        # yielded
+        self._open = [self.unaccounted, NOOP]
+        self.t_made_ns = self.t_ns = time.monotonic_ns()
+
+    def interval_profiled(self) -> bool:
+        """Did a phase start inside a profiler session since the last
+        call, or is one active now; starts the next interval."""
+        now = _profiling()
+        was, self.profiled = self.profiled or now, now
+        return was
+
+    def snapshot(self) -> dict:
+        """The counters, copied (from another thread: see the module
+        docstring); they cover ``t_made_ns`` to ``t_ns``."""
+        rest = self.unaccounted
+        return {"ns": {n: p.ns for n, p in self.phases.items()},
+                "starved_ns": {n: p.starved_ns
+                               for n, p in self.phases.items()},
+                "count": {n: p.count for n, p in self.phases.items()},
+                "unaccounted_ns": rest.ns,
+                "unaccounted_starved_ns": rest.starved_ns}
+
+
+class _Phase:
+    """``Account.phase(name)``: entering it stamps the clock, charges
+    the time since the newest stamp to the phase that was innermost,
+    and yields the site's span (``NOOP`` while tracing is off) built
+    from that stamp; leaving it does the same with the phase itself."""
+    __slots__ = ("account", "name", "span_name", "launches", "ns",
+                 "starved_ns", "count")
+
+    def __init__(self, account: Account, name: str,
+                 span_name: Optional[str], launches: int):
+        self.account, self.name = account, name
+        self.span_name, self.launches = span_name, launches
+        self.ns = self.starved_ns = self.count = 0
+
+    def __enter__(self):
+        acct = self.account
+        now = time.monotonic_ns()
+        stack = acct._open
+        outer = stack[-2]
+        dt = now - acct.t_ns
+        acct.t_ns = now
+        outer.ns += dt
+        if not acct.in_flight:
+            outer.starved_ns += dt
+        stack.append(self)
+        self.count += 1
+        sp = NOOP
+        profiling = _profiling()
+        if profiling:
+            acct.profiled = True
+        if self.span_name is not None and (profiling or tracing_enabled()):
+            sp = Span(self.span_name, "internal", None, {},
+                      annotate=profiling).open(now)
+        stack.append(sp)
+        return sp
+
+    def __exit__(self, exc_type, exc, tb):
+        acct = self.account
+        now = time.monotonic_ns()
+        stack = acct._open
+        sp = stack.pop()
+        stack.pop()
+        dt = now - acct.t_ns
+        acct.t_ns = now
+        self.ns += dt
+        if not acct.in_flight:
+            self.starved_ns += dt
+        if self.launches > 0:
+            acct.in_flight += 1
+        elif self.launches:
+            acct.in_flight = 0
+        if sp:
+            sp.close(now, exc_type, exc, tb)
+        return False
+
 
 def inject_context() -> Optional[dict]:
     """The current span's context, for carrying across a thread or in a
@@ -300,11 +440,16 @@ def call_in_context(ctx: Optional[dict], fn, *args, **kwargs):
 
 
 def _emit(span_: Span) -> None:
-    _ring.append(span_)
-    if not os.environ.get("RAY_TPU_TRACE_DIR"):
-        return
+    global _ring_dropped
+    to_file = bool(os.environ.get("RAY_TPU_TRACE_DIR"))
     with _lock:
-        _pending.append(span_)
+        if len(_ring) == RING_SIZE:
+            _ring_dropped += 1
+        _ring.append(span_)
+        if to_file:
+            _pending.append(span_)
+    if not to_file:
+        return
     # opportunistic drain: whoever gets the I/O lock writes the whole
     # batch; a contended emitter's span is picked up by a retry here —
     # the in-flight writer popped its batch BEFORE this append landed,
@@ -361,9 +506,16 @@ def get_finished_spans(name: Optional[str] = None) -> List[dict]:
             if name is None or s.name == name]
 
 
+def ring_dropped() -> int:
+    """Spans the full ring has evicted since the process started: a
+    reader that differences it over its window knows whether the ring
+    still holds all of it."""
+    return _ring_dropped
+
+
 def clear() -> None:
-    _ring.clear()
     with _lock:
+        _ring.clear()
         _pending.clear()
 
 

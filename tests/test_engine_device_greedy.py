@@ -130,7 +130,7 @@ def test_sampled_row_alone_gives_the_same_stream(params, cfg):
 def test_first_token_behind_a_running_decode(params, cfg, sampling_calls):
     """A prompt that ends while other rows decode: its first token is
     the chunk's own argmax, not waited for before the pass's decode step
-    is dispatched (it is read inside that step's fetch), the row joins
+    is dispatched (it is read in that step's fetch), the row joins
     the batch a pass later, and a request that its first token ends
     never decodes.  Streams are the oracle's, token for token."""
     eng = _engine(params, cfg)
@@ -161,21 +161,24 @@ def test_first_token_behind_a_running_decode(params, cfg, sampling_calls):
         == 40 + sum(m for _, m in plan)
     spans = {s["span_id"]: s for s in tracing.get_finished_spans()}
     tracing.clear()
-    # a first token read from INSIDE a decode step's fetch: the step was
-    # dispatched behind the chunk before anyone waited for the chunk
-    behind = [s for s in spans.values() if s["name"] == "engine.fetch"
-              and spans.get(s["parent_id"], {}).get("name") == "engine.fetch"]
-    assert behind and all(s["attributes"]["bytes"] == 4 for s in behind)
-    # ... and no chunk waited for its own token while a row decoded
+    # every decode step fetched its rows' integers, nothing else ...
+    steps = [s for s in spans.values() if s["name"] == "engine.fetch"
+             and spans.get(s["parent_id"], {}).get("name") == "engine.decode"]
+    assert steps and all(
+        s["attributes"]["bytes"] == 4 * 4 + 4 * s["attributes"]["first_tokens"]
+        for s in steps)
+    # ... but for a first token read INSIDE a step's fetch: the step was
+    # dispatched behind the chunk before anyone waited for the chunk.
+    # The read is that span's own (``first_tokens``), no span inside it
+    assert sum(s["attributes"]["first_tokens"] for s in steps) >= 1
+    assert not [s for s in spans.values() if s["name"] == "engine.fetch"
+                and spans.get(s["parent_id"], {}).get("name") == "engine.fetch"]
+    # and no chunk waited for its own token while a row decoded
     for s in spans.values():
         if s["name"] == "engine.fetch" and spans.get(
                 s["parent_id"], {}).get("name") == "engine.prefill_chunk":
             chunk = spans[s["parent_id"]]
             assert spans[chunk["parent_id"]]["attributes"]["active"] == 0
-    # every decode step fetched its rows' integers, nothing else
-    steps = [s for s in spans.values() if s["name"] == "engine.fetch"
-             and spans.get(s["parent_id"], {}).get("name") == "engine.decode"]
-    assert steps and all(s["attributes"]["bytes"] == 4 * 4 for s in steps)
 
 
 def test_row_preempted_between_its_chunk_and_the_step_re_prefills(

@@ -195,6 +195,158 @@ def test_timeline_renders_span_names_and_attributes(traced):
     assert abs(by_name["engine.pass"]["ts"] / 1e6 - time.time()) < 60
 
 
+# -- the account: one thread's time by phase, fed by its span sites ----------
+
+PHASES = {"admit": "engine.schedule", "dispatch": "engine.dispatch",
+          "wait": "engine.fetch", "host": None}
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    """``time.monotonic_ns`` as ``tracing`` sees it, set by hand: every
+    stamp a phase takes is the value the test put there."""
+    class Clock:
+        now = 1_000
+
+        def monotonic_ns(self):
+            return self.now
+
+        def __getattr__(self, name):
+            return getattr(time, name)
+    c = Clock()
+    monkeypatch.setattr(tracing, "time", c)
+    return c
+
+
+def _account():
+    return tracing.Account(PHASES, launch=("dispatch",), land=("wait",))
+
+
+def test_account_partitions_the_threads_time_exactly(clock):
+    """Sum of the phases' self times + unaccounted = the time since the
+    account was made, to the nanosecond; a phase entered inside another
+    suspends the outer one."""
+    tracing.disable_tracing()
+    acct = _account()
+    clock.now += 7                      # before any site: unaccounted
+    with acct.phase("admit"):
+        clock.now += 100
+        with acct.phase("dispatch"):    # suspends admit
+            clock.now += 30
+            with acct.phase("wait"):
+                clock.now += 5
+            clock.now += 2
+        clock.now += 10
+    clock.now += 11
+    with acct.phase("wait"):
+        clock.now += 1_000
+    snap = acct.snapshot()
+    assert snap["ns"] == {"admit": 110, "dispatch": 32, "wait": 1_005,
+                          "host": 0}
+    assert snap["unaccounted_ns"] == 18
+    assert snap["count"] == {"admit": 1, "dispatch": 1, "wait": 2, "host": 0}
+    assert sum(snap["ns"].values()) + snap["unaccounted_ns"] \
+        == acct.t_ns - acct.t_made_ns == clock.now - 1_000
+    # a phase that raises is charged and closed like any other
+    with pytest.raises(ValueError):
+        with acct.phase("host"):
+            clock.now += 3
+            raise ValueError("x")
+    with acct.phase("admit"):
+        clock.now += 1
+    snap = acct.snapshot()
+    assert snap["ns"]["host"] == 3 and snap["ns"]["admit"] == 111
+    assert sum(snap["ns"].values()) + snap["unaccounted_ns"] \
+        == clock.now - 1_000
+
+
+def test_account_phase_counts_off_and_yields_the_sites_span_on(clock):
+    """Off: the counters move and the site gets the shared NOOP.  On:
+    the span the site would have had, from the account's two stamps."""
+    tracing.disable_tracing()
+    tracing.clear()
+    acct = _account()
+    with acct.phase("admit") as sp:
+        assert sp is tracing.NOOP
+        sp.set(admitted=1)
+        clock.now += 40
+    assert acct.snapshot()["ns"]["admit"] == 40
+    assert tracing.get_finished_spans() == []
+    tracing.enable_tracing()
+    try:
+        clock.now += 5
+        with tracing.span("engine.pass") as outer:
+            with acct.phase("admit") as sp:
+                sp.set(admitted=2)
+                t0 = clock.now
+                clock.now += 60
+                with acct.phase("host") as inner:   # a site with no span
+                    assert inner is tracing.NOOP
+                    clock.now += 9
+            t1 = clock.now
+    finally:
+        tracing.disable_tracing()
+    (got,) = tracing.get_finished_spans("engine.schedule")
+    tracing.clear()
+    assert (got["t0_ns"], got["t1_ns"]) == (t0, t1)
+    assert got["attributes"] == {"admitted": 2}
+    assert got["parent_id"] == outer.span_id
+    snap = acct.snapshot()
+    assert snap["ns"]["admit"] == 100 and snap["ns"]["host"] == 9
+    assert snap["count"]["admit"] == 2
+
+
+def test_account_starved_time_follows_launches_and_landings(clock):
+    """Time that passes while nothing is in flight is starved: with a
+    synchronous loop everything but the wait; a step launched behind
+    the one in flight leaves only what is outside both."""
+    tracing.disable_tracing()
+    acct = _account()
+
+    def spend(phase, ns):
+        with acct.phase(phase):
+            clock.now += ns
+    spend("admit", 10)          # nothing launched yet: starved
+    spend("dispatch", 20)       # ... and so is the launch itself
+    assert acct.in_flight == 1
+    clock.now += 3              # between sites, a program in flight
+    spend("wait", 500)
+    assert acct.in_flight == 0
+    clock.now += 4              # between sites, nothing in flight
+    spend("admit", 10)
+    # two launches landed by ONE fetch (a chunk and the step behind it)
+    spend("dispatch", 20)
+    spend("dispatch", 25)       # the first is in flight: not starved
+    assert acct.in_flight == 2
+    spend("host", 7)
+    spend("wait", 400)
+    assert acct.in_flight == 0
+    snap = acct.snapshot()
+    assert snap["starved_ns"] == {"admit": 20, "dispatch": 40, "wait": 0,
+                                  "host": 0}
+    assert snap["ns"] == {"admit": 20, "dispatch": 65, "wait": 900,
+                          "host": 7}
+    assert (snap["unaccounted_ns"], snap["unaccounted_starved_ns"]) == (7, 4)
+
+
+def test_ring_counts_what_it_evicts(monkeypatch):
+    """``deque(maxlen)`` drops the oldest span in silence; the count
+    tells a reader that its window may be cut."""
+    import collections
+    tracing.disable_tracing()
+    monkeypatch.setattr(tracing, "RING_SIZE", 4)
+    monkeypatch.setattr(tracing, "_ring", collections.deque(maxlen=4))
+    before = tracing.ring_dropped()
+    for i in range(4):
+        tracing.record_span(f"s{i}", 10 * i, 10 * i + 5)
+    assert tracing.ring_dropped() == before
+    for i in range(4, 7):
+        tracing.record_span(f"s{i}", 10 * i, 10 * i + 5)
+    assert tracing.ring_dropped() == before + 3
+    assert [s["name"] for s in tracing.get_finished_spans()] \
+        == ["s3", "s4", "s5", "s6"]
+
+
 # -- spans where the work happens: engine, serve front, trainer -------------
 # tiny CPU models, one engine for the module, no cluster, no subprocess
 
@@ -242,13 +394,14 @@ def _children(spans, parent):
 def test_request_spans_partition_submit_to_finish(engine, tiny):
     """Always on: queue + prefill + decode = finish - submit for every
     request of a burst, a preempted one included; with tracing off the
-    ring holds nothing else."""
+    ring holds nothing else but the loop's account."""
     tracing.disable_tracing()
     tracing.clear()
     before = engine.stats()
     reqs = _burst(engine, tiny[0])
     after = engine.stats()
-    spans = tracing.get_finished_spans()
+    spans = [s for s in tracing.get_finished_spans()
+             if s["name"] != "engine.account"]
     assert {s["name"] for s in spans} \
         == {"request.queue", "request.prefill", "request.decode"}
     by_req = {}
@@ -511,7 +664,56 @@ def test_profiler_session_switches_spans_on_and_carries_them(
     wanted = {"engine.pass", "engine.decode", "engine.fetch", "train.step",
               "train.next_batch"}
     assert wanted <= set(host) and wanted <= ring
+    # every pass-level span is an annotation; the always-on account
+    # (built from stamps, like a request's spans) is none
     assert set(host) == {n for n in ring
-                         if n.startswith(("engine.", "train."))}
+                         if n.startswith(("engine.", "train."))
+                         and n != "engine.account"}
     assert host["train.step"]["step_num"] in (0, 1)
     assert host["engine.fetch"]["bytes"] > 0
+
+
+def test_annotations_carry_their_stamp_onto_the_profilers_clock(tmp_path):
+    """The clock map: in a session an annotated span's ``t0_ns`` rides
+    as metadata beside the event's ``start_ns`` (which counts from the
+    session's start), so ONE offset places ``time.monotonic_ns()``, and
+    with it the spans that are no annotations, on the device's
+    timeline."""
+    import jax
+    from jax.profiler import ProfileData, ProfileOptions
+    tracing.disable_tracing()
+    tracing.clear()
+    acct = tracing.Account({"dispatch": "engine.dispatch"})
+    opts = ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=opts)
+    try:
+        for i in range(5):
+            with tracing.span("engine.pass", active=i):
+                with acct.phase("dispatch") as sp:
+                    assert sp and sp.t0_ns == acct.t_ns
+                    time.sleep(0.002)
+            always = tracing.record_span("engine.account", sp.t0_ns,
+                                         sp.t1_ns)
+    finally:
+        jax.profiler.stop_trace()
+    assert acct.interval_profiled() and not acct.interval_profiled()
+    ring = {s["t0_ns"]: s for s in tracing.get_finished_spans()
+            if s["name"] != "engine.account"}
+    tracing.clear()
+    (path,) = (tmp_path / "prof").glob("plugins/profile/*/*.xplane.pb")
+    offsets = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("engine."):
+                        t0_ns = int(dict(ev.stats)["t0_ns"])
+                        assert ring[t0_ns]["name"] == ev.name
+                        offsets.append(t0_ns - ev.start_ns)
+    assert len(offsets) == 10 == len(ring)
+    # one offset, to the stamp-to-annotation distance (microseconds)
+    assert max(offsets) - min(offsets) < 1_000_000
+    # ... which places a span that is no annotation
+    on_session_clock = always.t0_ns - offsets[-1]
+    assert 0 < on_session_clock < 60e9
