@@ -41,6 +41,11 @@ go to the scratch block), the embedding, the head.
     the greedy tokens out — the argmax of the float32 logits taken
     inside the program (every row's; the chunk's last real
     position's).  recurrent.py's two programs do the same.
+  * paged_step_chunk — the two above as ONE program, for the pass that
+    holds both a chunk and decoding rows: the rows' tokens and the
+    chunk's are one window of the layer function, so a layer's weights
+    stream once for both, and only the attention step treats the two
+    parts apart (``paged_attend`` with a length a row AND a mask).
   * spec_verify_step — the decode step widened to a [b, W] token
     window (W = speculate_k + 1): column 0 is each row's current input
     token, columns 1.. are DRAFTED continuations.  One call scores all
@@ -179,7 +184,7 @@ def make_prefill_fn(cfg: GPTConfig, *, mesh=None,
 
 def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
                  mesh=None, rules=None, kv_lengths=None, mask=None,
-                 q_per_kv: int = 1, scale=None):
+                 mask_tables=None, q_per_kv: int = 1, scale=None):
     """Where a window meets the pool, for every model family:
     ``attend_for(layer)`` gives that layer's ``attend(q [b, h, w, hd],
     k, v [b, w, h_kv, hd]) -> o [b, h, w, hd]`` over the (K, V)
@@ -200,7 +205,12 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
         the rows' ``tables`` [b, T] are gathered as contexts [b, T*bs,
         W], keys in position order, heads still packed as stored, and
         attended so (``packed_attention``); with a mesh the contexts
-        are constrained to the pool's heads sharding."""
+        are constrained to the pool's heads sharding.
+      * both (``make_paged_step_chunk``) — ONE window [1, n + w] that
+        holds ``n`` = ``len(kv_lengths)`` one-token rows and then a
+        window of ``w`` queries: committed together, the first ``n``
+        queries attended as one-token rows of ``tables`` [n, T], the
+        rest under ``mask`` over ``mask_tables`` [1, T], and joined."""
     held = {"pools": pools}
     if kv_lengths is not None:
         def sp(*axes):
@@ -213,6 +223,18 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
                 walk, mesh, (q_spec, sp(*POOL_AXES), sp(*POOL_AXES), sp(),
                              sp("batch", None), sp("batch")), q_spec)
 
+    def rows(q, layer):
+        return walk(q, *held["pools"], lay.rows(layer, 0), tables,
+                    kv_lengths)
+
+    def window(q, layer, of):
+        ctx_k, ctx_v = (
+            gpt._constrain(lay.read(p, layer, of),
+                           ("batch", None, "heads"), mesh, rules)
+            for p in held["pools"])
+        return packed_attention(q, ctx_k, ctx_v, groups=lay.shards,
+                                q_per_kv=q_per_kv, scale=scale, mask=mask)
+
     def attend_for(layer):
         def attend(q, k, v):
             new = (k.reshape(*blocks.shape, *k.shape[2:]),
@@ -220,16 +242,16 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
             held["pools"] = tuple(
                 lay.commit(p, layer, blocks, offsets, x)
                 for p, x in zip(held["pools"], new))
-            if kv_lengths is not None:
-                return walk(q, *held["pools"], lay.rows(layer, 0), tables,
-                            kv_lengths)
-            ctx_k, ctx_v = (
-                gpt._constrain(lay.read(p, layer, tables),
-                               ("batch", None, "heads"), mesh, rules)
-                for p in held["pools"])
-            return packed_attention(q, ctx_k, ctx_v, groups=lay.shards,
-                                    q_per_kv=q_per_kv, scale=scale,
-                                    mask=mask)
+            if mask is None:
+                return rows(q, layer)
+            if kv_lengths is None:
+                return window(q, layer, tables)
+            n = kv_lengths.shape[0]
+            # [1, h, n, hd] <-> [n, h, 1, hd]: a row's one query
+            o = rows(q[:, :, :n].transpose(2, 1, 0, 3), layer)
+            return jnp.concatenate(
+                [o.transpose(2, 1, 0, 3),
+                 window(q[:, :, n:], layer, mask_tables)], axis=2)
         return attend
     return attend_for, held
 
@@ -298,6 +320,39 @@ def unpack_chunk(packed, T: int, C: int):
             packed[T + C + 1], packed[T + C + 2])
 
 
+def pack_step_chunk(step: np.ndarray, chunk: np.ndarray) -> np.ndarray:
+    """What ``make_paged_step_chunk``'s program takes: a ``pack_step``
+    array, flat, then a ``pack_chunk`` array, as one fresh int32
+    ``[b * (T + 3) + T + C + 3]``."""
+    return np.concatenate([step.ravel(), chunk])
+
+
+def _step_indices(tables, positions, active, bs: int):
+    """Where a decode step's rows write and how far they attend:
+    -> (block ids [b], offsets [b], kv lengths [b]); an inactive row
+    goes to the scratch block (id 0) and attends nothing."""
+    rows = jnp.arange(positions.shape[0])
+    return (jnp.where(active, tables[rows, positions // bs], 0),
+            jnp.where(active, positions % bs, 0),
+            jnp.where(active, positions + 1, 0))              # 0: sits out
+
+
+def _chunk_indices(cfg, table, start, C: int, bs: int):
+    """A chunk's window at positions ``start .. start + C`` of one row:
+    -> (positions for ``wpe`` [C], block ids [C], offsets [C], mask
+    [C, T * bs]).  Each query's mask is its own causal horizon; a
+    position past the table's span writes to the scratch block, which
+    no table position of a real row names."""
+    S = table.shape[0] * bs
+    pos = start + jnp.arange(C, dtype=jnp.int32)
+    oob = pos >= S
+    safe = jnp.where(oob, 0, pos)
+    return (jnp.clip(pos, 0, cfg.max_seq - 1),
+            jnp.where(oob, 0, table[safe // bs]),
+            jnp.where(oob, 0, pos % bs),
+            jnp.arange(S)[None, :] <= pos[:, None])
+
+
 def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
                            n_table: int, mesh=None,
                            rules: Rules = DEFAULT_LLM_RULES):
@@ -326,15 +381,11 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
         @partial(jax.jit, donate_argnums=(1, 2))
         def step(params, k_pool, v_pool, packed):
             tables, tokens, positions, active = unpack_step(packed, T)
-            b = tokens.shape[0]
             lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
             x = (gpt._token_rows(params, tokens, cfg)
                  + params["wpe"][positions])
             x = x[:, None, :].astype(cfg.dtype)               # [b, 1, d]
-            rows = jnp.arange(b)
-            bidx = jnp.where(active, tables[rows, positions // bs], 0)
-            off = jnp.where(active, positions % bs, 0)
-            kv_len = jnp.where(active, positions + 1, 0)      # 0: sits out
+            bidx, off, kv_len = _step_indices(tables, positions, active, bs)
             x, pools = _paged_layers(
                 cfg, mesh, rules, params["layers"], x, pools,
                 lambda pools: paged_attend(
@@ -378,26 +429,17 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
     prefill cost per token cadence).
     """
     bs, C, T = int(block_size), int(chunk), int(n_table)
-    S = T * bs
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
         def chunk_fn(params, k_pool, v_pool, packed):
             table, tokens, start, _, n_valid = unpack_chunk(packed, T, C)
             lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
-            pos = start + jnp.arange(C, dtype=jnp.int32)       # [C]
-            oob = pos >= S
-            wpe_pos = jnp.clip(pos, 0, cfg.max_seq - 1)
+            wpe_pos, bidx, off, mask = _chunk_indices(cfg, table, start,
+                                                      C, bs)
             x = (gpt._token_rows(params, tokens, cfg)
                  + params["wpe"][wpe_pos])
             x = x[None, :, :].astype(cfg.dtype)                # [1, C, d]
-            safe = jnp.where(oob, 0, pos)
-            bidx = jnp.where(oob, 0, table[safe // bs])
-            off = jnp.where(oob, 0, pos % bs)
-            # each query row's mask is its own causal horizon; an
-            # out-of-range row's K/V went to the scratch block, which
-            # no table position of a real row names
-            mask = (jnp.arange(S)[None, :] <= pos[:, None])    # [C, S]
             x, pools = _paged_layers(
                 cfg, mesh, rules, params["layers"], x, pools,
                 lambda pools: paged_attend(
@@ -413,6 +455,72 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
         return chunk_fn
 
     return _cached(("chunk_prefill", bs, T, C), cfg, mesh, rules, build)
+
+
+def make_paged_step_chunk(cfg: GPTConfig, *, chunk: int, block_size: int,
+                          n_table: int, mesh=None,
+                          rules: Rules = DEFAULT_LLM_RULES):
+    """jitted decode step AND one prefill chunk as ONE program: what a
+    pass that holds both runs in place of ``make_paged_decode_step``'s
+    and ``make_chunk_prefill_fn``'s programs back to back, so that each
+    layer's weights stream from HBM once a pass, not twice.
+
+    (params, k_pool, v_pool [cache.PoolLayout], packed [b * (T + 3) +
+     T + C + 3] int32 (``pack_step_chunk``: a ``pack_step`` array, flat,
+     then a ``pack_chunk`` array))
+        -> (logits [b + 1, vocab] f32, greedy [b + 1] int32, k_pool,
+            v_pool)
+
+    The ``b`` rows' tokens and the chunk's ``C`` are ONE window
+    ``[1, b + C]`` of the layer function: every product of it reads its
+    weights once for ``b + C`` rows.  The layer's attention step commits
+    the whole window's K/V (inactive rows and the chunk's out-of-span
+    positions to the scratch block, as in the two programs) and attends
+    its two parts in the forms they have there: the first ``b`` queries
+    walk their tables in the one-token kernel, the last ``C`` attend the
+    chunk row's gathered table under their causal mask
+    (``paged_attend`` with both).  The head runs over ``b + 1`` rows:
+    the decode rows and the chunk's last REAL position (``n_valid -
+    1``); ``greedy[b]`` is the prompt's first token when the chunk ends
+    it.  Same dtypes, products and masks as the two programs."""
+    bs, C, T = int(block_size), int(chunk), int(n_table)
+
+    def build():
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def step_chunk(params, k_pool, v_pool, packed):
+            b = (packed.shape[0] - (T + C + 3)) // (T + 3)
+            tables, tokens, positions, active = unpack_step(
+                packed[:b * (T + 3)].reshape(b, T + 3), T)
+            table, chunk_tokens, start, _, n_valid = unpack_chunk(
+                packed[b * (T + 3):], T, C)
+            lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
+            bidx, off, kv_len = _step_indices(tables, positions, active, bs)
+            wpe_pos, c_bidx, c_off, mask = _chunk_indices(cfg, table,
+                                                          start, C, bs)
+            x = (gpt._token_rows(
+                     params, jnp.concatenate([tokens, chunk_tokens]), cfg)
+                 + params["wpe"][jnp.concatenate([positions, wpe_pos])])
+            x = x[None, :, :].astype(cfg.dtype)            # [1, b + C, d]
+            x, pools = _paged_layers(
+                cfg, mesh, rules, params["layers"], x, pools,
+                lambda pools: paged_attend(
+                    lay, pools, jnp.concatenate([bidx, c_bidx])[None],
+                    jnp.concatenate([off, c_off])[None], tables,
+                    mesh=mesh, rules=rules, kv_lengths=kv_len,
+                    mask=mask[None, None], mask_tables=table[None]))
+            k_pool, v_pool = (gpt._constrain(p, POOL_AXES, mesh, rules)
+                              for p in pools)
+            last = b + jnp.maximum(n_valid, 1) - 1
+            x = jnp.concatenate(
+                [x[:, :b], lax.dynamic_slice_in_dim(x, last, 1, axis=1)],
+                axis=1)                                    # [1, b + 1, d]
+            logits = gpt._head(params, x, cfg, mesh, rules)[0]
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return logits, greedy, k_pool, v_pool
+
+        return step_chunk
+
+    return _cached(("paged_step_chunk", bs, T, C), cfg, mesh, rules, build)
 
 
 def make_spec_verify_step(cfg: GPTConfig, *, width: int, block_size: int,

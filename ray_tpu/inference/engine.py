@@ -63,11 +63,16 @@ full-recompute oracle; asserted in tests).  The logits [n_slots, vocab]
 stay on the device (6.4 MB a pass at GPT-2 XL's 32 rows, which went to
 the host and back for an argmax program of its own until ISSUE 39); a
 row with temperature > 0 keeps its per-request rng and samples from its
-row of them there, one dispatch of its own.  A prompt's greedy first
-token is its last chunk's own argmax and is not waited for before the
-pass's decode step goes out behind the chunk (_finish_prefill).  The
-full-width prefill and the speculation programs have no greedy output:
-they sample on the logits as before.
+row of them there, one dispatch of its own.  A pass that holds both a
+chunk and decoding rows runs them as ONE program where the family has
+it (decode.make_paged_step_chunk, ``_step_chunk``: the weights stream
+once a pass): the pass's last chunk is prepared and packed, and
+launched by the decode step.  A prompt's greedy first token is its last
+chunk's own argmax, read inside the decode step's fetch: the fused
+program's last integer, or the chunk program's, which is not waited for
+before the step goes out behind it (_finish_prefill).  The full-width
+prefill and the speculation programs have no greedy output: they sample
+on the logits as before.
 """
 
 from __future__ import annotations
@@ -91,10 +96,11 @@ from ray_tpu.inference.decode import (SpeculationUnsupported,
                                       make_chunk_prefill_fn,
                                       make_paged_decode_step,
                                       make_paged_draft_step,
+                                      make_paged_step_chunk,
                                       make_prefill_fn,
                                       make_spec_verify_step,
                                       ngram_propose, pack_chunk,
-                                      pack_step)
+                                      pack_step, pack_step_chunk)
 from ray_tpu.inference.recurrent import (make_recurrent_chunk_fn,
                                          make_recurrent_decode_step)
 from ray_tpu.models import gpt, hybrid
@@ -434,6 +440,15 @@ class _KVOnly(_Seam):
         eng._chunk = make_chunk_prefill_fn(
             cfg, chunk=ec.prefill_chunk, block_size=bs,
             n_table=eng.pool.blocks_per_seq, mesh=mesh, rules=rules)
+        # the two as ONE program, for a pass that holds both.  Not
+        # where a pass may take the speculative iteration in the step's
+        # place, nor for routed experts: their capacity is per window,
+        # and a decode row's one-token window can never drop
+        eng._step_chunk = (
+            None if ec.speculate is not None or cfg.n_experts
+            else make_paged_step_chunk(
+                cfg, chunk=ec.prefill_chunk, block_size=bs,
+                n_table=eng.pool.blocks_per_seq, mesh=mesh, rules=rules))
 
     @staticmethod
     def run(eng, program, packed):
@@ -487,6 +502,8 @@ class _KVAndState(_Seam):
         eng._chunk = make_recurrent_chunk_fn(
             cfg, chunk=ec.prefill_chunk, block_size=bs,
             n_table=eng.pool.blocks_per_seq)
+        # no fused program yet: a pass runs the two back to back
+        eng._step_chunk = None
 
     @staticmethod
     def run(eng, program, packed):
@@ -642,7 +659,8 @@ class InferenceEngine:
         self._preemptions = 0
         # written by the loop thread alone, so without the lock:
         self._admissions = 0           # requests given a row
-        self._chunk_passes = 0         # chunk-prefill programs run
+        self._chunk_passes = 0         # prefill chunks run ...
+        self._chunks_in_step = 0       # ... of them inside a decode step
         self._prefill_tokens = 0       # prompt tokens run through a
         #                                prefill program (hits excluded)
         # per one-token decode pass: the blocks that hold a key of a
@@ -762,6 +780,33 @@ class InferenceEngine:
         return self.submit(prompt, max_new=max_new, temperature=temperature,
                            seed=seed).result(timeout=timeout)
 
+    def warm_up(self, timeout: float = 300.0) -> None:
+        """Bring every program a pass can run to the device before the
+        first request: one short generation (the chunk program and the
+        decode step), and the program that runs both once on nothing —
+        every decode row inactive and a chunk of no real token, all of
+        whose writes go to the scratch block.  Requests that arrive one
+        at a time never give a pass both a chunk and a decoding row, so
+        the first overlap would otherwise compile on the request path."""
+        self.generate([1], max_new=2, timeout=timeout)
+        if self._step_chunk is None:
+            return
+
+        def run_once():
+            n, T = self._tables.shape
+            zeros = np.zeros(n, np.int32)
+            packed = pack_step_chunk(
+                pack_step(np.zeros_like(self._tables), zeros, zeros, zeros),
+                pack_chunk(np.zeros(T, np.int32),
+                           np.zeros(self.engine_cfg.prefill_chunk, np.int32),
+                           0, 0, 0))
+            with self._acct.phase("dispatch"):
+                self._seam.run(self, self._step_chunk, packed)
+            with self._acct.phase("wait"):
+                jax.block_until_ready(self._load.pop())
+
+        self._run_op(run_once, timeout=timeout)
+
     # ------------------------------------------------------------- loop
 
     def _loop_pass(self) -> bool:
@@ -804,13 +849,15 @@ class InferenceEngine:
                            waiting=len(self._waiting))
                 self._schedule_locked()
             try:
-                if self._prefilling:
-                    # at most ONE chunk per pass: prefill progress is
-                    # interleaved with decode so a long prompt cannot
-                    # stall its neighbors' token cadence
-                    self._prefill_chunk_pass()
+                # prefill progress is interleaved with decode, ONE
+                # chunk a pass at healthy occupancy, so a long prompt
+                # cannot stall its neighbors' token cadence; the pass's
+                # last chunk rides the decode step where ONE program
+                # runs both
+                ride = (self._prefill_chunk_pass() if self._prefilling
+                        else None)
                 if self._active.any():
-                    self._paged_decode_iteration()
+                    self._paged_decode_iteration(ride)
                 self._pass_done()
             except Exception as e:            # step failure: fail the
                 self._fail_all(e)             # in-flight requests, keep serving
@@ -835,6 +882,7 @@ class InferenceEngine:
             engine=self.name, passes=self._passes,
             decode_iterations=self._decode_iterations,
             chunk_passes=self._chunk_passes,
+            chunks_in_step=self._chunks_in_step,
             profiling=acct.interval_profiled(),
             ring_dropped=tracing.ring_dropped(), **acct.snapshot())
         self._account_t1_ns = t1_ns
@@ -1089,7 +1137,7 @@ class InferenceEngine:
         self._tables[row, bidx] = nb
         return True
 
-    def _prefill_chunk_pass(self) -> None:
+    def _prefill_chunk_pass(self):
         """Advance prefills, occupancy-aware.  At healthy decode
         occupancy (>= half the rows active), ONE chunk per pass — that
         bounds the active streams' per-iteration stall (the point of
@@ -1098,18 +1146,23 @@ class InferenceEngine:
         chunks as there are prefilling rows before the next iteration
         (each picked shortest-remaining-first, so the cheapest prefill
         usually FINISHES within the pass rather than every row
-        advancing one step)."""
-        n = self.engine_cfg.max_slots
-        if 2 * int(self._active.sum()) >= n:
-            self._prefill_one_chunk()
-            return
-        for _ in range(len(self._prefilling)):
-            if (not self._prefilling
-                    or 2 * int(self._active.sum()) >= n):
-                break
-            self._prefill_one_chunk()
+        advancing one step).
 
-    def _prefill_one_chunk(self) -> None:
+        -> the pass's LAST chunk, prepared and packed but not launched
+        (``_advance_prefill``), when the decode step is to run it;
+        else None."""
+        n = self.engine_cfg.max_slots
+        todo = (1 if 2 * int(self._active.sum()) >= n
+                else len(self._prefilling))
+        ride = None
+        for i in range(todo):
+            if not self._prefilling or (
+                    i and 2 * int(self._active.sum()) >= n):
+                break
+            ride = self._prefill_one_chunk(last=i == todo - 1)
+        return ride
+
+    def _prefill_one_chunk(self, last: bool = False):
         """Advance ONE prefilling request, shortest-remaining-first
         (ties by arrival).  SRF activates the cheapest prefill soonest
         (occupancy), and — critically for shared prefixes — SERIALIZES
@@ -1118,12 +1171,26 @@ class InferenceEngine:
         each paying the whole train.  (Round-robin interleaves the
         duplicates so none publishes until nearly everyone has paid.)
         On prompt completion the request gets its first token and the
-        row turns active (``_finish_prefill``)."""
+        row turns active (``_finish_prefill``).
+
+        The pass's ``last`` chunk rides the decode step when rows are
+        decoding and ONE program runs both.  The step's block hunt then
+        comes first: it may preempt the very row whose chunk would
+        ride, and a preempted row's chunk is never launched."""
+        may_ride = (last and self._step_chunk is not None
+                    and bool(self._active.any()))
+        if may_ride:
+            self._grow_rows()
+            if not self._prefilling:
+                return None
         with tracing.span("engine.prefill_chunk") as sp, \
                 self._acct.phase("prefill_host"):
-            self._advance_prefill(sp)
+            return self._advance_prefill(sp, may_ride)
 
-    def _advance_prefill(self, sp) -> None:
+    def _advance_prefill(self, sp, may_ride: bool = False):
+        """-> None once the chunk is launched, or, of a chunk that
+        ``may_ride`` while a row still decodes, (row, tokens, the packed
+        chunk) for ``_paged_decode_iteration`` to launch."""
         row = min(self._prefilling,
                   key=lambda r: (int(self._slot_req[r].prompt.size)
                                  - self._prefilling[r],
@@ -1133,7 +1200,7 @@ class InferenceEngine:
             self._release_row(row)
             req._finish()
             self._note_done(req)
-            return
+            return None
         pos = self._prefilling[row]
         bs = self.pool.block_size
         C = self.engine_cfg.prefill_chunk
@@ -1185,7 +1252,7 @@ class InferenceEngine:
                 self.pool.write_prefill(self._tables[row], k_new[:, 0],
                                         v_new[:, 0])
             self._finish_prefill(row, req, logits, (0, n - 1))
-            return
+            return None
         # the write window [pos, pos+C) must only touch exclusively
         # owned blocks — only the FIRST can be shared (an adopted
         # partial tail), but the scan is cheap
@@ -1193,7 +1260,7 @@ class InferenceEngine:
         last = min(-(-(pos + C) // bs), len(self._row_blocks[row]))
         for bidx in range(first, last):
             if not self._cow_block(row, bidx):
-                return                     # row preempted under pressure
+                return None                # row preempted under pressure
         n_q = min(C, n - pos)
         if sp:
             sp.set(row=row, tokens=n_q, full_width=False,
@@ -1206,28 +1273,44 @@ class InferenceEngine:
         with self._acct.phase("pack") as up:
             packed = pack_chunk(self._tables[row], chunk_toks, pos, row, n_q)
             up.set(bytes=packed.nbytes)
+        # (the copy-on-write above may have preempted the last active row)
+        if may_ride and self._active.any():
+            return row, n_q, packed
         with self._acct.phase("dispatch"):
             logits = self._seam.run(self, self._chunk, packed)
-        new_pos = pos + n_q
-        if new_pos < n:
+        self._chunk_launched(row, n_q, logits, n_q - 1)
+        return None
+
+    def _chunk_launched(self, row: int, n_q: int, logits, idx,
+                        in_step: bool = False) -> None:
+        """A chunk of ``n_q`` tokens of ``row``'s prompt is on its way,
+        by the chunk program or ``in_step``; ``logits[idx]`` are its
+        last real position's."""
+        req = self._slot_req[row]
+        new_pos = self._prefilling[row] + n_q
+        if new_pos < int(req.prompt.size):
             self._prefilling[row] = new_pos
             return
-        self._finish_prefill(row, req, logits, n_q - 1, self._load[-1])
+        self._finish_prefill(row, req, logits, idx, self._load[-1], in_step)
 
     def _finish_prefill(self, row: int, req: GenerationRequest,
-                        logits, idx, owed=None) -> None:
+                        logits, idx, owed=None,
+                        in_step: bool = False) -> None:
         """Prompt fully in cache: its first token is the last prompt
         position's (``logits[idx]``), and the row then turns active (or
         evicts immediately on EOS / max_new == 1).
 
-        A greedy first token is the chunk program's own argmax, the
-        last entry of ``owed`` (the program's int32 vector, still on the
+        A greedy first token is its program's own argmax, the last
+        entry of ``owed`` (the program's int32 vector, still on the
         device): no slice and no sampling dispatched.  And not waited
-        for here while other rows decode: the pass's decode step is
-        dispatched behind the chunk first (the device runs the two back
-        to back), the token is emitted as soon as the chunk has ended
-        (``_emit_first``, from ``_fetch_step``), and the row joins the
-        decode batch at the end of the pass (``_pass_done``).  A sampled
+        for here while other rows decode: where the chunk program ran,
+        the pass's decode step is dispatched behind it first (the
+        device runs the two back to back); the token is emitted as soon
+        as its program has ended (``_emit_first``, from
+        ``_fetch_step``), and the row joins the decode batch at the end
+        of the pass (``_pass_done``).  So does the sampled first token
+        of a chunk that ran ``in_step``: sampled here on the step's
+        logits, it is owed by that dispatch.  Any other sampled
         request, and a full-width prefill (no ``owed``), take one
         sampling dispatch on the logits and wait for it."""
         del self._prefilling[row]
@@ -1244,7 +1327,13 @@ class InferenceEngine:
                 self._note_prefix_published(
                     req.prompt[:full],
                     self._row_blocks[row][:full // self.pool.block_size])
-        if owed is None or req.temperature != 0.0:
+        if req.temperature != 0.0 and in_step:
+            with self._acct.phase("emit") as sample:
+                sample.set(rows=1)
+                owed = gpt.sample_token(
+                    logits[idx], temperature=req.temperature,
+                    rng=req._next_rng())[None]
+        elif owed is None or req.temperature != 0.0:
             tok = self._first_token(req, logits[idx])
             req._emit(tok)
             self._start_decoding(row, req, tok)
@@ -1256,8 +1345,9 @@ class InferenceEngine:
     def _emit_first(self, in_step_fetch: bool = False) -> tuple:
         """Read and emit the first tokens this pass's chunks owe.
         Inside the decode step's fetch the reads are part of that wait
-        -> (tokens read, their bytes); with no step behind the chunk
-        (``_pass_done``) each is a wait of its own."""
+        -> (tokens read from other programs than the step, their
+        bytes); with no step behind the chunk (``_pass_done``) each is
+        a wait of its own."""
         n = n_bytes = 0
         for pend in self._first_pending:
             row, req, owed, tok = pend
@@ -1266,15 +1356,21 @@ class InferenceEngine:
             if tok is not None or self._slot_req.get(row) is not req:
                 continue
             if in_step_fetch:
-                tok = int(jax.device_get(owed)[self._seam.N_LOAD])
-                n, n_bytes = n + 1, n_bytes + owed.nbytes
+                tok = int(jax.device_get(owed)[-1])
+                # the step's own vector (its chunk ended the prompt)
+                # comes once, and is counted with the loads
+                if owed is not self._load[-1]:
+                    n, n_bytes = n + 1, n_bytes + owed.nbytes
             else:
                 with self._acct.phase("wait") as fetch:
-                    tok = int(jax.device_get(owed)[self._seam.N_LOAD])
+                    tok = int(jax.device_get(owed)[-1])
                     self._fetched(fetch, owed.nbytes)
             pend[3] = tok
             req._emit(tok)
-            self._tokens_on_device += 1
+            if req.temperature == 0.0:
+                self._tokens_on_device += 1
+            else:
+                self._tokens_sampled += 1
         return n, n_bytes
 
     def _pass_done(self) -> None:
@@ -1299,9 +1395,10 @@ class InferenceEngine:
         pass and of the chunks before it — in ONE small transfer; the
         logits stay on the device (a sampled row indexes them there).
         First tokens that this pass's chunks owe go out first: their
-        programs ended a decode step ago, and their reads belong to
-        this wait (``fetch``, its span: ``first_tokens``, and their
-        bytes among its ``bytes``)."""
+        programs ended a decode step ago (the token of a chunk that ran
+        inside the step is the last of the step's own integers), and
+        their reads belong to this wait (``fetch``, its span:
+        ``first_tokens``, and their bytes among its ``bytes``)."""
         n_first, n_bytes = self._emit_first(in_step_fetch=True)
         loads = jax.device_get(self._load)
         self._load = []
@@ -1596,7 +1693,11 @@ class InferenceEngine:
                 self._spec_rollback(row)
         return stepped, emitted
 
-    def _paged_decode_iteration(self) -> None:
+    def _grow_rows(self) -> None:
+        """Before a decode step: every active row's write-target block
+        exists and is its own (``_grow_row``: the block hunt, which may
+        preempt); cancelled rows are evicted.  Rows it has seen to
+        before in the same pass cost a look."""
         with self._acct.phase("grow") as sp:
             preempted0 = self._preemptions
             for row in [r for r in list(self._slot_req) if self._active[r]]:
@@ -1609,6 +1710,13 @@ class InferenceEngine:
                     continue
                 self._grow_row(row)       # False = row preempted; skip
             sp.set(admitted=0, preempted=self._preemptions - preempted0)
+
+    def _paged_decode_iteration(self, ride=None) -> None:
+        """One decode step over the active rows.  ``ride``: the chunk
+        this pass prepared for the step to run, (row, tokens, packed);
+        the block hunt was made before it was packed."""
+        if ride is None:
+            self._grow_rows()
         if not self._active.any():
             return
         # draft-then-verify when configured; False = no row produced a
@@ -1631,13 +1739,22 @@ class InferenceEngine:
         with tracing.span("engine.decode", speculative=False) as sp:
             if sp:
                 sp.set(active=int(self._active.sum()),
-                       state_rows=self.pool.state_rows_in_use)
+                       state_rows=self.pool.state_rows_in_use,
+                       chunk_tokens=ride[1] if ride else 0)
+            program = self._step
             with self._acct.phase("pack") as up:
                 packed = pack_step(self._tables, self._tokens,
                                    self._positions, self._active)
+                if ride:
+                    program = self._step_chunk
+                    packed = pack_step_chunk(packed, ride[2])
                 up.set(bytes=packed.nbytes)
             with self._acct.phase("dispatch"):
-                logits = self._seam.run(self, self._step, packed)
+                logits = self._seam.run(self, program, packed)
+            if ride:
+                self._chunks_in_step += 1
+                self._chunk_launched(*ride[:2], logits,
+                                     self.engine_cfg.max_slots, in_step=True)
             if self._mesh is not None:
                 # every shard just committed its slice of the donated
                 # scatter — the point where a multi-host straggler or
@@ -1953,6 +2070,7 @@ class InferenceEngine:
             preemptions = self._preemptions
             admissions = self._admissions
             chunk_passes = self._chunk_passes
+            chunks_in_step = self._chunks_in_step
             prefill_tokens = self._prefill_tokens
             kv_attended = self._kv_blocks_attended
             kv_tabled = self._kv_blocks_tabled
@@ -1975,7 +2093,10 @@ class InferenceEngine:
             # counters at the span boundaries (util/tracing.py); /metrics
             # exports them (metrics_snapshot)
             "admissions": admissions,
+            # prefill chunks run, and those of them that ran inside a
+            # decode step's program (``_step_chunk``)
             "chunk_passes": chunk_passes,
+            "chunks_in_step": chunks_in_step,
             "prefill_tokens": prefill_tokens,
             "kv_blocks_attended": kv_attended,
             "kv_blocks_tabled": kv_tabled,
@@ -2081,7 +2202,7 @@ def metrics_snapshot() -> list:
         engines = dict(_ENGINES)
     active, waiting, occ, gen, comp = {}, {}, {}, {}, {}
     butil, phit, pcached, preempt = {}, {}, {}, {}
-    admits, chunks, ptoks = {}, {}, {}
+    admits, chunks, in_step, ptoks = {}, {}, {}, {}
     kv_att, kv_tab = {}, {}
     on_dev, sampled, fbytes = {}, {}, {}
     tps, arate, saccept = {}, {}, {}
@@ -2112,6 +2233,7 @@ def metrics_snapshot() -> list:
         # over admissions how many prefill programs a prompt costs
         admits[key] = float(st["admissions"])
         chunks[key] = float(st["chunk_passes"])
+        in_step[key] = float(st["chunks_in_step"])
         ptoks[key] = float(st["prefill_tokens"])
         kv_att[key] = float(st["kv_blocks_attended"])
         kv_tab[key] = float(st["kv_blocks_tabled"])
@@ -2170,7 +2292,11 @@ def metrics_snapshot() -> list:
          "Requests given a cache row (a preempted request counts again)",
          admits or zero),
         ("ray_tpu_inference_chunk_passes_total", "counter",
-         "Chunk-prefill programs run", chunks or zero),
+         "Prefill chunks run, by the chunk program or inside a decode "
+         "step", chunks or zero),
+        ("ray_tpu_inference_chunks_in_step_total", "counter",
+         "Prefill chunks that ran inside a decode step's program (one "
+         "read of the weights for both)", in_step or zero),
         ("ray_tpu_inference_prefill_tokens_total", "counter",
          "Prompt tokens run through a prefill program (prefix-cache "
          "hits excluded)", ptoks or zero),

@@ -143,9 +143,10 @@ class GPTServer:
             else init_params_for(self.cfg, jax.random.PRNGKey(seed)),
             self.cfg, self.engine_cfg, name=name, labels=labels, **kw)
         if self._warm:
-            # compile prefill+decode off the request path, so a freshly
-            # scaled-up replica doesn't serve its first requests cold
-            eng.generate([1], max_new=2, timeout=300)
+            # compile every program of a pass off the request path, so
+            # a freshly scaled-up replica doesn't serve its first
+            # requests cold
+            eng.warm_up(timeout=300)
         return eng
 
     def _engine_for(self, req: dict) -> InferenceEngine:
@@ -376,8 +377,9 @@ def build_gpt_deployment(*, name: str = DEFAULT_ROUTE,
     and cache pool.  ``variants`` ({model_id: seed}) turns each replica
     into a model-multiplexed server: at most ``multiplex_capacity``
     variants resident per replica, LRU-evicted; requests pick one with
-    the ``model`` field.  ``warm_on_init`` compiles prefill+decode at
-    replica construction so scale-ups don't serve cold.  ``mesh`` (+
+    the ``model`` field.  ``warm_on_init`` runs every program of a pass
+    once at replica construction (``InferenceEngine.warm_up``) so
+    scale-ups don't serve cold.  ``mesh`` (+
     optional ``rules``) serves every replica tensor-parallel: params
     and KV pools heads-sharded over the mesh's ``tp`` axis, one decode
     program shared across replicas of the same geometry.

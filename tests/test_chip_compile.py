@@ -26,7 +26,8 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from ray_tpu.inference.cache import BlockPool, PoolLayout
 from ray_tpu.inference.decode import (make_chunk_prefill_fn,
-                                      make_paged_decode_step)
+                                      make_paged_decode_step,
+                                      make_paged_step_chunk)
 from ray_tpu.models import gpt
 from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES, spec_for
 
@@ -226,7 +227,8 @@ def xl(one_chip):
 
 @pytest.fixture(scope="module")
 def xl_compiled(xl):
-    """``program -> `` the cell's decode step or chunk prefill, compiled
+    """``program -> `` the cell's decode step, chunk prefill or the
+    program that runs both (``step_chunk``), compiled
     once with the served tree's shapes (``out_info``: its results'
     shapes instead)."""
     cfg, on_chip, pool, lay = xl
@@ -242,11 +244,17 @@ def xl_compiled(xl):
                 lowered = make_paged_decode_step(
                     cfg, block_size=lay.block_size, n_table=T).lower(
                     params, pool, pool, on_chip((rows, T + 3), jnp.int32))
-            else:
+            elif program == "chunk":
                 lowered = make_chunk_prefill_fn(
                     cfg, chunk=32, block_size=lay.block_size,
                     n_table=T).lower(
                     params, pool, pool, on_chip((T + 32 + 3,), jnp.int32))
+            else:                   # the two as ONE program (ISSUE 41)
+                lowered = make_paged_step_chunk(
+                    cfg, chunk=32, block_size=lay.block_size,
+                    n_table=T).lower(
+                    params, pool, pool,
+                    on_chip((rows * (T + 3) + T + 32 + 3,), jnp.int32))
             done[program] = (lowered.compile(), lowered.out_info)
         return done[program][int(out_info)]
     return compiled
@@ -260,19 +268,54 @@ def test_xl_chunk_prefill_moves_no_pool(xl, xl_compiled):
     _assert_pool_stays_put(xl_compiled("chunk"), xl[3])
 
 
+def test_xl_step_chunk_moves_no_pool(xl, xl_compiled):
+    _assert_pool_stays_put(xl_compiled("step_chunk"), xl[3])
+
+
+def test_xl_step_chunk_reads_each_layer_s_weights_once(xl, xl_compiled):
+    """What ISSUE 41 bought: in the pass that holds a chunk and decoding
+    rows, the 32 rows' tokens and the chunk's 32 are ONE window of the
+    layer function, so each of a layer's four matrices is the operand
+    of ONE product, 64 rows wide, and streams from HBM once a pass; the
+    two programs it stands in for each read all of them for 32 rows."""
+    cfg, lay = xl[0], xl[3]
+    pool = "bf16[" + ",".join(map(str, lay.shape)) + "]"
+    text = xl_compiled("step_chunk").as_text()
+    body = re.search(r"body=(%[\w.]+)", next(
+        line for line in text.splitlines() if " while(" in line
+        and pool in line)).group(1)
+    scan = text.split(f"\n{body} ", 1)[1].split("\n}\n", 1)[0]
+    d, ff = cfg.d_model, cfg.d_ff
+    for out in (3 * d, d, ff):
+        wide = re.findall(rf"= bf16\[(?:1,)?64,{out}\]\S* (?:fusion|dot|"
+                          rf"convolution)\(", scan)
+        assert wide, f"no product [64, {out}] in the layer scan"
+    # no product of the layer over one part of the window alone
+    for out in (3 * d, ff):
+        assert not re.findall(rf"bf16\[(?:1,)?32,(?:1,)?{out}\]", scan)
+    # ... and the decode step's and the chunk program's products are
+    # what this test would have seen there
+    for program in ("decode", "chunk"):
+        assert re.findall(rf"bf16\[(?:1,)?32,(?:1,)?{ff}\]",
+                          xl_compiled(program).as_text())
+
+
 def _kernel_calls(text, name="paged_decode_attention"):
     return [line.strip() for line in text.splitlines()
             if " custom-call(" in line and "tpu_custom_call" in line
             and line.strip().startswith(f"%{name}")]
 
 
-def test_xl_decode_step_walks_the_tables_in_one_kernel(xl, xl_compiled):
+@pytest.mark.parametrize("program", ["decode", "step_chunk"])
+def test_xl_decode_step_walks_the_tables_in_one_kernel(xl, xl_compiled,
+                                                       program):
     """What ISSUE 35 bought: the decode step gathers no row's table
     (32 rows x 64 columns of [16, 1664] blocks, one a pool a layer, were
     two thirds of the program) and holds the table-walking kernel ONCE,
-    inside the layer scan, on both pools as stored."""
+    inside the layer scan, on both pools as stored.  So does the program
+    that also runs a chunk (which gathers the chunk row's one table)."""
     lay = xl[3]
-    text = xl_compiled("decode").as_text()
+    text = xl_compiled(program).as_text()
     assert "bf16[2048,16,1664]" not in text
     assert "bf16[32,1024,1664]" not in text
     pool = "bf16[" + ",".join(map(str, lay.shape)) + "]"
@@ -285,7 +328,7 @@ def test_xl_decode_step_walks_the_tables_in_one_kernel(xl, xl_compiled):
     assert call in scan
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("program", ["decode", "chunk", "step_chunk"])
 def test_xl_program_casts_and_retiles_no_weights(xl, xl_compiled, program):
     """What ISSUE 30 bought: handed the served tree, a program holds no
     ``convert`` whose result is a stacked layer weight (from float32
@@ -311,9 +354,10 @@ def test_xl_program_casts_and_retiles_no_weights(xl, xl_compiled, program):
     assert xl_compiled(program).memory_analysis().temp_size_in_bytes < 5e8
 
 
-@pytest.mark.parametrize("program, n", [("decode", 32), ("chunk", 1)])
+@pytest.mark.parametrize("program, n, rows", [
+    ("decode", 32, 32), ("chunk", 1, 32), ("step_chunk", 33, 33)])
 def test_xl_program_returns_its_own_greedy_tokens(xl, xl_compiled, program,
-                                                  n):
+                                                  n, rows):
     """What ISSUE 39 bought: beside the float32 logits, which stay on
     the device, each program hands back the argmax it took itself, as
     int32 (every row's from the step, the last real position's from the
@@ -322,14 +366,17 @@ def test_xl_program_returns_its_own_greedy_tokens(xl, xl_compiled, program,
     compiled programs still move no pool and cast no weight (above)."""
     cfg = xl[0]
     logits, greedy, k, v = xl_compiled(program, out_info=True)
+    # (the step that runs a chunk: its 32 rows, then the chunk's last
+    # real position, whose token a prompt's first is)
     assert (logits.dtype, logits.shape) == (jnp.float32,
-                                            (32, cfg.vocab_size))
+                                            (rows, cfg.vocab_size))
     assert (greedy.dtype, greedy.shape) == (jnp.int32, (n,))
     assert k.shape == v.shape == xl[3].shape
     entry = re.search(r"entry_computation_layout=.*",
                       xl_compiled(program).as_text()).group(0)
     results = entry.split("->", 1)[1]
-    assert f"s32[{n}]" in results and f"f32[32,{cfg.vocab_size}]" in results
+    assert f"s32[{n}]" in results \
+        and f"f32[{rows},{cfg.vocab_size}]" in results
     # one packed int32 array in: no other integer argument
     assert entry.split("->", 1)[0].count("s32[") == 1
 
@@ -348,10 +395,13 @@ def test_xl_write_blocks_moves_no_pool(xl):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
-def test_paged_decode_step_keeps_its_pool_shard_under_dp_tp(mesh_2x2):
+@pytest.mark.parametrize("program", ["decode", "step_chunk"])
+def test_paged_decode_step_keeps_its_pool_shard_under_dp_tp(mesh_2x2,
+                                                            program):
     """tp=2: every device holds whole heads (6 of 12: 384 lanes, no
     padding) of every block, in the same row-major layout, and the
-    program copies none of it; its attention kernel runs per shard."""
+    program copies none of it; its attention kernel runs per shard.
+    The step that also runs a chunk (ISSUE 41) the same."""
     cfg = gpt.GPTConfig.gpt2_124m()
     rows, bs = 8, 16
     n_table = cfg.max_seq // bs
@@ -366,11 +416,17 @@ def test_paged_decode_step_keeps_its_pool_shard_under_dp_tp(mesh_2x2):
                      cfg.head_dim, cache_mod.heads_shards(mesh_2x2))
     assert (lay.shards, lay.width) == (2, cfg.d_model)
     pool = on_mesh(lay.shape, cfg.dtype, cache_mod.POOL_AXES)
-    step = make_paged_decode_step(cfg, block_size=bs, n_table=n_table,
-                                  mesh=mesh_2x2)
-    compiled = step.lower(
-        _params_of(cfg, on_mesh), pool, pool,
-        on_mesh((rows, n_table + 3), jnp.int32)).compile()
+    if program == "decode":
+        step = make_paged_decode_step(cfg, block_size=bs, n_table=n_table,
+                                      mesh=mesh_2x2)
+        packed = on_mesh((rows, n_table + 3), jnp.int32)
+    else:
+        step = make_paged_step_chunk(cfg, chunk=32, block_size=bs,
+                                     n_table=n_table, mesh=mesh_2x2)
+        packed = on_mesh((rows * (n_table + 3) + n_table + 32 + 3,),
+                         jnp.int32)
+    compiled = step.lower(_params_of(cfg, on_mesh), pool, pool,
+                          packed).compile()
     shard = PoolLayout(lay.n_layers, lay.n_rows, bs, cfg.n_heads // 2,
                        cfg.head_dim)
     assert shard.shape == (*lay.shape[:2], 384)

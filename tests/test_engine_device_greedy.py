@@ -127,13 +127,20 @@ def test_sampled_row_alone_gives_the_same_stream(params, cfg):
 
 # -------------------------------- a first token behind the decode step
 
-def test_first_token_behind_a_running_decode(params, cfg, sampling_calls):
+@pytest.mark.parametrize("fused", [True, False],
+                         ids=["one_program", "two_programs"])
+def test_first_token_behind_a_running_decode(params, cfg, sampling_calls,
+                                             fused):
     """A prompt that ends while other rows decode: its first token is
-    the chunk's own argmax, not waited for before the pass's decode step
-    is dispatched (it is read in that step's fetch), the row joins
-    the batch a pass later, and a request that its first token ends
-    never decodes.  Streams are the oracle's, token for token."""
+    its program's own argmax — the last integer of the step that ran
+    the chunk (ISSUE 41), or the chunk program's, not waited for before
+    the pass's decode step is dispatched (it is read in that step's
+    fetch) — the row joins the batch a pass later, and a request that
+    its first token ends never decodes.  Streams are the oracle's,
+    token for token."""
     eng = _engine(params, cfg)
+    if not fused:
+        eng._step_chunk = None
     rng = np.random.default_rng(3)
     long_ = rng.integers(0, cfg.vocab_size, 6).tolist()
     plan = [(11, 1), (17, 5), (4, 1), (23, 7)]
@@ -164,13 +171,22 @@ def test_first_token_behind_a_running_decode(params, cfg, sampling_calls):
     # every decode step fetched its rows' integers, nothing else ...
     steps = [s for s in spans.values() if s["name"] == "engine.fetch"
              and spans.get(s["parent_id"], {}).get("name") == "engine.decode"]
+    def rode(s):          # a chunk ran inside the step: one integer more
+        return spans[s["parent_id"]]["attributes"]["chunk_tokens"] > 0
     assert steps and all(
-        s["attributes"]["bytes"] == 4 * 4 + 4 * s["attributes"]["first_tokens"]
+        s["attributes"]["bytes"]
+        == 4 * (4 + rode(s)) + 4 * s["attributes"]["first_tokens"]
         for s in steps)
+    assert sum(map(rode, steps)) == st["chunks_in_step"]
     # ... but for a first token read INSIDE a step's fetch: the step was
     # dispatched behind the chunk before anyone waited for the chunk.
-    # The read is that span's own (``first_tokens``), no span inside it
-    assert sum(s["attributes"]["first_tokens"] for s in steps) >= 1
+    # The read is that span's own (``first_tokens``), no span inside it.
+    # A prompt that ended inside the step owes no read of its own
+    if fused:         # the last chunk of every pass that had a chunk
+        assert 1 <= st["chunks_in_step"] < st["chunk_passes"]
+    else:
+        assert st["chunks_in_step"] == 0
+        assert sum(s["attributes"]["first_tokens"] for s in steps) >= 1
     assert not [s for s in spans.values() if s["name"] == "engine.fetch"
                 and spans.get(s["parent_id"], {}).get("name") == "engine.fetch"]
     # and no chunk waited for its own token while a row decoded
@@ -187,20 +203,23 @@ def test_row_preempted_between_its_chunk_and_the_step_re_prefills(
     prompt ended in that pass's chunk (the youngest): its first token is
     then neither read nor emitted, it re-prefills and streams exactly."""
     eng = _engine(params, cfg)
+    # the pass of two programs (where ONE runs both, the hunt comes
+    # before the chunk is packed: tests/test_engine_fused_pass.py)
+    eng._step_chunk = None
     rng = np.random.default_rng(5)
     long_ = rng.integers(0, cfg.vocab_size, 6).tolist()
     late = rng.integers(0, cfg.vocab_size, 13).tolist()
     took = []
     sound = eng._paged_decode_iteration
 
-    def hunted():
+    def hunted(ride=None):
         # what ``_grow_row`` -> ``_take_block`` does when the pool is dry
         if eng._first_pending and not took:
             row, req = eng._first_pending[0][:2]
             assert not req.tokens           # nothing emitted yet
             took.append(req)
             eng._preempt_row(row)
-        sound()
+        sound(ride)
     eng._paged_decode_iteration = hunted
     try:
         first = eng.submit(long_, max_new=30)

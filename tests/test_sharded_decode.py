@@ -259,7 +259,10 @@ def test_sharded_parity_speculative_self(cfg, params, mesh2):
 # ------------------------------------- the programs' own greedy tokens
 
 def test_in_program_argmax_over_sharded_logits_dp_tp(cfg, params):
-    """dp2 x tp2: the decode step's and the chunk's greedy tokens are
+    """dp2 x tp2: the decode step's and the chunk's greedy tokens, and
+    those of the ONE program that runs both in a pass that holds both
+    (ISSUE 41: its window is one batch row, its one-token kernel runs
+    per shard on the rows' queries), are
     taken inside the program, over logits whose vocabulary is split
     across the tp shards, and equal the host's argmax of the gathered
     logits, ties included.  Every vocabulary row has a twin in the
@@ -275,7 +278,7 @@ def test_in_program_argmax_over_sharded_logits_dp_tp(cfg, params):
     twins = {**params, "wte": wte.at[half:].set(wte[:half])}
     eng = InferenceEngine(twins, cfg, EngineConfig(
         max_slots=4, kv_block_size=8, prefill_chunk=8), mesh=mesh)
-    seen = {"step": 0, "chunk": 0}
+    seen = {"step": 0, "chunk": 0, "step_chunk": 0}
 
     def checked(kind, program):
         def run(params_, k, v, packed):
@@ -292,8 +295,8 @@ def test_in_program_argmax_over_sharded_logits_dp_tp(cfg, params):
             seen[kind] += 1
             return logits, greedy, k, v
         return run
-    eng._step = checked("step", eng._step)
-    eng._chunk = checked("chunk", eng._chunk)
+    for kind in seen:
+        setattr(eng, "_" + kind, checked(kind, getattr(eng, "_" + kind)))
     try:
         rng = np.random.default_rng(2)
         jobs = [(p := rng.integers(0, cfg.vocab_size, n).tolist(),
@@ -306,7 +309,10 @@ def test_in_program_argmax_over_sharded_logits_dp_tp(cfg, params):
         assert st["tokens_greedy_on_device"] == 18
     finally:
         eng.shutdown()
-    assert seen["step"] >= 5 and seen["chunk"] == 1 + 2 + 3
+    # a pass's last chunk rode the step wherever a row was decoding
+    assert seen["step"] + seen["step_chunk"] >= 5
+    assert seen["chunk"] + seen["step_chunk"] == 1 + 2 + 3
+    assert seen["step_chunk"] == st["chunks_in_step"] >= 1
 
 
 # ----------------------------------------------------------- MoE decode
