@@ -4,9 +4,11 @@ vLLM's insight (PagedAttention) is that serving memory must be bounded
 by a PREALLOCATED pool handed out in fixed-size units and reclaimed on
 sequence exit — never grown per request.
 
-``BlockPool`` is that pool: fixed-size TOKEN BLOCKS (two arrays stored
-as ``PoolLayout`` defines, once, for every program that reads or writes
-them), a per-request BLOCK TABLE mapping sequence positions to blocks,
+``BlockPool`` is that pool: fixed-size TOKEN BLOCKS (two arrays, K and
+V — or ONE, for a model whose values are a view of its keys: latent
+attention caches one vector a token — stored as ``PoolLayout`` defines,
+once, for every program that reads or writes them), a per-request
+BLOCK TABLE mapping sequence positions to blocks,
 and per-block REFCOUNTS so blocks are shared across requests (prefix
 reuse) with copy-on-write on a shared partially-filled tail.  The
 decode step stays compiled-once because the table width and batch width
@@ -88,6 +90,12 @@ class PoolLayout:
     over the heads axis (``POOL_AXES``): each shard holds whole heads,
     then its own padding lanes.  Padding lanes are written as zeros
     and never read.
+
+    ``value_lanes``: None where keys and values are two such pools.
+    An int where there is ONE pool (the model's ``value_lanes``): a
+    token is one head of ``head_dim`` lanes shared by every query head
+    (a latent and a rotated key), and its values are its first
+    ``value_lanes`` lanes — no second array exists.
     """
     n_layers: int
     n_rows: int             # blocks per layer, scratch block included
@@ -95,6 +103,7 @@ class PoolLayout:
     n_heads: int
     head_dim: int
     shards: int = 1         # heads_shards(mesh, rules)
+    value_lanes: Optional[int] = None
 
     @classmethod
     def of(cls, cfg, pool: jax.Array, shards: int = 1) -> "PoolLayout":
@@ -104,7 +113,7 @@ class PoolLayout:
         query heads under grouped queries) and the head size."""
         layers, heads, head_dim = cfg.kv_geometry
         lay = cls(layers, pool.shape[0] // layers, pool.shape[1], heads,
-                  head_dim, shards)
+                  head_dim, shards, cfg.value_lanes)
         if lay.shape != pool.shape:
             raise ValueError(f"pool {pool.shape} is not a {lay.shape} "
                              f"pool of {layers} layers x "
@@ -189,12 +198,12 @@ class PoolLayout:
 # donate the pools: a block chain moves, never the pool
 
 
-@partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
-def _copy_block(lay: PoolLayout, pool_k, pool_v, src, dst):
-    """Both pools <- block src at dst, every layer (copy-on-write)."""
+@partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+def _copy_block(lay: PoolLayout, pools: tuple, src, dst):
+    """Every pool <- block src at dst, every layer (copy-on-write)."""
     layers = jnp.arange(lay.n_layers)
     s, d = lay.rows(layers, src), lay.rows(layers, dst)
-    return pool_k.at[d].set(pool_k[s]), pool_v.at[d].set(pool_v[s])
+    return tuple(p.at[d].set(p[s]) for p in pools)
 
 
 @partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2))
@@ -234,9 +243,12 @@ class BlockPool:
 
     The K and V arrays are stored as ``PoolLayout`` says
     (``self.layout``; ``[n_layers * (n_blocks + 1), block_size,
-    width]`` each) — block id 0 is the reserved scratch block (never
-    allocated; inactive/out-of-range writes in the compiled step are
-    redirected there), usable blocks are ids ``1..n_blocks``.
+    width]`` each; ``self.pools`` is the tuple of them that a program is
+    handed: ``(k, v)``, or ``(k,)`` alone where the layout's
+    ``value_lanes`` says the values are the keys' first lanes, and
+    ``self.v`` is then None) — block id 0 is the reserved scratch block
+    (never allocated; inactive/out-of-range writes in the compiled step
+    are redirected there), usable blocks are ids ``1..n_blocks``.
     ``read_blocks`` / ``write_blocks_at`` speak the interchange format
     ``[L, T, n_heads, block_size, head_dim]`` and convert at the
     boundary.
@@ -293,9 +305,9 @@ class BlockPool:
                 f"the pool shards the heads dim evenly per device")
         self.layout = PoolLayout(kv_layers, self.n_blocks + 1,
                                  self.block_size, kv_heads, head_dim,
-                                 shards)
+                                 shards, cfg.value_lanes)
         self.k = self._zeros()
-        self.v = self._zeros()
+        self.v = self._zeros() if self.layout.value_lanes is None else None
         # the second kind of state: what a model's recurrent layers keep
         # per decode row, beside the row's blocks (None for a model whose
         # whole past is K/V)
@@ -392,10 +404,16 @@ class BlockPool:
 
     # ------------------------------------------------------------- arrays
 
+    @property
+    def pools(self) -> tuple:
+        """The pool arrays a program is handed: ``(k, v)``, or ``(k,)``
+        where the values are a view of the keys."""
+        return (self.k,) if self.v is None else (self.k, self.v)
+
     def copy_block(self, src: int, dst: int) -> None:
-        """Copy-on-write: duplicate src's K/V into dst (both pools)."""
-        self.k, self.v = _copy_block(self.layout, self.k, self.v,
-                                     jnp.int32(src), jnp.int32(dst))
+        """Copy-on-write: duplicate src's K/V into dst (every pool)."""
+        self.swap(*_copy_block(self.layout, self.pools, jnp.int32(src),
+                               jnp.int32(dst)))
 
     def read_blocks(self, ids) -> tuple:
         """Gather a block chain's K/V to host arrays — the EXPORT side
@@ -403,6 +421,7 @@ class BlockPool:
         ``[L, T, h, bs, hd]`` each (T = len(ids)), fully replicated
         host-side so the bytes can ride the object plane regardless of
         the holder's mesh layout."""
+        self._two_pools("read_blocks")
         t = jnp.asarray(list(ids), jnp.int32)
         k, v = jax.device_get(_gather_blocks(self.layout, self.k,
                                              self.v, t))
@@ -415,6 +434,7 @@ class BlockPool:
         exclusively (refcount 1, just alloc'd), so no CoW is needed;
         with a mesh the ``.at[].set`` lands sharded through the pool's
         own sharding."""
+        self._two_pools("write_blocks_at")
         t = jnp.asarray(list(ids), jnp.int32)
         lay = self.layout
         chain = (lay.n_layers, t.shape[0], lay.n_heads, lay.block_size,
@@ -432,6 +452,7 @@ class BlockPool:
         S may be shorter than the table span (zero-padded right);
         unowned table entries point at the scratch block, whose garbage
         the kv-length masks hide."""
+        self._two_pools("write_prefill")
         span = self.blocks_per_seq * self.block_size
         s = k_new.shape[2]
         if s < span:
@@ -442,8 +463,17 @@ class BlockPool:
                                        jnp.asarray(table, jnp.int32),
                                        k_new, v_new)
 
-    def swap(self, k: jax.Array, v: jax.Array) -> None:
-        """Install the compiled step's updated pool arrays."""
+    def _two_pools(self, what: str) -> None:
+        """The interchange format ``[L, T, h, bs, hd]`` is a K and a V
+        of head lanes: a pool of latents has none yet."""
+        if self.v is None:
+            raise NotImplementedError(
+                f"{what}: no interchange format for a pool whose values "
+                f"are a view of its keys")
+
+    def swap(self, k: jax.Array, v: Optional[jax.Array] = None) -> None:
+        """Install the compiled step's updated pool arrays
+        (``*self.pools`` as a program returned them)."""
         self.k, self.v = k, v
 
     def reset(self) -> None:
@@ -457,7 +487,7 @@ class BlockPool:
         included — recovery must restore the same layout the compiled
         steps donate-commit into."""
         self.k = self._zeros()
-        self.v = self._zeros()
+        self.v = self._zeros() if self.layout.value_lanes is None else None
         if self.state is not None:
             self.state.reset()
         with self._lock:
@@ -468,10 +498,11 @@ class BlockPool:
     # ------------------------------------------------------------- stats
 
     def bytes_total(self) -> int:
-        """Bytes of both pools as stored, padding lanes included, and of
+        """Bytes of the pools as stored, padding lanes included, and of
         the recurrent-state pool where there is one."""
         itemsize = np.dtype(jnp.zeros((), self.dtype).dtype).itemsize
-        return (2 * int(np.prod(self.layout.shape)) * itemsize
+        return (len(self.pools) * int(np.prod(self.layout.shape))
+                * itemsize
                 + self.state_bytes())
 
     def state_bytes(self) -> int:

@@ -21,7 +21,12 @@ reads the blocks that hold a key, no other
 prefill, verify) give a mask, gather their rows' tables and attend them
 as stored (ops/attention.packed_attention).  What a program states
 itself is what differs: the index arithmetic of its window (dead lanes
-go to the scratch block), the embedding, the head.
+go to the scratch block), the embedding, the head.  ``latent_attend`` is
+the same meeting place for a model whose attention layers cache ONE
+latent a token (the hybrid family's latent layout, recurrent.py): one
+pool, the one-token form with the up-projection absorbed
+(ops/attention.latent_decode_attention), the window form a block of
+decompressed keys at a time (latent_window_attention).
 
   * chunk_prefill — a fixed-width window of ONE prompt ([C] tokens at
     positions start..start+C), each query row masked to its OWN causal
@@ -102,7 +107,9 @@ from jax import lax
 from ray_tpu.inference.cache import POOL_AXES, PoolLayout, heads_shards
 from ray_tpu.models import gpt
 from ray_tpu.models.gpt import GPTConfig
-from ray_tpu.ops.attention import (_per_shard, packed_attention,
+from ray_tpu.ops.attention import (KEY_BLOCK, _per_shard,
+                                   latent_decode_attention,
+                                   latent_window_attention, packed_attention,
                                    paged_decode_attention)
 from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES, Rules, spec_for
 
@@ -252,6 +259,63 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
             return jnp.concatenate(
                 [o.transpose(2, 1, 0, 3),
                  window(q[:, :, n:], layer, mask_tables)], axis=2)
+        return attend
+    return attend_for, held
+
+
+def latent_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
+                  scale: float, kv_lengths=None, q_pos=None):
+    """``paged_attend`` for a model whose attention layers keep ONE
+    latent a token (``lay``: one head of ``kv_rank + rope`` lanes, the
+    values its first ``lay.value_lanes``; ``pools``: the one pool):
+    ``attend_for(layer)`` gives ``attend(q_nope [b, w, h, dn], q_rope
+    [b, w, h, dr], latent [b, w, kv_rank + dr], w_uk [h, kv_rank, dn],
+    w_uv [h, kv_rank, dv]) -> o [b, w, h * dv]``, which commits the
+    window's latents at ``(blocks, offsets)`` and attends the committed
+    pool:
+
+      * ``kv_lengths`` [b] — ONE token a row (w = 1): the up-projection
+        absorbed, ``q_lat = q_nope W_uk^T`` and ``o = (P c_kv) W_uv``,
+        through the kernel that walks each live row's table over the
+        pool as stored (``latent_decode_attention``);
+      * ``q_pos`` [w] — ONE row's window (b = 1) at those positions:
+        the table walked ``KEY_BLOCK`` keys at a time, each block
+        gathered, decompressed and attended under a running softmax
+        (``latent_window_attention``); no array of the table's span."""
+    held = {"pools": pools}
+    rank, bs = lay.value_lanes, lay.block_size
+
+    def attend_for(layer):
+        def attend(q_nope, q_rope, latent, w_uk, w_uv):
+            pool, = held["pools"]
+            pool = lay.commit(pool, layer, blocks, offsets, latent.reshape(
+                *blocks.shape, 1, latent.shape[-1]))
+            held["pools"] = (pool,)
+            if kv_lengths is not None:
+                q_lat = jnp.einsum("bhd,hcd->bhc", q_nope[:, 0], w_uk)
+                q = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)
+                q = jnp.pad(q, [(0, 0), (0, 0),
+                                (0, lay.width - q.shape[-1])])
+                o_lat = latent_decode_attention(
+                    q, pool, lay.rows(layer, 0), tables, kv_lengths,
+                    value_lanes=rank, scale=scale)
+                o = jnp.einsum("bhc,hcd->bhd", o_lat, w_uv)
+                return o.reshape(o.shape[0], 1, -1)
+            # whole key blocks of the table, the last one padded with
+            # the scratch block (its keys lie past every query)
+            per = KEY_BLOCK // bs if KEY_BLOCK % bs == 0 else 1
+            table = jnp.pad(tables[0], (0, -tables.shape[1] % per))
+
+            def read_keys(j, n):
+                ids = lax.dynamic_slice_in_dim(table, j * per, per)
+                return pool[lay.rows(layer, ids)].reshape(n, lay.width)
+
+            span = table.shape[0] // per
+            return latent_window_attention(
+                q_nope[0], q_rope[0], read_keys, w_uk, w_uv, q_pos,
+                scale=scale, key_block=per * bs,
+                n_blocks=jnp.minimum(jnp.max(q_pos) // (per * bs) + 1,
+                                     span))[None]
         return attend
     return attend_for, held
 

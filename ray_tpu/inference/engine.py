@@ -327,7 +327,7 @@ class GenerationRequest:
 # holds its engine weakly, see _engine_loop)
 def init_params_for(cfg, key):
     """Seeded parameters of ``cfg``'s model family."""
-    family = hybrid if cfg.state_geometry is not None else gpt
+    family = hybrid if isinstance(cfg, hybrid.HybridConfig) else gpt
     return family.init_params(cfg, key)
 
 
@@ -461,26 +461,36 @@ class _KVOnly(_Seam):
 
 
 class _KVAndState(_Seam):
-    """The seam for a model that also keeps a recurrent state per row
-    (models/hybrid.py, the programs of recurrent.py): its programs carry
-    the state pool beside the K/V pools and report the routed experts'
-    load."""
+    """The seam for the hybrid family (models/hybrid.py, the programs of
+    recurrent.py): its programs carry the block pools the model's
+    attention layers keep (K and V, or the one latent pool) and, where
+    the model has recurrent layers, the state pool beside them, and
+    report the routed experts' load.  What follows from a recurrent
+    state — no prefix index, re-prefill after preemption — the engine
+    derives from ``cfg.state_geometry``, not from the family: a model
+    of this family without recurrent layers keeps its whole past in
+    blocks, and is served with both."""
 
     N_LOAD = hybrid.N_LOAD
 
     @staticmethod
-    def refuse(ec: "EngineConfig", mesh) -> None:
-        """What a recurrent state makes impossible today, refused at
-        construction: speculation (a rejected draft cannot be rolled
-        back out of a state), a mesh."""
+    def refuse(cfg, ec: "EngineConfig", mesh) -> None:
+        """What the family's programs have no form for today, refused
+        at construction: speculation (no verify program; and a rejected
+        draft cannot be rolled back out of a recurrent state), a
+        mesh."""
+        recurrent = cfg.state_geometry is not None
         if ec.speculate is not None:
             raise SpeculationUnsupported(
                 "speculative decoding needs a cache that can roll "
                 "rejected tokens back; a recurrent state cannot (no "
-                "snapshot at the draft's start yet)")
+                "snapshot at the draft's start yet)" if recurrent else
+                "the hybrid family has no verify program yet")
         if mesh is not None:
-            raise ValueError("a model with recurrent layers is served "
-                             "on one device (no sharding rules yet)")
+            raise ValueError(
+                ("a model with recurrent layers" if recurrent else
+                 "the hybrid family") + " is served on one device (no "
+                "sharding rules yet)")
 
     @staticmethod
     def serve(params, cfg):
@@ -508,10 +518,12 @@ class _KVAndState(_Seam):
     @staticmethod
     def run(eng, program, packed):
         st = eng.pool.state
-        logits, load, k, v, conv, ssm = program(
-            eng.params, eng.pool.k, eng.pool.v, st.conv, st.ssm, packed)
-        eng.pool.swap(k, v)
-        st.swap(conv, ssm)
+        logits, load, pools, state = program(
+            eng.params, eng.pool.pools,
+            () if st is None else (st.conv, st.ssm), packed)
+        eng.pool.swap(*pools)
+        if st is not None:
+            st.swap(*state)
         # every program's load stays on the device until the next
         # decode pass fetches them all
         eng._load.append(load)
@@ -528,11 +540,13 @@ class _KVAndState(_Seam):
 
     @staticmethod
     def row_admitted(eng, row) -> None:
-        eng.pool.state.admit(row)
+        if eng.pool.state is not None:
+            eng.pool.state.admit(row)
 
     @staticmethod
     def row_released(eng, row) -> None:
-        eng.pool.state.release(row)
+        if eng.pool.state is not None:
+            eng.pool.state.release(row)
 
 
 class InferenceEngine:
@@ -559,15 +573,18 @@ class InferenceEngine:
         self._rules = rules
         # THE seam between the scheduler and a model family: what
         # programs a pass runs and what pools they carry follows from
-        # what the model keeps of a row's past — K/V blocks alone
-        # (models/gpt.py), or K/V blocks for its attention layers and a
-        # recurrent state for the others (models/hybrid.py,
-        # ``cfg.state_geometry``).  Chosen once, here; a pass calls
-        # ``self._seam``'s functions and branches on nothing.
+        # the family (models/gpt.py: K/V blocks alone; models/hybrid.py:
+        # blocks for its attention layers, a recurrent state for its
+        # state-space layers, either or both).  Chosen once, here; a
+        # pass calls ``self._seam``'s functions and branches on nothing.
+        # What a row's past IS follows from the model, not the family:
+        # with a recurrent state (``cfg.state_geometry``) a prefix is
+        # more than its blocks.
         recurrent = cfg.state_geometry is not None
-        self._seam = _KVAndState if recurrent else _KVOnly
-        if recurrent:
-            _KVAndState.refuse(ec, mesh)
+        hybrid_family = isinstance(cfg, hybrid.HybridConfig)
+        self._seam = _KVAndState if hybrid_family else _KVOnly
+        if hybrid_family:
+            _KVAndState.refuse(cfg, ec, mesh)
         # what the programs are handed every pass; the engine keeps no
         # reference to a leaf that ``serve`` replaced
         self.params, axes, cast = self._seam.serve(params, cfg)
@@ -656,6 +673,7 @@ class InferenceEngine:
         self._occupancy_sum = 0.0      # Σ active/max_slots per iteration
         self._prefix_hit_tokens = 0
         self._prefix_lookup_tokens = 0
+        self._prefix_blocks_adopted = 0    # blocks taken over from the index
         self._preemptions = 0
         # written by the loop thread alone, so without the lock:
         self._admissions = 0           # requests given a row
@@ -668,6 +686,11 @@ class InferenceEngine:
         # layer) and the rows' whole tables (what a gather would read)
         self._kv_blocks_attended = 0
         self._kv_blocks_tabled = 0
+        # per prefill chunk: the keys in reach of its window (what a
+        # window form must read of the row's past: position + tokens)
+        # and the (query, key) pairs under the causal mask
+        self._chunk_keys = 0
+        self._chunk_query_keys = 0
         self._peak_active = 0
         self._spec_drafted = 0         # drafted tokens offered to verify
         self._spec_accepted = 0        # drafted tokens accepted
@@ -1041,6 +1064,7 @@ class InferenceEngine:
         self._admissions += 1
         with self._mlock:
             self._prefix_hit_tokens += hit
+            self._prefix_blocks_adopted += len(ids)
             self._prefix_lookup_tokens += n_prompt
             self._peak_active = max(self._peak_active, occupied)
         return True
@@ -1226,6 +1250,7 @@ class InferenceEngine:
                     # the prompt was counted at admission; fold in only
                     # the INCREMENTAL tokens the re-match won
                     self._prefix_hit_tokens += hit2 - pos
+                    self._prefix_blocks_adopted += len(ids2) - pos // bs
                 pos = self._prefilling[row] = hit2
             else:
                 for bid in ids2:
@@ -1268,6 +1293,8 @@ class InferenceEngine:
         req.chunk_passes += 1
         self._chunk_passes += 1
         self._prefill_tokens += n_q
+        self._chunk_keys += pos + n_q
+        self._chunk_query_keys += n_q * pos + n_q * (n_q + 1) // 2
         chunk_toks = np.zeros(C, np.int32)
         chunk_toks[:n_q] = prompt[pos:pos + n_q]
         with self._acct.phase("pack") as up:
@@ -1962,6 +1989,9 @@ class InferenceEngine:
         ReplicaDeadError shapes the adopter maps to local recompute."""
         if self.trie is None:
             raise PrefixUnavailable("engine has no prefix index")
+        if self.pool.v is None:
+            raise PrefixUnavailable("a pool whose values are a view of its "
+                                    "keys has no interchange format yet")
         toks = np.asarray(list(tokens), np.int32)
         bs = self.pool.block_size
         n = int(toks.size)
@@ -2004,6 +2034,9 @@ class InferenceEngine:
         optimization; real work is not)."""
         if self.trie is None:
             raise PrefixUnavailable("engine has no prefix index")
+        if self.pool.v is None:
+            raise PrefixUnavailable("a pool whose values are a view of its "
+                                    "keys has no interchange format yet")
         toks = np.asarray(list(tokens), np.int32)
         bs = self.pool.block_size
         n = int(toks.size)
@@ -2100,6 +2133,8 @@ class InferenceEngine:
             "prefill_tokens": prefill_tokens,
             "kv_blocks_attended": kv_attended,
             "kv_blocks_tabled": kv_tabled,
+            "chunk_keys": self._chunk_keys,
+            "chunk_query_keys": self._chunk_query_keys,
             # decode and first tokens by where they were chosen: by a
             # program's own argmax (the integers a pass fetches), or by
             # a dispatch of their own on the logits (a sampled row, a
@@ -2166,6 +2201,7 @@ class InferenceEngine:
             "prefix_cached_blocks": (self.trie.cached_blocks
                                      if self.trie is not None else 0),
             "prefix_hit_tokens": hit_toks,
+            "prefix_blocks_adopted": self._prefix_blocks_adopted,
             "prefix_lookup_tokens": lookup_toks,
             "prefix_hit_rate": (hit_toks / lookup_toks
                                 if lookup_toks else 0.0),
@@ -2202,8 +2238,10 @@ def metrics_snapshot() -> list:
         engines = dict(_ENGINES)
     active, waiting, occ, gen, comp = {}, {}, {}, {}, {}
     butil, phit, pcached, preempt = {}, {}, {}, {}
+    padopt, phtok = {}, {}
     admits, chunks, in_step, ptoks = {}, {}, {}, {}
     kv_att, kv_tab = {}, {}
+    ckeys, cqkeys = {}, {}
     on_dev, sampled, fbytes = {}, {}, {}
     tps, arate, saccept = {}, {}, {}
     meshdev, tpsh = {}, {}
@@ -2227,6 +2265,8 @@ def metrics_snapshot() -> list:
         butil[key] = float(st.get("block_utilization", 0.0))
         phit[key] = float(st.get("prefix_hit_rate", 0.0))
         pcached[key] = float(st.get("prefix_cached_blocks", 0))
+        padopt[key] = float(st.get("prefix_blocks_adopted", 0))
+        phtok[key] = float(st.get("prefix_hit_tokens", 0))
         preempt[key] = float(st.get("preemptions", 0))
         # the prefill side of the load: prefill against generated tokens
         # says which of the two a replica's passes go to, chunk passes
@@ -2237,6 +2277,8 @@ def metrics_snapshot() -> list:
         ptoks[key] = float(st["prefill_tokens"])
         kv_att[key] = float(st["kv_blocks_attended"])
         kv_tab[key] = float(st["kv_blocks_tabled"])
+        ckeys[key] = float(st["chunk_keys"])
+        cqkeys[key] = float(st["chunk_query_keys"])
         on_dev[key] = float(st["tokens_greedy_on_device"])
         sampled[key] = float(st["tokens_sampled"])
         fbytes[key] = float(st["fetch_bytes"])
@@ -2286,6 +2328,12 @@ def metrics_snapshot() -> list:
          "tokens seen", phit or zero),
         ("ray_tpu_inference_prefix_cached_blocks", "gauge",
          "Blocks held by the radix prefix index", pcached or zero),
+        ("ray_tpu_inference_prefix_hit_tokens_total", "counter",
+         "Prompt tokens served from blocks adopted from the radix prefix "
+         "index (no prefill program ran them)", phtok or zero),
+        ("ray_tpu_inference_prefix_blocks_adopted_total", "counter",
+         "Blocks taken over from the radix prefix index by admissions and "
+         "re-matches", padopt or zero),
         ("ray_tpu_inference_preemptions_total", "counter",
          "Requests requeued by block-pressure preemption", preempt or zero),
         ("ray_tpu_inference_admissions_total", "counter",
@@ -2306,6 +2354,12 @@ def metrics_snapshot() -> list:
         ("ray_tpu_inference_kv_blocks_tabled_total", "counter",
          "Block-table entries of all rows, summed over one-token decode "
          "passes (what a whole-table gather reads)", kv_tab or zero),
+        ("ray_tpu_inference_chunk_keys_total", "counter",
+         "Keys in reach of prefill chunks' windows (position + tokens, "
+         "summed over chunk passes)", ckeys or zero),
+        ("ray_tpu_inference_chunk_query_keys_total", "counter",
+         "(query, key) pairs under the causal mask, summed over prefill "
+         "chunk passes", cqkeys or zero),
         ("ray_tpu_inference_tokens_greedy_on_device_total", "counter",
          "Decode and first tokens chosen by a serving program's own "
          "argmax (a pass fetches the integers, not the logits)",

@@ -1,30 +1,38 @@
-"""Paged serving programs of a model with recurrent layers: the decode
-step and the chunk-prefill window of ``models/hybrid.py``.
+"""Paged serving programs of the hybrid family: the decode step and the
+chunk-prefill window of ``models/hybrid.py``.
 
 Both are the model's ONE layer function (``hybrid.run_layers`` over
 ``hybrid.block``) at a serving shape — ``[rows, 1]`` and ``[1,
-chunk]`` — with the past supplied from the two kinds of pool the cache
-manager owns (inference/cache.py):
+chunk]`` — with the past supplied from the pools the cache manager owns
+(inference/cache.py), whichever of them the model's layers keep:
 
   * an attention layer's keys come from the K/V ``BlockPool`` through
     the row's block table through the ``attend`` decode.py's GPT
     programs use (``decode.paged_attend``: commit the window's K/V,
     then gather the table; ``packed_attention`` attends the rows as
     stored, grouped queries sharing their K/V head's lanes);
+  * a latent attention layer's come from the ONE latent pool
+    (``decode.latent_attend``: commit the window's latents, then the
+    one-token kernel with the up-projection absorbed, or the window
+    form that decompresses a block of keys at a time), at rotary
+    positions the program states;
   * a Mamba layer's convolution and SSM state come from the
     ``StatePool`` at the window's decode rows and go back there.
 
-All four pool arrays are donated and updated in place.  Besides the
-logits each program returns ``load`` int32 — held expert assignments,
-all assignments, the busiest held expert's assignments and the held
-experts touched, summed over the experts sublayers, of the real tokens
-only (``hybrid.run_layers``), then the greedy tokens (the argmax of
-the logits, taken on the device): every row's from the decode step, the
-last real position's from the chunk.  A greedy pass then fetches ``4 +
-rows`` integers and a greedy first token one vector of 5; the ``[rows,
-vocab]`` logits never leave the device (12.8 MB a pass at 64 rows x
-50,176, to the host and back for the argmax: 8 of the 13.5 ms of host
-time a pass that the first chip runs of PR 29 read).
+A program takes ``pools``, the tuple of block-pool arrays
+(``BlockPool.pools``: K and V, or the one latent pool), and ``state``,
+the tuple of state arrays (``(conv, ssm)``, or ``()`` for a model
+without recurrent layers), all donated and updated in place.  Besides
+the logits each program returns ``load`` int32 — held expert
+assignments, all assignments, the busiest held expert's assignments and
+the held experts touched, summed over the experts sublayers, of the
+real tokens only (``hybrid.run_layers``), then the greedy tokens (the
+argmax of the logits, taken on the device): every row's from the decode
+step, the last real position's from the chunk.  A greedy pass then
+fetches ``4 + rows`` integers and a greedy first token one vector of 5;
+the ``[rows, vocab]`` logits never leave the device (12.8 MB a pass at
+64 rows x 50,176, to the host and back for the argmax: 8 of the 13.5 ms
+of host time a pass that the first chip runs of PR 29 read).
 
 What the host sends a pass is ONE int32 array (``decode.pack_step`` /
 ``pack_chunk``), handed to the program as the fresh numpy array it is:
@@ -36,9 +44,9 @@ decode.py's GPT programs exchange the same with the host — the packed
 array in, the greedy tokens out, the logits left on the device — so the
 engine's pass is ONE host algorithm for both families
 (``InferenceEngine._fetch_step`` / ``_emit_first`` / ``_pass_done``).
-What these two add is the state pools they carry and the ``load``
-counts in front of the greedy tokens (``hybrid.N_LOAD`` of them).  The
-jitted functions are named ``step`` and ``chunk_fn`` like decode.py's (a
+What these two add is the state they carry and the ``load`` counts in
+front of the greedy tokens (``hybrid.N_LOAD`` of them).  The jitted
+functions are named ``step`` and ``chunk_fn`` like decode.py's (a
 device trace's program names are ``jit_step`` / ``jit_chunk_fn`` for
 either model family).  No mesh: one device holds one chip's share of the
 deployment (experts over an ``ep`` axis are future work).
@@ -52,8 +60,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.inference.cache import PoolLayout
-from ray_tpu.inference.decode import (_cached, paged_attend, unpack_chunk,
-                                      unpack_step)
+from ray_tpu.inference.decode import (_cached, latent_attend, paged_attend,
+                                      unpack_chunk, unpack_step)
 from ray_tpu.models import hybrid
 from ray_tpu.models.hybrid import HybridConfig
 
@@ -62,10 +70,10 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
                                n_table: int):
     """jitted one-token step over the whole row batch.
 
-    (params, k_pool, v_pool, conv, ssm, packed [b, T + 3] int32
+    (params, pools, state, packed [b, T + 3] int32
      (``pack_step``: tables | tokens | positions | active))
         -> (logits [b, vocab] f32, load + greedy [4 + b] int32,
-            k_pool, v_pool, conv, ssm)
+            pools, state)
 
     Decode row r's state is row r of the state arrays.  An inactive row
     (free, or still prefilling) is a window of 0 real tokens: its K/V
@@ -74,24 +82,23 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
     bs, T = int(block_size), int(n_table)
 
     def build():
-        @partial(jax.jit, donate_argnums=(1, 2, 3, 4))
-        def step(params, k_pool, v_pool, conv, ssm, packed):
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def step(params, pools, state, packed):
             tables, tokens, positions, active = unpack_step(packed, T)
             b = tokens.shape[0]
-            lay = PoolLayout.of(cfg, k_pool)
+            lay = PoolLayout.of(cfg, pools[0])
             rows = jnp.arange(b)
             bidx = jnp.where(active, tables[rows, positions // bs], 0)
             off = jnp.where(active, positions % bs, 0)
             kv_len = jnp.where(active, positions + 1, 0)      # 0: sits out
-            attend_for, kv = paged_attend(
-                lay, (k_pool, v_pool), bidx, off, tables,
-                q_per_kv=cfg.n_heads // cfg.n_kv_heads,
-                scale=cfg.attention_multiplier, kv_lengths=kv_len)
-            state = {"conv": [], "ssm": ssm}
+            attend_for, kv = _attend_over(
+                cfg, lay, pools, bidx, off, tables, kv_lengths=kv_len)
+            conv, ssm = state or (None, None)
+            held = {"conv": [], "ssm": ssm}
 
             def state_out(mi, new):
-                state["conv"].append(new[0])
-                state["ssm"] = new[1][0]
+                held["conv"].append(new[0])
+                held["ssm"] = new[1][0]
 
             # The SSM state goes in as the whole pool and the layer's
             # index: the one-token form advances the live rows' state
@@ -104,53 +111,73 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
             x, load = hybrid.run_layers(
                 cfg, params, hybrid.embed(cfg, params, tokens[:, None]),
                 active.astype(jnp.int32),
-                state_in=lambda mi: (conv[mi], (state["ssm"], mi)),
-                state_out=state_out, attend_for=attend_for)
+                state_in=lambda mi: (conv[mi], (held["ssm"], mi)),
+                state_out=state_out, attend_for=attend_for,
+                positions=positions[:, None])
             logits = hybrid.head(cfg, params, x[:, 0])
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (logits, jnp.concatenate([load, greedy]), *kv["pools"],
-                    jnp.stack(state["conv"]), state["ssm"])
+            state = (jnp.stack(held["conv"]), held["ssm"]) if state else ()
+            return (logits, jnp.concatenate([load, greedy]), kv["pools"],
+                    state)
 
         return step
 
     return _cached(("recurrent_step", bs, T), cfg, None, None, build)
 
 
+def _attend_over(cfg, lay, pools, blocks, offsets, tables, *,
+                 kv_lengths=None, q_pos=None):
+    """``paged_attend`` or ``latent_attend``, as the model's attention
+    layers keep K/V heads or one latent: ONE token a row that attends
+    its first ``kv_lengths`` keys, or one row's window of queries at
+    positions ``q_pos`` [w], each over the keys up to its own."""
+    if cfg.value_lanes is not None:
+        return latent_attend(lay, pools, blocks, offsets, tables,
+                             scale=cfg.attention_multiplier,
+                             kv_lengths=kv_lengths, q_pos=q_pos)
+    mask = None
+    if q_pos is not None:
+        S = tables.shape[-1] * lay.block_size
+        mask = (jnp.arange(S)[None, :] <= q_pos[:, None])[None, None]
+    return paged_attend(lay, pools, blocks, offsets, tables,
+                        q_per_kv=cfg.n_heads // cfg.n_kv_heads,
+                        scale=cfg.attention_multiplier,
+                        kv_lengths=kv_lengths, mask=mask)
+
+
 def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
                             block_size: int, n_table: int):
     """jitted fixed-width prefill chunk of ONE row.
 
-    (params, k_pool, v_pool, conv, ssm, packed [T + C + 3] int32
+    (params, pools, state, packed [T + C + 3] int32
      (``pack_chunk``: table | tokens | start, row, n_valid))
         -> (logits [C, vocab] f32, load + greedy [5] int32,
-            k_pool, v_pool, conv, ssm)
+            pools, state)
 
     Prompt positions ``start .. start + n_valid`` of decode row ``row``:
     attention as decode.py's chunk program (each query masked to its own
-    causal horizon over the gathered table), the Mamba layers as one
-    window from the row's state, which advances by the ``n_valid`` real
-    tokens only — the padding of a partial last chunk is the identity
-    on it.
+    causal horizon over the gathered table; a latent layer walks the
+    table a block of keys at a time and gathers no table), the Mamba
+    layers as one window from the row's state, which advances by the
+    ``n_valid`` real tokens only — the padding of a partial last chunk
+    is the identity on it.
     """
     bs, C, T = int(block_size), int(chunk), int(n_table)
     S = T * bs
 
     def build():
-        @partial(jax.jit, donate_argnums=(1, 2, 3, 4))
-        def chunk_fn(params, k_pool, v_pool, conv, ssm, packed):
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def chunk_fn(params, pools, state, packed):
             table, tokens, start, row, n_valid = unpack_chunk(packed, T, C)
-            lay = PoolLayout.of(cfg, k_pool)
+            lay = PoolLayout.of(cfg, pools[0])
             pos = start + jnp.arange(C, dtype=jnp.int32)
             oob = pos >= S
             safe = jnp.where(oob, 0, pos)
             bidx = jnp.where(oob, 0, table[safe // bs])[None]   # [1, C]
             off = jnp.where(oob, 0, pos % bs)[None]
-            mask = (jnp.arange(S)[None, :] <= pos[:, None])[None, None]
-            attend_for, kv = paged_attend(
-                lay, (k_pool, v_pool), bidx, off, table[None],
-                q_per_kv=cfg.n_heads // cfg.n_kv_heads,
-                scale=cfg.attention_multiplier, mask=mask)
-            state = {"conv": conv, "ssm": ssm}
+            attend_for, kv = _attend_over(
+                cfg, lay, pools, bidx, off, table[None], q_pos=pos)
+            held = dict(zip(("conv", "ssm"), state))
 
             def state_in(mi):
                 # ONE dynamic slice of the pool: a static slice of the
@@ -160,21 +187,21 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
                         pool, (mi, row) + (0,) * (pool.ndim - 2),
                         (1, 1) + pool.shape[2:])
                 # the row's SSM state as a pool of one layer and one row
-                return row_of(state["conv"])[0], (row_of(state["ssm"]), 0)
+                return row_of(held["conv"])[0], (row_of(held["ssm"]), 0)
 
             def state_out(mi, new):
-                state["conv"] = state["conv"].at[mi, row].set(new[0][0])
-                state["ssm"] = state["ssm"].at[mi, row].set(new[1][0][0, 0])
+                held["conv"] = held["conv"].at[mi, row].set(new[0][0])
+                held["ssm"] = held["ssm"].at[mi, row].set(new[1][0][0, 0])
 
             x, load = hybrid.run_layers(
                 cfg, params, hybrid.embed(cfg, params, tokens[None]),
                 n_valid[None], state_in=state_in, state_out=state_out,
-                attend_for=attend_for)
+                attend_for=attend_for, positions=pos[None])
             logits = hybrid.head(cfg, params, x[0])             # [C, V]
             greedy = jnp.argmax(logits[jnp.maximum(n_valid, 1) - 1]
                                 ).astype(jnp.int32)
-            return (logits, jnp.append(load, greedy), *kv["pools"],
-                    state["conv"], state["ssm"])
+            return (logits, jnp.append(load, greedy), kv["pools"],
+                    tuple(held[k] for k in ("conv", "ssm")[:len(state)]))
 
         return chunk_fn
 

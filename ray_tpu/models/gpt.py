@@ -98,6 +98,7 @@ class GPTConfig:
         return (self.n_layers, self.n_heads, self.head_dim)
 
     state_geometry = None            # no recurrent state beside the K/V
+    value_lanes = None               # keys and values are two pools
 
     @staticmethod
     def gpt2_124m(**kw) -> "GPTConfig":

@@ -1,12 +1,13 @@
-"""Hybrid language model: Mamba-2 mixers, attention mixers and routed
-experts in a periodic layer pattern.  ONE layer function serves the two
-layouts public configs of the kind have:
+"""Hybrid language model: Mamba-2 mixers, attention mixers, latent
+attention mixers, routed experts and dense gated MLPs in a layer
+pattern.  ONE layer function serves the three layouts public configs of
+the kind have:
 
     x0 = wte[ids] * embedding_multiplier            (no position embedding)
     x  = x + residual_multiplier * mixer(RMSNorm(x) * w)     per sublayer
     logits = RMSNorm(x) @ W_head / logits_scaling   (W_head = wte^T if tied)
 
-``mixer`` is one of three kinds:
+``mixer`` is one of five kinds:
 
   * attention: grouped queries (``n_heads`` query heads over
     ``n_kv_heads`` K/V heads), no bias, no rotary; softmax(q k^T *
@@ -23,17 +24,39 @@ layouts public configs of the kind have:
     ungated.  ``gated_experts``: ``W_out (silu(a) * b)`` experts behind
     a softmax over the chosen logits; else ``W_out relu(W_in h)^2``
     experts behind sigmoid scores, chosen by ``score + bias``, weighted
-    by the chosen scores normalised and times ``routed_scale``.
+    by the chosen scores normalised and times ``routed_scale``.  With
+    ``route_groups`` (n_group, topk_group) the third published form:
+    scores the softmax over ALL router outputs in float32, the choice
+    limited to the ``topk_group`` groups with the largest maximum score,
+    gates the chosen scores times ``routed_scale``, normalised only if
+    ``norm_topk``.  ``shared_width`` is the width of ONE ungated MLP,
+    however many shared experts the config counts (they are published
+    as one MLP of their summed width).
+  * latent attention (``q_rank`` / ``kv_rank``): queries through a
+    normed low-rank bottleneck, per head ``[nope | rope]`` lanes; ONE
+    normed latent ``c_kv`` [kv_rank] and ONE rotated key ``k_rope``
+    [rope_dim] a token, shared by all heads — what a cache keeps (see
+    ``kv_geometry`` / ``value_lanes``); per-head keys and values are
+    ``c_kv W_uk`` and ``c_kv W_uv``.  Rotary positions (YaRN-scaled,
+    ``Yarn``) turn the rope lanes of q and k, half-split pairs; ``attention_multiplier``
+    holds the softmax scale, YaRN's ``mscale`` squared included.  The
+    ``attend`` it is handed chooses the product's form: decompressed
+    keys a block at a time for a window, ``W_uk`` absorbed into the
+    query and ``W_uv`` into the output for one token (ops/attention.py).
+  * a dense gated MLP (``dense_width``): ``W_out (silu(a) * b)``.
 
 A published layer is one such sublayer (``nemotron_h``: the pattern
 string's ``M`` / ``*`` / ``E``), or, with ``experts_in_every_layer``
-(``granitemoehybrid``), a Mamba or attention sublayer FOLLOWED by an
-experts sublayer with its own norm and residual: the same function twice.
+(``granitemoehybrid``, ``deepseek_v2``), a Mamba, attention or latent
+sublayer FOLLOWED by a feed-forward sublayer with its own norm and
+residual — experts, or the dense MLP in the first ``dense_layers``
+layers: the same function twice.
 
 ``block`` takes a window of tokens per row and what the row's mixer needs
 from the past — for a Mamba sublayer the convolution and SSM state the
-row arrives with, for an attention sublayer a function that attends the
-window's queries over the row's keys.  The full-sequence ``forward``
+row arrives with, for an attention or latent sublayer a function that
+attends the window's queries over the row's keys (and, latent, the
+rotary tables at the window's positions).  The full-sequence ``forward``
 (zero state, keys = the window's own), the serving engine's
 chunk-prefill program ([1 row, chunk], state and K/V blocks from the
 pools) and its decode program ([rows, 1]) are that one function at three
@@ -58,12 +81,60 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import ssm
+from ray_tpu.ops.attention import KEY_BLOCK, latent_window_attention
 from ray_tpu.ops.routed_experts import lanes, mlp, routed_experts
 
 MAMBA, ATTENTION, EXPERTS = "mamba", "attention", "experts"
+LATENT, DENSE = "latent", "dense"
 N_LOAD = 4      # numbers in ``run_layers``' load vector (its text names them)
 # the sublayer kinds of a ``nemotron_h`` pattern string
 PATTERN_KINDS = {"M": MAMBA, "*": ATTENTION, "E": EXPERTS}
+
+
+@dataclass(frozen=True)
+class Yarn:
+    """YaRN-scaled rotary positions, a published ``rope_scaling`` of
+    type ``yarn`` under its own keys."""
+    theta: float = 10000.0
+    factor: float = 40.0
+    original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _mscale(factor: float, m: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
+
+    @property
+    def softmax_mscale(self) -> float:
+        """What the softmax scale is multiplied by, squared."""
+        return self._mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def table_mscale(self) -> float:
+        """What the cos / sin tables are multiplied by."""
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
+
+    def inv_freq(self, dim: int):
+        """[dim / 2] float64: a pair's frequency, interpolated (divided
+        by ``factor``) where its wavelength exceeds the original
+        context, kept where it turns ``beta_fast`` times within it, a
+        linear ramp between."""
+        import numpy as np
+        f = self.theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+        def turn(beta):         # the pair that turns beta times in the
+            return (dim * math.log(self.original_max       # original
+                                   / (beta * 2 * math.pi))  # context
+                    / (2 * math.log(self.theta)))
+        low = max(math.floor(turn(self.beta_fast)), 0)
+        high = min(math.ceil(turn(self.beta_slow)), dim - 1)
+        ramp = np.clip((np.arange(dim // 2) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        return f / self.factor * ramp + f * (1.0 - ramp)
 
 
 @dataclass(frozen=True)
@@ -82,6 +153,12 @@ class HybridConfig:
     ssm_groups: int = 1
     conv_width: int = 4
     ssm_chunk: int = 256
+    # latent attention mixer (``head_dim`` is its no-position lanes)
+    q_rank: int = 0
+    kv_rank: int = 0
+    rope_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Any = None                 # a ``Yarn``
     # experts
     n_experts: int = 72              # router width, as published
     experts_per_token: int = 10
@@ -91,7 +168,11 @@ class HybridConfig:
     # the layout and the forms that differ between published families
     experts_in_every_layer: bool = True   # mixer THEN experts, a layer
     gated_experts: bool = True       # False: relu^2 MLPs, sigmoid router
-    routed_scale: float = 1.0        # on the sigmoid router's weights
+    routed_scale: float = 1.0        # on the chosen scores' gates
+    route_groups: tuple = ()         # (n_group, topk_group): third form
+    norm_topk: bool = True           # ... its gates normalised to sum 1
+    dense_layers: int = 0            # leading layers: dense MLP, no experts
+    dense_width: int = 0
     tied_head: bool = True
     # the first family's four multipliers
     embedding_multiplier: float = 12.0
@@ -104,7 +185,7 @@ class HybridConfig:
     param_dtype: Any = jnp.bfloat16  # as the published checkpoint
 
     def __post_init__(self):
-        bad = set(self.layer_types) - {MAMBA, ATTENTION, EXPERTS}
+        bad = set(self.layer_types) - {MAMBA, ATTENTION, EXPERTS, LATENT}
         if bad:
             raise ValueError(f"unknown layer types {sorted(bad)}")
         if self.experts_in_every_layer and EXPERTS in self.layer_types:
@@ -118,15 +199,26 @@ class HybridConfig:
         if not 0 <= lo < hi <= self.n_experts:
             raise ValueError(f"experts_held {self.experts_held} is not a "
                              f"range of {self.n_experts} experts")
+        if self.dense_layers and not self.experts_in_every_layer:
+            raise ValueError("dense_layers: only where a layer is its "
+                             "mixer then a feed-forward sublayer")
+        if LATENT in self.layer_types and set(self.layer_types) != {LATENT}:
+            raise ValueError("latent and head-lane attention layers keep "
+                             "different things: one pool holds one kind")
+        if self.route_groups and self.n_experts % self.route_groups[0]:
+            raise ValueError(f"{self.n_experts} experts in "
+                             f"{self.route_groups[0]} groups")
 
     @classmethod
     def from_published(cls, config: dict, **overrides) -> "HybridConfig":
         """From a public ``config.json``'s own keys: ``nemotron_h``'s
-        where it has a ``hybrid_override_pattern``, else
-        ``granitemoehybrid``'s."""
+        where it has a ``hybrid_override_pattern``, ``deepseek_v2``'s
+        where it has a ``kv_lora_rank``, else ``granitemoehybrid``'s."""
         c = config
         if "hybrid_override_pattern" in c:
             return cls(**{**_nemotron_h_keys(c), **overrides})
+        if "kv_lora_rank" in c:
+            return cls(**{**_latent_keys(c), **overrides})
         kw = dict(
             vocab_size=c["vocab_size"], d_model=c["hidden_size"],
             layer_types=tuple(c["layer_types"][:c["num_hidden_layers"]]),
@@ -175,6 +267,10 @@ class HybridConfig:
         return self.layer_types.count(ATTENTION)
 
     @property
+    def n_latent(self) -> int:
+        return self.layer_types.count(LATENT)
+
+    @property
     def sublayers(self) -> tuple:
         """(index into ``params["layers"]``, kind) of every residual
         sublayer, in the order they run."""
@@ -182,7 +278,7 @@ class HybridConfig:
         for i, kind in enumerate(self.layer_types):
             out.append((i, kind))
             if self.experts_in_every_layer:
-                out.append((i, EXPERTS))
+                out.append((i, DENSE if i < self.dense_layers else EXPERTS))
         return tuple(out)
 
     @property
@@ -200,8 +296,20 @@ class HybridConfig:
     # -- what a serving cache holds for this model (inference/cache.py) --
     @property
     def kv_geometry(self) -> tuple:
-        """(layers that keep K/V, K/V heads, head size)."""
+        """(layers that keep K/V, K/V heads, head size).  Latent
+        attention keeps ONE head a token: the normed latent then the
+        rotated key, ``kv_rank + rope_dim`` lanes (``value_lanes`` says
+        which of them are the values)."""
+        if self.n_latent:
+            return (self.n_latent, 1, self.kv_rank + self.rope_dim)
         return (self.n_attention, self.n_kv_heads, self.head_dim)
+
+    @property
+    def value_lanes(self):
+        """None: keys and values are two pools.  An int: there is ONE
+        pool, and a token's values are the first ``value_lanes`` lanes
+        of its key (latent attention: the latent is both)."""
+        return self.kv_rank if self.n_latent else None
 
     @property
     def state_geometry(self) -> tuple:
@@ -211,7 +319,10 @@ class HybridConfig:
         whose trailing dims are ``(8192, 128)`` has one natural tiling,
         so no program re-lays the whole pool out to suit its own
         products (the chunk program did, a 2.4 GB copy, when the three
-        dims were kept apart)."""
+        dims were kept apart).  None for a model without recurrent
+        layers: its whole past is blocks."""
+        if not self.n_mamba:
+            return None
         return (self.n_mamba, (self.conv_width - 1, self.conv_channels),
                 (self.ssm_heads * self.ssm_head_dim, self.ssm_state))
 
@@ -252,6 +363,57 @@ def _nemotron_h_keys(c: dict) -> dict:
         residual_multiplier=1.0, logits_scaling=1.0,
         rms_eps=c["layer_norm_epsilon"],
         max_seq=c["max_position_embeddings"])
+
+
+def _latent_keys(c: dict) -> dict:
+    """``HybridConfig`` fields from ``deepseek_v2`` keys.  What the layer
+    function has no form for is refused here, by name."""
+    rs = c.get("rope_scaling") or {}
+    for key, got, ok in (
+            ("rope_scaling.type", rs.get("type"), ("yarn",)),
+            ("topk_method", c.get("topk_method", "greedy"),
+             ("group_limited_greedy", "greedy")),
+            ("scoring_func", c.get("scoring_func", "softmax"), ("softmax",)),
+            ("moe_layer_freq", c.get("moe_layer_freq", 1), (1,)),
+            ("tie_word_embeddings", c.get("tie_word_embeddings", False),
+             (False,)),
+            ("attention_bias", c.get("attention_bias", False), (False,))):
+        if got not in ok:
+            raise ValueError(f"{key} = {got!r} is not implemented (only "
+                             f"{' / '.join(map(repr, ok))})")
+    if not c.get("q_lora_rank"):
+        raise ValueError("q_lora_rank = None (a full-rank query "
+                         "projection) is not implemented")
+    yarn = Yarn(theta=c["rope_theta"], factor=rs["factor"],
+                original_max=rs["original_max_position_embeddings"],
+                beta_fast=rs["beta_fast"], beta_slow=rs["beta_slow"],
+                mscale=rs["mscale"], mscale_all_dim=rs["mscale_all_dim"])
+    grouped = c.get("topk_method") == "group_limited_greedy"
+    qk = c["qk_nope_head_dim"] + c["qk_rope_head_dim"]
+    return dict(
+        vocab_size=c["vocab_size"], d_model=c["hidden_size"],
+        layer_types=(LATENT,) * c["num_hidden_layers"],
+        n_heads=c["num_attention_heads"], n_kv_heads=1,
+        head_dim=c["qk_nope_head_dim"], rope_dim=c["qk_rope_head_dim"],
+        v_head_dim=c["v_head_dim"], q_rank=c["q_lora_rank"],
+        kv_rank=c["kv_lora_rank"], yarn=yarn,
+        n_experts=c["n_routed_experts"],
+        experts_per_token=c["num_experts_per_tok"],
+        expert_width=c["moe_intermediate_size"],
+        shared_width=c["n_shared_experts"] * c["moe_intermediate_size"],
+        experts_held=(0, c["n_routed_experts"]),
+        experts_in_every_layer=True, gated_experts=True,
+        routed_scale=c["routed_scaling_factor"],
+        route_groups=((c["n_group"], c["topk_group"]) if grouped
+                      else (1, 1)),
+        norm_topk=c["norm_topk_prob"],
+        dense_layers=min(c["first_k_dense_replace"],
+                         c["num_hidden_layers"]),
+        dense_width=c["intermediate_size"], tied_head=False,
+        embedding_multiplier=1.0,
+        attention_multiplier=qk ** -0.5 * yarn.softmax_mscale ** 2,
+        residual_multiplier=1.0, logits_scaling=1.0,
+        rms_eps=c["rms_norm_eps"], max_seq=c["max_position_embeddings"])
 
 
 # -- params ----------------------------------------------------------------
@@ -308,6 +470,27 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
                 "wqkv": norm((d, hq + 2 * hkv)),
                 "wo": norm((hq, d)),
             }
+        if kind == LATENT:
+            nh, dn, dr, dv = (cfg.n_heads, cfg.head_dim, cfg.rope_dim,
+                              cfg.v_head_dim)
+            return {
+                "norm": jnp.ones((d,), pd),
+                "wq_a": norm((d, cfg.q_rank)),
+                "q_norm": jnp.ones((cfg.q_rank,), pd),
+                "wq_nope": norm((nh, dn, cfg.q_rank)),
+                "wq_rope": norm((dr, nh, cfg.q_rank)),
+                "wkv_a": norm((d, cfg.kv_rank + dr)),
+                "kv_norm": jnp.ones((cfg.kv_rank,), pd),
+                "w_uk": norm((nh, cfg.kv_rank, dn)),
+                "w_uv": norm((nh, cfg.kv_rank, dv)),
+                "wo": norm((nh * dv, d)),
+            }
+        if kind == DENSE:
+            return {
+                "norm": jnp.ones((d,), pd),
+                "w_in": norm((d, 2 * cfg.dense_width)),
+                "w_out": norm((cfg.dense_width, d)),
+            }
         ffn = {
             "norm": jnp.ones((d,), pd),
             "router": norm((d, cfg.n_experts)),
@@ -327,8 +510,7 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
     streams = [iter(jax.random.split(key, 12)) for key in keys[1:]]
     layers = [{} for _ in cfg.layer_types]
     for i, kind in cfg.sublayers:
-        layers[i]["ffn" if kind == EXPERTS else "mixer"] = sublayer(
-            kind, streams[i])
+        layers[i][slot_of(kind)] = sublayer(kind, streams[i])
     params = {
         "wte": (jax.random.normal(keys[0], (cfg.vocab_size, d))
                 * (0.02 / cfg.embedding_multiplier)).astype(pd),
@@ -342,6 +524,11 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
     return params
 
 
+def slot_of(kind: str) -> str:
+    """The key of a layer's dict that holds a sublayer of ``kind``."""
+    return "ffn" if kind in (EXPERTS, DENSE) else "mixer"
+
+
 def num_params(params) -> int:
     return sum(int(math.prod(p.shape)) for p in jax.tree.leaves(params))
 
@@ -352,7 +539,8 @@ def cast_at_use(params) -> list:
     float32).  The family is published and held in that dtype, so the
     casts are no-ops and the tree is served as it is."""
     out = [params[n] for n in ("wte", "head") if n in params]
-    names = {"mixer": ("in_proj", "out_proj", "wqkv", "wo"),
+    names = {"mixer": ("in_proj", "out_proj", "wqkv", "wo", "wq_a",
+                       "wq_nope", "wq_rope", "wkv_a", "w_uk", "w_uv"),
              "ffn": ("router", "shared_in", "shared_out", "w_in", "w_out")}
     for lp in params["layers"]:
         out += [lp[sub][n] for sub in names if sub in lp
@@ -412,6 +600,74 @@ def _attention_mixer(cfg, ap, h, attend):
         return jnp.dot(o, ap["wo"].astype(h.dtype))
 
 
+def rotary_tables(cfg: HybridConfig, positions):
+    """positions [...] int -> (cos, sin) [..., rope_dim / 2] float32 at
+    the YaRN-scaled frequencies; None for a model without rotary
+    positions."""
+    if cfg.yarn is None:
+        return None
+    with jax.named_scope("rotary"):
+        inv = jnp.asarray(cfg.yarn.inv_freq(cfg.rope_dim), jnp.float32)
+        ang = positions.astype(jnp.float32)[..., None] * inv
+        m = cfg.yarn.table_mscale
+        return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
+def rotate(x, tables):
+    """x [..., w, (heads,) rope_dim] turned by ``tables`` [..., w,
+    rope_dim / 2]: pair j is lanes (j, j + rope_dim / 2)."""
+    cos, sin = tables
+    if x.ndim == cos.ndim + 1:                 # a heads dim before lanes
+        cos, sin = cos[..., None, :], sin[..., None, :]
+    xf = x.astype(jnp.float32)
+    a, b = jnp.split(xf, 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _latent_mixer(cfg, ap, h, attend, tables):
+    """h [b, w, d]; ``tables`` the rotary tables at the window's
+    positions; ``attend(q_nope [b, w, h, dn], q_rope [b, w, h, dr],
+    latent [b, w, kv_rank + dr], w_uk [h, kv_rank, dn], w_uv [h,
+    kv_rank, dv]) -> o [b, w, h * dv]`` supplies the latents of the
+    past, and chooses the form of the product.
+
+    The published ``W_qb`` is held as its no-position and its rope
+    columns apart (``wq_nope`` [h, dn, q_rank] and ``wq_rope`` [dr, h,
+    q_rank], the rank minor; the rope lanes before the heads, as the
+    rotation splits them) and ``W_kvb`` a head, keys and values
+    apart (``w_uk``, ``w_uv`` [h, kv_rank, .]): handed the fused
+    matrices, the one-token program re-laid each out every pass to slice
+    it and to bring the heads forward for the absorbed product (75 + 33
+    MB of copies a layer in the described-chip compile)."""
+    b, w, _ = h.shape
+    nh, dn, dr = cfg.n_heads, cfg.head_dim, cfg.rope_dim
+    with jax.named_scope("mixer_latent_proj"):
+        cq = _rms_norm(jnp.dot(h, ap["wq_a"].astype(h.dtype)),
+                       ap["q_norm"], cfg.rms_eps)
+        q_nope = jnp.einsum("bwr,hdr->bwhd", cq,
+                            ap["wq_nope"].astype(h.dtype))
+        q_rope = jnp.einsum("bwr,dhr->bwhd", cq,
+                            ap["wq_rope"].astype(h.dtype))
+        ckv = jnp.dot(h, ap["wkv_a"].astype(h.dtype))
+        c = _rms_norm(ckv[..., :cfg.kv_rank], ap["kv_norm"], cfg.rms_eps)
+    with jax.named_scope("rotary"):
+        q_rope = rotate(q_rope, tables)
+        k_rope = rotate(ckv[..., cfg.kv_rank:], tables)
+    with jax.named_scope("mixer_latent_attention"):
+        o = attend(q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1),
+                   ap["w_uk"].astype(h.dtype), ap["w_uv"].astype(h.dtype))
+    with jax.named_scope("mixer_latent_proj"):
+        return jnp.dot(o, ap["wo"].astype(h.dtype))
+
+
+def _dense(cfg, fp, h):
+    b, w, d = h.shape
+    with jax.named_scope("dense_mlp"):
+        return mlp(h.reshape(b * w, d), fp["w_in"],
+                   fp["w_out"]).reshape(b, w, d)
+
+
 def _experts(cfg, fp, h, valid):
     """h [b, w, d] -> (routed + shared [b, w, d], counts [E_held],
     total)."""
@@ -422,7 +678,8 @@ def _experts(cfg, fp, h, valid):
             flat, fp["router"], fp["w_in"], fp["w_out"],
             top_k=cfg.experts_per_token, held=cfg.experts_held,
             valid=valid.reshape(b * w), gated=cfg.gated_experts,
-            bias=fp.get("router_bias"), scale=cfg.routed_scale)
+            bias=fp.get("router_bias"), scale=cfg.routed_scale,
+            groups=cfg.route_groups, normalise=cfg.norm_topk)
     with jax.named_scope("shared_expert"):
         shared = mlp(flat, fp["shared_in"], fp["shared_out"],
                      cfg.gated_experts)
@@ -433,8 +690,9 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid):
     """ONE residual sublayer on a window: x [b, w, d], ``n_valid`` [b]
     real tokens a row; ``lp`` its parameters.  ``past`` is the row's
     state for a Mamba sublayer (returned updated), the ``attend``
-    function for an attention sublayer (returned as it came) and unused
-    by experts.
+    function for an attention sublayer and (``attend``, rotary tables)
+    for a latent one (returned as they came) and unused by experts and
+    the dense MLP.
     -> (x, past, (counts [E_held], total) of an experts sublayer, else
         None)."""
     h = _rms_norm(x, lp["norm"], cfg.rms_eps)
@@ -443,6 +701,10 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid):
         mix, past = _mamba_mixer(cfg, lp, h, past, n_valid)
     elif kind == ATTENTION:
         mix = _attention_mixer(cfg, lp, h, past)
+    elif kind == LATENT:
+        mix = _latent_mixer(cfg, lp, h, *past)
+    elif kind == DENSE:
+        mix = _dense(cfg, lp, h)
     else:
         valid = jnp.arange(x.shape[1])[None, :] < n_valid[:, None]
         mix, counts, total = _experts(cfg, lp, h, valid)
@@ -465,21 +727,29 @@ def head(cfg: HybridConfig, params, x):
 
 
 def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
-               state_out: Callable, attend_for: Callable):
+               state_out: Callable, attend_for: Callable, positions=None):
     """The unrolled layer loop over a window.  ``state_in(mi)`` gives
     Mamba layer ``mi``'s state for the window's rows, (conv, (ssm pool,
     layer)) as ``_mamba_mixer`` takes it, and ``state_out(mi, state)``
-    takes it back; ``attend_for(ai)`` gives
-    attention layer ``ai``'s ``attend``.
+    takes it back; ``attend_for(ai)`` gives attention or latent layer
+    ``ai``'s ``attend``; ``positions`` [b, w] are the window's, read by
+    rotary positions alone.
     -> (x, load [N_LOAD] int32: held assignments, all assignments, the
         busiest held expert's assignments and the held experts with at
         least one assignment, each summed over the experts sublayers;
         real tokens only)."""
     mi = ai = 0
     load = jnp.zeros((N_LOAD,), jnp.int32)
+    tables = rotary_tables(cfg, positions) if cfg.n_latent else None
     for i, kind in cfg.sublayers:
         lp = params["layers"][i]
-        if kind == MAMBA:
+        if kind == LATENT:
+            x, _, _ = block(cfg, kind, lp["mixer"], x,
+                            (attend_for(ai), tables), n_valid)
+            ai += 1
+        elif kind == DENSE:
+            x, _, _ = block(cfg, kind, lp["ffn"], x, None, n_valid)
+        elif kind == MAMBA:
             x, state, _ = block(cfg, kind, lp["mixer"], x, state_in(mi),
                                 n_valid)
             state_out(mi, state)
@@ -499,13 +769,28 @@ def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
 def zero_state(cfg: HybridConfig, rows: int):
     """(conv, ssm) state of ``rows`` rows that have seen nothing, the
     SSM state as a pool of this one layer."""
+    if cfg.state_geometry is None:
+        return None
     _, conv, ssm_shape = cfg.state_geometry
     return (jnp.zeros((rows, *conv), cfg.dtype),
             (jnp.zeros((1, rows, *ssm_shape), jnp.float32), 0))
 
 
 def causal_attend(cfg: HybridConfig):
-    """``attend`` over the window's own keys (a full sequence)."""
+    """``attend`` over the window's own keys (a full sequence), in the
+    form the model's attention layers take it."""
+    if cfg.n_latent:
+        def latent(q_nope, q_rope, lat, w_uk, w_uv):
+            b, w = lat.shape[:2]
+            pos = jnp.arange(w, dtype=jnp.int32)
+            lat = jnp.pad(lat, ((0, 0), (0, -w % KEY_BLOCK), (0, 0)))
+            return jnp.stack([latent_window_attention(
+                q_nope[i], q_rope[i],
+                lambda j, n, c=lat[i]: jax.lax.dynamic_slice_in_dim(
+                    c, j * n, n),
+                w_uk, w_uv, pos, scale=cfg.attention_multiplier,
+                n_blocks=-(-w // KEY_BLOCK)) for i in range(b)])
+        return latent
     rep = cfg.n_heads // cfg.n_kv_heads
 
     def attend(q, k, v):
@@ -531,5 +816,6 @@ def forward(params, tokens, cfg: HybridConfig):
         jnp.full((b,), s, jnp.int32),
         state_in=lambda mi: zero_state(cfg, b),
         state_out=lambda mi, state: None,
-        attend_for=lambda ai: attend)
+        attend_for=lambda ai: attend,
+        positions=jnp.broadcast_to(jnp.arange(s), (b, s)))
     return head(cfg, params, x)

@@ -181,6 +181,20 @@ def paged_attention(q, k_pool, v_pool, block_tables, *,
 WAVE_BYTES = 4 << 20
 
 
+def _list_live_rows(len_ref, rows_ref):
+    """``rows_ref`` <- the rows with ``len`` > 0, in order; -> their
+    count (a row that sits out costs one scalar comparison)."""
+    def list_live(row, n):
+        live = len_ref[row] > 0
+
+        @pl.when(live)
+        def _():
+            rows_ref[n] = row
+        return n + live.astype(jnp.int32)
+
+    return lax.fori_loop(0, rows_ref.shape[0], list_live, 0)
+
+
 def _decode_kernel(base_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
                    rows_ref, k_buf, v_buf, sem, qx_ref, acc_ref, *,
                    wave: int, n_table: int, n_kv: int, head_dim: int,
@@ -207,15 +221,7 @@ def _decode_kernel(base_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
     reps, gp = q_ref.shape[1], qx_ref.shape[0] // q_ref.shape[1]
     base = base_ref[0]
 
-    def list_live(row, n):
-        live = len_ref[row] > 0
-
-        @pl.when(live)
-        def _():
-            rows_ref[n] = row
-        return n + live.astype(jnp.int32)
-
-    count = lax.fori_loop(0, rows_ref.shape[0], list_live, 0)
+    count = _list_live_rows(len_ref, rows_ref)
 
     def blocks_of(row, w):
         """Blocks of the row's wave w that hold a key: 0 .. wave."""
@@ -380,6 +386,285 @@ def paged_decode_attention(q, k_pool, v_pool, base, tables, kv_lengths, *,
     out = jnp.where((kv_lengths > 0)[:, None, None], out[..., :n_kv * hd], 0.0)
     out = out.reshape(b, q_per_kv, n_kv, hd).transpose(0, 2, 1, 3)
     return out.reshape(b, h, 1, hd).astype(v_pool.dtype)
+
+
+# ---------------------------------------------------------------------------
+# latent attention: ONE cached vector a token, [c_kv | k_rope], shared by
+# every head; a head's key is [c_kv W_uk | k_rope] and its value c_kv
+# W_uv.  Two forms of the same sums:
+# a WINDOW of queries decompresses the keys a block at a time (the work
+# is the keys' and is shared by the window's queries); ONE token absorbs
+# W_uk into its query and W_uv into its output and attends the latents
+# as they are stored (decompressing every key for one query would be the
+# whole cost of a prompt, every step).
+
+KEY_BLOCK = 1024    # keys a step of the window form decompresses
+_MASKED = -1e30     # a masked score: finite, so no (-inf) - (-inf)
+
+
+def _window_block_kernel(k0_ref, pos_ref, qn_ref, qr_ref, kn_ref, kr_ref,
+                         vt_ref, m_ref, l_ref, acc_ref, m_out, l_out,
+                         acc_out, *, scale: float):
+    """One head's window of queries against one block of decompressed
+    keys, the running softmax carried in and out.  Everything is held
+    TRANSPOSED — scores [keys, queries], values [dv, keys], the running
+    output [dv, queries] — so that a query's maximum and sum are lane-
+    dense rows [1, queries] and no product needs a transposed operand:
+    the scores never leave VMEM."""
+    f32 = jnp.float32
+    nt = (((1,), (1,)), ((), ()))
+    s = (lax.dot_general(kn_ref[...], qn_ref[...], nt,
+                         preferred_element_type=f32)
+         + lax.dot_general(kr_ref[...], qr_ref[...], nt,
+                           preferred_element_type=f32)) * scale
+    k_pos = k0_ref[0] + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    seen = k_pos <= pos_ref[...]                       # [keys, queries]
+    s = jnp.where(seen, s, _MASKED)
+    m_prev = m_ref[...]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.where(seen, jnp.exp(s - m_next), 0.0)
+    m_out[...] = m_next
+    l_out[...] = l_ref[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
+    acc_out[...] = acc_ref[...] * alpha + jnp.dot(
+        vt_ref[...], p.astype(vt_ref.dtype), preferred_element_type=f32)
+
+
+def _window_block(qn, qr, k_nope, k_rope, v_t, pos, k0, carry, scale):
+    """``carry`` (m [h, 1, w], l [h, 1, w], acc [h, dv, w]) advanced by
+    one block of keys: one grid step a head, the carry updated in
+    place."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops.flash_attention import _interpret_mode
+
+    h, w, dn = qn.shape
+    dr, n, dv = qr.shape[-1], k_nope.shape[1], v_t.shape[1]
+
+    def per_head(*shape):
+        return pl.BlockSpec((None, *shape), lambda i: (i, 0, 0))
+
+    def shared(*shape):
+        return pl.BlockSpec(shape, lambda i: (0, 0))
+
+    stats = [per_head(1, w), per_head(1, w), per_head(dv, w)]
+    return pl.pallas_call(
+        functools.partial(_window_block_kernel, scale=scale),
+        grid=(h,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), shared(1, w),
+                  per_head(w, dn), per_head(w, dr), per_head(n, dn),
+                  shared(n, dr), per_head(dv, n)] + stats,
+        out_specs=stats,
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in carry],
+        input_output_aliases={7: 0, 8: 1, 9: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=64 << 20),
+        interpret=_interpret_mode(),
+        name="latent_window_attention",
+    )(jnp.asarray(k0, jnp.int32)[None], pos, qn, qr, k_nope, k_rope, v_t,
+      *carry)
+
+
+def latent_window_attention(q_nope, q_rope, read_keys, w_uk, w_uv, q_pos, *,
+                            scale: float, key_block: int = KEY_BLOCK,
+                            n_blocks=None):
+    """ONE row's window of queries over the row's cached latents, the
+    keys decompressed ``key_block`` at a time under a running softmax.
+
+    q_nope    [w, h, dn], q_rope [w, h, dr] (rotated)
+    read_keys ``(j, n) -> [n, >= kv_rank + dr]``: the latents of key
+              positions ``j * n .. (j + 1) * n`` (what lies past the
+              row's last key may be anything)
+    w_uk      [h, kv_rank, dn], w_uv [h, kv_rank, dv]
+    q_pos     [w] int32: a query attends the keys at positions <= its
+              own (key 0 is every query's)
+    n_blocks  key blocks walked; None: those that hold a key of the
+              window's last query (a traced count, a ``while`` loop)
+    -> [w, h * dv]
+
+    A block is decompressed ONCE for the whole window (``c_kv W_uk``,
+    ``c_kv W_uv``: the compiler's products) and attended in one Pallas
+    kernel, a grid step a head, whose scores stay in VMEM
+    (``_window_block_kernel``): as a plain ``lax`` loop the float32
+    scores ``[h, w, keys]`` went through HBM four times a block and the
+    form ran at 17 % of its roofline (PR 42, on the chip).  No array
+    here has more than ``key_block`` keys, whatever the row's length."""
+    f32 = jnp.float32
+    w, h, dn = q_nope.shape
+    dr, (_, kv_rank, dv) = q_rope.shape[-1], w_uv.shape
+    last = jnp.max(q_pos)
+    if n_blocks is None:
+        n_blocks = last // key_block + 1
+    qn, qr = q_nope.transpose(1, 0, 2), q_rope.transpose(1, 0, 2)
+    pos = q_pos.astype(jnp.int32)[None, :]
+
+    def body(j, carry):
+        lat = read_keys(j, key_block)
+        k_pos = j * key_block + jnp.arange(key_block, dtype=jnp.int32)
+        # 0 x NaN is NaN: what no query of the window may see is zeroed
+        lat = jnp.where((k_pos <= last)[:, None], lat, jnp.zeros_like(lat))
+        c = lat[:, :kv_rank]
+        return _window_block(
+            qn, qr, jnp.einsum("kc,hcd->hkd", c, w_uk),
+            lat[:, kv_rank:kv_rank + dr], jnp.einsum("kc,hcd->hdk", c, w_uv),
+            pos, j * key_block, carry, scale)
+
+    init = (jnp.full((h, 1, w), _MASKED, f32), jnp.zeros((h, 1, w), f32),
+            jnp.zeros((h, dv, w), f32))
+    _, l, acc = lax.fori_loop(0, n_blocks, body, init)
+    o = (acc / l).astype(q_nope.dtype)                     # [h, dv, w]
+    return o.transpose(2, 0, 1).reshape(w, h * dv)
+
+
+# VMEM the latent kernel's wave buffers take (ONE pool, double
+# buffered): 64 blocks of 16 x 640 bf16
+LATENT_WAVE_BYTES = 4 << 20
+
+
+def _latent_decode_kernel(base_ref, len_ref, tab_ref, q_ref, pool_hbm, o_ref,
+                          rows_ref, buf, sem, acc_ref, *, wave: int,
+                          n_table: int, value_lanes: int, scale: float):
+    """``_decode_kernel``'s walk (live rows listed first; wave w + 1, or
+    the next live row's first, on its way into one half of the buffer
+    while wave w is attended in the other) over ONE pool whose every
+    token is one key for ALL heads: a row's [heads, W] queries times the
+    wave's [tokens, W] keys is a plain MXU product, and the values are
+    the same tile's first ``value_lanes`` lanes."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    bs = buf.shape[1] // wave
+    tokens = wave * bs
+    base = base_ref[0]
+
+    count = _list_live_rows(len_ref, rows_ref)
+
+    def each_copy(row, w, half, do):
+        def one(i, _):
+            at = pl.ds(pl.multiple_of(i * bs, bs), bs)
+            src = base + tab_ref[row * n_table + w * wave + i]
+            do(pltpu.make_async_copy(pool_hbm.at[src], buf.at[half, at],
+                                     sem.at[half]))
+        # blocks of the row's wave w that hold a key: 0 .. wave
+        lax.fori_loop(0, jnp.clip(pl.cdiv(len_ref[row], bs) - w * wave,
+                                  0, wave), one, None)
+
+    def start(row, w, half):
+        each_copy(row, w, half, lambda c: c.start())
+
+    def wait(row, w, half):
+        each_copy(row, w, half, lambda c: c.wait())
+
+    @pl.when(count > 0)
+    def _():
+        start(rows_ref[0], 0, 0)
+
+    def row_body(slot, done):
+        row = rows_ref[slot]
+        kv_len = len_ref[row]
+        n_waves = pl.cdiv(kv_len, tokens)
+        q = q_ref[row]                                      # [heads, W]
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def wave_body(w, carry):
+            m_prev, l_prev = carry
+            half = (done + w) % 2
+            more = w + 1 < n_waves
+
+            @pl.when(more | (slot + 1 < count))
+            def _():
+                start(jnp.where(more, row, rows_ref[
+                    jnp.minimum(slot + 1, rows_ref.shape[0] - 1)]),
+                    jnp.where(more, w + 1, 0), 1 - half)
+
+            wait(row, w, half)
+
+            # a key past kv_len weighs exactly 0, and what lies there (a
+            # block not copied, a block's unwritten tail) may be
+            # anything: 0 x NaN is NaN
+            @pl.when((w + 1) * tokens > kv_len)
+            def _():
+                pos = w * tokens + lax.broadcasted_iota(
+                    jnp.int32, buf.shape[1:], 0)
+                k = buf[half]
+                buf[half] = jnp.where(pos < kv_len, k, jnp.zeros_like(k))
+
+            s = lax.dot_general(
+                q, buf[half], (((1,), (1,)), ((), ())),
+                preferred_element_type=f32) * scale     # [heads, tokens]
+            key = w * tokens + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(key < kv_len, s, -jnp.inf)
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(s - m_next)
+            acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+                p.astype(buf.dtype), buf[half, :, :value_lanes],
+                preferred_element_type=f32)
+            return m_next, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+
+        heads = q.shape[0]
+        _, l = lax.fori_loop(
+            0, n_waves, wave_body,
+            (jnp.full((heads, 1), -jnp.inf, f32), jnp.zeros((heads, 1), f32)))
+        o_ref[row] = (acc_ref[...] / l).astype(o_ref.dtype)
+        return done + n_waves
+
+    lax.fori_loop(0, count, row_body, 0)
+
+
+def latent_decode_attention(q, pool, base, tables, kv_lengths, *,
+                            value_lanes: int,
+                            scale: float) -> jax.Array:
+    """One token a row over the latent pool AS STORED, reading only the
+    blocks a row holds: ``paged_decode_attention`` for a pool whose
+    token is ONE key for all heads and whose values are the key's first
+    ``value_lanes`` lanes.
+
+    q           [b, h, W]: a head's absorbed query ``[q_nope W_uk^T |
+                q_rope]`` in the pool's lanes, zeros in its padding
+    pool        [rows, bs, W] (inference/cache.PoolLayout, one head)
+    base        int32 scalar: the pool's row of this layer's block 0
+    tables      [b, T] int32; kv_lengths [b] int32 (0: the row sits out)
+    -> [b, h, value_lanes] (``P c_kv``; the caller multiplies by W_uv);
+       a row that sits out gets zeros."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops.flash_attention import _interpret_mode
+
+    b, h, width = q.shape
+    n_table = tables.shape[1]
+    _, bs, _ = pool.shape
+    item = pool.dtype.itemsize
+    wave = max(1, LATENT_WAVE_BYTES // (2 * bs * width * item))
+    wave = min(1 << (wave.bit_length() - 1), n_table)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # every row's queries and outputs stay in VMEM beside the waves
+    need = (b * h * (width + value_lanes) * item
+            + 2 * wave * bs * width * item
+            + h * (value_lanes + 3 * wave * bs) * 4)
+    out = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, wave=wave, n_table=n_table,
+                          value_lanes=value_lanes, scale=scale),
+        in_specs=[smem] * 3 + [vmem, hbm],
+        out_specs=vmem,
+        out_shape=jax.ShapeDtypeStruct((b, h, value_lanes), pool.dtype),
+        scratch_shapes=[
+            pltpu.SMEM((b,), jnp.int32),
+            pltpu.VMEM((2, wave * bs, width), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((h, value_lanes), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(32 << 20, 2 * need)),
+        interpret=_interpret_mode(),
+        name="latent_decode_attention",
+    )(jnp.asarray(base, jnp.int32)[None], kv_lengths.astype(jnp.int32),
+      tables.reshape(-1), q.astype(pool.dtype), pool)
+    return jnp.where((kv_lengths > 0)[:, None, None], out,
+                     jnp.zeros_like(out))
 
 
 def on_tpu() -> bool:

@@ -17,12 +17,18 @@ assignments' and not tokens x experts, and an expert no token chose is
 not read).  Assignments to absent experts sort past the last run,
 are multiplied by nothing and carry a gate of 0.
 
-Two published forms of the router and of an expert:
+Three published forms of the router, two of an expert:
 
   * no selection ``bias``: the k largest router logits, gates their
     softmax; with a ``bias`` [E]: scores ``sigmoid(logits)``, the k
     largest of ``score + bias`` chosen, gates the UNBIASED scores of the
-    chosen, normalised to sum 1 and times ``scale``;
+    chosen, normalised to sum 1 and times ``scale``; with ``groups``
+    (n_group, topk_group): scores the softmax over ALL E logits in
+    float32, the experts in n_group consecutive groups of which only
+    the topk_group with the largest MAXIMUM score may be chosen from
+    (device-limited routing: a group is a device's experts), the k
+    largest scores left chosen, gates those scores times ``scale``,
+    normalised to sum 1 first only if ``normalise``;
   * ``gated``: ``W_out (silu(a) * b)``, ``[a | b] = W_in h`` with ``w_in
     [.., d, 2f]``; else ``W_out relu(W_in h)^2`` with ``w_in [.., d,
     f']``, f' = f or f rounded up to whole 128-lane tiles with zero
@@ -36,11 +42,28 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def route(h, w_router, top_k: int, bias=None, scale: float = 1.0):
+def route(h, w_router, top_k: int, bias=None, scale: float = 1.0,
+          groups: tuple = (), normalise: bool = True):
     """h [T, d], w_router [d, E] -> (experts [T, k] int32, gates [T, k]
-    float32): see the module's text for the two forms."""
+    float32): see the module's text for the three forms."""
     logits = jnp.dot(h, w_router.astype(h.dtype),
                      preferred_element_type=jnp.float32)
+    if groups:
+        n_group, topk_group = groups
+        scores = jax.nn.softmax(logits, axis=-1)
+        limited = scores
+        if n_group > 1:
+            T, E = scores.shape
+            best = scores.reshape(T, n_group, E // n_group).max(-1)
+            _, keep = lax.top_k(best, topk_group)               # [T, g]
+            kept = (jnp.arange(n_group)[None, :, None]
+                    == keep[:, None, :]).any(-1)                # [T, G]
+            limited = jnp.where(jnp.repeat(kept, E // n_group, axis=1),
+                                scores, 0.0)
+        gates, experts = lax.top_k(limited, top_k)
+        if normalise:
+            gates = gates / gates.sum(-1, keepdims=True)
+        return experts.astype(jnp.int32), gates * scale
     if bias is None:
         top, experts = lax.top_k(logits, top_k)
         return experts.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
@@ -121,14 +144,15 @@ def grouped_matmul(x, w, group_sizes):
 
 def routed_experts(h, w_router, w_in, w_out, *, top_k: int,
                    held: tuple, valid=None, gated: bool = True, bias=None,
-                   scale: float = 1.0):
+                   scale: float = 1.0, groups: tuple = (),
+                   normalise: bool = True):
     """The held experts' part of a routed-expert layer.
 
     h [T, d]; w_router [d, E]; w_in [E_held, d, 2f or f'], w_out
     [E_held, f, d] the weights of experts ``held[0] .. held[1] - 1`` in
     the form ``gated`` says; ``valid`` [T] bool marks real tokens
-    (padding routes like any token but is not counted); ``bias`` [E]
-    and ``scale`` as ``route`` takes them.
+    (padding routes like any token but is not counted); ``bias`` [E],
+    ``scale``, ``groups`` and ``normalise`` as ``route`` takes them.
     -> (out [T, d], counts [E_held] int32: real assignments per held
         expert, total int32: real assignments to ANY expert)."""
     T, d = h.shape
@@ -137,7 +161,8 @@ def routed_experts(h, w_router, w_in, w_out, *, top_k: int,
     if w_in.shape[0] != n_held:
         raise ValueError(f"{w_in.shape[0]} expert weights for the held "
                          f"range {held}")
-    experts, gates = route(h, w_router, top_k, bias, scale)
+    experts, gates = route(h, w_router, top_k, bias, scale, groups,
+                           normalise)
     flat = experts.reshape(-1)                                  # [T*k]
     mine = (flat >= lo) & (flat < hi)
     local = jnp.where(mine, flat - lo, n_held)      # absent: past the runs

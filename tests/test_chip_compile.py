@@ -509,7 +509,7 @@ def hybrid_decode_compiled(hybrid_cell):
     T = cfg.max_seq // lay.block_size
     step = make_recurrent_decode_step(cfg, block_size=lay.block_size,
                                       n_table=T)
-    return step.lower(params, pool, pool, conv, ssm,
+    return step.lower(params, (pool, pool), (conv, ssm),
                       on_chip((rows, T + 3), jnp.int32)).compile()
 
 
@@ -563,7 +563,7 @@ def test_hybrid_chunk_prefill_fits_and_moves_no_pool(hybrid_cell):
     chunk = make_recurrent_chunk_fn(cfg, chunk=cfg.ssm_chunk,
                                     block_size=lay.block_size, n_table=T)
     compiled = chunk.lower(
-        params, pool, pool, conv, ssm,
+        params, (pool, pool), (conv, ssm),
         on_chip((T + cfg.ssm_chunk + 3,), jnp.int32)).compile()
     _assert_pool_stays_put(compiled, lay)
     _assert_state_stays_put(compiled, ssm)
@@ -579,7 +579,7 @@ def _no_expert_stack_is_copied(text, params):
     tiles is re-laid-out whole on every pass (660 MB a layer at 64 x
     2688 x 1856: ``ops/routed_experts.lanes``)."""
     stacks = {"bf16[" + ",".join(map(str, lp["ffn"][name].shape)) + "]"
-              for lp in params["layers"] if "ffn" in lp
+              for lp in params["layers"] if "router" in lp.get("ffn", ())
               for name in ("w_in", "w_out")}
     assert len(stacks) == 2
     for shape in stacks:
@@ -643,7 +643,7 @@ def nano_decode_compiled(nano_cell):
     T = cfg.max_seq // lay.block_size
     step = make_recurrent_decode_step(cfg, block_size=lay.block_size,
                                       n_table=T)
-    return step.lower(params, pool, pool, conv, ssm,
+    return step.lower(params, (pool, pool), (conv, ssm),
                       on_chip((rows, T + 3), jnp.int32)).compile()
 
 
@@ -705,7 +705,7 @@ def test_nano_chunk_prefill_fits_and_moves_no_pool(nano_cell):
     assert C == cfg.ssm_chunk == 128
     chunk = make_recurrent_chunk_fn(cfg, chunk=C, block_size=lay.block_size,
                                     n_table=T)
-    compiled = chunk.lower(params, pool, pool, conv, ssm,
+    compiled = chunk.lower(params, (pool, pool), (conv, ssm),
                            on_chip((T + C + 3,), jnp.int32)).compile()
     _assert_pool_stays_put(compiled, lay)
     _assert_state_stays_put(compiled, ssm)
@@ -727,3 +727,111 @@ def test_grouped_matmul_is_one_kernel_at_both_layouts(hybrid_decode_compiled,
     assert len(calls(nano_decode_compiled, "%gmm")) == 10
     for compiled in (hybrid_decode_compiled, nano_decode_compiled):
         assert not calls(compiled, "%ragged-dot")
+
+
+# ---------------------------------------------------------------------------
+# the latent-attention layout: ONE latent pool, the one-token kernel with
+# the up-projection absorbed, the window form a key block at a time
+
+
+@pytest.fixture(scope="module")
+def latent_cell(one_chip):
+    """The shapes of ``serve-deepseek-v2-docqa8k-r80``, from the cell's
+    own configuration file through its traffic kind's ``model_config``:
+    7 layers (the first dense) at published widths, 20 of 160 experts
+    and an eighth of the vocabulary held, 32 rows, 28,672 + 1 blocks of
+    16 x 640 lanes, 592-block tables."""
+    import json
+    import os
+    from chipbench.traffic.open_loop_http_deepseek_v2 import model_config
+    from ray_tpu.models import hybrid
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "deepseek-v2-7L-e20.json")) as f:
+        config = json.load(f)
+    cfg, _, held = model_config(config)
+    engine = config["engine"]
+    on_chip = _on(one_chip)
+    lay = PoolLayout(*cfg.kv_geometry[:1], engine["n_blocks"] + 1,
+                     engine["kv_block_size"], *cfg.kv_geometry[1:], 1,
+                     cfg.value_lanes)
+    assert lay.shape == (7 * 28673, 16, 640)
+    params = jax.tree.map(
+        lambda s: on_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda: hybrid.init_params(cfg,
+                                                  jax.random.PRNGKey(0))))
+    return cfg, on_chip, params, on_chip(lay.shape, cfg.dtype), lay, engine
+
+
+def _latent_program(latent_cell, which):
+    from ray_tpu.inference.recurrent import (make_recurrent_chunk_fn,
+                                             make_recurrent_decode_step)
+    cfg, on_chip, params, pool, lay, engine = latent_cell
+    T = -(-engine["max_seq"] // lay.block_size)
+    if which == "step":
+        fn = make_recurrent_decode_step(cfg, block_size=lay.block_size,
+                                        n_table=T)
+        packed = on_chip((engine["max_slots"], T + 3), jnp.int32)
+    else:
+        C = engine["prefill_chunk"]
+        fn = make_recurrent_chunk_fn(cfg, chunk=C,
+                                     block_size=lay.block_size, n_table=T)
+        packed = on_chip((T + C + 3,), jnp.int32)
+    return fn.lower(params, (pool,), (), packed).compile()
+
+
+def _no_table_span_by_heads(text, cfg, lay, engine):
+    """No array holds the table's span of keys (or the cache's width in
+    positions) beside the 128 heads: scores or decompressed K/V of a
+    whole row."""
+    span = -(-engine["max_seq"] // lay.block_size) * lay.block_size
+    for shape in set(re.findall(r"(?:bf16|f32)\[([\d,]+)\]", text)):
+        dims = [int(d) for d in shape.split(",")]
+        assert not (cfg.n_heads in dims and (
+            span in dims or engine["max_seq"] in dims)), shape
+
+
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_latent_programs_fit_and_move_no_pool(latent_cell, which):
+    cfg, _, params, _, lay, engine = latent_cell
+    compiled = _latent_program(latent_cell, which)
+    _assert_pool_stays_put(compiled, lay, n_pools=1)
+    text = compiled.as_text()
+    _no_table_span_by_heads(text, cfg, lay, engine)
+    _no_expert_stack_is_copied(text, params)
+    # no weight of the latent mixer is re-laid out a pass (the fused
+    # W_qb / W_kvb were: 75 + 33 MB of copies a layer)
+    big = [m.group(1) for m in re.finditer(
+        r"= \(?bf16\[([\d,]*)\]\S* copy\(", text)
+        if np.prod([int(d) for d in m.group(1).split(",")]) >= 8e6]
+    if which == "chunk":
+        # the decode step's layout of W_uk / W_uv (heads, latent, lanes)
+        # is not the decompression's: the chunk program turns each ONCE
+        # a layer, outside its loop over key blocks (2 x 16.8 MB of the
+        # ~0.3 GB a layer's other weights stream anyway)
+        assert big.count("128,512,128") <= 2 * cfg.n_latent
+        # ... and the window's rotated queries, an activation, are
+        # brought heads-first for the kernel (16 MB a layer)
+        rope_q = f"128,{engine['prefill_chunk']},64"
+        big = [b for b in big if b not in ("128,512,128", rope_q)]
+    assert not big, big
+    calls = _kernel_calls(text, "latent_decode_attention")
+    if which == "chunk":
+        # the window kernel once a layer, inside the loop over key blocks
+        assert not calls
+        assert len(_kernel_calls(text, "latent_window_attention")) \
+            == cfg.n_latent
+        return
+    # ONE one-token latent kernel a layer, the pool its operand as stored
+    assert len(calls) == cfg.n_latent == 7
+    pool = "bf16[" + ",".join(map(str, lay.shape)) + "]"
+    from chipbench import deepseek_v2_trace
+    marks = deepseek_v2_trace.marks_of(
+        {"num_experts_per_tok": 6, "n_routed_experts": 160,
+         "num_attention_heads": 128, "qk_nope_head_dim": 128,
+         "v_head_dim": 128}, engine["max_slots"], engine["prefill_chunk"])
+    for line in calls:
+        assert pool in line.split(" custom-call(", 1)[1]
+        assert "bf16[32,128,512]" in line.split(" custom-call(", 1)[0]
+        assert deepseek_v2_trace.label_of(line, marks) \
+            == "latent_decode_attention"

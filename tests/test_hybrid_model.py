@@ -526,8 +526,8 @@ def test_programs_chunks_then_decode_equal_reference_logits(fam):
         n_q = min(C, n_prompt - pos)
         toks = np.zeros(C, np.int32)
         toks[:n_q] = seq[pos:pos + n_q]
-        logits, load, k, v, conv, s_ = chunk(
-            params, k, v, conv, s_,
+        logits, load, (k, v), (conv, s_) = chunk(
+            params, (k, v), (conv, s_),
             pack_chunk(table, toks, pos, row, n_q))
         np.testing.assert_allclose(np.asarray(logits)[:n_q],
                                    want[pos:pos + n_q], atol=fam.atol)
@@ -546,8 +546,8 @@ def test_programs_chunks_then_decode_equal_reference_logits(fam):
         tokens = np.zeros(n_rows, np.int32)
         positions = np.zeros(n_rows, np.int32)
         tokens[row], positions[row] = seq[pos], pos
-        logits, load, k, v, conv, s_ = step(
-            params, k, v, conv, s_,
+        logits, load, (k, v), (conv, s_) = step(
+            params, (k, v), (conv, s_),
             pack_step(tables, tokens, positions, active))
         np.testing.assert_allclose(np.asarray(logits)[row], want[pos],
                                    atol=fam.atol)
