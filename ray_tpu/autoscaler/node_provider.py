@@ -91,7 +91,12 @@ class LocalNodeProvider(NodeProvider):
         # distinct session prefix => distinct shm arena (arena name is
         # derived from session[:8])
         session = f"a{self._n:03d}{uuid.uuid4().hex[:8]}"
+        # --port 0: several nodes share this host, and the service's own
+        # default (6379) let only ONE of them bind, on the whole machine:
+        # two tests that launched a local node at once took turns failing
+        # with "no alive node joined the launched head" (PR 43)
         args = [sys.executable, "-m", "ray_tpu.core.node",
+                "--port", "0",
                 "--head-address", head_address,
                 "--session", session,
                 "--session-dir", os.path.join(self._base, node_id),

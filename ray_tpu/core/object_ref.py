@@ -9,6 +9,7 @@ via ``ray_tpu.get``.
 from __future__ import annotations
 
 import asyncio
+import collections
 import concurrent.futures
 import threading
 from typing import Any, Callable, Optional
@@ -35,6 +36,8 @@ class _RefTracker:
         self._pending: list[bytes] = []
         self._sink: Optional[Callable[[list], None]] = None
         self._timer: Optional[threading.Timer] = None
+        # deaths recorded and not yet counted (see ``decref``)
+        self._dead: collections.deque[bytes] = collections.deque()
 
     def set_sink(self, sink: Optional[Callable[[list], None]]) -> None:
         with self._lock:
@@ -43,35 +46,64 @@ class _RefTracker:
     def incref(self, ob: bytes) -> None:
         with self._lock:
             self._counts[ob] = self._counts.get(ob, 0) + 1
+        self._settle()
 
     def decref(self, ob: bytes) -> None:
-        flush = False
-        with self._lock:
+        """A death never WAITS for a lock.  ``ObjectRef.__del__`` calls
+        this, and the collector runs a finalizer on whatever thread
+        crosses its threshold, after any call, under any lock — this
+        tracker's own included: a reference dying while THIS thread
+        made the flush timer under the lock waited for itself for good
+        (the tier-1 run that never ended, PR 43).  So a death is
+        recorded, and counted by whoever has the lock; and a full batch
+        is flushed by the timer's thread, not from here, where the
+        sink's own locks (the client's send lock) may be this thread's
+        too."""
+        self._dead.append(ob)
+        self._settle()
+
+    def _settle(self) -> None:
+        """Count the recorded deaths, unless another frame is at it:
+        that frame looks again after it lets the lock go, so a death
+        recorded behind its back is not left lying."""
+        while self._dead and self._lock.acquire(blocking=False):
+            try:
+                self._count_deaths()
+            finally:
+                self._lock.release()
+
+    def _count_deaths(self) -> None:
+        """With the lock held."""
+        while self._dead:
+            ob = self._dead.popleft()
             c = self._counts.get(ob)
             if c is None:
-                return
-            if c <= 1:
-                del self._counts[ob]
-                if self._sink is not None:
-                    self._pending.append(ob)
-                    flush = len(self._pending) >= self._FLUSH_BATCH
-                    if not flush and self._timer is None:
-                        self._timer = threading.Timer(self._FLUSH_DELAY,
-                                                      self.flush)
-                        self._timer.daemon = True
-                        self._timer.start()
-            else:
+                continue
+            if c > 1:
                 self._counts[ob] = c - 1
-        if flush:
-            self.flush()
+                continue
+            del self._counts[ob]
+            if self._sink is None:
+                continue
+            self._pending.append(ob)
+            full = len(self._pending) == self._FLUSH_BATCH
+            if full or self._timer is None:
+                if self._timer is not None:
+                    self._timer.cancel()
+                self._timer = threading.Timer(
+                    0.0 if full else self._FLUSH_DELAY, self.flush)
+                self._timer.daemon = True
+                self._timer.start()
 
     def flush(self) -> None:
         with self._lock:
+            self._count_deaths()
             if self._timer is not None:
                 self._timer.cancel()
                 self._timer = None
             batch, self._pending = self._pending, []
             sink = self._sink
+        self._settle()
         if sink is not None and batch:
             try:
                 sink(batch)
@@ -79,6 +111,7 @@ class _RefTracker:
                 pass   # connection racing shutdown: storage dies with it
 
     def held_count(self, ob: bytes) -> int:
+        self._settle()
         with self._lock:
             return self._counts.get(ob, 0)
 

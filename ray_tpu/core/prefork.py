@@ -75,6 +75,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--socket", required=True)
     args = ap.parse_args()
+    # who to watch for (below), read BEFORE the second of imports: a
+    # node that died meanwhile left a template that took its adopter
+    # for its parent and stayed for good
+    parent = os.getppid()
 
     # Pre-import the worker's dependency graph — the whole point of the
     # template.  Everything a worker touches before user code: client,
@@ -107,7 +111,6 @@ def main() -> None:
     # a plain accept() would orphan this template forever.  Poll for
     # reparenting (our parent IS the node service process).
     lst.settimeout(1.0)
-    parent = os.getppid()
     while True:
         try:
             conn, _ = lst.accept()

@@ -190,6 +190,10 @@ def test_local_provider_end_to_end(tmp_path):
     cfgp.write_text(yaml.safe_dump({
         "cluster_name": "loc1",
         "provider": {"type": "local", "base_dir": str(tmp_path / "nodes")},
+        # a port asked of the OS: the default, 6380, is held for good by
+        # any head a killed run left behind, and this test then failed
+        # with "local head did not publish its address"
+        "head": {"port": 0},
         "initial_workers": 1,
         "worker_nodes": {"num_cpus": 2}}))
     cfg = C.load_cluster_config(str(cfgp))

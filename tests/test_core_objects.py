@@ -212,3 +212,45 @@ def test_nested_ref_survives_inner_release(rt):
             break
         time.sleep(0.2)
     assert ray_tpu.object_store_stats()["num_objects"] == 0
+
+
+def test_reference_dying_under_the_trackers_lock_does_not_wait_for_it(
+        monkeypatch):
+    """The collector runs a dying ObjectRef's ``__del__`` on whatever
+    thread crosses its threshold, after any call — also while that
+    thread is inside the tracker, making the flush timer under its lock.
+    The second death must not wait for the lock the first one holds
+    (the tier-1 run that never reached its end, PR 43): both are
+    counted, both are released, once."""
+    import threading
+
+    from ray_tpu.core import object_ref
+
+    tracker = object_ref._RefTracker()
+    released = []
+    done = threading.Event()
+
+    def sink(batch):
+        released.extend(batch)
+        done.set()
+
+    tracker.set_sink(sink)
+    tracker.incref(b"first")
+    tracker.incref(b"second")
+
+    class TimerThatCollects(threading.Timer):
+        """What the collector did, made certain: a finalizer runs
+        inside ``threading.Timer(...)``."""
+        def __init__(self, *args, **kwargs):
+            tracker.decref(b"second")
+            super().__init__(*args, **kwargs)
+
+    monkeypatch.setattr(object_ref.threading, "Timer", TimerThatCollects)
+    dying = threading.Thread(target=tracker.decref, args=(b"first",),
+                             daemon=True)
+    dying.start()
+    dying.join(timeout=10)
+    assert not dying.is_alive(), "a dying reference waited for itself"
+    assert done.wait(timeout=10)
+    assert sorted(released) == [b"first", b"second"]
+    assert tracker.held_count(b"first") == tracker.held_count(b"second") == 0

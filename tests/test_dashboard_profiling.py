@@ -97,25 +97,27 @@ def test_stack_cli(rt, capsys, tmp_path):
     from ray_tpu.scripts import main as cli_main
 
     stop = tmp_path / "release_hold"
+    started = tmp_path / "hold_started"
 
     @ray_tpu.remote
-    def hold(stop_path):
+    def hold(started_path, stop_path):
         # run until the test has captured the stack — a fixed sleep
         # raced the dump under parallel suite load
         import os as _os
+        open(started_path, "w").close()
         deadline = time.time() + 60
         while not _os.path.exists(stop_path) and time.time() < deadline:
             time.sleep(0.1)
         return 1
 
-    ref = hold.remote(str(stop))
+    ref = hold.remote(str(started), str(stop))
+    # wait for the task BODY, not for a busy worker: a worker is busy
+    # from the dispatch on, while it still unpickles the function, and
+    # a stack taken then has no frame of `hold` (failed alone, every run)
     deadline = time.time() + 60
-    while time.time() < deadline:
-        svc = rt.node_service
-        if any(c.kind == "worker" and c.state == "busy"
-               for c in svc.clients.values()):
-            break
-        time.sleep(0.2)
+    while time.time() < deadline and not started.exists():
+        time.sleep(0.05)
+    assert started.exists(), "hold never started"
     rc = cli_main(["stack", "--address", rt.node_service.address])
     out = capsys.readouterr().out
     stop.write_text("go")

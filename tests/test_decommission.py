@@ -72,7 +72,16 @@ def test_node_decommission_e2e_8_nodes(cluster):
         return i
 
     big_ref = produce.remote()
-    inner_ref = ray_tpu.get(put_inner.remote(), timeout=120)
+    # the container is KEPT: a reference read out of a stored value is
+    # a borrowed one, which v1's ownership does not count (the inner
+    # object lives while its container does: node_transfer.
+    # _track_nested).  With the container a temporary, its release and
+    # the putting worker's reached the victim whenever the 0.5 s flush
+    # timers and the next task let them: after the handoff, mostly; on a
+    # loaded box DURING the drain, and the handoff then found nothing to
+    # hand over and the read below timed out (the take-turns failure)
+    container_ref = put_inner.remote()
+    inner_ref = ray_tpu.get(container_ref, timeout=120)
 
     # wait until the victim-held result settled at its owner (so the
     # drain exercises the HANDOFF, not in-flight forwarding)
@@ -119,6 +128,7 @@ def test_node_decommission_e2e_8_nodes(cluster):
 
     # and the cluster keeps serving on the survivors
     assert ray_tpu.get(work.remote(99), timeout=120) == 99
+    del container_ref
 
 
 def test_draining_node_takes_no_new_placements(cluster):

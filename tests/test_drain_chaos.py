@@ -139,6 +139,15 @@ def test_node_killed_mid_decommission_recovers_via_lineage():
         plan.script(hard_kill, point="node_drain_handoff", nth=1)
         with fault_injection.injected(plan):
             ray_tpu.drain_node(victim.node_id.hex(), deadline_s=10)
+            # the read follows the DEATH: drain_node returns when the
+            # drain has begun, and a read sent at once sometimes pulled
+            # the only copy off the victim before the scripted kill
+            # landed; nothing was then lost and nothing reconstructed
+            # (recons 0: failed alone and in turns, by box load)
+            deadline = time.time() + 60
+            while victim._thread.is_alive() and time.time() < deadline:
+                time.sleep(0.05)
+            assert not victim._thread.is_alive(), "scripted kill never landed"
             out = ray_tpu.get(ref, timeout=120)
         assert out.shape == (200_000,) and out[123] == 123
         recons = sum(lin["recons"] for lin in n0.lineage.values())

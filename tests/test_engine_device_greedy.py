@@ -86,8 +86,17 @@ def test_mixed_batch_greedy_exact_and_sampled_reproduce(
     max_new = 10
     eng = _engine(params, cfg)
     try:
-        reqs = [eng.submit(p, max_new=max_new, temperature=t, seed=7 + i)
-                for i, (p, t) in enumerate(zip(prompts, temps))]
+        reqs = []
+        for i, (p, t) in enumerate(zip(prompts, temps)):
+            reqs.append(eng.submit(p, max_new=max_new, temperature=t,
+                                   seed=7 + i))
+            if first_long and i == 0:
+                # "on an idle engine" is the loop's to see: it prefills
+                # the shortest remaining prompt first, so once it sees
+                # all four the three short ones decode before the long
+                # one starts and it chunks (the every-run failure on a
+                # quiet box, 0 == 1).  The others follow its first token.
+                next(reqs[0].stream(timeout=300))
         outs = [r.result(timeout=300) for r in reqs]
         st, calls = eng.stats(), sorted(sampling_calls)
     finally:

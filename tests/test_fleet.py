@@ -309,8 +309,12 @@ def test_engine_priority_preempts_at_prefill_boundary():
 
 
 def test_fleet_http_shed_returns_429_with_retry_after():
+    # a token every 100 s: at 0.5 a second the bucket refilled whenever
+    # the two POSTs below took 2 s together (the first one compiles), and
+    # the third was then admitted, not shed (took turns failing under
+    # six workers)
     _run_fleet(num_replicas=1,
-               fleet_cfg=FleetConfig(rate=0.5, burst=2,
+               fleet_cfg=FleetConfig(rate=0.01, burst=2,
                                      max_queue_depth=0),
                http=True)
     addr = serve.proxy_address()

@@ -53,31 +53,37 @@ def test_oom_kill_retries_without_losing_node(local_rt, tmp_path):
             time.sleep(0.05)
         return "done"
 
-    _press(svc)                      # simulated pressure: no allocation
     ref = hog.remote(str(marker), str(stop))
-    # wait for the FIRST execution's pid, then for that process to die —
-    # asserting on oom_kill_count alone raced: a kill could be counted
-    # while the hog itself survived to finish without a retry.  Every
-    # wait below is an event poll with a WIDE deadline (box-load
-    # dependent flake, PR 9's tier-1 run): the deadlines only bound a
-    # genuinely hung monitor, they are not the expected durations.
+    # wait for the FIRST execution's pid, THEN press, then wait for that
+    # process to die.  Pressing before the submit let the monitor kill
+    # the worker 0.3-0.55 s after it, before the task body had written
+    # its pid: the pid read here was then the RETRY's, which nothing
+    # would ever kill once the pressure was relaxed, and the test waited
+    # out its whole deadline (300 s, every run).  Asserting on
+    # oom_kill_count alone raced too: a kill could be counted while the
+    # hog itself survived to finish without a retry.  Every wait below
+    # is an event poll with a WIDE deadline (box-load dependent flake,
+    # PR 9's tier-1 run): the deadlines only bound a genuinely hung
+    # monitor, they are not the expected durations.
     deadline = time.time() + 120
-    while time.time() < deadline and not marker.exists():
+    while time.time() < deadline and not (
+            marker.exists() and marker.read_text().split()):
         time.sleep(0.05)
     assert marker.exists(), "hog never started"
     first_pid = int(marker.read_text().split()[0])
+    _press(svc)                      # simulated pressure: no allocation
     # relax the INSTANT the kill is counted: pressure left on past this
     # point raced the retry — the monitor could kill the re-executed hog
     # too, burn the max_retries=2 budget, and the get() below surfaced
     # OutOfMemoryError under suite load.  The kill just counted still
     # has to land on first_pid, so relaxing here forfeits nothing the
     # later assertions need.
-    deadline = time.time() + 300
+    deadline = time.time() + 120
     while time.time() < deadline and svc.oom_kill_count < 1:
         time.sleep(0.05)
     assert svc.oom_kill_count >= 1, "monitor never killed the hog"
     _relax(svc)
-    deadline = time.time() + 300
+    deadline = time.time() + 120
     while time.time() < deadline:
         try:
             os.kill(first_pid, 0)
@@ -88,7 +94,7 @@ def test_oom_kill_retries_without_losing_node(local_rt, tmp_path):
         raise AssertionError("killed worker process never exited")
     stop.write_text("go")            # let the retried execution finish
 
-    assert ray_tpu.get(ref, timeout=300) == "done"
+    assert ray_tpu.get(ref, timeout=120) == "done"
     pids = [int(x) for x in marker.read_text().split()]
     assert len(pids) >= 2, "task was not re-executed on a new worker"
     assert pids[0] != pids[-1]

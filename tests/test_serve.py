@@ -116,7 +116,10 @@ def test_batching_wrong_length_raises_clearly():
     caller with an error naming the function and both lengths — never
     fan out misaligned results."""
 
-    @serve.batch(max_batch_size=4, batch_wait_timeout_s=0.01)
+    # the batch of four forms when the fourth caller arrives, never on
+    # the timer: at 10 ms a loaded box flushed the threads' calls in
+    # smaller batches, whose errors name other lengths than 3 and 4
+    @serve.batch(max_batch_size=4, batch_wait_timeout_s=30)
     def truncating(items):
         return items[:-1]               # one result short
 
