@@ -549,13 +549,16 @@ def _zero_row(conv, ssm, row):
 
 
 class StatePool:
-    """Per-row recurrent state of a model's state-space layers: the
-    second kind of serving state, owned by the ``BlockPool`` whose blocks
-    hold the attention layers' K/V.
+    """Per-row recurrent state of a model's state-space or
+    linear-attention layers: the second kind of serving state, owned by
+    the ``BlockPool`` whose blocks hold the attention layers' K/V.
 
-    Two preallocated arrays ``[recurrent layers, rows, ...]`` —
-    ``conv`` (the causal convolution's last inputs, in the activation
-    dtype) and ``ssm`` (the selective-scan state, float32) — indexed by
+    Two preallocated arrays ``[recurrent layers, rows, ...]``, their
+    shapes the model's (``cfg.state_geometry``) — ``conv`` (the causal
+    convolution's last inputs, in the activation dtype) and ``ssm``
+    (float32: Mamba-2's selective-scan state ``[heads * head width,
+    state]``, or the delta rule's matrix state ``[keys, heads *
+    values]``) — indexed by
     DECODE ROW: a row's state is the fixed-size summary of everything
     the row has read, so there is nothing to page.  Like the K/V pools
     they are donated to every compiled program and updated in place.  A

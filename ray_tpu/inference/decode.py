@@ -108,6 +108,7 @@ from ray_tpu.inference.cache import POOL_AXES, PoolLayout, heads_shards
 from ray_tpu.models import gpt
 from ray_tpu.models.gpt import GPTConfig
 from ray_tpu.ops.attention import (KEY_BLOCK, _per_shard,
+                                   head_window_attention,
                                    latent_decode_attention,
                                    latent_window_attention, packed_attention,
                                    paged_decode_attention)
@@ -189,9 +190,49 @@ def make_prefill_fn(cfg: GPTConfig, *, mesh=None,
 # the whole pool).
 
 
+# K/V heads up to which a window of queries attends the gathered table
+# as stored (``packed_attention``: every head's product spans the FULL
+# stored width, K/V heads x the arithmetic, bought back where heads are
+# narrower than a lane tile and paid gladly where they are few); above
+# it, heads of whole lane tiles are attended head by head.  The cells
+# measured on the packed form have 25 heads of 64 lanes, and 8 and 2
+# heads of 128.
+PACKED_MAX_HEADS = 8
+
+
+def window_by_head(lay: PoolLayout) -> bool:
+    """Whether a window of queries over ``lay``'s pools is attended
+    head by head, a block of keys at a time (``paged_attend``'s
+    ``q_pos``), and not packed over the gathered table (``mask``)."""
+    return (lay.shards == 1 and lay.head_dim % 128 == 0
+            and lay.n_heads > PACKED_MAX_HEADS)
+
+
+def _key_blocks(lay: PoolLayout, tables, q_pos):
+    """ONE row's table walked ``KEY_BLOCK`` keys at a time: whole key
+    blocks, the last one padded with the scratch block (its keys lie
+    past every query).  -> (``read_keys(pool, layer, j)``: key block
+    ``j`` of that pool and layer as stored, [keys, width]; the walk's
+    ``key_block`` and ``n_blocks``: the blocks that hold a key of the
+    window's last query)."""
+    bs = lay.block_size
+    per = KEY_BLOCK // bs if KEY_BLOCK % bs == 0 else 1
+    table = jnp.pad(tables[0], (0, -tables.shape[1] % per))
+
+    def read_keys(pool, layer, j):
+        ids = lax.dynamic_slice_in_dim(table, j * per, per)
+        return pool[lay.rows(layer, ids)].reshape(per * bs, lay.width)
+
+    return read_keys, dict(
+        key_block=per * bs,
+        n_blocks=jnp.minimum(jnp.max(q_pos) // (per * bs) + 1,
+                             table.shape[0] // per))
+
+
 def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
                  mesh=None, rules=None, kv_lengths=None, mask=None,
-                 mask_tables=None, q_per_kv: int = 1, scale=None):
+                 mask_tables=None, q_per_kv: int = 1, scale=None,
+                 q_pos=None):
     """Where a window meets the pool, for every model family:
     ``attend_for(layer)`` gives that layer's ``attend(q [b, h, w, hd],
     k, v [b, w, h_kv, hd]) -> o [b, h, w, hd]`` over the (K, V)
@@ -213,6 +254,11 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
         W], keys in position order, heads still packed as stored, and
         attended so (``packed_attention``); with a mesh the contexts
         are constrained to the pool's heads sharding.
+      * ``q_pos`` [w] — ONE row's window (b = 1) of causal queries at
+        those positions, head by head: the table walked ``KEY_BLOCK``
+        keys at a time, each block gathered and attended under a
+        running softmax (``head_window_attention``); no array of the
+        table's span, no product wider than a head.
       * both (``make_paged_step_chunk``) — ONE window [1, n + w] that
         holds ``n`` = ``len(kv_lengths)`` one-token rows and then a
         window of ``w`` queries: committed together, the first ``n``
@@ -234,6 +280,15 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
         return walk(q, *held["pools"], lay.rows(layer, 0), tables,
                     kv_lengths)
 
+    def by_head(q, layer):
+        read_keys, walk = _key_blocks(lay, tables, q_pos)
+        return head_window_attention(
+            q[0], lambda j, n: tuple(read_keys(p, layer, j)
+                                     for p in held["pools"]),
+            q_pos, n_kv_heads=lay.n_heads,
+            scale=lay.head_dim ** -0.5 if scale is None else scale,
+            **walk)[None]
+
     def window(q, layer, of):
         ctx_k, ctx_v = (
             gpt._constrain(lay.read(p, layer, of),
@@ -249,6 +304,8 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
             held["pools"] = tuple(
                 lay.commit(p, layer, blocks, offsets, x)
                 for p, x in zip(held["pools"], new))
+            if q_pos is not None:
+                return by_head(q, layer)
             if mask is None:
                 return rows(q, layer)
             if kv_lengths is None:
@@ -283,7 +340,7 @@ def latent_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
         gathered, decompressed and attended under a running softmax
         (``latent_window_attention``); no array of the table's span."""
     held = {"pools": pools}
-    rank, bs = lay.value_lanes, lay.block_size
+    rank = lay.value_lanes
 
     def attend_for(layer):
         def attend(q_nope, q_rope, latent, w_uk, w_uv):
@@ -301,21 +358,11 @@ def latent_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
                     value_lanes=rank, scale=scale)
                 o = jnp.einsum("bhc,hcd->bhd", o_lat, w_uv)
                 return o.reshape(o.shape[0], 1, -1)
-            # whole key blocks of the table, the last one padded with
-            # the scratch block (its keys lie past every query)
-            per = KEY_BLOCK // bs if KEY_BLOCK % bs == 0 else 1
-            table = jnp.pad(tables[0], (0, -tables.shape[1] % per))
-
-            def read_keys(j, n):
-                ids = lax.dynamic_slice_in_dim(table, j * per, per)
-                return pool[lay.rows(layer, ids)].reshape(n, lay.width)
-
-            span = table.shape[0] // per
+            read_keys, walk = _key_blocks(lay, tables, q_pos)
             return latent_window_attention(
-                q_nope[0], q_rope[0], read_keys, w_uk, w_uv, q_pos,
-                scale=scale, key_block=per * bs,
-                n_blocks=jnp.minimum(jnp.max(q_pos) // (per * bs) + 1,
-                                     span))[None]
+                q_nope[0], q_rope[0],
+                lambda j, n: read_keys(pool, layer, j), w_uk, w_uv,
+                q_pos, scale=scale, **walk)[None]
         return attend
     return attend_for, held
 

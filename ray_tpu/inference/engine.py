@@ -691,6 +691,12 @@ class InferenceEngine:
         # and the (query, key) pairs under the causal mask
         self._chunk_keys = 0
         self._chunk_query_keys = 0
+        # a model with linear-attention layers (a matrix state a row):
+        # rows whose state a one-token pass wrote, and real prompt
+        # tokens through the window form; 0 for every other model
+        self._linear = getattr(cfg, "n_linear", 0) > 0
+        self._linear_state_rows_advanced = 0
+        self._linear_chunk_tokens = 0
         self._peak_active = 0
         self._spec_drafted = 0         # drafted tokens offered to verify
         self._spec_accepted = 0        # drafted tokens accepted
@@ -1295,6 +1301,8 @@ class InferenceEngine:
         self._prefill_tokens += n_q
         self._chunk_keys += pos + n_q
         self._chunk_query_keys += n_q * pos + n_q * (n_q + 1) // 2
+        if self._linear:
+            self._linear_chunk_tokens += n_q
         chunk_toks = np.zeros(C, np.int32)
         chunk_toks[:n_q] = prompt[pos:pos + n_q]
         with self._acct.phase("pack") as up:
@@ -1799,6 +1807,9 @@ class InferenceEngine:
                     (self._positions[self._active]
                      // self.engine_cfg.kv_block_size + 1).sum())
                 self._kv_blocks_tabled += self._tables.size
+                if self._linear:
+                    self._linear_state_rows_advanced += int(
+                        self._active.sum())
                 greedy = self._seam.greedy(self, logits)
                 stepped = 0
                 for row in list(self._slot_req):
@@ -2135,6 +2146,8 @@ class InferenceEngine:
             "kv_blocks_tabled": kv_tabled,
             "chunk_keys": self._chunk_keys,
             "chunk_query_keys": self._chunk_query_keys,
+            "linear_state_rows_advanced": self._linear_state_rows_advanced,
+            "linear_chunk_tokens": self._linear_chunk_tokens,
             # decode and first tokens by where they were chosen: by a
             # program's own argmax (the integers a pass fetches), or by
             # a dispatch of their own on the logits (a sampled row, a
@@ -2242,6 +2255,7 @@ def metrics_snapshot() -> list:
     admits, chunks, in_step, ptoks = {}, {}, {}, {}
     kv_att, kv_tab = {}, {}
     ckeys, cqkeys = {}, {}
+    lrows, ltoks = {}, {}
     on_dev, sampled, fbytes = {}, {}, {}
     tps, arate, saccept = {}, {}, {}
     meshdev, tpsh = {}, {}
@@ -2279,6 +2293,8 @@ def metrics_snapshot() -> list:
         kv_tab[key] = float(st["kv_blocks_tabled"])
         ckeys[key] = float(st["chunk_keys"])
         cqkeys[key] = float(st["chunk_query_keys"])
+        lrows[key] = float(st.get("linear_state_rows_advanced", 0))
+        ltoks[key] = float(st.get("linear_chunk_tokens", 0))
         on_dev[key] = float(st["tokens_greedy_on_device"])
         sampled[key] = float(st["tokens_sampled"])
         fbytes[key] = float(st["fetch_bytes"])
@@ -2360,6 +2376,12 @@ def metrics_snapshot() -> list:
         ("ray_tpu_inference_chunk_query_keys_total", "counter",
          "(query, key) pairs under the causal mask, summed over prefill "
          "chunk passes", cqkeys or zero),
+        ("ray_tpu_inference_linear_state_rows_advanced_total", "counter",
+         "Rows whose linear-attention matrix state a one-token decode "
+         "pass wrote, summed over passes", lrows or zero),
+        ("ray_tpu_inference_linear_chunk_tokens_total", "counter",
+         "Real prompt tokens through the window form of the delta rule, "
+         "summed over prefill chunk passes", ltoks or zero),
         ("ray_tpu_inference_tokens_greedy_on_device_total", "counter",
          "Decode and first tokens chosen by a serving program's own "
          "argmax (a pass fetches the integers, not the logits)",

@@ -61,7 +61,8 @@ import jax.numpy as jnp
 
 from ray_tpu.inference.cache import PoolLayout
 from ray_tpu.inference.decode import (_cached, latent_attend, paged_attend,
-                                      unpack_chunk, unpack_step)
+                                      unpack_chunk, unpack_step,
+                                      window_by_head)
 from ray_tpu.models import hybrid
 from ray_tpu.models.hybrid import HybridConfig
 
@@ -136,13 +137,14 @@ def _attend_over(cfg, lay, pools, blocks, offsets, tables, *,
                              scale=cfg.attention_multiplier,
                              kv_lengths=kv_lengths, q_pos=q_pos)
     mask = None
-    if q_pos is not None:
+    if q_pos is not None and not window_by_head(lay):
         S = tables.shape[-1] * lay.block_size
         mask = (jnp.arange(S)[None, :] <= q_pos[:, None])[None, None]
+        q_pos = None
     return paged_attend(lay, pools, blocks, offsets, tables,
                         q_per_kv=cfg.n_heads // cfg.n_kv_heads,
                         scale=cfg.attention_multiplier,
-                        kv_lengths=kv_lengths, mask=mask)
+                        kv_lengths=kv_lengths, mask=mask, q_pos=q_pos)
 
 
 def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,

@@ -1,17 +1,26 @@
-"""Hybrid language model: Mamba-2 mixers, attention mixers, latent
-attention mixers, routed experts and dense gated MLPs in a layer
-pattern.  ONE layer function serves the three layouts public configs of
-the kind have:
+"""Hybrid language model: Mamba-2 mixers, gated-delta-rule linear
+attention mixers, attention mixers, latent attention mixers, routed
+experts and dense gated MLPs in a layer pattern.  ONE layer function
+serves the four layouts public configs of the kind have:
 
     x0 = wte[ids] * embedding_multiplier            (no position embedding)
     x  = x + residual_multiplier * mixer(RMSNorm(x) * w)     per sublayer
+    x  = x + RMSNorm(mixer(x)) * w              ... with ``norm_output``
     logits = RMSNorm(x) @ W_head / logits_scaling   (W_head = wte^T if tied)
 
-``mixer`` is one of five kinds:
+``mixer`` is one of six kinds:
 
   * attention: grouped queries (``n_heads`` query heads over
     ``n_kv_heads`` K/V heads), no bias, no rotary; softmax(q k^T *
-    attention_multiplier, causal) v; output projection.
+    attention_multiplier, causal) v; output projection.  With
+    ``qk_norm`` an RMSNorm over the WHOLE query projection and one over
+    the whole key projection, before the heads are split.
+  * linear attention (ops/delta_rule.py, ``olmo_hybrid``): ``[q | k |
+    v] = silu(causal_conv(x [W_q | W_k | W_v]))`` (no bias), per head
+    ``q / |q| / sqrt(K)`` and ``k / |k|``; ``beta = 2 sigmoid(x w_b)``,
+    ``log alpha = -exp(A_log) softplus(x w_a + dt_bias)``; the gated
+    delta rule over a matrix state ``[V, K]`` a head; ``y = RMSNorm_V(
+    o) * w * silu(x W_g)`` per head; output projection.
   * Mamba-2 (ops/ssm.py): ``[z | xBC | dt] = in_proj(h)``; ``xBC =
     silu(causal_conv(xBC) + b)``; ``[x | B | C]`` with B, C in
     ``ssm_groups`` groups; ``dt = softplus(dt + dt_bias)``; ``A =
@@ -47,16 +56,17 @@ the kind have:
 
 A published layer is one such sublayer (``nemotron_h``: the pattern
 string's ``M`` / ``*`` / ``E``), or, with ``experts_in_every_layer``
-(``granitemoehybrid``, ``deepseek_v2``), a Mamba, attention or latent
-sublayer FOLLOWED by a feed-forward sublayer with its own norm and
-residual — experts, or the dense MLP in the first ``dense_layers``
-layers: the same function twice.
+(``granitemoehybrid``, ``deepseek_v2``, ``olmo_hybrid``), a Mamba,
+linear, attention or latent sublayer FOLLOWED by a feed-forward sublayer
+with its own norm and residual — experts, or the dense MLP in the first
+``dense_layers`` layers (``olmo_hybrid``: all of them): the same
+function twice.
 
 ``block`` takes a window of tokens per row and what the row's mixer needs
-from the past — for a Mamba sublayer the convolution and SSM state the
-row arrives with, for an attention or latent sublayer a function that
-attends the window's queries over the row's keys (and, latent, the
-rotary tables at the window's positions).  The full-sequence ``forward``
+from the past — for a Mamba or linear sublayer the convolution and
+SSM (or matrix) state the row arrives with, for an attention or latent
+sublayer a function that attends the window's queries over the row's
+keys (and, latent, the rotary tables at the window's positions).  The full-sequence ``forward``
 (zero state, keys = the window's own), the serving engine's
 chunk-prefill program ([1 row, chunk], state and K/V blocks from the
 pools) and its decode program ([rows, 1]) are that one function at three
@@ -80,12 +90,12 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import ssm
+from ray_tpu.ops import delta_rule, ssm
 from ray_tpu.ops.attention import KEY_BLOCK, latent_window_attention
 from ray_tpu.ops.routed_experts import lanes, mlp, routed_experts
 
 MAMBA, ATTENTION, EXPERTS = "mamba", "attention", "experts"
-LATENT, DENSE = "latent", "dense"
+LATENT, DENSE, LINEAR = "latent", "dense", "linear_attention"
 N_LOAD = 4      # numbers in ``run_layers``' load vector (its text names them)
 # the sublayer kinds of a ``nemotron_h`` pattern string
 PATTERN_KINDS = {"M": MAMBA, "*": ATTENTION, "E": EXPERTS}
@@ -153,6 +163,10 @@ class HybridConfig:
     ssm_groups: int = 1
     conv_width: int = 4
     ssm_chunk: int = 256
+    # linear attention mixer (the gated delta rule; ``conv_width`` too)
+    lin_heads: int = 0
+    lin_key_dim: int = 0
+    lin_value_dim: int = 0
     # latent attention mixer (``head_dim`` is its no-position lanes)
     q_rank: int = 0
     kv_rank: int = 0
@@ -174,6 +188,8 @@ class HybridConfig:
     dense_layers: int = 0            # leading layers: dense MLP, no experts
     dense_width: int = 0
     tied_head: bool = True
+    qk_norm: bool = False            # attention: RMSNorm of whole q and k
+    norm_output: bool = False        # a sublayer's norm on its OUTPUT
     # the first family's four multipliers
     embedding_multiplier: float = 12.0
     attention_multiplier: float = 1.0 / 128
@@ -185,7 +201,8 @@ class HybridConfig:
     param_dtype: Any = jnp.bfloat16  # as the published checkpoint
 
     def __post_init__(self):
-        bad = set(self.layer_types) - {MAMBA, ATTENTION, EXPERTS, LATENT}
+        bad = set(self.layer_types) - {MAMBA, ATTENTION, EXPERTS, LATENT,
+                                       LINEAR}
         if bad:
             raise ValueError(f"unknown layer types {sorted(bad)}")
         if self.experts_in_every_layer and EXPERTS in self.layer_types:
@@ -205,6 +222,9 @@ class HybridConfig:
         if LATENT in self.layer_types and set(self.layer_types) != {LATENT}:
             raise ValueError("latent and head-lane attention layers keep "
                              "different things: one pool holds one kind")
+        if MAMBA in self.layer_types and LINEAR in self.layer_types:
+            raise ValueError("Mamba and linear attention layers keep "
+                             "different states: one pool holds one kind")
         if self.route_groups and self.n_experts % self.route_groups[0]:
             raise ValueError(f"{self.n_experts} experts in "
                              f"{self.route_groups[0]} groups")
@@ -213,12 +233,15 @@ class HybridConfig:
     def from_published(cls, config: dict, **overrides) -> "HybridConfig":
         """From a public ``config.json``'s own keys: ``nemotron_h``'s
         where it has a ``hybrid_override_pattern``, ``deepseek_v2``'s
-        where it has a ``kv_lora_rank``, else ``granitemoehybrid``'s."""
+        where it has a ``kv_lora_rank``, ``olmo_hybrid``'s where it has
+        a ``linear_key_head_dim``, else ``granitemoehybrid``'s."""
         c = config
         if "hybrid_override_pattern" in c:
             return cls(**{**_nemotron_h_keys(c), **overrides})
         if "kv_lora_rank" in c:
             return cls(**{**_latent_keys(c), **overrides})
+        if "linear_key_head_dim" in c:
+            return cls(**{**_olmo_hybrid_keys(c), **overrides})
         kw = dict(
             vocab_size=c["vocab_size"], d_model=c["hidden_size"],
             layer_types=tuple(c["layer_types"][:c["num_hidden_layers"]]),
@@ -263,6 +286,10 @@ class HybridConfig:
         return self.layer_types.count(MAMBA)
 
     @property
+    def n_linear(self) -> int:
+        return self.layer_types.count(LINEAR)
+
+    @property
     def n_attention(self) -> int:
         return self.layer_types.count(ATTENTION)
 
@@ -290,6 +317,11 @@ class HybridConfig:
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
+    def lin_conv_channels(self) -> int:
+        """[q | k | v] of every linear-attention head."""
+        return self.lin_heads * (2 * self.lin_key_dim + self.lin_value_dim)
+
+    @property
     def n_held(self) -> int:
         return self.experts_held[1] - self.experts_held[0]
 
@@ -313,14 +345,22 @@ class HybridConfig:
 
     @property
     def state_geometry(self) -> tuple:
-        """Per row and recurrent layer: (layers, conv state shape, SSM
-        state shape).  The SSM state ``[heads, head width, state]`` is
-        STORED with heads and head width folded into one dim: a pool
-        whose trailing dims are ``(8192, 128)`` has one natural tiling,
-        so no program re-lays the whole pool out to suit its own
-        products (the chunk program did, a 2.4 GB copy, when the three
-        dims were kept apart).  None for a model without recurrent
-        layers: its whole past is blocks."""
+        """Per row and recurrent layer: (layers, conv state shape,
+        recurrent state shape).  The SSM state ``[heads, head width,
+        state]`` is STORED with heads and head width folded into one
+        dim: a pool whose trailing dims are ``(8192, 128)`` has one
+        natural tiling, so no program re-lays the whole pool out to
+        suit its own products (the chunk program did, a 2.4 GB copy,
+        when the three dims were kept apart).  The delta rule's matrix
+        state ``[heads, values, keys]`` is stored transposed with the
+        heads folded into the minor dim for the same reason, ``[keys,
+        heads * values]`` (``(96, 5760)``: whole tiles; ops/
+        delta_rule.py).  None for a model without recurrent layers: its
+        whole past is blocks."""
+        if self.n_linear:
+            return (self.n_linear,
+                    (self.conv_width - 1, self.lin_conv_channels),
+                    (self.lin_key_dim, self.lin_heads * self.lin_value_dim))
         if not self.n_mamba:
             return None
         return (self.n_mamba, (self.conv_width - 1, self.conv_channels),
@@ -365,22 +405,28 @@ def _nemotron_h_keys(c: dict) -> dict:
         max_seq=c["max_position_embeddings"])
 
 
+def _refuse_unless(*checks) -> None:
+    """``(key, value got, values with a form)`` each: the first whose
+    value has no form is refused by name."""
+    for key, got, ok in checks:
+        if got not in ok:
+            raise ValueError(f"{key} = {got!r} is not implemented (only "
+                             f"{' / '.join(map(repr, ok))})")
+
+
 def _latent_keys(c: dict) -> dict:
     """``HybridConfig`` fields from ``deepseek_v2`` keys.  What the layer
     function has no form for is refused here, by name."""
     rs = c.get("rope_scaling") or {}
-    for key, got, ok in (
-            ("rope_scaling.type", rs.get("type"), ("yarn",)),
-            ("topk_method", c.get("topk_method", "greedy"),
-             ("group_limited_greedy", "greedy")),
-            ("scoring_func", c.get("scoring_func", "softmax"), ("softmax",)),
-            ("moe_layer_freq", c.get("moe_layer_freq", 1), (1,)),
-            ("tie_word_embeddings", c.get("tie_word_embeddings", False),
-             (False,)),
-            ("attention_bias", c.get("attention_bias", False), (False,))):
-        if got not in ok:
-            raise ValueError(f"{key} = {got!r} is not implemented (only "
-                             f"{' / '.join(map(repr, ok))})")
+    _refuse_unless(
+        ("rope_scaling.type", rs.get("type"), ("yarn",)),
+        ("topk_method", c.get("topk_method", "greedy"),
+         ("group_limited_greedy", "greedy")),
+        ("scoring_func", c.get("scoring_func", "softmax"), ("softmax",)),
+        ("moe_layer_freq", c.get("moe_layer_freq", 1), (1,)),
+        ("tie_word_embeddings", c.get("tie_word_embeddings", False),
+         (False,)),
+        ("attention_bias", c.get("attention_bias", False), (False,)))
     if not c.get("q_lora_rank"):
         raise ValueError("q_lora_rank = None (a full-rank query "
                          "projection) is not implemented")
@@ -412,6 +458,42 @@ def _latent_keys(c: dict) -> dict:
         dense_width=c["intermediate_size"], tied_head=False,
         embedding_multiplier=1.0,
         attention_multiplier=qk ** -0.5 * yarn.softmax_mscale ** 2,
+        residual_multiplier=1.0, logits_scaling=1.0,
+        rms_eps=c["rms_norm_eps"], max_seq=c["max_position_embeddings"])
+
+
+def _olmo_hybrid_keys(c: dict) -> dict:
+    """``HybridConfig`` fields from ``olmo_hybrid`` keys.  What the
+    layer function has no form for is refused here, by name."""
+    kinds = {"linear_attention": LINEAR, "full_attention": ATTENTION}
+    types = c["layer_types"][:c["num_hidden_layers"]]
+    _refuse_unless(
+        ("rope_parameters.rope_theta",
+         (c.get("rope_parameters") or {}).get("rope_theta"), (None,)),
+        ("linear_num_key_heads", c["linear_num_key_heads"],
+         (c["linear_num_value_heads"],)),
+        ("linear_allow_neg_eigval", c.get("linear_allow_neg_eigval"),
+         (True,)),
+        ("hidden_act", c.get("hidden_act", "silu"), ("silu",)),
+        ("tie_word_embeddings", c.get("tie_word_embeddings", False),
+         (False,)),
+        ("attention_bias", c.get("attention_bias", False), (False,)),
+        *(("layer_types", t, tuple(kinds)) for t in types))
+    head_dim = c["hidden_size"] // c["num_attention_heads"]
+    return dict(
+        vocab_size=c["vocab_size"], d_model=c["hidden_size"],
+        layer_types=tuple(kinds[t] for t in types),
+        n_heads=c["num_attention_heads"],
+        n_kv_heads=c["num_key_value_heads"], head_dim=head_dim,
+        lin_heads=c["linear_num_value_heads"],
+        lin_key_dim=c["linear_key_head_dim"],
+        lin_value_dim=c["linear_value_head_dim"],
+        conv_width=c["linear_conv_kernel_dim"],
+        # every layer is its mixer THEN the dense gated MLP: no experts
+        experts_in_every_layer=True, dense_layers=len(types),
+        dense_width=c["intermediate_size"], tied_head=False,
+        qk_norm=True, norm_output=True,
+        embedding_multiplier=1.0, attention_multiplier=head_dim ** -0.5,
         residual_multiplier=1.0, logits_scaling=1.0,
         rms_eps=c["rms_norm_eps"], max_seq=c["max_position_embeddings"])
 
@@ -464,12 +546,31 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
                 "gnorm": jnp.ones((di,), pd),
                 "out_proj": norm((di, d)),
             }
-        if kind == ATTENTION:
+        if kind == LINEAR:
+            Hl, Vl = cfg.lin_heads, cfg.lin_value_dim
+            dt = jnp.exp(unif((Hl,), math.log(1e-3), math.log(1e-1)))
             return {
+                "norm": jnp.ones((d,), pd),
+                "wqkv": norm((d, cfg.lin_conv_channels)),
+                "wg": norm((d, Hl * Vl)),
+                "wab": norm((d, 2 * Hl)),
+                "conv_w": unif((K, cfg.lin_conv_channels), -bound,
+                               bound).astype(pd),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "A_log": jnp.log(unif((Hl,), 1.0, 16.0)),
+                "gnorm": jnp.ones((Vl,), pd),
+                "wo": norm((Hl * Vl, d)),
+            }
+        if kind == ATTENTION:
+            ap = {
                 "norm": jnp.ones((d,), pd),
                 "wqkv": norm((d, hq + 2 * hkv)),
                 "wo": norm((hq, d)),
             }
+            if cfg.qk_norm:
+                ap["q_norm"] = jnp.ones((hq,), pd)
+                ap["k_norm"] = jnp.ones((hkv,), pd)
+            return ap
         if kind == LATENT:
             nh, dn, dr, dv = (cfg.n_heads, cfg.head_dim, cfg.rope_dim,
                               cfg.v_head_dim)
@@ -540,7 +641,8 @@ def cast_at_use(params) -> list:
     casts are no-ops and the tree is served as it is."""
     out = [params[n] for n in ("wte", "head") if n in params]
     names = {"mixer": ("in_proj", "out_proj", "wqkv", "wo", "wq_a",
-                       "wq_nope", "wq_rope", "wkv_a", "w_uk", "w_uv"),
+                       "wq_nope", "wq_rope", "wkv_a", "w_uk", "w_uv",
+                       "wg", "wab"),
              "ffn": ("router", "shared_in", "shared_out", "w_in", "w_out")}
     for lp in params["layers"]:
         out += [lp[sub][n] for sub in names if sub in lp
@@ -586,6 +688,45 @@ def _mamba_mixer(cfg, mp, h, state, n_valid):
     return out, (conv_state, (ssm_pool, ssm_layer))
 
 
+def _unit(x, eps: float = 1e-6):
+    """x [..., K] float32 scaled to unit length."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def _linear_mixer(cfg, lp, h, state, n_valid):
+    """h [b, w, d]; state (conv [b, K-1, C], matrix): the rows' matrix
+    state as ``ops/delta_rule.delta_rule`` addresses it, (pool [L, b,
+    keys, heads * values] f32, layer).  -> (out [b, w, d], state)."""
+    conv_state, (pool, layer) = state
+    b, w, _ = h.shape
+    H, K, V = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
+    f32 = jnp.float32
+    with jax.named_scope("mixer_linear_proj"):
+        qkv = jnp.dot(h, lp["wqkv"].astype(h.dtype))
+        gate = jnp.dot(h, lp["wg"].astype(h.dtype))
+        ab = jnp.dot(h, lp["wab"].astype(h.dtype),
+                     preferred_element_type=f32)
+        # ONE materialisation each: the chunk program otherwise computes
+        # the [chunk, 11520] product again for every consumer (the taps,
+        # the splits: 4-5 x 0.47 ms a layer, read on the chip, PR 44)
+        qkv, gate = jax.lax.optimization_barrier((qkv, gate))
+    with jax.named_scope("mixer_linear_attention"):
+        qkv, conv_state = ssm.causal_conv(qkv, conv_state, lp["conv_w"],
+                                          None, n_valid)
+        q, k, v = jnp.split(qkv, [H * K, 2 * H * K], axis=-1)
+        q = _unit(q.reshape(b, w, H, K).astype(f32)) * K ** -0.5
+        k = _unit(k.reshape(b, w, H, K).astype(f32))
+        a, beta = ab[..., :H], 2.0 * jax.nn.sigmoid(ab[..., H:])
+        g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(a + lp["dt_bias"])
+        o, pool = delta_rule.delta_rule(q, k, v.reshape(b, w, H, V), g,
+                                        beta, pool, layer, n_valid)
+        y = _rms_norm(o, lp["gnorm"], cfg.rms_eps).reshape(b, w, H * V) \
+            * jax.nn.silu(gate.astype(f32))
+    with jax.named_scope("mixer_linear_proj"):
+        out = jnp.dot(y.astype(h.dtype), lp["wo"].astype(h.dtype))
+    return out, (conv_state, (pool, layer))
+
+
 def _attention_mixer(cfg, ap, h, attend):
     """h [b, w, d]; ``attend(q [b, h, w, hd], k, v [b, w, hkv, hd]) ->
     o [b, h, w, hd]`` supplies the keys of the past."""
@@ -594,6 +735,11 @@ def _attention_mixer(cfg, ap, h, attend):
     with jax.named_scope("mixer_attention"):
         qkv = jnp.dot(h, ap["wqkv"].astype(h.dtype))
         q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
+        if cfg.qk_norm:
+            # (one materialisation: see ``_linear_mixer``)
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
+            q = _rms_norm(q, ap["q_norm"], cfg.rms_eps)
+            k = _rms_norm(k, ap["k_norm"], cfg.rms_eps)
         o = attend(q.reshape(b, w, nh, hd).transpose(0, 2, 1, 3),
                    k.reshape(b, w, nkv, hd), v.reshape(b, w, nkv, hd))
         o = o.transpose(0, 2, 1, 3).reshape(b, w, nh * hd)
@@ -695,10 +841,12 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid):
     the dense MLP.
     -> (x, past, (counts [E_held], total) of an experts sublayer, else
         None)."""
-    h = _rms_norm(x, lp["norm"], cfg.rms_eps)
+    h = x if cfg.norm_output else _rms_norm(x, lp["norm"], cfg.rms_eps)
     load = None
     if kind == MAMBA:
         mix, past = _mamba_mixer(cfg, lp, h, past, n_valid)
+    elif kind == LINEAR:
+        mix, past = _linear_mixer(cfg, lp, h, past, n_valid)
     elif kind == ATTENTION:
         mix = _attention_mixer(cfg, lp, h, past)
     elif kind == LATENT:
@@ -709,6 +857,8 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid):
         valid = jnp.arange(x.shape[1])[None, :] < n_valid[:, None]
         mix, counts, total = _experts(cfg, lp, h, valid)
         load = (counts, total)
+    if cfg.norm_output:
+        mix = _rms_norm(mix, lp["norm"], cfg.rms_eps)
     return x + cfg.residual_multiplier * mix, past, load
 
 
@@ -729,8 +879,9 @@ def head(cfg: HybridConfig, params, x):
 def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
                state_out: Callable, attend_for: Callable, positions=None):
     """The unrolled layer loop over a window.  ``state_in(mi)`` gives
-    Mamba layer ``mi``'s state for the window's rows, (conv, (ssm pool,
-    layer)) as ``_mamba_mixer`` takes it, and ``state_out(mi, state)``
+    recurrent (Mamba or linear) layer ``mi``'s state for the window's
+    rows, (conv, (state pool, layer)) as its mixer takes it, and
+    ``state_out(mi, state)``
     takes it back; ``attend_for(ai)`` gives attention or latent layer
     ``ai``'s ``attend``; ``positions`` [b, w] are the window's, read by
     rotary positions alone.
@@ -749,7 +900,7 @@ def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
             ai += 1
         elif kind == DENSE:
             x, _, _ = block(cfg, kind, lp["ffn"], x, None, n_valid)
-        elif kind == MAMBA:
+        elif kind in (MAMBA, LINEAR):
             x, state, _ = block(cfg, kind, lp["mixer"], x, state_in(mi),
                                 n_valid)
             state_out(mi, state)
@@ -767,8 +918,8 @@ def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
 
 
 def zero_state(cfg: HybridConfig, rows: int):
-    """(conv, ssm) state of ``rows`` rows that have seen nothing, the
-    SSM state as a pool of this one layer."""
+    """(conv, recurrent) state of ``rows`` rows that have seen
+    nothing, the recurrent state as a pool of this one layer."""
     if cfg.state_geometry is None:
         return None
     _, conv, ssm_shape = cfg.state_geometry

@@ -517,6 +517,104 @@ def latent_window_attention(q_nope, q_rope, read_keys, w_uk, w_uv, q_pos, *,
     return o.transpose(2, 0, 1).reshape(w, h * dv)
 
 
+def _head_block_kernel(k0_ref, pos_ref, q_ref, k_ref, vt_ref, m_ref, l_ref,
+                       acc_ref, m_out, l_out, acc_out, *, scale: float):
+    """``_window_block_kernel`` for keys that are stored a head's lanes
+    each: one head's window of queries against one block of its K/V
+    head's keys, held transposed the same way."""
+    f32 = jnp.float32
+    s = lax.dot_general(k_ref[...], q_ref[...], (((1,), (1,)), ((), ())),
+                        preferred_element_type=f32) * scale
+    k_pos = k0_ref[0] + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    seen = k_pos <= pos_ref[...]                       # [keys, queries]
+    s = jnp.where(seen, s, _MASKED)
+    m_prev = m_ref[...]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.where(seen, jnp.exp(s - m_next), 0.0)
+    m_out[...] = m_next
+    l_out[...] = l_ref[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
+    acc_out[...] = acc_ref[...] * alpha + jnp.dot(
+        vt_ref[...], p.astype(vt_ref.dtype), preferred_element_type=f32)
+
+
+def head_window_attention(q, read_keys, q_pos, *, n_kv_heads: int,
+                          scale: float, key_block: int = KEY_BLOCK,
+                          n_blocks=None):
+    """ONE row's window of queries over the row's cached K/V, head by
+    head, ``key_block`` keys at a time under a running softmax.
+
+    q         [h, w, hd]
+    read_keys ``(j, n) -> (k, v)`` each [n, >= n_kv_heads * hd], as the
+              pool stores them (a token's heads side by side): the keys
+              at positions ``j * n .. (j + 1) * n`` (what lies past the
+              row's last key may be anything)
+    q_pos     [w] int32: a query attends the keys at positions <= its
+              own (key 0 is every query's)
+    n_blocks  key blocks walked; None: those that hold a key of the
+              window's last query
+    -> [h, w, hd]
+
+    For heads of whole lane tiles (``hd`` a multiple of 128), where a
+    head's keys ARE a tile-aligned slice of the stored block: each
+    query head multiplies its own K/V head's lanes only (``h /
+    n_kv_heads`` consecutive query heads share one), where
+    ``packed_attention`` multiplies the full stored width a head —
+    ``n_kv_heads`` x the arithmetic and a float32 score array of the
+    whole table.  One Pallas kernel a block, a grid step a head, the
+    scores in VMEM (``latent_window_attention``'s walk); the values of
+    a block are transposed once, [n_kv_heads, hd, n]."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops.flash_attention import _interpret_mode
+
+    f32 = jnp.float32
+    h, w, hd = q.shape
+    rep = h // n_kv_heads
+    last = jnp.max(q_pos)
+    if n_blocks is None:
+        n_blocks = last // key_block + 1
+    pos = q_pos.astype(jnp.int32)[None, :]
+    n = key_block
+
+    def per_head(*shape):
+        return pl.BlockSpec((None, *shape), lambda i: (i, 0, 0))
+
+    stats = [per_head(1, w), per_head(1, w), per_head(hd, w)]
+    call = pl.pallas_call(
+        functools.partial(_head_block_kernel, scale=scale),
+        grid=(h,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, w), lambda i: (0, 0)), per_head(w, hd),
+                  pl.BlockSpec((n, hd), lambda i: (0, i // rep)),
+                  pl.BlockSpec((None, hd, n), lambda i: (i // rep, 0, 0))]
+        + stats,
+        out_specs=stats,
+        out_shape=[jax.ShapeDtypeStruct((h, 1, w), f32)] * 2
+        + [jax.ShapeDtypeStruct((h, hd, w), f32)],
+        input_output_aliases={5: 0, 6: 1, 7: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=64 << 20),
+        interpret=_interpret_mode(),
+        name="head_window_attention",
+    )
+
+    def body(j, carry):
+        k, v = read_keys(j, n)
+        k_pos = j * n + jnp.arange(n, dtype=jnp.int32)
+        # 0 x NaN is NaN: what no query of the window may see is zeroed
+        v = jnp.where((k_pos <= last)[:, None], v, jnp.zeros_like(v))
+        v_t = v[:, :n_kv_heads * hd].reshape(n, n_kv_heads, hd)
+        return tuple(call(jnp.asarray(j * n, jnp.int32)[None], pos, q, k,
+                          v_t.transpose(1, 2, 0), *carry))
+
+    init = (jnp.full((h, 1, w), _MASKED, f32), jnp.zeros((h, 1, w), f32),
+            jnp.zeros((h, hd, w), f32))
+    _, l, acc = lax.fori_loop(0, n_blocks, body, init)
+    return (acc / l).astype(q.dtype).transpose(0, 2, 1)
+
+
 # VMEM the latent kernel's wave buffers take (ONE pool, double
 # buffered): 64 blocks of 16 x 640 bf16
 LATENT_WAVE_BYTES = 4 << 20

@@ -52,13 +52,14 @@ def causal_conv(x, state, w, b, n_valid):
     """Depthwise causal convolution of width K over a window.
 
     x [b, s, C] window inputs; state [b, K-1, C] the K-1 inputs before
-    the window; w [K, C] (tap K-1 multiplies the current token), b [C];
-    n_valid [b].  -> (silu(conv) [b, s, C], new state [b, K-1, C] = the
-    last K-1 inputs up to and including token n_valid - 1)."""
+    the window; w [K, C] (tap K-1 multiplies the current token), b [C]
+    or None (no bias); n_valid [b].  -> (silu(conv) [b, s, C], new state
+    [b, K-1, C] = the last K-1 inputs up to and including token
+    n_valid - 1)."""
     k = w.shape[0]
     s = x.shape[1]
     full = jnp.concatenate([state.astype(x.dtype), x], axis=1)  # [b,K-1+s,C]
-    out = b.astype(jnp.float32)
+    out = 0.0 if b is None else b.astype(jnp.float32)
     for j in range(k):
         out = out + full[:, j:j + s].astype(jnp.float32) \
             * w[j].astype(jnp.float32)

@@ -835,3 +835,111 @@ def test_latent_programs_fit_and_move_no_pool(latent_cell, which):
         assert "bf16[32,128,512]" in line.split(" custom-call(", 1)[0]
         assert deepseek_v2_trace.label_of(line, marks) \
             == "latent_decode_attention"
+
+
+# the olmo_hybrid layout: a matrix state a row beside 30 K/V heads of 128
+# lanes; the one-token delta-rule kernel on the state pool as stored, the
+# window form's head-wise attention a key block at a time
+
+
+@pytest.fixture(scope="module")
+def olmo_cell(one_chip):
+    """The shapes of ``serve-olmo-hybrid-doc3k-r80``, from the cell's
+    own configuration file: 16 layers (12 linear : 4 full) at published
+    widths, the whole vocabulary, 32 rows, 4,096 + 1 blocks of 16 x
+    3,840 lanes, 536-block tables."""
+    import json
+    import os
+    from ray_tpu.models import hybrid
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "olmo-hybrid-7b-16L.json")) as f:
+        config = json.load(f)
+    engine = config["engine"]
+    cfg = hybrid.HybridConfig.from_published(config,
+                                             max_seq=engine["max_seq"])
+    on_chip = _on(one_chip)
+    lay = PoolLayout(*cfg.kv_geometry[:1], engine["n_blocks"] + 1,
+                     engine["kv_block_size"], *cfg.kv_geometry[1:])
+    assert lay.shape == (4 * 4097, 16, 3840)
+    layers, conv, matrix = cfg.state_geometry
+    rows = engine["max_slots"]
+    params = jax.tree.map(
+        lambda s: on_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda: hybrid.init_params(cfg,
+                                                  jax.random.PRNGKey(0))))
+    n_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                  for s in jax.tree.leaves(params))
+    assert 8.19e9 < n_bytes < 8.21e9            # 4,101 M bf16 parameters
+    return (cfg, on_chip, params, on_chip(lay.shape, cfg.dtype), lay,
+            on_chip((layers, rows, *conv), cfg.dtype),
+            on_chip((layers, rows, *matrix), jnp.float32), engine)
+
+
+def _olmo_program(olmo_cell, which):
+    from ray_tpu.inference.recurrent import (make_recurrent_chunk_fn,
+                                             make_recurrent_decode_step)
+    cfg, on_chip, params, pool, lay, conv, matrix, engine = olmo_cell
+    T = -(-engine["max_seq"] // lay.block_size)
+    if which == "step":
+        fn = make_recurrent_decode_step(cfg, block_size=lay.block_size,
+                                        n_table=T)
+        packed = on_chip((engine["max_slots"], T + 3), jnp.int32)
+    else:
+        C = engine["prefill_chunk"]
+        fn = make_recurrent_chunk_fn(cfg, chunk=C,
+                                     block_size=lay.block_size, n_table=T)
+        packed = on_chip((T + C + 3,), jnp.int32)
+    return fn.lower(params, (pool, pool), (conv, matrix), packed).compile()
+
+
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_olmo_programs_fit_and_move_no_pool(olmo_cell, which):
+    """Both programs compile for the described chip at the published
+    widths, fit, and re-lay neither K/V pool nor the state pool out."""
+    from chipbench import olmo_hybrid_trace
+    cfg, _, _, _, lay, _, matrix, engine = olmo_cell
+    compiled = _olmo_program(olmo_cell, which)
+    _assert_pool_stays_put(compiled, lay)
+    _assert_state_stays_put(compiled, matrix)
+    text = compiled.as_text()
+    state = "f32[" + ",".join(map(str, matrix.shape)) + "]"
+    assert state == "f32[12,32,96,5760]"
+    # no array holds the table's span of keys beside the 30 heads:
+    # scores of a whole row
+    span = -(-engine["max_seq"] // lay.block_size) * lay.block_size
+    for shape in set(re.findall(r"(?:bf16|f32)\[([\d,]+)\]", text)):
+        dims = [int(d) for d in shape.split(",")]
+        assert not (cfg.n_heads in dims and span in dims), shape
+    marks = olmo_hybrid_trace.marks_of(cfg_keys(cfg), engine["max_slots"],
+                                       engine["prefill_chunk"])
+    steps = _kernel_calls(text, "delta_step")
+    if which == "chunk":
+        assert not steps
+        # the window kernel once a full-attention layer, inside the loop
+        # over key blocks; no gather of the table
+        calls = _kernel_calls(text, "head_window_attention")
+        assert len(calls) == cfg.n_attention == 4
+        assert f"bf16[{span},3840]" not in text
+        for line in calls:
+            assert olmo_hybrid_trace.label_of(line, marks) \
+                == "window_attention"
+        return
+    # ONE one-token kernel a linear layer, the pool its operand AND its
+    # result as stored
+    assert len(steps) == cfg.n_linear == 12
+    for line in steps:
+        result, operands = line.split(" custom-call(", 1)
+        assert state in result and state in operands
+        assert olmo_hybrid_trace.label_of(line, marks) == "delta_step"
+    # the one-token attention kernel at 3,840 stored lanes, once a layer
+    assert len(_kernel_calls(text)) == cfg.n_attention
+
+
+def cfg_keys(cfg):
+    """The published keys ``olmo_hybrid_trace.marks_of`` reads."""
+    return {"hidden_size": cfg.d_model,
+            "linear_num_value_heads": cfg.lin_heads,
+            "linear_key_head_dim": cfg.lin_key_dim,
+            "linear_value_head_dim": cfg.lin_value_dim,
+            "num_attention_heads": cfg.n_heads}
