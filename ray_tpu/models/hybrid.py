@@ -713,6 +713,10 @@ def _linear_mixer(cfg, lp, h, state, n_valid):
     with jax.named_scope("mixer_linear_attention"):
         qkv, conv_state = ssm.causal_conv(qkv, conv_state, lp["conv_w"],
                                           None, n_valid)
+        # (one materialisation again: q's, k's and v's consumers each
+        # computed the taps over the whole [chunk, 11520] otherwise, 30
+        # evaluations in 12 layers, compiled for the chip, PR 45)
+        qkv = jax.lax.optimization_barrier(qkv)
         q, k, v = jnp.split(qkv, [H * K, 2 * H * K], axis=-1)
         q = _unit(q.reshape(b, w, H, K).astype(f32)) * K ** -0.5
         k = _unit(k.reshape(b, w, H, K).astype(f32))

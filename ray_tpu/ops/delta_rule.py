@@ -16,13 +16,20 @@ and no program re-lays it out (``models/hybrid.state_geometry``).
 Two forms of the same recurrence, chosen by the window's static width
 as ``ops/ssm.py``'s are:
 
-  * a WINDOW of tokens (``delta_window``): the paper's chunkwise form.
-    Inside a block of ``BLOCK`` tokens the updates are resolved by the
-    inverse of ``I + strict_lower(diag(beta) (K K^T * decay))`` (unit
-    lower triangular: inverted two blocks at a time from blocks of one
-    up, batched products and no substitution loop), for every block of
-    the window at once; the state then crosses the blocks through a
-    ``lax.scan`` of four products against it.
+  * a WINDOW of tokens (``delta_window``): the paper's chunkwise form,
+    ONE Pallas kernel.  The grid walks (row, group of heads, block of
+    ``BLOCK`` tokens), the blocks in order: a group's state ``[heads,
+    K, V]`` is read into VMEM before the window's first block, stays
+    there, and is written once after the last.  A visit reads the
+    block's q, k, v, log decay and beta once, resolves the block's
+    updates by the inverse of ``I + strict_lower(diag(beta) (K K^T *
+    decay))`` (unit lower triangular: diagonal blocks of ``SOLVE_BLOCK``
+    by forward substitution on the vector unit, merged upward two
+    blocks at a time by matrix products, all in VMEM) applied to ``K
+    e^gc`` and ``V``, does the four products against the resident state
+    (``W S`` and ``Q S`` as one, ``A (U - W S)``, ``K_out^T (U - W
+    S)``) and writes the block's ``o`` once, a token's heads side by
+    side as the mixer reads them.
   * ONE token (``delta_step``): the recurrence itself as a rank-one
     correction of the state, a Pallas kernel over the state POOL where
     it is stored: a live row's state is read once and written once, in
@@ -30,11 +37,14 @@ as ``ops/ssm.py``'s are:
 
 Both take ``n_valid`` [b]: only the first ``n_valid`` tokens of a row's
 window are real.  In the window form a token past it has ``beta`` = 0
-and ``log alpha`` = 0, the identity on the state; the one-token form
-does not visit a row with ``n_valid`` 0 at all.
+and ``log alpha`` = 0, the identity on the state bit for bit; the
+one-token form does not visit a row with ``n_valid`` 0 at all.
 
 Every product that touches the state is float32: the one-token form on
-the vector unit, the window form as matmuls at ``Precision.HIGHEST``.
+the vector unit, the window form's matmuls (every one of them: the
+block's ``K K^T`` and ``Q K^T`` and the solve's merges too) at
+``Precision.HIGHEST`` inside the kernel, which Mosaic runs as bfloat16
+passes over the operands' split parts that add up to float32.
 """
 
 from __future__ import annotations
@@ -190,82 +200,164 @@ def delta_step(q, k, v, g, beta, pool, layer, n_valid):
     return y[:, None], pool
 
 
-def _unit_lower_inverse(n):
-    """(I + n)^-1 for strictly lower triangular ``n`` [..., c, c], c a
-    power of two: ``[[A, 0], [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1,
-    B^-1]]`` from blocks of one up, ``log2 c`` rounds of two batched
-    products.  As stable as the substitution it stands for: every
-    intermediate is a block of the inverse itself (the powers of ``n``
-    that the nilpotent series sums grow to ~1e15 at 64 keys of 8 lanes
-    before they cancel)."""
-    hi = lax.Precision.HIGHEST
-    c, lead = n.shape[-1], n.shape[:-2]
+# heads a grid step of the window form holds in VMEM (their state, a
+# block's q, k, v, o and the block's [c, c] intermediates), and the
+# side of the diagonal blocks its solve resolves by substitution
+WINDOW_HEADS, SOLVE_BLOCK = 10, 8
 
-    def diagonal_blocks(x, size):   # [..., c, c] -> [..., c/size, size, size]
-        x = x.reshape(*lead, c // size, size, c // size, size)
-        return jnp.moveaxis(jnp.diagonal(x, axis1=-4, axis2=-2), -1, -3)
 
-    inv = jnp.ones((*lead, c, 1, 1), n.dtype)       # blocks of one: [1]
-    m = 1
-    while m < c:
-        low = diagonal_blocks(n, 2 * m)[..., m:, :m]    # C of every pair
-        a, b = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
-        off = -jnp.matmul(jnp.matmul(b, low, precision=hi), a, precision=hi)
-        inv = jnp.concatenate([
-            jnp.concatenate([a, jnp.zeros_like(a)], axis=-1),
-            jnp.concatenate([off, b], axis=-1)], axis=-2)
-        m *= 2
-    return inv[..., 0, :, :]
+def _heads_a_step(H: int, V: int) -> int:
+    """The largest divisor of ``H`` up to WINDOW_HEADS whose heads'
+    values are whole lane tiles side by side; all ``H`` where there is
+    none (test sizes)."""
+    return max((d for d in range(1, min(WINDOW_HEADS, H) + 1)
+                if H % d == 0 and d * V % LANES == 0), default=H)
+
+
+def _mm(a, b, contract):
+    """Batched float32-exact product of a [h, ., .] and b [h, ., .]
+    over ``contract`` = (a's dim, b's dim)."""
+    return lax.dot_general(a, b, (((contract[0],), (contract[1],)),
+                                  ((0,), (0,))),
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _unit_lower_solve(n, row, col):
+    """(I + n)^-1 for strictly lower triangular ``n`` [h, c, c], c a
+    power of two (``row``, ``col`` [1, c, c]: an element's indices).
+    Diagonal blocks of SOLVE_BLOCK by forward substitution (a rank-one
+    update a column, on the vector unit), merged upward two blocks at a
+    time: with ``T`` the inverse of the block diagonal and ``C`` the
+    lower-left block of every pair, ``[[A, 0], [C, B]]^-1 = T - T C T``.
+    As stable as the substitution it stands for: every intermediate is
+    a block of the inverse itself (the powers of ``n`` that the
+    nilpotent series sums grow to ~1e15 at 64 keys of 8 lanes before
+    they cancel)."""
+    c = n.shape[-1]
+    r = min(SOLVE_BLOCK, c)
+    eye = jnp.where(row == col, 1.0, 0.0)
+    parts = []
+    for m in range(0, c, r):
+        x = jnp.broadcast_to(eye[:, m:m + r], (n.shape[0], r, c))
+        for j in range(r - 1):
+            # row j is final: take its multiple out of the rows below
+            x = x - n[:, m:m + r, m + j:m + j + 1] * x[:, j:j + 1]
+        parts.append(x)
+    t = jnp.concatenate(parts, axis=1)
+    while r < c:
+        pair = ((row ^ col) < 2 * r) & ((row & r) != 0) & ((col & r) == 0)
+        low = jnp.where(pair, n, 0.0)
+        t = t - _mm(t, _mm(low, t, (2, 1)), (2, 1))
+        r *= 2
+    return t
+
+
+def _window_kernel(q_ref, k_ref, v_ref, gb_ref, s_in_ref, o_ref, s_ref):
+    """One block of c tokens of some heads: q, k [h, c, K]; v and o as
+    the mixer holds them, a token's heads side by side [c, h * V];
+    ``gb`` [h, 2, c] (log decay and beta, a token a lane); the heads'
+    state [h, K, V], which stays in ``s_ref`` from the window's first
+    block to its last."""
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = s_in_ref[...]
+
+    q, k = q_ref[...], k_ref[...]
+    h, c, _ = q.shape
+    V = s_ref.shape[-1]
+    v = jnp.stack([v_ref[:, i * V:(i + 1) * V] for i in range(h)]).astype(f32)
+    row = lax.broadcasted_iota(jnp.int32, (1, c, c), 1)
+    col = lax.broadcasted_iota(jnp.int32, (1, c, c), 2)
+    g_row, beta_row = gb_ref[:, 0:1, :], gb_ref[:, 1:2, :]      # [h, 1, c]
+
+    def column(t, keep):    # [h, 1, c] -> [h, c, 1]: row i sums lanes ``keep``
+        return jnp.sum(jnp.where(keep, t, 0.0), axis=2, keepdims=True)
+
+    beta = column(beta_row, row == col)
+    # the running log decay, inclusive, a token a sublane and a token a lane
+    gc = column(g_row, col <= row)
+    gc_row = jnp.sum(jnp.where(row <= col, column(g_row, row == col), 0.0),
+                     axis=1, keepdims=True)
+    # decay from token j (exclusive) to token i (inclusive), j <= i
+    decay = jnp.exp(jnp.where(row >= col, gc - gc_row, -jnp.inf))
+    kb = k * beta
+    both = _mm(jnp.concatenate([q, kb], axis=1), k, (2, 2))     # [h, 2c, c]
+    qk, kk = both[:, :c] * decay, both[:, c:] * decay
+    t = _unit_lower_solve(jnp.where(row > col, kk, 0.0), row, col)
+    grow = jnp.exp(gc)
+    w = _mm(t, kb * grow, (2, 1))                               # [h, c, K]
+    u = _mm(t, v * beta, (2, 1))                                # [h, c, V]
+    state = s_ref[...]
+    seen = _mm(jnp.concatenate([w, q * grow], axis=1), state, (2, 1))
+    new = u - seen[:, :c]                                       # [h, c, V]
+    o = seen[:, c:] + _mm(qk, new, (2, 1))
+    for i in range(h):
+        o_ref[:, i * V:(i + 1) * V] = o[i]
+    end = jnp.sum(g_row, axis=2, keepdims=True)                 # [h, 1, 1]
+    s_ref[...] = jnp.exp(end) * state + _mm(k * jnp.exp(end - gc), new,
+                                            (1, 1))
 
 
 def delta_window(q, k, v, g, beta, state, n_valid, block: int = BLOCK):
     """A window of s tokens in blocks of ``block``.  q, k [b, s, H, K],
     v [b, s, H, V], g, beta [b, s, H]; ``state`` [b, H, K, V] float32
-    (each head's S^T).  -> (o [b, s, H, V] float32, state)."""
-    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    (each head's S^T).  -> (o [b, s, H, V] float32, state).
+
+    ONE Pallas kernel: the grid walks (row, group of heads, block), the
+    blocks in order; the group's state is read into VMEM before its
+    first block and written after its last."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops.flash_attention import _interpret_mode
+
+    f32 = jnp.float32
     b, s, H, K = q.shape
+    V = v.shape[-1]
     live = (jnp.arange(s)[None, :] < n_valid[:, None])[..., None]
     g = jnp.where(live, g.astype(f32), 0.0)
     beta = jnp.where(live, beta.astype(f32), 0.0)
     c = min(int(block), 1 << max(s - 1, 0).bit_length())     # a power of two
     pad = -s % c
+    n = (s + pad) // c
 
-    def blocks(t):                 # [b, s, H, ...] -> [b, H, n, c, ...]
+    def blocks(t):                 # [b, s, H, ...] -> [b, n, c, H, ...]
         if pad:
             t = jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
-        t = t.astype(f32).reshape(b, -1, c, *t.shape[2:])
-        return jnp.moveaxis(t, 3, 1)
+        return t.reshape(b, n, c, *t.shape[2:])
 
-    q, k, v = blocks(q), blocks(k), blocks(v)      # [b, H, n, c, K | V]
-    g, beta = blocks(g), blocks(beta)              # [b, H, n, c]
-    gc = jnp.cumsum(g, axis=-1)                    # inclusive, <= 0
-    tri = jnp.tril(jnp.ones((c, c), bool))
-    # decay from token j (exclusive) to token i (inclusive), j <= i
-    decay = jnp.exp(jnp.where(tri, gc[..., :, None] - gc[..., None, :],
-                              -jnp.inf))
-    kb, vb = k * beta[..., None], v * beta[..., None]
-    kk = jnp.einsum("bhnik,bhnjk->bhnij", kb, k, precision=hi) * decay
-    t = _unit_lower_inverse(jnp.where(tri & ~jnp.eye(c, dtype=bool), kk, 0.0))
-    w = jnp.matmul(t, kb * jnp.exp(gc)[..., None], precision=hi)
-    u = jnp.matmul(t, vb, precision=hi)
-    qk = jnp.einsum("bhnik,bhnjk->bhnij", q, k, precision=hi) * decay
-    q_in = q * jnp.exp(gc)[..., None]              # against the carried state
-    k_out = k * jnp.exp(gc[..., -1:] - gc)[..., None]      # to the block's end
-    end = jnp.exp(gc[..., -1])                     # [b, H, n]
+    def by_head(t):                # [b, s, H, K] -> [b, H, n, c, K]
+        return blocks(t.astype(f32)).transpose(0, 3, 1, 2, 4)
 
-    def body(state, xs):
-        w, u, qk, q_in, k_out, end = xs
-        new = u - jnp.matmul(w, state, precision=hi)            # [b,H,c,V]
-        o = jnp.matmul(q_in, state, precision=hi) \
-            + jnp.matmul(qk, new, precision=hi)
-        state = end[..., None, None] * state + jnp.einsum(
-            "bhck,bhcv->bhkv", k_out, new, precision=hi)
-        return state, o
-
-    state, o = lax.scan(body, state, tuple(
-        jnp.moveaxis(x, 2, 0) for x in (w, u, qk, q_in, k_out, end)))
-    o = jnp.moveaxis(o, 0, 2)                                   # [b,H,n,c,V]
-    return jnp.moveaxis(o, 1, 3).reshape(b, s + pad, H, -1)[:, :s], state
+    gb = jnp.stack([blocks(g), blocks(beta)], axis=-1)
+    gb = gb.transpose(0, 1, 3, 4, 2)                    # [b, n, H, 2, c]
+    hg = _heads_a_step(H, V)
+    per_block = pl.BlockSpec((None, hg, None, c, K),
+                             lambda i, h, j: (i, h, j, 0, 0))
+    by_token = pl.BlockSpec((None, None, c, hg * V),
+                            lambda i, h, j: (i, j, 0, h))
+    whole = pl.BlockSpec((None, hg, K, V), lambda i, h, j: (i, h, 0, 0))
+    interpret = _interpret_mode()
+    params = {} if interpret else dict(compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary")))
+    o, state = pl.pallas_call(
+        _window_kernel,
+        grid=(b, H // hg, n),
+        in_specs=[per_block, per_block, by_token,
+                  pl.BlockSpec((None, None, hg, 2, c),
+                               lambda i, h, j: (i, j, h, 0, 0)), whole],
+        out_specs=[by_token, whole],
+        out_shape=[jax.ShapeDtypeStruct((b, n, c, H * V), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        input_output_aliases={4: 1},
+        interpret=interpret,
+        name="delta_window",
+        **params,
+    )(by_head(q), by_head(k), blocks(v).reshape(b, n, c, H * V), gb,
+      state.astype(f32))
+    return o.reshape(b, s + pad, H, V)[:, :s], state
 
 
 def delta_rule(q, k, v, g, beta, pool, layer, n_valid, block: int = BLOCK):

@@ -924,6 +924,20 @@ def test_olmo_programs_fit_and_move_no_pool(olmo_cell, which):
         for line in calls:
             assert olmo_hybrid_trace.label_of(line, marks) \
                 == "window_attention"
+        # the delta rule's window kernel once a linear layer, on THIS
+        # layer's rows' state (never the pool), credited to the label
+        # the benchmark sums
+        windows = _kernel_calls(text, "delta_window")
+        assert len(windows) == cfg.n_linear == 12
+        for line in windows:
+            assert state not in line
+            assert olmo_hybrid_trace.label_of(line, marks) \
+                == "mixer_linear_attention"
+        # no loop carries a row's matrix state from block to block
+        for line in text.splitlines():
+            if " while(" in line:
+                assert not re.search(r"f32\[(?:\d+,)*(?:30,96,192|96,5760)\]",
+                                     line), line
         return
     # ONE one-token kernel a linear layer, the pool its operand AND its
     # result as stored

@@ -1,8 +1,8 @@
 """The gated delta rule (ops/delta_rule.py) at small sizes on the CPU:
-the window form and the one-token kernel (interpret mode) against the
-token-by-token recurrence, ragged ``n_valid``, a row that sits a pass
-out left bit-identical, head counts that are no multiple of anything,
-and windows split at and off the block's boundaries."""
+the window kernel and the one-token kernel (both in interpret mode)
+against the token-by-token recurrence, ragged ``n_valid``, a row that
+sits a pass out left bit-identical, head counts that are no multiple of
+anything, and windows split at and off the block's boundaries."""
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +48,7 @@ def _stored(state):
     (64, 5, 16, 8),         # exactly one block, V < K
     (7, 30, 8, 16),         # shorter than a block; 30 heads
     (129, 1, 24, 40),       # one head; one token into the third block
+    (192, 2, 96, 192),      # the published head: two heads are 3 lane tiles
 ])
 def test_window_form_equals_the_recurrence(s, H, K, V):
     xs, state = _inputs(2, s, H, K, V)
@@ -57,17 +58,22 @@ def test_window_form_equals_the_recurrence(s, H, K, V):
     np.testing.assert_allclose(got, want_s, atol=ATOL)
 
 
-def test_window_form_ragged_rows_and_a_row_that_sits_out():
-    xs, state = _inputs(3, 100, 3, 8, 16, seed=1)
-    n_valid = jnp.array([100, 37, 0])
-    o, got = dr.delta_window(*xs, state, n_valid)
-    for row, n in enumerate(n_valid.tolist()):
+@pytest.mark.parametrize("s, n_valid", [
+    (100, [100, 37, 0]),
+    (150, [131, 150, 0]),   # no multiple of 64; a row ends inside the last
+    (64, [0, 64, 1]),       # block; the row that sits out comes first
+])
+def test_window_form_ragged_rows_and_a_row_that_sits_out(s, n_valid):
+    xs, state = _inputs(3, s, 3, 8, 16, seed=1)
+    o, got = dr.delta_window(*xs, state, jnp.array(n_valid))
+    for row, n in enumerate(n_valid):
         want_o, want_s = dr.delta_recurrence(
             *(x[row:row + 1, :n] for x in xs), state[row:row + 1])
         np.testing.assert_allclose(o[row, :n], want_o[0], atol=ATOL)
         np.testing.assert_allclose(got[row], want_s[0], atol=ATOL)
     # the padding is the identity, not nearly so
-    assert bool((got[2] == state[2]).all())
+    out = n_valid.index(0)
+    assert bool((got[out] == state[out]).all())
 
 
 @pytest.mark.parametrize("cut", [64, 50, 1, 127])
@@ -84,13 +90,16 @@ def test_a_window_split_in_two_equals_one(cut):
     np.testing.assert_allclose(end, whole_s, atol=ATOL)
 
 
-def test_unit_lower_inverse_is_the_inverse_where_the_series_overflows():
+def test_unit_lower_solve_is_the_inverse_where_the_series_overflows():
     """64 keys of 8 lanes at beta 2: the powers of the strict part reach
-    ~1e15 before they vanish; the blockwise inverse never forms them."""
+    ~1e15 before they vanish; substitution in the diagonal blocks and
+    the blockwise merges (the kernel's solve, here on its own) never
+    form them."""
     k = jax.random.normal(jax.random.PRNGKey(3), (64, 8))
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     n = jnp.tril(2.0 * k @ k.T, -1)
-    inv = dr._unit_lower_inverse(n[None])[0]
+    row, col = jnp.indices((1, 64, 64), dtype=jnp.int32)[1:]
+    inv = dr._unit_lower_solve(n[None], row, col)[0]
     np.testing.assert_allclose(inv @ (jnp.eye(64) + n), jnp.eye(64),
                                atol=1e-4)
     assert bool((jnp.triu(inv, 1) == 0).all())
