@@ -64,10 +64,16 @@ stay on the device (6.4 MB a pass at GPT-2 XL's 32 rows, which went to
 the host and back for an argmax program of its own until ISSUE 39); a
 row with temperature > 0 keeps its per-request rng and samples from its
 row of them there, one dispatch of its own.  A pass that holds both a
-chunk and decoding rows runs them as ONE program where the family has
-it (decode.make_paged_step_chunk, ``_step_chunk``: the weights stream
-once a pass): the pass's last chunk is prepared and packed, and
-launched by the decode step.  A prompt's greedy first token is its last
+chunk and decoding rows runs them as ONE program where the model has
+one (``_step_chunk``: the weights stream once a pass; the GPT family's
+decode.make_paged_step_chunk without speculation or routed experts,
+the hybrid family's recurrent.make_recurrent_step_chunk for the layouts
+whose every sublayer kind takes a window in two parts — Mamba-2,
+attention over K/V blocks, routed experts, the dense MLP; not latent
+attention, the delta rule or the head-by-head window form — which each
+seam's ``build`` derives from the configuration): the pass's last chunk
+is prepared and packed, and launched by the decode step.  A prompt's
+greedy first token is its last
 chunk's own argmax, read inside the decode step's fetch: the fused
 program's last integer, or the chunk program's, which is not waited for
 before the step goes out behind it (_finish_prefill).  The full-width
@@ -101,8 +107,10 @@ from ray_tpu.inference.decode import (SpeculationUnsupported,
                                       make_spec_verify_step,
                                       ngram_propose, pack_chunk,
                                       pack_step, pack_step_chunk)
-from ray_tpu.inference.recurrent import (make_recurrent_chunk_fn,
-                                         make_recurrent_decode_step)
+from ray_tpu.inference.recurrent import (has_step_chunk,
+                                         make_recurrent_chunk_fn,
+                                         make_recurrent_decode_step,
+                                         make_recurrent_step_chunk)
 from ray_tpu.models import gpt, hybrid
 from ray_tpu.parallel.sharding import (DEFAULT_LLM_RULES, Rules,
                                        tree_shardings)
@@ -396,8 +404,10 @@ class _Seam:
     N_LOAD = 0
 
     @staticmethod
-    def count(eng, loads) -> None:
-        """``loads``: the int32 vectors a decode pass fetched."""
+    def count(eng, loads, rode: bool) -> None:
+        """``loads``: the int32 vectors a decode pass fetched, the
+        step's last; ``rode``: that one is the fused program's, a chunk
+        ran inside the step."""
 
     @staticmethod
     def greedy(eng, logits):
@@ -442,8 +452,9 @@ class _KVOnly(_Seam):
             n_table=eng.pool.blocks_per_seq, mesh=mesh, rules=rules)
         # the two as ONE program, for a pass that holds both.  Not
         # where a pass may take the speculative iteration in the step's
-        # place, nor for routed experts: their capacity is per window,
-        # and a decode row's one-token window can never drop
+        # place, nor for THIS family's routed experts: their capacity
+        # is per window, and a decode row's one-token window can never
+        # drop (the hybrid family's are dropless, and fuse)
         eng._step_chunk = (
             None if ec.speculate is not None or cfg.n_experts
             else make_paged_step_chunk(
@@ -451,9 +462,13 @@ class _KVOnly(_Seam):
                 n_table=eng.pool.blocks_per_seq, mesh=mesh, rules=rules))
 
     @staticmethod
+    def operands(eng) -> tuple:
+        """What every program of a pass takes before its packed array."""
+        return eng.params, eng.pool.k, eng.pool.v
+
+    @staticmethod
     def run(eng, program, packed):
-        logits, greedy, k, v = program(eng.params, eng.pool.k, eng.pool.v,
-                                       packed)
+        logits, greedy, k, v = program(*_KVOnly.operands(eng), packed)
         eng.pool.swap(k, v)
         # no load to count: only the newest program's tokens are owed
         eng._load = [greedy]
@@ -469,7 +484,11 @@ class _KVAndState(_Seam):
     state — no prefix index, re-prefill after preemption — the engine
     derives from ``cfg.state_geometry``, not from the family: a model
     of this family without recurrent layers keeps its whole past in
-    blocks, and is served with both."""
+    blocks, and is served with both.  Whether a pass that holds a chunk
+    and decoding rows runs ONE program follows from the sublayer kinds
+    (``build``; ``recurrent.has_step_chunk``), a recurrent state or
+    not; that program's int32 vector is the step's and then the
+    chunk's, each with its own load counts (``count``)."""
 
     N_LOAD = hybrid.N_LOAD
 
@@ -512,31 +531,49 @@ class _KVAndState(_Seam):
         eng._chunk = make_recurrent_chunk_fn(
             cfg, chunk=ec.prefill_chunk, block_size=bs,
             n_table=eng.pool.blocks_per_seq)
-        # no fused program yet: a pass runs the two back to back
-        eng._step_chunk = None
+        # the two as ONE program where every sublayer kind of the model
+        # takes a window in two parts (Mamba-2, attention over K/V
+        # blocks, routed experts, the dense MLP); a latent, delta-rule
+        # or head-by-head attention sublayer: the two back to back
+        eng._step_chunk = (
+            make_recurrent_step_chunk(
+                cfg, chunk=ec.prefill_chunk, block_size=bs,
+                n_table=eng.pool.blocks_per_seq)
+            if has_step_chunk(cfg, eng.pool.layout) else None)
+
+    @staticmethod
+    def operands(eng) -> tuple:
+        st = eng.pool.state
+        return (eng.params, eng.pool.pools,
+                () if st is None else (st.conv, st.ssm))
 
     @staticmethod
     def run(eng, program, packed):
-        st = eng.pool.state
-        logits, load, pools, state = program(
-            eng.params, eng.pool.pools,
-            () if st is None else (st.conv, st.ssm), packed)
+        logits, load, pools, state = program(*_KVAndState.operands(eng),
+                                             packed)
         eng.pool.swap(*pools)
-        if st is not None:
-            st.swap(*state)
+        if eng.pool.state is not None:
+            eng.pool.state.swap(*state)
         # every program's load stays on the device until the next
         # decode pass fetches them all
         eng._load.append(load)
         return logits
 
     @staticmethod
-    def count(eng, loads) -> None:
-        for load in loads:
+    def count(eng, loads, rode: bool) -> None:
+        # the fused program's vector is the step's and then the chunk's
+        # own ``N_LOAD`` + 1 (``make_recurrent_step_chunk``), the load
+        # of each counted apart: the decode rows' touches stay the
+        # step's alone
+        *chunks, step = loads
+        if rode:
+            chunks.append(step[-(hybrid.N_LOAD + 1):])
+        for load in (*chunks, step):
             eng._expert_held += int(load[0])
             eng._expert_total += int(load[1])
             eng._expert_load_max += int(load[2])
             eng._expert_touched += int(load[3])
-        eng._expert_touched_decode += int(loads[-1][3])
+        eng._expert_touched_decode += int(step[3])
 
     @staticmethod
     def row_admitted(eng, row) -> None:
@@ -811,30 +848,56 @@ class InferenceEngine:
 
     def warm_up(self, timeout: float = 300.0) -> None:
         """Bring every program a pass can run to the device before the
-        first request: one short generation (the chunk program and the
-        decode step), and the program that runs both once on nothing —
-        every decode row inactive and a chunk of no real token, all of
-        whose writes go to the scratch block.  Requests that arrive one
-        at a time never give a pass both a chunk and a decoding row, so
-        the first overlap would otherwise compile on the request path."""
-        self.generate([1], max_new=2, timeout=timeout)
-        if self._step_chunk is None:
-            return
+        first request.  First all of them side by side, a thread each:
+        compiled, or loaded from the persistent compile cache, from the
+        operands a pass hands them (``lower(..).compile()``, which the
+        first real call then finds done).  A program's bring-up from a
+        warm cache is ~5 s at the hybrid cells' sizes, most of it
+        outside the interpreter, and a model with the fused program has
+        three of them.  Then one short generation (the chunk program
+        and the decode step), and the program that runs both once on
+        nothing — every decode row inactive and a chunk of no real
+        token, all of whose writes go to the scratch block.  Requests
+        that arrive one at a time never give a pass both a chunk and a
+        decoding row, so the first overlap would otherwise compile on
+        the request path."""
+        n, T = self._tables.shape
+        zeros = np.zeros(n, np.int32)
+        step = pack_step(np.zeros_like(self._tables), zeros, zeros, zeros)
+        chunk = pack_chunk(np.zeros(T, np.int32),
+                           np.zeros(self.engine_cfg.prefill_chunk, np.int32),
+                           0, 0, 0)
+        programs = [(self._chunk, chunk), (self._step, step)]
+        if self._step_chunk is not None:
+            programs.append((self._step_chunk, pack_step_chunk(step, chunk)))
+
+        def load_all():
+            operands, errors = self._seam.operands(self), []
+
+            def load(program, packed):
+                try:
+                    program.lower(*operands, packed).compile()
+                except BaseException as e:      # raised by ``load_all``
+                    errors.append(e)
+            threads = [threading.Thread(target=load, args=p, daemon=True)
+                       for p in programs]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            if errors:
+                raise errors[0]
 
         def run_once():
-            n, T = self._tables.shape
-            zeros = np.zeros(n, np.int32)
-            packed = pack_step_chunk(
-                pack_step(np.zeros_like(self._tables), zeros, zeros, zeros),
-                pack_chunk(np.zeros(T, np.int32),
-                           np.zeros(self.engine_cfg.prefill_chunk, np.int32),
-                           0, 0, 0))
             with self._acct.phase("dispatch"):
-                self._seam.run(self, self._step_chunk, packed)
+                self._seam.run(self, *programs[-1])
             with self._acct.phase("wait"):
                 jax.block_until_ready(self._load.pop())
 
-        self._run_op(run_once, timeout=timeout)
+        self._run_op(load_all, timeout=timeout)
+        self.generate([1], max_new=2, timeout=timeout)
+        if self._step_chunk is not None:
+            self._run_op(run_once, timeout=timeout)
 
     # ------------------------------------------------------------- loop
 
@@ -1424,7 +1487,7 @@ class InferenceEngine:
         fetch.set(bytes=n_bytes, **attributes)
         self._fetch_bytes += n_bytes
 
-    def _fetch_step(self, fetch) -> None:
+    def _fetch_step(self, fetch, rode: bool) -> None:
         """The rows' greedy tokens of the decode step just dispatched
         — and, of a model that reports one, the expert load of this
         pass and of the chunks before it — in ONE small transfer; the
@@ -1433,12 +1496,14 @@ class InferenceEngine:
         programs ended a decode step ago (the token of a chunk that ran
         inside the step is the last of the step's own integers), and
         their reads belong to this wait (``fetch``, its span:
-        ``first_tokens``, and their bytes among its ``bytes``)."""
+        ``first_tokens``, and their bytes among its ``bytes``).
+        ``rode``: a chunk ran inside the step (the seam's ``count``
+        then finds the chunk's load behind the step's)."""
         n_first, n_bytes = self._emit_first(in_step_fetch=True)
         loads = jax.device_get(self._load)
         self._load = []
         self._greedy = loads[-1][self._seam.N_LOAD:]   # the step's own
-        self._seam.count(self, loads)
+        self._seam.count(self, loads, rode)
         self._fetched(fetch, n_bytes + sum(load.nbytes for load in loads),
                       first_tokens=n_first)
 
@@ -1797,7 +1862,7 @@ class InferenceEngine:
                 self._chaos("infer_shard_commit",
                             tp_shards=self.pool.heads_shards)
             with self._acct.phase("wait") as fetch:
-                self._fetch_step(fetch)
+                self._fetch_step(fetch, ride is not None)
             with self._acct.phase("emit") as sample:
                 with self._mlock:
                     self._decode_iterations += 1

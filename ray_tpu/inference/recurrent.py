@@ -1,9 +1,11 @@
 """Paged serving programs of the hybrid family: the decode step and the
-chunk-prefill window of ``models/hybrid.py``.
+chunk-prefill window of ``models/hybrid.py``, and the two as ONE
+program.
 
-Both are the model's ONE layer function (``hybrid.run_layers`` over
-``hybrid.block``) at a serving shape — ``[rows, 1]`` and ``[1,
-chunk]`` — with the past supplied from the pools the cache manager owns
+Each is the model's ONE layer function (``hybrid.run_layers`` over
+``hybrid.block``) at a serving shape — ``[rows, 1]``, ``[1, chunk]``
+and the window in two parts ``[1, rows + chunk]`` — with the past
+supplied from the pools the cache manager owns
 (inference/cache.py), whichever of them the model's layers keep:
 
   * an attention layer's keys come from the K/V ``BlockPool`` through
@@ -50,6 +52,20 @@ functions are named ``step`` and ``chunk_fn`` like decode.py's (a
 device trace's program names are ``jit_step`` / ``jit_chunk_fn`` for
 either model family).  No mesh: one device holds one chip's share of the
 deployment (experts over an ``ep`` axis are future work).
+
+A pass that holds a prompt chunk AND decoding rows runs the two as ONE
+program, ``step_chunk`` (``make_recurrent_step_chunk``; ``jit_step_chunk``
+in a trace, as the GPT family's), where the model's every sublayer kind
+takes a window in two parts: Mamba-2, attention over K/V blocks gathered
+by table, routed experts (dropless: a part's tokens are dropped by
+nobody), the dense MLP — the granite and nemotron layouts.  Every
+matrix then streams from HBM once a pass, for ``rows + chunk`` tokens,
+and only the recurrences and the attention run a part at a time, in the
+forms the two programs give them.  A latent-attention sublayer, a
+delta-rule sublayer or a window attended head by head has no such form
+yet, and a model with one keeps the pass of two programs
+(``has_step_chunk``: derived from the sublayer kinds and the pool's
+layout, by the engine, where it builds the programs).
 """
 
 from __future__ import annotations
@@ -60,9 +76,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.inference.cache import PoolLayout
-from ray_tpu.inference.decode import (_cached, latent_attend, paged_attend,
-                                      unpack_chunk, unpack_step,
-                                      window_by_head)
+from ray_tpu.inference.decode import (_cached, _step_indices, latent_attend,
+                                      paged_attend, unpack_chunk,
+                                      unpack_step, window_by_head)
 from ray_tpu.models import hybrid
 from ray_tpu.models.hybrid import HybridConfig
 
@@ -86,12 +102,8 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
         @partial(jax.jit, donate_argnums=(1, 2))
         def step(params, pools, state, packed):
             tables, tokens, positions, active = unpack_step(packed, T)
-            b = tokens.shape[0]
             lay = PoolLayout.of(cfg, pools[0])
-            rows = jnp.arange(b)
-            bidx = jnp.where(active, tables[rows, positions // bs], 0)
-            off = jnp.where(active, positions % bs, 0)
-            kv_len = jnp.where(active, positions + 1, 0)      # 0: sits out
+            bidx, off, kv_len = _step_indices(tables, positions, active, bs)
             attend_for, kv = _attend_over(
                 cfg, lay, pools, bidx, off, tables, kv_lengths=kv_len)
             conv, ssm = state or (None, None)
@@ -127,11 +139,14 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
 
 
 def _attend_over(cfg, lay, pools, blocks, offsets, tables, *,
-                 kv_lengths=None, q_pos=None):
+                 kv_lengths=None, q_pos=None, q_table=None):
     """``paged_attend`` or ``latent_attend``, as the model's attention
     layers keep K/V heads or one latent: ONE token a row that attends
     its first ``kv_lengths`` keys, or one row's window of queries at
-    positions ``q_pos`` [w], each over the keys up to its own."""
+    positions ``q_pos`` [w], each over the keys up to its own — or, for
+    K/V heads attended packed, both in ONE window (``paged_attend``'s
+    fourth form): the one-token rows of ``tables`` and then the window
+    of the row whose table is ``q_table`` [1, T]."""
     if cfg.value_lanes is not None:
         return latent_attend(lay, pools, blocks, offsets, tables,
                              scale=cfg.attention_multiplier,
@@ -144,7 +159,29 @@ def _attend_over(cfg, lay, pools, blocks, offsets, tables, *,
     return paged_attend(lay, pools, blocks, offsets, tables,
                         q_per_kv=cfg.n_heads // cfg.n_kv_heads,
                         scale=cfg.attention_multiplier,
-                        kv_lengths=kv_lengths, mask=mask, q_pos=q_pos)
+                        kv_lengths=kv_lengths, mask=mask, q_pos=q_pos,
+                        mask_tables=q_table)
+
+
+def _chunk_window(table, start, C: int, bs: int):
+    """A chunk's window at positions ``start .. start + C`` of the row
+    whose table is ``table``: -> (positions [C], block ids [C], offsets
+    [C]); a position past the table's span writes to the scratch
+    block."""
+    pos = start + jnp.arange(C, dtype=jnp.int32)
+    oob = pos >= table.shape[0] * bs
+    safe = jnp.where(oob, 0, pos)
+    return (pos, jnp.where(oob, 0, table[safe // bs]),
+            jnp.where(oob, 0, pos % bs))
+
+
+def _row_of(pool, layer, row):
+    """Layer ``layer``'s state of decode row ``row`` (a traced scalar)
+    as a pool of one layer and one row, [1, 1, ..]: ONE dynamic slice
+    of the pool (a static slice of the layer first is a 268 MB copy a
+    layer on the chip)."""
+    return jax.lax.dynamic_slice(
+        pool, (layer, row) + (0,) * (pool.ndim - 2), (1, 1) + pool.shape[2:])
 
 
 def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
@@ -165,31 +202,22 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
     is the identity on it.
     """
     bs, C, T = int(block_size), int(chunk), int(n_table)
-    S = T * bs
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
         def chunk_fn(params, pools, state, packed):
             table, tokens, start, row, n_valid = unpack_chunk(packed, T, C)
             lay = PoolLayout.of(cfg, pools[0])
-            pos = start + jnp.arange(C, dtype=jnp.int32)
-            oob = pos >= S
-            safe = jnp.where(oob, 0, pos)
-            bidx = jnp.where(oob, 0, table[safe // bs])[None]   # [1, C]
-            off = jnp.where(oob, 0, pos % bs)[None]
+            pos, bidx, off = _chunk_window(table, start, C, bs)
             attend_for, kv = _attend_over(
-                cfg, lay, pools, bidx, off, table[None], q_pos=pos)
+                cfg, lay, pools, bidx[None], off[None], table[None],
+                q_pos=pos)
             held = dict(zip(("conv", "ssm"), state))
 
             def state_in(mi):
-                # ONE dynamic slice of the pool: a static slice of the
-                # layer first is a 268 MB copy a layer on the chip
-                def row_of(pool):
-                    return jax.lax.dynamic_slice(
-                        pool, (mi, row) + (0,) * (pool.ndim - 2),
-                        (1, 1) + pool.shape[2:])
                 # the row's SSM state as a pool of one layer and one row
-                return row_of(held["conv"])[0], (row_of(held["ssm"]), 0)
+                return (_row_of(held["conv"], mi, row)[0],
+                        (_row_of(held["ssm"], mi, row), 0))
 
             def state_out(mi, new):
                 held["conv"] = held["conv"].at[mi, row].set(new[0][0])
@@ -208,3 +236,98 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
         return chunk_fn
 
     return _cached(("recurrent_chunk", bs, T, C), cfg, None, None, build)
+
+
+def has_step_chunk(cfg: HybridConfig, lay: PoolLayout) -> bool:
+    """Whether ``make_recurrent_step_chunk`` has a program for this
+    model over pools of layout ``lay``: every sublayer kind must have
+    the two-part form (``hybrid.TWO_PART``: Mamba-2, attention over K/V
+    blocks, routed experts, the dense MLP; not latent attention, not
+    the delta rule), and a window of queries must be attended packed
+    under its mask, not head by head (``window_by_head``)."""
+    return ({kind for _, kind in cfg.sublayers} <= hybrid.TWO_PART
+            and not (cfg.n_attention and window_by_head(lay)))
+
+
+def make_recurrent_step_chunk(cfg: HybridConfig, *, chunk: int,
+                              block_size: int, n_table: int):
+    """jitted decode step AND one prefill chunk as ONE program: what a
+    pass that holds both runs in place of the two programs above back to
+    back, so that each layer's weights stream from HBM once a pass, not
+    twice (``decode.make_paged_step_chunk``, for this family).
+
+    (params, pools, state, packed [b * (T + 3) + T + C + 3] int32
+     (``pack_step_chunk``: a ``pack_step`` array, flat, then a
+     ``pack_chunk`` array))
+        -> (logits [b + 1, vocab] f32,
+            the step's load + greedy [4 + b], then the chunk's [5] int32,
+            pools, state)
+
+    The ``b`` rows' tokens and the chunk's ``C`` are ONE window ``[1, b
+    + C]`` of the layer function in its two-part form (``hybrid.block``
+    with ``rows``): the embedding, the norms, ``in_proj`` / ``out_proj``,
+    ``wqkv`` / ``wo``, the router, the shared expert and the dense MLP
+    are one product each over ``b + C`` rows, and the routed experts ONE
+    sort and one grouped matmul a projection over the ``(b + C) x
+    top-k`` assignments.  A mixer runs its two parts in the forms they
+    have in the two programs: attention commits the whole window's K/V
+    and attends the first ``b`` queries as one-token rows and the last
+    ``C`` under the chunk's mask over its row's gathered table; Mamba-2
+    advances the ``b`` rows' state where it lies in the pool, and the
+    chunk's row's from a slice of the pool that goes back there (that
+    row is inactive in the step: the two touch different rows).  The
+    head runs over ``b + 1`` rows: the decode rows and the chunk's last
+    real position.  The int32 vector is the step's then the chunk's, as
+    the two programs give them, the experts' load of each part counted
+    apart.  Same dtypes, products and masks as the two programs.
+
+    Only for a model ``has_step_chunk`` admits."""
+    bs, C, T = int(block_size), int(chunk), int(n_table)
+
+    def build():
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def step_chunk(params, pools, state, packed):
+            b = (packed.shape[0] - (T + C + 3)) // (T + 3)
+            tables, tokens, positions, active = unpack_step(
+                packed[:b * (T + 3)].reshape(b, T + 3), T)
+            table, chunk_tokens, start, row, n_valid = unpack_chunk(
+                packed[b * (T + 3):], T, C)
+            lay = PoolLayout.of(cfg, pools[0])
+            bidx, off, kv_len = _step_indices(tables, positions, active, bs)
+            pos, c_bidx, c_off = _chunk_window(table, start, C, bs)
+            attend_for, kv = _attend_over(
+                cfg, lay, pools, jnp.concatenate([bidx, c_bidx])[None],
+                jnp.concatenate([off, c_off])[None], tables,
+                kv_lengths=kv_len, q_pos=pos, q_table=table[None])
+            conv, ssm = state or (None, None)
+            held = {"conv": [], "ssm": ssm}
+
+            def state_out(mi, new):
+                held["conv"].append(new[0])
+                held["ssm"] = new[1][0]
+
+            # the rows' state as the step hands it over, and which row
+            # is the chunk's
+            x, load = hybrid.run_layers(
+                cfg, params,
+                hybrid.embed(cfg, params,
+                             jnp.concatenate([tokens, chunk_tokens])[None]),
+                jnp.append(active.astype(jnp.int32), n_valid),
+                state_in=lambda mi: (conv[mi], (held["ssm"], mi), row),
+                state_out=state_out,
+                attend_for=attend_for, rows=b)
+            last = b + jnp.maximum(n_valid, 1) - 1
+            x = jnp.concatenate(
+                [x[0, :b], jax.lax.dynamic_slice_in_dim(x[0], last, 1)])
+            logits = hybrid.head(cfg, params, x)            # [b + 1, V]
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            state = (jnp.stack(held["conv"]), held["ssm"]) if state else ()
+            return (logits,
+                    jnp.concatenate([load[0], greedy[:b], load[1],
+                                     greedy[b:]]),
+                    kv["pools"], state)
+
+        return step_chunk
+
+    return _cached(("recurrent_step_chunk", bs, T, C), cfg, None, None,
+                   build)

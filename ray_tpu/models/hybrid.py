@@ -70,7 +70,10 @@ keys (and, latent, the rotary tables at the window's positions).  The full-seque
 (zero state, keys = the window's own), the serving engine's
 chunk-prefill program ([1 row, chunk], state and K/V blocks from the
 pools) and its decode program ([rows, 1]) are that one function at three
-shapes (inference/recurrent.py builds the latter two).
+shapes (inference/recurrent.py builds the latter two).  A fourth is the
+two serving shapes as ONE window in two parts, [1, rows + chunk]
+(``block``'s ``rows``; the sublayer kinds of ``TWO_PART``): the pass
+that holds both then multiplies by every matrix once.
 
 Parameters are ``{"wte", "norm_f", "layers": [one dict a layer]}`` (and
 ``"head"`` [d, V] where it is not tied), each layer ``{"mixer": {...}}``,
@@ -97,6 +100,8 @@ from ray_tpu.ops.routed_experts import lanes, mlp, routed_experts
 MAMBA, ATTENTION, EXPERTS = "mamba", "attention", "experts"
 LATENT, DENSE, LINEAR = "latent", "dense", "linear_attention"
 N_LOAD = 4      # numbers in ``run_layers``' load vector (its text names them)
+# the sublayer kinds that take a window in two parts (``block``'s ``rows``)
+TWO_PART = frozenset({MAMBA, ATTENTION, EXPERTS, DENSE})
 # the sublayer kinds of a ``nemotron_h`` pattern string
 PATTERN_KINDS = {"M": MAMBA, "*": ATTENTION, "E": EXPERTS}
 
@@ -658,34 +663,90 @@ def _rms_norm(x, w, eps):
     return (y * jnp.asarray(w, jnp.float32)).astype(x.dtype)
 
 
-def _mamba_mixer(cfg, mp, h, state, n_valid):
-    """h [b, w, d]; state (conv [b, K-1, C], ssm): the rows' SSM state
-    as ``ops/ssm.ssd`` addresses it, (pool [L, b, H * P, N] f32, layer).
-    -> (out [b, w, d], state)."""
+def _ssm_core(cfg, mp, z, xBC, dt, state, n_valid):
+    """The Mamba-2 mixer between its two projections, on ``in_proj``'s
+    three outputs for the rows of ``state``.  -> (y [b, w, inner],
+    state)."""
     conv_state, (ssm_pool, ssm_layer) = state
-    b, w, _ = h.shape
+    b, w, _ = z.shape
     di, H, P, N, G = (cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_head_dim,
                       cfg.ssm_state, cfg.ssm_groups)
+    xBC, conv_state = ssm.causal_conv(xBC, conv_state, mp["conv_w"],
+                                      mp["conv_b"], n_valid)
+    x, B, C = jnp.split(xBC, [di, di + G * N], axis=-1)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"])
+    y, ssm_pool = ssm.ssd(x.reshape(b, w, H, P), dt,
+                          -jnp.exp(mp["A_log"]), B.reshape(b, w, G, N),
+                          C.reshape(b, w, G, N), mp["D"],
+                          ssm_pool, ssm_layer, n_valid, cfg.ssm_chunk)
+    y = y.reshape(b, w, di) * jax.nn.silu(z.astype(jnp.float32))
+    # the mean square over each group's channels, the weight over all
+    y = _rms_norm(y.reshape(b, w, G, di // G), 1.0, cfg.rms_eps)
+    y = (y.reshape(b, w, di)
+         * mp["gnorm"].astype(jnp.float32)).astype(z.dtype)
+    return y, (conv_state, (ssm_pool, ssm_layer))
+
+
+# The two-part window's program calls what repeats layer after layer
+# through ONE jitted function each: equal layers are traced and lowered
+# once, XLA inlines the calls.  On the chip, from a warm compile cache,
+# the fused program then adds 0.6-0.8 s to granite's set-up and 1.8-2.1
+# to nemotron's; without, 3.7-5.0 and 3.7 (PR 46, PERF.md section 6).
+# The one-part programs call the functions themselves and compile to
+# what they always did: doing it for all three is ROADMAP A8's.
+_SSM_CORE_PARAMS = ("conv_w", "conv_b", "dt_bias", "A_log", "D", "gnorm")
+_ssm_core_once = jax.jit(_ssm_core, static_argnums=0)
+
+
+def _mamba_mixer(cfg, mp, h, state, n_valid, rows: int = 0):
+    """h [b, w, d]; state (conv [b, K-1, C], ssm): the rows' SSM state
+    as ``ops/ssm.ssd`` addresses it, (pool [L, b, H * P, N] f32, layer).
+    -> (out [b, w, d], state).
+
+    With ``rows`` the window is in two parts (``block``): h [1, rows +
+    w, d], ``n_valid`` [rows + 1], and ``state`` (conv, ssm, row): the
+    one-token rows' state as above and WHICH of those rows the window
+    of ``w`` tokens belongs to (it sits the step out: ``n_valid`` 0).
+    The projections are one product each over the whole window; what
+    lies between them runs a part at a time in the form that part has
+    alone: the one-token kernel on the pool, then the chunked scan on
+    that row's state, read from the pool the kernel left (the pool stays
+    ONE chain of updates in place: read before the kernel's update it
+    is a second reader of the kernel's operand, and a copy of the
+    pool, 2.4 GB compiled for the chip)."""
+    di = cfg.ssm_inner
     with jax.named_scope("mixer_ssm_proj"):
         zxd = jnp.dot(h, mp["in_proj"].astype(h.dtype))
+        if rows:
+            # ONE materialisation: with the parts' six consumers the
+            # compiler computes the product again for most of them, the
+            # weights read each time (25 evaluations in 9 layers, 4 ms
+            # of a 29 ms program, read on the chip, PR 46)
+            zxd = jax.lax.optimization_barrier(zxd)
         z, xBC, dt = jnp.split(zxd, [di, di + cfg.conv_channels], axis=-1)
     with jax.named_scope("mixer_ssm"):
-        xBC, conv_state = ssm.causal_conv(xBC, conv_state, mp["conv_w"],
-                                          mp["conv_b"], n_valid)
-        x, B, C = jnp.split(xBC, [di, di + G * N], axis=-1)
-        dt = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"])
-        y, ssm_pool = ssm.ssd(x.reshape(b, w, H, P), dt,
-                              -jnp.exp(mp["A_log"]), B.reshape(b, w, G, N),
-                              C.reshape(b, w, G, N), mp["D"],
-                              ssm_pool, ssm_layer, n_valid, cfg.ssm_chunk)
-        y = y.reshape(b, w, di) * jax.nn.silu(z.astype(jnp.float32))
-        # the mean square over each group's channels, the weight over all
-        y = _rms_norm(y.reshape(b, w, G, di // G), 1.0, cfg.rms_eps)
-        y = (y.reshape(b, w, di)
-             * mp["gnorm"].astype(jnp.float32)).astype(h.dtype)
+        if rows:
+            conv0, ssm0, row = state
+            core = {k: mp[k] for k in _SSM_CORE_PARAMS}
+            # [1, rows + w, .] -> [rows, 1, .] and [1, w, .]
+            y1, (conv, (pool, layer)) = _ssm_core_once(
+                cfg, core, *(t[0, :rows, None] for t in (z, xBC, dt)),
+                (conv0, ssm0), n_valid[:rows])
+            at = (layer, row, 0, 0)
+            own = jax.lax.dynamic_slice(pool, at, (1, 1) + pool.shape[2:])
+            yw, (own_conv, (own, _)) = _ssm_core_once(
+                cfg, core, *(t[:, rows:] for t in (z, xBC, dt)),
+                (jax.lax.dynamic_slice_in_dim(conv0, row, 1), (own, 0)),
+                n_valid[rows:])
+            state = (conv.at[row].set(own_conv[0]),
+                     (jax.lax.dynamic_update_slice(pool, own, at), layer),
+                     row)
+            y = jnp.concatenate([y1[None, :, 0], yw], axis=1)
+        else:
+            y, state = _ssm_core(cfg, mp, z, xBC, dt, state, n_valid)
     with jax.named_scope("mixer_ssm_proj"):
         out = jnp.dot(y, mp["out_proj"].astype(h.dtype))
-    return out, (conv_state, (ssm_pool, ssm_layer))
+    return out, state
 
 
 def _unit(x, eps: float = 1e-6):
@@ -819,15 +880,18 @@ def _dense(cfg, fp, h):
 
 
 def _experts(cfg, fp, h, valid):
-    """h [b, w, d] -> (routed + shared [b, w, d], counts [E_held],
-    total)."""
+    """h [b, w, d]; ``valid`` [b, w] marks the real tokens, or [parts,
+    b, w] the real tokens of each part of the window -> (routed +
+    shared [b, w, d], counts [E_held], total; [parts, E_held] and
+    [parts] of a window in parts)."""
     b, w, d = h.shape
     flat = h.reshape(b * w, d)
     with jax.named_scope("routed_experts"):
         routed, counts, total = routed_experts(
             flat, fp["router"], fp["w_in"], fp["w_out"],
             top_k=cfg.experts_per_token, held=cfg.experts_held,
-            valid=valid.reshape(b * w), gated=cfg.gated_experts,
+            valid=valid.reshape(*valid.shape[:-2], b * w),
+            gated=cfg.gated_experts,
             bias=fp.get("router_bias"), scale=cfg.routed_scale,
             groups=cfg.route_groups, normalise=cfg.norm_topk)
     with jax.named_scope("shared_expert"):
@@ -836,7 +900,11 @@ def _experts(cfg, fp, h, valid):
     return (routed + shared).reshape(b, w, d), counts, total
 
 
-def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid):
+_experts_once = jax.jit(_experts, static_argnums=0)   # see ``_ssm_core_once``
+
+
+def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
+          rows: int = 0):
     """ONE residual sublayer on a window: x [b, w, d], ``n_valid`` [b]
     real tokens a row; ``lp`` its parameters.  ``past`` is the row's
     state for a Mamba sublayer (returned updated), the ``attend``
@@ -844,11 +912,25 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid):
     for a latent one (returned as they came) and unused by experts and
     the dense MLP.
     -> (x, past, (counts [E_held], total) of an experts sublayer, else
-        None)."""
+        None).
+
+    ``rows`` > 0: a window in TWO PARTS, x [1, rows + w, d] — ``rows``
+    one-token rows (a decode step's) and then ONE row's window of ``w``
+    tokens (a prefill chunk's) — with ``n_valid`` [rows + 1]: 0 / 1 a
+    one-token row, then the window's real tokens.  Every product over
+    ``d`` is then one product for both parts.  A Mamba sublayer's
+    ``past`` is the one-token rows' state and which of them the window
+    belongs to, (conv, ssm, row); an attention sublayer's ``attend``
+    treats the two parts apart itself (``decode.paged_attend`` with a
+    length a row AND a mask); experts count the parts apart: counts [2,
+    E_held], total [2].  Linear and latent attention have no such
+    form."""
     h = x if cfg.norm_output else _rms_norm(x, lp["norm"], cfg.rms_eps)
     load = None
+    if rows and kind not in TWO_PART:
+        raise ValueError(f"a {kind} sublayer has no two-part form")
     if kind == MAMBA:
-        mix, past = _mamba_mixer(cfg, lp, h, past, n_valid)
+        mix, past = _mamba_mixer(cfg, lp, h, past, n_valid, rows)
     elif kind == LINEAR:
         mix, past = _linear_mixer(cfg, lp, h, past, n_valid)
     elif kind == ATTENTION:
@@ -858,8 +940,15 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid):
     elif kind == DENSE:
         mix = _dense(cfg, lp, h)
     else:
-        valid = jnp.arange(x.shape[1])[None, :] < n_valid[:, None]
-        mix, counts, total = _experts(cfg, lp, h, valid)
+        if rows:
+            at = jnp.arange(-rows, x.shape[1] - rows)   # the window's from 0
+            valid = jnp.stack([
+                jnp.pad(n_valid[:rows] > 0, (0, at.size - rows)),
+                (at >= 0) & (at < n_valid[rows])])[:, None]
+        else:
+            valid = jnp.arange(x.shape[1])[None, :] < n_valid[:, None]
+        mix, counts, total = (_experts_once if rows else _experts)(
+            cfg, lp, h, valid)
         load = (counts, total)
     if cfg.norm_output:
         mix = _rms_norm(mix, lp["norm"], cfg.rms_eps)
@@ -881,43 +970,48 @@ def head(cfg: HybridConfig, params, x):
 
 
 def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
-               state_out: Callable, attend_for: Callable, positions=None):
+               state_out: Callable, attend_for: Callable, positions=None,
+               rows: int = 0):
     """The unrolled layer loop over a window.  ``state_in(mi)`` gives
     recurrent (Mamba or linear) layer ``mi``'s state for the window's
     rows, (conv, (state pool, layer)) as its mixer takes it, and
     ``state_out(mi, state)``
     takes it back; ``attend_for(ai)`` gives attention or latent layer
     ``ai``'s ``attend``; ``positions`` [b, w] are the window's, read by
-    rotary positions alone.
+    rotary positions alone.  ``rows``: the window is in two parts
+    (``block``: x [1, rows + w, d], ``n_valid`` [rows + 1], a Mamba
+    layer's state with the window's row).
     -> (x, load [N_LOAD] int32: held assignments, all assignments, the
         busiest held expert's assignments and the held experts with at
         least one assignment, each summed over the experts sublayers;
-        real tokens only)."""
+        real tokens only; [2, N_LOAD] of a window in two parts, the
+        one-token rows' then the window's)."""
     mi = ai = 0
-    load = jnp.zeros((N_LOAD,), jnp.int32)
+    load = jnp.zeros((2, N_LOAD) if rows else (N_LOAD,), jnp.int32)
     tables = rotary_tables(cfg, positions) if cfg.n_latent else None
     for i, kind in cfg.sublayers:
         lp = params["layers"][i]
         if kind == LATENT:
             x, _, _ = block(cfg, kind, lp["mixer"], x,
-                            (attend_for(ai), tables), n_valid)
+                            (attend_for(ai), tables), n_valid, rows)
             ai += 1
         elif kind == DENSE:
-            x, _, _ = block(cfg, kind, lp["ffn"], x, None, n_valid)
+            x, _, _ = block(cfg, kind, lp["ffn"], x, None, n_valid, rows)
         elif kind in (MAMBA, LINEAR):
             x, state, _ = block(cfg, kind, lp["mixer"], x, state_in(mi),
-                                n_valid)
+                                n_valid, rows)
             state_out(mi, state)
             mi += 1
         elif kind == ATTENTION:
             x, _, _ = block(cfg, kind, lp["mixer"], x, attend_for(ai),
-                            n_valid)
+                            n_valid, rows)
             ai += 1
         else:
             x, _, (counts, total) = block(cfg, kind, lp["ffn"], x, None,
-                                          n_valid)
-            load = load + jnp.stack([counts.sum(), total, counts.max(),
-                                     (counts > 0).sum(dtype=jnp.int32)])
+                                          n_valid, rows)
+            load = load + jnp.stack(
+                [counts.sum(-1), total, counts.max(-1),
+                 (counts > 0).sum(-1, dtype=jnp.int32)], axis=-1)
     return x, load
 
 

@@ -151,10 +151,12 @@ def routed_experts(h, w_router, w_in, w_out, *, top_k: int,
     h [T, d]; w_router [d, E]; w_in [E_held, d, 2f or f'], w_out
     [E_held, f, d] the weights of experts ``held[0] .. held[1] - 1`` in
     the form ``gated`` says; ``valid`` [T] bool marks real tokens
-    (padding routes like any token but is not counted); ``bias`` [E],
+    (padding routes like any token but is not counted), or [P, T] the
+    real tokens of P sets counted apart; ``bias`` [E],
     ``scale``, ``groups`` and ``normalise`` as ``route`` takes them.
     -> (out [T, d], counts [E_held] int32: real assignments per held
-        expert, total int32: real assignments to ANY expert)."""
+        expert, total int32: real assignments to ANY expert; [P, E_held]
+        and [P] for P sets)."""
     T, d = h.shape
     lo, hi = held
     n_held = hi - lo
@@ -181,8 +183,15 @@ def routed_experts(h, w_router, w_in, w_out, *, top_k: int,
     out = y[jnp.argsort(order)].reshape(T, top_k, d).sum(1)
     if valid is None:
         valid = jnp.ones((T,), bool)
-    real = jnp.repeat(valid, top_k)
-    counts = jnp.bincount(jnp.where(real, local, n_held),
-                          length=n_held + 1)[:n_held].astype(jnp.int32)
-    total = (valid.sum() * top_k).astype(jnp.int32)
+
+    def count(valid):
+        real = jnp.repeat(valid, top_k)
+        counts = jnp.bincount(jnp.where(real, local, n_held),
+                              length=n_held + 1)[:n_held].astype(jnp.int32)
+        return counts, (valid.sum() * top_k).astype(jnp.int32)
+
+    if valid.ndim == 1:
+        counts, total = count(valid)
+    else:
+        counts, total = (jnp.stack(t) for t in zip(*map(count, valid)))
     return out.astype(h.dtype), counts, total

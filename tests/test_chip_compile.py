@@ -729,6 +729,100 @@ def test_grouped_matmul_is_one_kernel_at_both_layouts(hybrid_decode_compiled,
         assert not calls(compiled, "%ragged-dot")
 
 
+# ---- the two programs of a pass as ONE, for the layouts whose every
+# ---- sublayer kind takes a window in two parts (ISSUE 46)
+
+@pytest.fixture(scope="module")
+def fused_cells(hybrid_cell, nano_cell):
+    """``name ->`` (cfg, params, layout, ssm pool, rows, chunk, the fused
+    program compiled once at the cell's shapes)."""
+    from ray_tpu.inference.recurrent import (has_step_chunk,
+                                             make_recurrent_step_chunk)
+    cells = {"granite": (*hybrid_cell[:8], hybrid_cell[0].ssm_chunk),
+             "nano": (*nano_cell[:8], nano_cell[8]["prefill_chunk"])}
+    done = {}
+
+    def compiled(name):
+        if name not in done:
+            cfg, on_chip, params, pool, lay, conv, ssm, rows, C = cells[name]
+            assert has_step_chunk(cfg, lay)
+            T = cfg.max_seq // lay.block_size
+            fused = make_recurrent_step_chunk(
+                cfg, chunk=C, block_size=lay.block_size, n_table=T)
+            args = (params, (pool, pool), (conv, ssm),
+                    on_chip((rows * (T + 3) + T + C + 3,), jnp.int32))
+            done[name] = (cfg, params, lay, ssm, rows, C,
+                          fused.lower(*args).compile(),
+                          jax.make_jaxpr(fused)(*args))
+        return done[name]
+    return compiled
+
+
+@pytest.mark.parametrize("name", ["granite", "nano"])
+def test_hybrid_step_chunk_fits_and_moves_no_pool(fused_cells, name):
+    """The K/V pools and the state pool ride the fused program as they
+    ride the two: in place, in one layout, never copied — though the
+    state pool now has TWO readers a layer (the one-token kernel and the
+    slice of the chunk's row) — and no expert stack is re-laid out."""
+    cfg, params, lay, ssm, rows, C, compiled, _ = fused_cells(name)
+    _assert_pool_stays_put(compiled, lay)
+    _assert_state_stays_put(compiled, ssm)
+    _no_expert_stack_is_copied(compiled.as_text(), params)
+
+
+@pytest.mark.parametrize("name", ["granite", "nano"])
+def test_hybrid_step_chunk_reads_each_layer_s_weights_once(fused_cells,
+                                                           name):
+    """What ISSUE 46 bought: the rows' tokens and the chunk's are ONE
+    window of the layer function, so every matrix of a layer is named by
+    ONE product, ``rows + chunk`` rows wide (the experts' stacks by one
+    grouped matmul each over the window's assignments), and streams from
+    HBM once a pass; only the recurrences run a part at a time, the
+    one-token kernel on the pool as stored."""
+    cfg, params, lay, ssm, rows, C, compiled, traced = fused_cells(name)
+    text = compiled.as_text()
+    # as traced: every matrix of every layer is the operand of ONE
+    # equation (the parameters are the program's first inputs)
+    program = traced.jaxpr.eqns[0].params["jaxpr"].jaxpr
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    matrices = 0
+    for (path, leaf), var in zip(leaves, program.invars):
+        where = jax.tree_util.keystr(path)
+        if "layers" in where and leaf.ndim >= 2 and "conv_w" not in where:
+            matrices += 1
+            assert sum(var in eqn.invars for eqn in program.eqns) == 1, where
+    assert matrices == sum(
+        w.ndim >= 2 and k != "conv_w" for lp in params["layers"]
+        for sub in lp.values() for k, w in sub.items()) >= 40
+    # the projections are as wide as the whole window
+    w = rows + C
+    d = cfg.d_model
+    assert re.findall(
+        rf"bf16\[(?:1,)?{w},{d}\]\S* (?:fusion|dot|convolution)\(", text)
+    for part in (rows, C):
+        assert not re.findall(
+            rf"bf16\[(?:1,)?{part},{d}\]\S* (?:dot|convolution)\(", text)
+    # ... and evaluated ONCE: a Mamba layer's ``in_proj`` product has six
+    # consumers in the two parts, and without its barrier the compiler
+    # computes it again for most of them (25 evaluations in granite's 9
+    # layers, the weights read each time: 4 ms of 29 on the chip)
+    wide = cfg.ssm_inner + cfg.conv_channels + cfg.ssm_heads
+    products = re.findall(
+        rf"^\s*%\S+ = bf16\[(?:1,)?{w},{wide}\]\S* fusion\(", text, re.M)
+    assert len(products) == cfg.n_mamba, len(products)
+    # the recurrences: one one-token kernel a Mamba layer, on the pool
+    pool = "f32[" + ",".join(map(str, ssm.shape)) + "]"
+    on_pool = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line
+               and pool in line]
+    assert len(on_pool) == cfg.n_mamba
+    gmm = [line for line in text.splitlines()
+           if " custom-call(" in line and "tpu_custom_call" in line
+           and line.strip().startswith("%gmm")]
+    assert len(gmm) == 2 * sum(k == "experts" for _, k in cfg.sublayers)
+    assert len(_kernel_calls(text)) == cfg.n_attention
+
+
 # ---------------------------------------------------------------------------
 # the latent-attention layout: ONE latent pool, the one-token kernel with
 # the up-projection absorbed, the window form a key block at a time

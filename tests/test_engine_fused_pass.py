@@ -4,8 +4,13 @@ make_paged_step_chunk`` stands in for the chunk program and the decode
 step back to back, the pass's last chunk is prepared and packed and the
 step launches it, and a prompt that ends in it gets its first token from
 the step's own integers.  ``eng._step_chunk = None`` is the only switch
-there is: the engine then takes the pass of two programs, as the family
-with a recurrent state always does.
+there is: the engine then takes the pass of two programs.  Since ISSUE 46
+the hybrid family has the program too (``recurrent.
+make_recurrent_step_chunk``) for the layouts whose every sublayer kind
+takes a window in two parts, Mamba-2's recurrent state among them; a
+latent, delta-rule or head-by-head attention sublayer keeps the pass of
+two programs (``recurrent.has_step_chunk``, derived from the sublayer
+kinds and the pool's layout).
 
 Tiny CPU models at f32 (greedy parity must not hinge on bf16 ties), both
 seams where the case applies."""
@@ -15,9 +20,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.inference import EngineConfig, InferenceEngine, decode
+from ray_tpu.inference import (EngineConfig, InferenceEngine, decode,
+                               recurrent)
 from ray_tpu.inference import engine as engine_mod
-from ray_tpu.inference.cache import BlockPool
+from ray_tpu.inference.cache import BlockPool, PoolLayout
 from ray_tpu.models import gpt, hybrid
 from ray_tpu.util import tracing
 
@@ -47,9 +53,17 @@ def seam(request, cfg, params):
 
 
 def _ref_tokens(params, cfg, prompt, max_new):
-    out = gpt.generate(params, cfg, jnp.asarray([prompt], jnp.int32),
-                       max_new=max_new, temperature=0.0)
-    return np.asarray(out)[0, len(prompt):].tolist()
+    """Greedy tokens by full recompute, for either family."""
+    if isinstance(cfg, gpt.GPTConfig):
+        out = gpt.generate(params, cfg, jnp.asarray([prompt], jnp.int32),
+                           max_new=max_new, temperature=0.0)
+        return np.asarray(out)[0, len(prompt):].tolist()
+    seq = np.zeros((1, 64), np.int32)       # causal: the padding is unseen
+    seq[0, :len(prompt)] = prompt
+    forward = jax.jit(lambda t: hybrid.forward(params, t, cfg))
+    for n in range(len(prompt), len(prompt) + max_new):
+        seq[0, n] = int(np.argmax(forward(seq)[0, n - 1]))
+    return seq[0, len(prompt):len(prompt) + max_new].tolist()
 
 
 def _spy(eng):
@@ -128,6 +142,170 @@ def test_one_program_gives_what_the_two_give(cfg, params):
         np.testing.assert_allclose(np.asarray(got)[keep],
                                    np.asarray(want)[keep],
                                    rtol=1e-5, atol=1e-5)
+
+
+# ----------------------------------------- the hybrid family's program
+
+def _nemotron_like():
+    """Single-mixer layers ``MEM*E``, two B/C groups, sigmoid-routed
+    relu^2 experts, an untied head."""
+    return hybrid.HybridConfig.tiny(
+        layer_types=tuple(hybrid.PATTERN_KINDS[k] for k in "MEM*E"),
+        experts_in_every_layer=False, gated_experts=False, ssm_groups=2,
+        routed_scale=2.5, tied_head=False, embedding_multiplier=1.0,
+        residual_multiplier=1.0, logits_scaling=1.0)
+
+
+def _no_experts():
+    """A mixer and the dense MLP a layer: no experts sublayer at all
+    (the load of either part is zeros, in the same places)."""
+    return hybrid.HybridConfig.tiny(dense_layers=3, dense_width=32)
+
+
+HYBRID_LAYOUTS = {"granite_like": hybrid.HybridConfig.tiny,
+                  "nemotron_like": _nemotron_like,
+                  "no_experts": _no_experts}
+# (the step's active rows, the chunk's decode row, its real tokens)
+WINDOWS = {"partial_chunk": ([1, 1, 0, 1], 2, 5),
+           "whole_chunk": ([1, 0, 0, 1], 1, C),
+           "one_token_chunk": ([0, 1, 1, 1], 0, 1),
+           "warm_up": ([0, 0, 0, 0], 0, 0)}
+
+
+@pytest.fixture(scope="module", params=list(HYBRID_LAYOUTS))
+def layout(request):
+    cfg = HYBRID_LAYOUTS[request.param]()
+    return cfg, hybrid.init_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("window", list(WINDOWS))
+def test_hybrid_program_gives_what_the_two_give(layout, window):
+    """One fused call against the chunk program then the decode step on
+    the same pools, state and packed inputs: the active rows' logits
+    and greedy tokens, the chunk's last real position's, the experts'
+    load of each part, the K/V pools (the scratch block aside) and every
+    row's convolution and SSM state, within float32 tolerance.  An
+    inactive row's logits are nobody's: the two programs hand the
+    chunk's row to the step with the chunk's state, the fused one with
+    the state before it."""
+    cfg, params = layout
+    active, row, n_valid = WINDOWS[window]
+    b = len(active)
+    pool = BlockPool(cfg, 20, BS, max_seq=96, state_rows=b)
+    T = pool.blocks_per_seq
+    assert recurrent.has_step_chunk(cfg, pool.layout)
+    geometry = dict(block_size=BS, n_table=T)
+    step = recurrent.make_recurrent_decode_step(cfg, **geometry)
+    chunk = recurrent.make_recurrent_chunk_fn(cfg, chunk=C, **geometry)
+    fused = recurrent.make_recurrent_step_chunk(cfg, chunk=C, **geometry)
+    assert fused is recurrent.make_recurrent_step_chunk(cfg, chunk=C,
+                                                        **geometry)
+
+    def fresh():
+        arrays = (*pool.pools, pool.state.conv, pool.state.ssm)
+        k, v, conv, ssm = (
+            jax.random.normal(jax.random.PRNGKey(i), a.shape, a.dtype)
+            for i, a in enumerate(arrays, 1))
+        return (k, v), (conv, ssm * 0.1)
+    rng = np.random.default_rng(0)
+    tables = np.zeros((b, T), np.int32)
+    tables[0, :3], tables[1, :2], tables[3, :1] = [1, 2, 3], [4, 5], [6]
+    tables[2, :2] = [10, 11]
+    positions = np.array([17, 9, 12, 3], np.int32)
+    packed_step = decode.pack_step(
+        tables, rng.integers(0, cfg.vocab_size, b).astype(np.int32),
+        positions, np.array(active, bool))
+    table = np.zeros(T, np.int32)
+    table[:3] = [7, 8, 9]
+    toks = np.zeros(C, np.int32)
+    toks[:n_valid] = rng.integers(0, cfg.vocab_size, n_valid)
+    # positions 8 .. of the chunk row's prompt (nothing, at warm-up)
+    packed_chunk = decode.pack_chunk(table, toks, 8 if n_valid else 0, row,
+                                     n_valid)
+
+    l_c, i_c, pools, state = chunk(params, *fresh(), packed_chunk)
+    l_s, i_s, pools, state = step(params, pools, state, packed_step)
+    l_f, i_f, pools_f, state_f = fused(
+        params, *fresh(), decode.pack_step_chunk(packed_step, packed_chunk))
+    n = hybrid.N_LOAD
+    assert l_f.shape == (b + 1, cfg.vocab_size) and l_f.dtype == jnp.float32
+    assert i_f.shape == (n + b + n + 1,) and i_f.dtype == jnp.int32
+    live = np.flatnonzero(active)
+    tol = dict(rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(l_f)[live], np.asarray(l_s)[live],
+                               **tol)
+    np.testing.assert_allclose(l_f[b], l_c[max(n_valid, 1) - 1], **tol)
+    i_f, i_s, i_c = (np.asarray(i).tolist() for i in (i_f, i_s, i_c))
+    # the load of each part apart, then that part's greedy tokens
+    assert i_f[:n] == i_s[:n] and i_f[n + b:] == i_c
+    assert [i_f[n + r] for r in live] == [i_s[n + r] for r in live]
+    k = cfg.experts_per_token * sum(
+        kind == hybrid.EXPERTS for _, kind in cfg.sublayers)
+    assert i_f[1] == k * len(live) and i_f[n + b + 1] == k * n_valid
+    lay = PoolLayout.of(cfg, pool.k)
+    scratch = [int(lay.rows(layer, 0)) for layer in range(lay.n_layers)]
+    for got, want in zip(pools_f, pools):
+        keep = np.ones(got.shape[0], bool)
+        keep[scratch] = False
+        np.testing.assert_allclose(np.asarray(got)[keep],
+                                   np.asarray(want)[keep], **tol)
+    for got, want in zip(state_f, state):
+        np.testing.assert_allclose(got, want, **tol)
+
+
+@pytest.mark.parametrize("kinds, kw, fused", [
+    ((hybrid.MAMBA, hybrid.ATTENTION), {}, True),
+    ((hybrid.ATTENTION,), {}, True),
+    ((hybrid.MAMBA, hybrid.ATTENTION),
+     dict(dense_layers=2, dense_width=32), True),
+    ((hybrid.MAMBA, hybrid.ATTENTION, hybrid.MAMBA),
+     dict(experts_in_every_layer=False), True),
+    ((hybrid.LINEAR, hybrid.ATTENTION), {}, False),
+    ((hybrid.LATENT,), {}, False)],
+    ids=["mamba_attention", "attention_alone", "dense_mlp_no_experts",
+         "mixers_alone", "linear_attention", "latent"])
+def test_which_layouts_get_the_program_follows_from_the_sublayer_kinds(
+        kinds, kw, fused):
+    """Every sublayer kind must take a window in two parts: Mamba-2,
+    attention over K/V blocks, routed experts and the dense MLP do, with
+    or without an experts sublayer among them; a delta-rule or a latent
+    sublayer keeps the pass of two programs.  No engine option and no
+    model name says so.  ``warm_up`` brings up and runs what there is."""
+    kw = dict(layer_types=kinds, **kw)
+    if hybrid.LINEAR in kinds:
+        kw.update(lin_heads=2, lin_key_dim=8, lin_value_dim=16,
+                  dense_layers=len(kinds), dense_width=32)
+    if hybrid.LATENT in kinds:
+        kw.update(q_rank=16, kv_rank=32, rope_dim=8, v_head_dim=16,
+                  n_kv_heads=1, yarn=hybrid.Yarn())
+    cfg = hybrid.HybridConfig.tiny(**kw)
+    eng = InferenceEngine(
+        hybrid.init_params(cfg, jax.random.PRNGKey(0)), cfg,
+        EngineConfig(max_slots=2, max_seq=32, kv_block_size=BS,
+                     prefill_chunk=C))
+    try:
+        assert recurrent.has_step_chunk(cfg, eng.pool.layout) == fused
+        assert (eng._step_chunk is not None) == fused
+        eng.warm_up(timeout=300)
+        assert eng.stats()["chunks_in_step"] == 0 and eng._load == []
+        if fused:
+            assert eng._step_chunk._cache_size() == 1
+    finally:
+        eng.shutdown()
+
+
+def test_head_by_head_window_attention_keeps_the_two_programs():
+    """More K/V heads of whole lane tiles than the packed form takes
+    (``decode.window_by_head``): the chunk's queries are attended head
+    by head, which the fused window has no form for."""
+    def layout_of(cfg):
+        layers, heads, head_dim = cfg.kv_geometry
+        return PoolLayout(layers, 5, BS, heads, head_dim)
+    cfg = hybrid.HybridConfig.tiny(n_heads=12, n_kv_heads=12, head_dim=128)
+    assert decode.window_by_head(layout_of(cfg))
+    assert not recurrent.has_step_chunk(cfg, layout_of(cfg))
+    few = hybrid.HybridConfig.tiny(n_heads=8, n_kv_heads=8, head_dim=128)
+    assert recurrent.has_step_chunk(few, layout_of(few))
 
 
 # ---------------------------------------------------------- the pass
@@ -230,20 +408,17 @@ def test_counters_spans_and_account_say_how_often_it_engaged(
     """``chunks_in_step`` in ``stats()``, on ``engine.account`` beside
     ``chunk_passes``, and ``chunk_tokens`` on ``engine.decode``;
     ``engine.prefill_chunk`` stays a chunk's host preparation whether or
-    not it rides.  An engine of the family with a recurrent state has no
-    fused program and never packs for one."""
+    not it rides.  A recurrent state does not stand in the way: the
+    tiny hybrid model's sublayer kinds all take a window in two parts."""
     cfg, params, ec = seam
     monkeypatch.setattr(engine_mod, "ACCOUNT_EVERY_NS", 0)
-    recurrent = cfg.state_geometry is not None
-    if recurrent:
-        def never(*a):
-            raise AssertionError("a fused pass on the K/V-and-state seam")
-        monkeypatch.setattr(engine_mod, "pack_step_chunk", never)
     rng = np.random.default_rng(2)
     tracing.clear()
     tracing.enable_tracing()
     eng = InferenceEngine(params, cfg, ec)
-    assert (eng._step_chunk is None) == recurrent
+    assert eng._step_chunk is not None
+    if cfg.state_geometry is not None:
+        assert recurrent.has_step_chunk(cfg, eng.pool.layout)
     calls, rode = _spy(eng)
     try:
         first = eng.submit(rng.integers(0, cfg.vocab_size, 5).tolist(),
@@ -266,7 +441,7 @@ def test_counters_spans_and_account_say_how_often_it_engaged(
     assert st["decode_iterations"] == calls["step"] + calls["step_chunk"]
     assert st["chunks_in_step"] == calls["step_chunk"]
     # (two rows prefilling in a pass: the first's chunk runs alone)
-    assert calls["step_chunk"] == 0 if recurrent else calls["step_chunk"] >= 3
+    assert calls["step_chunk"] >= 3
     decodes = [s["attributes"] for s in spans if s["name"] == "engine.decode"]
     assert sum(a["chunk_tokens"] > 0 for a in decodes) == st["chunks_in_step"]
     assert sum(a["chunk_tokens"] for a in decodes) \
@@ -360,17 +535,31 @@ def test_streams_exact_under_block_pressure(cfg, params, fused):
 def test_warm_up_brings_the_fused_program_to_the_device(seam):
     """Requests that arrive one at a time never overlap a chunk with a
     decoding row: ``warm_up`` runs the fused program once on nothing,
-    and the first real overlap compiles nothing."""
+    and the first real overlap compiles nothing.  It brings the pass's
+    three programs up side by side first, and the calls that follow find
+    each done: the compiler is handed every program ONCE (jax's own
+    count, ``jax.monitoring``: one event a lowering and one a compile,
+    whichever thread makes it)."""
     cfg, params, ec = seam
     decode.clear_fn_cache()
     eng = InferenceEngine(params, cfg, ec)
+    made = []
+
+    def note(event, duration, fun_name=None, **kw):
+        if event.endswith(("/jaxpr_to_mlir_module_duration",
+                           "/backend_compile_duration")):
+            made.append((fun_name, event.rsplit("/", 1)[1]))
+    jax.monitoring.register_event_duration_secs_listener(note)
     try:
         eng.warm_up(timeout=300)
+        for name in ("step", "chunk_fn", "step_chunk"):
+            assert sorted(what for fn, what in made
+                          if fn == f"jit({name})") \
+                == ["backend_compile_duration",
+                    "jaxpr_to_mlir_module_duration"], name
         warm = eng.stats()
         assert warm["chunks_in_step"] == 0 and warm["chunk_passes"] == 1
         assert warm["decode_iterations"] == 1 and eng._load == []
-        if eng._step_chunk is None:
-            return
         assert eng._step_chunk._cache_size() == 1
         first = eng.submit([3, 1, 4, 1, 5], max_new=30)
         next(first.stream(timeout=300))
@@ -380,7 +569,11 @@ def test_warm_up_brings_the_fused_program_to_the_device(seam):
         assert out == _ref_tokens(params, cfg, list(range(2, 21)), 3)
         assert first.result(timeout=300) \
             == _ref_tokens(params, cfg, [3, 1, 4, 1, 5], 30)
+        # ... and nothing of the pass since
+        assert len([fn for fn, _ in made if fn in (
+            "jit(step)", "jit(chunk_fn)", "jit(step_chunk)")]) == 6
     finally:
+        jax.monitoring.unregister_event_duration_listener(note)
         eng.shutdown()
         decode.clear_fn_cache()
 

@@ -6,6 +6,7 @@ deployment, sampled rows beside greedy ones.
 """
 
 import time
+from types import SimpleNamespace
 
 import jax
 import numpy as np
@@ -13,6 +14,7 @@ import pytest
 
 from ray_tpu.inference import (EngineConfig, InferenceEngine,
                                SpeculationUnsupported, metrics_snapshot)
+from ray_tpu.models import hybrid
 from test_hybrid_model import (_ref_logits, fam, params,  # noqa: F401
                                params_n)
 
@@ -194,3 +196,121 @@ def test_sampled_rows_beside_greedy_rows(fam):
     assert outs[0] == outs[1]
     assert _margins(fam, list(range(20, 31)), outs[0][1]).max() <= fam.atol
     assert _margins(fam, list(range(9)), outs[0][0]).max() > fam.atol
+
+
+# ------------------------------ a chunk inside the decode step (ISSUE 46)
+
+def _serve_behind(fam, fused, plan, seed, long_new=40):
+    """One long answer, then the prompts of ``plan`` (length, max_new)
+    while it streams -> (its tokens, theirs, stats, the prompts, how
+    often each of the pass's three programs was launched)."""
+    eng = _engine(fam, max_slots=4, n_blocks=20)
+    assert eng._step_chunk is not None
+    if not fused:
+        eng._step_chunk = None
+    calls = {}
+    for name in ("_step", "_chunk", "_step_chunk"):
+        def run(*args, program=getattr(eng, name), name=name):
+            calls[name] = calls.get(name, 0) + 1
+            return program(*args)
+        if getattr(eng, name) is not None:
+            setattr(eng, name, run)
+    rng = np.random.default_rng(seed)
+    long_ = rng.integers(0, 256, 6).tolist()
+    prompts = [rng.integers(0, 256, n).tolist() for n, _ in plan]
+    try:
+        first = eng.submit(long_, max_new=long_new)
+        it = first.stream(timeout=300)
+        head = [next(it) for _ in range(2)]           # it is decoding now
+        reqs = [eng.submit(p, max_new=m) for p, (_, m) in zip(prompts, plan)]
+        outs = [r.result(timeout=300) for r in reqs]
+        assert not first.done                         # ... and still is
+        whole = head + list(it)
+    finally:
+        eng.shutdown()
+    # (read once the loop has ended: a pass books its row steps after
+    # it has handed its tokens out)
+    return whole, outs, eng.stats(), [long_] + prompts, calls
+
+
+@pytest.mark.parametrize("fused", [True, False],
+                         ids=["one_program", "two_programs"])
+def test_prompts_behind_decoding_rows_stream_the_reference(fam, fused):
+    """Prompts of several chunks (partial last ones, one a whole number
+    of chunks, one whose first token ends it) arrive behind a decoding
+    row and then decode beside it: every stream is the reference's
+    whether the pass's last chunk rides the step or not."""
+    plan = [(19, 6), (11, 1), (16, 3), (3, 5), (27, 4)]
+    whole, outs, st, prompts, calls = _serve_behind(fam, fused, plan, 46)
+    for p, o, m in zip(prompts, [whole] + outs, [40] + [m for _, m in plan]):
+        assert len(o) == m and _margins(fam, p, o).max() <= fam.atol
+    rode = calls.get("_step_chunk", 0)
+    assert st["chunks_in_step"] == rode and (rode >= 5) == fused
+    assert st["chunk_passes"] == calls["_chunk"] + rode
+    assert st["decode_iterations"] == calls["_step"] + rode
+    tokens = sum(len(p) + len(o) - 1 for p, o in zip(prompts, [whole] + outs))
+    assert st["expert_assignments_total"] == st["expert_assignments_held"] \
+        == tokens * fam.top_k * fam.expert_layers
+
+
+def test_a_fused_pass_books_the_chunk_s_experts_to_the_chunk(fam):
+    """What the roofline readers divide by keeps its meaning: with ONE
+    row decoding (the prompts behind it end with their first token),
+    every decode pass touches that row's top-k experts a layer and no
+    more, however many experts the chunk that rode it touched; and every
+    counter is the two-program engine's."""
+    plan = [(19, 1), (9, 1), (24, 1)]
+    runs = {fused: _serve_behind(fam, fused, plan, 7, long_new=30)
+            for fused in (True, False)}
+    (whole, outs, st, _, calls), (whole2, outs2, st2, _, calls2) = \
+        runs[True], runs[False]
+    assert whole == whole2 and outs == outs2
+    assert calls["_step_chunk"] >= 4 and "_step_chunk" not in calls2
+    assert st["chunks_in_step"] == calls["_step_chunk"]
+    assert st2["chunks_in_step"] == 0
+    for key in ("row_steps", "decode_iterations", "chunk_passes",
+                "expert_touched_held_decode", "expert_touched_held",
+                "expert_assignments_held", "expert_assignments_total",
+                "expert_load_max", "generated_tokens"):
+        assert st[key] == st2[key], key
+    assert st["row_steps"] == st["decode_iterations"] == 29
+    assert st["expert_touched_held_decode"] \
+        == 29 * fam.top_k * fam.expert_layers
+    assert st["expert_touched_held"] > st["expert_touched_held_decode"] + \
+        3 * fam.expert_layers * fam.top_k
+
+
+@pytest.mark.parametrize(
+    "kw", [dict(dense_layers=3, dense_width=32),
+           dict(experts_in_every_layer=False)],
+    ids=["dense_mlp", "mixers_alone"])
+def test_a_model_with_no_experts_sublayer_rides_too(kw):
+    """The fused program is derived from the sublayer kinds, and a
+    layout of mixers with the dense MLP, or of mixers alone, has them
+    all: its load is zeros in the places the engine reads.  Streams are
+    the two-program engine's and the full forward's, and no expert is
+    ever counted."""
+    cfg = hybrid.HybridConfig.tiny(**kw)
+    assert hybrid.EXPERTS not in {kind for _, kind in cfg.sublayers}
+    model = SimpleNamespace(
+        cfg=cfg, params=hybrid.init_params(cfg, jax.random.PRNGKey(2)))
+    plan = [(19, 4), (11, 1), (16, 3)]
+    (whole, outs, st, prompts, calls), (whole2, outs2, st2, _, calls2) = (
+        _serve_behind(model, fused, plan, 5, long_new=30)
+        for fused in (True, False))
+    assert whole == whole2 and outs == outs2
+    assert st["chunks_in_step"] == calls["_step_chunk"] >= 3
+    assert st2["chunks_in_step"] == 0 and "_step_chunk" not in calls2
+    forward = jax.jit(lambda t: hybrid.forward(model.params, t, cfg))
+    for p, o in zip(prompts, [whole] + outs):
+        seq = np.zeros((1, 64), np.int32)   # causal: the padding is unseen
+        seq[0, :len(p) + len(o)] = p + o
+        logits = np.asarray(forward(seq))[0, len(p) - 1:len(p) + len(o) - 1]
+        assert (logits.max(-1) - logits[np.arange(len(o)), o]).max() <= 1e-5
+    for key in ("row_steps", "decode_iterations", "chunk_passes",
+                "generated_tokens"):
+        assert st[key] == st2[key], key
+    for key in ("expert_touched_held_decode", "expert_touched_held",
+                "expert_assignments_held", "expert_assignments_total",
+                "expert_load_max"):
+        assert st[key] == st2[key] == 0, key
