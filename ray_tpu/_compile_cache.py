@@ -1,7 +1,7 @@
 """Persistent XLA compile cache for process entry points.
 
-Called by the programs that run on the chip (chip_smoke.py, bench.py,
-benchmarks/*.py, examples/*) — never at import time of a library
+Called by the programs that run on the chip (chip_smoke.py,
+chipbench/run.py, examples/*) — never at import time of a library
 module.  The directory is part of the cache key, so it must not move:
 ``JAX_COMPILATION_CACHE_DIR`` decides when it is set (then nothing is
 set in code — jax reads the variable itself), otherwise the cache lives

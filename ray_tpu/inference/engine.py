@@ -114,6 +114,7 @@ from ray_tpu.inference.recurrent import (has_step_chunk,
 from ray_tpu.models import gpt, hybrid
 from ray_tpu.parallel.sharding import (DEFAULT_LLM_RULES, Rules,
                                        tree_shardings)
+from ray_tpu.serve import engine_stats
 from ray_tpu.util import tracing
 
 
@@ -568,12 +569,13 @@ class _KVAndState(_Seam):
         *chunks, step = loads
         if rode:
             chunks.append(step[-(hybrid.N_LOAD + 1):])
+        counts = eng._counts
         for load in (*chunks, step):
-            eng._expert_held += int(load[0])
-            eng._expert_total += int(load[1])
-            eng._expert_load_max += int(load[2])
-            eng._expert_touched += int(load[3])
-        eng._expert_touched_decode += int(step[3])
+            counts.expert_assignments_held += int(load[0])
+            counts.expert_assignments_total += int(load[1])
+            counts.expert_load_max += int(load[2])
+            counts.expert_touched_held += int(load[3])
+        counts.expert_touched_held_decode += int(step[3])
 
     @staticmethod
     def row_admitted(eng, row) -> None:
@@ -702,65 +704,15 @@ class InferenceEngine:
         # cluster directory (bounded; oldest dropped first)
         self._prefix_outbox: list = []
 
-        # metrics (guarded by _cond's lock via _mlock simplicity: own lock)
+        # what the engine counts: the cumulative rows of
+        # ``serve/engine_stats.py``, an attribute each.  The loop thread
+        # writes most of them alone, without a lock; ``_mlock`` guards
+        # the ones another thread may write or that are read as a pair
+        # (the table says which), and the prefix outbox
         self._mlock = threading.Lock()
-        self._generated_tokens = 0
-        self._requests_completed = 0
-        self._decode_iterations = 0
-        self._occupancy_sum = 0.0      # Σ active/max_slots per iteration
-        self._prefix_hit_tokens = 0
-        self._prefix_lookup_tokens = 0
-        self._prefix_blocks_adopted = 0    # blocks taken over from the index
-        self._preemptions = 0
-        # written by the loop thread alone, so without the lock:
-        self._admissions = 0           # requests given a row
-        self._chunk_passes = 0         # prefill chunks run ...
-        self._chunks_in_step = 0       # ... of them inside a decode step
-        self._prefill_tokens = 0       # prompt tokens run through a
-        #                                prefill program (hits excluded)
-        # per one-token decode pass: the blocks that hold a key of a
-        # live row (what the attention kernel reads of each pool and
-        # layer) and the rows' whole tables (what a gather would read)
-        self._kv_blocks_attended = 0
-        self._kv_blocks_tabled = 0
-        # per prefill chunk: the keys in reach of its window (what a
-        # window form must read of the row's past: position + tokens)
-        # and the (query, key) pairs under the causal mask
-        self._chunk_keys = 0
-        self._chunk_query_keys = 0
-        # a model with linear-attention layers (a matrix state a row):
-        # rows whose state a one-token pass wrote, and real prompt
-        # tokens through the window form; 0 for every other model
+        self._counts = engine_stats.Counters()
+        # a model with linear-attention layers (a matrix state a row)
         self._linear = getattr(cfg, "n_linear", 0) > 0
-        self._linear_state_rows_advanced = 0
-        self._linear_chunk_tokens = 0
-        self._peak_active = 0
-        self._spec_drafted = 0         # drafted tokens offered to verify
-        self._spec_accepted = 0        # drafted tokens accepted
-        self._spec_passes = 0          # verify passes run
-        # per-ROW step accounting: tokens_per_step = row_tokens /
-        # row_steps is exactly 1.0 for plain decode by construction,
-        # and 1 + accepted-per-row-pass under speculation — the batch
-        # width cancels out, so the gauge isolates speculation's win
-        self._row_steps = 0            # (row, compiled-call) pairs
-        self._row_tokens = 0           # tokens those pairs emitted
-        # routed-expert load of the programs that report one (loop
-        # thread only): assignments to the experts held here, to all
-        # experts, and the busiest held expert's summed over layers and
-        # passes (max / (held / experts held) = that pass's imbalance)
-        self._expert_held = 0
-        self._expert_total = 0
-        self._expert_load_max = 0
-        self._expert_touched = 0          # held experts with >= 1 token
-        self._expert_touched_decode = 0   # ... in decode steps alone
-        # where a decode or first token was chosen (loop thread only):
-        # by a program's own argmax, or by a dispatch of its own (a
-        # sampled row, a full-width prefill's first token, a
-        # speculative pass's accept walk); and the bytes every
-        # ``engine.fetch`` brought to the host
-        self._tokens_on_device = 0
-        self._tokens_sampled = 0
-        self._fetch_bytes = 0
         # the loop thread's time by phase, always on; ``engine.account``
         # spans carry it to the ring (``_write_account``)
         self._passes = 0               # passes that found work
@@ -972,9 +924,9 @@ class InferenceEngine:
         tracing.record_span(
             "engine.account", self._account_t1_ns, t1_ns,
             engine=self.name, passes=self._passes,
-            decode_iterations=self._decode_iterations,
-            chunk_passes=self._chunk_passes,
-            chunks_in_step=self._chunks_in_step,
+            decode_iterations=self._counts.decode_iterations,
+            chunk_passes=self._counts.chunk_passes,
+            chunks_in_step=self._counts.chunks_in_step,
             profiling=acct.interval_profiled(),
             ring_dropped=tracing.ring_dropped(), **acct.snapshot())
         self._account_t1_ns = t1_ns
@@ -984,7 +936,8 @@ class InferenceEngine:
         reaping, admission."""
         with self._acct.phase("admit") as sp:
             # only this thread writes the two counters
-            admitted0, preempted0 = self._admissions, self._preemptions
+            counts = self._counts
+            admitted0, preempted0 = counts.admissions, counts.preemptions
             if self._ops:
                 self._run_ops_locked()
             # reap cancelled waiters even when the pool is full:
@@ -1000,8 +953,8 @@ class InferenceEngine:
             self._waiting = live
             self._paged_admit_locked()
             if sp:
-                sp.set(admitted=self._admissions - admitted0,
-                       preempted=self._preemptions - preempted0)
+                sp.set(admitted=counts.admissions - admitted0,
+                       preempted=counts.preemptions - preempted0)
 
     def _admission_possible(self) -> bool:
         """Cheap park-predicate check; the real budget decision happens
@@ -1130,12 +1083,14 @@ class InferenceEngine:
         req._admitted()
         req.prefix_hit_tokens = hit
         occupied = self.engine_cfg.max_slots - len(self._free_rows)
-        self._admissions += 1
+        counts = self._counts
+        counts.admissions += 1
         with self._mlock:
-            self._prefix_hit_tokens += hit
-            self._prefix_blocks_adopted += len(ids)
-            self._prefix_lookup_tokens += n_prompt
-            self._peak_active = max(self._peak_active, occupied)
+            counts.prefix_hit_tokens += hit
+            counts.prefix_blocks_adopted += len(ids)
+            counts.prefix_lookup_tokens += n_prompt
+            counts.peak_active_requests = max(counts.peak_active_requests,
+                                              occupied)
         return True
 
     def _take_block(self, row: int) -> Optional[int]:
@@ -1185,7 +1140,7 @@ class InferenceEngine:
         req._consumed = len(req.tokens)
         req.preemptions += 1
         with self._mlock:
-            self._preemptions += 1
+            self._counts.preemptions += 1
         with self._cond:
             stopped = self._stopped
             if not stopped:
@@ -1318,8 +1273,9 @@ class InferenceEngine:
                 with self._mlock:
                     # the prompt was counted at admission; fold in only
                     # the INCREMENTAL tokens the re-match won
-                    self._prefix_hit_tokens += hit2 - pos
-                    self._prefix_blocks_adopted += len(ids2) - pos // bs
+                    self._counts.prefix_hit_tokens += hit2 - pos
+                    self._counts.prefix_blocks_adopted += (len(ids2)
+                                                           - pos // bs)
                 pos = self._prefilling[row] = hit2
             else:
                 for bid in ids2:
@@ -1338,7 +1294,7 @@ class InferenceEngine:
             # also means no adopted blocks — the table is exclusive.)
             sp.set(row=row, tokens=n, full_width=True)
             req.full_width_prefill = True
-            self._prefill_tokens += n
+            self._counts.prefill_tokens += n
             padded = np.zeros((1, self.max_seq), np.int32)
             padded[0, :n] = prompt
             with self._acct.phase("dispatch"):
@@ -1360,12 +1316,13 @@ class InferenceEngine:
             sp.set(row=row, tokens=n_q, full_width=False,
                    state_rows=self.pool.state_rows_in_use)
         req.chunk_passes += 1
-        self._chunk_passes += 1
-        self._prefill_tokens += n_q
-        self._chunk_keys += pos + n_q
-        self._chunk_query_keys += n_q * pos + n_q * (n_q + 1) // 2
+        counts = self._counts
+        counts.chunk_passes += 1
+        counts.prefill_tokens += n_q
+        counts.chunk_keys += pos + n_q
+        counts.chunk_query_keys += n_q * pos + n_q * (n_q + 1) // 2
         if self._linear:
-            self._linear_chunk_tokens += n_q
+            counts.linear_chunk_tokens += n_q
         chunk_toks = np.zeros(C, np.int32)
         chunk_toks[:n_q] = prompt[pos:pos + n_q]
         with self._acct.phase("pack") as up:
@@ -1447,6 +1404,7 @@ class InferenceEngine:
         bytes); with no step behind the chunk (``_pass_done``) each is
         a wait of its own."""
         n = n_bytes = 0
+        counts = self._counts
         for pend in self._first_pending:
             row, req, owed, tok = pend
             # a row preempted since (the block hunt of this pass's
@@ -1466,9 +1424,9 @@ class InferenceEngine:
             pend[3] = tok
             req._emit(tok)
             if req.temperature == 0.0:
-                self._tokens_on_device += 1
+                counts.tokens_greedy_on_device += 1
             else:
-                self._tokens_sampled += 1
+                counts.tokens_sampled += 1
         return n, n_bytes
 
     def _pass_done(self) -> None:
@@ -1485,7 +1443,7 @@ class InferenceEngine:
     def _fetched(self, fetch, n_bytes: int, **attributes) -> None:
         """An ``engine.fetch`` span brought ``n_bytes`` to the host."""
         fetch.set(bytes=n_bytes, **attributes)
-        self._fetch_bytes += n_bytes
+        self._counts.fetch_bytes += n_bytes
 
     def _fetch_step(self, fetch, rode: bool) -> None:
         """The rows' greedy tokens of the decode step just dispatched
@@ -1529,7 +1487,7 @@ class InferenceEngine:
             tok = gpt.sample_token(last_logits,
                                    temperature=req.temperature,
                                    rng=req._next_rng())
-        self._tokens_sampled += 1
+        self._counts.tokens_sampled += 1
         with self._acct.phase("wait") as fetch:
             self._fetched(fetch, 4)
             return int(tok)
@@ -1726,17 +1684,17 @@ class InferenceEngine:
             self._fetched(fetch, logits.nbytes)
         with self._acct.phase("emit") as sample:
             with self._mlock:
-                self._decode_iterations += 1
-                self._spec_passes += 1
-                self._occupancy_sum += (float(self._active.sum())
-                                        / self.engine_cfg.max_slots)
+                self._counts.decode_iterations += 1
+                self._counts.spec_passes += 1
+                self._counts.occupancy_sum += (float(self._active.sum())
+                                               / self.engine_cfg.max_slots)
             stepped, emitted = self._spec_accept(logits, drafts, want,
                                                  force_reject)
             sample.set(rows=stepped)
-        self._tokens_sampled += emitted
+        self._counts.tokens_sampled += emitted
         with self._mlock:
-            self._row_steps += stepped
-            self._row_tokens += emitted
+            self._counts.row_steps += stepped
+            self._counts.row_tokens += emitted
 
     def _spec_accept(self, logits: np.ndarray, drafts: np.ndarray,
                      want: np.ndarray, force_reject: bool) -> tuple:
@@ -1785,8 +1743,8 @@ class InferenceEngine:
             req.spec_drafted += w
             req.spec_accepted += accepted
             with self._mlock:
-                self._spec_drafted += w
-                self._spec_accepted += accepted
+                self._counts.spec_drafted_tokens += w
+                self._counts.spec_accepted_tokens += accepted
             if finished:
                 self._paged_evict(row)    # releases the whole chain
             else:
@@ -1799,7 +1757,7 @@ class InferenceEngine:
         preempt); cancelled rows are evicted.  Rows it has seen to
         before in the same pass cost a look."""
         with self._acct.phase("grow") as sp:
-            preempted0 = self._preemptions
+            preempted0 = self._counts.preemptions
             for row in [r for r in list(self._slot_req) if self._active[r]]:
                 req = self._slot_req.get(row)
                 if req is None or not self._active[row]:
@@ -1809,7 +1767,8 @@ class InferenceEngine:
                     self._paged_evict(row, cache_prefix=False)
                     continue
                 self._grow_row(row)       # False = row preempted; skip
-            sp.set(admitted=0, preempted=self._preemptions - preempted0)
+            sp.set(admitted=0,
+                   preempted=self._counts.preemptions - preempted0)
 
     def _paged_decode_iteration(self, ride=None) -> None:
         """One decode step over the active rows.  ``ride``: the chunk
@@ -1852,7 +1811,7 @@ class InferenceEngine:
             with self._acct.phase("dispatch"):
                 logits = self._seam.run(self, program, packed)
             if ride:
-                self._chunks_in_step += 1
+                self._counts.chunks_in_step += 1
                 self._chunk_launched(*ride[:2], logits,
                                      self.engine_cfg.max_slots, in_step=True)
             if self._mesh is not None:
@@ -1864,16 +1823,17 @@ class InferenceEngine:
             with self._acct.phase("wait") as fetch:
                 self._fetch_step(fetch, ride is not None)
             with self._acct.phase("emit") as sample:
+                counts = self._counts
                 with self._mlock:
-                    self._decode_iterations += 1
-                    self._occupancy_sum += (float(self._active.sum())
-                                            / self.engine_cfg.max_slots)
-                self._kv_blocks_attended += int(
+                    counts.decode_iterations += 1
+                    counts.occupancy_sum += (float(self._active.sum())
+                                             / self.engine_cfg.max_slots)
+                counts.kv_blocks_attended += int(
                     (self._positions[self._active]
                      // self.engine_cfg.kv_block_size + 1).sum())
-                self._kv_blocks_tabled += self._tables.size
+                counts.kv_blocks_tabled += self._tables.size
                 if self._linear:
-                    self._linear_state_rows_advanced += int(
+                    counts.linear_state_rows_advanced += int(
                         self._active.sum())
                 greedy = self._seam.greedy(self, logits)
                 stepped = 0
@@ -1883,13 +1843,13 @@ class InferenceEngine:
                     req = self._slot_req[row]
                     if req.temperature == 0.0:
                         tok = int(greedy[row])
-                        self._tokens_on_device += 1
+                        counts.tokens_greedy_on_device += 1
                     else:
                         # its own rng, on its logits where they lie
                         tok = int(gpt.sample_token(
                             logits[row], temperature=req.temperature,
                             rng=req._next_rng()))
-                        self._tokens_sampled += 1
+                        counts.tokens_sampled += 1
                     req._emit(tok)
                     stepped += 1
                     self._positions[row] += 1
@@ -1904,8 +1864,8 @@ class InferenceEngine:
                 # 0.75 ms a pass on the chip: ``PERF.md`` section 5)
                 del logits
         with self._mlock:
-            self._row_steps += stepped
-            self._row_tokens += stepped
+            counts.row_steps += stepped
+            counts.row_tokens += stepped
 
     def _paged_evict(self, row: int, cache_prefix: bool = True) -> None:
         """Natural eviction (EOS / max-tokens / cancel): donate the
@@ -1924,14 +1884,14 @@ class InferenceEngine:
 
     def _request_finished(self, req: GenerationRequest, tok: int) -> bool:
         with self._mlock:
-            self._generated_tokens += 1
+            self._counts.generated_tokens += 1
         eos = self.engine_cfg.eos_token
         return (len(req.tokens) >= req.max_new
                 or (eos is not None and tok == eos))
 
     def _note_done(self, req: GenerationRequest) -> None:
         with self._mlock:
-            self._requests_completed += 1
+            self._counts.requests_completed += 1
         self._fr_note(req)
 
     def _fail_all(self, e: BaseException) -> None:
@@ -2162,6 +2122,8 @@ class InferenceEngine:
         return self._run_op(op)
 
     def stats(self) -> dict:
+        """Every row of ``serve/engine_stats.py``: the counters as they
+        stand, the gauges read off the engine here, the ratios."""
         with self._cond:
             waiting = len(self._waiting)
             interactive = sum(1 for r in self._waiting
@@ -2169,136 +2131,43 @@ class InferenceEngine:
             stopped = self._stopped
             draining = self._draining
             occupied = self.engine_cfg.max_slots - len(self._free_rows)
-        with self._mlock:
-            iters = self._decode_iterations
-            occ = (self._occupancy_sum / iters) if iters else 0.0
-            generated = self._generated_tokens
-            completed = self._requests_completed
-            hit_toks = self._prefix_hit_tokens
-            lookup_toks = self._prefix_lookup_tokens
-            preemptions = self._preemptions
-            admissions = self._admissions
-            chunk_passes = self._chunk_passes
-            chunks_in_step = self._chunks_in_step
-            prefill_tokens = self._prefill_tokens
-            kv_attended = self._kv_blocks_attended
-            kv_tabled = self._kv_blocks_tabled
-            peak = self._peak_active
-            drafted = self._spec_drafted
-            accepted = self._spec_accepted
-            spec_passes = self._spec_passes
-            row_steps = self._row_steps
-            row_tokens = self._row_tokens
-        out = {
-            "max_slots": self.engine_cfg.max_slots,
-            "waiting_requests": waiting,
-            "waiting_interactive": interactive,
-            "stopped": stopped,
-            "draining": draining,
-            "batch_occupancy": occ,
-            "generated_tokens": generated,
-            "requests_completed": completed,
-            "decode_iterations": iters,
-            # counters at the span boundaries (util/tracing.py); /metrics
-            # exports them (metrics_snapshot)
-            "admissions": admissions,
-            # prefill chunks run, and those of them that ran inside a
-            # decode step's program (``_step_chunk``)
-            "chunk_passes": chunk_passes,
-            "chunks_in_step": chunks_in_step,
-            "prefill_tokens": prefill_tokens,
-            "kv_blocks_attended": kv_attended,
-            "kv_blocks_tabled": kv_tabled,
-            "chunk_keys": self._chunk_keys,
-            "chunk_query_keys": self._chunk_query_keys,
-            "linear_state_rows_advanced": self._linear_state_rows_advanced,
-            "linear_chunk_tokens": self._linear_chunk_tokens,
-            # decode and first tokens by where they were chosen: by a
-            # program's own argmax (the integers a pass fetches), or by
-            # a dispatch of their own on the logits (a sampled row, a
-            # full-width prefill's first token, a speculative pass's
-            # accept walk); and what every ``engine.fetch`` brought
-            "tokens_greedy_on_device": self._tokens_on_device,
-            "tokens_sampled": self._tokens_sampled,
-            "fetch_bytes": self._fetch_bytes,
-            # the loop thread's wall time by phase (``_LOOP_PHASES``):
-            # self ``ns``, the part of it with no program in flight
-            # ``starved_ns``, entries ``count``; what no phase covers;
-            # from ``t_made_ns`` to ``t_ns``, the loop's newest stamp
-            "loop_account": {**self._acct.snapshot(),
-                             "passes": self._passes,
-                             "t_made_ns": self._acct.t_made_ns,
-                             "t_ns": self._acct.t_ns},
-            # tokens emitted per (row, compiled call) pair: exactly 1.0
-            # for plain decode by construction, 1 + accepted-per-pass
-            # under speculation — batch width cancels out
-            "tokens_per_step": (row_tokens / row_steps) if row_steps
-                               else 0.0,
-            # raw counters behind tokens_per_step so fleet aggregation
-            # can reduce exactly instead of averaging averages
-            "row_steps": row_steps,
-            "row_tokens": row_tokens,
-            # ---- speculative decoding (zeros when speculate=None)
-            "speculate": self._spec,
-            "spec_drafted_tokens": drafted,
-            "spec_accepted_tokens": accepted,
-            "spec_accept_rate": (accepted / drafted) if drafted else 0.0,
-            "spec_passes": spec_passes,
-            # ---- serving geometry (mesh_devices=1 when unmeshed so
-            # fleet aggregation can sum/compare without None checks)
-            "mesh_devices": (int(np.prod(list(self._mesh.devices.shape)))
-                             if self._mesh is not None else 1),
-            "mesh_axes": (dict(zip(self._mesh.axis_names,
-                                   self._mesh.devices.shape))
-                          if self._mesh is not None else {}),
-            "tp_shards": self.pool.heads_shards,
-            # the tree the programs are handed, and how much of it they
-            # cast to the dtype they compute in EVERY pass (0: each such
-            # leaf is stored in it; by shapes and dtypes at construction)
-            "weight_bytes": self._weight_bytes,
-            "weight_bytes_cast_per_pass": self._weight_bytes_cast,
-        }
+        out = self._counts.snapshot(self._mlock)
         pool = self.pool.stats()
-        total = pool["blocks_total"]
-        out.update({
-            # occupied rows (decoding + prefilling)
-            "active_slots": occupied,
-            "free_slots": self.engine_cfg.max_slots - occupied,
-            "cache_bytes": pool["bytes_total"],
-            "cache_bytes_per_device": pool["bytes_per_device"],
-            "block_size": pool["block_size"],
-            # block COUNTS are replicated across tp shards (heads
-            # are what's split): blocks_total is the global
-            # admission budget AND the per-device count — both
-            # keys reported so neither meaning is silently guessed
-            "blocks_total": total,
-            "blocks_per_device": pool["blocks_per_device"],
-            "blocks_free": pool["blocks_free"],
-            "block_utilization": (pool["blocks_used"] / total
-                                  if total else 0.0),
-            "prefix_cached_blocks": (self.trie.cached_blocks
-                                     if self.trie is not None else 0),
-            "prefix_hit_tokens": hit_toks,
-            "prefix_blocks_adopted": self._prefix_blocks_adopted,
-            "prefix_lookup_tokens": lookup_toks,
-            "prefix_hit_rate": (hit_toks / lookup_toks
-                                if lookup_toks else 0.0),
-            "preemptions": preemptions,
-            "peak_active_requests": peak,
-            # the second kind of state (zeros for a model that keeps
-            # none) and the routed experts' load (zeros for a model
-            # whose programs report none)
-            "state_bytes": pool["state_bytes"],
-            "state_rows_in_use": pool["state_rows_in_use"],
-            "expert_assignments_held": self._expert_held,
-            "expert_assignments_total": self._expert_total,
-            "expert_load_max": self._expert_load_max,
-            "expert_touched_held": self._expert_touched,
-            "expert_touched_held_decode": self._expert_touched_decode,
-            # fences remotely-advertised block ids across donated-
-            # pool recoveries (cluster prefix plane)
-            "pool_generation": pool["generation"],
-        })
+        mesh = self._mesh
+        out.update(
+            max_slots=self.engine_cfg.max_slots,
+            active_slots=occupied,
+            free_slots=self.engine_cfg.max_slots - occupied,
+            waiting_requests=waiting,
+            waiting_interactive=interactive,
+            stopped=stopped,
+            draining=draining,
+            cache_bytes=pool["bytes_total"],
+            cache_bytes_per_device=pool["bytes_per_device"],
+            block_size=pool["block_size"],
+            blocks_total=pool["blocks_total"],
+            blocks_per_device=pool["blocks_per_device"],
+            blocks_free=pool["blocks_free"],
+            prefix_cached_blocks=(self.trie.cached_blocks
+                                  if self.trie is not None else 0),
+            pool_generation=pool["generation"],
+            loop_account={**self._acct.snapshot(),
+                          "passes": self._passes,
+                          "t_made_ns": self._acct.t_made_ns,
+                          "t_ns": self._acct.t_ns},
+            speculate=self._spec,
+            mesh_devices=(int(np.prod(list(mesh.devices.shape)))
+                          if mesh is not None else 1),
+            mesh_axes=(dict(zip(mesh.axis_names, mesh.devices.shape))
+                       if mesh is not None else {}),
+            tp_shards=self.pool.heads_shards,
+            state_bytes=pool["state_bytes"],
+            state_rows_in_use=pool["state_rows_in_use"],
+            weight_bytes=self._weight_bytes,
+            weight_bytes_cast_per_pass=self._weight_bytes_cast)
+        out.update(engine_stats.ratios(out))
+        for key in engine_stats.OPERANDS_ONLY:
+            del out[key]
         return out
 
     def shutdown(self, timeout: float = 5.0) -> None:
@@ -2311,22 +2180,13 @@ class InferenceEngine:
 def metrics_snapshot() -> list:
     """Per-engine gauges/counters in the metrics exporter's tuple format
     (ray_tpu.metrics.render_prometheus); aggregated by the serve-layer
-    /metrics endpoint alongside the per-deployment request counters."""
+    /metrics endpoint alongside the per-deployment request counters.
+    One series a row of ``serve/engine_stats.py`` that names one, in the
+    table's order, then the loop account's two by phase."""
     with _registry_lock:
         engines = dict(_ENGINES)
-    active, waiting, occ, gen, comp = {}, {}, {}, {}, {}
-    butil, phit, pcached, preempt = {}, {}, {}, {}
-    padopt, phtok = {}, {}
-    admits, chunks, in_step, ptoks = {}, {}, {}, {}
-    kv_att, kv_tab = {}, {}
-    ckeys, cqkeys = {}, {}
-    lrows, ltoks = {}, {}
-    on_dev, sampled, fbytes = {}, {}, {}
-    tps, arate, saccept = {}, {}, {}
-    meshdev, tpsh = {}, {}
-    sbytes, srows, eheld, etotal, emax = {}, {}, {}, {}, {}
-    etouch, etouchd = {}, {}
-    wbytes, wcast = {}, {}
+    rows = [row for row in engine_stats.ROWS if row.metric]
+    series = {row.key: {} for row in rows}
     loop_s, starved_s = {}, {}
     for name, eng in sorted(engines.items()):
         st = eng.stats()
@@ -2334,172 +2194,19 @@ def metrics_snapshot() -> list:
         # multi-replica fleet from collapsing into one ambiguous series
         key = ((("engine", name),)
                + tuple(sorted(eng.labels.items())))
-        active[key] = float(st["active_slots"])
-        waiting[key] = float(st["waiting_requests"])
-        occ[key] = float(st["batch_occupancy"])
-        gen[key] = float(st["generated_tokens"])
-        comp[key] = float(st["requests_completed"])
-        # paged-cache capacity signal: the router/autoscaler read these
-        # through fleet_stats, operators through /metrics
-        butil[key] = float(st.get("block_utilization", 0.0))
-        phit[key] = float(st.get("prefix_hit_rate", 0.0))
-        pcached[key] = float(st.get("prefix_cached_blocks", 0))
-        padopt[key] = float(st.get("prefix_blocks_adopted", 0))
-        phtok[key] = float(st.get("prefix_hit_tokens", 0))
-        preempt[key] = float(st.get("preemptions", 0))
-        # the prefill side of the load: prefill against generated tokens
-        # says which of the two a replica's passes go to, chunk passes
-        # over admissions how many prefill programs a prompt costs
-        admits[key] = float(st["admissions"])
-        chunks[key] = float(st["chunk_passes"])
-        in_step[key] = float(st["chunks_in_step"])
-        ptoks[key] = float(st["prefill_tokens"])
-        kv_att[key] = float(st["kv_blocks_attended"])
-        kv_tab[key] = float(st["kv_blocks_tabled"])
-        ckeys[key] = float(st["chunk_keys"])
-        cqkeys[key] = float(st["chunk_query_keys"])
-        lrows[key] = float(st.get("linear_state_rows_advanced", 0))
-        ltoks[key] = float(st.get("linear_chunk_tokens", 0))
-        on_dev[key] = float(st["tokens_greedy_on_device"])
-        sampled[key] = float(st["tokens_sampled"])
-        fbytes[key] = float(st["fetch_bytes"])
-        # speculation signal, per replica: accept-rate is the drafter's
-        # quality gauge, tokens/step the latency win it buys
-        tps[key] = float(st.get("tokens_per_step", 0.0))
-        arate[key] = float(st.get("spec_accept_rate", 0.0))
-        saccept[key] = float(st.get("spec_accepted_tokens", 0))
-        # serving geometry: 1/1 for unmeshed engines so the series
-        # always exists and a sharded rollout shows up as a step change
-        meshdev[key] = float(st.get("mesh_devices", 1))
-        tpsh[key] = float(st.get("tp_shards", 1))
-        # the recurrent-state pool and the routed experts' load (zeros
-        # for a model with neither)
-        sbytes[key] = float(st.get("state_bytes", 0))
-        srows[key] = float(st.get("state_rows_in_use", 0))
-        eheld[key] = float(st.get("expert_assignments_held", 0))
-        etotal[key] = float(st.get("expert_assignments_total", 0))
-        emax[key] = float(st.get("expert_load_max", 0))
-        etouch[key] = float(st.get("expert_touched_held", 0))
-        etouchd[key] = float(st.get("expert_touched_held_decode", 0))
-        wbytes[key] = float(st["weight_bytes"])
-        wcast[key] = float(st["weight_bytes_cast_per_pass"])
+        for row in rows:
+            series[row.key][key] = float(st[row.key])
         # the loop thread's time by phase: over the phases the first
         # adds up to the thread's wall time
         acct = st["loop_account"]
-        for series, k in ((loop_s, "ns"), (starved_s, "starved_ns")):
+        for by_phase, k in ((loop_s, "ns"), (starved_s, "starved_ns")):
             for phase, ns in (*acct[k].items(), (
                     tracing.UNACCOUNTED, acct["unaccounted_" + k])):
-                series[key + (("phase", phase),)] = ns / 1e9
+                by_phase[key + (("phase", phase),)] = ns / 1e9
     zero = {(("engine", "none"),): 0.0}
     return [
-        ("ray_tpu_inference_active_slots", "gauge",
-         "Cache slots currently decoding, per engine", active or zero),
-        ("ray_tpu_inference_waiting_requests", "gauge",
-         "Requests queued for a free slot, per engine", waiting or zero),
-        ("ray_tpu_inference_batch_occupancy_ratio", "gauge",
-         "Mean active/max_slots per decode iteration", occ or zero),
-        ("ray_tpu_inference_generated_tokens_total", "counter",
-         "Tokens generated since engine start", gen or zero),
-        ("ray_tpu_inference_requests_completed_total", "counter",
-         "Generation requests completed since engine start", comp or zero),
-        ("ray_tpu_inference_block_utilization_ratio", "gauge",
-         "Paged KV pool blocks in use / usable blocks", butil or zero),
-        ("ray_tpu_inference_prefix_hit_rate", "gauge",
-         "Prompt tokens adopted from the radix prefix cache / prompt "
-         "tokens seen", phit or zero),
-        ("ray_tpu_inference_prefix_cached_blocks", "gauge",
-         "Blocks held by the radix prefix index", pcached or zero),
-        ("ray_tpu_inference_prefix_hit_tokens_total", "counter",
-         "Prompt tokens served from blocks adopted from the radix prefix "
-         "index (no prefill program ran them)", phtok or zero),
-        ("ray_tpu_inference_prefix_blocks_adopted_total", "counter",
-         "Blocks taken over from the radix prefix index by admissions and "
-         "re-matches", padopt or zero),
-        ("ray_tpu_inference_preemptions_total", "counter",
-         "Requests requeued by block-pressure preemption", preempt or zero),
-        ("ray_tpu_inference_admissions_total", "counter",
-         "Requests given a cache row (a preempted request counts again)",
-         admits or zero),
-        ("ray_tpu_inference_chunk_passes_total", "counter",
-         "Prefill chunks run, by the chunk program or inside a decode "
-         "step", chunks or zero),
-        ("ray_tpu_inference_chunks_in_step_total", "counter",
-         "Prefill chunks that ran inside a decode step's program (one "
-         "read of the weights for both)", in_step or zero),
-        ("ray_tpu_inference_prefill_tokens_total", "counter",
-         "Prompt tokens run through a prefill program (prefix-cache "
-         "hits excluded)", ptoks or zero),
-        ("ray_tpu_inference_kv_blocks_attended_total", "counter",
-         "KV blocks holding a key of a live row, summed over one-token "
-         "decode passes (read once a pool and layer)", kv_att or zero),
-        ("ray_tpu_inference_kv_blocks_tabled_total", "counter",
-         "Block-table entries of all rows, summed over one-token decode "
-         "passes (what a whole-table gather reads)", kv_tab or zero),
-        ("ray_tpu_inference_chunk_keys_total", "counter",
-         "Keys in reach of prefill chunks' windows (position + tokens, "
-         "summed over chunk passes)", ckeys or zero),
-        ("ray_tpu_inference_chunk_query_keys_total", "counter",
-         "(query, key) pairs under the causal mask, summed over prefill "
-         "chunk passes", cqkeys or zero),
-        ("ray_tpu_inference_linear_state_rows_advanced_total", "counter",
-         "Rows whose linear-attention matrix state a one-token decode "
-         "pass wrote, summed over passes", lrows or zero),
-        ("ray_tpu_inference_linear_chunk_tokens_total", "counter",
-         "Real prompt tokens through the window form of the delta rule, "
-         "summed over prefill chunk passes", ltoks or zero),
-        ("ray_tpu_inference_tokens_greedy_on_device_total", "counter",
-         "Decode and first tokens chosen by a serving program's own "
-         "argmax (a pass fetches the integers, not the logits)",
-         on_dev or zero),
-        ("ray_tpu_inference_tokens_sampled_total", "counter",
-         "Decode and first tokens chosen by a dispatch of their own on "
-         "the logits (temperature > 0, a full-width prefill's first "
-         "token, a speculative pass)", sampled or zero),
-        ("ray_tpu_inference_fetch_bytes_total", "counter",
-         "Bytes the engine's loop fetched from the device",
-         fbytes or zero),
-        ("ray_tpu_inference_tokens_per_step", "gauge",
-         "Tokens emitted per compiled decode/verify call (speculative "
-         "decoding pushes this above 1)", tps or zero),
-        ("ray_tpu_inference_spec_accept_rate", "gauge",
-         "Drafted tokens accepted by the verify pass / drafted tokens "
-         "offered", arate or zero),
-        ("ray_tpu_inference_spec_accepted_tokens_total", "counter",
-         "Drafted tokens accepted since engine start", saccept or zero),
-        ("ray_tpu_inference_mesh_devices", "gauge",
-         "Devices in the engine's mesh (1 = unmeshed single device)",
-         meshdev or zero),
-        ("ray_tpu_inference_tp_shards", "gauge",
-         "Tensor-parallel shards of the paged KV pool's heads dim "
-         "(block counts are per-device AND global — heads are what's "
-         "split)", tpsh or zero),
-        ("ray_tpu_inference_state_bytes", "gauge",
-         "Bytes of the per-row recurrent-state pool (0 = the model "
-         "keeps K/V only)", sbytes or zero),
-        ("ray_tpu_inference_state_rows_in_use", "gauge",
-         "Decode rows holding a recurrent state", srows or zero),
-        ("ray_tpu_inference_expert_assignments_held_total", "counter",
-         "(token, expert) assignments routed to experts held here",
-         eheld or zero),
-        ("ray_tpu_inference_expert_assignments_total", "counter",
-         "(token, expert) assignments routed to any expert",
-         etotal or zero),
-        ("ray_tpu_inference_expert_load_max_total", "counter",
-         "Assignments of the busiest held expert, summed over layers "
-         "and passes", emax or zero),
-        ("ray_tpu_inference_expert_touched_held_total", "counter",
-         "Held experts with at least one assignment, summed over "
-         "expert layers and passes", etouch or zero),
-        ("ray_tpu_inference_expert_touched_held_decode_total", "counter",
-         "Held experts with at least one assignment, summed over "
-         "expert layers and decode steps (no prefill chunk)",
-         etouchd or zero),
-        ("ray_tpu_inference_weight_bytes", "gauge",
-         "Bytes of the parameter tree the programs are handed",
-         wbytes or zero),
-        ("ray_tpu_inference_weight_bytes_cast_per_pass", "gauge",
-         "Bytes of weights a program casts to its compute dtype every "
-         "pass (0 = each is stored in it)", wcast or zero),
+        *((row.metric, row.metric_kind, row.help, series[row.key] or zero)
+          for row in rows),
         ("ray_tpu_inference_loop_seconds_total", "counter",
          "The engine loop thread's wall time by phase (self time; "
          "`wait` is the wait for the device, `parked` an engine with no "

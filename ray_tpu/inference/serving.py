@@ -39,10 +39,25 @@ from ray_tpu.inference.engine import (EngineConfig, EngineDrainingError,
                                       EngineStoppedError, InferenceEngine,
                                       init_params_for, parse_priority)
 from ray_tpu.models.gpt import GPTConfig
+from ray_tpu.serve import engine_stats
 from ray_tpu.serve.deployment import (AutoscalingConfig, Deployment,
                                       DeploymentOptions)
 
 DEFAULT_ROUTE = "v1"
+
+# what a replica reports of its engines to the router's probe
+# (``GPTServer.fleet_stats``), each by its rule in ``engine_stats``.  The
+# blocks are there because the occupancy router and the autoscaler must
+# see BLOCK pressure, not just row counts: a replica whose rows are free
+# but whose pool is nearly full is not spare capacity
+_FLEET_STATS = (
+    "max_slots", "active_slots", "waiting_requests", "waiting_interactive",
+    "blocks_total", "blocks_free", "block_utilization",
+    "mesh_devices", "tp_shards",
+    "prefix_hit_tokens", "prefix_lookup_tokens", "prefix_hit_rate",
+    "spec_drafted_tokens", "spec_accepted_tokens", "spec_accept_rate",
+    "tokens_per_step",
+)
 
 
 def encode_prompt(prompt: Union[str, Sequence[int]],
@@ -223,51 +238,12 @@ class GPTServer:
     def fleet_stats(self) -> dict:
         """The router's probe surface: engine load + loaded variants.
         Multiplexed replicas aggregate over resident engines (total
-        slots grow with residency — the router sees real capacity)."""
+        slots grow with residency — the router sees real capacity),
+        each key by its rule in ``serve/engine_stats.py``."""
         engines = self._engines()
         stats = [e.stats() for e in engines]
-        blocks_total = sum(s.get("blocks_total", 0) for s in stats)
-        blocks_free = sum(s.get("blocks_free", 0) for s in stats)
-        hit = sum(s.get("prefix_hit_tokens", 0) for s in stats)
-        lookup = sum(s.get("prefix_lookup_tokens", 0) for s in stats)
-        drafted = sum(s.get("spec_drafted_tokens", 0) for s in stats)
-        s_accept = sum(s.get("spec_accepted_tokens", 0) for s in stats)
-        row_steps = sum(s.get("row_steps", 0) for s in stats)
-        row_tokens = sum(s.get("row_tokens", 0) for s in stats)
         return {
-            "max_slots": sum(s["max_slots"] for s in stats),
-            "active_slots": sum(s["active_slots"] for s in stats),
-            "waiting_requests": sum(s["waiting_requests"] for s in stats),
-            "waiting_interactive": sum(s["waiting_interactive"]
-                                       for s in stats),
-            # paged-cache capacity signal: the occupancy router and the
-            # autoscaler see BLOCK pressure, not just row counts — a
-            # replica whose rows are free but whose pool is nearly full
-            # is not actually spare capacity
-            # block counts are GLOBAL admission budgets (replicated in
-            # count across tp shards — heads are what's split), so
-            # summing across engines needs no per-shard correction
-            "blocks_total": blocks_total,
-            "blocks_free": blocks_free,
-            "block_utilization": ((blocks_total - blocks_free)
-                                  / blocks_total if blocks_total else 0.0),
-            # serving geometry: devices under this replica's engines
-            # (max, not sum — multiplexed engines share the one mesh)
-            "mesh_devices": max((s.get("mesh_devices", 1)
-                                 for s in stats), default=1),
-            "tp_shards": max((s.get("tp_shards", 1)
-                              for s in stats), default=1),
-            "prefix_hit_tokens": hit,
-            "prefix_lookup_tokens": lookup,
-            "prefix_hit_rate": (hit / lookup) if lookup else 0.0,
-            # speculative decoding: the router and autoscaler see the
-            # replica's accept-rate and per-row decode throughput (1.0
-            # without speculation — same-run baselines stay comparable)
-            "spec_drafted_tokens": drafted,
-            "spec_accepted_tokens": s_accept,
-            "spec_accept_rate": (s_accept / drafted) if drafted else 0.0,
-            "tokens_per_step": (row_tokens / row_steps) if row_steps
-                               else 0.0,
+            **engine_stats.reduce(stats, _FLEET_STATS),
             "models": (self._mux.loaded_models()
                        if self._mux is not None else []),
             "stopped": self._closed or not engines

@@ -157,7 +157,7 @@ def cmd_timeline(args) -> int:
         spans = collect_spans(trace_dir)
     # serve-fleet ingress events: from the armed flight recorder, plus
     # any Fleet.dump_events file (ingress processes that ran without a
-    # recorder — e.g. the trace-replay harness)
+    # recorder)
     ingress = list(fr.get("ingress", []))
     serve_events = getattr(args, "serve_events", None)
     if serve_events:

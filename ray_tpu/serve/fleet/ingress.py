@@ -39,11 +39,25 @@ from typing import Any, Iterator, Optional
 
 from ray_tpu.core import fault_injection as _fi
 from ray_tpu.core import flight_recorder as _fr
+from ray_tpu.serve import engine_stats
 from ray_tpu.serve.fleet.admission import (AdmissionController, ShedError,
                                            parse_priority)
 from ray_tpu.serve.fleet.router import NoReplicaError, OccupancyRouter
 from ray_tpu.serve.qos import (PRIORITY_BATCH, EngineDrainingError,
                                ReplicaDeadError)
+
+# the engines' rows a fleet reports (``Fleet.fleet_snapshot``: the blocks
+# are the REAL memory signal behind the row counts), each reduced over
+# the live replicas' probes by its rule in ``engine_stats``, and the
+# snapshot's own names for three of them
+_SNAPSHOT_ROWS = (
+    "max_slots", "active_slots", "waiting_requests", "blocks_total",
+    "block_utilization", "mesh_devices", "tp_shards", "prefix_hit_rate",
+    "spec_drafted_tokens", "spec_accepted_tokens", "spec_accept_rate",
+)
+_SNAPSHOT_NAMES = {"max_slots": "total_slots",
+                   "waiting_requests": "engine_waiting",
+                   "blocks_total": "total_blocks"}
 
 
 def _is_replica_death(e: BaseException, replica) -> bool:
@@ -208,35 +222,21 @@ class Fleet:
         return total
 
     def fleet_snapshot(self) -> dict:
-        """Point-in-time fleet state (the trace-replay sampler's row)."""
+        """Point-in-time fleet state: the live replicas' probes, each
+        key reduced by its rule in ``serve/engine_stats.py``, and the
+        ingress's own counters."""
         reps = self.router.live_replicas()
-        slots = active = waiting = 0
-        blocks_total = blocks_free = hit_toks = lookup_toks = 0
-        drafted = accepted = 0
-        mesh_devices = tp_shards = 1
+        probed = []
         for r in reps:
             try:
                 st = self.router.probe(r)
             except Exception:
                 continue
             if st and not st.get("stopped"):
-                slots += int(st.get("max_slots", 0))
-                active += int(st.get("active_slots", 0))
-                waiting += int(st.get("waiting_requests", 0))
-                # engine blocks_total is the GLOBAL admission budget
-                # (block counts replicate across tp shards; heads are
-                # what's split) — summing replicas needs no per-shard
-                # correction, and total_blocks never silently reports
-                # per-shard numbers
-                blocks_total += int(st.get("blocks_total", 0))
-                blocks_free += int(st.get("blocks_free", 0))
-                hit_toks += int(st.get("prefix_hit_tokens", 0))
-                lookup_toks += int(st.get("prefix_lookup_tokens", 0))
-                drafted += int(st.get("spec_drafted_tokens", 0))
-                accepted += int(st.get("spec_accepted_tokens", 0))
-                mesh_devices = max(mesh_devices,
-                                   int(st.get("mesh_devices", 1)))
-                tp_shards = max(tp_shards, int(st.get("tp_shards", 1)))
+                probed.append(st)
+        snap = {_SNAPSHOT_NAMES.get(key, key): value for key, value
+                in engine_stats.reduce(probed, _SNAPSHOT_ROWS).items()}
+        slots = snap["total_slots"]
         with self._clock:
             counters = dict(self.counters.__dict__)
         # compatibility aggregate (the split fields are authoritative)
@@ -250,29 +250,9 @@ class Fleet:
             counters.update(self.prefix.counters())
         return {
             "replicas": len(reps),
-            "total_slots": slots,
-            "active_slots": active,
-            "engine_waiting": waiting,
+            **snap,
             "ingress_queued": self.admission.queue_depth(),
-            "occupancy": (active / slots) if slots else 0.0,
-            # paged-cache capacity across the fleet: the REAL memory
-            # signal behind
-            # the row counts, exported at /metrics for the autoscaler's
-            # operators and dashboards
-            "total_blocks": blocks_total,
-            "block_utilization": ((blocks_total - blocks_free)
-                                  / blocks_total if blocks_total else 0.0),
-            # serving geometry (1/1 = unmeshed): max across replicas —
-            # a mixed rollout shows its widest mesh, not a bogus sum
-            "mesh_devices": mesh_devices,
-            "tp_shards": tp_shards,
-            "prefix_hit_rate": (hit_toks / lookup_toks
-                                if lookup_toks else 0.0),
-            # speculative decoding across the fleet (0.0 when no replica
-            # speculates — plain arms report nothing, not a fake zero%)
-            "spec_drafted_tokens": drafted,
-            "spec_accepted_tokens": accepted,
-            "spec_accept_rate": (accepted / drafted) if drafted else 0.0,
+            "occupancy": (snap["active_slots"] / slots) if slots else 0.0,
             **counters,
         }
 

@@ -69,10 +69,10 @@ def detect_tpu_type() -> str:
     return tpu_type_of(jax.devices()[0].device_kind)
 
 
-# Published per-chip peaks: (bf16 FLOP/s, HBM bytes/s).  THE table every
-# MFU / roofline figure in the repo divides by (bench.py,
-# benchmarks/gpt_sweep.py).  Source: Google Cloud documentation,
-# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM).
+# Published per-chip peaks: (bf16 FLOP/s, HBM bytes/s).  Source: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM).  The
+# benchmark divides by its own copy (chipbench/peaks.json); the scripts
+# that read this one went with PR 47 (ROADMAP.md C8).
 CHIP_PEAKS = {
     TPU_V5E: (197e12, 819e9),
 }

@@ -52,31 +52,48 @@ def test_flamegraph_svg_renders():
     assert "inner" in svg and "&lt;" not in "inner"
 
 
-def test_profile_live_worker_end_to_end(rt):
+def test_profile_live_worker_end_to_end(rt, tmp_path):
     from ray_tpu.core.observer import observer_query
     from ray_tpu.core.runtime import get_runtime
 
     @ray_tpu.remote
-    def spin(sec):
+    def spin(started, stop, ceiling_s):
+        """Busy from ``started`` until the file ``stop`` exists: however
+        late the profile starts on a loaded machine, the task outlasts
+        it."""
         import math
+        import os
+        open(started, "w").close()
         t0 = time.time()
         x = 0.0
-        while time.time() - t0 < sec:
-            x += math.sin(x) ** 2
+        while time.time() - t0 < ceiling_s and not os.path.exists(stop):
+            for _ in range(20000):
+                x += math.sin(x) ** 2
         return x
 
-    ref = spin.remote(5.0)
-    time.sleep(1.0)
-    svc = get_runtime().node_service
-    pid = next(c.pid for c in svc.clients.values()
-               if c.kind == "worker" and c.state == "busy")
-    (reply,) = observer_query(
-        svc.address,
-        [{"t": "profile_worker", "pid": pid, "duration": 1.0}],
-        request_timeout=60)
-    folded = reply.get("folded", "")
-    assert any("spin" in ln for ln in folded.splitlines()), folded
-    ray_tpu.get(ref, timeout=60)
+    started, stop = tmp_path / "started", tmp_path / "stop"
+    ref = spin.remote(str(started), str(stop), 150.0)
+    try:
+        # the task is RUNNING once its file is there: a worker that has
+        # only just turned busy may still be connecting, and a profile
+        # request that reaches it then waits in its inbox behind the
+        # task (ROADMAP.md C17)
+        deadline = time.monotonic() + 60
+        while not started.exists():
+            assert time.monotonic() < deadline, "the task never started"
+            time.sleep(0.05)
+        svc = get_runtime().node_service
+        pid = next(c.pid for c in list(svc.clients.values())
+                   if c.kind == "worker" and c.state == "busy")
+        (reply,) = observer_query(
+            svc.address,
+            [{"t": "profile_worker", "pid": pid, "duration": 1.0}],
+            request_timeout=60)
+        folded = reply.get("folded", "")
+        assert any("spin" in ln for ln in folded.splitlines()), folded
+    finally:
+        stop.touch()
+    ray_tpu.get(ref, timeout=30)
 
 
 # -- spill backends ---------------------------------------------------------
