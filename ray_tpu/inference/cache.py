@@ -106,14 +106,22 @@ class PoolLayout:
     value_lanes: Optional[int] = None
 
     @classmethod
-    def of(cls, cfg, pool: jax.Array, shards: int = 1) -> "PoolLayout":
+    def of(cls, cfg, pool: jax.Array, shards: int = 1,
+           window: bool = False) -> "PoolLayout":
         """The layout of ``pool`` as a compiled program sees it, from
         the model's ``kv_geometry``: the layers that keep K/V (not every
         layer of a hybrid model does), the K/V heads (fewer than the
-        query heads under grouped queries) and the head size."""
-        layers, heads, head_dim = cfg.kv_geometry
+        query heads under grouped queries) and the head size.
+        ``window``: ``pool`` belongs to the model's SECOND group of K/V
+        layers, those that attend a window (``window_geometry``)."""
+        if window:
+            layers, heads, head_dim = cfg.window_geometry[:3]
+            value_lanes = None
+        else:
+            layers, heads, head_dim = cfg.kv_geometry
+            value_lanes = cfg.value_lanes
         lay = cls(layers, pool.shape[0] // layers, pool.shape[1], heads,
-                  head_dim, shards, cfg.value_lanes)
+                  head_dim, shards, value_lanes)
         if lay.shape != pool.shape:
             raise ValueError(f"pool {pool.shape} is not a {lay.shape} "
                              f"pool of {layers} layers x "
@@ -271,11 +279,25 @@ class BlockPool:
     and copy-on-write are shard-oblivious and ``n_blocks`` is both the
     global admission budget AND the per-device block count (per-device
     bytes are ``bytes_total() / tp``).
+
+    TWO KINDS OF K/V STATE.  A model some of whose attention layers
+    attend their last ``window`` keys only (``cfg.window_geometry``)
+    gets a second pool for those layers, ``self.window``: a ``BlockPool``
+    of its own — own arrays, own layout (the window layers folded into
+    its leading dim), own free list and refcounts — whose blocks a row
+    holds through a SECOND table and gives back once every query that
+    could read them has passed (the engine's ``_window_cover``), so a
+    row's share of it is bounded by ``window_span`` tokens whatever its
+    context.  The pool of the full layers (this object) grows with the
+    context as before.  ``pools`` hands a program both groups' arrays,
+    the full layers' first; ``swap`` takes them back in that order.
     """
 
     def __init__(self, cfg, n_blocks: int, block_size: int,
                  max_seq: Optional[int] = None, dtype=None, mesh=None,
-                 rules: Rules = DEFAULT_LLM_RULES, state_rows: int = 0):
+                 rules: Rules = DEFAULT_LLM_RULES, state_rows: int = 0,
+                 n_window_blocks: Optional[int] = None,
+                 window_span: Optional[int] = None, _window: bool = False):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.cfg = cfg
@@ -289,14 +311,23 @@ class BlockPool:
                 f"{cfg.max_seq} (wpe table bound)")
         # block-table width: enough blocks to cover one max_seq sequence
         self.blocks_per_seq = -(-self.max_seq // self.block_size)
-        if n_blocks < self.blocks_per_seq:
+        wg = getattr(cfg, "window_geometry", None)
+        # the most blocks ONE row may hold: its whole context, or (the
+        # window layers' pool) a window, a chunk and a block's rounding
+        self.blocks_per_row = self.blocks_per_seq
+        if _window:
+            span = int(window_span or wg[3] + self.block_size)
+            self.blocks_per_row = min(
+                self.blocks_per_seq, -(-span // self.block_size) + 1)
+        if n_blocks < self.blocks_per_row:
             raise ValueError(
                 f"n_blocks {n_blocks} cannot hold one max_seq={self.max_seq} "
-                f"sequence ({self.blocks_per_seq} blocks of {block_size})")
+                f"sequence ({self.blocks_per_row} blocks of {block_size})")
         self.n_blocks = int(n_blocks)             # usable (excludes scratch)
         self.dtype = dtype or cfg.dtype
         shards = self.heads_shards
-        kv_layers, kv_heads, head_dim = cfg.kv_geometry
+        kv_layers, kv_heads, head_dim = wg[:3] if _window \
+            else cfg.kv_geometry
         if kv_heads % shards:
             raise ValueError(
                 f"n_heads {kv_heads} is not divisible by the heads "
@@ -305,14 +336,25 @@ class BlockPool:
                 f"the pool shards the heads dim evenly per device")
         self.layout = PoolLayout(kv_layers, self.n_blocks + 1,
                                  self.block_size, kv_heads, head_dim,
-                                 shards, cfg.value_lanes)
+                                 shards, None if _window else cfg.value_lanes)
         self.k = self._zeros()
         self.v = self._zeros() if self.layout.value_lanes is None else None
         # the second kind of state: what a model's recurrent layers keep
         # per decode row, beside the row's blocks (None for a model whose
         # whole past is K/V)
         self.state = (StatePool(cfg, state_rows)
-                      if cfg.state_geometry is not None else None)
+                      if cfg.state_geometry is not None and not _window
+                      else None)
+        # ... and the second kind of K/V state: the window layers' pool
+        self.window: Optional[BlockPool] = None
+        if wg is not None and not _window:
+            if n_window_blocks is None:
+                raise ValueError("a model with window layers needs "
+                                 "n_window_blocks")
+            self.window = BlockPool(
+                cfg, n_window_blocks, block_size, max_seq=self.max_seq,
+                dtype=dtype, mesh=mesh, rules=rules,
+                window_span=window_span, _window=True)
         self._lock = threading.Lock()
         # pop() -> block 1 first; id 0 (scratch) is never in the list
         self._free = list(range(self.n_blocks, 0, -1))
@@ -407,12 +449,18 @@ class BlockPool:
     @property
     def pools(self) -> tuple:
         """The pool arrays a program is handed: ``(k, v)``, or ``(k,)``
-        where the values are a view of the keys."""
+        where the values are a view of the keys; then the window
+        layers' ``(k, v)`` where the model has such layers."""
+        own = self._own
+        return own if self.window is None else own + self.window.pools
+
+    @property
+    def _own(self) -> tuple:
         return (self.k,) if self.v is None else (self.k, self.v)
 
     def copy_block(self, src: int, dst: int) -> None:
         """Copy-on-write: duplicate src's K/V into dst (every pool)."""
-        self.swap(*_copy_block(self.layout, self.pools, jnp.int32(src),
+        self.swap(*_copy_block(self.layout, self._own, jnp.int32(src),
                                jnp.int32(dst)))
 
     def read_blocks(self, ids) -> tuple:
@@ -471,10 +519,13 @@ class BlockPool:
                 f"{what}: no interchange format for a pool whose values "
                 f"are a view of its keys")
 
-    def swap(self, k: jax.Array, v: Optional[jax.Array] = None) -> None:
+    def swap(self, k: jax.Array, v: Optional[jax.Array] = None,
+             *window) -> None:
         """Install the compiled step's updated pool arrays
         (``*self.pools`` as a program returned them)."""
         self.k, self.v = k, v
+        if window:
+            self.window.swap(*window)
 
     def reset(self) -> None:
         """Reallocate the pool and drop every reference.  Needed after a
@@ -490,6 +541,8 @@ class BlockPool:
         self.v = self._zeros() if self.layout.value_lanes is None else None
         if self.state is not None:
             self.state.reset()
+        if self.window is not None:
+            self.window.reset()
         with self._lock:
             self._free = list(range(self.n_blocks, 0, -1))
             self._rc = [0] * (self.n_blocks + 1)
@@ -501,9 +554,10 @@ class BlockPool:
         """Bytes of the pools as stored, padding lanes included, and of
         the recurrent-state pool where there is one."""
         itemsize = np.dtype(jnp.zeros((), self.dtype).dtype).itemsize
-        return (len(self.pools) * int(np.prod(self.layout.shape))
-                * itemsize
-                + self.state_bytes())
+        return (len(self._own) * int(np.prod(self.layout.shape)) * itemsize
+                + self.state_bytes()
+                + (self.window.bytes_total() if self.window is not None
+                   else 0))
 
     def state_bytes(self) -> int:
         return self.state.bytes_total() if self.state is not None else 0
@@ -534,6 +588,11 @@ class BlockPool:
             "bytes_per_device": self.bytes_total() // shards,
             "tp_shards": shards,
             "generation": self.generation,
+            # the window layers' pool (0 / 0 where the model has none)
+            "window_blocks_total": (self.window.n_blocks
+                                    if self.window is not None else 0),
+            "window_blocks_held": (self.window.n_used
+                                   if self.window is not None else 0),
         }
 
 

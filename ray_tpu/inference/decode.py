@@ -232,7 +232,7 @@ def _key_blocks(lay: PoolLayout, tables, q_pos):
 def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
                  mesh=None, rules=None, kv_lengths=None, mask=None,
                  mask_tables=None, q_per_kv: int = 1, scale=None,
-                 q_pos=None):
+                 q_pos=None, window: int = 0):
     """Where a window meets the pool, for every model family:
     ``attend_for(layer)`` gives that layer's ``attend(q [b, h, w, hd],
     k, v [b, w, h_kv, hd]) -> o [b, h, w, hd]`` over the (K, V)
@@ -263,13 +263,20 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
         holds ``n`` = ``len(kv_lengths)`` one-token rows and then a
         window of ``w`` queries: committed together, the first ``n``
         queries attended as one-token rows of ``tables`` [n, T], the
-        rest under ``mask`` over ``mask_tables`` [1, T], and joined."""
+        rest under ``mask`` over ``mask_tables`` [1, T], and joined.
+
+    ``window`` > 0 (the ``kv_lengths`` and ``q_pos`` forms): the pools
+    are a window layer's, and a query attends its last ``window`` keys
+    only, its own among them.  Both walks then START at the block that
+    holds the first of those keys, so a layer's time and bytes are
+    bounded by the window; the table's entries before it may name
+    anything (the cache gives those blocks back)."""
     held = {"pools": pools}
     if kv_lengths is not None:
         def sp(*axes):
             return spec_for(axes, rules, mesh)
         walk = partial(paged_decode_attention, q_per_kv=q_per_kv,
-                       scale=scale)
+                       scale=scale, window=window)
         if mesh is not None:
             q_spec = sp("batch", "heads", None, None)
             walk = _per_shard(
@@ -287,9 +294,9 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
                                      for p in held["pools"]),
             q_pos, n_kv_heads=lay.n_heads,
             scale=lay.head_dim ** -0.5 if scale is None else scale,
-            **walk)[None]
+            window=window, **walk)[None]
 
-    def window(q, layer, of):
+    def packed(q, layer, of):
         ctx_k, ctx_v = (
             gpt._constrain(lay.read(p, layer, of),
                            ("batch", None, "heads"), mesh, rules)
@@ -309,13 +316,13 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
             if mask is None:
                 return rows(q, layer)
             if kv_lengths is None:
-                return window(q, layer, tables)
+                return packed(q, layer, tables)
             n = kv_lengths.shape[0]
             # [1, h, n, hd] <-> [n, h, 1, hd]: a row's one query
             o = rows(q[:, :, :n].transpose(2, 1, 0, 3), layer)
             return jnp.concatenate(
                 [o.transpose(2, 1, 0, 3),
-                 window(q[:, :, n:], layer, mask_tables)], axis=2)
+                 packed(q[:, :, n:], layer, mask_tables)], axis=2)
         return attend
     return attend_for, held
 

@@ -134,6 +134,10 @@ class EngineConfig:
     kv_block_size: int = 16              # tokens per block
     n_blocks: Optional[int] = None       # usable blocks; None = max_slots
     #                                      * ceil(max_seq/block)
+    # a model with window-attention layers: usable blocks of THEIR pool
+    # (cache.BlockPool.window); None = max_slots * what one row may hold
+    # (a window, a chunk and a block's rounding)
+    n_window_blocks: Optional[int] = None
     prefill_chunk: int = 32              # chunked-prefill window width
     prefix_cache: bool = True            # radix prefix reuse on/off
     # ---- speculative decoding (draft-then-verify).
@@ -650,14 +654,28 @@ class InferenceEngine:
         bs = ec.kv_block_size
         per_seq = -(-int(ec.max_seq or cfg.max_seq) // bs)
         n_blocks = ec.n_blocks if ec.n_blocks is not None else n * per_seq
-        self.pool = BlockPool(cfg, n_blocks, bs, max_seq=ec.max_seq,
-                              mesh=mesh, rules=rules, state_rows=n)
+        # window layers (``cfg.window_geometry``): a row holds, of their
+        # pool, the keys its next query can still see and the chunk it
+        # is writing
+        wg = getattr(cfg, "window_geometry", None)
+        self._window = wg[3] if wg is not None else 0
+        span = self._window + ec.prefill_chunk + bs
+        self.pool = BlockPool(
+            cfg, n_blocks, bs, max_seq=ec.max_seq, mesh=mesh, rules=rules,
+            state_rows=n, window_span=span,
+            n_window_blocks=None if wg is None else (
+                ec.n_window_blocks if ec.n_window_blocks is not None
+                else n * min(per_seq, -(-span // bs) + 1)))
         self.max_seq = self.pool.max_seq
         # a cached prefix is its K/V blocks: with recurrent layers
         # that is no longer the whole of a prefix, so nothing is
-        # adopted (no index) — by derivation, not by an option
+        # adopted (no index) — by derivation, not by an option.  Nor
+        # with window layers: the window pool's blocks of a prefix are
+        # given back as the row moves on, so a chain in the index would
+        # name full-layer blocks whose window-layer half is gone
         self.trie = (RadixIndex(self.pool)
-                     if ec.prefix_cache and not recurrent else None)
+                     if ec.prefix_cache and not recurrent and wg is None
+                     else None)
         self._seam.build(self, bs)
         if self._spec is not None:
             self._verify = make_spec_verify_step(
@@ -679,8 +697,19 @@ class InferenceEngine:
         self._load = []
         self._greedy = None
         self._first_pending = []
-        self._tables = np.zeros((n, self.pool.blocks_per_seq), np.int32)
+        # a row's block table(s) as the programs take them: the full
+        # layers' [n, T], then (a model with window layers) the window
+        # layers' beside it, ONE array [n, 2 T] of which both are views.
+        # A window table is indexed by position like the other; its
+        # entries behind the window are 0 again (the scratch block)
+        T = self.pool.blocks_per_seq
+        self._tables_all = np.zeros((n, T * (2 if self._window else 1)),
+                                    np.int32)
+        self._tables = self._tables_all[:, :T]
+        self._wtables = self._tables_all[:, T:]
         self._row_blocks: dict[int, list[int]] = {}
+        # row -> [index of its first window block held, the ids from there]
+        self._row_wblocks: dict[int, list] = {}
         self._free_rows = list(range(n - 1, -1, -1))
         self._prefilling: dict[int, int] = {}   # row -> next prefill pos
 
@@ -813,9 +842,10 @@ class InferenceEngine:
         that arrive one at a time never give a pass both a chunk and a
         decoding row, so the first overlap would otherwise compile on
         the request path."""
-        n, T = self._tables.shape
+        n, T = self._tables_all.shape
         zeros = np.zeros(n, np.int32)
-        step = pack_step(np.zeros_like(self._tables), zeros, zeros, zeros)
+        step = pack_step(np.zeros_like(self._tables_all), zeros, zeros,
+                         zeros)
         chunk = pack_chunk(np.zeros(T, np.int32),
                            np.zeros(self.engine_cfg.prefill_chunk, np.int32),
                            0, 0, 0)
@@ -961,7 +991,8 @@ class InferenceEngine:
         in the admission pass."""
         return bool(self._free_rows) and (
             self.pool.n_free > 0
-            or (self.trie is not None and self.trie.cached_blocks > 0))
+            or (self.trie is not None and self.trie.cached_blocks > 0)) \
+            and (not self._window or self.pool.window.n_free > 0)
 
     def _drain_pending(self) -> None:
         """Terminal cleanup: fail everything still queued or in-flight."""
@@ -1059,7 +1090,12 @@ class InferenceEngine:
             # pressure: evict unreferenced cached prefixes, LRU-first
             # (the just-matched chain is protected by its new refcount)
             self.trie.evict(need - self.pool.n_free)
-        if self.pool.n_free < need:
+        # the window layers' pool must hold what the prompt's first
+        # chunks will ask of it (taken chunk by chunk: ``_window_cover``)
+        w_need = (min(p_blocks, self.pool.window.blocks_per_row)
+                  if self._window else 0)
+        if self.pool.n_free < need or (
+                w_need and self.pool.window.n_free < w_need):
             for bid in ids:
                 self.pool.decref(bid)
             return False
@@ -1074,7 +1110,10 @@ class InferenceEngine:
         blocks = list(ids)
         for _ in range(need):
             blocks.append(self.pool.alloc())
-        self._tables[row, :] = 0
+        self._counts.kv_blocks_allocated += need
+        self._tables_all[row, :] = 0
+        if self._window:
+            self._row_wblocks[row] = [0, []]
         self._tables[row, :len(blocks)] = blocks
         self._row_blocks[row] = blocks
         self._slot_req[row] = req
@@ -1093,15 +1132,22 @@ class InferenceEngine:
                                               occupied)
         return True
 
-    def _take_block(self, row: int) -> Optional[int]:
+    def _take_block(self, row: int, window: bool = False) -> Optional[int]:
         """A fresh block for ``row``: free list, else LRU prefix
         eviction, else preempt the youngest lowest-priority occupied
         row (``row`` itself last).  Returns None when ``row`` was the
-        preemption victim — the caller must stop touching it."""
+        preemption victim — the caller must stop touching it.
+        ``window``: of the window layers' pool (a preempted row gives
+        back its blocks of both)."""
+        pool = self.pool.window if window else self.pool
         while True:
             self._chaos("infer_block_alloc", row=row)
-            bid = self.pool.alloc()
+            bid = pool.alloc()
             if bid is not None:
+                if window:
+                    self._counts.window_blocks_allocated += 1
+                else:
+                    self._counts.kv_blocks_allocated += 1
                 return bid
             if self.trie is not None and self.trie.evict(1):
                 continue
@@ -1163,10 +1209,38 @@ class InferenceEngine:
         self._seam.row_released(self, row)
         for bid in self._row_blocks.pop(row, []):
             self.pool.decref(bid)
-        self._tables[row, :] = 0
+        for bid in self._row_wblocks.pop(row, (0, ()))[1]:
+            self.pool.window.decref(bid)
+        self._tables_all[row, :] = 0
         with self._cond:
             self._free_rows.append(row)
             self._cond.notify_all()
+
+    def _window_cover(self, row: int, start: int, end: int) -> bool:
+        """Before ``row`` writes positions ``start .. end`` (a chunk, or
+        one token): give back the window layers' blocks behind the
+        window of the query at ``start`` — the earliest query still to
+        come sees keys ``start - window + 1`` on, and no later one sees
+        further back — then take the blocks the write needs.  A block
+        goes back exactly once: it leaves the row's list as it is
+        released.  False = ``row`` was preempted hunting for a block."""
+        bs = self.pool.block_size
+        held = self._row_wblocks[row]
+        live = max(0, start - self._window + 1) // bs
+        while held[1] and held[0] < live:
+            self.pool.window.decref(held[1].pop(0))
+            self._wtables[row, held[0]] = 0
+            held[0] += 1
+            self._counts.window_blocks_returned += 1
+        if not held[1]:
+            held[0] = max(held[0], live)
+        for bidx in range(held[0] + len(held[1]), (end - 1) // bs + 1):
+            nb = self._take_block(row, window=True)
+            if nb is None:
+                return False
+            held[1].append(nb)
+            self._wtables[row, bidx] = nb
+        return True
 
     def _cow_block(self, row: int, bidx: int) -> bool:
         """Copy-on-write: make table entry ``bidx`` exclusively owned
@@ -1312,6 +1386,8 @@ class InferenceEngine:
             if not self._cow_block(row, bidx):
                 return None                # row preempted under pressure
         n_q = min(C, n - pos)
+        if self._window and not self._window_cover(row, pos, pos + n_q):
+            return None
         if sp:
             sp.set(row=row, tokens=n_q, full_width=False,
                    state_rows=self.pool.state_rows_in_use)
@@ -1323,10 +1399,18 @@ class InferenceEngine:
         counts.chunk_query_keys += n_q * pos + n_q * (n_q + 1) // 2
         if self._linear:
             counts.linear_chunk_tokens += n_q
+        if self._window:
+            # the same two of a window layer: keys in its queries' reach
+            # and (query, key) pairs inside the window
+            counts.window_chunk_keys += pos + n_q - max(
+                0, pos - self._window + 1)
+            counts.window_query_keys += int(np.minimum(
+                np.arange(pos, pos + n_q) + 1, self._window).sum())
         chunk_toks = np.zeros(C, np.int32)
         chunk_toks[:n_q] = prompt[pos:pos + n_q]
         with self._acct.phase("pack") as up:
-            packed = pack_chunk(self._tables[row], chunk_toks, pos, row, n_q)
+            packed = pack_chunk(self._tables_all[row], chunk_toks, pos, row,
+                                n_q)
             up.set(bytes=packed.nbytes)
         # (the copy-on-write above may have preempted the last active row)
         if may_ride and self._active.any():
@@ -1499,6 +1583,8 @@ class InferenceEngine:
         pos = int(self._positions[row])
         bidx = pos // self.pool.block_size
         blocks = self._row_blocks[row]
+        if self._window and not self._window_cover(row, pos, pos + 1):
+            return False
         if bidx < len(blocks):
             return self._cow_block(row, bidx)
         nb = self._take_block(row)
@@ -1802,7 +1888,7 @@ class InferenceEngine:
                        chunk_tokens=ride[1] if ride else 0)
             program = self._step
             with self._acct.phase("pack") as up:
-                packed = pack_step(self._tables, self._tokens,
+                packed = pack_step(self._tables_all, self._tokens,
                                    self._positions, self._active)
                 if ride:
                     program = self._step_chunk
@@ -1832,6 +1918,18 @@ class InferenceEngine:
                     (self._positions[self._active]
                      // self.engine_cfg.kv_block_size + 1).sum())
                 counts.kv_blocks_tabled += self._tables.size
+                if self._window:
+                    # the blocks that hold a key inside a live row's
+                    # window; what the rows hold of the window layers'
+                    # pool, beside what ONE table a row would hold
+                    kv = self._positions[self._active] + 1
+                    bs = self.engine_cfg.kv_block_size
+                    counts.window_blocks_attended += int(
+                        (-(-kv // bs)
+                         - np.maximum(kv - self._window, 0) // bs).sum())
+                    counts.window_blocks_resident_sum += \
+                        self.pool.window.n_used
+                    counts.window_blocks_one_table_sum += self.pool.n_used
                 if self._linear:
                     counts.linear_state_rows_advanced += int(
                         self._active.sum())
@@ -1908,7 +2006,8 @@ class InferenceEngine:
         self._load.clear()
         self._acct.in_flight = 0        # what was launched has failed
         self._row_blocks.clear()
-        self._tables[:, :] = 0
+        self._row_wblocks.clear()
+        self._tables_all[:, :] = 0
         if self.trie is not None:
             self.trie.clear()
         self.pool.reset()
@@ -2163,6 +2262,8 @@ class InferenceEngine:
             tp_shards=self.pool.heads_shards,
             state_bytes=pool["state_bytes"],
             state_rows_in_use=pool["state_rows_in_use"],
+            window_blocks_total=pool["window_blocks_total"],
+            window_blocks_held=pool["window_blocks_held"],
             weight_bytes=self._weight_bytes,
             weight_bytes_cast_per_pass=self._weight_bytes_cast)
         out.update(engine_stats.ratios(out))

@@ -92,20 +92,25 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
         -> (logits [b, vocab] f32, load + greedy [4 + b] int32,
             pools, state)
 
+    A model with window layers has two tables a row, side by side in
+    ``packed`` [b, 2 T + 3]: the full layers' and the window layers'
+    (``_two_groups``).
+
     Decode row r's state is row r of the state arrays.  An inactive row
     (free, or still prefilling) is a window of 0 real tokens: its K/V
     write goes to the scratch block and its state comes back unchanged.
     """
-    bs, T = int(block_size), int(n_table)
+    bs, T = int(block_size), int(n_table) * (2 if cfg.n_window else 1)
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
         def step(params, pools, state, packed):
             tables, tokens, positions, active = unpack_step(packed, T)
-            lay = PoolLayout.of(cfg, pools[0])
-            bidx, off, kv_len = _step_indices(tables, positions, active, bs)
-            attend_for, kv = _attend_over(
-                cfg, lay, pools, bidx, off, tables, kv_lengths=kv_len)
+            _, off, kv_len = _step_indices(tables, positions, active, bs)
+            attend_for, window_for, pools_out = _two_groups(
+                cfg, pools, tables,
+                lambda t: _step_indices(t, positions, active, bs)[0], off,
+                kv_lengths=kv_len)
             conv, ssm = state or (None, None)
             held = {"conv": [], "ssm": ssm}
 
@@ -126,11 +131,11 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
                 active.astype(jnp.int32),
                 state_in=lambda mi: (conv[mi], (held["ssm"], mi)),
                 state_out=state_out, attend_for=attend_for,
-                positions=positions[:, None])
+                window_for=window_for, positions=positions[:, None])
             logits = hybrid.head(cfg, params, x[:, 0])
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             state = (jnp.stack(held["conv"]), held["ssm"]) if state else ()
-            return (logits, jnp.concatenate([load, greedy]), kv["pools"],
+            return (logits, jnp.concatenate([load, greedy]), pools_out(),
                     state)
 
         return step
@@ -139,20 +144,25 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
 
 
 def _attend_over(cfg, lay, pools, blocks, offsets, tables, *,
-                 kv_lengths=None, q_pos=None, q_table=None):
+                 kv_lengths=None, q_pos=None, q_table=None, window: int = 0):
     """``paged_attend`` or ``latent_attend``, as the model's attention
     layers keep K/V heads or one latent: ONE token a row that attends
     its first ``kv_lengths`` keys, or one row's window of queries at
     positions ``q_pos`` [w], each over the keys up to its own — or, for
     K/V heads attended packed, both in ONE window (``paged_attend``'s
     fourth form): the one-token rows of ``tables`` and then the window
-    of the row whose table is ``q_table`` [1, T]."""
+    of the row whose table is ``q_table`` [1, T].  ``window``: the
+    pools and tables are the window layers', attended within it.  A
+    model with window layers attends a row's window of queries head by
+    head whatever its head count: the packed form multiplies every head
+    over the full stored width of the row's WHOLE table, and such a
+    model is served at contexts where that is most of a chunk's time."""
     if cfg.value_lanes is not None:
         return latent_attend(lay, pools, blocks, offsets, tables,
                              scale=cfg.attention_multiplier,
                              kv_lengths=kv_lengths, q_pos=q_pos)
     mask = None
-    if q_pos is not None and not window_by_head(lay):
+    if q_pos is not None and not (window_by_head(lay) or cfg.n_window):
         S = tables.shape[-1] * lay.block_size
         mask = (jnp.arange(S)[None, :] <= q_pos[:, None])[None, None]
         q_pos = None
@@ -160,7 +170,31 @@ def _attend_over(cfg, lay, pools, blocks, offsets, tables, *,
                         q_per_kv=cfg.n_heads // cfg.n_kv_heads,
                         scale=cfg.attention_multiplier,
                         kv_lengths=kv_lengths, mask=mask, q_pos=q_pos,
-                        mask_tables=q_table)
+                        mask_tables=q_table, window=window)
+
+
+def _two_groups(cfg, pools, tables, blocks_of, offsets, **how):
+    """``_attend_over`` for each of the model's groups of K/V layers:
+    the full layers' over ``pools[:2]`` through the first half of
+    ``tables`` [.., 2 T], the window layers' over ``pools[2:]`` through
+    the second.  ``blocks_of(table)``: where the pass's tokens are
+    written, by that group's table.
+    -> (attend_for, window_for, ``pools()``: what the layers left, in
+        ``pools``' order)."""
+    if not cfg.n_window:
+        attend_for, kv = _attend_over(
+            cfg, PoolLayout.of(cfg, pools[0]), pools, blocks_of(tables),
+            offsets, tables, **how)
+        return attend_for, None, lambda: kv["pools"]
+    T = tables.shape[-1] // 2
+    full, within = tables[..., :T], tables[..., T:]
+    attend_for, kv = _attend_over(
+        cfg, PoolLayout.of(cfg, pools[0]), pools[:2], blocks_of(full),
+        offsets, full, **how)
+    window_for, wkv = _attend_over(
+        cfg, PoolLayout.of(cfg, pools[2], window=True), pools[2:],
+        blocks_of(within), offsets, within, window=cfg.window, **how)
+    return attend_for, window_for, lambda: kv["pools"] + wkv["pools"]
 
 
 def _chunk_window(table, start, C: int, bs: int):
@@ -193,6 +227,9 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
         -> (logits [C, vocab] f32, load + greedy [5] int32,
             pools, state)
 
+    (Two tables, [2 T + C + 3], of a model with window layers: as the
+    decode step.)
+
     Prompt positions ``start .. start + n_valid`` of decode row ``row``:
     attention as decode.py's chunk program (each query masked to its own
     causal horizon over the gathered table; a latent layer walks the
@@ -201,17 +238,18 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
     ``n_valid`` real tokens only — the padding of a partial last chunk
     is the identity on it.
     """
-    bs, C, T = int(block_size), int(chunk), int(n_table)
+    bs, C = int(block_size), int(chunk)
+    T = int(n_table) * (2 if cfg.n_window else 1)
 
     def build():
         @partial(jax.jit, donate_argnums=(1, 2))
         def chunk_fn(params, pools, state, packed):
             table, tokens, start, row, n_valid = unpack_chunk(packed, T, C)
-            lay = PoolLayout.of(cfg, pools[0])
-            pos, bidx, off = _chunk_window(table, start, C, bs)
-            attend_for, kv = _attend_over(
-                cfg, lay, pools, bidx[None], off[None], table[None],
-                q_pos=pos)
+            pos, _, off = _chunk_window(table[:int(n_table)], start, C, bs)
+            attend_for, window_for, pools_out = _two_groups(
+                cfg, pools, table[None],
+                lambda t: _chunk_window(t[0], start, C, bs)[1][None],
+                off[None], q_pos=pos)
             held = dict(zip(("conv", "ssm"), state))
 
             def state_in(mi):
@@ -226,11 +264,12 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
             x, load = hybrid.run_layers(
                 cfg, params, hybrid.embed(cfg, params, tokens[None]),
                 n_valid[None], state_in=state_in, state_out=state_out,
-                attend_for=attend_for, positions=pos[None])
+                attend_for=attend_for, window_for=window_for,
+                positions=pos[None])
             logits = hybrid.head(cfg, params, x[0])             # [C, V]
             greedy = jnp.argmax(logits[jnp.maximum(n_valid, 1) - 1]
                                 ).astype(jnp.int32)
-            return (logits, jnp.append(load, greedy), kv["pools"],
+            return (logits, jnp.append(load, greedy), pools_out(),
                     tuple(held[k] for k in ("conv", "ssm")[:len(state)]))
 
         return chunk_fn

@@ -1,20 +1,32 @@
 """Hybrid language model: Mamba-2 mixers, gated-delta-rule linear
 attention mixers, attention mixers, latent attention mixers, routed
 experts and dense gated MLPs in a layer pattern.  ONE layer function
-serves the four layouts public configs of the kind have:
+serves the five layouts public configs of the kind have:
 
     x0 = wte[ids] * embedding_multiplier            (no position embedding)
     x  = x + residual_multiplier * mixer(RMSNorm(x) * w)     per sublayer
     x  = x + RMSNorm(mixer(x)) * w              ... with ``norm_output``
+    x  = x + RMSNorm(mixer(RMSNorm(x) * w)) * w'  ... with ``sandwich_norm``
     logits = RMSNorm(x) @ W_head / logits_scaling   (W_head = wte^T if tied)
 
-``mixer`` is one of six kinds:
+``mixer`` is one of seven kinds:
 
   * attention: grouped queries (``n_heads`` query heads over
     ``n_kv_heads`` K/V heads), no bias, no rotary; softmax(q k^T *
     attention_multiplier, causal) v; output projection.  With
     ``qk_norm`` an RMSNorm over the WHOLE query projection and one over
-    the whole key projection, before the heads are split.
+    the whole key projection, before the heads are split; with
+    ``qk_norm`` "head" one over each head's lanes (one weight
+    ``[head_dim]`` for q, one for k); with ``attn_gate`` the output is
+    ``o_proj(attention * sigmoid(x W_gate))``, W_gate's columns held
+    behind q, k and v in ``wqkv``.
+  * window attention (``afmoe``'s ``sliding_attention`` layers): the
+    same sublayer whose query t sees keys ``t - window < j <= t`` only,
+    and whose q and k are turned by rotary positions (``rope_theta``,
+    all ``head_dim`` lanes, half-split pairs, no scaling) where the
+    full-attention layers beside it use none.  Its K/V is a SECOND
+    group of layers to a cache (``window_geometry``): what lies behind
+    the window is never read again, and given back.
   * linear attention (ops/delta_rule.py, ``olmo_hybrid``): ``[q | k |
     v] = silu(causal_conv(x [W_q | W_k | W_v]))`` (no bias), per head
     ``q / |q| / sqrt(K)`` and ``k / |k|``; ``beta = 2 sigmoid(x w_b)``,
@@ -38,7 +50,11 @@ serves the four layouts public configs of the kind have:
     scores the softmax over ALL router outputs in float32, the choice
     limited to the ``topk_group`` groups with the largest maximum score,
     gates the chosen scores times ``routed_scale``, normalised only if
-    ``norm_topk``.  ``shared_width`` is the width of ONE ungated MLP,
+    ``norm_topk``.  The router's form follows the experts' unless
+    ``sigmoid_router`` says otherwise (``afmoe``: sigmoid scores and a
+    selection bias over GATED experts and a gated shared expert,
+    ``route_eps`` added to the chosen scores' sum).
+    ``shared_width`` is the width of ONE ungated MLP,
     however many shared experts the config counts (they are published
     as one MLP of their summed width).
   * latent attention (``q_rank`` / ``kv_rank``): queries through a
@@ -56,8 +72,8 @@ serves the four layouts public configs of the kind have:
 
 A published layer is one such sublayer (``nemotron_h``: the pattern
 string's ``M`` / ``*`` / ``E``), or, with ``experts_in_every_layer``
-(``granitemoehybrid``, ``deepseek_v2``, ``olmo_hybrid``), a Mamba,
-linear, attention or latent sublayer FOLLOWED by a feed-forward sublayer
+(``granitemoehybrid``, ``deepseek_v2``, ``olmo_hybrid``, ``afmoe``), a
+Mamba, linear, attention, window or latent sublayer FOLLOWED by a feed-forward sublayer
 with its own norm and residual — experts, or the dense MLP in the first
 ``dense_layers`` layers (``olmo_hybrid``: all of them): the same
 function twice.
@@ -99,6 +115,7 @@ from ray_tpu.ops.routed_experts import lanes, mlp, routed_experts
 
 MAMBA, ATTENTION, EXPERTS = "mamba", "attention", "experts"
 LATENT, DENSE, LINEAR = "latent", "dense", "linear_attention"
+WINDOW = "window_attention"     # attention over the last ``window`` keys
 N_LOAD = 4      # numbers in ``run_layers``' load vector (its text names them)
 # the sublayer kinds that take a window in two parts (``block``'s ``rows``)
 TWO_PART = frozenset({MAMBA, ATTENTION, EXPERTS, DENSE})
@@ -193,8 +210,17 @@ class HybridConfig:
     dense_layers: int = 0            # leading layers: dense MLP, no experts
     dense_width: int = 0
     tied_head: bool = True
-    qk_norm: bool = False            # attention: RMSNorm of whole q and k
+    # attention: RMSNorm of whole q and k (True), or of each head's
+    # lanes with ONE weight [head_dim] ("head")
+    qk_norm: Any = False
     norm_output: bool = False        # a sublayer's norm on its OUTPUT
+    # the ``afmoe`` layout's forms
+    sandwich_norm: bool = False      # ... a norm before AND after
+    attn_gate: bool = False          # o_proj(attn * sigmoid(x W_gate))
+    window: int = 0                  # keys a WINDOW layer attends
+    rope_theta: float = 0.0          # rotary q, k of the WINDOW layers
+    sigmoid_router: Any = None       # None: the relu^2 experts' (above)
+    route_eps: float = 0.0           # added to the chosen scores' sum
     # the first family's four multipliers
     embedding_multiplier: float = 12.0
     attention_multiplier: float = 1.0 / 128
@@ -207,7 +233,7 @@ class HybridConfig:
 
     def __post_init__(self):
         bad = set(self.layer_types) - {MAMBA, ATTENTION, EXPERTS, LATENT,
-                                       LINEAR}
+                                       LINEAR, WINDOW}
         if bad:
             raise ValueError(f"unknown layer types {sorted(bad)}")
         if self.experts_in_every_layer and EXPERTS in self.layer_types:
@@ -230,6 +256,11 @@ class HybridConfig:
         if MAMBA in self.layer_types and LINEAR in self.layer_types:
             raise ValueError("Mamba and linear attention layers keep "
                              "different states: one pool holds one kind")
+        if (WINDOW in self.layer_types) != (self.window > 0):
+            raise ValueError("window layers and a window > 0 go together")
+        if self.n_window and (self.n_latent or self.state_geometry):
+            raise ValueError("window layers beside latent or recurrent "
+                             "layers: no cache holds the three kinds")
         if self.route_groups and self.n_experts % self.route_groups[0]:
             raise ValueError(f"{self.n_experts} experts in "
                              f"{self.route_groups[0]} groups")
@@ -239,7 +270,9 @@ class HybridConfig:
         """From a public ``config.json``'s own keys: ``nemotron_h``'s
         where it has a ``hybrid_override_pattern``, ``deepseek_v2``'s
         where it has a ``kv_lora_rank``, ``olmo_hybrid``'s where it has
-        a ``linear_key_head_dim``, else ``granitemoehybrid``'s."""
+        a ``linear_key_head_dim``, ``afmoe``'s where it has a
+        ``global_attn_every_n_layers`` or a ``sliding_attention`` layer,
+        else ``granitemoehybrid``'s."""
         c = config
         if "hybrid_override_pattern" in c:
             return cls(**{**_nemotron_h_keys(c), **overrides})
@@ -247,6 +280,9 @@ class HybridConfig:
             return cls(**{**_latent_keys(c), **overrides})
         if "linear_key_head_dim" in c:
             return cls(**{**_olmo_hybrid_keys(c), **overrides})
+        if ("global_attn_every_n_layers" in c
+                or "sliding_attention" in c.get("layer_types", ())):
+            return cls(**{**_afmoe_keys(c), **overrides})
         kw = dict(
             vocab_size=c["vocab_size"], d_model=c["hidden_size"],
             layer_types=tuple(c["layer_types"][:c["num_hidden_layers"]]),
@@ -303,6 +339,17 @@ class HybridConfig:
         return self.layer_types.count(LATENT)
 
     @property
+    def n_window(self) -> int:
+        return self.layer_types.count(WINDOW)
+
+    @property
+    def routes_by_sigmoid(self) -> bool:
+        """Sigmoid scores chosen by ``score + bias`` (a held
+        ``router_bias``); else a softmax form."""
+        return (not self.gated_experts if self.sigmoid_router is None
+                else bool(self.sigmoid_router))
+
+    @property
     def sublayers(self) -> tuple:
         """(index into ``params["layers"]``, kind) of every residual
         sublayer, in the order they run."""
@@ -340,6 +387,18 @@ class HybridConfig:
         if self.n_latent:
             return (self.n_latent, 1, self.kv_rank + self.rope_dim)
         return (self.n_attention, self.n_kv_heads, self.head_dim)
+
+    @property
+    def window_geometry(self):
+        """(window layers, K/V heads, head size, window) of the SECOND
+        group of K/V layers: the layers that attend their last
+        ``window`` keys only, whose blocks a cache gives back once every
+        query that could read them has passed (their own pools, table
+        and allocator: inference/cache.py).  None: every K/V layer
+        keeps its whole context (``kv_geometry``'s one group)."""
+        if not self.n_window:
+            return None
+        return (self.n_window, self.n_kv_heads, self.head_dim, self.window)
 
     @property
     def value_lanes(self):
@@ -503,6 +562,53 @@ def _olmo_hybrid_keys(c: dict) -> dict:
         rms_eps=c["rms_norm_eps"], max_seq=c["max_position_embeddings"])
 
 
+def _afmoe_keys(c: dict) -> dict:
+    """``HybridConfig`` fields from ``afmoe`` keys.  What the layer
+    function has no form for is refused here, by name."""
+    kinds = {"sliding_attention": WINDOW, "full_attention": ATTENTION}
+    types = c["layer_types"][:c["num_hidden_layers"]]
+    _refuse_unless(
+        ("rope_scaling", c.get("rope_scaling"), (None,)),
+        ("score_func", c.get("score_func", "sigmoid"), ("sigmoid",)),
+        ("route_norm", c.get("route_norm", True), (True,)),
+        ("hidden_act", c.get("hidden_act", "silu"), ("silu",)),
+        ("n_group", c.get("n_group", 1), (1,)),
+        ("topk_group", c.get("topk_group", 1), (1,)),
+        ("num_expert_groups", c.get("num_expert_groups", 1), (1,)),
+        ("num_limited_groups", c.get("num_limited_groups", 1), (1,)),
+        ("tie_word_embeddings", c.get("tie_word_embeddings", False),
+         (False,)),
+        ("attention_bias", c.get("attention_bias", False), (False,)),
+        *(("layer_types", t, tuple(kinds)) for t in types))
+    if c["num_shared_experts"] < 1:
+        raise ValueError("num_shared_experts = 0 is not implemented")
+    if "sliding_attention" not in types:
+        raise ValueError("an afmoe model without a sliding_attention "
+                         "layer is not implemented")
+    return dict(
+        vocab_size=c["vocab_size"], d_model=c["hidden_size"],
+        layer_types=tuple(kinds[t] for t in types),
+        n_heads=c["num_attention_heads"],
+        n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        window=c["sliding_window"], rope_theta=float(c["rope_theta"]),
+        qk_norm="head", attn_gate=True, sandwich_norm=True,
+        n_experts=c["num_experts"],
+        experts_per_token=c["num_experts_per_tok"],
+        expert_width=c["moe_intermediate_size"],
+        shared_width=c["num_shared_experts"] * c["moe_intermediate_size"],
+        experts_held=(0, c["num_experts"]),
+        experts_in_every_layer=True, gated_experts=True,
+        sigmoid_router=True, route_eps=1e-20,
+        routed_scale=c["route_scale"],
+        dense_layers=min(c["num_dense_layers"], len(types)),
+        dense_width=c["intermediate_size"], tied_head=False,
+        embedding_multiplier=(math.sqrt(c["hidden_size"])
+                              if c.get("mup_enabled") else 1.0),
+        attention_multiplier=c["head_dim"] ** -0.5,
+        residual_multiplier=1.0, logits_scaling=1.0,
+        rms_eps=c["rms_norm_eps"], max_seq=c["max_position_embeddings"])
+
+
 # -- params ----------------------------------------------------------------
 
 def init_params(cfg: HybridConfig, rng: jax.Array):
@@ -566,13 +672,19 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
                 "gnorm": jnp.ones((Vl,), pd),
                 "wo": norm((Hl * Vl, d)),
             }
-        if kind == ATTENTION:
+        if kind in (ATTENTION, WINDOW):
+            # (with an output gate its projection rides the same product:
+            # [q | k | v | gate], one read of the window a layer)
             ap = {
                 "norm": jnp.ones((d,), pd),
-                "wqkv": norm((d, hq + 2 * hkv)),
+                "wqkv": norm((d, hq + 2 * hkv
+                              + (hq if cfg.attn_gate else 0))),
                 "wo": norm((hq, d)),
             }
-            if cfg.qk_norm:
+            if cfg.qk_norm == "head":
+                ap["q_norm"] = jnp.ones((cfg.head_dim,), pd)
+                ap["k_norm"] = jnp.ones((cfg.head_dim,), pd)
+            elif cfg.qk_norm:
                 ap["q_norm"] = jnp.ones((hq,), pd)
                 ap["k_norm"] = jnp.ones((hkv,), pd)
             return ap
@@ -607,7 +719,7 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
                 [(0, 0), (0, 0), (0, pad)]),
             "w_out": norm((cfg.n_held, cfg.expert_width, d)),
         }
-        if not cfg.gated_experts:
+        if cfg.routes_by_sigmoid:
             ffn["router_bias"] = jax.random.normal(
                 next(k), (cfg.n_experts,)) * 0.02
         return ffn
@@ -617,6 +729,8 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
     layers = [{} for _ in cfg.layer_types]
     for i, kind in cfg.sublayers:
         layers[i][slot_of(kind)] = sublayer(kind, streams[i])
+        if cfg.sandwich_norm:
+            layers[i][slot_of(kind)]["post_norm"] = jnp.ones((d,), pd)
     params = {
         "wte": (jax.random.normal(keys[0], (cfg.vocab_size, d))
                 * (0.02 / cfg.embedding_multiplier)).astype(pd),
@@ -792,29 +906,57 @@ def _linear_mixer(cfg, lp, h, state, n_valid):
     return out, (conv_state, (pool, layer))
 
 
-def _attention_mixer(cfg, ap, h, attend):
+def _attention_mixer(cfg, ap, h, attend, tables=None,
+                     scope: str = "mixer_attention"):
     """h [b, w, d]; ``attend(q [b, h, w, hd], k, v [b, w, hkv, hd]) ->
-    o [b, h, w, hd]`` supplies the keys of the past."""
+    o [b, h, w, hd]`` supplies the keys of the past (and, of a window
+    layer, forgets those behind the window); ``tables``: the rotary
+    tables at the window's positions where the layer turns q and k (all
+    ``head_dim`` lanes, half-split pairs) — the turned key is what
+    ``attend`` is handed, and what a cache keeps."""
     b, w, _ = h.shape
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    with jax.named_scope("mixer_attention"):
+    with jax.named_scope(scope):
         qkv = jnp.dot(h, ap["wqkv"].astype(h.dtype))
-        q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], axis=-1)
-        if cfg.qk_norm:
+        q, k, v, *gate = jnp.split(
+            qkv, [nh * hd, (nh + nkv) * hd, (nh + 2 * nkv) * hd][
+                :3 if cfg.attn_gate else 2], axis=-1)
+        if cfg.qk_norm == "head":
+            q, k, v, *gate = jax.lax.optimization_barrier((q, k, v, *gate))
+            q = _rms_norm(q.reshape(b, w, nh, hd), ap["q_norm"],
+                          cfg.rms_eps)
+            k = _rms_norm(k.reshape(b, w, nkv, hd), ap["k_norm"],
+                          cfg.rms_eps)
+        elif cfg.qk_norm:
             # (one materialisation: see ``_linear_mixer``)
             q, k, v = jax.lax.optimization_barrier((q, k, v))
             q = _rms_norm(q, ap["q_norm"], cfg.rms_eps)
             k = _rms_norm(k, ap["k_norm"], cfg.rms_eps)
-        o = attend(q.reshape(b, w, nh, hd).transpose(0, 2, 1, 3),
-                   k.reshape(b, w, nkv, hd), v.reshape(b, w, nkv, hd))
+        q, k = q.reshape(b, w, nh, hd), k.reshape(b, w, nkv, hd)
+        if tables is not None:
+            with jax.named_scope("rotary"):
+                q, k = rotate(q, tables), rotate(k, tables)
+        o = attend(q.transpose(0, 2, 1, 3), k, v.reshape(b, w, nkv, hd))
         o = o.transpose(0, 2, 1, 3).reshape(b, w, nh * hd)
+        if cfg.attn_gate:
+            with jax.named_scope("attention_gate"):
+                o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate[0].astype(jnp.float32))).astype(h.dtype)
         return jnp.dot(o, ap["wo"].astype(h.dtype))
 
 
 def rotary_tables(cfg: HybridConfig, positions):
     """positions [...] int -> (cos, sin) [..., rope_dim / 2] float32 at
-    the YaRN-scaled frequencies; None for a model without rotary
-    positions."""
+    the YaRN-scaled frequencies — or [..., head_dim / 2] at the plain
+    ones of ``rope_theta`` (no scaling), which turn the window layers'
+    whole heads; None for a model without rotary positions."""
+    if cfg.rope_theta and cfg.yarn is None:
+        with jax.named_scope("rotary"):
+            inv = cfg.rope_theta ** (
+                -jnp.arange(0, cfg.head_dim, 2, dtype=jnp.float32)
+                / cfg.head_dim)
+            ang = positions.astype(jnp.float32)[..., None] * inv
+            return jnp.cos(ang), jnp.sin(ang)
     if cfg.yarn is None:
         return None
     with jax.named_scope("rotary"):
@@ -893,7 +1035,8 @@ def _experts(cfg, fp, h, valid):
             valid=valid.reshape(*valid.shape[:-2], b * w),
             gated=cfg.gated_experts,
             bias=fp.get("router_bias"), scale=cfg.routed_scale,
-            groups=cfg.route_groups, normalise=cfg.norm_topk)
+            groups=cfg.route_groups, normalise=cfg.norm_topk,
+            eps=cfg.route_eps)
     with jax.named_scope("shared_expert"):
         shared = mlp(flat, fp["shared_in"], fp["shared_out"],
                      cfg.gated_experts)
@@ -909,8 +1052,8 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
     real tokens a row; ``lp`` its parameters.  ``past`` is the row's
     state for a Mamba sublayer (returned updated), the ``attend``
     function for an attention sublayer and (``attend``, rotary tables)
-    for a latent one (returned as they came) and unused by experts and
-    the dense MLP.
+    for a latent or a window-attention one (returned as they came) and
+    unused by experts and the dense MLP.
     -> (x, past, (counts [E_held], total) of an experts sublayer, else
         None).
 
@@ -934,7 +1077,12 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
     elif kind == LINEAR:
         mix, past = _linear_mixer(cfg, lp, h, past, n_valid)
     elif kind == ATTENTION:
-        mix = _attention_mixer(cfg, lp, h, past)
+        mix = _attention_mixer(cfg, lp, h, past,
+                               scope="mixer_full_attention" if cfg.n_window
+                               else "mixer_attention")
+    elif kind == WINDOW:
+        mix = _attention_mixer(cfg, lp, h, *past,
+                               scope="mixer_swa_attention")
     elif kind == LATENT:
         mix = _latent_mixer(cfg, lp, h, *past)
     elif kind == DENSE:
@@ -952,6 +1100,8 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
         load = (counts, total)
     if cfg.norm_output:
         mix = _rms_norm(mix, lp["norm"], cfg.rms_eps)
+    elif cfg.sandwich_norm:
+        mix = _rms_norm(mix, lp["post_norm"], cfg.rms_eps)
     return x + cfg.residual_multiplier * mix, past, load
 
 
@@ -971,13 +1121,15 @@ def head(cfg: HybridConfig, params, x):
 
 def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
                state_out: Callable, attend_for: Callable, positions=None,
-               rows: int = 0):
+               rows: int = 0, window_for: Callable = None):
     """The unrolled layer loop over a window.  ``state_in(mi)`` gives
     recurrent (Mamba or linear) layer ``mi``'s state for the window's
     rows, (conv, (state pool, layer)) as its mixer takes it, and
     ``state_out(mi, state)``
     takes it back; ``attend_for(ai)`` gives attention or latent layer
-    ``ai``'s ``attend``; ``positions`` [b, w] are the window's, read by
+    ``ai``'s ``attend`` and ``window_for(wi)`` window-attention layer
+    ``wi``'s (the two kinds count apart: they keep their K/V in
+    different pools); ``positions`` [b, w] are the window's, read by
     rotary positions alone.  ``rows``: the window is in two parts
     (``block``: x [1, rows + w, d], ``n_valid`` [rows + 1], a Mamba
     layer's state with the window's row).
@@ -986,12 +1138,17 @@ def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
         least one assignment, each summed over the experts sublayers;
         real tokens only; [2, N_LOAD] of a window in two parts, the
         one-token rows' then the window's)."""
-    mi = ai = 0
+    mi = ai = wi = 0
     load = jnp.zeros((2, N_LOAD) if rows else (N_LOAD,), jnp.int32)
-    tables = rotary_tables(cfg, positions) if cfg.n_latent else None
+    tables = (rotary_tables(cfg, positions)
+              if cfg.n_latent or cfg.n_window else None)
     for i, kind in cfg.sublayers:
         lp = params["layers"][i]
-        if kind == LATENT:
+        if kind == WINDOW:
+            x, _, _ = block(cfg, kind, lp["mixer"], x,
+                            (window_for(wi), tables), n_valid, rows)
+            wi += 1
+        elif kind == LATENT:
             x, _, _ = block(cfg, kind, lp["mixer"], x,
                             (attend_for(ai), tables), n_valid, rows)
             ai += 1
@@ -1025,9 +1182,10 @@ def zero_state(cfg: HybridConfig, rows: int):
             (jnp.zeros((1, rows, *ssm_shape), jnp.float32), 0))
 
 
-def causal_attend(cfg: HybridConfig):
+def causal_attend(cfg: HybridConfig, window: int = 0):
     """``attend`` over the window's own keys (a full sequence), in the
-    form the model's attention layers take it."""
+    form the model's attention layers take it; ``window`` > 0: each
+    query over its last ``window`` keys only, its own among them."""
     if cfg.n_latent:
         def latent(q_nope, q_rope, lat, w_uk, w_uv):
             b, w = lat.shape[:2]
@@ -1049,6 +1207,8 @@ def causal_attend(cfg: HybridConfig):
                             preferred_element_type=jnp.float32) \
             * cfg.attention_multiplier
         mask = jnp.tril(jnp.ones((w, w), bool))
+        if window:
+            mask &= ~jnp.tril(jnp.ones((w, w), bool), -window)
         probs = jax.nn.softmax(jnp.where(mask, logits, -jnp.inf), axis=-1)
         o = jnp.einsum("bgrqk,bkgd->bgrqd", probs.astype(v.dtype), v)
         return o.reshape(b, nh, w, hd)
@@ -1060,11 +1220,12 @@ def forward(params, tokens, cfg: HybridConfig):
     one window of the whole sequence, from zero state."""
     b, s = tokens.shape
     attend = causal_attend(cfg)
+    within = causal_attend(cfg, cfg.window) if cfg.n_window else None
     x, _ = run_layers(
         cfg, params, embed(cfg, params, tokens),
         jnp.full((b,), s, jnp.int32),
         state_in=lambda mi: zero_state(cfg, b),
         state_out=lambda mi, state: None,
-        attend_for=lambda ai: attend,
+        attend_for=lambda ai: attend, window_for=lambda wi: within,
         positions=jnp.broadcast_to(jnp.arange(s), (b, s)))
     return head(cfg, params, x)

@@ -198,7 +198,7 @@ def _list_live_rows(len_ref, rows_ref):
 def _decode_kernel(base_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
                    rows_ref, k_buf, v_buf, sem, qx_ref, acc_ref, *,
                    wave: int, n_table: int, n_kv: int, head_dim: int,
-                   scale: float):
+                   scale: float, window: int = 0):
     """Every live row's one query over the blocks its table names, a
     wave of blocks at a time: wave w + 1 (or the next live row's first)
     is on its way into one half of the buffers while wave w is attended
@@ -212,7 +212,13 @@ def _decode_kernel(base_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
     own lanes hold the query, the rest are zero, so one product over
     the full width gives every head's logits [heads, tokens] and one
     product with V every (head, lane) pair, of which each lane keeps
-    its own head's."""
+    its own head's.
+
+    ``window`` > 0: a row attends its LAST ``window`` keys only (keys
+    ``len - window .. len``), and the walk starts at the wave, and in
+    it at the block, that holds the first of them: what lies before is
+    neither copied nor multiplied (its table entries may name blocks
+    the row gave back), so a row's time is bounded by the window."""
     from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
@@ -223,9 +229,22 @@ def _decode_kernel(base_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     count = _list_live_rows(len_ref, rows_ref)
 
+    def first_key(row):
+        """The first key the row attends."""
+        return jnp.maximum(len_ref[row] - window, 0) if window else 0
+
+    def first_wave(row):
+        return first_key(row) // tokens if window else 0
+
     def blocks_of(row, w):
         """Blocks of the row's wave w that hold a key: 0 .. wave."""
         return jnp.clip(pl.cdiv(len_ref[row], bs) - w * wave, 0, wave)
+
+    def first_block(row, w):
+        """... and the first of them that holds a key the row attends."""
+        if not window:
+            return 0
+        return jnp.clip(first_key(row) // bs - w * wave, 0, wave)
 
     def copies(row, w, half, i):
         at = pl.ds(pl.multiple_of(i * bs, bs), bs)
@@ -239,7 +258,7 @@ def _decode_kernel(base_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
         def one(i, _):
             for c in copies(row, w, half, i):
                 do(c)
-        lax.fori_loop(0, blocks_of(row, w), one, None)
+        lax.fori_loop(first_block(row, w), blocks_of(row, w), one, None)
 
     def start(row, w, half):
         each_copy(row, w, half, lambda c: c.start())
@@ -256,13 +275,14 @@ def _decode_kernel(base_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     @pl.when(count > 0)
     def _():
-        start(rows_ref[0], 0, 0)
+        start(rows_ref[0], first_wave(rows_ref[0]), 0)
 
     def row_body(slot, done):
         """``done``: waves attended so far, whose parity says which
         half of the buffers this row's first wave is arriving in."""
         row = rows_ref[slot]
         kv_len = len_ref[row]
+        lo, w0 = first_key(row), first_wave(row)
         n_waves = pl.cdiv(kv_len, tokens)
         q = q_ref[row]                                          # [R, W]
         for r in range(reps):
@@ -272,34 +292,47 @@ def _decode_kernel(base_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         def wave_body(w, carry):
             m_prev, l_prev = carry
-            half = (done + w) % 2
+            half = (done + w - w0) % 2
             more = w + 1 < n_waves
 
             @pl.when(more | (slot + 1 < count))
             def _():
-                start(jnp.where(more, row, rows_ref[
-                    jnp.minimum(slot + 1, rows_ref.shape[0] - 1)]),
-                    jnp.where(more, w + 1, 0), 1 - half)
+                # (with a window the next row's length is read too: past
+                # the last live row the list holds nothing, so stay on it)
+                nxt = rows_ref[jnp.minimum(
+                    slot + 1, count - 1 if window else rows_ref.shape[0] - 1)]
+                start(jnp.where(more, row, nxt),
+                      jnp.where(more, w + 1, first_wave(nxt)), 1 - half)
 
             wait(row, w, half)
             s = lax.dot_general(
                 qx_ref[...], k_buf[half], (((1,), (1,)), ((), ())),
                 preferred_element_type=f32) * scale     # [heads, tokens]
             key = w * tokens + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(key < kv_len, s, -jnp.inf)
+            seen = key < kv_len
+            if window:
+                seen &= key >= lo
+            s = jnp.where(seen, s, -jnp.inf)
             m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_next)
             p = jnp.exp(s - m_next)
 
-            # a key past kv_len weighs exactly 0, and what lies there
-            # (a block not copied, a block's unwritten tail) may be
-            # anything: 0 x NaN is NaN
-            @pl.when((w + 1) * tokens > kv_len)
+            # a key past kv_len (or before the window) weighs exactly
+            # 0, and what lies there (a block not copied, a block's
+            # unwritten tail) may be anything: 0 x NaN is NaN
+            ragged = (w + 1) * tokens > kv_len
+            if window:
+                ragged |= w * tokens < lo
+
+            @pl.when(ragged)
             def _():
                 pos = w * tokens + lax.broadcasted_iota(
                     jnp.int32, (tokens, width), 0)
                 v = v_buf[half]
-                v_buf[half] = jnp.where(pos < kv_len, v, jnp.zeros_like(v))
+                keep = pos < kv_len
+                if window:
+                    keep &= pos >= lo
+                v_buf[half] = jnp.where(keep, v, jnp.zeros_like(v))
 
             acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
                 p.astype(v_buf.dtype), v_buf[half],
@@ -308,21 +341,22 @@ def _decode_kernel(base_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         heads = qx_ref.shape[0]
         _, l = lax.fori_loop(
-            0, n_waves, wave_body,
+            w0, n_waves, wave_body,
             (jnp.full((heads, 1), -jnp.inf, f32), jnp.zeros((heads, 1), f32)))
         out = acc_ref[...] / l                                  # [heads, W]
         for r in range(reps):
             o_ref[row, r:r + 1, :] = jnp.sum(
                 jnp.where(own(), out[r * gp:(r + 1) * gp], 0.0),
                 axis=0, keepdims=True)
-        return done + n_waves
+        return done + n_waves - w0
 
     lax.fori_loop(0, count, row_body, 0)
 
 
 def paged_decode_attention(q, k_pool, v_pool, base, tables, kv_lengths, *,
                            q_per_kv: int = 1,
-                           scale: Optional[float] = None) -> jax.Array:
+                           scale: Optional[float] = None,
+                           window: int = 0) -> jax.Array:
     """One query a row over the paged pools AS STORED, reading only the
     blocks a row holds.
 
@@ -334,6 +368,9 @@ def paged_decode_attention(q, k_pool, v_pool, base, tables, kv_lengths, *,
     tables       [b, T] int32 block ids in position order
     kv_lengths   [b] int32: keys the row attends; 0 = the row sits the
                  pass out
+    window       > 0: of those keys only the last ``window`` (the row's
+                 query is the last of them); the blocks before them are
+                 not read, and their table entries may name anything
     -> [b, h, 1, hd]; a row that sits out gets zeros.
 
     ONE Pallas kernel walks the live rows.  For each it copies the
@@ -368,7 +405,7 @@ def paged_decode_attention(q, k_pool, v_pool, base, tables, kv_lengths, *,
     out = pl.pallas_call(
         functools.partial(_decode_kernel, wave=wave, n_table=n_table,
                           n_kv=n_kv, head_dim=hd,
-                          scale=_scale_for(q, scale)),
+                          scale=_scale_for(q, scale), window=int(window)),
         in_specs=[smem] * 3 + [vmem, hbm, hbm],
         out_specs=vmem,
         out_shape=jax.ShapeDtypeStruct(qr.shape, jnp.float32),
@@ -518,15 +555,21 @@ def latent_window_attention(q_nope, q_rope, read_keys, w_uk, w_uv, q_pos, *,
 
 
 def _head_block_kernel(k0_ref, pos_ref, q_ref, k_ref, vt_ref, m_ref, l_ref,
-                       acc_ref, m_out, l_out, acc_out, *, scale: float):
+                       acc_ref, m_out, l_out, acc_out, *, scale: float,
+                       window: bool = False):
     """``_window_block_kernel`` for keys that are stored a head's lanes
     each: one head's window of queries against one block of its K/V
-    head's keys, held transposed the same way."""
+    head's keys, held transposed the same way.  ``window``: ``pos_ref``
+    [2, queries] holds each query's position and, below it, the last
+    key position it no longer sees."""
     f32 = jnp.float32
     s = lax.dot_general(k_ref[...], q_ref[...], (((1,), (1,)), ((), ())),
                         preferred_element_type=f32) * scale
     k_pos = k0_ref[0] + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    seen = k_pos <= pos_ref[...]                       # [keys, queries]
+    if window:
+        seen = (k_pos <= pos_ref[0:1, :]) & (k_pos > pos_ref[1:2, :])
+    else:
+        seen = k_pos <= pos_ref[...]                   # [keys, queries]
     s = jnp.where(seen, s, _MASKED)
     m_prev = m_ref[...]
     m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
@@ -540,7 +583,7 @@ def _head_block_kernel(k0_ref, pos_ref, q_ref, k_ref, vt_ref, m_ref, l_ref,
 
 def head_window_attention(q, read_keys, q_pos, *, n_kv_heads: int,
                           scale: float, key_block: int = KEY_BLOCK,
-                          n_blocks=None):
+                          n_blocks=None, window: int = 0):
     """ONE row's window of queries over the row's cached K/V, head by
     head, ``key_block`` keys at a time under a running softmax.
 
@@ -553,6 +596,12 @@ def head_window_attention(q, read_keys, q_pos, *, n_kv_heads: int,
               own (key 0 is every query's)
     n_blocks  key blocks walked; None: those that hold a key of the
               window's last query
+    window    > 0: a query attends its last ``window`` keys only (its
+              own among them), and the walk starts at the block that
+              holds the first key of the window's FIRST query: the
+              work is bounded by ``window + w`` keys, whatever the
+              row's length (what ``read_keys`` gives for the blocks
+              before may be anything)
     -> [h, w, hd]
 
     For heads of whole lane tiles (``hd`` a multiple of 128), where a
@@ -575,6 +624,11 @@ def head_window_attention(q, read_keys, q_pos, *, n_kv_heads: int,
     if n_blocks is None:
         n_blocks = last // key_block + 1
     pos = q_pos.astype(jnp.int32)[None, :]
+    first, j0 = 0, 0
+    if window:
+        pos = jnp.concatenate([pos, pos - window])
+        first = jnp.maximum(jnp.min(q_pos) - window + 1, 0)
+        j0 = first // key_block
     n = key_block
 
     def per_head(*shape):
@@ -582,10 +636,11 @@ def head_window_attention(q, read_keys, q_pos, *, n_kv_heads: int,
 
     stats = [per_head(1, w), per_head(1, w), per_head(hd, w)]
     call = pl.pallas_call(
-        functools.partial(_head_block_kernel, scale=scale),
+        functools.partial(_head_block_kernel, scale=scale,
+                          window=bool(window)),
         grid=(h,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, w), lambda i: (0, 0)), per_head(w, hd),
+                  pl.BlockSpec(pos.shape, lambda i: (0, 0)), per_head(w, hd),
                   pl.BlockSpec((n, hd), lambda i: (0, i // rep)),
                   pl.BlockSpec((None, hd, n), lambda i: (i // rep, 0, 0))]
         + stats,
@@ -604,14 +659,17 @@ def head_window_attention(q, read_keys, q_pos, *, n_kv_heads: int,
         k, v = read_keys(j, n)
         k_pos = j * n + jnp.arange(n, dtype=jnp.int32)
         # 0 x NaN is NaN: what no query of the window may see is zeroed
-        v = jnp.where((k_pos <= last)[:, None], v, jnp.zeros_like(v))
+        seen = k_pos <= last
+        if window:
+            seen &= k_pos >= first
+        v = jnp.where(seen[:, None], v, jnp.zeros_like(v))
         v_t = v[:, :n_kv_heads * hd].reshape(n, n_kv_heads, hd)
         return tuple(call(jnp.asarray(j * n, jnp.int32)[None], pos, q, k,
                           v_t.transpose(1, 2, 0), *carry))
 
     init = (jnp.full((h, 1, w), _MASKED, f32), jnp.zeros((h, 1, w), f32),
             jnp.zeros((h, hd, w), f32))
-    _, l, acc = lax.fori_loop(0, n_blocks, body, init)
+    _, l, acc = lax.fori_loop(j0, n_blocks, body, init)
     return (acc / l).astype(q.dtype).transpose(0, 2, 1)
 
 

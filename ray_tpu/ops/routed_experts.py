@@ -22,7 +22,8 @@ Three published forms of the router, two of an expert:
   * no selection ``bias``: the k largest router logits, gates their
     softmax; with a ``bias`` [E]: scores ``sigmoid(logits)``, the k
     largest of ``score + bias`` chosen, gates the UNBIASED scores of the
-    chosen, normalised to sum 1 and times ``scale``; with ``groups``
+    chosen, normalised to sum 1 (``eps`` added to the sum first, where a
+    published recipe has one) and times ``scale``; with ``groups``
     (n_group, topk_group): scores the softmax over ALL E logits in
     float32, the experts in n_group consecutive groups of which only
     the topk_group with the largest MAXIMUM score may be chosen from
@@ -43,7 +44,7 @@ from jax import lax
 
 
 def route(h, w_router, top_k: int, bias=None, scale: float = 1.0,
-          groups: tuple = (), normalise: bool = True):
+          groups: tuple = (), normalise: bool = True, eps: float = 0.0):
     """h [T, d], w_router [d, E] -> (experts [T, k] int32, gates [T, k]
     float32): see the module's text for the three forms."""
     logits = jnp.dot(h, w_router.astype(h.dtype),
@@ -70,7 +71,8 @@ def route(h, w_router, top_k: int, bias=None, scale: float = 1.0,
     scores = jax.nn.sigmoid(logits)
     _, experts = lax.top_k(scores + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
-    gates = chosen / chosen.sum(-1, keepdims=True) * scale
+    total = chosen.sum(-1, keepdims=True)
+    gates = chosen / (total + eps if eps else total) * scale
     return experts.astype(jnp.int32), gates
 
 
@@ -145,15 +147,16 @@ def grouped_matmul(x, w, group_sizes):
 def routed_experts(h, w_router, w_in, w_out, *, top_k: int,
                    held: tuple, valid=None, gated: bool = True, bias=None,
                    scale: float = 1.0, groups: tuple = (),
-                   normalise: bool = True):
+                   normalise: bool = True, eps: float = 0.0):
     """The held experts' part of a routed-expert layer.
 
     h [T, d]; w_router [d, E]; w_in [E_held, d, 2f or f'], w_out
     [E_held, f, d] the weights of experts ``held[0] .. held[1] - 1`` in
     the form ``gated`` says; ``valid`` [T] bool marks real tokens
-    (padding routes like any token but is not counted), or [P, T] the
-    real tokens of P sets counted apart; ``bias`` [E],
-    ``scale``, ``groups`` and ``normalise`` as ``route`` takes them.
+    (padding joins no expert's run, gets no routed part and is not
+    counted), or [P, T] the real tokens of P sets counted apart; ``bias`` [E],
+    ``scale``, ``groups``, ``normalise`` and ``eps`` as ``route`` takes
+    them.
     -> (out [T, d], counts [E_held] int32: real assignments per held
         expert, total int32: real assignments to ANY expert; [P, E_held]
         and [P] for P sets)."""
@@ -164,9 +167,14 @@ def routed_experts(h, w_router, w_in, w_out, *, top_k: int,
         raise ValueError(f"{w_in.shape[0]} expert weights for the held "
                          f"range {held}")
     experts, gates = route(h, w_router, top_k, bias, scale, groups,
-                           normalise)
+                           normalise, eps)
+    if valid is None:
+        valid = jnp.ones((T,), bool)
     flat = experts.reshape(-1)                                  # [T*k]
-    mine = (flat >= lo) & (flat < hi)
+    # padding joins no expert's run: a decode pass's empty rows would
+    # else have the experts of THEIR choice streamed for nothing
+    real = jnp.repeat(valid if valid.ndim == 1 else valid.any(0), top_k)
+    mine = (flat >= lo) & (flat < hi) & real
     local = jnp.where(mine, flat - lo, n_held)      # absent: past the runs
     order = jnp.argsort(local, stable=True)
     token = order // top_k
@@ -181,8 +189,6 @@ def routed_experts(h, w_router, w_in, w_out, *, top_k: int,
         * gate[:, None]
     # back to (token, choice) order: a gather, then the k parts add up
     out = y[jnp.argsort(order)].reshape(T, top_k, d).sum(1)
-    if valid is None:
-        valid = jnp.ones((T,), bool)
 
     def count(valid):
         real = jnp.repeat(valid, top_k)

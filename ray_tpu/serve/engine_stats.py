@@ -225,6 +225,36 @@ ROWS: tuple[Row, ...] = (
            "keeps K/V only)"),
     _gauge("state_rows_in_use", "ray_tpu_inference_state_rows_in_use",
            "Decode rows holding a recurrent state"),
+    # ---- the second kind of K/V state: the pool of a model's
+    # window-attention layers (zeros for a model without).  A row takes
+    # its blocks chunk by chunk and gives them back behind the window;
+    # the two sums, a decode pass each, set what the rows hold of it
+    # beside what they hold of the full layers' pool, which is what ONE
+    # table a row would keep of the window layers too
+    _gauge("window_blocks_total", over=SUM),
+    _gauge("window_blocks_held", "ray_tpu_inference_window_blocks_held",
+           "Blocks of the window-attention layers' pool held by rows",
+           over=SUM),
+    _counter("window_blocks_allocated",
+             "ray_tpu_inference_window_blocks_allocated_total",
+             "Blocks of the window-attention layers' pool handed to rows"),
+    _counter("window_blocks_returned",
+             "ray_tpu_inference_window_blocks_returned_total",
+             "Blocks of the window-attention layers' pool given back "
+             "behind the window by rows still running"),
+    _counter("kv_blocks_allocated",
+             "ray_tpu_inference_kv_blocks_allocated_total",
+             "Blocks of the (full-attention layers') paged KV pool handed "
+             "to rows, adopted ones not counted"),
+    _counter("window_blocks_resident_sum"),
+    _counter("window_blocks_one_table_sum"),
+    # what a window layer's two programs must read and multiply
+    _counter("window_blocks_attended",
+             "ray_tpu_inference_window_blocks_attended_total",
+             "Blocks holding a key inside a live row's window, summed over "
+             "one-token decode passes (read once a pool and window layer)"),
+    _counter("window_chunk_keys"),      # keys in reach of chunks' windows
+    _counter("window_query_keys"),      # (query, key) pairs inside them
     # ---- routed-expert load, of the programs that report one (zeros
     # for the others).  max / (held / experts held) = the imbalance
     _counter("expert_assignments_held",

@@ -1051,3 +1051,96 @@ def cfg_keys(cfg):
             "linear_key_head_dim": cfg.lin_key_dim,
             "linear_value_head_dim": cfg.lin_value_dim,
             "num_attention_heads": cfg.n_heads}
+
+
+# the afmoe layout (Trinity): window and full attention layers over TWO
+# groups of K/V pools, each walked by the one-token kernel and by the
+# head-wise window kernel; the window layers' walks start behind the
+# window
+
+
+@pytest.fixture(scope="module")
+def afmoe_cell(one_chip):
+    """The shapes of ``serve-trinity-large-mixlen32k-r80``, from the
+    cell's own configuration file: 5 layers (4 window : 1 full) at
+    published widths, 32 of 256 experts, an eighth of the vocabulary,
+    32 rows, two tables of 520 blocks of 64 a row."""
+    import json
+    import os
+    from chipbench.traffic.open_loop_http_afmoe import model_config
+    from ray_tpu.models import hybrid
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "trinity-large-preview-5L-e32.json")) as f:
+        config = json.load(f)
+    engine = config["engine"]
+    cfg, _, _ = model_config(config)
+    assert (cfg.n_window, cfg.n_attention, cfg.window) == (4, 1, 4096)
+    on_chip = _on(one_chip)
+    bs = engine["kv_block_size"]
+    full = PoolLayout(1, engine["n_blocks"] + 1, bs, *cfg.kv_geometry[1:])
+    within = PoolLayout(4, engine["n_window_blocks"] + 1, bs,
+                        *cfg.window_geometry[1:3])
+    assert full.shape == (4097, 64, 1024)
+    assert within.shape == (4 * 2625, 64, 1024)
+    params = jax.tree.map(
+        lambda s: on_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda: hybrid.init_params(cfg,
+                                                  jax.random.PRNGKey(0))))
+    n_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                  for s in jax.tree.leaves(params))
+    assert 8.6e9 < n_bytes < 8.7e9              # 4,322 M bf16 parameters
+    pools = tuple(on_chip(lay.shape, cfg.dtype)
+                  for lay in (full, full, within, within))
+    return cfg, on_chip, params, pools, full, within, engine
+
+
+def _afmoe_program(afmoe_cell, which):
+    from ray_tpu.inference.recurrent import (make_recurrent_chunk_fn,
+                                             make_recurrent_decode_step)
+    cfg, on_chip, params, pools, full, _, engine = afmoe_cell
+    T = -(-engine["max_seq"] // full.block_size)
+    if which == "step":
+        fn = make_recurrent_decode_step(cfg, block_size=full.block_size,
+                                        n_table=T)
+        packed = on_chip((engine["max_slots"], 2 * T + 3), jnp.int32)
+    else:
+        C = engine["prefill_chunk"]
+        fn = make_recurrent_chunk_fn(cfg, chunk=C,
+                                     block_size=full.block_size, n_table=T)
+        packed = on_chip((2 * T + C + 3,), jnp.int32)
+    return fn.lower(params, pools, (), packed).compile()
+
+
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_afmoe_programs_fit_and_move_neither_group_of_pools(afmoe_cell,
+                                                            which):
+    """Both programs compile for the described chip at the published
+    widths and the cell's sizes, fit, and re-lay out no pool of either
+    group; each attention layer is ONE kernel call on its own group's
+    pools (decode) or one call inside its loop over key blocks (chunk),
+    and nothing holds a row's whole table of keys."""
+    from chipbench import afmoe_trace
+    cfg, _, _, _, full, within, engine = afmoe_cell
+    compiled = _afmoe_program(afmoe_cell, which)
+    _assert_pool_stays_put(compiled, full)
+    _assert_pool_stays_put(compiled, within)
+    text = compiled.as_text()
+    span = -(-engine["max_seq"] // full.block_size) * full.block_size
+    for shape in set(re.findall(r"(?:bf16|f32)\[([\d,]+)\]", text)):
+        dims = [int(d) for d in shape.split(",")]
+        assert not (cfg.n_heads in dims and span in dims), shape
+        assert not (span in dims and full.width in dims), shape
+    marks = afmoe_trace.marks_of(engine, full.shape, within.shape)
+    if which == "chunk":
+        calls = _kernel_calls(text, "head_window_attention")
+        assert len(calls) == cfg.n_window + cfg.n_attention == 5
+        labels = sorted(afmoe_trace.label_of(line, marks) for line in calls)
+        assert labels == ["mixer_full_attention"] \
+            + ["mixer_swa_attention"] * 4
+        assert not _kernel_calls(text)
+        return
+    calls = _kernel_calls(text)
+    assert len(calls) == 5
+    labels = sorted(afmoe_trace.label_of(line, marks) for line in calls)
+    assert labels == ["full_decode_attention"] + ["swa_decode_attention"] * 4

@@ -2,7 +2,8 @@
 types of ``stats()``, the ``/metrics`` series in order, and what a
 replica and a fleet return of several engines.  The literals below were
 recorded at the commit before the table (``ray_tpu/serve/engine_stats.py``)
-existed: they hold the table to what every reader already reads.
+existed: they hold the table to what every reader already reads (the
+window pool's rows, marked, are ISSUE 48's).
 
 Everything runs on CPU with the ``tiny`` configurations of both families.
 """
@@ -54,6 +55,12 @@ STATS_TYPES = {
     "expert_assignments_total": int, "expert_load_max": int,
     "expert_touched_held": int, "expert_touched_held_decode": int,
     "pool_generation": int,
+    # the second group of K/V layers (ISSUE 48): rows added to the table
+    "window_blocks_total": int, "window_blocks_held": int,
+    "window_blocks_allocated": int, "window_blocks_returned": int,
+    "kv_blocks_allocated": int, "window_blocks_resident_sum": int,
+    "window_blocks_one_table_sum": int, "window_blocks_attended": int,
+    "window_chunk_keys": int, "window_query_keys": int,
 }
 LOOP_ACCOUNT_TYPES = {
     "ns": dict, "starved_ns": dict, "count": dict, "unaccounted_ns": int,
@@ -144,6 +151,19 @@ SERIES = [
      "keeps K/V only)"),
     ("ray_tpu_inference_state_rows_in_use", "gauge",
      "Decode rows holding a recurrent state"),
+    ("ray_tpu_inference_window_blocks_held", "gauge",
+     "Blocks of the window-attention layers' pool held by rows"),
+    ("ray_tpu_inference_window_blocks_allocated_total", "counter",
+     "Blocks of the window-attention layers' pool handed to rows"),
+    ("ray_tpu_inference_window_blocks_returned_total", "counter",
+     "Blocks of the window-attention layers' pool given back behind "
+     "the window by rows still running"),
+    ("ray_tpu_inference_kv_blocks_allocated_total", "counter",
+     "Blocks of the (full-attention layers') paged KV pool handed to "
+     "rows, adopted ones not counted"),
+    ("ray_tpu_inference_window_blocks_attended_total", "counter",
+     "Blocks holding a key inside a live row's window, summed over "
+     "one-token decode passes (read once a pool and window layer)"),
     ("ray_tpu_inference_expert_assignments_held_total", "counter",
      "(token, expert) assignments routed to experts held here"),
     ("ray_tpu_inference_expert_assignments_total", "counter",

@@ -204,7 +204,7 @@ def test_bias_chooses_and_unbiased_scores_weigh():
     np.testing.assert_allclose(np.asarray(gates).sum(-1), 2.5, rtol=1e-6)
 
 
-def test_padding_routes_but_is_not_counted():
+def test_padding_is_not_counted():
     w_r, w_in, w_out, h = _expert_weights()
     valid = jnp.arange(40) < 25
     out, counts, total = rx.routed_experts(h, w_r, w_in[2:6], w_out[2:6],
@@ -214,6 +214,33 @@ def test_padding_routes_but_is_not_counted():
     want = [(np.asarray(experts) == e).sum() for e in range(2, 6)]
     assert counts.tolist() == want and int(total) == 75
     assert out.shape == h.shape
+
+
+@pytest.mark.parametrize("sets", [1, 2])
+def test_padding_joins_no_experts_run(sets, monkeypatch):
+    """A decode pass's empty rows and a last chunk's tail choose experts
+    like any token; none of them may be in a run (an expert that only
+    padding chose is not streamed), their routed part is nothing, and
+    the real tokens' is what it is without them."""
+    w_r, w_in, w_out, h = _expert_weights()
+    valid = jnp.arange(40) < 25
+    if sets == 2:       # a window in two parts: real if real in either
+        valid = jnp.stack([jnp.arange(40) < 10,
+                           (jnp.arange(40) >= 10) & (jnp.arange(40) < 25)])
+    seen = []
+    grouped = rx.grouped_matmul
+    monkeypatch.setattr(rx, "grouped_matmul", lambda x, w, sizes: (
+        seen.append(np.asarray(sizes)), grouped(x, w, sizes))[1])
+    out, counts, _ = rx.routed_experts(h, w_r, w_in[2:6], w_out[2:6],
+                                       top_k=3, held=(2, 6), valid=valid)
+    alone, _, _ = rx.routed_experts(h[:25], w_r, w_in[2:6], w_out[2:6],
+                                    top_k=3, held=(2, 6))
+    np.testing.assert_allclose(out[:25], alone, atol=1e-6)
+    assert not np.asarray(out[25:]).any()
+    assert seen[0].tolist() == np.asarray(counts).reshape(
+        sets, 4).sum(0).tolist()
+    experts, _ = rx.route(h[25:], w_r, 3)
+    assert ((np.asarray(experts) >= 2) & (np.asarray(experts) < 6)).any()
 
 
 # ------------------------------------------------------------ attention
