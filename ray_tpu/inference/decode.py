@@ -111,7 +111,7 @@ from ray_tpu.ops.attention import (KEY_BLOCK, _per_shard,
                                    head_window_attention,
                                    latent_decode_attention,
                                    latent_window_attention, packed_attention,
-                                   paged_decode_attention)
+                                   paged_decode_attention, real_positions)
 from ray_tpu.parallel.sharding import DEFAULT_LLM_RULES, Rules, spec_for
 
 
@@ -208,6 +208,13 @@ def window_by_head(lay: PoolLayout) -> bool:
             and lay.n_heads > PACKED_MAX_HEADS)
 
 
+def window_key_block(block_size: int) -> int:
+    """Keys a step of the window forms' walk takes: ``KEY_BLOCK`` in
+    whole cache blocks, or one cache block where they do not divide
+    it."""
+    return KEY_BLOCK if KEY_BLOCK % block_size == 0 else block_size
+
+
 def _key_blocks(lay: PoolLayout, tables, q_pos):
     """ONE row's table walked ``KEY_BLOCK`` keys at a time: whole key
     blocks, the last one padded with the scratch block (its keys lie
@@ -216,7 +223,7 @@ def _key_blocks(lay: PoolLayout, tables, q_pos):
     ``key_block`` and ``n_blocks``: the blocks that hold a key of the
     window's last query)."""
     bs = lay.block_size
-    per = KEY_BLOCK // bs if KEY_BLOCK % bs == 0 else 1
+    per = window_key_block(bs) // bs
     table = jnp.pad(tables[0], (0, -tables.shape[1] % per))
 
     def read_keys(pool, layer, j):
@@ -328,7 +335,7 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
 
 
 def latent_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
-                  scale: float, kv_lengths=None, q_pos=None):
+                  scale: float, kv_lengths=None, q_pos=None, n_valid=None):
     """``paged_attend`` for a model whose attention layers keep ONE
     latent a token (``lay``: one head of ``kv_rank + rope`` lanes, the
     values its first ``lay.value_lanes``; ``pools``: the one pool):
@@ -345,7 +352,10 @@ def latent_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
       * ``q_pos`` [w] — ONE row's window (b = 1) at those positions:
         the table walked ``KEY_BLOCK`` keys at a time, each block
         gathered, decompressed and attended under a running softmax
-        (``latent_window_attention``); no array of the table's span."""
+        (``latent_window_attention``); no array of the table's span.
+        ``n_valid``: the window's real queries, its first; the rest are
+        padding lanes, and neither the walk nor the kernel's tiles go
+        where only they see."""
     held = {"pools": pools}
     rank = lay.value_lanes
 
@@ -365,11 +375,12 @@ def latent_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
                     value_lanes=rank, scale=scale)
                 o = jnp.einsum("bhc,hcd->bhd", o_lat, w_uv)
                 return o.reshape(o.shape[0], 1, -1)
-            read_keys, walk = _key_blocks(lay, tables, q_pos)
+            read_keys, walk = _key_blocks(lay, tables,
+                                          real_positions(q_pos, n_valid))
             return latent_window_attention(
                 q_nope[0], q_rope[0],
                 lambda j, n: read_keys(pool, layer, j), w_uk, w_uv,
-                q_pos, scale=scale, **walk)[None]
+                q_pos, scale=scale, n_valid=n_valid, **walk)[None]
         return attend
     return attend_for, held
 

@@ -106,12 +106,14 @@ from ray_tpu.inference.decode import (SpeculationUnsupported,
                                       make_prefill_fn,
                                       make_spec_verify_step,
                                       ngram_propose, pack_chunk,
-                                      pack_step, pack_step_chunk)
+                                      pack_step, pack_step_chunk,
+                                      window_key_block)
 from ray_tpu.inference.recurrent import (has_step_chunk,
                                          make_recurrent_chunk_fn,
                                          make_recurrent_decode_step,
                                          make_recurrent_step_chunk)
 from ray_tpu.models import gpt, hybrid
+from ray_tpu.ops.attention import window_tiles
 from ray_tpu.parallel.sharding import (DEFAULT_LLM_RULES, Rules,
                                        tree_shardings)
 from ray_tpu.serve import engine_stats
@@ -742,6 +744,8 @@ class InferenceEngine:
         self._counts = engine_stats.Counters()
         # a model with linear-attention layers (a matrix state a row)
         self._linear = getattr(cfg, "n_linear", 0) > 0
+        # a model with latent-attention layers (ONE latent pool)
+        self._latent = getattr(cfg, "n_latent", 0) > 0
         # the loop thread's time by phase, always on; ``engine.account``
         # spans carry it to the ring (``_write_account``)
         self._passes = 0               # passes that found work
@@ -1399,6 +1403,11 @@ class InferenceEngine:
         counts.chunk_query_keys += n_q * pos + n_q * (n_q + 1) // 2
         if self._linear:
             counts.linear_chunk_tokens += n_q
+        if self._latent:
+            tiles = window_tiles(pos, n_q, C, window_key_block(bs))
+            counts.chunk_pairs_walked += tiles["pairs"]
+            counts.chunk_tiles_plain += tiles["plain"]
+            counts.chunk_tiles_diagonal += tiles["diagonal"]
         if self._window:
             # the same two of a window layer: keys in its queries' reach
             # and (query, key) pairs inside the window

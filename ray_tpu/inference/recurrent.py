@@ -144,7 +144,8 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
 
 
 def _attend_over(cfg, lay, pools, blocks, offsets, tables, *,
-                 kv_lengths=None, q_pos=None, q_table=None, window: int = 0):
+                 kv_lengths=None, q_pos=None, q_table=None, window: int = 0,
+                 n_valid=None):
     """``paged_attend`` or ``latent_attend``, as the model's attention
     layers keep K/V heads or one latent: ONE token a row that attends
     its first ``kv_lengths`` keys, or one row's window of queries at
@@ -156,11 +157,15 @@ def _attend_over(cfg, lay, pools, blocks, offsets, tables, *,
     model with window layers attends a row's window of queries head by
     head whatever its head count: the packed form multiplies every head
     over the full stored width of the row's WHOLE table, and such a
-    model is served at contexts where that is most of a chunk's time."""
+    model is served at contexts where that is most of a chunk's time.
+    ``n_valid``: the real queries of the window, its first (the latent
+    form alone takes note: its kernel skips what only padding lanes
+    see)."""
     if cfg.value_lanes is not None:
         return latent_attend(lay, pools, blocks, offsets, tables,
                              scale=cfg.attention_multiplier,
-                             kv_lengths=kv_lengths, q_pos=q_pos)
+                             kv_lengths=kv_lengths, q_pos=q_pos,
+                             n_valid=n_valid)
     mask = None
     if q_pos is not None and not (window_by_head(lay) or cfg.n_window):
         S = tables.shape[-1] * lay.block_size
@@ -249,7 +254,7 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
             attend_for, window_for, pools_out = _two_groups(
                 cfg, pools, table[None],
                 lambda t: _chunk_window(t[0], start, C, bs)[1][None],
-                off[None], q_pos=pos)
+                off[None], q_pos=pos, n_valid=n_valid)
             held = dict(zip(("conv", "ssm"), state))
 
             def state_in(mi):

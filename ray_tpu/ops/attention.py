@@ -437,43 +437,144 @@ def paged_decode_attention(q, k_pool, v_pool, base, tables, kv_lengths, *,
 
 KEY_BLOCK = 1024    # keys a step of the window form decompresses
 _MASKED = -1e30     # a masked score: finite, so no (-inf) - (-inf)
+# the window kernel's score tile, (keys, queries): a head's [key_block,
+# w] scores are worked through in tiles of this size (the whole array
+# where it is smaller), each done by what its place demands
+WINDOW_TILE = (512, 256)
 
 
-def _window_block_kernel(k0_ref, pos_ref, qn_ref, qr_ref, kn_ref, kr_ref,
-                         vt_ref, m_ref, l_ref, acc_ref, m_out, l_out,
-                         acc_out, *, scale: float):
-    """One head's window of queries against one block of decompressed
-    keys, the running softmax carried in and out.  Everything is held
-    TRANSPOSED — scores [keys, queries], values [dv, keys], the running
-    output [dv, queries] — so that a query's maximum and sum are lane-
-    dense rows [1, queries] and no product needs a transposed operand:
-    the scores never leave VMEM."""
+def _spans(n: int, tile: int) -> list:
+    """``range(n)`` in runs of ``tile``: [(first, count)], the last run
+    what is left."""
+    return [(a, min(tile, n - a)) for a in range(0, n, tile)]
+
+
+def _tile_kind(k_lo, k_hi, q_lo, q_hi):
+    """Where a score tile lies against the causal edge: its keys are at
+    positions ``k_lo .. k_hi``, ``q_lo`` is the least position of its
+    queries and ``q_hi`` the greatest of its REAL ones (-1: the tile's
+    queries are all padding lanes).  -> (unseen, plain): no real query
+    of the tile sees a key of it; every query sees every key.  A tile
+    that is neither is DIAGONAL: the edge crosses it.  (Numbers or
+    arrays, on the host or in the kernel.)"""
+    return k_lo > q_hi, (k_hi <= q_lo) & (k_lo <= q_hi)
+
+
+def window_tiles(pos: int, n_q: int, w: int,
+                 key_block: int = KEY_BLOCK) -> dict:
+    """What ``latent_window_attention`` does with a window of ``w``
+    lanes at positions ``pos ..`` whose first ``n_q`` queries are real,
+    a head and layer: the tiles of each kind over the key blocks it
+    walks, and the (query, key) pairs of the tiles it does not skip —
+    the kernel's own classification (``_tile_kind``), on the host."""
+    import numpy as np
+    tile = WINDOW_TILE
+    out = dict(unseen=0, plain=0, diagonal=0, pairs=0)
+    if n_q <= 0:
+        return out
+    keys = np.asarray([(j * key_block + a, j * key_block + a + n - 1, n)
+                       for j in range((pos + n_q - 1) // key_block + 1)
+                       for a, n in _spans(key_block, tile[0])])
+    for q0, tq in _spans(w, tile[1]):
+        real = min(q0 + tq, n_q) - q0
+        unseen, plain = _tile_kind(keys[:, 0], keys[:, 1], pos + q0,
+                                   pos + q0 + real - 1 if real > 0 else -1)
+        out["unseen"] += int(unseen.sum())
+        out["plain"] += int(plain.sum())
+        out["diagonal"] += int((~unseen & ~plain).sum())
+        out["pairs"] += int(keys[~unseen, 2].sum()) * tq
+    return out
+
+
+def _window_block_kernel(k0_ref, edge_ref, pos_ref, qn_ref, qr_ref, kn_ref,
+                         kr_ref, vt_ref, m_ref, l_ref, acc_ref, m_out, l_out,
+                         acc_out, *, tile):
+    """One head's window of queries (scaled) against one block of
+    decompressed keys, the running softmax carried in and out.
+    Everything is held TRANSPOSED — scores [keys, queries], values [dv,
+    keys], the running output [dv, queries] — so that a query's maximum
+    and sum are lane-dense rows [1, queries] and no product needs a
+    transposed operand: the scores never leave VMEM.
+
+    The scores are worked through in tiles of ``tile`` (keys, queries),
+    and a tile is done by what its place demands (``_tile_kind``, from
+    the block's first key position ``k0_ref`` and ``edge_ref``: a query
+    tile's least position and its real queries' greatest): UNSEEN — no
+    product, the carry as it came; PLAIN — products, maximum, exp, sum
+    and rescale with no mask; DIAGONAL — the same under the mask of
+    each query's own position (``pos_ref``).  Where all of the block's
+    tiles are plain for a tile of queries (every block but the last of
+    a walk) they are done as one: one maximum and one rescale of the
+    running output, not one a tile."""
     f32 = jnp.float32
     nt = (((1,), (1,)), ((), ()))
-    s = (lax.dot_general(kn_ref[...], qn_ref[...], nt,
-                         preferred_element_type=f32)
-         + lax.dot_general(kr_ref[...], qr_ref[...], nt,
-                           preferred_element_type=f32)) * scale
-    k_pos = k0_ref[0] + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    seen = k_pos <= pos_ref[...]                       # [keys, queries]
-    s = jnp.where(seen, s, _MASKED)
-    m_prev = m_ref[...]
-    m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-    alpha = jnp.exp(m_prev - m_next)
-    p = jnp.where(seen, jnp.exp(s - m_next), 0.0)
-    m_out[...] = m_next
-    l_out[...] = l_ref[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
-    acc_out[...] = acc_ref[...] * alpha + jnp.dot(
-        vt_ref[...], p.astype(vt_ref.dtype), preferred_element_type=f32)
+    k0 = k0_ref[0]
+
+    def attend(ks, qs, k_lo):
+        """The tile's update of its queries' carry; ``k_lo``: the tile's
+        first key position where the causal edge crosses it."""
+        s = (lax.dot_general(kn_ref[ks, :], qn_ref[qs, :], nt,
+                             preferred_element_type=f32)
+             + lax.dot_general(kr_ref[ks, :], qr_ref[qs, :], nt,
+                               preferred_element_type=f32))
+        if k_lo is not None:
+            seen = (lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                    <= pos_ref[:, qs] - k_lo)              # [keys, queries]
+            s = jnp.where(seen, s, _MASKED)
+        m_prev = m_out[:, qs]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - m_next)
+        if k_lo is not None:
+            p = jnp.where(seen, p, 0.0)
+        m_out[:, qs] = m_next
+        l_out[:, qs] = l_out[:, qs] * alpha + jnp.sum(p, axis=0,
+                                                      keepdims=True)
+        acc_out[:, qs] = acc_out[:, qs] * alpha + jnp.dot(
+            vt_ref[:, ks], p.astype(vt_ref.dtype),
+            preferred_element_type=f32)
+
+    n, w = kn_ref.shape[0], qn_ref.shape[0]
+
+    def query_tile(t, qs):
+        """Query tile ``t``, the window's lanes ``qs``, against the
+        block."""
+        m_out[:, qs], l_out[:, qs] = m_ref[:, qs], l_ref[:, qs]
+        acc_out[:, qs] = acc_ref[:, qs]
+        edge = edge_ref[2 * t], edge_ref[2 * t + 1]
+        # a block of nothing but plain tiles is taken as ONE
+        _, whole = _tile_kind(k0, k0 + n - 1, *edge)
+        crossed = jnp.logical_not(whole)
+        pl.when(whole)(functools.partial(attend, slice(0, n), qs, None))
+        for a, tk in _spans(n, tile[0]):
+            ks = slice(a, a + tk)
+            unseen, plain = _tile_kind(k0 + a, k0 + a + tk - 1, *edge)
+            pl.when(crossed & plain)(functools.partial(attend, ks, qs, None))
+            pl.when(crossed & jnp.logical_not(unseen | plain))(
+                functools.partial(attend, ks, qs, k0 + a))
+
+    # the whole tiles in a loop (ONE copy of the code, whatever the
+    # window's width), then what is left of the window
+    tq = tile[1]
+    full = w // tq
+    if full:
+        lax.fori_loop(0, full, lambda t, _: query_tile(
+            t, pl.ds(pl.multiple_of(t * tq, tq), tq)), None)
+    if w % tq:
+        query_tile(full, slice(full * tq, w))
 
 
-def _window_block(qn, qr, k_nope, k_rope, v_t, pos, k0, carry, scale):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _window_block(qn, qr, k_nope, k_rope, v_t, pos, edges, k0, carry, *,
+                  tile, interpret):
     """``carry`` (m [h, 1, w], l [h, 1, w], acc [h, dv, w]) advanced by
     one block of keys: one grid step a head, the carry updated in
-    place."""
+    place.  Jitted, so that a program's equal layers trace and lower
+    the kernel ONCE (XLA inlines the calls): traced a layer each, a
+    copy of the code a query tile, it added 3-5 s to the chunk
+    program's bring-up from a warm compile cache; so, and with the
+    query tiles in a loop, 0.9 (my chip runs, PR 49)."""
     from jax.experimental.pallas import tpu as pltpu
-
-    from ray_tpu.ops.flash_attention import _interpret_mode
 
     h, w, dn = qn.shape
     dr, n, dv = qr.shape[-1], k_nope.shape[1], v_t.shape[1]
@@ -484,28 +585,37 @@ def _window_block(qn, qr, k_nope, k_rope, v_t, pos, k0, carry, scale):
     def shared(*shape):
         return pl.BlockSpec(shape, lambda i: (0, 0))
 
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     stats = [per_head(1, w), per_head(1, w), per_head(dv, w)]
     return pl.pallas_call(
-        functools.partial(_window_block_kernel, scale=scale),
+        functools.partial(_window_block_kernel, tile=tile),
         grid=(h,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), shared(1, w),
-                  per_head(w, dn), per_head(w, dr), per_head(n, dn),
-                  shared(n, dr), per_head(dv, n)] + stats,
+        in_specs=[smem, smem, shared(1, w), per_head(w, dn), per_head(w, dr),
+                  per_head(n, dn), shared(n, dr), per_head(dv, n)] + stats,
         out_specs=stats,
         out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in carry],
-        input_output_aliases={7: 0, 8: 1, 9: 2},
+        input_output_aliases={8: 0, 9: 1, 10: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=64 << 20),
-        interpret=_interpret_mode(),
+        interpret=interpret,
         name="latent_window_attention",
-    )(jnp.asarray(k0, jnp.int32)[None], pos, qn, qr, k_nope, k_rope, v_t,
-      *carry)
+    )(jnp.asarray(k0, jnp.int32)[None], edges, pos, qn, qr, k_nope, k_rope,
+      v_t, *carry)
+
+
+def real_positions(q_pos, n_valid=None):
+    """``q_pos`` [w] with the padding lanes (those from ``n_valid`` on;
+    None: there are none) at -1, before every key."""
+    q_pos = q_pos.astype(jnp.int32)
+    if n_valid is None:
+        return q_pos
+    return jnp.where(jnp.arange(q_pos.shape[0]) < n_valid, q_pos, -1)
 
 
 def latent_window_attention(q_nope, q_rope, read_keys, w_uk, w_uv, q_pos, *,
                             scale: float, key_block: int = KEY_BLOCK,
-                            n_blocks=None):
+                            n_blocks=None, n_valid=None):
     """ONE row's window of queries over the row's cached latents, the
     keys decompressed ``key_block`` at a time under a running softmax.
 
@@ -517,7 +627,11 @@ def latent_window_attention(q_nope, q_rope, read_keys, w_uk, w_uv, q_pos, *,
     q_pos     [w] int32: a query attends the keys at positions <= its
               own (key 0 is every query's)
     n_blocks  key blocks walked; None: those that hold a key of the
-              window's last query (a traced count, a ``while`` loop)
+              window's last real query (a traced count, a ``while``
+              loop)
+    n_valid   the window's first ``n_valid`` queries are real, the rest
+              padding lanes whose output is finite and nothing more;
+              None: all are real
     -> [w, h * dv]
 
     A block is decompressed ONCE for the whole window (``c_kv W_uk``,
@@ -525,16 +639,29 @@ def latent_window_attention(q_nope, q_rope, read_keys, w_uk, w_uv, q_pos, *,
     kernel, a grid step a head, whose scores stay in VMEM
     (``_window_block_kernel``): as a plain ``lax`` loop the float32
     scores ``[h, w, keys]`` went through HBM four times a block and the
-    form ran at 17 % of its roofline (PR 42, on the chip).  No array
-    here has more than ``key_block`` keys, whatever the row's length."""
+    form ran at 17 % of its roofline (PR 42, on the chip).  The kernel
+    takes its scores a tile at a time and masks only the tiles the
+    causal edge crosses; the tiles past every real query of theirs it
+    skips.  The softmax scale is folded into the queries, once.  No
+    array here has more than ``key_block`` keys, whatever the row's
+    length."""
+    from ray_tpu.ops.flash_attention import _interpret_mode
+
     f32 = jnp.float32
     w, h, dn = q_nope.shape
     dr, (_, kv_rank, dv) = q_rope.shape[-1], w_uv.shape
-    last = jnp.max(q_pos)
+    tile = WINDOW_TILE
+    pos = q_pos.astype(jnp.int32)
+    real = real_positions(q_pos, n_valid)
+    last = jnp.max(real)
     if n_blocks is None:
         n_blocks = last // key_block + 1
-    qn, qr = q_nope.transpose(1, 0, 2), q_rope.transpose(1, 0, 2)
-    pos = q_pos.astype(jnp.int32)[None, :]
+    qn, qr = ((q.transpose(1, 0, 2).astype(f32) * scale).astype(q.dtype)
+              for q in (q_nope, q_rope))
+    # a query tile's least position and its real queries' greatest
+    edges = jnp.stack([reach(x[q0:q0 + tq])
+                       for q0, tq in _spans(w, tile[1])
+                       for reach, x in ((jnp.min, pos), (jnp.max, real))])
 
     def body(j, carry):
         lat = read_keys(j, key_block)
@@ -545,12 +672,14 @@ def latent_window_attention(q_nope, q_rope, read_keys, w_uk, w_uv, q_pos, *,
         return _window_block(
             qn, qr, jnp.einsum("kc,hcd->hkd", c, w_uk),
             lat[:, kv_rank:kv_rank + dr], jnp.einsum("kc,hcd->hdk", c, w_uv),
-            pos, j * key_block, carry, scale)
+            pos[None, :], edges, j * key_block, carry, tile=tile,
+            interpret=_interpret_mode())
 
     init = (jnp.full((h, 1, w), _MASKED, f32), jnp.zeros((h, 1, w), f32),
             jnp.zeros((h, dv, w), f32))
     _, l, acc = lax.fori_loop(0, n_blocks, body, init)
-    o = (acc / l).astype(q_nope.dtype)                     # [h, dv, w]
+    # a padding lane in a tile of nothing else was never visited: l 0
+    o = (acc / jnp.where(l > 0, l, 1.0)).astype(q_nope.dtype)  # [h, dv, w]
     return o.transpose(2, 0, 1).reshape(w, h * dv)
 
 

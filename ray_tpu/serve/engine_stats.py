@@ -156,6 +156,23 @@ ROWS: tuple[Row, ...] = (
     _counter("chunk_query_keys", "ray_tpu_inference_chunk_query_keys_total",
              "(query, key) pairs under the causal mask, summed over "
              "prefill chunk passes"),
+    # 0 for a model with no latent-attention layer: what its window
+    # kernel walked of those pairs, tile by tile
+    # (``ops/attention.window_tiles``: the kernel's own classification)
+    _counter("chunk_pairs_walked",
+             "ray_tpu_inference_chunk_pairs_walked_total",
+             "(query, key) pairs of the score tiles the latent window "
+             "kernel did not skip, summed over prefill chunk passes"),
+    _counter("chunk_tiles_plain",
+             "ray_tpu_inference_chunk_tiles_plain_total",
+             "Score tiles the latent window kernel ran with no mask (every "
+             "key before every query), a head and layer, summed over "
+             "prefill chunk passes"),
+    _counter("chunk_tiles_diagonal",
+             "ray_tpu_inference_chunk_tiles_diagonal_total",
+             "Score tiles the latent window kernel ran under the causal "
+             "mask (the edge crosses them), a head and layer, summed over "
+             "prefill chunk passes"),
     # 0 for a model with no linear-attention layer
     _counter("linear_state_rows_advanced",
              "ray_tpu_inference_linear_state_rows_advanced_total",

@@ -61,6 +61,9 @@ STATS_TYPES = {
     "kv_blocks_allocated": int, "window_blocks_resident_sum": int,
     "window_blocks_one_table_sum": int, "window_blocks_attended": int,
     "window_chunk_keys": int, "window_query_keys": int,
+    # what the latent window kernel walked, tile by tile (ISSUE 49)
+    "chunk_pairs_walked": int, "chunk_tiles_plain": int,
+    "chunk_tiles_diagonal": int,
 }
 LOOP_ACCOUNT_TYPES = {
     "ns": dict, "starved_ns": dict, "count": dict, "unaccounted_ns": int,
@@ -117,6 +120,17 @@ SERIES = [
     ("ray_tpu_inference_chunk_query_keys_total", "counter",
      "(query, key) pairs under the causal mask, summed over prefill "
      "chunk passes"),
+    ("ray_tpu_inference_chunk_pairs_walked_total", "counter",
+     "(query, key) pairs of the score tiles the latent window kernel did "
+     "not skip, summed over prefill chunk passes"),
+    ("ray_tpu_inference_chunk_tiles_plain_total", "counter",
+     "Score tiles the latent window kernel ran with no mask (every key "
+     "before every query), a head and layer, summed over prefill chunk "
+     "passes"),
+    ("ray_tpu_inference_chunk_tiles_diagonal_total", "counter",
+     "Score tiles the latent window kernel ran under the causal mask (the "
+     "edge crosses them), a head and layer, summed over prefill chunk "
+     "passes"),
     ("ray_tpu_inference_linear_state_rows_advanced_total", "counter",
      "Rows whose linear-attention matrix state a one-token decode "
      "pass wrote, summed over passes"),
@@ -285,6 +299,18 @@ def _fleet_types(which):
         "fleet_stats", "fleet_snapshot"])
 def test_reported_as_recorded_before_the_table(read, recorded):
     assert read() == recorded
+
+
+@pytest.mark.parametrize("make", [_gpt_engine, _hybrid_engine],
+                         ids=["gpt", "hybrid"])
+def test_latent_window_counters_read_zero_without_latent_layers(make):
+    """The window kernel's three counters (ISSUE 49) are a latent
+    layout's: a model that keeps K/V heads ran chunks and counted
+    none."""
+    st = _stats_of(make)
+    assert st["chunk_passes"] > 0 and st["chunk_query_keys"] > 0
+    assert (st["chunk_pairs_walked"], st["chunk_tiles_plain"],
+            st["chunk_tiles_diagonal"]) == (0, 0, 0)
 
 
 def test_no_engine_renders_one_zero_row_a_series():

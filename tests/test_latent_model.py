@@ -474,6 +474,122 @@ def test_window_form_ignores_what_lies_past_its_queries():
     assert np.isfinite(clean).all()
 
 
+def _window_oracle(q_nope, q_rope, lat, w_uk, w_uv, pos, scale):
+    """Plain masked softmax over every key, decompressed at once."""
+    rank = w_uk.shape[1]
+    k_pos = np.arange(lat.shape[0])
+    c = np.where((k_pos <= pos.max())[:, None], lat, 0.0)
+    k = np.concatenate([np.einsum("kc,hcd->khd", c[:, :rank], w_uk),
+                        np.broadcast_to(c[:, None, rank:],
+                                        (len(c), w_uk.shape[0],
+                                         c.shape[1] - rank))], -1)
+    v = np.einsum("kc,hcd->khd", c[:, :rank], w_uv)
+    s = np.einsum("qhd,khd->hqk", np.concatenate([q_nope, q_rope], -1), k)
+    s = np.where(k_pos[None, None, :] <= pos[None, :, None], s * scale,
+                 -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    o = np.einsum("hqk,khd->qhd", p / p.sum(-1, keepdims=True), v)
+    return o.reshape(len(pos), -1)
+
+
+# (the window's first position, lanes, real queries (None: all), keys a
+# block, the kernel's tile (None: the code's own))
+WINDOW_CASES = {
+    "starts_at_0": (0, 48, None, 64, (32, 16)),
+    "start_off_tile_and_block": (37, 48, None, 64, (32, 16)),
+    "narrower_than_a_tile": (70, 5, None, 64, (32, 16)),
+    "no_multiple_of_a_tile": (21, 40, None, 48, (32, 16)),
+    "three_key_blocks_and_more": (100, 100, None, 64, (32, 16)),
+    "padding_lanes": (64, 48, 20, 64, (32, 16)),
+    "padding_lanes_mid_tile": (50, 64, 37, 32, (16, 16)),
+    "one_query": (130, 1, None, 64, (32, 16)),
+    "one_real_query_of_a_window": (130, 32, 1, 64, (32, 16)),
+    "the_codes_own_tile": (700, 600, None, 1024, None),
+    "the_codes_own_tile_padded": (1024, 512, 100, 1024, None),
+}
+
+
+@pytest.mark.parametrize("case", WINDOW_CASES)
+def test_window_form_equals_plain_masked_softmax(case, monkeypatch):
+    """``latent_window_attention`` (interpret mode) against a plain
+    masked softmax, over windows that reach each kind of tile and each
+    edge.  What lies past the last REAL query is NaN; a padding lane's
+    output is finite, and nothing more is asked of it."""
+    first, w, n_valid, key_block, tile = WINDOW_CASES[case]
+    if tile is not None:
+        monkeypatch.setattr(attention_mod, "WINDOW_TILE", tile)
+    rng = np.random.default_rng(len(case))
+    h, dn, dr, dv, rank = 2, 16, 8, 16, 32
+    n_real = w if n_valid is None else n_valid
+    pos = first + np.arange(w)
+    keys = -(-(first + w) // key_block) * key_block
+    lat = rng.standard_normal((keys, rank + dr)).astype(np.float32)
+    lat[first + n_real:] = np.nan
+    q_nope, q_rope, w_uk, w_uv = (
+        rng.standard_normal(s).astype(np.float32)
+        for s in ((w, h, dn), (w, h, dr), (h, rank, dn), (h, rank, dv)))
+    tiles = attention_mod.window_tiles(first, n_real, w, key_block)
+    got = np.asarray(attention_mod.latent_window_attention(
+        jnp.asarray(q_nope), jnp.asarray(q_rope),
+        lambda j, k: jax.lax.dynamic_slice_in_dim(jnp.asarray(lat),
+                                                  j * k, k),
+        jnp.asarray(w_uk), jnp.asarray(w_uv), jnp.asarray(pos), scale=0.2,
+        key_block=key_block,
+        n_valid=None if n_valid is None else jnp.asarray(n_valid)))
+    assert got.shape == (w, h * dv) and np.isfinite(got).all()
+    want = _window_oracle(q_nope[:n_real], q_rope[:n_real], lat, w_uk, w_uv,
+                          pos[:n_real], 0.2)
+    np.testing.assert_allclose(got[:n_real], want, atol=2e-5, rtol=2e-5)
+    # the case reaches what its name says
+    if "padding" in case or "one_real" in case:
+        assert tiles["unseen"] > 0
+    if case in ("start_off_tile_and_block", "three_key_blocks_and_more",
+                "the_codes_own_tile", "the_codes_own_tile_padded"):
+        assert min(tiles["plain"], tiles["diagonal"], tiles["unseen"]) > 0
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_tile_kinds_cover_the_walk_and_every_credited_pair(seed,
+                                                           monkeypatch):
+    """The classification the kernel makes, on the host
+    (``window_tiles``): the three kinds are all of the walk's tiles, a
+    credited (query, key) pair lies in a tile that is not skipped, a
+    plain tile holds no masked pair, and the walked pairs are the sizes
+    of the tiles not skipped."""
+    rng = np.random.default_rng(seed)
+    tile = [(32, 16), (16, 32), (64, 64), (8, 8), (48, 16), (32, 128)][seed]
+    monkeypatch.setattr(attention_mod, "WINDOW_TILE", tile)
+    for _ in range(20):
+        key_block = int(rng.choice([32, 64, 96]))
+        w = int(rng.integers(1, 100))
+        pos, n_q = int(rng.integers(0, 300)), int(rng.integers(1, w + 1))
+        got = attention_mod.window_tiles(pos, n_q, w, key_block)
+        q_spans = attention_mod._spans(w, tile[1])
+        k_spans = [(j * key_block + a, n)
+                   for j in range((pos + n_q - 1) // key_block + 1)
+                   for a, n in attention_mod._spans(key_block, tile[0])]
+        assert got["unseen"] + got["plain"] + got["diagonal"] \
+            == len(q_spans) * len(k_spans)
+        walked = credited = 0
+        for q0, tq in q_spans:
+            lanes = pos + q0 + np.arange(tq)
+            real = lanes[:max(min(q0 + tq, n_q) - q0, 0)]
+            for k0, tk in k_spans:
+                keys = k0 + np.arange(tk)
+                seen = keys[:, None] <= lanes[None, :]
+                pairs = int(seen[:, :len(real)].sum())
+                unseen, plain = attention_mod._tile_kind(
+                    k0, k0 + tk - 1, lanes[0],
+                    real[-1] if len(real) else -1)
+                assert not (unseen and plain)
+                assert (pairs == 0) == bool(unseen)
+                assert (seen.all() and pairs > 0) == bool(plain)
+                credited += pairs
+                walked += 0 if unseen else tk * tq
+        assert credited == n_q * pos + n_q * (n_q + 1) // 2
+        assert got["pairs"] == walked >= credited
+
+
 # ------------------------------------------------------------ the engine
 
 def _margins(params, prompt, emitted):
@@ -542,6 +658,34 @@ def test_engine_cold_then_adopted_prefix_equal_the_reference(cfg, params):
         assert cold.submit(doc + q2, max_new=8).result(timeout=300) == b
     finally:
         cold.shutdown()
+
+
+def test_engine_counts_the_tiles_its_window_kernel_walks(cfg, params):
+    """``chunk_pairs_walked`` / ``chunk_tiles_*`` are the classifier's
+    sums over the chunk passes the engine ran (a cold prompt of 21
+    tokens in chunks of 8, then 5 more behind 16 adopted ones), a head
+    and layer: what the kernel walks is never less than what is
+    credited."""
+    doc = _tokens(21, 21).tolist()
+    eng = _engine(cfg, params)
+    try:
+        eng.submit(doc, max_new=2).result(timeout=300)
+        eng.submit(doc[:16] + _tokens(5, 22).tolist(),
+                   max_new=2).result(timeout=300)
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    assert st["chunk_passes"] == 4 and st["prefix_hit_tokens"] == 16
+    tiles = [attention_mod.window_tiles(pos, n_q, 8, 1024)
+             for pos, n_q in ((0, 8), (8, 8), (16, 5), (16, 5))]
+    for key, name in (("chunk_pairs_walked", "pairs"),
+                      ("chunk_tiles_plain", "plain"),
+                      ("chunk_tiles_diagonal", "diagonal")):
+        assert st[key] == sum(t[name] for t in tiles), key
+    # one block of 1,024 keys in two tiles of 512: the first crossed by
+    # the causal edge, the second past every query
+    assert tiles[0] == dict(unseen=1, plain=0, diagonal=1, pairs=512 * 8)
+    assert st["chunk_pairs_walked"] >= st["chunk_query_keys"] > 0
 
 
 def test_engine_rows_admitted_at_different_times(cfg, params):
