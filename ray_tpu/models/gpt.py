@@ -71,12 +71,12 @@ class GPTConfig:
     moe_aux_weight: float = 0.01     # load-balance aux loss coefficient
 
     def __post_init__(self):
-        if self.remat_policy not in (None, "dots", "dots_flash"):
+        if self.remat_policy not in (None, "dots"):
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r}; expected "
-                "None (full recompute), 'dots', or 'dots_flash' (dots + "
-                "saved flash-attention out/lse so the backward pass never "
-                "re-runs the attention forward kernel)")
+                "None (full recompute) or 'dots' (matmul outputs and the "
+                "fused attention's output + logsumexp are kept, so the "
+                "backward pass re-runs no matmul and no attention forward)")
         if self.n_experts:
             if not 1 <= self.expert_top_k <= self.n_experts:
                 raise ValueError(
@@ -302,14 +302,9 @@ def _attend(q, k, v, cfg: GPTConfig, mesh: Optional[Mesh], rules: Rules):
         ring = partial(ring_attention, axis_name="sp", causal=True)
         return shard_map(ring, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
-    # dots_flash: the lse-exposing flash variant names its outputs
-    # (flash_out/flash_lse) inside its vjp, so the scan's checkpoint
-    # policy saves them and the backward pass reconstructs the layer
-    # without re-running the attention forward kernel
     return attention(q, k, v, causal=True, impl=cfg.attn_impl,
                      block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
-                     mesh=mesh, spec=spec,
-                     save_lse=cfg.remat_policy == "dots_flash")
+                     mesh=mesh, spec=spec)
 
 
 def _moe_mlp(y, lp, cfg: GPTConfig, mesh: Optional[Mesh], rules: Rules):
@@ -441,6 +436,24 @@ def _transformer_layer(x, lp, cfg: GPTConfig, mesh: Optional[Mesh],
     return x, aux
 
 
+def _checkpoint_policy(remat_policy: Optional[str]):
+    """What a rematerialised layer keeps for its backward pass (names
+    validated at GPTConfig construction).  None keeps nothing.  "dots"
+    keeps matmul outputs and recomputes only the cheap elementwise/norm
+    work — a fraction of full-remat's extra FLOPs for modest activation
+    memory (the policy knob the scaling playbook recommends).  The fused
+    attention is the layer's largest product and no ``dot`` to that
+    policy, so what its forward rule names (ops/flash_attention.py: the
+    output and one float32 a row of logsumexp) is kept by name beside
+    them: the backward scan then runs no attention forward kernel."""
+    if remat_policy is None:
+        return None
+    cp = jax.checkpoint_policies
+    return cp.save_from_both_policies(
+        cp.dots_with_no_batch_dims_saveable,
+        cp.save_only_these_names("flash_out", "flash_lse"))
+
+
 def _layer_scan_body(cfg: GPTConfig, mesh, rules, return_kv: bool = False):
     """Scan body over a stacked layer dim, rematerialized per cfg: the
     block over a whole sequence.  Carry is (x, accumulated moe aux
@@ -459,23 +472,8 @@ def _layer_scan_body(cfg: GPTConfig, mesh, rules, return_kv: bool = False):
         return (x, aux + a), None
 
     if cfg.remat:
-        # "dots" keeps matmul outputs and recomputes only the cheap
-        # elementwise/norm work in the backward pass — a fraction of
-        # full-remat's extra FLOPs for modest activation memory
-        # (the policy knob the scaling playbook recommends; validated
-        # at GPTConfig construction).  "dots_flash" additionally saves
-        # the named flash-attention outputs so the backward never
-        # re-runs the attention forward kernel.
-        cp = jax.checkpoint_policies
-        if cfg.remat_policy == "dots":
-            policy = cp.dots_with_no_batch_dims_saveable
-        elif cfg.remat_policy == "dots_flash":
-            policy = cp.save_from_both_policies(
-                cp.dots_with_no_batch_dims_saveable,
-                cp.save_only_these_names("flash_out", "flash_lse"))
-        else:
-            policy = None
-        return jax.checkpoint(layer, policy=policy)
+        return jax.checkpoint(layer,
+                              policy=_checkpoint_policy(cfg.remat_policy))
     return layer
 
 

@@ -986,8 +986,7 @@ def attention(q, k, v, *, causal: bool = True,
               impl: Optional[str] = None,
               block_q: int = 512, block_k: int = 512,
               mesh: Optional[Mesh] = None,
-              spec: Optional[PartitionSpec] = None,
-              save_lse: bool = False) -> jax.Array:
+              spec: Optional[PartitionSpec] = None) -> jax.Array:
     """Dispatching multi-head attention, [batch, heads, seq, head_dim].
 
     impl: "flash" (pallas TPU kernel), "reference", or None = auto
@@ -999,11 +998,9 @@ def attention(q, k, v, *, causal: bool = True,
     ``mesh`` + ``spec`` (the PartitionSpec of q/k/v on that mesh, seq
     unsharded): the flash kernel then runs per shard under shard_map —
     the reference and xla_fused impls are plain XLA and partition on
-    their own.  ``save_lse`` picks the lse-exposing flash variant whose
-    named outputs a ``dots_flash`` checkpoint policy saves.
+    their own.
     """
-    from ray_tpu.ops.flash_attention import (flash_attention,
-                                             flash_attention_with_lse)
+    from ray_tpu.ops.flash_attention import flash_attention
 
     if impl is None:
         tile_ok = (q.shape[-2] % 128 == 0 and k.shape[-2] % 128 == 0
@@ -1016,15 +1013,8 @@ def attention(q, k, v, *, causal: bool = True,
             raise ValueError(
                 "flash impl has no custom-mask / kv_lengths support; use "
                 "impl='reference' (causal masking is built in)")
-        kw = dict(causal=causal, scale=scale, block_q=block_q,
-                  block_k=block_k)
-        if save_lse:
-            # the lse itself is only a checkpoint-policy save target
-            # (named inside the kernel's vjp); callers get ``out``
-            def fn(q, k, v):
-                return flash_attention_with_lse(q, k, v, **kw)[0]
-        else:
-            fn = functools.partial(flash_attention, **kw)
+        fn = functools.partial(flash_attention, causal=causal, scale=scale,
+                               block_q=block_q, block_k=block_k)
         if mesh is not None and mesh.size > 1:
             if spec is None:
                 raise ValueError("flash attention under a mesh needs the "

@@ -8,10 +8,14 @@ prunes the kv loop to the lower triangle.
 
 Backward is a custom VJP that recomputes probabilities block-by-block from
 the saved logsumexp (the standard flash trade: extra FLOPs for O(s·block)
-memory).  On block-aligned shapes it runs as two fused pallas kernels —
-one grid pass over kv blocks producing dk/dv, one over q blocks producing
-dq — with bf16 matmul operands and f32 accumulation; ragged shapes fall
-back to a plain-jax scan that XLA fuses.
+memory).  What the forward rule keeps for it is q, k, v, the output and
+ONE float32 a query row of logsumexp, the last two named ``flash_out`` /
+``flash_lse`` for a ``jax.checkpoint`` policy to keep (models/gpt.py,
+remat_policy="dots"): a layer rematerialised under such a policy then
+runs no forward kernel in its backward pass.  On block-aligned shapes it
+runs as two fused pallas kernels — one grid pass over kv blocks producing
+dk/dv, one over q blocks producing dq — with bf16 matmul operands and f32
+accumulation; ragged shapes fall back to a plain-jax scan that XLA fuses.
 
 Reference capability context: the reference framework has no fused
 attention of its own (it rides torch/CUDA kernels); this is the TPU-native
@@ -26,13 +30,33 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
-# lse/delta ride VMEM broadcast across one full lane register, the same
-# convention as jax's reference TPU flash kernel (MIN_BLOCK_SIZE lanes):
-# scalar-per-row vectors are awkward on the VPU, a [rows, 128] tile is not.
+# Inside a kernel lse/delta ride broadcast across one full lane register,
+# the same convention as jax's reference TPU flash kernel (MIN_BLOCK_SIZE
+# lanes): scalar-per-row vectors are awkward on the VPU, a [rows, 128]
+# tile is not.  In HBM they are ONE float32 a row, [b*h, 1, seq]: the
+# kernels turn a [1, rows] block into the tile and back (a transpose on
+# the XLU), so neither the residual a layer keeps nor what the backward
+# kernels stream a grid step is 128 copies of a number.
 LANES = 128
+
+
+def _lanes_to_row(col):
+    """[rows, 1] -> [1, rows]: a per-row statistic turned along the
+    lanes, the form it is stored in (one float32 a row, not 128)."""
+    rows = col.shape[0]
+    return jax.lax.broadcast_in_dim(col[:, 0], (rows, LANES), (0,)).T[:1, :]
+
+
+def _row_to_lanes(row_ref):
+    """[1, rows] ref -> [rows, LANES]: the stored row turned back into
+    the column the [rows, block_k] score tiles subtract, broadcast over
+    one lane register (``jnp.tile`` carries it across the rest)."""
+    rows = row_ref.shape[1]
+    return jnp.broadcast_to(row_ref[...], (LANES, rows)).T
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse,
@@ -93,9 +117,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *maybe_lse,
         # pallas body is opaque to XLA, so an unused output would not be
         # dead-code-eliminated)
         l_ref, = maybe_lse
-        lse = m + jnp.log(l)  # [bq, 1]
-        l_ref[...] = jax.lax.broadcast_in_dim(
-            lse[:, 0], l_ref.shape, (0,))
+        l_ref[...] = _lanes_to_row(m + jnp.log(l))   # [1, bq]
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, need_lse=False):
@@ -119,8 +141,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, need_lse=False):
                                block_k=block_k, kv_len=kv_len, q_len=sq)
     o_spec = pl.BlockSpec((None, block_q, d), lambda bh, qi: (bh, qi, 0))
     o_shape = jax.ShapeDtypeStruct((b * h, sq, d), q.dtype)
-    lse_spec = pl.BlockSpec((None, block_q, LANES), lambda bh, qi: (bh, qi, 0))
-    lse_shape = jax.ShapeDtypeStruct((b * h, sq, LANES), jnp.float32)
+    lse_spec = pl.BlockSpec((None, 1, block_q), lambda bh, qi: (bh, 0, qi))
+    lse_shape = jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32)
     res = pl.pallas_call(
         kernel,
         grid=grid,
@@ -135,7 +157,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, need_lse=False):
         name="flash_fwd",
     )(qf, kf, vf)
     out = res[0].reshape(b, h, sq, d)
-    return (out, res[1]) if need_lse else (out, None)
+    return (out, res[1][:, 0]) if need_lse else (out, None)
 
 
 def _interpret_mode() -> bool:
@@ -169,6 +191,12 @@ def _fwd_rule(q, k, v, scale, causal, block_q, block_k):
     s = (q.shape[-1] ** -0.5) if scale is None else scale
     out, lse = _flash_fwd(q, k, v, s, causal, block_q, block_k,
                           need_lse=True)
+    # the two residuals the forward made itself are named, which costs
+    # nothing where no checkpoint policy reads the names.  The primal
+    # output IS the named value, so nothing downstream asks the kernel
+    # for it again.
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")               # [b*h, sq] f32
     return out, (q, k, v, out, lse)
 
 
@@ -226,7 +254,8 @@ def _bwd_kv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         qj = q_ref[...]       # [bq, d] model dtype
         doj = do_ref[...]
         p, ds = _recompute_p_ds(
-            qj, doj, k_ref[...], v_ref[...], lse_ref[...], delta_ref[...],
+            qj, doj, k_ref[...], v_ref[...], _row_to_lanes(lse_ref),
+            _row_to_lanes(delta_ref),
             row0=j * block_q + off, col0=ki * block_k,
             scale=scale, causal=causal)
         # dv += pᵀ @ do
@@ -268,8 +297,8 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     def _accumulate():
         kj = k_ref[...]         # [bk, d]
         _, ds = _recompute_p_ds(
-            q_ref[...], do_ref[...], kj, v_ref[...], lse_ref[...],
-            delta_ref[...],
+            q_ref[...], do_ref[...], kj, v_ref[...], _row_to_lanes(lse_ref),
+            _row_to_lanes(delta_ref),
             row0=qi * block_q + off, col0=j * block_k,
             scale=scale, causal=causal)
         dq_acc[...] += jax.lax.dot_general(
@@ -295,12 +324,12 @@ def _bwd_pallas(scale, causal, bq, bk, res, do):
     kf = k.reshape(bh, kv_len, d)
     vf = v.reshape(bh, kv_len, d)
     dof = do.reshape(bh, sq, d)
-    # delta_i = Σ_d do·o — cheap rowwise reduce, XLA fuses it; broadcast
-    # across lanes to match the lse layout.
+    # delta_i = Σ_d do·o — cheap rowwise reduce, XLA fuses it; like lse
+    # one float32 a row, handed over as [bh, 1, sq]
     delta = jnp.sum(dof.astype(jnp.float32)
                     * out.reshape(bh, sq, d).astype(jnp.float32),
-                    axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], (bh, sq, LANES))
+                    axis=-1)[:, None, :]
+    lse = lse[:, None, :]
 
     interpret = _interpret_mode()
     # the innermost grid dim revisits the same output block (accumulation)
@@ -309,14 +338,14 @@ def _bwd_pallas(scale, causal, bq, bk, res, do):
 
     # grid (bh, ki, j): q/do/lse/delta stream along j, k/v pinned by ki
     q_j = pl.BlockSpec((None, bq, d), lambda g, ki, j: (g, j, 0))
-    lane_j = pl.BlockSpec((None, bq, LANES), lambda g, ki, j: (g, j, 0))
+    row_j = pl.BlockSpec((None, 1, bq), lambda g, ki, j: (g, 0, j))
     kv_ki = pl.BlockSpec((None, bk, d), lambda g, ki, j: (g, ki, 0))
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_kv_kernel, scale=scale, causal=causal,
                           nq=nq, q_len=sq, kv_len=kv_len),
         grid=(bh, nk, nq),
-        in_specs=[q_j, q_j, lane_j, lane_j, kv_ki, kv_ki],
+        in_specs=[q_j, q_j, row_j, row_j, kv_ki, kv_ki],
         out_specs=[kv_ki, kv_ki],
         out_shape=[jax.ShapeDtypeStruct((bh, kv_len, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, kv_len, d), v.dtype)],
@@ -329,14 +358,14 @@ def _bwd_pallas(scale, causal, bq, bk, res, do):
 
     # grid (bh, qi, j): k/v stream along j, q/do/lse/delta pinned by qi
     q_qi = pl.BlockSpec((None, bq, d), lambda g, qi, j: (g, qi, 0))
-    lane_qi = pl.BlockSpec((None, bq, LANES), lambda g, qi, j: (g, qi, 0))
+    row_qi = pl.BlockSpec((None, 1, bq), lambda g, qi, j: (g, 0, qi))
     kv_j = pl.BlockSpec((None, bk, d), lambda g, qi, j: (g, j, 0))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           nk=nk, q_len=sq, kv_len=kv_len),
         grid=(bh, nq, nk),
-        in_specs=[q_qi, q_qi, lane_qi, lane_qi, kv_j, kv_j],
+        in_specs=[q_qi, q_qi, row_qi, row_qi, kv_j, kv_j],
         out_specs=q_qi,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -350,7 +379,7 @@ def _bwd_pallas(scale, causal, bq, bk, res, do):
 
 
 def _bwd_rule(scale, causal, block_q, block_k, res, do):
-    q, k, v, out, lse_lanes = res
+    q, k, v, out, lse = res
     s = (q.shape[-1] ** -0.5) if scale is None else scale
     b, h, sq, d = q.shape
     kv_len = k.shape[2]
@@ -377,7 +406,7 @@ def _bwd_rule(scale, causal, block_q, block_k, res, do):
     kb = k.reshape(b, h, nk, bk, d)
     vb = v.reshape(b, h, nk, bk, d)
 
-    lse = lse_lanes[..., 0].reshape(b, h, sq)
+    lse = lse.reshape(b, h, sq)
 
     def kv_step(dq, j):
         kj = kb[:, :, j]  # [b,h,bk,d]
@@ -408,57 +437,3 @@ def _bwd_rule(scale, causal, block_q, block_k, res, do):
 
 
 _flash.defvjp(_fwd_rule, _bwd_rule)
-
-
-# -- lse-exposing variant ---------------------------------------------------
-#
-# Same kernel, but the log-sum-exp rides out as a PRIMAL output.  Under
-# jax.checkpoint, naming (out, lse) via jax.ad_checkpoint.checkpoint_name
-# lets a save_only_these_names policy keep both, so the backward pass
-# reconstructs the layer without re-running the flash forward kernel
-# (models/gpt.py remat_policy="dots_flash").
-
-
-def _named(out, lse):
-    from jax.ad_checkpoint import checkpoint_name
-    return (checkpoint_name(out, "flash_out"),
-            checkpoint_name(lse, "flash_lse"))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse(q, k, v, scale, causal, block_q, block_k):
-    s = (q.shape[-1] ** -0.5) if scale is None else scale
-    out, lse = _flash_fwd(q, k, v, s, causal, block_q, block_k,
-                          need_lse=True)
-    return _named(out, lse)
-
-
-def _fwd_rule_lse(q, k, v, scale, causal, block_q, block_k):
-    s = (q.shape[-1] ** -0.5) if scale is None else scale
-    out, lse = _flash_fwd(q, k, v, s, causal, block_q, block_k,
-                          need_lse=True)
-    # residuals ARE the named values: a save_only_these_names policy then
-    # keeps exactly what the backward kernel needs, and the recompute
-    # graph dead-code-eliminates the forward kernel call
-    out, lse = _named(out, lse)
-    return (out, lse), (q, k, v, out, lse)
-
-
-def _bwd_rule_lse(scale, causal, block_q, block_k, res, g):
-    do, _dlse = g   # lse is an auxiliary output; its cotangent is unused
-    return _bwd_rule(scale, causal, block_q, block_k, res, do)
-
-
-_flash_lse.defvjp(_fwd_rule_lse, _bwd_rule_lse)
-
-
-def flash_attention_with_lse(q, k, v, *, scale: Optional[float] = None,
-                             causal: bool = True, block_q: int = 512,
-                             block_k: int = 512):
-    """Fused attention returning (out, lse); [b, h, s, d] layout.
-
-    lse is a NON-DIFFERENTIABLE auxiliary output (stop_gradient): it
-    exists for checkpoint-policy saves and inference-side diagnostics.
-    A z-loss-style term on lse needs its own differentiable path."""
-    out, lse = _flash_lse(q, k, v, scale, causal, block_q, block_k)
-    return out, jax.lax.stop_gradient(lse)

@@ -106,28 +106,70 @@ def test_flash_forward_backward_compiles(qkv_one_chip):
     _compile(_sum_grad(flash_mod.flash_attention), x, x, x)
 
 
-def test_flash_lse_variant_compiles(qkv_one_chip):
-    x = qkv_one_chip
-    _compile(_sum_grad(
-        lambda q, k, v: flash_mod.flash_attention_with_lse(q, k, v)[0]),
-        x, x, x)
+def _flash_calls_by_computation(text):
+    """{computation: [result type of each Mosaic call in it]} of a
+    compiled program's text; a computation with none is left out."""
+    calls, name = {}, None
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            m = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+            name = m.group(1) if m else None
+        elif name and 'custom_call_target="tpu_custom_call"' in line:
+            calls.setdefault(name, []).append(
+                line.split(" = ", 1)[1].split(" custom-call(")[0])
+    return calls
 
 
-@pytest.mark.parametrize("remat_policy", ["dots", "dots_flash"])
-def test_attend_compiles_under_dp_tp_mesh(mesh_2x2, remat_policy):
+@pytest.mark.parametrize("remat_policy, n_calls", [("dots", 3), (None, 4)])
+def test_step_holds_its_flash_calls_once(one_chip, remat_policy, n_calls):
+    """``jax.grad(gpt.loss_fn)`` at 124M width, 2 layers.  Under
+    ``remat_policy="dots"`` the layer keeps what the flash forward rule
+    names, so the backward scan's body holds the two backward kernels
+    and NO forward (the one call that writes a float32 logsumexp, one
+    number a query row): three Mosaic calls a layer.  Full recompute
+    (None) reads no name and runs the forward again: four, the
+    control."""
+    cfg = gpt.GPTConfig.gpt2_124m(n_layers=2, remat=True,
+                                  remat_policy=remat_policy)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: gpt.init_params(cfg, jax.random.PRNGKey(0))))
+    batch = {"tokens": jax.ShapeDtypeStruct((QKV[0], cfg.max_seq + 1),
+                                            jnp.int32, sharding=one_chip)}
+    calls = _flash_calls_by_computation(_compile(
+        jax.grad(lambda p, b: gpt.loss_fn(p, b, cfg)), params, batch))
+    bh = QKV[0] * QKV[1]
+    # the forward is the call with a float32 result, the backward pair
+    # gives bf16 only: dq, and (dk, dv) as a tuple
+    is_fwd = [[f"f32[{bh},1,{QKV[2]}]" in c for c in body]
+              for body in calls.values()]
+    assert sorted(map(len, is_fwd)) == [1, n_calls - 1], calls
+    fwd_scan, bwd_scan = sorted(is_fwd, key=len)
+    assert fwd_scan == [True]
+    assert sum(bwd_scan) == n_calls - 3, calls
+    for body in calls.values():
+        assert all(f"bf16[{bh},{QKV[2]},{QKV[3]}]" in c for c in body), calls
+
+
+def test_attend_compiles_under_dp_tp_mesh(mesh_2x2):
     """The partitioner refuses a bare Mosaic call under a mesh
     ("cannot be automatically partitioned"); gpt._attend must hand it
-    over per shard.  q/k/v sharded batch->dp, heads->tp."""
-    cfg = gpt.GPTConfig.gpt2_124m(remat=True, remat_policy=remat_policy)
+    over per shard.  q/k/v sharded batch->dp, heads->tp.  The residuals
+    the forward rule names are per shard then, and the layer's
+    checkpoint policy still finds them: one forward call, not two."""
+    cfg = gpt.GPTConfig.gpt2_124m(remat=True, remat_policy="dots")
     spec = spec_for(("batch", "heads", "seq", "kv"), DEFAULT_LLM_RULES,
                     mesh_2x2)
     x = jax.ShapeDtypeStruct(QKV, jnp.bfloat16,
                              sharding=NamedSharding(mesh_2x2, spec))
-    text = _compile(_sum_grad(
+    text = _compile(_sum_grad(jax.checkpoint(
         lambda q, k, v: gpt._attend(q, k, v, cfg, mesh_2x2,
-                                    DEFAULT_LLM_RULES)), x, x, x)
+                                    DEFAULT_LLM_RULES),
+        policy=gpt._checkpoint_policy(cfg.remat_policy))), x, x, x)
     # per shard: 16/2 batch rows x 12/2 heads
     assert "bf16[8,6,1024,64]" in text
+    calls = sum(_flash_calls_by_computation(text).values(), [])
+    assert len(calls) == 3 and sum("f32[" in c for c in calls) == 1, calls
 
 
 def test_loss_grad_compiles_under_pp_dp_mesh(topo, for_tpu):

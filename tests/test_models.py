@@ -91,6 +91,14 @@ def test_gpt_flash_under_mesh_matches_reference(axes):
         np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-5), g_fl, g_ref)
 
 
+def test_gpt_remat_policy_names():
+    """One name for one behaviour: None (full recompute) or "dots"."""
+    assert gpt._checkpoint_policy(None) is None
+    assert callable(gpt._checkpoint_policy("dots"))
+    with pytest.raises(ValueError, match="unknown remat_policy"):
+        gpt.GPTConfig.tiny(remat_policy="dots_and_more")
+
+
 def test_gpt_generate(tiny_cfg, tiny_params):
     prompt = jnp.array([[1, 2, 3]], jnp.int32)
     out = gpt.generate(tiny_params, tiny_cfg, prompt, max_new=5,

@@ -221,3 +221,66 @@ def test_attention_mask_flash_raises():
     mask = jnp.ones((1, 1, 8, 8), bool)
     with _pytest.raises(ValueError):
         attention(q, q, q, mask=mask, impl="flash")
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_forward_rule_keeps_one_lse_a_row(dtype):
+    """What the backward is handed and no more: q, k, v, the output in
+    the inputs' dtype and ONE float32 a query row of logsumexp (the
+    backward kernels turn a block of it into their lane tile)."""
+    import importlib
+    # the module, not the same-named function ray_tpu.ops re-exports
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    b, h, sq, skv, d = 2, 3, 128, 256, 64
+    q = jax.ShapeDtypeStruct((b, h, sq, d), dtype)
+    kv = jax.ShapeDtypeStruct((b, h, skv, d), dtype)
+    out, res = jax.eval_shape(
+        lambda q, k, v: fa._fwd_rule(q, k, v, None, True, 128, 128),
+        q, kv, kv)
+    assert (out.shape, out.dtype) == ((b, h, sq, d), dtype)
+    assert [(r.shape, r.dtype) for r in res] == [
+        ((b, h, sq, d), dtype), ((b, h, skv, d), dtype),
+        ((b, h, skv, d), dtype), ((b, h, sq, d), dtype),
+        ((b * h, sq), jnp.float32)]
+
+
+@pytest.mark.parametrize("sq, skv, block", [
+    (256, 256, 128),      # block-aligned: the fused pallas backward
+    (200, 200, 128),      # ragged: the plain-jax backward
+    (128, 384, 128),      # cross-length causal (q at the tail of kv)
+])
+def test_flash_grads_under_dots_checkpoint(sq, skv, block, capsys):
+    """A layer rematerialised under the policy gpt's "dots" builds keeps
+    the flash forward's named residuals: its gradients are those of the
+    layer with no checkpoint, and of the flash call the forward pass
+    saves the two named values alone."""
+    from jax.ad_checkpoint import print_saved_residuals
+    from ray_tpu.models.gpt import _checkpoint_policy
+    b, h, d = 1, 2, 64
+    kx, ky, kw = jax.random.split(jax.random.PRNGKey(11), 3)
+    x = jax.random.normal(kx, (b, sq, h * d))
+    y = jax.random.normal(ky, (b, skv, h * d))
+    w = jax.random.normal(kw, (4, h * d, h * d)) * (h * d) ** -0.5
+
+    def heads(t):
+        return t.reshape(b, -1, h, d).transpose(0, 2, 1, 3)
+
+    def layer(x, y, w):
+        o = flash_attention(heads(x @ w[0]), heads(y @ w[1]),
+                            heads(y @ w[2]), causal=True,
+                            block_q=block, block_k=block)
+        return jnp.sum((o.transpose(0, 2, 1, 3).reshape(b, sq, h * d)
+                        @ w[3]) ** 2)
+
+    kept = jax.checkpoint(layer, policy=_checkpoint_policy("dots"))
+    g_plain = jax.grad(layer, argnums=(0, 1, 2))(x, y, w)
+    g_kept = jax.grad(kept, argnums=(0, 1, 2))(x, y, w)
+    for a, b_ in zip(g_plain, g_kept):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), **TOL)
+    capsys.readouterr()
+    print_saved_residuals(kept, x, y, w)
+    saved = [line for line in capsys.readouterr().out.splitlines()
+             if "(flash_attention)" in line]
+    # (a value saved for a name shows as the policy's own marker op)
+    assert [line.split()[0] for line in saved] == [
+        f"f32[{b},{h},{sq},{d}]", f"f32[{b * h},{sq}]"], saved
