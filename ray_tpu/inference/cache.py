@@ -26,6 +26,13 @@ whose prompt head matches a cached prefix ADOPTS those blocks by
 refcount instead of re-running prefill (SGLang's RadixAttention shape).
 Unreferenced cached prefixes are LRU-evicted under pool pressure.
 
+``StatePool`` is what a model's recurrent layers keep a decode row.
+Where that state is small enough to keep a BLOCK (``snapshot_geometry``:
+a state that is nothing but a convolution's last inputs), the pool also
+holds the state at each block's END, indexed by the block's id: a chain
+of blocks then carries its state, and the index adopts across the
+recurrent layers too, with no second structure.
+
 Array updates go through jitted helpers (block write, block copy,
 pool swap) so the engine loop never materializes a second full pool on
 the host.
@@ -342,7 +349,7 @@ class BlockPool:
         # the second kind of state: what a model's recurrent layers keep
         # per decode row, beside the row's blocks (None for a model whose
         # whole past is K/V)
-        self.state = (StatePool(cfg, state_rows)
+        self.state = (StatePool(cfg, state_rows, self.n_blocks)
                       if cfg.state_geometry is not None and not _window
                       else None)
         # ... and the second kind of K/V state: the window layers' pool
@@ -459,7 +466,10 @@ class BlockPool:
         return (self.k,) if self.v is None else (self.k, self.v)
 
     def copy_block(self, src: int, dst: int) -> None:
-        """Copy-on-write: duplicate src's K/V into dst (every pool)."""
+        """Copy-on-write: duplicate src's K/V into dst (every pool).
+        (A block's state snapshot does not ride along: only a FULL block
+        has one, and a full block is never written again — where a
+        cache keeps snapshots the index shares full blocks only.)"""
         self.swap(*_copy_block(self.layout, self._own, jnp.int32(src),
                                jnp.int32(dst)))
 
@@ -513,11 +523,22 @@ class BlockPool:
 
     def _two_pools(self, what: str) -> None:
         """The interchange format ``[L, T, h, bs, hd]`` is a K and a V
-        of head lanes: a pool of latents has none yet."""
+        of head lanes: a pool of latents has none yet, nor has a chain
+        that carries state snapshots."""
         if self.v is None:
             raise NotImplementedError(
                 f"{what}: no interchange format for a pool whose values "
                 f"are a view of its keys")
+        if self.snapshots:
+            raise NotImplementedError(
+                f"{what}: no interchange format for blocks that carry a "
+                f"state snapshot")
+
+    @property
+    def snapshots(self) -> bool:
+        """Whether every full block carries the recurrent state at its
+        end (``StatePool.snap``, indexed by block id)."""
+        return self.state is not None and self.state.snap is not None
 
     def swap(self, k: jax.Array, v: Optional[jax.Array] = None,
              *window) -> None:
@@ -573,6 +594,8 @@ class BlockPool:
         return {
             "state_bytes": self.state_bytes(),
             "state_rows_in_use": self.state_rows_in_use,
+            "state_snapshot_bytes": (self.state.snapshot_bytes()
+                                     if self.state is not None else 0),
             "block_size": self.block_size,
             # blocks are replicated in COUNT across tp shards (heads are
             # what's split), so blocks_total is simultaneously the
@@ -607,66 +630,142 @@ def _zero_row(conv, ssm, row):
             ssm.at[:, row].set(jnp.zeros((), ssm.dtype)))
 
 
-class StatePool:
-    """Per-row recurrent state of a model's state-space or
-    linear-attention layers: the second kind of serving state, owned by
-    the ``BlockPool`` whose blocks hold the attention layers' K/V.
+@partial(jax.jit, donate_argnums=(0,))
+def _restore_row(conv, snap, row, block):
+    """Decode row ``row`` <- the state at the END of block ``block``,
+    every layer; block 0 (no block adopted): zeros.  ONE program for a
+    cold admission and an adoption alike, in ``_zero_row``'s place."""
+    kept = snap[block].reshape(conv.shape[0], *conv.shape[2:])
+    return conv.at[:, row].set(jnp.where(block > 0, kept, 0))
 
-    Two preallocated arrays ``[recurrent layers, rows, ...]``, their
-    shapes the model's (``cfg.state_geometry``) — ``conv`` (the causal
-    convolution's last inputs, in the activation dtype) and ``ssm``
-    (float32: Mamba-2's selective-scan state ``[heads * head width,
-    state]``, or the delta rule's matrix state ``[keys, heads *
-    values]``) — indexed by
-    DECODE ROW: a row's state is the fixed-size summary of everything
+
+def snapshot_geometry(cfg):
+    """(layers, lanes a layer) of the state snapshot a block carries, or None
+    where the model's state has no snapshot form.  DERIVED from
+    ``cfg.state_geometry``, no option: a state that is nothing but a
+    convolution's last ``L - 1`` inputs (no recurrent entry) is a few
+    tokens' activations a layer — under one block of the same model's
+    K/V at any block size past a few tokens — and the state after ANY
+    token of a window is a slice of that window's own inputs, so a
+    program keeps it at every block boundary it crosses with no second
+    pass.  An SSM or matrix state is neither small (2-4 MB a layer a
+    row) nor to be had mid-window without stopping the scan there.
+    Stored ``[blocks, layers * (L - 1) * d]``: the block's id leads, as
+    it leads a K/V pool's rows, so that the scatter of the blocks a pass
+    closes writes whole rows in place (indexed behind a layers dim the
+    compiler re-laid the whole array out around every scatter, 604 MB
+    each way in the described-chip compile), and everything behind it is
+    folded into the minor dim: a trailing ``(L - 1, d)`` would be tiled
+    ``(16, 128)`` and take eight times its bytes."""
+    geometry = getattr(cfg, "state_geometry", None)
+    if geometry is None or geometry[2] is not None:
+        return None
+    layers, conv, _ = geometry
+    return layers, int(np.prod(conv))
+
+
+class StatePool:
+    """Per-row recurrent state of a model's state-space,
+    linear-attention or short-convolution layers: the second kind of
+    serving state, owned by the ``BlockPool`` whose blocks hold the
+    attention layers' K/V.
+
+    Preallocated arrays ``[recurrent layers, rows, ...]``, what the
+    model's ``cfg.state_geometry`` says and no more — ``conv`` (the
+    causal convolution's last inputs, in the activation dtype) and,
+    where the geometry has a recurrent entry, ``ssm`` (float32:
+    Mamba-2's selective-scan state ``[heads * head width, state]``, or
+    the delta rule's matrix state ``[keys, heads * values]``; None for
+    the short convolution, whose inputs are its whole state) — indexed
+    by DECODE ROW: a row's state is the fixed-size summary of everything
     the row has read, so there is nothing to page.  Like the K/V pools
-    they are donated to every compiled program and updated in place.  A
+    they are donated to every compiled program and updated in place
+    (``arrays``, in the order a program takes and returns them).  A
     program leaves the state of a row it does not advance exactly as it
     was (ops/ssm.py: ``n_valid`` 0), so no scratch row is needed.
 
-    Lifecycle, with the row's blocks: ``admit(row)`` zeroes the row's
-    state (a prompt starts from nothing), ``release(row)`` drops it —
-    at natural exit and at preemption alike: unlike K/V blocks a state
-    cannot be kept for a prefix (it is the state AFTER the row's last
-    token, not a position-addressed record), so a preempted request
-    re-prefills from zero.
+    SNAPSHOTS.  Where ``snapshot_geometry`` gives the state a snapshot
+    form, ``snap`` ``[n_blocks + 1, layers * lanes]`` holds the state at
+    the END of each full block, indexed by the BLOCK's id (0: the
+    scratch block, where a program's idle writes land): written by the
+    program that writes the block's last token, it is allocated,
+    refcounted, evicted and freed WITH the block — it has no life of
+    its own — and a chain of blocks in the ``RadixIndex`` carries the
+    state after its last token.  ``snap`` is None for the SSM and matrix
+    states: their rows cannot be kept for a prefix, and a preempted
+    request of such a model re-prefills from zero.
+
+    Lifecycle, with the row's blocks: ``admit(row, block)`` sets the
+    row's state to the snapshot of the last ADOPTED block (zeros with
+    none: a prompt starts from nothing), ``release(row)`` drops it — at
+    natural exit and at preemption alike.
     """
 
-    def __init__(self, cfg, n_rows: int):
+    def __init__(self, cfg, n_rows: int, n_blocks: int = 0):
         if n_rows < 1:
             raise ValueError(f"a state pool needs >= 1 row, got {n_rows}")
         layers, conv, ssm = cfg.state_geometry
-        self._shapes = (((layers, n_rows, *conv), cfg.dtype),
-                        ((layers, n_rows, *ssm), jnp.float32))
+        self._shapes = [((layers, n_rows, *conv), cfg.dtype)]
+        if ssm is not None:
+            self._shapes.append(((layers, n_rows, *ssm), jnp.float32))
+        snap = snapshot_geometry(cfg)
+        self._snap_shape = None if snap is None else (
+            (n_blocks + 1, snap[0] * snap[1]), cfg.dtype)
         self._rows: set = set()
         self.reset()
 
     def reset(self) -> None:
         """(Re)allocate zeroed arrays and forget every row (also the
         recovery after a failed program invalidated the donated ones)."""
-        (cs, cd), (ss, sd) = self._shapes
-        self.conv, self.ssm = jnp.zeros(cs, cd), jnp.zeros(ss, sd)
+        zeros = [jnp.zeros(shape, dt) for shape, dt in self._shapes]
+        self.conv = zeros[0]
+        self.ssm = zeros[1] if len(zeros) > 1 else None
+        self.snap = (None if self._snap_shape is None
+                     else jnp.zeros(*self._snap_shape))
         self._rows.clear()
 
-    def admit(self, row: int) -> None:
-        self.conv, self.ssm = _zero_row(self.conv, self.ssm,
-                                        np.int32(row))
+    @property
+    def arrays(self) -> tuple:
+        """What a program is handed, and hands back: ``(conv, ssm)``, or
+        ``(conv, snap)`` of a state with a snapshot form."""
+        return tuple(a for a in (self.conv, self.ssm, self.snap)
+                     if a is not None)
+
+    def admit(self, row: int, block: int = 0) -> None:
+        """``block``: the last block of the chain the row adopted."""
+        if self.snap is not None:
+            self.conv = _restore_row(self.conv, self.snap, np.int32(row),
+                                     np.int32(block))
+        else:
+            self.conv, self.ssm = _zero_row(self.conv, self.ssm,
+                                            np.int32(row))
         self._rows.add(row)
 
     def release(self, row: int) -> None:
         self._rows.discard(row)
 
-    def swap(self, conv: jax.Array, ssm: jax.Array) -> None:
-        """Install a compiled program's updated state arrays."""
-        self.conv, self.ssm = conv, ssm
+    def swap(self, *arrays) -> None:
+        """Install a compiled program's updated state arrays
+        (``arrays``' order)."""
+        if self.snap is not None:
+            self.conv, self.snap = arrays
+        else:
+            self.conv, self.ssm = arrays
 
     @property
     def rows_in_use(self) -> int:
         return len(self._rows)
 
+    def snapshot_bytes(self) -> int:
+        if self._snap_shape is None:
+            return 0
+        shape, dt = self._snap_shape
+        return int(np.prod(shape)) * np.dtype(dt).itemsize
+
     def bytes_total(self) -> int:
+        """The rows' state and the blocks' snapshots."""
         return sum(int(np.prod(shape)) * np.dtype(dt).itemsize
-                   for shape, dt in self._shapes)
+                   for shape, dt in self._shapes) + self.snapshot_bytes()
 
 
 # ---------------------------------------------------------------------------
@@ -704,6 +803,11 @@ class RadixIndex:
       1, i.e. only the trie holds the block); interior nodes become
       evictable once their subtree is gone.
 
+    Over a pool whose blocks carry state snapshots (``pool.snapshots``)
+    a chain is its blocks AND the state after its last token, which
+    only a FULL block has: partial tails are then neither inserted nor
+    matched, and every match ends on a block boundary.
+
     Single-threaded by design: called only from the engine loop thread
     (stats excepted, guarded by the pool's lock via refcounts).
     """
@@ -711,6 +815,7 @@ class RadixIndex:
     def __init__(self, pool: BlockPool):
         self.pool = pool
         self.bs = pool.block_size
+        self.tails = not pool.snapshots
         self.root = _TrieNode((), 0, 0, None)
         self._clock = 0
         self._nodes = 0
@@ -749,7 +854,7 @@ class RadixIndex:
         # partial tail leaves: longest one whose WHOLE content prefixes
         # the remaining prompt (still leaving >= 1 token for prefill)
         best = None
-        for key, child in node.children.items():
+        for key, child in node.children.items() if self.tails else ():
             m = len(key)
             if m >= bs or m >= n - matched:
                 continue
@@ -788,7 +893,7 @@ class RadixIndex:
                 self.pool.incref(bid)
                 self._nodes += 1
             node = child
-        j = n % bs
+        j = n % bs if self.tails else 0
         if j:
             key = tuple(int(t) for t in tokens[n - j:])
             if key not in node.children:

@@ -422,10 +422,12 @@ class _Seam:
         return eng._greedy
 
     @staticmethod
-    def row_admitted(eng, row) -> None:
+    def row_admitted(eng, row, block: int = 0) -> None:
         pass
 
-    row_released = row_admitted
+    @staticmethod
+    def row_released(eng, row) -> None:
+        pass
 
 
 class _KVOnly(_Seam):
@@ -491,7 +493,15 @@ class _KVAndState(_Seam):
     state — no prefix index, re-prefill after preemption — the engine
     derives from ``cfg.state_geometry``, not from the family: a model
     of this family without recurrent layers keeps its whole past in
-    blocks, and is served with both.  Whether a pass that holds a chunk
+    blocks, and is served with both; so is one whose state has a
+    snapshot form (``cache.snapshot_geometry``: the short convolution's,
+    kept at every block's end with the block), for which a prefix is
+    its blocks AND the state after its last token — an adopting row's
+    state is restored from the LAST adopted block's snapshot inside its
+    admission (``row_admitted``), a match ends on a block boundary, and
+    a preempted row resumes from its last full block.  An SSM or matrix
+    state has no such form: those models adopt nothing and re-prefill
+    from zero.  Whether a pass that holds a chunk
     and decoding rows runs ONE program follows from the sublayer kinds
     (``build``; ``recurrent.has_step_chunk``), a recurrent state or
     not; that program's int32 vector is the step's and then the
@@ -503,14 +513,15 @@ class _KVAndState(_Seam):
     def refuse(cfg, ec: "EngineConfig", mesh) -> None:
         """What the family's programs have no form for today, refused
         at construction: speculation (no verify program; and a rejected
-        draft cannot be rolled back out of a recurrent state), a
-        mesh."""
+        draft cannot be rolled back out of a recurrent state: snapshots
+        are kept at block ends, not at a draft's start), a mesh."""
         recurrent = cfg.state_geometry is not None
         if ec.speculate is not None:
             raise SpeculationUnsupported(
                 "speculative decoding needs a cache that can roll "
                 "rejected tokens back; a recurrent state cannot (no "
-                "snapshot at the draft's start yet)" if recurrent else
+                "snapshot at the draft's start: where there are "
+                "snapshots they are a block end's)" if recurrent else
                 "the hybrid family has no verify program yet")
         if mesh is not None:
             raise ValueError(
@@ -552,7 +563,7 @@ class _KVAndState(_Seam):
     def operands(eng) -> tuple:
         st = eng.pool.state
         return (eng.params, eng.pool.pools,
-                () if st is None else (st.conv, st.ssm))
+                () if st is None else st.arrays)
 
     @staticmethod
     def run(eng, program, packed):
@@ -584,9 +595,12 @@ class _KVAndState(_Seam):
         counts.expert_touched_held_decode += int(step[3])
 
     @staticmethod
-    def row_admitted(eng, row) -> None:
-        if eng.pool.state is not None:
-            eng.pool.state.admit(row)
+    def row_admitted(eng, row, block: int = 0) -> None:
+        """``block``: the last block of the chain the row adopted (0:
+        none) — its snapshot is the state the row goes on from."""
+        st = eng.pool.state
+        if st is not None:
+            st.admit(row, block)
 
     @staticmethod
     def row_released(eng, row) -> None:
@@ -671,12 +685,15 @@ class InferenceEngine:
         self.max_seq = self.pool.max_seq
         # a cached prefix is its K/V blocks: with recurrent layers
         # that is no longer the whole of a prefix, so nothing is
-        # adopted (no index) — by derivation, not by an option.  Nor
-        # with window layers: the window pool's blocks of a prefix are
-        # given back as the row moves on, so a chain in the index would
-        # name full-layer blocks whose window-layer half is gone
+        # adopted (no index) — by derivation, not by an option — unless
+        # the state has a snapshot form and every full block carries
+        # the state at its end (``pool.snapshots``).  Nor with window
+        # layers: the window pool's blocks of a prefix are given back
+        # as the row moves on, so a chain in the index would name
+        # full-layer blocks whose window-layer half is gone
         self.trie = (RadixIndex(self.pool)
-                     if ec.prefix_cache and not recurrent and wg is None
+                     if ec.prefix_cache and wg is None
+                     and (not recurrent or self.pool.snapshots)
                      else None)
         self._seam.build(self, bs)
         if self._spec is not None:
@@ -746,6 +763,8 @@ class InferenceEngine:
         self._linear = getattr(cfg, "n_linear", 0) > 0
         # a model with latent-attention layers (ONE latent pool)
         self._latent = getattr(cfg, "n_latent", 0) > 0
+        # a model whose full blocks carry a state snapshot
+        self._snapshots = self.pool.snapshots
         # the loop thread's time by phase, always on; ``engine.account``
         # spans carry it to the ring (``_write_account``)
         self._passes = 0               # passes that found work
@@ -1122,7 +1141,11 @@ class InferenceEngine:
         self._row_blocks[row] = blocks
         self._slot_req[row] = req
         self._prefilling[row] = hit          # prefill resumes past the hit
-        self._seam.row_admitted(self, row)
+        # (a state with a snapshot form goes on from the last adopted
+        # block's: matches over such a pool end on a block boundary)
+        self._seam.row_admitted(self, row, ids[-1] if ids else 0)
+        if hit and self._snapshots:
+            self._counts.state_snapshots_restored += 1
         req._admitted()
         req.prefix_hit_tokens = hit
         occupied = self.engine_cfg.max_slots - len(self._free_rows)
@@ -1355,6 +1378,11 @@ class InferenceEngine:
                     self._counts.prefix_blocks_adopted += (len(ids2)
                                                            - pos // bs)
                 pos = self._prefilling[row] = hit2
+                if self._snapshots:
+                    self._seam.row_admitted(self, row, ids2[-1])
+                    if not req.prefix_hit_tokens:   # once a request
+                        self._counts.state_snapshots_restored += 1
+                    req.prefix_hit_tokens = hit2
             else:
                 for bid in ids2:
                     self.pool.decref(bid)
@@ -1403,6 +1431,8 @@ class InferenceEngine:
         counts.chunk_query_keys += n_q * pos + n_q * (n_q + 1) // 2
         if self._linear:
             counts.linear_chunk_tokens += n_q
+        if self._snapshots:
+            counts.state_snapshots_written += (pos + n_q) // bs - pos // bs
         if self._latent:
             tiles = window_tiles(pos, n_q, C, window_key_block(bs))
             counts.chunk_pairs_walked += tiles["pairs"]
@@ -1942,6 +1972,10 @@ class InferenceEngine:
                 if self._linear:
                     counts.linear_state_rows_advanced += int(
                         self._active.sum())
+                if self._snapshots:
+                    counts.state_snapshots_written += int((
+                        (self._positions[self._active] + 1)
+                        % self.engine_cfg.kv_block_size == 0).sum())
                 greedy = self._seam.greedy(self, logits)
                 stepped = 0
                 for row in list(self._slot_req):
@@ -2271,6 +2305,7 @@ class InferenceEngine:
             tp_shards=self.pool.heads_shards,
             state_bytes=pool["state_bytes"],
             state_rows_in_use=pool["state_rows_in_use"],
+            state_snapshot_bytes=pool["state_snapshot_bytes"],
             window_blocks_total=pool["window_blocks_total"],
             window_blocks_held=pool["window_blocks_held"],
             weight_bytes=self._weight_bytes,

@@ -19,11 +19,21 @@ supplied from the pools the cache manager owns
     form that decompresses a block of keys at a time), at rotary
     positions the program states;
   * a Mamba layer's convolution and SSM state come from the
-    ``StatePool`` at the window's decode rows and go back there.
+    ``StatePool`` at the window's decode rows and go back there;
+  * a short-convolution layer's state is its convolution's last inputs
+    alone, and the state after ANY token of a window is a slice of the
+    window's own activations: each program also writes the state at the
+    END of every block it closes into the pool's snapshots, by the
+    block's id (``_Snapshots``; the chunk program those of every block
+    boundary its window crosses, the decode step one where a row's
+    token is its block's last) — a gather and a scatter of a few KB a
+    block, no second pass — so that every full block a row holds
+    carries the state a later request needs to go on from it.
 
 A program takes ``pools``, the tuple of block-pool arrays
 (``BlockPool.pools``: K and V, or the one latent pool), and ``state``,
-the tuple of state arrays (``(conv, ssm)``, or ``()`` for a model
+the tuple of state arrays (``StatePool.arrays``: ``(conv, ssm)``,
+``(conv, snap)`` of a state with a snapshot form, or ``()`` for a model
 without recurrent layers), all donated and updated in place.  Besides
 the logits each program returns ``load`` int32 — held expert
 assignments, all assignments, the busiest held expert's assignments and
@@ -75,7 +85,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.inference.cache import PoolLayout
+from ray_tpu.inference.cache import PoolLayout, snapshot_geometry
 from ray_tpu.inference.decode import (_cached, _step_indices, latent_attend,
                                       paged_attend, unpack_chunk,
                                       unpack_step, window_by_head)
@@ -113,6 +123,10 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
                 kv_lengths=kv_len)
             conv, ssm = state or (None, None)
             held = {"conv": [], "ssm": ssm}
+            # a state with a snapshot form: (conv, snap), and what each
+            # row's token closes (``_Snapshots``)
+            keep = (_Snapshots(state) if snapshot_geometry(cfg) is not None
+                    else None)
 
             def state_out(mi, new):
                 held["conv"].append(new[0])
@@ -129,18 +143,116 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
             x, load = hybrid.run_layers(
                 cfg, params, hybrid.embed(cfg, params, tokens[:, None]),
                 active.astype(jnp.int32),
-                state_in=lambda mi: (conv[mi], (held["ssm"], mi)),
-                state_out=state_out, attend_for=attend_for,
+                state_in=keep.rows_in if keep else (
+                    lambda mi: (conv[mi], (held["ssm"], mi))),
+                state_out=keep.rows_out if keep else state_out,
+                attend_for=attend_for,
                 window_for=window_for, positions=positions[:, None])
             logits = hybrid.head(cfg, params, x[:, 0])
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            state = (jnp.stack(held["conv"]), held["ssm"]) if state else ()
+            if keep:
+                state = keep.done(_closed(
+                    *_step_indices(tables, positions, active, bs)[:2], bs))
+            else:
+                state = (jnp.stack(held["conv"]), held["ssm"]) if state \
+                    else ()
             return (logits, jnp.concatenate([load, greedy]), pools_out(),
                     state)
 
         return step
 
     return _cached(("recurrent_step", bs, T), cfg, None, None, build)
+
+
+def _closed(blocks, offsets, bs: int):
+    """The blocks a decode step's rows CLOSE: a row whose token lands on
+    its block's last offset names that block.  -> (ids [b], which rows
+    write [b]); an inactive row's block is the scratch block (id 0) and
+    closes nothing."""
+    return blocks, (offsets == bs - 1) & (blocks > 0)
+
+
+def _boundaries(table, start, n_valid, C: int, bs: int):
+    """The block boundaries a chunk's window crosses, at most ``ceil(C /
+    bs)``: -> (marks [J]: the token counts of the window after which a
+    block ends, 0 where the real tokens end first; ids [J]: those
+    blocks; which of them are real [J])."""
+    j = jnp.arange(-(-C // bs), dtype=jnp.int32)
+    block = start // bs + j
+    marks = (block + 1) * bs - start
+    real = marks <= n_valid
+    ids = table[jnp.minimum(block, table.shape[0] - 1)]
+    return jnp.where(real, marks, 0), ids, real
+
+
+def _write_rows(snap, ids, rows, write):
+    """snap [blocks, lanes] <- ``rows`` [n, lanes] at ``ids`` [n] where
+    ``write`` [n], in place: a loop over the WRITERS alone, one row's
+    update each (a pass closes a block for 0.3 of its rows on average:
+    one in ``block_size`` tokens).  As ONE scatter of all n rows the
+    compiler loops over every row, writers or not (0.35 ms a decode
+    pass at 48 rows), and two scatters in one program copy the whole
+    array between them (604 MB, 1.2 ms a pass that held a chunk: my
+    chip run, PR 52, call 1)."""
+    order = jnp.argsort(~write)                      # the writers first
+    rows = rows.astype(snap.dtype)
+
+    def one(i, snap):
+        j = order[i]
+        return jax.lax.dynamic_update_slice(
+            snap, jax.lax.dynamic_slice_in_dim(rows, j, 1), (ids[j], 0))
+    return jax.lax.fori_loop(0, write.sum(dtype=jnp.int32), one, snap)
+
+
+class _Snapshots:
+    """A program's traffic with a state that has a snapshot form
+    (``state`` = (conv [L, rows, K-1, d], snap [blocks, L (K-1) d])):
+    what ``run_layers`` is handed as ``state_in`` / ``state_out`` for the
+    rows of a step (``rows_in`` / ``rows_out``), for one row's window
+    (``window_in(row, marks)`` / ``rows_out``) or for both as ONE
+    window (``both_in``), and the state tuple the program returns
+    (``done``).  Every layer's new state is stacked and written once at
+    the end, as the decode step does for its convolution states."""
+
+    def __init__(self, state):
+        self.conv, self.snap = state
+        self.new, self.marked = [], []
+
+    def rows_in(self, mi):
+        return self.conv[mi], None
+
+    def rows_out(self, mi, new):
+        self.new.append(new[0])
+        if new[1] is not None:
+            self.marked.append(new[1][0])
+
+    def window_in(self, row, marks):
+        return lambda mi: (_row_of(self.conv, mi, row)[0], marks[None])
+
+    def both_in(self, row, marks):
+        return lambda mi: (self.conv[mi], marks[None], row)
+
+    def done(self, closed=None, row=None, marked=None):
+        """``closed`` (ids [rows], which write): the blocks the step's
+        rows closed, whose snapshot is those rows' NEW state; ``marked``
+        (ids [J], which are real): the blocks of a window's marks;
+        ``row``: a window alone, whose new state goes to that row of the
+        pool.  -> the program's state tuple; the snapshots are written
+        by ONE loop over everything that writes."""
+        new = jnp.stack(self.new)                    # [L, n, K-1, d]
+        parts = []                       # (ids, which write, the states)
+        if marked is not None:
+            parts.append((*marked, jnp.stack(self.marked)))
+        if closed is not None:
+            parts.append((*closed, new))
+        ids, write, states = zip(*parts)
+        snap = _write_rows(
+            self.snap, jnp.concatenate(ids), jnp.concatenate(
+                [jnp.swapaxes(s, 0, 1).reshape(s.shape[1], -1)
+                 for s in states]), jnp.concatenate(write))
+        if closed is not None:
+            return new, snap
+        return self.conv.at[:, row].set(new[:, 0]), snap
 
 
 def _attend_over(cfg, lay, pools, blocks, offsets, tables, *,
@@ -255,6 +367,10 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
                 cfg, pools, table[None],
                 lambda t: _chunk_window(t[0], start, C, bs)[1][None],
                 off[None], q_pos=pos, n_valid=n_valid)
+            keep = marked = None
+            if snapshot_geometry(cfg) is not None:
+                marks, *marked = _boundaries(table, start, n_valid, C, bs)
+                keep = _Snapshots(state)
             held = dict(zip(("conv", "ssm"), state))
 
             def state_in(mi):
@@ -268,13 +384,16 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
 
             x, load = hybrid.run_layers(
                 cfg, params, hybrid.embed(cfg, params, tokens[None]),
-                n_valid[None], state_in=state_in, state_out=state_out,
+                n_valid[None],
+                state_in=keep.window_in(row, marks) if keep else state_in,
+                state_out=keep.rows_out if keep else state_out,
                 attend_for=attend_for, window_for=window_for,
                 positions=pos[None])
             logits = hybrid.head(cfg, params, x[0])             # [C, V]
             greedy = jnp.argmax(logits[jnp.maximum(n_valid, 1) - 1]
                                 ).astype(jnp.int32)
             return (logits, jnp.append(load, greedy), pools_out(),
+                    keep.done(row=row, marked=marked) if keep else
                     tuple(held[k] for k in ("conv", "ssm")[:len(state)]))
 
         return chunk_fn
@@ -345,6 +464,10 @@ def make_recurrent_step_chunk(cfg: HybridConfig, *, chunk: int,
                 kv_lengths=kv_len, q_pos=pos, q_table=table[None])
             conv, ssm = state or (None, None)
             held = {"conv": [], "ssm": ssm}
+            keep = marked = None
+            if snapshot_geometry(cfg) is not None:
+                marks, *marked = _boundaries(table, start, n_valid, C, bs)
+                keep = _Snapshots(state)
 
             def state_out(mi, new):
                 held["conv"].append(new[0])
@@ -357,15 +480,22 @@ def make_recurrent_step_chunk(cfg: HybridConfig, *, chunk: int,
                 hybrid.embed(cfg, params,
                              jnp.concatenate([tokens, chunk_tokens])[None]),
                 jnp.append(active.astype(jnp.int32), n_valid),
-                state_in=lambda mi: (conv[mi], (held["ssm"], mi), row),
-                state_out=state_out,
-                attend_for=attend_for, rows=b)
+                state_in=(keep.both_in(row, marks) if keep else
+                          lambda mi: (conv[mi], (held["ssm"], mi), row)),
+                state_out=keep.rows_out if keep else state_out,
+                attend_for=attend_for, rows=b,
+                positions=(jnp.concatenate([positions, pos])[None]
+                           if cfg.rotary_full else None))
             last = b + jnp.maximum(n_valid, 1) - 1
             x = jnp.concatenate(
                 [x[0, :b], jax.lax.dynamic_slice_in_dim(x[0], last, 1)])
             logits = hybrid.head(cfg, params, x)            # [b + 1, V]
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            state = (jnp.stack(held["conv"]), held["ssm"]) if state else ()
+            if keep:
+                state = keep.done(_closed(bidx, off, bs), marked=marked)
+            else:
+                state = (jnp.stack(held["conv"]), held["ssm"]) if state \
+                    else ()
             return (logits,
                     jnp.concatenate([load[0], greedy[:b], load[1],
                                      greedy[b:]]),

@@ -1,7 +1,8 @@
 """Hybrid language model: Mamba-2 mixers, gated-delta-rule linear
-attention mixers, attention mixers, latent attention mixers, routed
-experts and dense gated MLPs in a layer pattern.  ONE layer function
-serves the five layouts public configs of the kind have:
+attention mixers, gated short-convolution mixers, attention mixers,
+latent attention mixers, routed experts and dense gated MLPs in a layer
+pattern.  ONE layer function serves the six layouts public configs of
+the kind have:
 
     x0 = wte[ids] * embedding_multiplier            (no position embedding)
     x  = x + residual_multiplier * mixer(RMSNorm(x) * w)     per sublayer
@@ -9,7 +10,7 @@ serves the five layouts public configs of the kind have:
     x  = x + RMSNorm(mixer(RMSNorm(x) * w)) * w'  ... with ``sandwich_norm``
     logits = RMSNorm(x) @ W_head / logits_scaling   (W_head = wte^T if tied)
 
-``mixer`` is one of seven kinds:
+``mixer`` is one of eight kinds:
 
   * attention: grouped queries (``n_heads`` query heads over
     ``n_kv_heads`` K/V heads), no bias, no rotary; softmax(q k^T *
@@ -33,6 +34,16 @@ serves the five layouts public configs of the kind have:
     ``log alpha = -exp(A_log) softplus(x w_a + dt_bias)``; the gated
     delta rule over a matrix state ``[V, K]`` a head; ``y = RMSNorm_V(
     o) * w * silu(x W_g)`` per head; output projection.
+  * the gated short convolution (ops/short_conv.py, ``lfm2_moe``): ``[B
+    | C | u] = h W_in`` (no bias), ``v = B * u``, a depthwise causal
+    convolution of ``conv_width`` taps over ``v`` with no bias and NO
+    activation, ``y = (C * conv) W_out``.  Its whole past is the last
+    ``conv_width - 1`` values of ``v``: a state of ``[L - 1, d]`` a row
+    and NOTHING else (``state_geometry``'s third entry is None), which
+    is the state after any token of a window for the price of a slice —
+    so a cache may keep it at every block boundary (inference/cache.py).
+    The full-attention layers beside it turn q and k by rotary positions
+    (``rotary_full``) after a per-head RMSNorm.
   * Mamba-2 (ops/ssm.py): ``[z | xBC | dt] = in_proj(h)``; ``xBC =
     silu(causal_conv(xBC) + b)``; ``[x | B | C]`` with B, C in
     ``ssm_groups`` groups; ``dt = softplus(dt + dt_bias)``; ``A =
@@ -56,7 +67,8 @@ serves the five layouts public configs of the kind have:
     ``route_eps`` added to the chosen scores' sum).
     ``shared_width`` is the width of ONE ungated MLP,
     however many shared experts the config counts (they are published
-    as one MLP of their summed width).
+    as one MLP of their summed width); 0: no shared expert, and no
+    product or parameter stands in for one (``lfm2_moe``).
   * latent attention (``q_rank`` / ``kv_rank``): queries through a
     normed low-rank bottleneck, per head ``[nope | rope]`` lanes; ONE
     normed latent ``c_kv`` [kv_rank] and ONE rotated key ``k_rope``
@@ -72,8 +84,9 @@ serves the five layouts public configs of the kind have:
 
 A published layer is one such sublayer (``nemotron_h``: the pattern
 string's ``M`` / ``*`` / ``E``), or, with ``experts_in_every_layer``
-(``granitemoehybrid``, ``deepseek_v2``, ``olmo_hybrid``, ``afmoe``), a
-Mamba, linear, attention, window or latent sublayer FOLLOWED by a feed-forward sublayer
+(``granitemoehybrid``, ``deepseek_v2``, ``olmo_hybrid``, ``afmoe``,
+``lfm2_moe``), a Mamba, linear, short-convolution, attention, window or
+latent sublayer FOLLOWED by a feed-forward sublayer
 with its own norm and residual — experts, or the dense MLP in the first
 ``dense_layers`` layers (``olmo_hybrid``: all of them): the same
 function twice.
@@ -109,16 +122,19 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import delta_rule, ssm
+from ray_tpu.ops import delta_rule, short_conv, ssm
 from ray_tpu.ops.attention import KEY_BLOCK, latent_window_attention
 from ray_tpu.ops.routed_experts import lanes, mlp, routed_experts
 
 MAMBA, ATTENTION, EXPERTS = "mamba", "attention", "experts"
 LATENT, DENSE, LINEAR = "latent", "dense", "linear_attention"
 WINDOW = "window_attention"     # attention over the last ``window`` keys
+SHORT_CONV = "short_conv"       # the gated short convolution
 N_LOAD = 4      # numbers in ``run_layers``' load vector (its text names them)
 # the sublayer kinds that take a window in two parts (``block``'s ``rows``)
-TWO_PART = frozenset({MAMBA, ATTENTION, EXPERTS, DENSE})
+TWO_PART = frozenset({MAMBA, SHORT_CONV, ATTENTION, EXPERTS, DENSE})
+# the sublayer kinds that carry a per-row state (``state_geometry``)
+RECURRENT = (MAMBA, LINEAR, SHORT_CONV)
 # the sublayer kinds of a ``nemotron_h`` pattern string
 PATTERN_KINDS = {"M": MAMBA, "*": ATTENTION, "E": EXPERTS}
 
@@ -219,6 +235,7 @@ class HybridConfig:
     attn_gate: bool = False          # o_proj(attn * sigmoid(x W_gate))
     window: int = 0                  # keys a WINDOW layer attends
     rope_theta: float = 0.0          # rotary q, k of the WINDOW layers
+    rotary_full: bool = False        # ... and of the ATTENTION layers
     sigmoid_router: Any = None       # None: the relu^2 experts' (above)
     route_eps: float = 0.0           # added to the chosen scores' sum
     # the first family's four multipliers
@@ -233,7 +250,7 @@ class HybridConfig:
 
     def __post_init__(self):
         bad = set(self.layer_types) - {MAMBA, ATTENTION, EXPERTS, LATENT,
-                                       LINEAR, WINDOW}
+                                       LINEAR, WINDOW, SHORT_CONV}
         if bad:
             raise ValueError(f"unknown layer types {sorted(bad)}")
         if self.experts_in_every_layer and EXPERTS in self.layer_types:
@@ -253,9 +270,12 @@ class HybridConfig:
         if LATENT in self.layer_types and set(self.layer_types) != {LATENT}:
             raise ValueError("latent and head-lane attention layers keep "
                              "different things: one pool holds one kind")
-        if MAMBA in self.layer_types and LINEAR in self.layer_types:
-            raise ValueError("Mamba and linear attention layers keep "
-                             "different states: one pool holds one kind")
+        if len(set(self.layer_types) & set(RECURRENT)) > 1:
+            raise ValueError("Mamba, linear attention and short "
+                             "convolution layers keep different states: "
+                             "one pool holds one kind")
+        if self.rotary_full and not self.rope_theta:
+            raise ValueError("rotary_full needs a rope_theta")
         if (WINDOW in self.layer_types) != (self.window > 0):
             raise ValueError("window layers and a window > 0 go together")
         if self.n_window and (self.n_latent or self.state_geometry):
@@ -270,7 +290,8 @@ class HybridConfig:
         """From a public ``config.json``'s own keys: ``nemotron_h``'s
         where it has a ``hybrid_override_pattern``, ``deepseek_v2``'s
         where it has a ``kv_lora_rank``, ``olmo_hybrid``'s where it has
-        a ``linear_key_head_dim``, ``afmoe``'s where it has a
+        a ``linear_key_head_dim``, ``lfm2_moe``'s where it has a
+        ``conv_L_cache``, ``afmoe``'s where it has a
         ``global_attn_every_n_layers`` or a ``sliding_attention`` layer,
         else ``granitemoehybrid``'s."""
         c = config
@@ -280,6 +301,8 @@ class HybridConfig:
             return cls(**{**_latent_keys(c), **overrides})
         if "linear_key_head_dim" in c:
             return cls(**{**_olmo_hybrid_keys(c), **overrides})
+        if "conv_L_cache" in c or c.get("model_type") == "lfm2_moe":
+            return cls(**{**_lfm2_keys(c), **overrides})
         if ("global_attn_every_n_layers" in c
                 or "sliding_attention" in c.get("layer_types", ())):
             return cls(**{**_afmoe_keys(c), **overrides})
@@ -329,6 +352,10 @@ class HybridConfig:
     @property
     def n_linear(self) -> int:
         return self.layer_types.count(LINEAR)
+
+    @property
+    def n_short_conv(self) -> int:
+        return self.layer_types.count(SHORT_CONV)
 
     @property
     def n_attention(self) -> int:
@@ -419,8 +446,13 @@ class HybridConfig:
         state ``[heads, values, keys]`` is stored transposed with the
         heads folded into the minor dim for the same reason, ``[keys,
         heads * values]`` (``(96, 5760)``: whole tiles; ops/
-        delta_rule.py).  None for a model without recurrent layers: its
+        delta_rule.py).  The short convolution has NO recurrent state:
+        its third entry is None, the convolution's last inputs are the
+        whole of it.  None for a model without recurrent layers: its
         whole past is blocks."""
+        if self.n_short_conv:
+            return (self.n_short_conv,
+                    (self.conv_width - 1, self.d_model), None)
         if self.n_linear:
             return (self.n_linear,
                     (self.conv_width - 1, self.lin_conv_channels),
@@ -609,6 +641,52 @@ def _afmoe_keys(c: dict) -> dict:
         rms_eps=c["rms_norm_eps"], max_seq=c["max_position_embeddings"])
 
 
+def _lfm2_keys(c: dict) -> dict:
+    """``HybridConfig`` fields from ``lfm2_moe`` keys.  What the layer
+    function has no form for is refused here, by name.  The published
+    config carries no ``head_dim`` (``hidden_size / num_attention_
+    heads``) and no ``tie_word_embeddings`` (tied: the published
+    parameter count holds one embedding matrix)."""
+    kinds = {"conv": SHORT_CONV, "full_attention": ATTENTION}
+    types = c["layer_types"][:c["num_hidden_layers"]]
+    _refuse_unless(
+        ("conv_bias", c.get("conv_bias", False), (False,)),
+        ("use_expert_bias", c.get("use_expert_bias", True), (True,)),
+        ("norm_topk_prob", c.get("norm_topk_prob", True), (True,)),
+        ("rope_scaling", c.get("rope_scaling"), (None,)),
+        ("tie_word_embeddings", c.get("tie_word_embeddings", True),
+         (True,)),
+        ("hidden_act", c.get("hidden_act", "silu"), ("silu",)),
+        ("attention_bias", c.get("attention_bias", False), (False,)),
+        ("num_shared_experts", c.get("num_shared_experts", 0), (0,)),
+        *(("layer_types", t, tuple(kinds)) for t in types))
+    if c["conv_L_cache"] < 2:
+        raise ValueError(f"conv_L_cache = {c['conv_L_cache']!r} is not "
+                         f"implemented (a convolution keeps >= 1 input)")
+    head_dim = c.get("head_dim") or (c["hidden_size"]
+                                     // c["num_attention_heads"])
+    return dict(
+        vocab_size=c["vocab_size"], d_model=c["hidden_size"],
+        layer_types=tuple(kinds[t] for t in types),
+        n_heads=c["num_attention_heads"],
+        n_kv_heads=c["num_key_value_heads"], head_dim=head_dim,
+        conv_width=c["conv_L_cache"],
+        rope_theta=float(c["rope_theta"]), rotary_full=True,
+        qk_norm="head",
+        n_experts=c["num_experts"],
+        experts_per_token=c["num_experts_per_tok"],
+        expert_width=c["moe_intermediate_size"], shared_width=0,
+        experts_held=(0, c["num_experts"]),
+        experts_in_every_layer=True, gated_experts=True,
+        sigmoid_router=True, route_eps=1e-6,
+        routed_scale=float(c["routed_scaling_factor"]),
+        dense_layers=min(c["num_dense_layers"], len(types)),
+        dense_width=c["intermediate_size"], tied_head=True,
+        embedding_multiplier=1.0, attention_multiplier=head_dim ** -0.5,
+        residual_multiplier=1.0, logits_scaling=1.0,
+        rms_eps=c["norm_eps"], max_seq=c["max_position_embeddings"])
+
+
 # -- params ----------------------------------------------------------------
 
 def init_params(cfg: HybridConfig, rng: jax.Array):
@@ -672,6 +750,13 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
                 "gnorm": jnp.ones((Vl,), pd),
                 "wo": norm((Hl * Vl, d)),
             }
+        if kind == SHORT_CONV:
+            return {
+                "norm": jnp.ones((d,), pd),
+                "in_proj": norm((d, 3 * d)),
+                "conv_w": unif((K, d), -bound, bound).astype(pd),
+                "out_proj": norm((d, d)),
+            }
         if kind in (ATTENTION, WINDOW):
             # (with an output gate its projection rides the same product:
             # [q | k | v | gate], one read of the window a layer)
@@ -709,16 +794,15 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
                 "w_in": norm((d, 2 * cfg.dense_width)),
                 "w_out": norm((cfg.dense_width, d)),
             }
-        ffn = {
-            "norm": jnp.ones((d,), pd),
-            "router": norm((d, cfg.n_experts)),
-            "shared_in": norm((d, halves * cfg.shared_width)),
-            "shared_out": norm((cfg.shared_width, d)),
-            "w_in": jnp.pad(
-                norm((cfg.n_held, d, halves * cfg.expert_width)),
-                [(0, 0), (0, 0), (0, pad)]),
-            "w_out": norm((cfg.n_held, cfg.expert_width, d)),
-        }
+        ffn = {"norm": jnp.ones((d,), pd),
+               "router": norm((d, cfg.n_experts))}
+        if cfg.shared_width:
+            ffn["shared_in"] = norm((d, halves * cfg.shared_width))
+            ffn["shared_out"] = norm((cfg.shared_width, d))
+        ffn["w_in"] = jnp.pad(
+            norm((cfg.n_held, d, halves * cfg.expert_width)),
+            [(0, 0), (0, 0), (0, pad)])
+        ffn["w_out"] = norm((cfg.n_held, cfg.expert_width, d))
         if cfg.routes_by_sigmoid:
             ffn["router_bias"] = jax.random.normal(
                 next(k), (cfg.n_experts,)) * 0.02
@@ -863,6 +947,65 @@ def _mamba_mixer(cfg, mp, h, state, n_valid, rows: int = 0):
     return out, state
 
 
+def _short_conv_core(cfg, mp, bcu, state, n_valid):
+    """The short convolution between its two projections, on
+    ``in_proj``'s output for the rows of ``state`` (conv [b, L-1, d],
+    ``marks`` [b, J] or None).  -> (y [b, w, d], (conv, the state after
+    each row's first ``marks`` tokens [b, J, L-1, d] or None))."""
+    conv, marks = state
+    K1, f32 = cfg.conv_width - 1, jnp.float32
+    B, C, u = jnp.split(bcu, 3, axis=-1)
+    v = (B.astype(f32) * u.astype(f32)).astype(bcu.dtype)
+    if bcu.shape[1] == 1 and marks is None:
+        c, conv = short_conv.conv_step(v[:, 0], conv, mp["conv_w"], n_valid)
+        c, marked = c[:, None], None
+    else:
+        c, full = short_conv.conv_window(v, conv, mp["conv_w"])
+        conv = short_conv.state_at(full, n_valid, K1).astype(conv.dtype)
+        marked = None if marks is None else jax.vmap(
+            lambda row, m: row[m[:, None] + jnp.arange(K1)])(full, marks)
+    y = (C.astype(f32) * c.astype(f32)).astype(bcu.dtype)
+    return y, (conv, marked)
+
+
+def _short_conv_mixer(cfg, mp, h, state, n_valid, rows: int = 0):
+    """h [b, w, d]; state (conv [b, L-1, d], ``marks``): the rows' last
+    L-1 values of ``v`` and, of a window, the token counts [b, J] after
+    which a cache wants the state kept (None: none).  -> (out [b, w, d],
+    (conv, the states at the marks [b, J, L-1, d] or None)).
+
+    With ``rows`` the window is in two parts (``block``): h [1, rows +
+    w, d], ``n_valid`` [rows + 1], ``state`` (conv [rows, L-1, d],
+    marks [1, J], row): the one-token rows' state, the marks of the
+    window of ``w`` tokens and WHICH of those rows it belongs to (it
+    sits the step out: ``n_valid`` 0).  The two projections are one
+    product each over the whole window; the taps run a part at a time
+    in the form that part has alone."""
+    with jax.named_scope("mixer_short_conv"):
+        with jax.named_scope("short_conv_in_proj"):
+            bcu = jnp.dot(h, mp["in_proj"].astype(h.dtype))
+            # ONE materialisation (see ``_mamba_mixer``)
+            bcu = jax.lax.optimization_barrier(bcu)
+        with jax.named_scope("short_conv_taps"):
+            if rows:
+                conv0, marks, row = state
+                y1, (conv, _) = _short_conv_core(
+                    cfg, mp, bcu[0, :rows, None], (conv0, None),
+                    n_valid[:rows])
+                yw, (own, marked) = _short_conv_core(
+                    cfg, mp, bcu[:, rows:],
+                    (jax.lax.dynamic_slice_in_dim(conv0, row, 1), marks),
+                    n_valid[rows:])
+                state = (jax.lax.dynamic_update_slice_in_dim(
+                    conv, own, row, 0), marked)
+                y = jnp.concatenate([y1[None, :, 0], yw], axis=1)
+            else:
+                y, state = _short_conv_core(cfg, mp, bcu, state, n_valid)
+        with jax.named_scope("short_conv_out_proj"):
+            out = jnp.dot(y, mp["out_proj"].astype(h.dtype))
+    return out, state
+
+
 def _unit(x, eps: float = 1e-6):
     """x [..., K] float32 scaled to unit length."""
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
@@ -949,7 +1092,8 @@ def rotary_tables(cfg: HybridConfig, positions):
     """positions [...] int -> (cos, sin) [..., rope_dim / 2] float32 at
     the YaRN-scaled frequencies — or [..., head_dim / 2] at the plain
     ones of ``rope_theta`` (no scaling), which turn the window layers'
-    whole heads; None for a model without rotary positions."""
+    whole heads (and, under ``rotary_full``, the attention layers');
+    None for a model without rotary positions."""
     if cfg.rope_theta and cfg.yarn is None:
         with jax.named_scope("rotary"):
             inv = cfg.rope_theta ** (
@@ -1024,8 +1168,9 @@ def _dense(cfg, fp, h):
 def _experts(cfg, fp, h, valid):
     """h [b, w, d]; ``valid`` [b, w] marks the real tokens, or [parts,
     b, w] the real tokens of each part of the window -> (routed +
-    shared [b, w, d], counts [E_held], total; [parts, E_held] and
-    [parts] of a window in parts)."""
+    shared [b, w, d] — routed alone where ``shared_width`` is 0 —
+    counts [E_held], total; [parts, E_held] and [parts] of a window in
+    parts)."""
     b, w, d = h.shape
     flat = h.reshape(b * w, d)
     with jax.named_scope("routed_experts"):
@@ -1037,10 +1182,11 @@ def _experts(cfg, fp, h, valid):
             bias=fp.get("router_bias"), scale=cfg.routed_scale,
             groups=cfg.route_groups, normalise=cfg.norm_topk,
             eps=cfg.route_eps)
-    with jax.named_scope("shared_expert"):
-        shared = mlp(flat, fp["shared_in"], fp["shared_out"],
-                     cfg.gated_experts)
-    return (routed + shared).reshape(b, w, d), counts, total
+    if cfg.shared_width:
+        with jax.named_scope("shared_expert"):
+            routed = routed + mlp(flat, fp["shared_in"], fp["shared_out"],
+                                  cfg.gated_experts)
+    return routed.reshape(b, w, d), counts, total
 
 
 _experts_once = jax.jit(_experts, static_argnums=0)   # see ``_ssm_core_once``
@@ -1050,10 +1196,11 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
           rows: int = 0):
     """ONE residual sublayer on a window: x [b, w, d], ``n_valid`` [b]
     real tokens a row; ``lp`` its parameters.  ``past`` is the row's
-    state for a Mamba sublayer (returned updated), the ``attend``
-    function for an attention sublayer and (``attend``, rotary tables)
-    for a latent or a window-attention one (returned as they came) and
-    unused by experts and the dense MLP.
+    state for a Mamba, linear or short-convolution sublayer (returned
+    updated), the ``attend`` function for an attention sublayer and
+    (``attend``, rotary tables) for a latent or a window-attention one —
+    and for an attention one under ``rotary_full`` — (returned as they
+    came) and unused by experts and the dense MLP.
     -> (x, past, (counts [E_held], total) of an experts sublayer, else
         None).
 
@@ -1063,7 +1210,8 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
     one-token row, then the window's real tokens.  Every product over
     ``d`` is then one product for both parts.  A Mamba sublayer's
     ``past`` is the one-token rows' state and which of them the window
-    belongs to, (conv, ssm, row); an attention sublayer's ``attend``
+    belongs to, (conv, ssm, row) — a short convolution's (conv, marks,
+    row); an attention sublayer's ``attend``
     treats the two parts apart itself (``decode.paged_attend`` with a
     length a row AND a mask); experts count the parts apart: counts [2,
     E_held], total [2].  Linear and latent attention have no such
@@ -1076,8 +1224,11 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
         mix, past = _mamba_mixer(cfg, lp, h, past, n_valid, rows)
     elif kind == LINEAR:
         mix, past = _linear_mixer(cfg, lp, h, past, n_valid)
+    elif kind == SHORT_CONV:
+        mix, past = _short_conv_mixer(cfg, lp, h, past, n_valid, rows)
     elif kind == ATTENTION:
-        mix = _attention_mixer(cfg, lp, h, past,
+        mix = _attention_mixer(cfg, lp, h,
+                               *(past if cfg.rotary_full else (past,)),
                                scope="mixer_full_attention" if cfg.n_window
                                else "mixer_attention")
     elif kind == WINDOW:
@@ -1123,8 +1274,9 @@ def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
                state_out: Callable, attend_for: Callable, positions=None,
                rows: int = 0, window_for: Callable = None):
     """The unrolled layer loop over a window.  ``state_in(mi)`` gives
-    recurrent (Mamba or linear) layer ``mi``'s state for the window's
-    rows, (conv, (state pool, layer)) as its mixer takes it, and
+    recurrent (Mamba, linear or short-convolution) layer ``mi``'s state
+    for the window's rows, (conv, (state pool, layer)) — or (conv,
+    marks) — as its mixer takes it, and
     ``state_out(mi, state)``
     takes it back; ``attend_for(ai)`` gives attention or latent layer
     ``ai``'s ``attend`` and ``window_for(wi)`` window-attention layer
@@ -1141,7 +1293,7 @@ def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
     mi = ai = wi = 0
     load = jnp.zeros((2, N_LOAD) if rows else (N_LOAD,), jnp.int32)
     tables = (rotary_tables(cfg, positions)
-              if cfg.n_latent or cfg.n_window else None)
+              if cfg.n_latent or cfg.n_window or cfg.rotary_full else None)
     for i, kind in cfg.sublayers:
         lp = params["layers"][i]
         if kind == WINDOW:
@@ -1154,14 +1306,15 @@ def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
             ai += 1
         elif kind == DENSE:
             x, _, _ = block(cfg, kind, lp["ffn"], x, None, n_valid, rows)
-        elif kind in (MAMBA, LINEAR):
+        elif kind in RECURRENT:
             x, state, _ = block(cfg, kind, lp["mixer"], x, state_in(mi),
                                 n_valid, rows)
             state_out(mi, state)
             mi += 1
         elif kind == ATTENTION:
-            x, _, _ = block(cfg, kind, lp["mixer"], x, attend_for(ai),
-                            n_valid, rows)
+            x, _, _ = block(cfg, kind, lp["mixer"], x,
+                            (attend_for(ai), tables) if cfg.rotary_full
+                            else attend_for(ai), n_valid, rows)
             ai += 1
         else:
             x, _, (counts, total) = block(cfg, kind, lp["ffn"], x, None,
@@ -1174,10 +1327,13 @@ def run_layers(cfg: HybridConfig, params, x, n_valid, state_in: Callable,
 
 def zero_state(cfg: HybridConfig, rows: int):
     """(conv, recurrent) state of ``rows`` rows that have seen
-    nothing, the recurrent state as a pool of this one layer."""
+    nothing, the recurrent state as a pool of this one layer (a short
+    convolution's: (conv, no marks))."""
     if cfg.state_geometry is None:
         return None
     _, conv, ssm_shape = cfg.state_geometry
+    if ssm_shape is None:
+        return jnp.zeros((rows, *conv), cfg.dtype), None
     return (jnp.zeros((rows, *conv), cfg.dtype),
             (jnp.zeros((1, rows, *ssm_shape), jnp.float32), 0))
 

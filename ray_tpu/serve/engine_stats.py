@@ -242,6 +242,22 @@ ROWS: tuple[Row, ...] = (
            "keeps K/V only)"),
     _gauge("state_rows_in_use", "ray_tpu_inference_state_rows_in_use",
            "Decode rows holding a recurrent state"),
+    # ... kept at block ends where it has a snapshot form (zeros for
+    # every other model): a block closed by a chunk or a decode step is
+    # a snapshot written; an admission (or a re-match before a chunk)
+    # that adopted a chain is the LAST block's snapshot restored, once
+    # a request; restored / ``admissions`` is the share of rows that
+    # went on from a cached state
+    _gauge("state_snapshot_bytes", "ray_tpu_inference_state_snapshot_bytes",
+           "Bytes of the recurrent-state snapshots kept with the paged KV "
+           "pool's blocks (0 = the state has no snapshot form)"),
+    _counter("state_snapshots_written",
+             "ray_tpu_inference_state_snapshots_written_total",
+             "Blocks closed with the recurrent state at their end kept"),
+    _counter("state_snapshots_restored",
+             "ray_tpu_inference_state_snapshots_restored_total",
+             "Requests whose row took its recurrent state from an adopted "
+             "block's snapshot"),
     # ---- the second kind of K/V state: the pool of a model's
     # window-attention layers (zeros for a model without).  A row takes
     # its blocks chunk by chunk and gives them back behind the window;

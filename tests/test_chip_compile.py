@@ -1186,3 +1186,115 @@ def test_afmoe_programs_fit_and_move_neither_group_of_pools(afmoe_cell,
     assert len(calls) == 5
     labels = sorted(afmoe_trace.label_of(line, marks) for line in calls)
     assert labels == ["full_decode_attention"] + ["swa_decode_attention"] * 4
+
+
+# ---------------------------------------------------------------------------
+# the lfm2_moe layout: short-convolution layers whose state is kept at
+# every block's end beside the K/V pools (ISSUE 52)
+
+
+@pytest.fixture(scope="module")
+def lfm2_cell(one_chip):
+    """The shapes of ``serve-lfm2-agent4k-r80``, from the cell's own
+    configuration file: 12 layers (9 conv : 3 attention) at published
+    widths, all 32 experts, the whole vocabulary, 48 rows, a table of 72
+    blocks of 64 a row, a snapshot a block."""
+    import json
+    import os
+    from chipbench.traffic.open_loop_http_lfm2 import model_config
+    from ray_tpu.inference.cache import snapshot_geometry
+    from ray_tpu.models import hybrid
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "lfm2-8b-a1b-12L.json")) as f:
+        config = json.load(f)
+    engine = config["engine"]
+    cfg, _, _ = model_config(config)
+    assert (cfg.n_short_conv, cfg.n_attention) == (9, 3)
+    assert snapshot_geometry(cfg) == (9, 4096)
+    on_chip = _on(one_chip)
+    bs, rows = engine["kv_block_size"], engine["max_slots"]
+    lay = PoolLayout(3, engine["n_blocks"] + 1, bs, *cfg.kv_geometry[1:])
+    assert lay.shape == (3 * (engine["n_blocks"] + 1), 64, 512)
+    params = jax.tree.map(
+        lambda s: on_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda: hybrid.init_params(cfg,
+                                                  jax.random.PRNGKey(0))))
+    n_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                  for s in jax.tree.leaves(params))
+    assert 7.85e9 < n_bytes < 7.87e9            # 3,929 M bf16 parameters
+    pools = (on_chip(lay.shape, cfg.dtype),) * 2
+    state = (on_chip((9, rows, 2, 2048), cfg.dtype),
+             on_chip((engine["n_blocks"] + 1, 9 * 4096), cfg.dtype))
+    done = {}
+
+    def compiled(which):
+        from ray_tpu.inference import recurrent
+        if which not in done:
+            T = -(-engine["max_seq"] // bs)
+            C = engine["prefill_chunk"]
+            make, n = {
+                "step": (recurrent.make_recurrent_decode_step,
+                         (rows, T + 3)),
+                "chunk": (recurrent.make_recurrent_chunk_fn, (T + C + 3,)),
+                "step_chunk": (recurrent.make_recurrent_step_chunk,
+                               (rows * (T + 3) + T + C + 3,))}[which]
+            kw = {} if which == "step" else {"chunk": C}
+            fn = make(cfg, block_size=bs, n_table=T, **kw)
+            done[which] = fn.lower(params, pools, state,
+                                   on_chip(n, jnp.int32)).compile()
+        return done[which]
+    return cfg, lay, state, engine, compiled
+
+
+@pytest.mark.parametrize("which", ["step", "chunk", "step_chunk"])
+def test_lfm2_programs_fit_and_move_no_pool_and_no_snapshot(lfm2_cell,
+                                                            which):
+    """All three programs compile for the described chip at the
+    published widths and the cell's sizes, fit, and copy neither the K/V
+    pools nor the snapshots (8,193 x 36,864 bf16 = 604 MB, written by
+    ONE scatter of the blocks a pass closes): they enter and leave in
+    place.  Each attention layer is one kernel call on the pool (the
+    one-token programs), each experts layer two grouped matmuls, and
+    the trace's table tells them apart."""
+    from chipbench import lfm2_trace
+    from ray_tpu.inference.recurrent import has_step_chunk
+    cfg, lay, state, engine, compiled = lfm2_cell
+    assert has_step_chunk(cfg, lay)
+    program = compiled(which)
+    _assert_pool_stays_put(program, lay)
+    text = program.as_text()
+    snap = ",".join(map(str, state[1].shape))
+    assert not re.findall(rf"= \(?bf16\[{snap}\]\S* copy(?:-start)?\(", text)
+    mem = program.memory_analysis()
+    # pools and snapshots are aliased to the program's results
+    assert mem.alias_size_in_bytes >= 2 * int(np.prod(lay.shape)) * 2 \
+        + int(np.prod(state[1].shape)) * 2
+    marks = lfm2_trace.marks_of(engine, cfg)
+    gmm = [line for line in text.splitlines()
+           if " custom-call(" in line and "tpu_custom_call" in line
+           and line.strip().startswith("%gmm")]
+    assert len(gmm) == 2 * 10
+    assert {lfm2_trace.label_of(line, marks) for line in gmm} \
+        == {"routed_experts"}
+    calls = _kernel_calls(text)
+    assert len(calls) == (0 if which == "chunk" else 3)
+    assert {lfm2_trace.label_of(line, marks) for line in calls} \
+        <= {"decode_attention"}
+
+
+def test_lfm2_step_chunk_multiplies_by_in_proj_once_a_layer(lfm2_cell):
+    """The rows' tokens and the chunk's are ONE window: a convolution
+    layer's ``in_proj`` product is ``rows + chunk`` rows wide and
+    evaluated once (its barrier), whatever its consumers in the two
+    parts."""
+    cfg, lay, state, engine, compiled = lfm2_cell
+    text = compiled("step_chunk").as_text()
+    w = engine["max_slots"] + engine["prefill_chunk"]
+    products = re.findall(
+        rf"^\s*%\S+ = bf16\[(?:1,)?{w},6144\]\S* (?:dot|convolution)\(.*"
+        rf"short_conv_in_proj", text, re.M)
+    assert len(products) == cfg.n_short_conv, len(products)
+    for part in (engine["max_slots"], engine["prefill_chunk"]):
+        assert not re.findall(
+            rf"bf16\[(?:1,)?{part},6144\]\S* (?:dot|convolution)\(", text)

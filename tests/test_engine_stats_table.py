@@ -3,7 +3,8 @@ types of ``stats()``, the ``/metrics`` series in order, and what a
 replica and a fleet return of several engines.  The literals below were
 recorded at the commit before the table (``ray_tpu/serve/engine_stats.py``)
 existed: they hold the table to what every reader already reads (the
-window pool's rows, marked, are ISSUE 48's).
+window pool's rows, marked, are ISSUE 48's; the state snapshots',
+ISSUE 52's).
 
 Everything runs on CPU with the ``tiny`` configurations of both families.
 """
@@ -64,6 +65,9 @@ STATS_TYPES = {
     # what the latent window kernel walked, tile by tile (ISSUE 49)
     "chunk_pairs_walked": int, "chunk_tiles_plain": int,
     "chunk_tiles_diagonal": int,
+    # the recurrent state kept at block ends (ISSUE 52)
+    "state_snapshot_bytes": int, "state_snapshots_written": int,
+    "state_snapshots_restored": int,
 }
 LOOP_ACCOUNT_TYPES = {
     "ns": dict, "starved_ns": dict, "count": dict, "unaccounted_ns": int,
@@ -165,6 +169,14 @@ SERIES = [
      "keeps K/V only)"),
     ("ray_tpu_inference_state_rows_in_use", "gauge",
      "Decode rows holding a recurrent state"),
+    ("ray_tpu_inference_state_snapshot_bytes", "gauge",
+     "Bytes of the recurrent-state snapshots kept with the paged KV "
+     "pool's blocks (0 = the state has no snapshot form)"),
+    ("ray_tpu_inference_state_snapshots_written_total", "counter",
+     "Blocks closed with the recurrent state at their end kept"),
+    ("ray_tpu_inference_state_snapshots_restored_total", "counter",
+     "Requests whose row took its recurrent state from an adopted "
+     "block's snapshot"),
     ("ray_tpu_inference_window_blocks_held", "gauge",
      "Blocks of the window-attention layers' pool held by rows"),
     ("ray_tpu_inference_window_blocks_allocated_total", "counter",
