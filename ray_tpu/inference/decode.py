@@ -190,22 +190,31 @@ def make_prefill_fn(cfg: GPTConfig, *, mesh=None,
 # the whole pool).
 
 
-# K/V heads up to which a window of queries attends the gathered table
-# as stored (``packed_attention``: every head's product spans the FULL
-# stored width, K/V heads x the arithmetic, bought back where heads are
-# narrower than a lane tile and paid gladly where they are few); above
-# it, heads of whole lane tiles are attended head by head.  The cells
-# measured on the packed form have 25 heads of 64 lanes, and 8 and 2
-# heads of 128.
-PACKED_MAX_HEADS = 8
+def window_by_head(lay: PoolLayout, n_table: int) -> bool:
+    """Whether one row's window of queries over ``lay``'s pools walks
+    the row's table of ``n_table`` blocks a key block at a time, head by
+    head (``head_window_attention``), and is not attended packed over
+    the gathered table (``packed_attention``): wherever the table spans
+    more than ONE key block, on an unsharded pool.
 
-
-def window_by_head(lay: PoolLayout) -> bool:
-    """Whether a window of queries over ``lay``'s pools is attended
-    head by head, a block of keys at a time (``paged_attend``'s
-    ``q_pos``), and not packed over the gathered table (``mask``)."""
-    return (lay.shards == 1 and lay.head_dim % 128 == 0
-            and lay.n_heads > PACKED_MAX_HEADS)
+    The packed form multiplies every head over the full stored width
+    (K/V heads x the arithmetic) of the row's WHOLE table, whatever the
+    window's reach, through a float32 score array [w, h, table span]
+    that goes to HBM and comes back: 604 MB and 3.22 ms a layer at 32
+    query heads over 8 K/V heads of 64 lanes, 1,024 queries and a table
+    of 4,608 keys, where the walk takes 0.16 ms at one key block, 0.43
+    at three and 0.65 at all five, 0.25 where half the chunk's lanes
+    are real (my chip runs, PR 53, ``benchmarks/window_walk.py``: one
+    layer alone; 0.60 against 0.08-0.23 at 8 K/V heads of 128, 256
+    queries and 2,304 keys, 0.21 against 0.07-0.14 at 2 of 128, 128 and
+    3,072).  On a table of one key block the walk IS the packed form's
+    one product, and packed keeps its trick for heads narrower than a
+    lane tile (GPT-2 XL: 25 heads of 64 lanes over 1,024 keys, measured
+    packed).  The cells measured on the walk: 8 K/V heads of 64 lanes
+    over 4,608 keys, 30 of 128 over 8,576, 8 of 128 over 2,304 and over
+    33 k, 2 of 128 over 3,072."""
+    return (lay.shards == 1
+            and n_table * lay.block_size > window_key_block(lay.block_size))
 
 
 def window_key_block(block_size: int) -> int:
@@ -216,12 +225,13 @@ def window_key_block(block_size: int) -> int:
 
 
 def _key_blocks(lay: PoolLayout, tables, q_pos):
-    """ONE row's table walked ``KEY_BLOCK`` keys at a time: whole key
-    blocks, the last one padded with the scratch block (its keys lie
-    past every query).  -> (``read_keys(pool, layer, j)``: key block
-    ``j`` of that pool and layer as stored, [keys, width]; the walk's
-    ``key_block`` and ``n_blocks``: the blocks that hold a key of the
-    window's last query)."""
+    """ONE row's table (``tables`` [1, T]) walked ``KEY_BLOCK`` keys at
+    a time: whole key blocks, the last one padded with the scratch block
+    (its keys lie past every query).  -> (``read_keys(pool, layer,
+    j)``: key block ``j`` of that pool and layer as stored, [keys,
+    width]; the walk's ``key_block`` and ``n_blocks``: the blocks that
+    hold a key of the window's last query, ``q_pos`` its REAL queries'
+    positions)."""
     bs = lay.block_size
     per = window_key_block(bs) // bs
     table = jnp.pad(tables[0], (0, -tables.shape[1] % per))
@@ -239,7 +249,7 @@ def _key_blocks(lay: PoolLayout, tables, q_pos):
 def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
                  mesh=None, rules=None, kv_lengths=None, mask=None,
                  mask_tables=None, q_per_kv: int = 1, scale=None,
-                 q_pos=None, window: int = 0):
+                 q_pos=None, n_valid=None, window: int = 0):
     """Where a window meets the pool, for every model family:
     ``attend_for(layer)`` gives that layer's ``attend(q [b, h, w, hd],
     k, v [b, w, h_kv, hd]) -> o [b, h, w, hd]`` over the (K, V)
@@ -256,21 +266,30 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
         blocks that hold a key, no other.  Under a mesh that splits
         the pool's width it runs per shard (whole heads, then the
         shard's padding), the rows split as the batch is.
-      * ``mask`` — a window of queries, each with its own horizon:
-        the rows' ``tables`` [b, T] are gathered as contexts [b, T*bs,
-        W], keys in position order, heads still packed as stored, and
-        attended so (``packed_attention``); with a mesh the contexts
-        are constrained to the pool's heads sharding.
+      * ``mask`` — a window of queries a row, each with a horizon of
+        its own (the verify window; the GPT family's chunk, whose
+        tables are one key block): the rows' ``tables`` [b, T] are
+        gathered as contexts [b, T*bs, W], keys in position order,
+        heads still packed as stored, and attended so
+        (``packed_attention``); with a mesh the contexts are
+        constrained to the pool's heads sharding.
       * ``q_pos`` [w] — ONE row's window (b = 1) of causal queries at
-        those positions, head by head: the table walked ``KEY_BLOCK``
-        keys at a time, each block gathered and attended under a
-        running softmax (``head_window_attention``); no array of the
-        table's span, no product wider than a head.
-      * both (``make_paged_step_chunk``) — ONE window [1, n + w] that
-        holds ``n`` = ``len(kv_lengths)`` one-token rows and then a
-        window of ``w`` queries: committed together, the first ``n``
-        queries attended as one-token rows of ``tables`` [n, T], the
-        rest under ``mask`` over ``mask_tables`` [1, T], and joined.
+        those positions, the first ``n_valid`` of them real (None:
+        all).  Where the row's table spans more than one key block
+        (``window_by_head``), and for a window layer always: head by
+        head, the table walked ``KEY_BLOCK`` keys at a time up to the
+        block of the last real query's key, each block gathered and
+        attended under a running softmax (``head_window_attention``);
+        no array of the table's span, no product wider than a head,
+        nothing where only padding lanes see.  A table of one key
+        block: the ``mask`` form under those positions' mask.
+      * ``kv_lengths`` and one of the two (``make_paged_step_chunk``,
+        ``recurrent.make_recurrent_step_chunk``) — ONE window [1, n +
+        w] that holds ``n`` = ``len(kv_lengths)`` one-token rows and
+        then a window of ``w`` queries: committed together, the first
+        ``n`` queries attended as one-token rows of ``tables`` [n, T],
+        the rest in their form (``mask``, or ``q_pos`` [w]) over
+        ``mask_tables`` [1, T], and joined.
 
     ``window`` > 0 (the ``kv_lengths`` and ``q_pos`` forms): the pools
     are a window layer's, and a query attends its last ``window`` keys
@@ -279,6 +298,13 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
     bounded by the window; the table's entries before it may name
     anything (the cache gives those blocks back)."""
     held = {"pools": pools}
+    # the table a window of queries attends: its own row's
+    q_tables = tables if mask_tables is None else mask_tables
+    if q_pos is not None and not (
+            window or window_by_head(lay, q_tables.shape[-1])):
+        S = q_tables.shape[-1] * lay.block_size
+        mask = (jnp.arange(S)[None, :] <= q_pos[:, None])[None, None]
+        q_pos = None
     if kv_lengths is not None:
         def sp(*axes):
             return spec_for(axes, rules, mesh)
@@ -295,21 +321,26 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
                     kv_lengths)
 
     def by_head(q, layer):
-        read_keys, walk = _key_blocks(lay, tables, q_pos)
+        read_keys, blocks_of = _key_blocks(
+            lay, q_tables, real_positions(q_pos, n_valid))
         return head_window_attention(
             q[0], lambda j, n: tuple(read_keys(p, layer, j)
                                      for p in held["pools"]),
             q_pos, n_kv_heads=lay.n_heads,
             scale=lay.head_dim ** -0.5 if scale is None else scale,
-            window=window, **walk)[None]
+            window=window, n_valid=n_valid, **blocks_of)[None]
 
-    def packed(q, layer, of):
+    def packed(q, layer):
         ctx_k, ctx_v = (
-            gpt._constrain(lay.read(p, layer, of),
+            gpt._constrain(lay.read(p, layer, q_tables),
                            ("batch", None, "heads"), mesh, rules)
             for p in held["pools"])
         return packed_attention(q, ctx_k, ctx_v, groups=lay.shards,
                                 q_per_kv=q_per_kv, scale=scale, mask=mask)
+
+    def queries(q, layer):
+        """The window of queries, in its form."""
+        return by_head(q, layer) if mask is None else packed(q, layer)
 
     def attend_for(layer):
         def attend(q, k, v):
@@ -318,18 +349,16 @@ def paged_attend(lay: PoolLayout, pools, blocks, offsets, tables, *,
             held["pools"] = tuple(
                 lay.commit(p, layer, blocks, offsets, x)
                 for p, x in zip(held["pools"], new))
-            if q_pos is not None:
-                return by_head(q, layer)
-            if mask is None:
-                return rows(q, layer)
             if kv_lengths is None:
-                return packed(q, layer, tables)
+                return queries(q, layer)
+            if mask is None and q_pos is None:
+                return rows(q, layer)
             n = kv_lengths.shape[0]
             # [1, h, n, hd] <-> [n, h, 1, hd]: a row's one query
             o = rows(q[:, :, :n].transpose(2, 1, 0, 3), layer)
             return jnp.concatenate(
-                [o.transpose(2, 1, 0, 3),
-                 packed(q[:, :, n:], layer, mask_tables)], axis=2)
+                [o.transpose(2, 1, 0, 3), queries(q[:, :, n:], layer)],
+                axis=2)
         return attend
     return attend_for, held
 

@@ -68,9 +68,9 @@ chunk and decoding rows runs them as ONE program where the model has
 one (``_step_chunk``: the weights stream once a pass; the GPT family's
 decode.make_paged_step_chunk without speculation or routed experts,
 the hybrid family's recurrent.make_recurrent_step_chunk for the layouts
-whose every sublayer kind takes a window in two parts — Mamba-2,
-attention over K/V blocks, routed experts, the dense MLP; not latent
-attention, the delta rule or the head-by-head window form — which each
+whose every sublayer kind takes a window in two parts — Mamba-2, the
+short convolution, attention over K/V blocks, routed experts, the dense
+MLP; not latent attention, the delta rule or window layers — which each
 seam's ``build`` derives from the configuration): the pass's last chunk
 is prepared and packed, and launched by the decode step.  A prompt's
 greedy first token is its last
@@ -107,7 +107,7 @@ from ray_tpu.inference.decode import (SpeculationUnsupported,
                                       make_spec_verify_step,
                                       ngram_propose, pack_chunk,
                                       pack_step, pack_step_chunk,
-                                      window_key_block)
+                                      window_by_head, window_key_block)
 from ray_tpu.inference.recurrent import (has_step_chunk,
                                          make_recurrent_chunk_fn,
                                          make_recurrent_decode_step,
@@ -550,14 +550,15 @@ class _KVAndState(_Seam):
             cfg, chunk=ec.prefill_chunk, block_size=bs,
             n_table=eng.pool.blocks_per_seq)
         # the two as ONE program where every sublayer kind of the model
-        # takes a window in two parts (Mamba-2, attention over K/V
-        # blocks, routed experts, the dense MLP); a latent, delta-rule
-        # or head-by-head attention sublayer: the two back to back
+        # takes a window in two parts (Mamba-2, the short convolution,
+        # attention over K/V blocks, routed experts, the dense MLP); a
+        # latent, delta-rule or window-attention sublayer: the two back
+        # to back
         eng._step_chunk = (
             make_recurrent_step_chunk(
                 cfg, chunk=ec.prefill_chunk, block_size=bs,
                 n_table=eng.pool.blocks_per_seq)
-            if has_step_chunk(cfg, eng.pool.layout) else None)
+            if has_step_chunk(cfg) else None)
 
     @staticmethod
     def operands(eng) -> tuple:
@@ -763,6 +764,12 @@ class InferenceEngine:
         self._linear = getattr(cfg, "n_linear", 0) > 0
         # a model with latent-attention layers (ONE latent pool)
         self._latent = getattr(cfg, "n_latent", 0) > 0
+        # the layers whose chunk program walks a row's table a key block
+        # at a time (what the hybrid family's ``paged_attend`` derives)
+        self._walk_layers = (
+            cfg.n_attention if self._seam is _KVAndState
+            and not self._latent and window_by_head(
+                self.pool.layout, self.pool.blocks_per_seq) else 0)
         # a model whose full blocks carry a state snapshot
         self._snapshots = self.pool.snapshots
         # the loop thread's time by phase, always on; ``engine.account``
@@ -1433,8 +1440,11 @@ class InferenceEngine:
             counts.linear_chunk_tokens += n_q
         if self._snapshots:
             counts.state_snapshots_written += (pos + n_q) // bs - pos // bs
+        key_block = window_key_block(bs)
+        walked = -(-(pos + n_q) // key_block)
+        counts.chunk_key_blocks_walked += self._walk_layers * walked
         if self._latent:
-            tiles = window_tiles(pos, n_q, C, window_key_block(bs))
+            tiles = window_tiles(pos, n_q, C, key_block)
             counts.chunk_pairs_walked += tiles["pairs"]
             counts.chunk_tiles_plain += tiles["plain"]
             counts.chunk_tiles_diagonal += tiles["diagonal"]
@@ -1445,6 +1455,9 @@ class InferenceEngine:
                 0, pos - self._window + 1)
             counts.window_query_keys += int(np.minimum(
                 np.arange(pos, pos + n_q) + 1, self._window).sum())
+            # a window layer's walk starts at its first query's window
+            counts.chunk_key_blocks_walked += self.cfg.n_window * (
+                walked - max(0, pos - self._window + 1) // key_block)
         chunk_toks = np.zeros(C, np.int32)
         chunk_toks[:n_q] = prompt[pos:pos + n_q]
         with self._acct.phase("pack") as up:

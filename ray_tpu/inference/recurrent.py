@@ -71,11 +71,13 @@ by table, routed experts (dropless: a part's tokens are dropped by
 nobody), the dense MLP — the granite and nemotron layouts.  Every
 matrix then streams from HBM once a pass, for ``rows + chunk`` tokens,
 and only the recurrences and the attention run a part at a time, in the
-forms the two programs give them.  A latent-attention sublayer, a
-delta-rule sublayer or a window attended head by head has no such form
-yet, and a model with one keeps the pass of two programs
-(``has_step_chunk``: derived from the sublayer kinds and the pool's
-layout, by the engine, where it builds the programs).
+forms the two programs give them (the chunk's queries walk their row's
+table or attend it packed, as ``paged_attend`` derives from the table's
+span: the fused pass does not depend on which).  A latent-attention
+sublayer, a delta-rule sublayer or a window layer has no such form yet,
+and a model with one keeps the pass of two programs
+(``has_step_chunk``: derived from the sublayer kinds, by the engine,
+where it builds the programs).
 """
 
 from __future__ import annotations
@@ -88,7 +90,7 @@ import jax.numpy as jnp
 from ray_tpu.inference.cache import PoolLayout, snapshot_geometry
 from ray_tpu.inference.decode import (_cached, _step_indices, latent_attend,
                                       paged_attend, unpack_chunk,
-                                      unpack_step, window_by_head)
+                                      unpack_step)
 from ray_tpu.models import hybrid
 from ray_tpu.models.hybrid import HybridConfig
 
@@ -262,31 +264,25 @@ def _attend_over(cfg, lay, pools, blocks, offsets, tables, *,
     layers keep K/V heads or one latent: ONE token a row that attends
     its first ``kv_lengths`` keys, or one row's window of queries at
     positions ``q_pos`` [w], each over the keys up to its own — or, for
-    K/V heads attended packed, both in ONE window (``paged_attend``'s
-    fourth form): the one-token rows of ``tables`` and then the window
-    of the row whose table is ``q_table`` [1, T].  ``window``: the
-    pools and tables are the window layers', attended within it.  A
-    model with window layers attends a row's window of queries head by
-    head whatever its head count: the packed form multiplies every head
-    over the full stored width of the row's WHOLE table, and such a
-    model is served at contexts where that is most of a chunk's time.
-    ``n_valid``: the real queries of the window, its first (the latent
-    form alone takes note: its kernel skips what only padding lanes
-    see)."""
+    K/V heads, both in ONE window (``paged_attend``'s fourth form): the
+    one-token rows of ``tables`` and then the window of the row whose
+    table is ``q_table`` [1, T].  ``window``: the pools and tables are
+    the window layers', attended within it.  ``n_valid``: the real
+    queries of the window, its first (neither form's walk goes where
+    only the padding lanes behind them see).  Which form attends a
+    window of queries — the walk over key blocks or, on a table of one
+    key block, the packed form under a mask — is ``paged_attend``'s to
+    derive from the table it is handed; no program here depends on
+    it."""
     if cfg.value_lanes is not None:
         return latent_attend(lay, pools, blocks, offsets, tables,
                              scale=cfg.attention_multiplier,
                              kv_lengths=kv_lengths, q_pos=q_pos,
                              n_valid=n_valid)
-    mask = None
-    if q_pos is not None and not (window_by_head(lay) or cfg.n_window):
-        S = tables.shape[-1] * lay.block_size
-        mask = (jnp.arange(S)[None, :] <= q_pos[:, None])[None, None]
-        q_pos = None
     return paged_attend(lay, pools, blocks, offsets, tables,
                         q_per_kv=cfg.n_heads // cfg.n_kv_heads,
                         scale=cfg.attention_multiplier,
-                        kv_lengths=kv_lengths, mask=mask, q_pos=q_pos,
+                        kv_lengths=kv_lengths, q_pos=q_pos, n_valid=n_valid,
                         mask_tables=q_table, window=window)
 
 
@@ -348,9 +344,11 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
     decode step.)
 
     Prompt positions ``start .. start + n_valid`` of decode row ``row``:
-    attention as decode.py's chunk program (each query masked to its own
-    causal horizon over the gathered table; a latent layer walks the
-    table a block of keys at a time and gathers no table), the Mamba
+    each query attends the keys up to its own position (the row's table
+    walked a block of keys at a time up to the last real query's, by
+    K/V heads or latents; a table of one key block gathered and
+    attended packed under the causal mask, as decode.py's chunk program
+    does), the Mamba
     layers as one window from the row's state, which advances by the
     ``n_valid`` real tokens only — the padding of a partial last chunk
     is the identity on it.
@@ -401,15 +399,14 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
     return _cached(("recurrent_chunk", bs, T, C), cfg, None, None, build)
 
 
-def has_step_chunk(cfg: HybridConfig, lay: PoolLayout) -> bool:
+def has_step_chunk(cfg: HybridConfig) -> bool:
     """Whether ``make_recurrent_step_chunk`` has a program for this
-    model over pools of layout ``lay``: every sublayer kind must have
-    the two-part form (``hybrid.TWO_PART``: Mamba-2, attention over K/V
-    blocks, routed experts, the dense MLP; not latent attention, not
-    the delta rule), and a window of queries must be attended packed
-    under its mask, not head by head (``window_by_head``)."""
-    return ({kind for _, kind in cfg.sublayers} <= hybrid.TWO_PART
-            and not (cfg.n_attention and window_by_head(lay)))
+    model: every sublayer kind must have the two-part form
+    (``hybrid.TWO_PART``: Mamba-2, the short convolution, attention over
+    K/V blocks — whichever form attends its window of queries —, routed
+    experts, the dense MLP; not latent attention, not the delta rule,
+    not a window layer)."""
+    return {kind for _, kind in cfg.sublayers} <= hybrid.TWO_PART
 
 
 def make_recurrent_step_chunk(cfg: HybridConfig, *, chunk: int,
@@ -435,7 +432,9 @@ def make_recurrent_step_chunk(cfg: HybridConfig, *, chunk: int,
     top-k`` assignments.  A mixer runs its two parts in the forms they
     have in the two programs: attention commits the whole window's K/V
     and attends the first ``b`` queries as one-token rows and the last
-    ``C`` under the chunk's mask over its row's gathered table; Mamba-2
+    ``C`` over the chunk row's table in the form the chunk program
+    gives them (the walk over key blocks, or packed under the chunk's
+    mask: ``paged_attend``); Mamba-2
     advances the ``b`` rows' state where it lies in the pool, and the
     chunk's row's from a slice of the pool that goes back there (that
     row is inactive in the step: the two touch different rows).  The
@@ -461,7 +460,8 @@ def make_recurrent_step_chunk(cfg: HybridConfig, *, chunk: int,
             attend_for, kv = _attend_over(
                 cfg, lay, pools, jnp.concatenate([bidx, c_bidx])[None],
                 jnp.concatenate([off, c_off])[None], tables,
-                kv_lengths=kv_len, q_pos=pos, q_table=table[None])
+                kv_lengths=kv_len, q_pos=pos, q_table=table[None],
+                n_valid=n_valid)
             conv, ssm = state or (None, None)
             held = {"conv": [], "ssm": ssm}
             keep = marked = None

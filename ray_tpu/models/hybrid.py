@@ -1213,7 +1213,8 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
     belongs to, (conv, ssm, row) — a short convolution's (conv, marks,
     row); an attention sublayer's ``attend``
     treats the two parts apart itself (``decode.paged_attend`` with a
-    length a row AND a mask); experts count the parts apart: counts [2,
+    length a row AND the window's positions); experts count the parts
+    apart: counts [2,
     E_held], total [2].  Linear and latent attention have no such
     form."""
     h = x if cfg.norm_output else _rms_norm(x, lp["norm"], cfg.rms_eps)

@@ -441,6 +441,12 @@ _MASKED = -1e30     # a masked score: finite, so no (-inf) - (-inf)
 # w] scores are worked through in tiles of this size (the whole array
 # where it is smaller), each done by what its place demands
 WINDOW_TILE = (512, 256)
+# ``head_window_attention``'s: a visit of a tile costs ~0.7 us beside its
+# arithmetic (4.3 us a head and 1,024 x 1,024 block in 512-wide tiles,
+# 5.6 in 256-wide, at 64 and at 128 lanes a head: my chip runs, PR 53,
+# benchmarks/window_walk.py), so a head's tiles are as wide as the
+# padding of a half-real chunk still lets it skip
+HEAD_TILE = (512, 512)
 
 
 def _spans(n: int, tile: int) -> list:
@@ -486,40 +492,45 @@ def window_tiles(pos: int, n_q: int, w: int,
     return out
 
 
-def _window_block_kernel(k0_ref, edge_ref, pos_ref, qn_ref, qr_ref, kn_ref,
-                         kr_ref, vt_ref, m_ref, l_ref, acc_ref, m_out, l_out,
-                         acc_out, *, tile):
-    """One head's window of queries (scaled) against one block of
-    decompressed keys, the running softmax carried in and out.
-    Everything is held TRANSPOSED — scores [keys, queries], values [dv,
+def _attend_tiles(scores, vt_ref, k0, edge_ref, pos_ref, carry_in, carry_out,
+                  *, n: int, w: int, tile, window: int = 0):
+    """One head's window of ``w`` queries against one block of ``n``
+    keys, the running softmax carried in (``carry_in``: m [1, w], l
+    [1, w], acc [dv, w]) and out: the body the window kernels share.
+    Everything is held TRANSPOSED — ``scores(ks, qs)`` gives the float32
+    scores [keys, queries] of a tile, the values are ``vt_ref`` [dv,
     keys], the running output [dv, queries] — so that a query's maximum
     and sum are lane-dense rows [1, queries] and no product needs a
     transposed operand: the scores never leave VMEM.
 
     The scores are worked through in tiles of ``tile`` (keys, queries),
     and a tile is done by what its place demands (``_tile_kind``, from
-    the block's first key position ``k0_ref`` and ``edge_ref``: a query
+    the block's first key position ``k0`` and ``edge_ref``: a query
     tile's least position and its real queries' greatest): UNSEEN — no
     product, the carry as it came; PLAIN — products, maximum, exp, sum
     and rescale with no mask; DIAGONAL — the same under the mask of
-    each query's own position (``pos_ref``).  Where all of the block's
-    tiles are plain for a tile of queries (every block but the last of
-    a walk) they are done as one: one maximum and one rescale of the
-    running output, not one a tile."""
+    each query's own position (``pos_ref`` [1, w]).  Where all of the
+    block's tiles are plain for a tile of queries (every block but the
+    last of a walk) they are done as one: one maximum and one rescale
+    of the running output, not one a tile.
+
+    ``window`` > 0: a query sees its last ``window`` keys only
+    (``pos_ref`` [2, w]: below a query's position, the last key
+    position it no longer sees).  A tile wholly behind its queries'
+    windows is unseen too, and every other one is masked."""
     f32 = jnp.float32
-    nt = (((1,), (1,)), ((), ()))
-    k0 = k0_ref[0]
+    m_ref, l_ref, acc_ref = carry_in
+    m_out, l_out, acc_out = carry_out
 
     def attend(ks, qs, k_lo):
         """The tile's update of its queries' carry; ``k_lo``: the tile's
         first key position where the causal edge crosses it."""
-        s = (lax.dot_general(kn_ref[ks, :], qn_ref[qs, :], nt,
-                             preferred_element_type=f32)
-             + lax.dot_general(kr_ref[ks, :], qr_ref[qs, :], nt,
-                               preferred_element_type=f32))
+        s = scores(ks, qs)
         if k_lo is not None:
-            seen = (lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                    <= pos_ref[:, qs] - k_lo)              # [keys, queries]
+            key = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            seen = key <= pos_ref[0:1, qs] - k_lo          # [keys, queries]
+            if window:
+                seen &= key > pos_ref[1:2, qs] - k_lo
             s = jnp.where(seen, s, _MASKED)
         m_prev = m_out[:, qs]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
@@ -534,14 +545,19 @@ def _window_block_kernel(k0_ref, edge_ref, pos_ref, qn_ref, qr_ref, kn_ref,
             vt_ref[:, ks], p.astype(vt_ref.dtype),
             preferred_element_type=f32)
 
-    n, w = kn_ref.shape[0], qn_ref.shape[0]
-
     def query_tile(t, qs):
         """Query tile ``t``, the window's lanes ``qs``, against the
         block."""
         m_out[:, qs], l_out[:, qs] = m_ref[:, qs], l_ref[:, qs]
         acc_out[:, qs] = acc_ref[:, qs]
         edge = edge_ref[2 * t], edge_ref[2 * t + 1]
+        if window:
+            for a, tk in _spans(n, tile[0]):
+                k_lo, k_hi = k0 + a, k0 + a + tk - 1
+                unseen, _ = _tile_kind(k_lo, k_hi, *edge)
+                pl.when(jnp.logical_not(unseen | (k_hi <= edge[0] - window)))(
+                    functools.partial(attend, slice(a, a + tk), qs, k_lo))
+            return
         # a block of nothing but plain tiles is taken as ONE
         _, whole = _tile_kind(k0, k0 + n - 1, *edge)
         crossed = jnp.logical_not(whole)
@@ -562,6 +578,25 @@ def _window_block_kernel(k0_ref, edge_ref, pos_ref, qn_ref, qr_ref, kn_ref,
             t, pl.ds(pl.multiple_of(t * tq, tq), tq)), None)
     if w % tq:
         query_tile(full, slice(full * tq, w))
+
+
+def _window_block_kernel(k0_ref, edge_ref, pos_ref, qn_ref, qr_ref, kn_ref,
+                         kr_ref, vt_ref, m_ref, l_ref, acc_ref, m_out, l_out,
+                         acc_out, *, tile):
+    """One head's window of queries (scaled) against one block of
+    decompressed keys, a key the sum of its two parts' products
+    (``_attend_tiles``)."""
+    nt = (((1,), (1,)), ((), ()))
+
+    def scores(ks, qs):
+        return (lax.dot_general(kn_ref[ks, :], qn_ref[qs, :], nt,
+                                preferred_element_type=jnp.float32)
+                + lax.dot_general(kr_ref[ks, :], qr_ref[qs, :], nt,
+                                  preferred_element_type=jnp.float32))
+
+    _attend_tiles(scores, vt_ref, k0_ref[0], edge_ref, pos_ref,
+                  (m_ref, l_ref, acc_ref), (m_out, l_out, acc_out),
+                  n=kn_ref.shape[0], w=qn_ref.shape[0], tile=tile)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -602,6 +637,15 @@ def _window_block(qn, qr, k_nope, k_rope, v_t, pos, edges, k0, carry, *,
         name="latent_window_attention",
     )(jnp.asarray(k0, jnp.int32)[None], edges, pos, qn, qr, k_nope, k_rope,
       v_t, *carry)
+
+
+def _tile_edges(pos, real, tq: int):
+    """What ``_attend_tiles`` places a tile of queries by, [2 x tiles]
+    int32: a query tile's least position and its real queries' greatest
+    (``real``: ``real_positions``)."""
+    return jnp.stack([reach(x[q0:q0 + n])
+                      for q0, n in _spans(pos.shape[0], tq)
+                      for reach, x in ((jnp.min, pos), (jnp.max, real))])
 
 
 def real_positions(q_pos, n_valid=None):
@@ -658,10 +702,7 @@ def latent_window_attention(q_nope, q_rope, read_keys, w_uk, w_uv, q_pos, *,
         n_blocks = last // key_block + 1
     qn, qr = ((q.transpose(1, 0, 2).astype(f32) * scale).astype(q.dtype)
               for q in (q_nope, q_rope))
-    # a query tile's least position and its real queries' greatest
-    edges = jnp.stack([reach(x[q0:q0 + tq])
-                       for q0, tq in _spans(w, tile[1])
-                       for reach, x in ((jnp.min, pos), (jnp.max, real))])
+    edges = _tile_edges(pos, real, tile[1])
 
     def body(j, carry):
         lat = read_keys(j, key_block)
@@ -683,36 +724,69 @@ def latent_window_attention(q_nope, q_rope, read_keys, w_uk, w_uv, q_pos, *,
     return o.transpose(2, 0, 1).reshape(w, h * dv)
 
 
-def _head_block_kernel(k0_ref, pos_ref, q_ref, k_ref, vt_ref, m_ref, l_ref,
-                       acc_ref, m_out, l_out, acc_out, *, scale: float,
-                       window: bool = False):
+def _head_block_kernel(k0_ref, edge_ref, pos_ref, q_ref, k_ref, vt_ref, m_ref,
+                       l_ref, acc_ref, m_out, l_out, acc_out, *, scale: float,
+                       tile, window: int):
     """``_window_block_kernel`` for keys that are stored a head's lanes
     each: one head's window of queries against one block of its K/V
-    head's keys, held transposed the same way.  ``window``: ``pos_ref``
-    [2, queries] holds each query's position and, below it, the last
-    key position it no longer sees."""
-    f32 = jnp.float32
-    s = lax.dot_general(k_ref[...], q_ref[...], (((1,), (1,)), ((), ())),
-                        preferred_element_type=f32) * scale
-    k_pos = k0_ref[0] + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    if window:
-        seen = (k_pos <= pos_ref[0:1, :]) & (k_pos > pos_ref[1:2, :])
-    else:
-        seen = k_pos <= pos_ref[...]                   # [keys, queries]
-    s = jnp.where(seen, s, _MASKED)
-    m_prev = m_ref[...]
-    m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-    alpha = jnp.exp(m_prev - m_next)
-    p = jnp.where(seen, jnp.exp(s - m_next), 0.0)
-    m_out[...] = m_next
-    l_out[...] = l_ref[...] * alpha + jnp.sum(p, axis=0, keepdims=True)
-    acc_out[...] = acc_ref[...] * alpha + jnp.dot(
-        vt_ref[...], p.astype(vt_ref.dtype), preferred_element_type=f32)
+    head's keys (``_attend_tiles``; the scores scaled in float32, as
+    ``packed_attention`` scales them)."""
+    def scores(ks, qs):
+        return lax.dot_general(k_ref[ks, :], q_ref[qs, :],
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32) * scale
+
+    _attend_tiles(scores, vt_ref, k0_ref[0], edge_ref, pos_ref,
+                  (m_ref, l_ref, acc_ref), (m_out, l_out, acc_out),
+                  n=k_ref.shape[0], w=q_ref.shape[0], tile=tile,
+                  window=window)
+
+
+@functools.partial(jax.jit, static_argnames=("rep", "scale", "tile", "window",
+                                             "interpret"))
+def _head_block(q, k, v_t, pos, edges, k0, carry, *, rep, scale, tile,
+                window, interpret):
+    """``carry`` (m [h, 1, w], l [h, 1, w], acc [h, hd, w]) advanced by
+    one block of keys: one grid step a query head, the carry updated in
+    place; the ``rep`` query heads of a K/V head follow one another, so
+    its block is read once.  ``k``: the block as stored, [n, >= kv x
+    hd], where a head's lanes are whole tiles, or a head each, [kv, n,
+    hd].  (Jitted as ``_window_block`` is: the layers lower the kernel
+    once.)"""
+    from jax.experimental.pallas import tpu as pltpu
+
+    h, w, hd = q.shape
+    n = v_t.shape[-1]
+
+    def per_head(*shape):
+        return pl.BlockSpec((None, *shape), lambda i: (i, 0, 0))
+
+    k_spec = (pl.BlockSpec((n, hd), lambda i: (0, i // rep)) if k.ndim == 2
+              else pl.BlockSpec((None, n, hd), lambda i: (i // rep, 0, 0)))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    stats = [per_head(1, w), per_head(1, w), per_head(hd, w)]
+    return pl.pallas_call(
+        functools.partial(_head_block_kernel, scale=scale, tile=tile,
+                          window=window),
+        grid=(h,),
+        in_specs=[smem, smem, pl.BlockSpec(pos.shape, lambda i: (0, 0)),
+                  per_head(w, hd), k_spec,
+                  pl.BlockSpec((None, hd, n), lambda i: (i // rep, 0, 0))]
+        + stats,
+        out_specs=stats,
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in carry],
+        input_output_aliases={6: 0, 7: 1, 8: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name="head_window_attention",
+    )(jnp.asarray(k0, jnp.int32)[None], edges, pos, q, k, v_t, *carry)
 
 
 def head_window_attention(q, read_keys, q_pos, *, n_kv_heads: int,
                           scale: float, key_block: int = KEY_BLOCK,
-                          n_blocks=None, window: int = 0):
+                          n_blocks=None, window: int = 0, n_valid=None):
     """ONE row's window of queries over the row's cached K/V, head by
     head, ``key_block`` keys at a time under a running softmax.
 
@@ -724,65 +798,47 @@ def head_window_attention(q, read_keys, q_pos, *, n_kv_heads: int,
     q_pos     [w] int32: a query attends the keys at positions <= its
               own (key 0 is every query's)
     n_blocks  key blocks walked; None: those that hold a key of the
-              window's last query
+              window's last real query
     window    > 0: a query attends its last ``window`` keys only (its
               own among them), and the walk starts at the block that
               holds the first key of the window's FIRST query: the
               work is bounded by ``window + w`` keys, whatever the
               row's length (what ``read_keys`` gives for the blocks
               before may be anything)
+    n_valid   the window's first ``n_valid`` queries are real, the rest
+              padding lanes that attend nothing (their output is
+              finite and nothing more); None: all are real
     -> [h, w, hd]
 
-    For heads of whole lane tiles (``hd`` a multiple of 128), where a
-    head's keys ARE a tile-aligned slice of the stored block: each
-    query head multiplies its own K/V head's lanes only (``h /
-    n_kv_heads`` consecutive query heads share one), where
-    ``packed_attention`` multiplies the full stored width a head —
-    ``n_kv_heads`` x the arithmetic and a float32 score array of the
-    whole table.  One Pallas kernel a block, a grid step a head, the
-    scores in VMEM (``latent_window_attention``'s walk); the values of
-    a block are transposed once, [n_kv_heads, hd, n]."""
-    from jax.experimental.pallas import tpu as pltpu
-
+    Each query head multiplies its own K/V head's lanes only (``h /
+    n_kv_heads`` consecutive query heads share one, and one read of its
+    block), where ``packed_attention`` multiplies the full stored width
+    a head — ``n_kv_heads`` x the arithmetic — and holds a float32
+    score array of the whole table.  One Pallas kernel a block, a grid
+    step a head, the scores in VMEM a tile at a time and the tiles no
+    real query sees skipped (``latent_window_attention``'s walk and
+    ``_attend_tiles``' body); the values of a block are transposed
+    once, [n_kv_heads, hd, n].  Where a head is whole lane tiles (``hd``
+    a multiple of 128) its keys ARE a tile-aligned slice of the stored
+    block; narrower heads' keys are re-laid beside the values, a head
+    each [n_kv_heads, n, hd], and contracted over their ``hd`` lanes."""
     from ray_tpu.ops.flash_attention import _interpret_mode
 
     f32 = jnp.float32
     h, w, hd = q.shape
-    rep = h // n_kv_heads
-    last = jnp.max(q_pos)
+    kv, n = n_kv_heads, key_block
+    real = real_positions(q_pos, n_valid)
+    last = jnp.max(real)
     if n_blocks is None:
-        n_blocks = last // key_block + 1
-    pos = q_pos.astype(jnp.int32)[None, :]
+        n_blocks = last // n + 1
+    edges = _tile_edges(q_pos.astype(jnp.int32), real, HEAD_TILE[1])
+    # the padding lanes lie before every key: masked wherever a mask is
+    pos = real[None, :]
     first, j0 = 0, 0
     if window:
         pos = jnp.concatenate([pos, pos - window])
         first = jnp.maximum(jnp.min(q_pos) - window + 1, 0)
-        j0 = first // key_block
-    n = key_block
-
-    def per_head(*shape):
-        return pl.BlockSpec((None, *shape), lambda i: (i, 0, 0))
-
-    stats = [per_head(1, w), per_head(1, w), per_head(hd, w)]
-    call = pl.pallas_call(
-        functools.partial(_head_block_kernel, scale=scale,
-                          window=bool(window)),
-        grid=(h,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec(pos.shape, lambda i: (0, 0)), per_head(w, hd),
-                  pl.BlockSpec((n, hd), lambda i: (0, i // rep)),
-                  pl.BlockSpec((None, hd, n), lambda i: (i // rep, 0, 0))]
-        + stats,
-        out_specs=stats,
-        out_shape=[jax.ShapeDtypeStruct((h, 1, w), f32)] * 2
-        + [jax.ShapeDtypeStruct((h, hd, w), f32)],
-        input_output_aliases={5: 0, 6: 1, 7: 2},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=64 << 20),
-        interpret=_interpret_mode(),
-        name="head_window_attention",
-    )
+        j0 = first // n
 
     def body(j, carry):
         k, v = read_keys(j, n)
@@ -792,14 +848,19 @@ def head_window_attention(q, read_keys, q_pos, *, n_kv_heads: int,
         if window:
             seen &= k_pos >= first
         v = jnp.where(seen[:, None], v, jnp.zeros_like(v))
-        v_t = v[:, :n_kv_heads * hd].reshape(n, n_kv_heads, hd)
-        return tuple(call(jnp.asarray(j * n, jnp.int32)[None], pos, q, k,
-                          v_t.transpose(1, 2, 0), *carry))
+        v_t = v[:, :kv * hd].reshape(n, kv, hd).transpose(1, 2, 0)
+        if hd % 128:
+            k = k[:, :kv * hd].reshape(n, kv, hd).transpose(1, 0, 2)
+        return tuple(_head_block(
+            q, k, v_t, pos, edges, j * n, carry, rep=h // kv, scale=scale,
+            tile=HEAD_TILE, window=window, interpret=_interpret_mode()))
 
     init = (jnp.full((h, 1, w), _MASKED, f32), jnp.zeros((h, 1, w), f32),
             jnp.zeros((h, hd, w), f32))
     _, l, acc = lax.fori_loop(j0, n_blocks, body, init)
-    return (acc / l).astype(q.dtype).transpose(0, 2, 1)
+    # a padding lane in a tile of nothing else was never visited: l 0
+    o = acc / jnp.where(l > 0, l, 1.0)
+    return o.astype(q.dtype).transpose(0, 2, 1)
 
 
 # VMEM the latent kernel's wave buffers take (ONE pool, double

@@ -156,6 +156,13 @@ ROWS: tuple[Row, ...] = (
     _counter("chunk_query_keys", "ray_tpu_inference_chunk_query_keys_total",
              "(query, key) pairs under the causal mask, summed over "
              "prefill chunk passes"),
+    # 0 where the table is one key block and the window is attended
+    # packed over it (``decode.window_by_head``), and for a latent layout
+    _counter("chunk_key_blocks_walked",
+             "ray_tpu_inference_chunk_key_blocks_walked_total",
+             "Key blocks the head-by-head window form walked, a layer "
+             "each (up to the block of the chunk's last real key), summed "
+             "over prefill chunk passes"),
     # 0 for a model with no latent-attention layer: what its window
     # kernel walked of those pairs, tile by tile
     # (``ops/attention.window_tiles``: the kernel's own classification)

@@ -314,6 +314,24 @@ def test_xl_step_chunk_moves_no_pool(xl, xl_compiled):
     _assert_pool_stays_put(xl_compiled("step_chunk"), xl[3])
 
 
+@pytest.mark.parametrize("program", ["chunk", "step_chunk"])
+def test_xl_chunk_stays_on_the_packed_form(xl, xl_compiled, program):
+    """XL's table is ONE key block (64 blocks of 16 = 1,024 keys), so a
+    chunk's queries are derived onto the packed form whatever family's
+    program asks (``decode.window_by_head``: the walk of one block IS
+    the packed product, and packed keeps the lane trick for 25 heads of
+    64): no window kernel in the programs, the gathered table there."""
+    from ray_tpu.inference.decode import window_by_head
+    cfg, _, _, lay = xl
+    T = cfg.max_seq // lay.block_size
+    assert T * lay.block_size == 1024 and not window_by_head(lay, T)
+    assert window_by_head(lay, T + 1)
+    text = xl_compiled(program).as_text()
+    assert not _kernel_calls(text, "head_window_attention")
+    assert re.search(rf"bf16\[(?:1,)?(?:{T},{lay.block_size}|1024),"
+                     rf"{lay.width}\]\S* (?:gather|fusion)\(", text)
+
+
 def test_xl_step_chunk_reads_each_layer_s_weights_once(xl, xl_compiled):
     """What ISSUE 41 bought: in the pass that holds a chunk and decoding
     rows, the 32 rows' tokens and the chunk's 32 are ONE window of the
@@ -787,7 +805,7 @@ def fused_cells(hybrid_cell, nano_cell):
     def compiled(name):
         if name not in done:
             cfg, on_chip, params, pool, lay, conv, ssm, rows, C = cells[name]
-            assert has_step_chunk(cfg, lay)
+            assert has_step_chunk(cfg)
             T = cfg.max_seq // lay.block_size
             fused = make_recurrent_step_chunk(
                 cfg, chunk=C, block_size=lay.block_size, n_table=T)
@@ -810,6 +828,29 @@ def test_hybrid_step_chunk_fits_and_moves_no_pool(fused_cells, name):
     _assert_pool_stays_put(compiled, lay)
     _assert_state_stays_put(compiled, ssm)
     _no_expert_stack_is_copied(compiled.as_text(), params)
+
+
+@pytest.mark.parametrize("name", ["granite", "nano"])
+def test_hybrid_step_chunk_walks_the_chunk_row_s_table(fused_cells, name):
+    """Both cells' tables are more than one key block (2,304 and 3,072
+    keys), so since PR 53 the chunk part of the fused window walks its
+    row's table head by head: one ``head_window_attention`` call an
+    attention layer beside the one-token kernel's, no gather of the
+    table, no float32 scores of its span — and each cell's trace table
+    leaves the kernel in ``other``, where the packed form's time lay."""
+    from chipbench import nemotron_trace, scoped_trace
+    from ray_tpu.inference.decode import window_by_head
+    cfg, params, lay, ssm, rows, C, compiled, _ = fused_cells(name)
+    assert window_by_head(lay, cfg.max_seq // lay.block_size)
+    text = compiled.as_text()
+    calls = _kernel_calls(text, "head_window_attention")
+    assert len(calls) == len(_kernel_calls(text)) == cfg.n_attention
+    assert f"bf16[1,{cfg.max_seq},{lay.width}]" not in text
+    assert f"bf16[{cfg.max_seq},{lay.width}]" not in text
+    _no_table_span_by_heads(text, cfg, lay, {"max_seq": cfg.max_seq})
+    for line in calls:
+        assert scoped_trace.label_of(line, (rows, C)) == "other"
+        assert nemotron_trace.label_of(line) == "other"
 
 
 @pytest.mark.parametrize("name", ["granite", "nano"])
@@ -1260,7 +1301,7 @@ def test_lfm2_programs_fit_and_move_no_pool_and_no_snapshot(lfm2_cell,
     from chipbench import lfm2_trace
     from ray_tpu.inference.recurrent import has_step_chunk
     cfg, lay, state, engine, compiled = lfm2_cell
-    assert has_step_chunk(cfg, lay)
+    assert has_step_chunk(cfg)
     program = compiled(which)
     _assert_pool_stays_put(program, lay)
     text = program.as_text()
@@ -1281,6 +1322,20 @@ def test_lfm2_programs_fit_and_move_no_pool_and_no_snapshot(lfm2_cell,
     assert len(calls) == (0 if which == "chunk" else 3)
     assert {lfm2_trace.label_of(line, marks) for line in calls} \
         <= {"decode_attention"}
+    # a chunk's queries walk the row's table (72 blocks of 64: 4.5 key
+    # blocks) head by head, in the lone program and in the fused one: a
+    # kernel call an attention layer, the re-laid key block and the
+    # values a K/V head each, and NO float32 array [w, h, span] (604 MB
+    # a layer until PR 53) nor any other of the table's span beside the
+    # heads.  The trace's table leaves the kernel in ``other``, not in a
+    # label a roofline share reads.
+    walk = _kernel_calls(text, "head_window_attention")
+    assert len(walk) == (0 if which == "step" else 3)
+    for line in walk:
+        assert lfm2_trace.label_of(line, marks) == "other"
+        assert "bf16[8,1024,64]" in line and "bf16[8,64,1024]" in line
+    _no_table_span_by_heads(text, cfg, lay, engine)
+    assert "f32[1024,32,4608]" not in text and "[1,4608,512]" not in text
 
 
 def test_lfm2_step_chunk_multiplies_by_in_proj_once_a_layer(lfm2_cell):

@@ -193,7 +193,7 @@ def test_hybrid_program_gives_what_the_two_give(layout, window):
     b = len(active)
     pool = BlockPool(cfg, 20, BS, max_seq=96, state_rows=b)
     T = pool.blocks_per_seq
-    assert recurrent.has_step_chunk(cfg, pool.layout)
+    assert recurrent.has_step_chunk(cfg)
     geometry = dict(block_size=BS, n_table=T)
     step = recurrent.make_recurrent_decode_step(cfg, **geometry)
     chunk = recurrent.make_recurrent_chunk_fn(cfg, chunk=C, **geometry)
@@ -284,7 +284,7 @@ def test_which_layouts_get_the_program_follows_from_the_sublayer_kinds(
         EngineConfig(max_slots=2, max_seq=32, kv_block_size=BS,
                      prefill_chunk=C))
     try:
-        assert recurrent.has_step_chunk(cfg, eng.pool.layout) == fused
+        assert recurrent.has_step_chunk(cfg) == fused
         assert (eng._step_chunk is not None) == fused
         eng.warm_up(timeout=300)
         assert eng.stats()["chunks_in_step"] == 0 and eng._load == []
@@ -294,18 +294,24 @@ def test_which_layouts_get_the_program_follows_from_the_sublayer_kinds(
         eng.shutdown()
 
 
-def test_head_by_head_window_attention_keeps_the_two_programs():
-    """More K/V heads of whole lane tiles than the packed form takes
-    (``decode.window_by_head``): the chunk's queries are attended head
-    by head, which the fused window has no form for."""
+def test_the_fused_program_does_not_depend_on_the_window_form():
+    """Which form attends the chunk's queries follows from the table's
+    span (``decode.window_by_head``: the walk over key blocks where the
+    table is more than one, packed on a table of one, and on a sharded
+    pool), whatever the heads; the fused program takes either, so many
+    K/V heads of whole lane tiles — attended head by head and kept on
+    the two programs until PR 53 — get it too."""
     def layout_of(cfg):
         layers, heads, head_dim = cfg.kv_geometry
         return PoolLayout(layers, 5, BS, heads, head_dim)
-    cfg = hybrid.HybridConfig.tiny(n_heads=12, n_kv_heads=12, head_dim=128)
-    assert decode.window_by_head(layout_of(cfg))
-    assert not recurrent.has_step_chunk(cfg, layout_of(cfg))
+    many = hybrid.HybridConfig.tiny(n_heads=12, n_kv_heads=12, head_dim=128)
     few = hybrid.HybridConfig.tiny(n_heads=8, n_kv_heads=8, head_dim=128)
-    assert recurrent.has_step_chunk(few, layout_of(few))
+    block = decode.window_key_block(BS) // BS
+    for cfg in (many, few):
+        lay = layout_of(cfg)
+        assert not decode.window_by_head(lay, block)
+        assert decode.window_by_head(lay, block + 1)
+        assert recurrent.has_step_chunk(cfg)
 
 
 # ---------------------------------------------------------- the pass
@@ -418,7 +424,7 @@ def test_counters_spans_and_account_say_how_often_it_engaged(
     eng = InferenceEngine(params, cfg, ec)
     assert eng._step_chunk is not None
     if cfg.state_geometry is not None:
-        assert recurrent.has_step_chunk(cfg, eng.pool.layout)
+        assert recurrent.has_step_chunk(cfg)
     calls, rode = _spy(eng)
     try:
         first = eng.submit(rng.integers(0, cfg.vocab_size, 5).tolist(),

@@ -65,6 +65,8 @@ STATS_TYPES = {
     # what the latent window kernel walked, tile by tile (ISSUE 49)
     "chunk_pairs_walked": int, "chunk_tiles_plain": int,
     "chunk_tiles_diagonal": int,
+    # the key blocks the head-by-head window form walked (ISSUE 53)
+    "chunk_key_blocks_walked": int,
     # the recurrent state kept at block ends (ISSUE 52)
     "state_snapshot_bytes": int, "state_snapshots_written": int,
     "state_snapshots_restored": int,
@@ -123,6 +125,10 @@ SERIES = [
      "summed over chunk passes)"),
     ("ray_tpu_inference_chunk_query_keys_total", "counter",
      "(query, key) pairs under the causal mask, summed over prefill "
+     "chunk passes"),
+    ("ray_tpu_inference_chunk_key_blocks_walked_total", "counter",
+     "Key blocks the head-by-head window form walked, a layer each (up "
+     "to the block of the chunk's last real key), summed over prefill "
      "chunk passes"),
     ("ray_tpu_inference_chunk_pairs_walked_total", "counter",
      "(query, key) pairs of the score tiles the latent window kernel did "
@@ -323,6 +329,9 @@ def test_latent_window_counters_read_zero_without_latent_layers(make):
     assert st["chunk_passes"] > 0 and st["chunk_query_keys"] > 0
     assert (st["chunk_pairs_walked"], st["chunk_tiles_plain"],
             st["chunk_tiles_diagonal"]) == (0, 0, 0)
+    # ... and their tables are one key block, attended packed: no key
+    # block was walked either (ISSUE 53)
+    assert st["chunk_key_blocks_walked"] == 0
 
 
 def test_no_engine_renders_one_zero_row_a_series():

@@ -6,13 +6,16 @@ cached prefixes ACROSS the recurrent state — held to the plain
 reference's full forward pass (no cache, no state) — with what the
 engine derives for the layouts whose state has no snapshot form."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from chipbench.reference import lfm2 as ref
-from ray_tpu.inference import EngineConfig, InferenceEngine, recurrent
+from ray_tpu.inference import (EngineConfig, InferenceEngine, decode,
+                               recurrent)
 from ray_tpu.inference.cache import BlockPool, RadixIndex
 from ray_tpu.inference.decode import pack_chunk, pack_step, pack_step_chunk
 from ray_tpu.models import hybrid
@@ -38,6 +41,22 @@ def params(cfg):
 def highest():
     with jax.default_matmul_precision("highest"):
         yield
+
+
+@pytest.fixture(params=["one_key_block", "walked"])
+def walked(request, monkeypatch):
+    """The two forms a chunk's queries are attended in
+    (``decode.window_by_head``): packed over the gathered table where it
+    is one key block, as these tables of 96-160 keys are — or, with the
+    key block shrunk to 32 keys, the walk over the key blocks the chunk
+    can see.  (The programs are cached by their shapes: built anew.)"""
+    if request.param == "walked":
+        monkeypatch.setattr(decode, "KEY_BLOCK", 32)
+        monkeypatch.setattr(importlib.import_module("ray_tpu.ops.attention"),
+                            "HEAD_TILE", (16, 8))
+    decode.clear_fn_cache()
+    yield request.param == "walked"
+    decode.clear_fn_cache()
 
 
 def _ref(params, toks):
@@ -82,7 +101,7 @@ def _state_after(cfg, params, toks):
 
 @pytest.mark.parametrize("fused", [False, True])
 def test_programs_chunks_then_decode_and_their_snapshots(cfg, params,
-                                                         fused):
+                                                         fused, walked):
     """Two rows prefilled in chunks (the last partial, one prompt ending
     ON a block boundary), then decoded together while a third sits out:
     logits are the reference's, and every block a program CLOSED carries
@@ -92,6 +111,7 @@ def test_programs_chunks_then_decode_and_their_snapshots(cfg, params,
     n_rows = 3
     pool = BlockPool(cfg, n_blocks=24, block_size=BS, max_seq=96,
                      state_rows=n_rows)
+    assert decode.window_by_head(pool.layout, pool.blocks_per_seq) == walked
     assert pool.snapshots and pool.state.ssm is None
     assert pool.state.snap.shape == (25, 5 * 2 * 64)
     assert [a.shape for a in pool.state.arrays] == [(5, 3, 2, 64),
@@ -101,7 +121,7 @@ def test_programs_chunks_then_decode_and_their_snapshots(cfg, params,
     step = recurrent.make_recurrent_decode_step(cfg, **kw)
     chunk = recurrent.make_recurrent_chunk_fn(cfg, chunk=C, **kw)
     both = recurrent.make_recurrent_step_chunk(cfg, chunk=C, **kw)
-    assert recurrent.has_step_chunk(cfg, pool.layout)
+    assert recurrent.has_step_chunk(cfg)
     seqs = {0: _tokens(60, 8), 2: _tokens(52, 9)}
     prompts = {0: 40, 2: 37}
     want = {r: _ref(params, s) for r, s in seqs.items()}
@@ -275,10 +295,13 @@ def test_decode_closed_blocks_are_offered_to_the_index(cfg, params):
     assert st["state_snapshots_written"] == 6 + (57 + 9) // BS - 6
 
 
-def test_concurrent_siblings_adopt_at_their_first_chunk(cfg, params):
+def test_concurrent_siblings_adopt_at_their_first_chunk(cfg, params, walked):
     """Three requests of one head admitted together: the re-match before
     a chunk adopts what the first one published and restores the state
-    with it; fused and two-program passes give the same streams."""
+    with it; fused and two-program passes give the same streams,
+    whichever form attends the chunks' queries — ``jit_step_chunk`` hands
+    its chunk part to the walk as ``jit_chunk_fn`` does — and the key
+    blocks walked are counted a chunk pass and walking layer."""
     shared = _tokens(40, seed=8)
     prompts = [np.concatenate([shared, _tokens(5 + 3 * i, seed=i)])
                for i in range(3)]
@@ -296,6 +319,12 @@ def test_concurrent_siblings_adopt_at_their_first_chunk(cfg, params):
         assert [r.prefix_hit_tokens for r in reqs] == [0, 40, 40]
         assert st["state_snapshots_restored"] == 2
         assert (st["chunks_in_step"] > 0) == fused
+        # the first prompt's chunks reach 16, 32 and 45 keys, the two
+        # adopters' one chunk each 40 + 8 and 40 + 11: 1, 1, 2, 2, 2
+        # key blocks of 32, an attention layer each
+        assert st["chunk_passes"] == 5
+        assert st["chunk_key_blocks_walked"] == (
+            8 * cfg.n_attention if walked else 0)
     assert streams[True] == streams[False]
     for p, o in zip(prompts, streams[True]):
         assert _margins(params, p, o).max() <= ATOL

@@ -14,12 +14,13 @@ import pytest
 
 from chipbench.reference import olmo_hybrid as ref
 from ray_tpu.inference import EngineConfig, InferenceEngine
-from ray_tpu.inference import recurrent
+from ray_tpu.inference import decode, recurrent
 from ray_tpu.inference.cache import BlockPool, PoolLayout
 from ray_tpu.inference.decode import (SpeculationUnsupported, pack_chunk,
                                       pack_step, window_by_head)
 from ray_tpu.models import hybrid
-from ray_tpu.ops.attention import head_window_attention, mha_reference
+from ray_tpu.ops.attention import (KEY_BLOCK, head_window_attention,
+                                   mha_reference)
 from tests.test_olmo_hybrid_model import F32, PUB
 
 # float32 against float32 (the tolerance of tests/test_olmo_hybrid_
@@ -140,16 +141,21 @@ def test_head_window_attention_equals_mha_reference(heads, kv_heads, start,
     np.testing.assert_allclose(got, want, atol=2e-6)
 
 
-def test_which_window_form_a_layout_gets():
-    """Whole-tile heads in number take the head-wise form; the layouts
-    measured on the packed form keep it."""
-    def lay(heads, hd, shards=1):
-        return PoolLayout(1, 4, 16, heads, hd, shards)
-    assert window_by_head(lay(30, 128))
-    assert not window_by_head(lay(8, 128))      # granite's K/V heads
-    assert not window_by_head(lay(2, 128))      # nemotron's
-    assert not window_by_head(lay(25, 64))      # XL's half-tile heads
-    assert not window_by_head(lay(32, 128, shards=2))
+@pytest.mark.parametrize("heads, hd", [(30, 128), (8, 128), (2, 128),
+                                       (25, 64), (8, 64)])
+def test_which_window_form_a_layout_gets(heads, hd):
+    """A table of more than one key block is walked head by head,
+    whatever the heads (olmo's, granite's, nemotron's, XL's, lfm2's); a
+    table of one key block is attended packed, and so is a sharded
+    pool's."""
+    def lay(shards=1, bs=16):
+        return PoolLayout(1, 4, bs, heads, hd, shards)
+    one = KEY_BLOCK // 16
+    assert not window_by_head(lay(), one)
+    assert window_by_head(lay(), one + 1)
+    assert not window_by_head(lay(shards=2), 4 * one)
+    # a cache block that does not divide KEY_BLOCK is a key block itself
+    assert window_by_head(lay(bs=24), 2) and not window_by_head(lay(bs=24), 1)
 
 
 def test_chunk_program_by_head_equals_the_packed_form(cfg, params,
@@ -185,7 +191,7 @@ def test_chunk_program_by_head_equals_the_packed_form(cfg, params,
     monkeypatch.setattr(recurrent, "_cached",
                         lambda key, cfg, mesh, rules, build: build())
     packed = run()
-    monkeypatch.setattr(recurrent, "window_by_head", lambda lay: True)
+    monkeypatch.setattr(decode, "window_by_head", lambda lay, n_table: True)
     np.testing.assert_allclose(run(), packed, atol=ATOL)
     np.testing.assert_allclose(packed, _ref(params, seq), atol=ATOL)
 
