@@ -203,9 +203,8 @@ class GenerationRequest:
         self.admitted_s: Optional[float] = None
         self.first_token_s: Optional[float] = None
         self.finished_s: Optional[float] = None
-        # per-token arrival stamps: consecutive diffs are the request's
-        # ITLs — the latency series speculation moves
-        self.token_times: list[float] = []
+        # ``stream()`` has read the first token: the consumer is awake
+        self.first_yield_s: Optional[float] = None
         # what the lifecycle spans report (_record_spans); the caller's
         # span (the serve front's) is their parent, where there is one
         self._trace_ctx = tracing.inject_context()
@@ -222,10 +221,8 @@ class GenerationRequest:
 
     def _emit(self, token: int) -> None:
         with self._cond:
-            now = time.monotonic()
             if self.first_token_s is None:
-                self.first_token_s = now
-            self.token_times.append(now)
+                self.first_token_s = time.monotonic()
             self.tokens.append(int(token))
             self._cond.notify_all()
 
@@ -248,7 +245,9 @@ class GenerationRequest:
         (always on): ``request.queue`` (submit -> a row),
         ``request.prefill`` (-> first token), ``request.decode``
         (-> finish) partition submit -> finish exactly; a request that
-        never reached a stage ends in the stage it died in."""
+        never reached a stage ends in the stage it died in.
+        ``request.decode`` says when a ``stream()`` read the first token
+        (``first_yield_ns``), if one had by now."""
         def ns(t):
             return int(t * 1e9)
         end = ns(self.finished_s)
@@ -273,9 +272,11 @@ class GenerationRequest:
                 req=self.id, chunk_passes=self.chunk_passes,
                 full_width=self.full_width_prefill)
         if self.first_token_s is not None:
+            woke = {} if self.first_yield_s is None else {
+                "first_yield_ns": ns(self.first_yield_s)}
             tracing.record_span(
                 "request.decode", first, end, parent=ctx,
-                req=self.id, output_tokens=len(self.tokens))
+                req=self.id, output_tokens=len(self.tokens), **woke)
 
     def _next_rng(self) -> Optional[jax.Array]:
         if self._rng is None:
@@ -312,6 +313,8 @@ class GenerationRequest:
                     self._cond.wait(timeout=remain)
                 if len(self.tokens) > i:
                     tok = self.tokens[i]
+                    if i == 0 and self.first_yield_s is None:
+                        self.first_yield_s = time.monotonic()
                 else:                      # done, mailbox drained
                     if self.error is not None:
                         raise self.error
@@ -369,6 +372,31 @@ _LOOP_PHASES = {
 # the loop's time between two ``engine.account`` spans, checked where a
 # pass ends
 ACCOUNT_EVERY_NS = 1_000_000_000
+
+# What a pass launched, noted at its ``dispatch`` sites, and the KIND of
+# pass that makes it (``tracing.Account.pass_done``): the decode step
+# alone, the fused program alone, chunk programs and no step (no row
+# decoding), lone chunks and then either of the two, a full-width
+# prefill or a draft-and-verify iteration whatever else ran, and
+# ``host``: a pass that launched nothing (cross-thread ops, a cancelled
+# or preempted prefill).  The loop's time outside every pass is of the
+# kind ``idle``: a park (its ``wait_ns``) and what led up to it.
+_CHUNK, _STEP, _STEP_CHUNK, _PREFILL, _SPEC = 1, 2, 4, 8, 16
+
+
+def _kind_of(launched: int) -> str:
+    if launched & _PREFILL:
+        return "prefill"
+    if launched & _SPEC:
+        return "spec"
+    step = ("step_chunk" if launched & _STEP_CHUNK
+            else "step" if launched & _STEP else "")
+    if launched & _CHUNK:
+        return "chunk+" + step if step else "chunk"
+    return step or "host"
+
+
+_PASS_KIND = tuple(_kind_of(launched) for launched in range(32))
 
 
 def _engine_loop(ref: "weakref.ref[InferenceEngine]") -> None:
@@ -773,11 +801,18 @@ class InferenceEngine:
         # a model whose full blocks carry a state snapshot
         self._snapshots = self.pool.snapshots
         # the loop thread's time by phase, always on; ``engine.account``
-        # spans carry it to the ring (``_write_account``)
+        # spans carry it to the ring (``_pass_ended``)
         self._passes = 0               # passes that found work
         self._acct = tracing.Account(_LOOP_PHASES, launch=("dispatch",),
-                                     land=("wait",))
+                                     land=("wait",),
+                                     waits=("wait", "parked"))
         self._account_t1_ns = self._acct.t_made_ns
+        # the pass in progress: what it launched (``_PASS_KIND``), the
+        # first tokens it emitted; and the tokens emitted as the last
+        # pass ended
+        self._launched = 0
+        self._first_tokens = 0
+        self._emitted = 0
 
         with _registry_lock:
             self.name = name or f"engine-{next(_engine_seq)}"
@@ -902,6 +937,7 @@ class InferenceEngine:
 
         def run_once():
             with self._acct.phase("dispatch"):
+                self._launched |= _STEP_CHUNK
                 self._seam.run(self, *programs[-1])
             with self._acct.phase("wait"):
                 jax.block_until_ready(self._load.pop())
@@ -941,6 +977,7 @@ class InferenceEngine:
                     # reference every tick, or it is never collectable
                     with self._acct.phase("parked"):
                         self._cond.wait(self.engine_cfg.idle_wait_s)
+                    self._acct.pass_done("idle")
                     return not self._stopped
                 if self._stopped:
                     return False
@@ -969,27 +1006,34 @@ class InferenceEngine:
         finally:
             sp.__exit__(*sys.exc_info())
             if worked:
-                self._write_account()
+                self._pass_ended()
 
-    def _write_account(self) -> None:
-        """Where a pass ends, once the loop has spent ``ACCOUNT_EVERY_NS``
-        since the last one: the account so far as ONE always-on span
-        that starts where the last one ended.  The counters are
-        cumulative, so a reader differences two spans; ``profiling``
-        says whether a ``jax.profiler`` session touched the interval."""
-        acct = self._acct
-        t1_ns = acct.t_ns
-        if t1_ns - self._account_t1_ns < ACCOUNT_EVERY_NS:
+    def _pass_ended(self) -> None:
+        """Where a pass ends, a failed one too: its time goes to its
+        kind's row of the account, beside the tokens it emitted (the
+        counters' growth since the last pass ended) and, once the loop
+        has spent ``ACCOUNT_EVERY_NS`` since the last one, the account
+        so far goes to the ring as an ``engine.account`` span, all of it
+        cumulative: the loop's account and every counter of the engine's
+        table (three of them also under their own keys, where
+        ``chunk_in_step_share.serve`` reads them)."""
+        counts, acct = self._counts, self._acct
+        emitted = counts.tokens_greedy_on_device + counts.tokens_sampled
+        tokens, self._emitted = emitted - self._emitted, emitted
+        acct.pass_done(_PASS_KIND[self._launched],
+                       tokens - self._first_tokens, tokens=tokens)
+        self._launched = self._first_tokens = 0
+        if acct.t_ns - self._account_t1_ns < ACCOUNT_EVERY_NS:
             return
-        tracing.record_span(
-            "engine.account", self._account_t1_ns, t1_ns,
-            engine=self.name, passes=self._passes,
-            decode_iterations=self._counts.decode_iterations,
-            chunk_passes=self._counts.chunk_passes,
-            chunks_in_step=self._counts.chunks_in_step,
-            profiling=acct.interval_profiled(),
-            ring_dropped=tracing.ring_dropped(), **acct.snapshot())
-        self._account_t1_ns = t1_ns
+        counters = counts.snapshot(self._mlock)
+        self._account_t1_ns = tracing.record_account(
+            "engine.account", self._account_t1_ns, acct.t_ns,
+            acct.interval_profiled(), engine=self.name,
+            passes=self._passes,
+            decode_iterations=counters["decode_iterations"],
+            chunk_passes=counters["chunk_passes"],
+            chunks_in_step=counters["chunks_in_step"], counters=counters,
+            **acct.snapshot())
 
     def _schedule_locked(self) -> None:
         """The pass's scheduling under ``_cond``: cross-thread ops,
@@ -1411,6 +1455,7 @@ class InferenceEngine:
             padded = np.zeros((1, self.max_seq), np.int32)
             padded[0, :n] = prompt
             with self._acct.phase("dispatch"):
+                self._launched |= _PREFILL
                 logits, k_new, v_new = self._prefill(self.params, padded)
                 self.pool.write_prefill(self._tables[row], k_new[:, 0],
                                         v_new[:, 0])
@@ -1468,6 +1513,7 @@ class InferenceEngine:
         if may_ride and self._active.any():
             return row, n_q, packed
         with self._acct.phase("dispatch"):
+            self._launched |= _CHUNK
             logits = self._seam.run(self, self._chunk, packed)
         self._chunk_launched(row, n_q, logits, n_q - 1)
         return None
@@ -1526,7 +1572,7 @@ class InferenceEngine:
                     rng=req._next_rng())[None]
         elif owed is None or req.temperature != 0.0:
             tok = self._first_token(req, logits[idx])
-            req._emit(tok)
+            self._emit_to(req, tok)
             self._start_decoding(row, req, tok)
             return
         self._first_pending.append([row, req, owed, None])
@@ -1558,12 +1604,20 @@ class InferenceEngine:
                     tok = int(jax.device_get(owed)[-1])
                     self._fetched(fetch, owed.nbytes)
             pend[3] = tok
-            req._emit(tok)
+            self._emit_to(req, tok)
             if req.temperature == 0.0:
                 counts.tokens_greedy_on_device += 1
             else:
                 counts.tokens_sampled += 1
         return n, n_bytes
+
+    def _emit_to(self, req: GenerationRequest, tok: int) -> None:
+        """A prompt's first token goes out: the request's first, unless
+        a preemption made it prefill again (then it is a gap to its
+        stream, as a decode step's token is)."""
+        if not req.tokens:
+            self._first_tokens += 1
+        req._emit(tok)
 
     def _pass_done(self) -> None:
         """The pass's decode step has been sampled (or there was none):
@@ -1758,6 +1812,7 @@ class InferenceEngine:
         pass rewrites all drafted positions at all layers anyway."""
         w = np.where(self._active, want, 0).astype(np.int32)
         with self._acct.phase("dispatch"):
+            self._launched |= _SPEC
             toks, kp, vp = self._draft(
                 self.params, self.pool.k, self.pool.v,
                 jnp.asarray(self._tables), jnp.asarray(self._tokens),
@@ -1814,6 +1869,7 @@ class InferenceEngine:
             if up:
                 up.set(bytes=sum(a.nbytes for a in args))
         with self._acct.phase("dispatch"):
+            self._launched |= _SPEC
             logits, k, v = self._verify(self.params, self.pool.k,
                                         self.pool.v, *args)
             self.pool.swap(k, v)
@@ -1905,8 +1961,7 @@ class InferenceEngine:
                     self._paged_evict(row, cache_prefix=False)
                     continue
                 self._grow_row(row)       # False = row preempted; skip
-            sp.set(admitted=0,
-                   preempted=self._counts.preemptions - preempted0)
+            sp.set(preempted=self._counts.preemptions - preempted0)
 
     def _paged_decode_iteration(self, ride=None) -> None:
         """One decode step over the active rows.  ``ride``: the chunk
@@ -1947,6 +2002,7 @@ class InferenceEngine:
                     packed = pack_step_chunk(packed, ride[2])
                 up.set(bytes=packed.nbytes)
             with self._acct.phase("dispatch"):
+                self._launched |= _STEP_CHUNK if ride else _STEP
                 logits = self._seam.run(self, program, packed)
             if ride:
                 self._counts.chunks_in_step += 1

@@ -96,6 +96,11 @@ def _shed_retry_after(e: BaseException):
         return None
 
 
+# the loop's time between two ``front.account`` spans, checked where a
+# streamed chunk has been written
+ACCOUNT_EVERY_NS = 1_000_000_000
+
+
 class AsyncHttpProxy:
     """Concurrent HTTP/1.1 ingress on an asyncio loop thread.
 
@@ -127,6 +132,12 @@ class AsyncHttpProxy:
         # long-polled route table: never touch controller state per
         # request (reference: proxy LongPollClient on route updates)
         self._routes: set[str] = set(controller.deployments.keys())
+        # the gaps between a streamed response's chunks as they were
+        # written and drained, counted always; the loop thread is their
+        # one writer and carries them to the ring (``_chunk_written``)
+        self._write_gaps = tracing.Histogram()
+        self._profiled = False
+        self._account_t1_ns = time.monotonic_ns()
         self._lp = LongPollClient(
             controller.long_poll, ["routes"],
             lambda key, snapshot: self._set_routes(snapshot))
@@ -372,11 +383,33 @@ class AsyncHttpProxy:
         writer.write(b"\r\n".join(lines) + b"\r\n\r\n" + body)
         await writer.drain()
 
+    def _chunk_written(self, since_ns: int) -> int:
+        """A streamed chunk has been written and drained, its
+        response's last one at ``since_ns`` (0: this is its first) ->
+        now.  The time between the two goes to the proxy's histogram,
+        and once the loop has spent ``ACCOUNT_EVERY_NS`` since the last
+        one the histogram so far goes to the ring as a ``front.account``
+        span (``tracing.record_account``); nothing is written while
+        nothing streams."""
+        now = time.monotonic_ns()
+        if since_ns:
+            self._write_gaps.add(now - since_ns)
+        if tracing.profiling():
+            self._profiled = True
+        if now - self._account_t1_ns >= ACCOUNT_EVERY_NS:
+            touched, self._profiled = self._profiled, False
+            self._account_t1_ns = tracing.record_account(
+                "front.account", self._account_t1_ns, now, touched,
+                proxy=f"{self.host}:{self.port}",
+                write_gaps=self._write_gaps.snapshot())
+        return now
+
     async def _respond_stream(self, writer, it, loop, front) -> None:
         """Chunked transfer-encoding over a (sync) iterator result —
         each chunk flushes as the replica produces it (reference:
         StreamingResponse through the proxy).  ``front`` (the request's
         span) is told when the first chunk is written and drained."""
+        written_ns = 0
         writer.write(b"HTTP/1.1 200 X\r\n"
                      b"Content-Type: application/octet-stream\r\n"
                      b"Transfer-Encoding: chunked\r\n"
@@ -384,12 +417,15 @@ class AsyncHttpProxy:
         await writer.drain()
 
         async def write_chunk(chunk):
+            nonlocal written_ns
             data = (chunk if isinstance(chunk, bytes)
                     else json.dumps(_jsonable(chunk)).encode())
             writer.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
             await writer.drain()
-            if "first_chunk_ns" not in front.attributes:
-                front.set(first_chunk_ns=time.monotonic_ns())
+            first = not written_ns
+            written_ns = self._chunk_written(written_ns)
+            if first:
+                front.set(first_chunk_ns=written_ns)
 
         if hasattr(it, "__anext__"):
             # async generator results drive directly on this loop

@@ -42,7 +42,20 @@ The off/on contract:
     same two stamps, else ``NOOP``.  An account is written by its ONE
     thread without a lock; other threads (``engine_stats()``) read its
     counters as whole Python ints, each one sound, a snapshot of
-    several at most one phase boundary apart.
+    several at most one phase boundary apart.  Where the thread ends a
+    unit of its time (the engine's scheduler pass, or a stretch with no
+    work) ``pass_done(kind, ..)`` adds the unit's time, split into host
+    and wait, to its KIND's row, and to ``gaps``, a ``Histogram`` of
+    what a token waited.
+  * **The histogram** (``Histogram``): durations counted into
+    geometric buckets by integer comparisons, one writer, no lock;
+    cumulative, so a reader differences two snapshots and interpolates
+    a quantile inside a bucket (``Histogram.edge_ns``).
+  * **The chain** (``record_account``): what is counted always reaches
+    the ring as ONE cumulative always-on span once its writer has spent
+    a second since the last, each starting where the last one ended and
+    saying whether a profiler session touched its interval
+    (``engine.account``, ``front.account``).
 
 Stamps are ``time.monotonic_ns()``, the clock of the benchmark's own
 stamps; in a profiler session an annotation also carries its span's
@@ -68,11 +81,13 @@ every process's file.
 from __future__ import annotations
 
 import atexit
+import bisect
 import collections
 import contextvars
 import glob
 import itertools
 import json
+import operator
 import os
 import secrets
 import sys
@@ -116,7 +131,7 @@ _annotation = None
 _step_annotation = None
 
 
-def _profiling() -> bool:
+def profiling() -> bool:
     """True while a ``jax.profiler`` session is active in this process."""
     global _annotation, _step_annotation
     if _annotation is None:
@@ -140,7 +155,7 @@ def tracing_enabled() -> bool:
 
 def active() -> bool:
     """Would ``span()`` record now: the flag, or a profiler session."""
-    return tracing_enabled() or _profiling()
+    return tracing_enabled() or profiling()
 
 
 def wall_time(t_ns: int) -> float:
@@ -286,7 +301,7 @@ def span(name: str, *, kind: str = "internal",
     if not (always or active()):
         return NOOP
     return Span(name, kind, parent, attributes,
-                annotate=not always and _profiling(), step_num=step_num)
+                annotate=not always and profiling(), step_num=step_num)
 
 
 def record_span(name: str, t0_ns: int, t1_ns: int, *,
@@ -299,7 +314,78 @@ def record_span(name: str, t0_ns: int, t1_ns: int, *,
     _emit(s)
     return s
 
+
+def record_account(name: str, t0_ns: int, t1_ns: int, touched: bool,
+                   **attributes: Any) -> int:
+    """One more link of a chain of cumulative always-on spans: ``name``
+    from ``t0_ns``, where the last link ended, to ``t1_ns``, with the
+    writer's counters (they only grow: a reader differences two links)
+    and ``profiling``: did a ``jax.profiler`` session touch the interval
+    (``touched``, or one is active now).  The writer asks for a link
+    once it has spent its interval since the last.  -> ``t1_ns``, where
+    the chain ends now."""
+    record_span(name, t0_ns, t1_ns, profiling=touched or profiling(),
+                ring_dropped=ring_dropped(), **attributes)
+    return t1_ns
+
+
+def _edges() -> tuple:
+    """0.1 ms, then each edge at most a tenth above the last, to 10 s."""
+    edges = [100_000]
+    while edges[-1] < 10_000_000_000:
+        edges.append(min(edges[-1] * 11 // 10, 10_000_000_000))
+    return tuple(edges)
+
+
+_EDGES = _edges()
+
+
+class Histogram:
+    """Durations (ns) counted into geometric buckets.
+
+    Bucket 0 holds what is under 0.1 ms, bucket ``N - 1`` what is 10 s
+    or over; between them bucket ``i`` starts at ``edge_ns(i)`` and ends
+    at ``edge_ns(i + 1)``, never more than 10 % above it, so a quantile
+    read by linear interpolation inside a bucket is off by less than a
+    tenth of itself.  The edges are a function of the index alone:
+    a reader takes them from ``edge_ns`` and keeps no copy of the
+    layout.  ``add`` finds the bucket by comparing integers (a bisection
+    of the edges: no logarithm, no float) and adds ``weight`` to a plain
+    int.  ONE thread writes; others read ``snapshot()`` as they read an
+    account's counters.  The counts are cumulative.
+
+    The bound: ``N`` = 123 buckets, so a snapshot with every bucket
+    counted is a dict of 123 small entries, under 12 KB (a serving
+    cell's gaps fill 20-40: 3-5 KB); an ``engine.account`` span that
+    carries one, eight kinds' rows and the engine's 46 counters is
+    13-18 KB as Python objects.  The ring takes one a second of a busy
+    loop beside every request's four spans, so they are a few percent
+    of it (a few MB) wherever requests finish; a ring that held nothing
+    else (8,192 seconds of one stream that never ends) would hold
+    ~150 MB of them."""
+
+    __slots__ = ("counts",)
+    N = len(_EDGES) + 1
+
+    def __init__(self):
+        self.counts = [0] * self.N
+
+    def add(self, ns: int, weight: int = 1) -> None:
+        self.counts[bisect.bisect_right(_EDGES, ns)] += weight
+
+    def snapshot(self) -> dict:
+        """{bucket index: count} of the buckets that hold any."""
+        return {i: c for i, c in enumerate(self.counts) if c}
+
+    @staticmethod
+    def edge_ns(i: int) -> int:
+        """Where bucket ``i`` starts (``0 <= i < N``); it ends where the
+        next one starts, the last one nowhere."""
+        return _EDGES[i - 1] if i else 0
+
+
 UNACCOUNTED = "unaccounted"
+_NS = operator.attrgetter("ns")
 
 
 class Account:
@@ -321,12 +407,32 @@ class Account:
 
     ``profiled`` is set where a phase starts inside a ``jax.profiler``
     session (``interval_profiled`` reads and resets it): such time is
-    the program's under an instrument, not its own."""
+    the program's under an instrument, not its own.
+
+    By kind of unit: the thread calls ``pass_done(kind, ..)`` where a
+    unit of its time ends: a pass of its work, or a stretch in which it
+    had none.  A unit runs from where the last one ended to the newest
+    stamp, so no time falls between two units: the thread's own
+    turn-around is part of what a token waits.  The time goes to the
+    kind's row of ``by_kind``: ``count``, ``ns``, which is ``wait_ns``
+    (the ``waits`` phases: the thread itself does nothing) + ``host_ns``
+    (every other phase, ``unaccounted`` too) exactly, and whatever the
+    caller counted in the unit (``**counted``).  The identity, exact as
+    of the last unit's end: the kinds' ``ns`` add up to ``t_ns -
+    t_made_ns``.
+
+    ``gaps`` is ONE histogram of the units' times, each weighted by the
+    tokens it handed to streams that already had one (``weight``): the
+    thread's own view of the gap between a stream's tokens.  A unit that
+    hands a stream several tokens (a speculative pass) counts each at
+    the unit's whole time: an approximation, the tokens arrive
+    together."""
 
     __slots__ = ("phases", "phase", "unaccounted", "in_flight", "profiled",
-                 "t_made_ns", "t_ns", "_open")
+                 "t_made_ns", "t_ns", "_open", "by_kind", "gaps", "_waits",
+                 "_unit")
 
-    def __init__(self, phases: dict, launch=(), land=()):
+    def __init__(self, phases: dict, launch=(), land=(), waits=()):
         # one reusable entry a phase: it owns the phase's counters, and
         # what an entry in progress holds is on the stack
         self.phases = {
@@ -341,24 +447,58 @@ class Account:
         # yielded
         self._open = [self.unaccounted, NOOP]
         self.t_made_ns = self.t_ns = time.monotonic_ns()
+        self.by_kind = {}
+        self.gaps = Histogram()
+        self._waits = [self.phases[name] for name in waits]
+        # where the unit in progress starts, and the ``waits`` phases'
+        # time so far, there
+        self._unit = (self.t_made_ns, 0)
+
+    def pass_done(self, kind: str, weight: int = 0, **counted: int) -> None:
+        """A unit of ``kind`` ends at the newest stamp (no clock is
+        read).  ``weight``: the tokens it handed to streams that already
+        had one (a first token is no gap); ``counted``: what else the
+        caller counts a kind, the same keys every time."""
+        t0_ns, wait0 = self._unit
+        t1_ns, wait1 = self.t_ns, sum(map(_NS, self._waits))
+        self._unit = (t1_ns, wait1)
+        ns, wait_ns = t1_ns - t0_ns, wait1 - wait0
+        row = self.by_kind.get(kind)
+        if row is None:
+            # (whole from the start: another thread may copy it now)
+            row = self.by_kind[kind] = {
+                "count": 0, "ns": 0, "host_ns": 0, "wait_ns": 0,
+                **dict.fromkeys(counted, 0)}
+        row["count"] += 1
+        row["ns"] += ns
+        row["host_ns"] += ns - wait_ns
+        row["wait_ns"] += wait_ns
+        for key, n in counted.items():
+            row[key] += n
+        if weight:
+            self.gaps.add(ns, weight)
 
     def interval_profiled(self) -> bool:
         """Did a phase start inside a profiler session since the last
         call, or is one active now; starts the next interval."""
-        now = _profiling()
+        now = profiling()
         was, self.profiled = self.profiled or now, now
         return was
 
     def snapshot(self) -> dict:
         """The counters, copied (from another thread: see the module
-        docstring); they cover ``t_made_ns`` to ``t_ns``."""
+        docstring); they cover ``t_made_ns`` to ``t_ns``, the rows by
+        kind and ``gaps`` to the last unit's end."""
         rest = self.unaccounted
         return {"ns": {n: p.ns for n, p in self.phases.items()},
                 "starved_ns": {n: p.starved_ns
                                for n, p in self.phases.items()},
                 "count": {n: p.count for n, p in self.phases.items()},
                 "unaccounted_ns": rest.ns,
-                "unaccounted_starved_ns": rest.starved_ns}
+                "unaccounted_starved_ns": rest.starved_ns,
+                "by_kind": {kind: dict(row)
+                            for kind, row in list(self.by_kind.items())},
+                "gaps": self.gaps.snapshot()}
 
 
 class _Phase:
@@ -388,12 +528,12 @@ class _Phase:
         stack.append(self)
         self.count += 1
         sp = NOOP
-        profiling = _profiling()
-        if profiling:
+        in_session = profiling()
+        if in_session:
             acct.profiled = True
-        if self.span_name is not None and (profiling or tracing_enabled()):
+        if self.span_name is not None and (in_session or tracing_enabled()):
             sp = Span(self.span_name, "internal", None, {},
-                      annotate=profiling).open(now)
+                      annotate=in_session).open(now)
         stack.append(sp)
         return sp
 

@@ -332,7 +332,17 @@ def _mix(cfg):
             (toks(21), 7, 0.9), (toks(16), 3, 0.0), (toks(3), 5, 0.0)]
 
 
+_SERVED = {}
+
+
 def _serve_mix(params, cfg, fused: bool):
+    """(The module's one model; served once a ``fused``.)"""
+    if fused not in _SERVED:
+        _SERVED[fused] = _serve_mix_once(params, cfg, fused)
+    return _SERVED[fused]
+
+
+def _serve_mix_once(params, cfg, fused: bool):
     eng = InferenceEngine(params, cfg, EngineConfig(
         max_slots=4, kv_block_size=BS, prefill_chunk=C))
     if not fused:
@@ -349,11 +359,12 @@ def _serve_mix(params, cfg, fused: bool):
         outs = [r.result(timeout=300) for r in reqs]
         assert not first.done                         # ... and still is
         whole = head + list(it)
-        st = eng.stats()
-        assert eng._first_pending == [] and st["active_slots"] == 0
+        assert eng._first_pending == [] \
+            and eng.stats()["active_slots"] == 0
     finally:
         eng.shutdown()
-    return long_, whole, mix, reqs, outs, st, calls, rode
+    # the loop thread has ended: the last pass is in the account
+    return long_, whole, mix, reqs, outs, eng.stats(), calls, rode
 
 
 def test_streams_are_the_two_program_pass_s_and_the_oracle_s(cfg, params):
@@ -391,6 +402,42 @@ def test_streams_are_the_two_program_pass_s_and_the_oracle_s(cfg, params):
     # of its own: 4 x (rows [+ 1]) a step, 4 a first token owed by a
     # chunk program, 4 a sampled token's wait outside a step
     assert st["fetch_bytes"] <= st2["fetch_bytes"] + 4 * calls["step_chunk"]
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_the_account_s_kinds_are_the_programs_the_passes_ran(cfg, params,
+                                                            fused):
+    """A pass's kind (``engine._PASS_KIND``) is derived from what it
+    launched: over the mix the kinds' counts are the launches the spy
+    saw, their tokens the tokens emitted, and ``gaps`` weighs every
+    token but a request's first."""
+    _, whole, mix, _reqs, outs, st, calls, _ = _serve_mix(params, cfg, fused)
+    acct = st["loop_account"]
+    kinds = acct["by_kind"]
+
+    def count(*names):
+        return sum(kinds[k]["count"] for k in names if k in kinds)
+    assert count(*kinds) - count("idle") == acct["passes"]
+    assert count("step_chunk", "chunk+step_chunk") == calls["step_chunk"]
+    assert count("step", "chunk+step") == calls["step"]
+    # (a pass whose chunks stop short of its last keeps the plain step)
+    assert (count("step_chunk", "chunk+step_chunk") > 0) == fused
+    assert fused or count("chunk+step") > 0
+    # every lone chunk program ran in a pass of a ``chunk..`` kind
+    assert count("chunk", "chunk+step", "chunk+step_chunk") \
+        <= calls["chunk"] == st["chunk_passes"] - calls["step_chunk"]
+    assert (calls["chunk"] > 0) == (
+        count("chunk", "chunk+step", "chunk+step_chunk") > 0)
+    assert sum(k.get("tokens", 0) for k in kinds.values()) \
+        == st["tokens_greedy_on_device"] + st["tokens_sampled"] \
+        == st["generated_tokens"]
+    assert sum(acct["gaps"].values()) \
+        == len(whole) - 1 + sum(len(out) - 1 for out in outs)
+    assert all(k["host_ns"] + k["wait_ns"] == k["ns"] > 0
+               for k in kinds.values())
+    assert sum(k["ns"] for k in kinds.values()) \
+        == acct["t_ns"] - acct["t_made_ns"]
+    assert kinds["idle"]["wait_ns"] == acct["ns"]["parked"]
 
 
 def test_a_pass_with_no_decoding_row_runs_the_chunk_program(seam):

@@ -9,6 +9,8 @@ Tiny CPU models at f32, both seams: the K/V-only family (with a cold
 long prompt, which takes the full-width prefill) and the family that
 also keeps a recurrent state."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -182,3 +184,236 @@ def test_no_fetch_span_nests_in_another_and_sites_keep_their_spans(
     assert named["engine.fetch"] == count["wait"]
     assert named["engine.sample"] == count["emit"]
     assert named["engine.prefill_chunk"] == count["prefill_host"]
+
+
+# ------------------------------------------ the account by KIND of pass
+# (ISSUE 54: ``tracing.Account.pass_done``, ``engine._PASS_KIND``)
+
+def _two_program_layout():
+    """A hybrid layout whose delta-rule sublayer keeps the pass of two
+    programs (``recurrent.has_step_chunk`` False)."""
+    cfg = hybrid.HybridConfig.tiny(
+        layer_types=(hybrid.LINEAR, hybrid.ATTENTION), lin_heads=2,
+        lin_key_dim=8, lin_value_dim=16, dense_layers=2, dense_width=32)
+    return (cfg, hybrid.init_params(cfg, jax.random.PRNGKey(0)),
+            EngineConfig(max_slots=4, max_seq=96, n_blocks=24,
+                         kv_block_size=8, prefill_chunk=8), None)
+
+
+def _gpt_layout():
+    cfg = gpt.GPTConfig.tiny(dtype=jnp.float32, max_seq=64)
+    return (cfg, gpt.init_params(cfg, jax.random.PRNGKey(0)),
+            EngineConfig(max_slots=4, kv_block_size=8, prefill_chunk=8), 40)
+
+
+KINDS = {
+    "gpt": (_gpt_layout, {"chunk", "step", "chunk+step_chunk",
+                          "step_chunk", "prefill"}),
+    "two_programs": (_two_program_layout, {"chunk", "step", "chunk+step"}),
+}
+
+
+@pytest.fixture(scope="module", params=list(KINDS))
+def kinds_run(request):
+    """One prompt alone (chunk passes, then steps); two more admitted in
+    ONE pass while it decodes (at low occupancy a pass runs a chunk of
+    each: the first alone, the last with the step where one program
+    does both); once all is quiet a cold long prompt (the full-width
+    prefill, where the family has one).  An ``engine.account`` span
+    where EVERY pass ends -> (expected kinds, engine, requests, stats
+    after the loop thread has ended, the account's chain)."""
+    make, expected = KINDS[request.param]
+    cfg, params, ec, long_prompt = make()
+    rng = np.random.default_rng(54)
+
+    def toks(n):
+        return rng.integers(0, cfg.vocab_size, n).tolist()
+    every, engine_mod.ACCOUNT_EVERY_NS = engine_mod.ACCOUNT_EVERY_NS, 0
+    tracing.disable_tracing()
+    tracing.clear()
+    eng = InferenceEngine(params, cfg, ec)
+    try:
+        first = eng.submit(toks(12), max_new=30)
+        it = first.stream(timeout=300)
+        next(it)
+        with eng._cond:                 # both reach the same admission
+            reqs = [first, eng.submit(toks(20), max_new=3),
+                    eng.submit(toks(20), max_new=3)]
+        for r in reqs:
+            r.result(timeout=300)
+        if long_prompt:
+            reqs.append(eng.submit(toks(long_prompt), max_new=2))
+            reqs[-1].result(timeout=300)
+            assert reqs[-1].full_width_prefill
+    finally:
+        eng.shutdown()
+        engine_mod.ACCOUNT_EVERY_NS = every
+    assert not eng._thread.is_alive()
+    spans = tracing.get_finished_spans()
+    tracing.clear()
+    return (expected, eng, reqs, eng.stats(), spans)
+
+
+def _partition(acct, t_ns):
+    """The identities of the account by kind, as of a pass's end: the
+    kinds' time, ``idle`` (no work: the parks and what led up to them)
+    among them, is the account's; the others' count is the passes'."""
+    kinds = acct["by_kind"]
+    assert sum(k["ns"] for k in kinds.values()) == t_ns - acct["t_made_ns"]
+    assert sum(k["count"] for kind, k in kinds.items() if kind != "idle") \
+        == acct["passes"]
+    assert all(k["host_ns"] + k["wait_ns"] == k["ns"] for k in kinds.values())
+    assert kinds.get("idle", {"wait_ns": 0})["wait_ns"] \
+        == acct["ns"]["parked"]
+
+
+def test_every_kind_of_pass_has_its_row_and_they_partition_the_time(
+        kinds_run):
+    expected, eng, reqs, st, spans = kinds_run
+    acct = st["loop_account"]
+    kinds = acct["by_kind"]
+    assert expected | {"idle"} <= set(kinds) \
+        <= {*engine_mod._PASS_KIND, "idle"}
+    _partition(acct, acct["t_ns"])
+    # ... as of EVERY pass's end: the chain carries the account there
+    chain = [s["attributes"] for s in spans if s["name"] == "engine.account"]
+    ends = [s["t1_ns"] for s in spans if s["name"] == "engine.account"]
+    assert len(chain) == acct["passes"]
+    for a, t1_ns in zip(chain, ends):
+        _partition({**a, "t_made_ns": acct["t_made_ns"]}, t1_ns)
+    assert sum(k["wait_ns"] for k in kinds.values()) \
+        == acct["ns"]["wait"] + acct["ns"]["parked"]
+    # a pass is of exactly one kind, and of the kind of what it ran: the
+    # counters' growth over the same pass, from the same span
+    seen = set()
+    for a, b in zip(chain, chain[1:]):
+        (kind,) = [k for k, row in b["by_kind"].items()
+                   if k != "idle" and row["count"] > a["by_kind"].get(
+                       k, {"count": 0})["count"]]
+        seen.add(kind)
+        chunks, rode, steps, prompt = (
+            b["counters"][k] - a["counters"][k] for k in (
+                "chunk_passes", "chunks_in_step", "decode_iterations",
+                "prefill_tokens"))
+        if kind == "prefill":
+            assert prompt >= int(reqs[-1].prompt_tokens)
+            continue
+        assert (chunks - rode > 0) == kind.startswith("chunk")
+        assert rode == kind.endswith("step_chunk")
+        assert steps == kind.endswith(("step", "step_chunk"))
+        if kind == "chunk+step_chunk":      # lone chunks AND the one inside
+            assert chunks >= 2
+    assert expected - seen <= {"chunk"}     # (the engine's first pass)
+    if "prefill" in expected:
+        assert kinds["prefill"]["count"] == 1
+
+
+def test_kinds_count_every_token_and_gaps_every_token_but_the_first(
+        kinds_run):
+    _expected, _eng, reqs, st, spans = kinds_run
+    acct = st["loop_account"]
+    assert sum(k.get("tokens", 0) for k in acct["by_kind"].values()) \
+        == st["tokens_greedy_on_device"] + st["tokens_sampled"] \
+        == sum(len(r.tokens) for r in reqs)
+    # a request's tokens, as its ``request.decode`` span has them
+    decoded = [s["attributes"]["output_tokens"] for s in spans
+               if s["name"] == "request.decode"]
+    assert sorted(decoded) == sorted(len(r.tokens) for r in reqs)
+    assert sum(acct["gaps"].values()) == sum(n - 1 for n in decoded)
+    # a pass of chunk programs alone ends no prompt (the row whose
+    # prompt ends turns active and the pass steps it): it emits nothing
+    assert acct["by_kind"]["chunk"]["tokens"] == 0
+    # the histogram's buckets hold passes' times: none beyond the
+    # longest time between two ends of the chain
+    chain = [s for s in spans if s["name"] == "engine.account"]
+    longest = max(s["t1_ns"] - s["t0_ns"] for s in chain)
+    top = max(acct["gaps"])
+    assert tracing.Histogram.edge_ns(top) <= longest
+
+
+def test_account_span_carries_the_engines_counter_table(kinds_run):
+    _expected, eng, _reqs, st, spans = kinds_run
+    last = [s for s in spans if s["name"] == "engine.account"][-1]
+    counters = last["attributes"]["counters"]
+    from ray_tpu.serve import engine_stats
+    assert set(counters) == set(engine_stats.COUNTED)
+    assert {k: st[k] for k in counters if k in st} \
+        == {k: v for k, v in counters.items() if k in st}
+    assert counters["chunk_passes"] == last["attributes"]["chunk_passes"] > 0
+    assert counters["chunks_in_step"] == last["attributes"]["chunks_in_step"]
+    assert counters["prefill_tokens"] == st["prefill_tokens"] > 0
+    assert last["attributes"]["engine"] == eng.name
+
+
+def test_a_failed_step_leaves_the_partition_exact(monkeypatch):
+    """``_fail_all``: the failed pass ends like any other (its kind is
+    what it had launched), and the engine keeps serving and counting."""
+    cfg, params, ec, _ = _gpt_layout()
+    monkeypatch.setattr(engine_mod, "ACCOUNT_EVERY_NS", 0)
+    tracing.disable_tracing()
+    tracing.clear()
+    eng = InferenceEngine(params, cfg, ec)
+    try:
+        real_step, boom = eng._step, {"armed": True}
+
+        def failing_step(*a):
+            if boom.pop("armed", False):
+                raise RuntimeError("injected step failure")
+            return real_step(*a)
+        eng._step = failing_step
+        bad = eng.submit([1, 2], max_new=8)
+        with pytest.raises(RuntimeError, match="injected"):
+            bad.result(timeout=120)
+        good = eng.submit([3, 4, 5], max_new=4)
+        assert len(good.result(timeout=120)) == 4
+    finally:
+        eng.shutdown()
+    st = eng.stats()
+    spans = tracing.get_finished_spans()
+    tracing.clear()
+    acct = st["loop_account"]
+    _partition(acct, acct["t_ns"])
+    for s in spans:
+        if s["name"] == "engine.account":
+            _partition({**s["attributes"], "t_made_ns": acct["t_made_ns"]},
+                       s["t1_ns"])
+    # each prompt is one chunk and the step behind it in the same pass:
+    # the first one's step failed, after the chunk's first token
+    assert {k: row["count"] for k, row in acct["by_kind"].items()
+            if k != "idle"} == {"chunk+step": 2, "step": 2}
+    assert acct["by_kind"]["chunk+step"]["tokens"] == 1 + 2
+    assert sum(k.get("tokens", 0) for k in acct["by_kind"].values()) \
+        == st["tokens_greedy_on_device"] + st["tokens_sampled"] \
+        == len(bad.tokens) + len(good.tokens) == 1 + 4
+    assert sum(acct["gaps"].values()) == 3
+
+
+def test_first_yield_is_stamped_by_a_stream_and_not_by_result():
+    """``request.decode`` says when the consumer's ``stream()`` had the
+    first token in hand; a request read by ``result()`` has no such
+    stamp."""
+    cfg, params, ec, _ = _gpt_layout()
+    tracing.disable_tracing()
+    tracing.clear()
+    eng = InferenceEngine(params, cfg, ec)
+    try:
+        streamed = eng.submit([5, 6, 7], max_new=30)
+        it = streamed.stream(timeout=300)
+        next(it)
+        stamp = streamed.first_yield_s
+        assert streamed.first_token_s <= stamp <= time.monotonic()
+        assert len(list(it)) == 29 and streamed.first_yield_s == stamp
+        waited = eng.submit([5, 6, 7], max_new=6)
+        waited.result(timeout=300)
+        assert waited.first_yield_s is None
+    finally:
+        eng.shutdown()
+    spans = {s["attributes"]["req"]: s
+             for s in tracing.get_finished_spans("request.decode")}
+    tracing.clear()
+    got = spans[streamed.id]
+    assert got["t0_ns"] <= got["attributes"]["first_yield_ns"] \
+        == int(stamp * 1e9) <= got["t1_ns"]
+    assert got["attributes"]["output_tokens"] == 30
+    assert "first_yield_ns" not in spans[waited.id]["attributes"]
+    assert spans[waited.id]["attributes"]["output_tokens"] == 6

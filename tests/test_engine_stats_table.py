@@ -75,6 +75,8 @@ LOOP_ACCOUNT_TYPES = {
     "ns": dict, "starved_ns": dict, "count": dict, "unaccounted_ns": int,
     "unaccounted_starved_ns": int, "passes": int, "t_made_ns": int,
     "t_ns": int,
+    # ISSUE 54: the account by kind of pass
+    "by_kind": dict, "gaps": dict,
 }
 
 SERIES = [

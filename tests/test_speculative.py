@@ -20,6 +20,7 @@ from ray_tpu.inference import (EngineConfig, InferenceEngine,
                                SpeculationUnsupported, metrics_snapshot,
                                ngram_propose)
 from ray_tpu.models import gpt
+from ray_tpu.util import tracing
 
 
 @pytest.fixture(scope="module")
@@ -288,6 +289,7 @@ def test_temperature_rows_fall_back_transparently(params, cfg):
 def test_spec_metrics_and_per_request_accounting(params, cfg):
     """stats()/metrics_snapshot expose accept-rate and per-row
     tokens-per-step; each request carries its own accept accounting."""
+    tracing.clear()
     eng = InferenceEngine(params, cfg, _spec_cfg("ngram"))
     try:
         rep = [1, 2, 3, 4] * 6
@@ -295,7 +297,6 @@ def test_spec_metrics_and_per_request_accounting(params, cfg):
         assert req.result(timeout=300) == _ref_tokens(params, cfg, rep, 8)
         assert req.spec_drafted > 0
         assert req.spec_accepted > 0
-        assert len(req.token_times) == 8     # per-token stamps = ITL series
         st = eng.stats()
         assert st["spec_accept_rate"] > 0.0
         assert st["tokens_per_step"] > 1.0
@@ -309,6 +310,41 @@ def test_spec_metrics_and_per_request_accounting(params, cfg):
         assert series["ray_tpu_inference_tokens_per_step"][key] > 1.0
     finally:
         eng.shutdown()
+    # the request's tokens, as its lifecycle span has them
+    (decode,) = tracing.get_finished_spans("request.decode")
+    tracing.clear()
+    assert decode["attributes"]["output_tokens"] == 8
+
+
+def test_a_draft_and_verify_pass_is_of_kind_spec(params, cfg):
+    """The account by kind of pass (``tracing.Account.pass_done``): a
+    pass that drafted and verified is ``spec`` whatever else it ran, and
+    weighs EVERY token it emitted to a row that already had one at the
+    pass's whole time; a draft-dry pass is the plain step's kind."""
+    eng = InferenceEngine(params, cfg, _spec_cfg("ngram"))
+    try:
+        rep = [1, 2, 3, 4] * 6
+        out = eng.generate(rep, max_new=12, timeout=300)
+        sampled = eng.generate([5, 6, 7], max_new=5, temperature=0.8,
+                               seed=1, timeout=300)
+    finally:
+        eng.shutdown()
+    st = eng.stats()
+    acct = st["loop_account"]
+    kinds = acct["by_kind"]
+    assert kinds["spec"]["count"] == st["spec_passes"] > 0
+    # several tokens a row and pass: fewer passes than tokens
+    assert kinds["spec"]["tokens"] > kinds["spec"]["count"]
+    assert sum(k["count"] for kind, k in kinds.items() if kind != "idle") \
+        == acct["passes"]
+    assert sum(k.get("tokens", 0) for k in kinds.values()) \
+        == st["tokens_greedy_on_device"] + st["tokens_sampled"] \
+        == len(out) + len(sampled) == 17
+    assert sum(acct["gaps"].values()) == 17 - 2
+    # the sampled request is never drafted: its passes are plain steps
+    assert kinds["step"]["tokens"] + kinds["chunk+step"]["tokens"] >= 5
+    assert sum(k["ns"] for k in kinds.values()) \
+        == acct["t_ns"] - acct["t_made_ns"]
 
 
 def test_timeline_renders_engine_request_slices():

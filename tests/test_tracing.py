@@ -329,6 +329,177 @@ def test_account_starved_time_follows_launches_and_landings(clock):
     assert (snap["unaccounted_ns"], snap["unaccounted_starved_ns"]) == (7, 4)
 
 
+def test_histogram_edges_are_a_function_of_the_index():
+    """0.1 ms to 10 s in steps of at most a tenth, one bucket under and
+    one over; a duration on an edge belongs to the bucket that starts
+    there."""
+    H = tracing.Histogram
+    edges = [H.edge_ns(i) for i in range(H.N)]
+    assert edges[:2] == [0, 100_000] and edges[-1] == 10_000_000_000
+    assert H.N <= 125
+    assert all(a < b <= a * 11 // 10 for a, b in zip(edges[1:], edges[2:]))
+    h = H()
+    for i in range(1, H.N):
+        h.add(edges[i])
+        h.add(edges[i] - 1, weight=2)
+    h.add(0)
+    h.add(10 ** 12)
+    snap = h.snapshot()
+    assert snap[0] == 3 and snap[H.N - 1] == 2
+    assert all(snap[i] == 3 for i in range(1, H.N - 1))
+    assert H().snapshot() == {}          # sparse: only what was counted
+
+
+@pytest.mark.parametrize("q", [50, 90, 95, 99])
+def test_histogram_quantile_is_within_a_bucket_of_the_sorted_list_s(q):
+    """Weighted durations in, a snapshot differenced against an earlier
+    one out: the reader's interpolated quantile lies within one bucket's
+    width (a tenth) of the quantile of the weighted list itself."""
+    from chipbench import pass_ledger
+    rng = np.random.default_rng(q)
+    h = tracing.Histogram()
+    for ns in rng.integers(100_000, 10 ** 9, 50):      # an earlier window
+        h.add(int(ns))
+    before = h.snapshot()
+    durations = np.exp(rng.uniform(np.log(2e5), np.log(5e9), 3_000))
+    weights = rng.integers(0, 40, durations.size)
+    listed = []
+    for ns, w in zip(durations.astype(int), weights):
+        h.add(int(ns), int(w))
+        listed += [int(ns)] * int(w)
+    after = h.snapshot()
+    hist = {i: after[i] - before.get(i, 0) for i in after}
+    assert sum(hist.values()) == len(listed) == int(weights.sum())
+    want = float(np.percentile(listed, q)) / 1e6
+    got = pass_ledger.quantile_ms(hist, q)
+    assert abs(got - want) <= 0.1 * want
+    assert pass_ledger.quantile_ms({}, q) is None
+
+
+def test_account_by_kind_partitions_the_threads_time_exactly(clock):
+    """A unit runs from the last one's end to the newest stamp, a
+    stretch with no work being a unit too: the kinds' ``ns`` add up to
+    the account's time; a kind's ``host_ns`` + ``wait_ns`` is its
+    ``ns``, the ``waits`` phases being the wait; what the caller counts
+    is summed a kind; ``gaps`` weighs a unit's time by the tokens that
+    waited for it."""
+    tracing.disable_tracing()
+    acct = tracing.Account({**PHASES, "parked": None}, launch=("dispatch",),
+                           land=("wait",), waits=("wait", "parked"))
+
+    def spend(phase, ns):
+        with acct.phase(phase):
+            clock.now += ns
+    clock.now += 4                       # the account was made: unit 1
+    spend("admit", 10)
+    spend("dispatch", 20)
+    clock.now += 1
+    spend("wait", 300)
+    spend("host", 6)
+    clock.now += 2      # after the newest stamp: the NEXT unit's time
+    acct.pass_done("step", 4, tokens=5)
+    spend("dispatch", 30)                # unit 2 starts 2 ns back
+    spend("dispatch", 40)
+    spend("wait", 700)
+    acct.pass_done("chunk+step", 2, tokens=3, chunks=1)
+    clock.now += 9                       # turn-around, then no work
+    spend("parked", 5_000)
+    acct.pass_done("idle")
+    clock.now += 3                       # woken for nothing, parks again
+    spend("parked", 1_000)
+    acct.pass_done("idle")
+    clock.now += 7                       # unit 5 starts where it woke
+    spend("admit", 10)
+    acct.pass_done("step", tokens=1)
+    snap = acct.snapshot()
+    assert snap["by_kind"] == {
+        "step": {"count": 2, "ns": 341 + 17, "host_ns": 41 + 17,
+                 "wait_ns": 300, "tokens": 6},
+        "chunk+step": {"count": 1, "ns": 772, "host_ns": 72,
+                       "wait_ns": 700, "tokens": 3, "chunks": 1},
+        "idle": {"count": 2, "ns": 6_012, "host_ns": 9 + 3,
+                 "wait_ns": 6_000}}
+    assert snap["by_kind"]["idle"]["wait_ns"] == snap["ns"]["parked"]
+    assert sum(k["ns"] for k in snap["by_kind"].values()) \
+        == acct.t_ns - acct.t_made_ns == clock.now - 1_000
+    assert all(k["host_ns"] + k["wait_ns"] == k["ns"]
+               for k in snap["by_kind"].values())
+    # the phases' own partition is untouched by the units
+    assert sum(snap["ns"].values()) + snap["unaccounted_ns"] \
+        == clock.now - 1_000
+    # 4 tokens waited 341 ns, 2 waited 772; a first token is no gap
+    assert snap["gaps"] == {0: 6}
+    big = tracing.Account(PHASES, land=("wait",), waits=("wait",))
+    clock.now += 250_000
+    spend_big = big.phase("wait")
+    with spend_big:
+        clock.now += 3_000_000
+    big.pass_done("spec", 9, tokens=9)
+    (bucket,) = big.snapshot()["gaps"]
+    assert tracing.Histogram.edge_ns(bucket) <= 3_250_000 \
+        < tracing.Histogram.edge_ns(bucket + 1)
+    assert big.snapshot()["gaps"][bucket] == 9
+
+
+def test_account_snapshot_from_another_thread_while_kinds_appear():
+    """One writer, no lock: a reader on another thread copies the rows
+    by kind while the writer adds kinds it has not seen before, and
+    every copy is whole (a row's five fields, counts that only grow)."""
+    import sys
+    acct = tracing.Account({"wait": None}, land=("wait",), waits=("wait",))
+    errors, seen = [], []
+    done = threading.Event()
+
+    def read():
+        try:
+            while not done.is_set():
+                snap = acct.snapshot()
+                assert all(set(row) == {"count", "ns", "host_ns", "wait_ns",
+                                        "tokens"}
+                           for row in snap["by_kind"].values())
+                seen.append(sum(r["count"] for r in snap["by_kind"].values()))
+        except BaseException as e:         # handed to the test's thread
+            errors.append(e)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    reader = threading.Thread(target=read, daemon=True)
+    try:
+        reader.start()
+        for i in range(20_000):
+            with acct.phase("wait"):
+                pass
+            acct.pass_done(f"kind-{i % 997}", 1, tokens=1)
+    finally:
+        done.set()
+        reader.join(timeout=60)
+        sys.setswitchinterval(interval)
+    assert not reader.is_alive() and not errors
+    assert seen == sorted(seen) and seen[-1] <= 20_000
+    snap = acct.snapshot()
+    assert len(snap["by_kind"]) == 997
+    assert sum(r["count"] for r in snap["by_kind"].values()) == 20_000 \
+        == sum(snap["gaps"].values())
+
+
+def test_record_account_chains_and_says_what_touched_it(clock):
+    """A link runs from where the writer's last one ended to the stamp
+    it is given, carries the writer's counters and says whether a
+    profiler session touched its interval."""
+    tracing.disable_tracing()
+    tracing.clear()
+    end = tracing.record_account("x.account", 1_000, 1_500, True, n=2)
+    assert end == 1_500
+    end = tracing.record_account("x.account", end, 2_100, False, n=5)
+    assert end == 2_100
+    a, b = tracing.get_finished_spans("x.account")
+    tracing.clear()
+    assert (a["t0_ns"], a["t1_ns"], b["t0_ns"], b["t1_ns"]) \
+        == (1_000, 1_500, 1_500, 2_100)
+    assert a["attributes"] == {"profiling": True, "ring_dropped":
+                               tracing.ring_dropped(), "n": 2}
+    assert b["attributes"]["profiling"] is False and b["attributes"]["n"] == 5
+
+
 def test_ring_counts_what_it_evicts(monkeypatch):
     """``deque(maxlen)`` drops the oldest span in silence; the count
     tells a reader that its window may be cut."""
@@ -596,6 +767,83 @@ def test_front_request_is_the_root_of_a_request_trace(tiny):
     first_token = mine[1]["t1_ns"]
     assert first_token <= front["attributes"]["first_chunk_ns"] \
         <= front["t1_ns"]
+
+
+def _post(host, port, payload) -> bytes:
+    import json
+    import socket
+    body = json.dumps(payload).encode()
+    with socket.create_connection((host, int(port)), timeout=120) as s:
+        s.sendall(b"POST /v1/generate HTTP/1.1\r\nHost: x\r\n"
+                  + f"Content-Length: {len(body)}\r\n\r\n".encode() + body)
+        s.settimeout(120)
+        buf = b""
+        while b"0\r\n\r\n" not in buf and not (
+                b"Content-Length" in buf and buf.endswith(b"}")):
+            data = s.recv(4096)
+            if not data:
+                break
+            buf += data
+    return buf.split(b"\r\n\r\n", 1)[1]
+
+
+def test_front_account_chains_and_counts_one_gap_fewer_than_chunks(
+        tiny, monkeypatch):
+    """The proxy counts, always, the time between a streamed response's
+    chunks as it has written them, and carries the counts to the ring as
+    a chain of ``front.account`` spans; nothing is written while nothing
+    streams.  ``request.decode`` says when the stream had its first
+    token: between the engine's emit and the first chunk's write."""
+    from ray_tpu import serve
+    from ray_tpu.inference import (EngineConfig, build_gpt_deployment,
+                                   parse_stream_chunks)
+    from ray_tpu.serve import asgi
+    cfg, params = tiny
+    monkeypatch.setattr(asgi, "ACCOUNT_EVERY_NS", 0)   # a span a chunk
+    tracing.disable_tracing()
+    tracing.clear()
+    serve.run(build_gpt_deployment(
+        cfg=cfg, engine_cfg=EngineConfig(max_slots=2), params=params),
+        use_actors=False, http=True)
+    try:
+        address = serve.proxy_address()[len("http://"):]
+        host, port = address.split(":")
+        plain = _post(host, port, {"prompt": [9, 2, 6], "max_tokens": 3})
+        assert b"tokens" in plain
+        assert tracing.get_finished_spans("front.account") == []
+        sent = []
+        for n in (40, 3):
+            chunks = parse_stream_chunks(_post(host, port, {
+                "prompt": [9, 2, 6], "max_tokens": n, "stream": True}))
+            assert chunks[-1]["done"] is True and len(chunks) == n + 1
+            sent.append(len(chunks))
+        deadline = time.time() + 10
+        while time.time() < deadline and len(
+                tracing.get_finished_spans("front.request")) < 3:
+            time.sleep(0.01)
+    finally:
+        serve.shutdown()
+    chain = tracing.get_finished_spans("front.account")
+    spans = tracing.get_finished_spans()
+    tracing.clear()
+    assert len(chain) == sum(sent) == 44 + 1     # one a chunk the client read
+    gaps = [sum(s["attributes"]["write_gaps"].values()) for s in chain]
+    for a, b in zip(chain, chain[1:]):
+        assert b["t0_ns"] == a["t1_ns"] <= b["t1_ns"]
+    # a response's first chunk is no gap: one gap fewer than its chunks
+    assert gaps == [*range(sent[0]), *range(sent[0] - 1, sum(sent) - 1)]
+    last = chain[-1]["attributes"]
+    assert set(last) == {"proxy", "write_gaps", "profiling", "ring_dropped"}
+    assert last["proxy"] == address and last["profiling"] is False
+    assert chain[0]["attributes"]["write_gaps"] == {}    # a first chunk
+    # the first token's two hops, of the long streamed request
+    decodes = [s for s in spans if s["name"] == "request.decode"]
+    assert "first_yield_ns" not in decodes[0]["attributes"]     # result()
+    woke = decodes[1]["attributes"]["first_yield_ns"]
+    front = next(s for s in spans if s["name"] == "front.request"
+                 and s["trace_id"] == decodes[1]["trace_id"])
+    assert decodes[1]["t0_ns"] <= woke \
+        <= front["attributes"]["first_chunk_ns"] == chain[0]["t1_ns"]
 
 
 def _fit(tmp_path, steps, report_every):
