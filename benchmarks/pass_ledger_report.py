@@ -2,7 +2,8 @@
 runs it, and then what ``chipbench/pass_ledger.py`` reads from the ring
 that the result line has no room for (PR 54; PERF.md section 5's table
 a cell): every KIND of pass with its count and its mean time, host and
-wait, the gaps' p50 / p95 / p99 as the engine emitted them and as the
+wait, how many of them were launched ahead of an unread pass and how
+many read early (PR 55), the gaps' p50 / p95 / p99 as the engine emitted them and as the
 front wrote them beside the client's, and the first token's two hops.
 
     chiprun -- python benchmarks/pass_ledger_report.py \
@@ -28,18 +29,29 @@ sys.path.insert(0, ROOT)
 def report(obs: dict, client: dict) -> dict:
     """``obs`` as a per-layer reader is handed it; ``client``: the
     client's own quantiles of the gap, {50: ms, 95: ms}."""
-    from chipbench import pass_ledger, stats
+    from chipbench import loop_account, pass_ledger, spans, stats
     led = pass_ledger.engine(obs)
     wrote = pass_ledger.front_gaps(obs)
     firsts = pass_ledger.first_tokens(obs)
     out = {"passes": led and led["passes"], "kinds": {}, "gaps_ms": {}}
+    for key in ("passes_launched_ahead", "passes_drained"):
+        out[key] = led["counters"].get(key) if led else None
+    # why passes were read early, since the engine was made (the newest
+    # ``engine.account`` span's own table)
+    chain = [s for s in spans.finished_spans(obs)
+             if s["name"] == loop_account.NAME]
+    out["drained_by"] = max(chain, key=lambda s: s["t1_ns"])[
+        "attributes"].get("drained_by") if chain else None
     for kind, row in sorted(led["by_kind"].items() if led else ()):
         if row["count"]:
             out["kinds"][kind] = {
                 "passes": row["count"],
                 **{part + "ms": row[part + "ns"] / row["count"] / 1e6
                    for part in ("", "host_", "wait_")},
-                "tokens": row.get("tokens", 0) / row["count"]}
+                "tokens": row.get("tokens", 0) / row["count"],
+                # (since PR 55; a parent's rows have neither)
+                **({"launched_ahead": row["ahead"],
+                    "drained": row["drained"]} if "ahead" in row else {})}
     for q in (50, 95, 99):
         out["gaps_ms"][f"p{q}"] = {
             "engine": led and pass_ledger.quantile_ms(led["gaps"], q),

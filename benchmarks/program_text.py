@@ -46,6 +46,13 @@ def main() -> int:
     from ray_tpu.inference.cache import BlockPool, PoolLayout
     from ray_tpu.models import gpt, hybrid
 
+    def feed(fn, rows: int) -> tuple:
+        """The token array, where the checkout's programs take one
+        (since PR 55) before their packed array."""
+        if "feed" not in inspect.signature(fn).parameters:
+            return ()
+        return (jax.ShapeDtypeStruct((rows,), jnp.int32),)
+
     out = {}
     for name, kind in CELLS.items():
         path = os.path.join("chipbench", "configs", f"{name}.json")
@@ -87,7 +94,7 @@ def main() -> int:
                 (e["max_slots"] * (T + 3) + T + C + 3,))
         for which, (fn, shape) in programs.items():
             out[f"{name}/{which}"] = digest(fn.lower(
-                params, pools, state,
+                params, pools, state, *feed(fn, e["max_slots"]),
                 jax.ShapeDtypeStruct(shape, jnp.int32)).as_text())
 
     with open(os.path.join("chipbench", "configs", "gpt2-xl.json")) as f:
@@ -110,7 +117,7 @@ def main() -> int:
             ("step_chunk", decode.make_paged_step_chunk(gcfg, chunk=C, **kw),
              (e["max_slots"] * (T + 3) + T + C + 3,))):
         out[f"gpt2-xl/{which}"] = digest(fn.lower(
-            params, pool, pool,
+            params, pool, pool, *feed(fn, e["max_slots"]),
             jax.ShapeDtypeStruct(shape, jnp.int32)).as_text())
     print(json.dumps(out, indent=1))
     return 0

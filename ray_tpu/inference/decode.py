@@ -45,7 +45,13 @@ decompressed keys at a time (latent_window_attention).
     ``pack_chunk``), and beside the logits, which stay on the device,
     the greedy tokens out — the argmax of the float32 logits taken
     inside the program (every row's; the chunk's last real
-    position's).  recurrent.py's two programs do the same.
+    position's).  recurrent.py's two programs do the same.  The same
+    tokens also STAY on the device, in the token array every program of
+    a pass carries beside the pools (``feed``, int32 a decode row,
+    donated): a step reads a row's input token there where the packed
+    array says ``FEED`` and writes every stepped row's greedy token
+    back, a chunk writes its own at its row — so the engine can launch
+    the next pass before it has read this one's integers.
   * paged_step_chunk — the two above as ONE program, for the pass that
     holds both a chunk and decoding rows: the rows' tokens and the
     chunk's are one window of the layer function, so a layer's weights
@@ -421,6 +427,12 @@ def _pools_in(cfg, k_pool, v_pool, mesh, rules):
                       for p in (k_pool, v_pool))
 
 
+def _feed_out(feed, mesh, rules):
+    """The token array as a program returns it: replicated, as it came
+    in (so that the donated buffer is the result's)."""
+    return gpt._constrain(feed, (None,), mesh, rules)
+
+
 def _paged_layers(cfg, mesh, rules, layers, x, pools, attend_over):
     """The stacked ``layers`` on the window x [b, w, d], the pools their
     scan's carry.  ``attend_over(pools)`` is the program's
@@ -446,20 +458,43 @@ def _paged_layers(cfg, mesh, rules, layers, x, pools, attend_over):
 # four tiny ``convert_element_type`` programs, 1.3-1.9 ms of a pass on
 # the chip with the device idle.
 
+# In ``pack_step``'s token column: the row's input token is the one the
+# device holds for it (the programs' token array ``feed``), not the
+# host's.
+FEED = -1
+
+
 def pack_step(tables, tokens, positions, active) -> np.ndarray:
     """The decode step's host inputs as one fresh int32 ``[b, T + 3]``:
-    a row's block table, then its token, position and whether it is
-    active."""
+    a row's block table, then its token (``FEED``: the one the device
+    holds for the row), position and whether it is active."""
     return np.concatenate(
         [tables, tokens[:, None], positions[:, None], active[:, None]],
         axis=1, dtype=np.int32)
 
 
-def unpack_step(packed, T: int):
+def unpack_step(packed, T: int, feed):
     """-> (tables [b, T], tokens [b], positions [b], active [b] bool) of
-    a ``pack_step`` array, inside a program."""
-    return (packed[:, :T], packed[:, T], packed[:, T + 1],
-            packed[:, T + 2] != 0)
+    a ``pack_step`` array, inside a program; a row whose token column
+    says ``FEED`` takes its token from the program's token array
+    ``feed`` [b]."""
+    tokens = jnp.where(packed[:, T] == FEED, feed, packed[:, T])
+    return packed[:, :T], tokens, packed[:, T + 1], packed[:, T + 2] != 0
+
+
+def feed_step(feed, active, greedy):
+    """The token array after a decode step: every stepped row's greedy
+    token is what the row feeds next."""
+    return jnp.where(active, greedy, feed)
+
+
+def feed_chunk(feed, row, n_valid, greedy):
+    """The token array after a chunk of ``n_valid`` real tokens of
+    decode row ``row``: the greedy token of its last real position is
+    what the row feeds next (the prompt's first token where the chunk
+    ends it; overwritten by the next chunk where it does not).  A chunk
+    of nothing (the warm-up's) writes nothing."""
+    return feed.at[row].set(jnp.where(n_valid > 0, greedy, feed[row]))
 
 
 def pack_chunk(table, tokens, start: int, row: int,
@@ -516,11 +551,18 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
                            rules: Rules = DEFAULT_LLM_RULES):
     """jitted one-token step over the whole row batch, block-pool cache.
 
-    (params, k_pool, v_pool [cache.PoolLayout], packed [b, T + 3] int32
+    (params, k_pool, v_pool [cache.PoolLayout], feed [b] int32,
+     packed [b, T + 3] int32
      (``pack_step``: tables | tokens | positions | active))
-        -> (logits [b, vocab] f32, greedy [b] int32, k_pool, v_pool)
+        -> (logits [b, vocab] f32, greedy [b] int32, k_pool, v_pool,
+            feed)
 
-    ``greedy`` is the argmax of the float32 logits, taken inside the
+    ``feed`` is the token each decode row feeds next, resident on the
+    device and donated like the pools (replicated under a mesh): a row
+    whose token column says ``FEED`` reads its input token there, and
+    every stepped row's greedy token is written back to it — so the
+    next step can be launched before the host has read this one's
+    tokens.  ``greedy`` is the argmax of the float32 logits, taken inside the
     program (ties to the lowest index, what ``gpt.sample_token`` at
     temperature 0 gives; under a mesh over the vocabulary-sharded
     logits): a greedy pass fetches ``b`` integers and the logits stay
@@ -536,9 +578,9 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
     bs, T = int(block_size), int(n_table)
 
     def build():
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def step(params, k_pool, v_pool, packed):
-            tables, tokens, positions, active = unpack_step(packed, T)
+        @partial(jax.jit, donate_argnums=(1, 2, 3))
+        def step(params, k_pool, v_pool, feed, packed):
+            tables, tokens, positions, active = unpack_step(packed, T, feed)
             lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
             x = (gpt._token_rows(params, tokens, cfg)
                  + params["wpe"][positions])
@@ -553,7 +595,8 @@ def make_paged_decode_step(cfg: GPTConfig, *, block_size: int,
                               for p in pools)
             logits = gpt._head(params, x, cfg, mesh, rules)[:, 0, :]
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return logits, greedy, k_pool, v_pool
+            return (logits, greedy, k_pool, v_pool,
+                    _feed_out(feed_step(feed, active, greedy), mesh, rules))
 
         return step
 
@@ -565,14 +608,17 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
                           rules: Rules = DEFAULT_LLM_RULES):
     """jitted fixed-width prefill chunk against the block pool.
 
-    (params, k_pool, v_pool [cache.PoolLayout], packed [T + C + 3] int32
+    (params, k_pool, v_pool [cache.PoolLayout], feed [b] int32,
+     packed [T + C + 3] int32
      (``pack_chunk``: table | tokens | start, row, n_valid))
-        -> (logits [C, vocab] f32, greedy [1] int32, k_pool, v_pool)
+        -> (logits [C, vocab] f32, greedy [1] int32, k_pool, v_pool,
+            feed)
 
     ``greedy`` is the argmax of the last REAL position's logits
     (``n_valid - 1``; a prompt's first token when the chunk ends it),
-    taken inside the program like the decode step's; ``row`` is the
-    recurrent family's (recurrent.py) and not read here.
+    taken inside the program like the decode step's, and written to
+    decode row ``row``'s entry of ``feed`` (the decode step's token
+    array), where the row's first decode step finds it.
 
     Processes prompt positions ``start .. start+C``: the window's K/V
     goes through the block table (rows past the table's span are
@@ -589,9 +635,9 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
     bs, C, T = int(block_size), int(chunk), int(n_table)
 
     def build():
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def chunk_fn(params, k_pool, v_pool, packed):
-            table, tokens, start, _, n_valid = unpack_chunk(packed, T, C)
+        @partial(jax.jit, donate_argnums=(1, 2, 3))
+        def chunk_fn(params, k_pool, v_pool, feed, packed):
+            table, tokens, start, row, n_valid = unpack_chunk(packed, T, C)
             lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
             wpe_pos, bidx, off, mask = _chunk_indices(cfg, table, start,
                                                       C, bs)
@@ -608,7 +654,9 @@ def make_chunk_prefill_fn(cfg: GPTConfig, *, chunk: int, block_size: int,
             logits = gpt._head(params, x, cfg, mesh, rules)[0]  # [C, V]
             greedy = jnp.argmax(logits[jnp.maximum(n_valid, 1) - 1]
                                 ).astype(jnp.int32)
-            return logits, greedy[None], k_pool, v_pool
+            return (logits, greedy[None], k_pool, v_pool,
+                    _feed_out(feed_chunk(feed, row, n_valid, greedy),
+                              mesh, rules))
 
         return chunk_fn
 
@@ -623,11 +671,11 @@ def make_paged_step_chunk(cfg: GPTConfig, *, chunk: int, block_size: int,
     and ``make_chunk_prefill_fn``'s programs back to back, so that each
     layer's weights stream from HBM once a pass, not twice.
 
-    (params, k_pool, v_pool [cache.PoolLayout], packed [b * (T + 3) +
-     T + C + 3] int32 (``pack_step_chunk``: a ``pack_step`` array, flat,
-     then a ``pack_chunk`` array))
+    (params, k_pool, v_pool [cache.PoolLayout], feed [b] int32,
+     packed [b * (T + 3) + T + C + 3] int32 (``pack_step_chunk``: a
+     ``pack_step`` array, flat, then a ``pack_chunk`` array))
         -> (logits [b + 1, vocab] f32, greedy [b + 1] int32, k_pool,
-            v_pool)
+            v_pool, feed)
 
     The ``b`` rows' tokens and the chunk's ``C`` are ONE window
     ``[1, b + C]`` of the layer function: every product of it reads its
@@ -644,12 +692,12 @@ def make_paged_step_chunk(cfg: GPTConfig, *, chunk: int, block_size: int,
     bs, C, T = int(block_size), int(chunk), int(n_table)
 
     def build():
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def step_chunk(params, k_pool, v_pool, packed):
+        @partial(jax.jit, donate_argnums=(1, 2, 3))
+        def step_chunk(params, k_pool, v_pool, feed, packed):
             b = (packed.shape[0] - (T + C + 3)) // (T + 3)
             tables, tokens, positions, active = unpack_step(
-                packed[:b * (T + 3)].reshape(b, T + 3), T)
-            table, chunk_tokens, start, _, n_valid = unpack_chunk(
+                packed[:b * (T + 3)].reshape(b, T + 3), T, feed)
+            table, chunk_tokens, start, row, n_valid = unpack_chunk(
                 packed[b * (T + 3):], T, C)
             lay, pools = _pools_in(cfg, k_pool, v_pool, mesh, rules)
             bidx, off, kv_len = _step_indices(tables, positions, active, bs)
@@ -674,7 +722,10 @@ def make_paged_step_chunk(cfg: GPTConfig, *, chunk: int, block_size: int,
                 axis=1)                                    # [1, b + 1, d]
             logits = gpt._head(params, x, cfg, mesh, rules)[0]
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return logits, greedy, k_pool, v_pool
+            feed = feed_chunk(feed_step(feed, active, greedy[:b]), row,
+                              n_valid, greedy[b])
+            return (logits, greedy, k_pool, v_pool,
+                    _feed_out(feed, mesh, rules))
 
         return step_chunk
 

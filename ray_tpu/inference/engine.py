@@ -2,7 +2,11 @@
 scheduling over a fixed decode-batch width) with a PAGED KV cache.
 
 One background loop owns the model state and runs one compiled decode
-step per iteration over ALL rows at once.  Between steps — the prefill
+step per iteration over ALL rows at once, ONE PASS AHEAD of what it has
+read: pass N+1 is dispatched, queued on the device behind pass N, before
+pass N's tokens are fetched and emitted (``_loop_pass``, ``_pass_done``,
+``_land``), so the host's turn-around runs beside the device and not
+between two of its programs.  Between steps — the prefill
 boundary — it admits waiting requests, advances prefills, and evicts
 finished requests (EOS / max-tokens).  Requests therefore join and
 leave MID-DECODE of their neighbors: a long generation never blocks a
@@ -74,11 +78,37 @@ MLP; not latent attention, the delta rule or window layers — which each
 seam's ``build`` derives from the configuration): the pass's last chunk
 is prepared and packed, and launched by the decode step.  A prompt's
 greedy first token is its last
-chunk's own argmax, read inside the decode step's fetch: the fused
+chunk's own argmax, read inside the pass's fetch: the fused
 program's last integer, or the chunk program's, which is not waited for
 before the step goes out behind it (_finish_prefill).  The full-width
 prefill and the speculation programs have no greedy output: they sample
 on the logits as before.
+
+What lets the loop run ahead: the token each row feeds its next step
+stays ON the device.  Every program of a pass carries a small int32
+array, a token a row (``_feed``; donated like the pools, replicated
+under a mesh): the decode step and the fused program write every stepped
+row's greedy token into it, a chunk program the greedy token of its last
+real position at its row, and a step reads a row's input token there
+wherever the packed array says ``decode.FEED`` in the token column — a
+row whose token the host has not read yet.  A row whose token the host
+knows (a sampled one, a full-width prefill's, every row of a
+speculating engine) is handed it in the packed array, as before.  What
+the host needs to pack pass N+1 it knows without pass N's tokens:
+positions advance by one, a row that reaches ``max_new`` at N sits out
+of N+1 (occupied until its last token lands and evicts it), a prompt
+whose last chunk rode N turns active in N+1.  What it cannot know: a row
+that emits EOS at N was stepped in N+1 already — that token is dropped
+where N+1 lands (the request has left the row), and what the stray step
+wrote lies in blocks and a state row that any later owner's programs
+run behind.  A row with temperature > 0 takes its token on the host: a
+pass with such a row is read before the next is packed, as is the pass
+in flight before anything off the hot path (a preemption, a cancelled
+row, a cross-thread op, a full-width prefill, shutdown: ``_drain``);
+an engine that speculates reads every pass at once.  Nothing in flight
+is the degenerate case of the one algorithm; ``passes_launched_ahead``
+over the account's ``passes`` is its hit share, ``passes_drained`` (by
+reason: the account's ``drained_by``) what fell back.
 """
 
 from __future__ import annotations
@@ -98,7 +128,7 @@ import numpy as np
 from ray_tpu.core import fault_injection as _fi
 from ray_tpu.core import flight_recorder as _fr
 from ray_tpu.inference.cache import BlockPool, RadixIndex
-from ray_tpu.inference.decode import (SpeculationUnsupported,
+from ray_tpu.inference.decode import (FEED, SpeculationUnsupported,
                                       make_chunk_prefill_fn,
                                       make_paged_decode_step,
                                       make_paged_draft_step,
@@ -399,6 +429,50 @@ def _kind_of(launched: int) -> str:
 _PASS_KIND = tuple(_kind_of(launched) for launched in range(32))
 
 
+class _Pass:
+    """What ONE pass launched and the host has not read yet.  The loop
+    builds one (``InferenceEngine._pass``) as it dispatches the pass's
+    programs, and keeps at most ONE more, whole, in flight behind it
+    (``_flight``): its tokens are read (``_land``) after the next pass
+    has been dispatched, so the device always has a program queued.
+
+    ``launched``: the programs, as ``_PASS_KIND`` indexes them.
+    ``stepped``: the (row, request) pairs its decode step stepped, in
+    the rows' order (None: no step); ``loads``: the int32 vectors the
+    step's fetch brings, still on the device, the step's own last;
+    ``step_seq``: the step's place among the loop's launches
+    (``tracing.Account.launched``);
+    ``logits``: the step's, where a stepped row samples on them (else
+    their shape alone: two passes must not both hold a pass's logits).
+    ``first``: the first tokens
+    its chunks owe, [row, request, the program's int32 vector (the
+    token is its last), the program's place among the dispatches];
+    ``joining``: the (row, request) pairs of them that turn active
+    where the pass ends.  ``chunks``: the int32 vector of its last
+    chunk PROGRAM and that program's place among the dispatches (None:
+    it launched none, or they are known to have ended).  ``ahead``: it
+    was dispatched while the pass before it was unread; ``drained``: it
+    was read before the next pass could be launched."""
+
+    __slots__ = ("launched", "stepped", "loads", "step_seq", "logits",
+                 "first", "joining", "chunks", "ahead", "drained")
+
+    def __init__(self):
+        self.launched = 0
+        self.stepped = None
+        self.loads = []
+        self.step_seq = 0
+        self.logits = None
+        self.first = []
+        self.joining = []
+        self.chunks = None
+        self.ahead = self.drained = False
+
+    @property
+    def owes(self) -> bool:
+        return self.stepped is not None or bool(self.first)
+
+
 def _engine_loop(ref: "weakref.ref[InferenceEngine]") -> None:
     """Loop-thread driver.  A strong reference exists only DURING a
     pass; between passes the engine is collectable, and a collected
@@ -432,9 +506,10 @@ class _Seam:
     int32 vector: ``N_LOAD`` counts of the routed experts' load, then
     the greedy tokens (every row's from the step, the last real
     position's from a chunk).  The pass's host algorithm over them is
-    the engine's own, stated once (``_fetch_step``, ``_emit_first``,
-    ``_pass_done``); a seam states what differs: the tree served, the
-    programs, the pools ``run`` hands them, the load."""
+    the engine's own, stated once (``_pass_done``, ``_land``); a seam
+    states what differs: the tree served, the programs, the pools
+    ``run`` hands them (the token array ``eng._feed`` behind them, for
+    both), the load."""
 
     N_LOAD = 0
 
@@ -446,7 +521,9 @@ class _Seam:
 
     @staticmethod
     def greedy(eng, logits):
-        """The decode step's greedy tokens, one a row, as fetched."""
+        """The decode step's greedy tokens, one a row, as fetched
+        (``logits``: the step's, or their shape alone where no stepped
+        row samples)."""
         return eng._greedy
 
     @staticmethod
@@ -501,11 +578,12 @@ class _KVOnly(_Seam):
     @staticmethod
     def operands(eng) -> tuple:
         """What every program of a pass takes before its packed array."""
-        return eng.params, eng.pool.k, eng.pool.v
+        return eng.params, eng.pool.k, eng.pool.v, eng._feed
 
     @staticmethod
     def run(eng, program, packed):
-        logits, greedy, k, v = program(*_KVOnly.operands(eng), packed)
+        logits, greedy, k, v, eng._feed = program(
+            *_KVOnly.operands(eng), packed)
         eng.pool.swap(k, v)
         # no load to count: only the newest program's tokens are owed
         eng._load = [greedy]
@@ -592,12 +670,12 @@ class _KVAndState(_Seam):
     def operands(eng) -> tuple:
         st = eng.pool.state
         return (eng.params, eng.pool.pools,
-                () if st is None else st.arrays)
+                () if st is None else st.arrays, eng._feed)
 
     @staticmethod
     def run(eng, program, packed):
-        logits, load, pools, state = program(*_KVAndState.operands(eng),
-                                             packed)
+        logits, load, pools, state, eng._feed = program(
+            *_KVAndState.operands(eng), packed)
         eng.pool.swap(*pools)
         if eng.pool.state is not None:
             eng.pool.state.swap(*state)
@@ -738,13 +816,21 @@ class InferenceEngine:
                 k=ec.speculate_k, block_size=bs,
                 n_table=self.pool.blocks_per_seq, mesh=mesh,
                 rules=rules) if self._spec == "self" else None)
+        # the token each row feeds its next decode step, ON the device:
+        # the programs read and write it (``decode.FEED``), carried and
+        # donated like the pools (replicated under a mesh), so a step
+        # can be launched before the last one's tokens have been read
+        self._feed = self._zero_feed()
         # the programs' int32 vectors (load counts, then greedy tokens)
-        # still on the device, the last decode step's greedy tokens as
-        # fetched, and the first tokens this pass's chunks owe:
-        # [row, request, its chunk's vector, the token once read]
+        # that no pass has taken yet (a pass's step takes them all), and
+        # the last decode step's greedy tokens as fetched
         self._load = []
         self._greedy = None
-        self._first_pending = []
+        # the pass being dispatched, and the one before it while its
+        # tokens are unread: at most ONE pass is in flight ahead of what
+        # the host has read (``_pass_done``, ``_land``)
+        self._pass = _Pass()
+        self._flight: Optional[_Pass] = None
         # a row's block table(s) as the programs take them: the full
         # layers' [n, T], then (a model with window layers) the window
         # layers' beside it, ONE array [n, 2 T] of which both are views.
@@ -762,9 +848,18 @@ class InferenceEngine:
         self._prefilling: dict[int, int] = {}   # row -> next prefill pos
 
         self._slot_req: dict[int, GenerationRequest] = {}
-        self._tokens = np.zeros(n, np.int32)      # current input token
+        # a row's next input token where the host knows it, ``FEED``
+        # where it is still on the device (launched, not read yet)
+        self._tokens = np.zeros(n, np.int32)
         self._positions = np.zeros(n, np.int32)   # where it will be written
+        # rows the next decode step steps.  A row whose LAST token is on
+        # its way (``max_new`` reached by what is launched) sits out,
+        # occupied, until that token lands and evicts it
         self._active = np.zeros(n, bool)
+        # tokens launched for a row and not read yet (0 .. 2)
+        self._owed = np.zeros(n, np.int32)
+        # occupied rows whose request samples (temperature > 0)
+        self._sampling = 0
         self._waiting: list[GenerationRequest] = []
         self._req_seq = itertools.count()
         self._cond = threading.Condition()
@@ -802,17 +897,23 @@ class InferenceEngine:
         self._snapshots = self.pool.snapshots
         # the loop thread's time by phase, always on; ``engine.account``
         # spans carry it to the ring (``_pass_ended``)
-        self._passes = 0               # passes that found work
+        self._passes = 0               # units of the account: passes
+        #                                whose tokens were read, and
+        #                                passes that owed none
         self._acct = tracing.Account(_LOOP_PHASES, launch=("dispatch",),
                                      land=("wait",),
                                      waits=("wait", "parked"))
         self._account_t1_ns = self._acct.t_made_ns
-        # the pass in progress: what it launched (``_PASS_KIND``), the
-        # first tokens it emitted; and the tokens emitted as the last
-        # pass ended
+        # what the pass being dispatched has launched (``_PASS_KIND``);
+        # the first tokens emitted since the account's last unit and the
+        # tokens emitted as it ended; whether this turn of the loop has
+        # landed a pass (its unit is booked where it lands); why passes
+        # in flight were landed early
         self._launched = 0
         self._first_tokens = 0
         self._emitted = 0
+        self._landed_now = False
+        self._drained_by: dict[str, int] = {}
 
         with _registry_lock:
             self.name = name or f"engine-{next(_engine_seq)}"
@@ -950,15 +1051,33 @@ class InferenceEngine:
     # ------------------------------------------------------------- loop
 
     def _loop_pass(self) -> bool:
-        """One scheduler pass (reap → admit → decode); False when
-        stopped.  Runs on the loop thread, which holds the engine only
-        WEAKLY between passes (_engine_loop) so an engine abandoned
-        without shutdown() is still collectable."""
+        """One turn of the loop: reap → admit → dispatch a pass → read
+        the pass BEFORE it (``_pass_done``); False when stopped.  Runs
+        on the loop thread, which holds the engine only WEAKLY between
+        turns (_engine_loop) so an engine abandoned without shutdown()
+        is still collectable.
+
+        The loop is ONE pass ahead of what it has read: the pass it
+        dispatches is queued behind the one in flight, whose tokens it
+        then reads and emits.  What the next pass needs of the last it
+        knows without its tokens (positions advance by one; a row that
+        reaches ``max_new`` sits out; a stepped row's input token is on
+        the device); what it cannot know first has the pass in flight
+        read (``_drain``): a row that samples, a preemption, a cancelled
+        row, a cross-thread op, a full-width prefill, shutdown, and
+        every pass of an engine that speculates."""
         # engine.pass opens once the park check finds work, under that
         # check's lock, and closes with the pass, the lock long released
         sp = tracing.NOOP
         worked = False
         try:
+            if self._flight is not None and (self._stopped or self._ops):
+                # off the hot path: the pass in flight lands first (not
+                # under the lock: submitters do not wait for the device)
+                try:
+                    self._drain("shutdown" if self._stopped else "op")
+                except Exception as e:
+                    self._fail_all(e)
             with self._cond:
                 # park unless there is work a pass can make progress
                 # on: an active row to decode, a prefill to advance, or
@@ -968,6 +1087,7 @@ class InferenceEngine:
                 # block availability also depends on evictable cached
                 # prefixes)
                 if (not self._stopped and not self._ops
+                        and self._flight is None
                         and not self._active.any()
                         and not self._prefilling
                         and not (self._waiting
@@ -982,7 +1102,6 @@ class InferenceEngine:
                 if self._stopped:
                     return False
                 worked = True
-                self._passes += 1
                 sp = tracing.span("engine.pass").__enter__()
                 if sp:
                     sp.set(active=int(self._active.sum()),
@@ -990,6 +1109,10 @@ class InferenceEngine:
                            waiting=len(self._waiting))
                 self._schedule_locked()
             try:
+                if self._sampling and self._samples():
+                    # a row that samples takes its token on the host,
+                    # from the logits of the pass in flight
+                    self._drain("sampled")
                 # prefill progress is interleaved with decode, ONE
                 # chunk a pass at healthy occupancy, so a long prompt
                 # cannot stall its neighbors' token cadence; the pass's
@@ -997,9 +1120,9 @@ class InferenceEngine:
                 # runs both
                 ride = (self._prefill_chunk_pass() if self._prefilling
                         else None)
-                if self._active.any():
-                    self._paged_decode_iteration(ride)
-                self._pass_done()
+                if not (self._active.any()
+                        and self._paged_decode_iteration(ride)):
+                    self._pass_done()
             except Exception as e:            # step failure: fail the
                 self._fail_all(e)             # in-flight requests, keep serving
             return True
@@ -1008,21 +1131,42 @@ class InferenceEngine:
             if worked:
                 self._pass_ended()
 
-    def _pass_ended(self) -> None:
-        """Where a pass ends, a failed one too: its time goes to its
-        kind's row of the account, beside the tokens it emitted (the
-        counters' growth since the last pass ended) and, once the loop
-        has spent ``ACCOUNT_EVERY_NS`` since the last one, the account
-        so far goes to the ring as an ``engine.account`` span, all of it
-        cumulative: the loop's account and every counter of the engine's
-        table (three of them also under their own keys, where
-        ``chunk_in_step_share.serve`` reads them)."""
-        counts, acct = self._counts, self._acct
+    def _unit_done(self, launched: int, ahead: bool = False,
+                   drained: bool = False) -> None:
+        """A unit of the account ends at the newest stamp: the loop's
+        time since the last one goes to the row of the KIND of pass
+        ``launched`` makes, beside the tokens emitted since (the
+        counters' growth) and whether the pass was launched ``ahead``
+        of an unread one and ``drained`` early.  Where a pass's tokens
+        were read and emitted (``_land``) it is that pass's kind,
+        whatever was dispatched meanwhile: a unit runs from the last
+        emit to this one, which is the gap its tokens waited."""
+        counts = self._counts
         emitted = counts.tokens_greedy_on_device + counts.tokens_sampled
         tokens, self._emitted = emitted - self._emitted, emitted
-        acct.pass_done(_PASS_KIND[self._launched],
-                       tokens - self._first_tokens, tokens=tokens)
-        self._launched = self._first_tokens = 0
+        self._passes += 1
+        self._acct.pass_done(_PASS_KIND[launched],
+                             tokens - self._first_tokens, tokens=tokens,
+                             ahead=int(ahead), drained=int(drained))
+        self._first_tokens = 0
+
+    def _pass_ended(self) -> None:
+        """Where a turn of the loop that found work ends, a failed one
+        too.  A turn that read no pass and left none in flight (its
+        pass owed no token: chunks of prompts that go on, cross-thread
+        ops; or it failed) is a unit of its own kind; a turn that only
+        launched ahead is none, its time is part of the unit that reads
+        its pass.  Once the loop has spent ``ACCOUNT_EVERY_NS`` since
+        the last one, the account so far goes to the ring as an
+        ``engine.account`` span, all of it cumulative: the loop's
+        account and every counter of the engine's table (three of them
+        also under their own keys, where ``chunk_in_step_share.serve``
+        reads them)."""
+        counts, acct = self._counts, self._acct
+        if not self._landed_now and self._flight is None:
+            self._unit_done(self._launched)
+        self._launched = 0
+        self._landed_now = False
         if acct.t_ns - self._account_t1_ns < ACCOUNT_EVERY_NS:
             return
         counters = counts.snapshot(self._mlock)
@@ -1033,7 +1177,7 @@ class InferenceEngine:
             decode_iterations=counters["decode_iterations"],
             chunk_passes=counters["chunk_passes"],
             chunks_in_step=counters["chunks_in_step"], counters=counters,
-            **acct.snapshot())
+            drained_by=dict(self._drained_by), **acct.snapshot())
 
     def _schedule_locked(self) -> None:
         """The pass's scheduling under ``_cond``: cross-thread ops,
@@ -1042,7 +1186,8 @@ class InferenceEngine:
             # only this thread writes the two counters
             counts = self._counts
             admitted0, preempted0 = counts.admissions, counts.preemptions
-            if self._ops:
+            if self._ops and self._flight is None:
+                # (ops queued since this turn's drain wait a turn)
                 self._run_ops_locked()
             # reap cancelled waiters even when the pool is full:
             # zombies must not consume max_waiting backpressure
@@ -1060,6 +1205,15 @@ class InferenceEngine:
                 sp.set(admitted=counts.admissions - admitted0,
                        preempted=counts.preemptions - preempted0)
 
+    def _zero_feed(self):
+        """The token array, zeroed: int32 a row, replicated under a
+        mesh."""
+        feed = jnp.zeros(self.engine_cfg.max_slots, jnp.int32)
+        if self._mesh is None:
+            return feed
+        return jax.device_put(feed, jax.sharding.NamedSharding(
+            self._mesh, jax.sharding.PartitionSpec()))
+
     def _admission_possible(self) -> bool:
         """Cheap park-predicate check; the real budget decision happens
         in the admission pass."""
@@ -1070,6 +1224,7 @@ class InferenceEngine:
 
     def _drain_pending(self) -> None:
         """Terminal cleanup: fail everything still queued or in-flight."""
+        self._flight = None
         with self._cond:
             self._stopped = True
             pending = list(self._slot_req.values()) + self._waiting
@@ -1191,6 +1346,7 @@ class InferenceEngine:
         self._tables[row, :len(blocks)] = blocks
         self._row_blocks[row] = blocks
         self._slot_req[row] = req
+        self._sampling += req.temperature != 0.0
         self._prefilling[row] = hit          # prefill resumes past the hit
         # (a state with a snapshot form goes on from the last adopted
         # block's: matches over such a pool end on a block boundary)
@@ -1218,6 +1374,7 @@ class InferenceEngine:
         ``window``: of the window layers' pool (a preempted row gives
         back its blocks of both)."""
         pool = self.pool.window if window else self.pool
+        req = self._slot_req.get(row)
         while True:
             self._chaos("infer_block_alloc", row=row)
             bid = pool.alloc()
@@ -1228,6 +1385,14 @@ class InferenceEngine:
                     self._counts.kv_blocks_allocated += 1
                 return bid
             if self.trie is not None and self.trie.evict(1):
+                continue
+            if self._flight is not None:
+                # before a row is preempted the pass in flight lands: a
+                # row that finished in it gives its blocks back, and a
+                # victim's tokens are read before it is requeued
+                self._drain("preempt")
+                if self._slot_req.get(row) is not req:
+                    return None           # ``row`` itself finished in it
                 continue
             victim = self._pick_victim()
             if victim is None:
@@ -1253,12 +1418,8 @@ class InferenceEngine:
         emitted tokens folded into the prompt — the stream continues
         exactly where it left off."""
         req = self._slot_req[row]
-        valid = (int(self._positions[row]) if self._active[row]
-                 else self._prefilling.get(row, 0))
-        seq = np.concatenate(
-            [req.prompt,
-             np.asarray(req.tokens[req._consumed:], np.int32)])
-        self._insert_prefix(row, seq[:valid])
+        seq = self._sequence(req)
+        self._insert_prefix(row, seq[:self._cached(row, req)])
         self._release_row(row)
         req.prompt = seq
         req._consumed = len(req.tokens)
@@ -1273,6 +1434,24 @@ class InferenceEngine:
         if stopped:       # raced with shutdown: never leave it hanging
             req._finish(EngineStoppedError("engine shut down"))
 
+    @staticmethod
+    def _sequence(req: GenerationRequest) -> np.ndarray:
+        """The request's tokens so far: its prompt (what it was
+        re-admitted with) and what it has emitted since."""
+        return np.concatenate(
+            [req.prompt, np.asarray(req.tokens[req._consumed:], np.int32)])
+
+    def _cached(self, row: int, req: GenerationRequest) -> int:
+        """The tokens of ``_sequence(req)`` whose K/V the row's blocks
+        hold for certain: a prefilling row's position; else every token
+        but the last EMITTED one (where the loop is a pass ahead the
+        row's position is too, and what that pass writes is nobody's
+        yet)."""
+        if row in self._prefilling:
+            return self._prefilling[row]
+        return max(int(req.prompt.size) + len(req.tokens) - req._consumed
+                   - 1, 0)
+
     def _insert_prefix(self, row: int, seq: np.ndarray) -> None:
         if self.trie is None or len(seq) == 0:
             return
@@ -1281,9 +1460,18 @@ class InferenceEngine:
     def _release_row(self, row: int) -> None:
         """Drop the row's references (blocks survive only if the prefix
         index kept them) and return the row to the free list."""
-        self._slot_req.pop(row, None)
+        req = self._slot_req.pop(row, None)
+        if req is not None:
+            self._sampling -= req.temperature != 0.0
         self._active[row] = False
+        self._owed[row] = 0
         self._prefilling.pop(row, None)
+        # a first token still owed to the row is nobody's now (a row
+        # preempted between its prompt's last chunk and the token's
+        # read prefills again, and gets it then)
+        for p in (self._pass, self._flight):
+            if p is not None and p.first:
+                p.first[:] = [owed for owed in p.first if owed[0] != row]
         self._seam.row_released(self, row)
         for bid in self._row_blocks.pop(row, []):
             self.pool.decref(bid)
@@ -1450,6 +1638,9 @@ class InferenceEngine:
             # (one cheap window beats an S-wide forward).  (pos == 0
             # also means no adopted blocks — the table is exclusive.)
             sp.set(row=row, tokens=n, full_width=True)
+            # its first token is sampled on its logits and waited for:
+            # the pass in flight lands first
+            self._drain("prefill")
             req.full_width_prefill = True
             self._counts.prefill_tokens += n
             padded = np.zeros((1, self.max_seq), np.int32)
@@ -1512,44 +1703,47 @@ class InferenceEngine:
         # (the copy-on-write above may have preempted the last active row)
         if may_ride and self._active.any():
             return row, n_q, packed
+        self._chunks_ended()
         with self._acct.phase("dispatch"):
             self._launched |= _CHUNK
             logits = self._seam.run(self, self._chunk, packed)
-        self._chunk_launched(row, n_q, logits, n_q - 1)
+        self._pass.chunks = (self._load[-1],
+                             self._acct.launched)
+        self._chunk_launched(row, n_q, logits, n_q - 1, self._load[-1])
         return None
 
-    def _chunk_launched(self, row: int, n_q: int, logits, idx,
+    def _chunk_launched(self, row: int, n_q: int, logits, idx, owed,
                         in_step: bool = False) -> None:
         """A chunk of ``n_q`` tokens of ``row``'s prompt is on its way,
         by the chunk program or ``in_step``; ``logits[idx]`` are its
-        last real position's."""
+        last real position's, ``owed`` its program's int32 vector."""
         req = self._slot_req[row]
         new_pos = self._prefilling[row] + n_q
         if new_pos < int(req.prompt.size):
             self._prefilling[row] = new_pos
             return
-        self._finish_prefill(row, req, logits, idx, self._load[-1], in_step)
+        self._finish_prefill(row, req, logits, idx, owed, in_step)
 
     def _finish_prefill(self, row: int, req: GenerationRequest,
                         logits, idx, owed=None,
                         in_step: bool = False) -> None:
-        """Prompt fully in cache: its first token is the last prompt
-        position's (``logits[idx]``), and the row then turns active (or
-        evicts immediately on EOS / max_new == 1).
+        """Prompt fully in cache (its last chunk dispatched): its first
+        token is the last prompt position's (``logits[idx]``).
 
         A greedy first token is its program's own argmax, the last
         entry of ``owed`` (the program's int32 vector, still on the
-        device): no slice and no sampling dispatched.  And not waited
-        for here while other rows decode: where the chunk program ran,
-        the pass's decode step is dispatched behind it first (the
-        device runs the two back to back); the token is emitted as soon
-        as its program has ended (``_emit_first``, from
-        ``_fetch_step``), and the row joins the decode batch at the end
-        of the pass (``_pass_done``).  So does the sampled first token
-        of a chunk that ran ``in_step``: sampled here on the step's
-        logits, it is owed by that dispatch.  Any other sampled
-        request, and a full-width prefill (no ``owed``), take one
-        sampling dispatch on the logits and wait for it."""
+        device), and the program has also written it to the row's entry
+        of the token array: no slice and no sampling dispatched, and
+        nothing waited for here.  The token is owed by the pass
+        (``_Pass.first``), read and emitted where the pass lands
+        (``_land``); the row joins the decode batch at the end of this
+        pass without it (``_pass_done``), its input token on the device.
+        So does the sampled first token of a chunk that ran
+        ``in_step``: sampled here on the step's logits, it is owed by
+        that dispatch (the host supplies it to the row's first step).
+        Any other sampled request, and a full-width prefill (no
+        ``owed``), take one sampling dispatch on the logits and wait
+        for it, the pass in flight read first."""
         del self._prefilling[row]
         if self.trie is not None:
             # publish the prompt's full blocks NOW (not at finish):
@@ -1571,45 +1765,29 @@ class InferenceEngine:
                     logits[idx], temperature=req.temperature,
                     rng=req._next_rng())[None]
         elif owed is None or req.temperature != 0.0:
+            self._drain("sampled")
             tok = self._first_token(req, logits[idx])
             self._emit_to(req, tok)
             self._start_decoding(row, req, tok)
             return
-        self._first_pending.append([row, req, owed, None])
-        if not self._active.any():          # no decode to run behind
-            self._pass_done()
-
-    def _emit_first(self, in_step_fetch: bool = False) -> tuple:
-        """Read and emit the first tokens this pass's chunks owe.
-        Inside the decode step's fetch the reads are part of that wait
-        -> (tokens read from other programs than the step, their
-        bytes); with no step behind the chunk (``_pass_done``) each is
-        a wait of its own."""
-        n = n_bytes = 0
-        counts = self._counts
-        for pend in self._first_pending:
-            row, req, owed, tok = pend
-            # a row preempted since (the block hunt of this pass's
-            # decode) re-prefills and gets its first token then
-            if tok is not None or self._slot_req.get(row) is not req:
-                continue
-            if in_step_fetch:
+        elif self._spec is not None and not self._active.any():
+            # a speculating engine drafts from the tokens on the host:
+            # the row's own iteration follows in this pass, so its first
+            # token is read here
+            with self._acct.phase("wait") as fetch:
                 tok = int(jax.device_get(owed)[-1])
-                # the step's own vector (its chunk ended the prompt)
-                # comes once, and is counted with the loads
-                if owed is not self._load[-1]:
-                    n, n_bytes = n + 1, n_bytes + owed.nbytes
-            else:
-                with self._acct.phase("wait") as fetch:
-                    tok = int(jax.device_get(owed)[-1])
-                    self._fetched(fetch, owed.nbytes)
-            pend[3] = tok
+                self._fetched(fetch, owed.nbytes)
             self._emit_to(req, tok)
-            if req.temperature == 0.0:
-                counts.tokens_greedy_on_device += 1
-            else:
-                counts.tokens_sampled += 1
-        return n, n_bytes
+            self._counts.tokens_greedy_on_device += 1
+            self._start_decoding(row, req, tok)
+            return
+        self._owed[row] += 1
+        self._pass.first.append(
+            [row, req, owed, self._acct.launched])
+        if self._active.any():
+            self._pass.joining.append((row, req))
+        else:                               # no decode to run behind:
+            self._turn_active(row, req)     # its own follows the chunk
 
     def _emit_to(self, req: GenerationRequest, tok: int) -> None:
         """A prompt's first token goes out: the request's first, unless
@@ -1619,45 +1797,184 @@ class InferenceEngine:
             self._first_tokens += 1
         req._emit(tok)
 
+    def _turn_active(self, row: int, req: GenerationRequest) -> None:
+        """``row``'s prompt is in the cache and its first token on its
+        way: the row joins the decode batch, whose next step finds the
+        token in the token array (or is handed it by the host, which
+        will have read a sampled one by then: a row that samples is
+        never stepped ahead of what was read).  A request of ONE new
+        token stays out: the token that is owed evicts it."""
+        self._positions[row] = int(req.prompt.size)
+        self._tokens[row] = FEED
+        self._active[row] = (len(req.tokens) + int(self._owed[row])
+                             < req.max_new)
+
     def _pass_done(self) -> None:
-        """The pass's decode step has been sampled (or there was none):
-        rows whose prompt ended in this pass start decoding."""
-        if not self._first_pending:
+        """The pass's programs are all dispatched.  Rows whose prompt
+        ended in it turn active; the pass BEFORE it, still unread, is
+        read now (``_land``): the device has this one queued behind it.
+        This pass then stays in flight for the next to be dispatched
+        behind — unless one of its rows samples or the engine
+        speculates: then the next pass needs its tokens, and it is read
+        at once (nothing in flight is the degenerate case of the same
+        algorithm)."""
+        this, before = self._pass, self._flight
+        for row, req in this.joining:
+            # (a row preempted since, by this pass's block hunt,
+            # prefills again)
+            if self._slot_req.get(row) is req:
+                self._turn_active(row, req)
+        self._pass, self._flight = _Pass(), None
+        if before is not None:
+            if self._launched:
+                this.ahead = True
+                self._counts.passes_launched_ahead += 1
+            self._land(before)
+        if not this.owes:
             return
-        self._emit_first()
-        pending, self._first_pending = self._first_pending, []
-        for row, req, _, tok in pending:
-            if tok is not None and self._slot_req.get(row) is req:
-                self._start_decoding(row, req, tok)
+        this.launched, self._launched = self._launched, 0
+        if self._spec is not None or isinstance(this.logits, jax.Array):
+            self._land(this)
+        else:
+            self._flight = this
+
+    def _drain(self, reason: str) -> None:
+        """Read the pass in flight NOW, before what the loop does next
+        (no-op with none in flight): ``reason`` says what could not go
+        ahead of it."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return
+        flight.drained = True
+        self._counts.passes_drained += 1
+        self._drained_by[reason] = self._drained_by.get(reason, 0) + 1
+        self._land(flight)
+
+    def _chunks_ended(self) -> None:
+        """Before a chunk PROGRAM is launched: those of the pass in
+        flight have ended.  A chunk program returns its whole window's
+        logits (``[chunk, vocab]`` float32: 411 MB at 1,024 x 100,352),
+        which the runtime holds from the launch to the program's end
+        whoever refers to them; a pass launched ahead would otherwise
+        hold its chunks' beside the unread pass's, where the synchronous
+        loop held one pass's.  The wait costs the device nothing: the
+        unread pass's decode step is queued behind its chunks, and this
+        chunk is dispatched while it runs."""
+        flight = self._flight
+        if flight is None or flight.chunks is None:
+            return
+        (vector, seq), flight.chunks = flight.chunks, None
+        with self._acct.phase("wait") as fetch:
+            jax.block_until_ready(vector)
+            self._fetched(fetch, 0)
+            self._acct.landed(seq)
 
     def _fetched(self, fetch, n_bytes: int, **attributes) -> None:
         """An ``engine.fetch`` span brought ``n_bytes`` to the host."""
         fetch.set(bytes=n_bytes, **attributes)
         self._counts.fetch_bytes += n_bytes
 
-    def _fetch_step(self, fetch, rode: bool) -> None:
-        """The rows' greedy tokens of the decode step just dispatched
-        — and, of a model that reports one, the expert load of this
-        pass and of the chunks before it — in ONE small transfer; the
-        logits stay on the device (a sampled row indexes them there).
-        First tokens that this pass's chunks owe go out first: their
-        programs ended a decode step ago (the token of a chunk that ran
-        inside the step is the last of the step's own integers), and
-        their reads belong to this wait (``fetch``, its span:
-        ``first_tokens``, and their bytes among its ``bytes``).
-        ``rode``: a chunk ran inside the step (the seam's ``count``
-        then finds the chunk's load behind the step's)."""
-        n_first, n_bytes = self._emit_first(in_step_fetch=True)
-        loads = jax.device_get(self._load)
-        self._load = []
-        self._greedy = loads[-1][self._seam.N_LOAD:]   # the step's own
-        self._seam.count(self, loads, rode)
-        self._fetched(fetch, n_bytes + sum(load.nbytes for load in loads),
-                      first_tokens=n_first)
+    def _land(self, flight: _Pass) -> None:
+        """Read and emit what ``flight`` owes, and end its unit of the
+        account.  ONE wait: the first tokens its chunks owe, each as
+        soon as its program has ended (a chunk program's ends a decode
+        step before the step's own vector; the token of a chunk that
+        ran inside the step is the last of the step's integers), each
+        emitted as it is read; then the rows' greedy tokens of its
+        decode step — and, of a model that reports one, the expert load
+        of the pass and of the chunks before it — in ONE small transfer.
+        The logits stay on the device (a sampled row indexes them
+        there).  Then the row loop: a token to its request's mailbox,
+        and what the token causes (EOS or ``max_new``: the eviction).
+
+        A stepped row whose request has left the row since (it emitted
+        EOS a pass ago, when this pass was already dispatched) emits
+        nothing: that step's token is nobody's, and what it wrote lies
+        in blocks and a state row that every later owner's programs run
+        behind."""
+        counts, acct = self._counts, self._acct
+        firsts, upto = [], 0
+        rode = bool(flight.launched & _STEP_CHUNK)  # a chunk in the step
+        with acct.phase("wait") as fetch:
+            n_first = n_bytes = 0
+            for row, req, owed, seq in flight.first:
+                tok = int(jax.device_get(owed)[-1])
+                upto = max(upto, seq)
+                # the step's own vector (its chunk ended the prompt)
+                # comes once, and is counted with the loads
+                if not (flight.loads and owed is flight.loads[-1]):
+                    n_first, n_bytes = n_first + 1, n_bytes + owed.nbytes
+                self._emit_to(req, tok)
+                if req.temperature == 0.0:
+                    counts.tokens_greedy_on_device += 1
+                else:
+                    counts.tokens_sampled += 1
+                firsts.append((row, req, tok))
+            if flight.stepped is not None:
+                loads = jax.device_get(flight.loads)
+                upto = max(upto, flight.step_seq)
+                self._greedy = loads[-1][self._seam.N_LOAD:]  # the step's
+                self._seam.count(self, loads, rode)
+                n_bytes += sum(load.nbytes for load in loads)
+            self._fetched(fetch, n_bytes, first_tokens=n_first,
+                          stepped=flight.stepped is not None, rode=rode)
+            # the programs this wait saw the end of; the newer pass's
+            # are still queued
+            acct.landed(upto)
+        stepped = 0
+        with acct.phase("emit") as sample:
+            for row, req, tok in firsts:
+                self._token_out(row, req, tok)
+            if flight.stepped is not None:
+                logits = flight.logits
+                greedy = self._seam.greedy(self, logits)
+                for row, req in flight.stepped:
+                    if self._slot_req.get(row) is not req:
+                        continue          # left the row a pass ago
+                    if req.temperature == 0.0:
+                        tok = int(greedy[row])
+                        counts.tokens_greedy_on_device += 1
+                    else:
+                        # its own rng, on its logits where they lie
+                        tok = int(gpt.sample_token(
+                            logits[row], temperature=req.temperature,
+                            rng=req._next_rng()))
+                        counts.tokens_sampled += 1
+                    req._emit(tok)
+                    stepped += 1
+                    self._token_out(row, req, tok)
+                sample.set(rows=stepped)
+                del logits
+                flight.logits = None
+                # inside the phase the loop lets go of the interpreter
+                # lock once, and every stream it has just woken takes
+                # its turn (0.2 - 0.75 ms a pass on the chip: ``PERF.md``
+                # section 5).  Releasing the step's logits did that
+                # until a greedy pass stopped holding them; the device
+                # has the next pass queued meanwhile
+                time.sleep(0)
+        with self._mlock:
+            counts.row_steps += stepped
+            counts.row_tokens += stepped
+        self._landed_now = True
+        self._unit_done(flight.launched, flight.ahead, flight.drained)
+
+    def _token_out(self, row: int, req: GenerationRequest,
+                   tok: int) -> None:
+        """``tok`` of ``row`` has been emitted: it is the row's next
+        input as the host knows it, unless a newer one is already on
+        its way (the device fed it); the row is evicted if the token
+        ended the request."""
+        self._owed[row] -= 1
+        if not self._owed[row]:
+            self._tokens[row] = tok
+        if self._request_finished(req, tok):
+            self._paged_evict(row)
 
     def _start_decoding(self, row: int, req: GenerationRequest,
                         tok: int) -> None:
-        """``tok``, the request's first token, has been emitted: the row
+        """``tok``, the request's first token, has been read and
+        emitted (a sampled one's, a full-width prefill's): the row
         turns active, or is evicted if that token ended the request."""
         if self._request_finished(req, tok):
             self._paged_evict(row)
@@ -1958,19 +2275,31 @@ class InferenceEngine:
                     continue              # preempted by an earlier row's
                 #                           block hunt this very pass
                 if req.cancelled:         # abandoned: free for live work
-                    self._paged_evict(row, cache_prefix=False)
+                    # (its tokens in flight are read first, as they
+                    # would have been by now)
+                    self._drain("cancel")
+                    if self._slot_req.get(row) is req:
+                        self._paged_evict(row, cache_prefix=False)
                     continue
                 self._grow_row(row)       # False = row preempted; skip
             sp.set(preempted=self._counts.preemptions - preempted0)
 
-    def _paged_decode_iteration(self, ride=None) -> None:
-        """One decode step over the active rows.  ``ride``: the chunk
-        this pass prepared for the step to run, (row, tokens, packed);
-        the block hunt was made before it was packed."""
+    def _samples(self) -> bool:
+        """Does a row the next step steps sample its token?"""
+        return any(self._active[row] and req.temperature != 0.0
+                   for row, req in self._slot_req.items())
+
+    def _paged_decode_iteration(self, ride=None) -> bool:
+        """One decode step over the active rows, dispatched; then the
+        pass is done: the pass before it is read (``_pass_done``,
+        inside this iteration's span).  ``ride``: the chunk this pass
+        prepared for the step to run, (row, tokens, packed); the block
+        hunt was made before it was packed.  -> whether the pass was
+        ended here."""
         if ride is None:
             self._grow_rows()
         if not self._active.any():
-            return
+            return False
         # draft-then-verify when configured; False = no row produced a
         # draft this pass (nothing to verify) — the plain one-token
         # step below is the fallback, so an all-sampled or draft-dry
@@ -1987,13 +2316,13 @@ class InferenceEngine:
                 if not self._prefilling:
                     break
                 self._prefill_one_chunk()
-            return
+            return False
         with tracing.span("engine.decode", speculative=False) as sp:
             if sp:
                 sp.set(active=int(self._active.sum()),
                        state_rows=self.pool.state_rows_in_use,
                        chunk_tokens=ride[1] if ride else 0)
-            program = self._step
+            this, program = self._pass, self._step
             with self._acct.phase("pack") as up:
                 packed = pack_step(self._tables_all, self._tokens,
                                    self._positions, self._active)
@@ -2004,90 +2333,77 @@ class InferenceEngine:
             with self._acct.phase("dispatch"):
                 self._launched |= _STEP_CHUNK if ride else _STEP
                 logits = self._seam.run(self, program, packed)
+            # the step's fetch brings every vector no pass has taken yet
+            this.loads, self._load = self._load, []
+            this.step_seq = self._acct.launched
+            # a pass none of whose rows samples lets go of its logits
+            # here: the next pass's are made while this one is unread
+            this.logits = (logits if self._sampling and self._samples()
+                           else jax.ShapeDtypeStruct(logits.shape,
+                                                     logits.dtype))
             if ride:
                 self._counts.chunks_in_step += 1
                 self._chunk_launched(*ride[:2], logits,
-                                     self.engine_cfg.max_slots, in_step=True)
+                                     self.engine_cfg.max_slots,
+                                     this.loads[-1], in_step=True)
+            del logits
             if self._mesh is not None:
                 # every shard just committed its slice of the donated
                 # scatter — the point where a multi-host straggler or
                 # mid-commit death would bite, so it is chaos-testable
                 self._chaos("infer_shard_commit",
                             tp_shards=self.pool.heads_shards)
-            with self._acct.phase("wait") as fetch:
-                self._fetch_step(fetch, ride is not None)
-            with self._acct.phase("emit") as sample:
-                counts = self._counts
-                with self._mlock:
-                    counts.decode_iterations += 1
-                    counts.occupancy_sum += (float(self._active.sum())
-                                             / self.engine_cfg.max_slots)
-                counts.kv_blocks_attended += int(
-                    (self._positions[self._active]
-                     // self.engine_cfg.kv_block_size + 1).sum())
-                counts.kv_blocks_tabled += self._tables.size
-                if self._window:
-                    # the blocks that hold a key inside a live row's
-                    # window; what the rows hold of the window layers'
-                    # pool, beside what ONE table a row would hold
-                    kv = self._positions[self._active] + 1
-                    bs = self.engine_cfg.kv_block_size
-                    counts.window_blocks_attended += int(
-                        (-(-kv // bs)
-                         - np.maximum(kv - self._window, 0) // bs).sum())
-                    counts.window_blocks_resident_sum += \
-                        self.pool.window.n_used
-                    counts.window_blocks_one_table_sum += self.pool.n_used
-                if self._linear:
-                    counts.linear_state_rows_advanced += int(
-                        self._active.sum())
-                if self._snapshots:
-                    counts.state_snapshots_written += int((
-                        (self._positions[self._active] + 1)
-                        % self.engine_cfg.kv_block_size == 0).sum())
-                greedy = self._seam.greedy(self, logits)
-                stepped = 0
-                for row in list(self._slot_req):
-                    if not self._active[row]:   # prefilling rows ride along
-                        continue
-                    req = self._slot_req[row]
-                    if req.temperature == 0.0:
-                        tok = int(greedy[row])
-                        counts.tokens_greedy_on_device += 1
-                    else:
-                        # its own rng, on its logits where they lie
-                        tok = int(gpt.sample_token(
-                            logits[row], temperature=req.temperature,
-                            rng=req._next_rng()))
-                        counts.tokens_sampled += 1
-                    req._emit(tok)
-                    stepped += 1
-                    self._positions[row] += 1
-                    self._tokens[row] = tok
-                    if self._request_finished(req, tok):
-                        self._paged_evict(row)
-                sample.set(rows=stepped)
-                # released inside the phase: a device buffer's release
-                # is where the loop first lets go of the interpreter
-                # lock after the row loop, and every stream the loop has
-                # just woken then takes its turn (with the release 0.2 -
-                # 0.75 ms a pass on the chip: ``PERF.md`` section 5)
-                del logits
+            with self._acct.phase("emit"):
+                self._stepped(this)
+            self._pass_done()
+        return True
+
+    def _stepped(self, this: _Pass) -> None:
+        """A decode step over the active rows is on its way (the row
+        loop's other half, in the same phase ``emit``): the pass keeps
+        who was stepped, what the step reads and writes is counted from
+        where the rows stand, and the rows move on without its tokens —
+        a position further, the next input token on the device, and out
+        of the next step if the token on its way is the request's
+        last."""
+        this.stepped = stepped = [
+            (row, req) for row, req in self._slot_req.items()
+            if self._active[row]]
+        counts = self._counts
+        at = self._positions[self._active]
+        bs = self.engine_cfg.kv_block_size
         with self._mlock:
-            counts.row_steps += stepped
-            counts.row_tokens += stepped
+            counts.decode_iterations += 1
+            counts.occupancy_sum += len(at) / self.engine_cfg.max_slots
+        counts.kv_blocks_attended += int((at // bs + 1).sum())
+        counts.kv_blocks_tabled += self._tables.size
+        if self._window:
+            # the blocks that hold a key inside a live row's
+            # window; what the rows hold of the window layers'
+            # pool, beside what ONE table a row would hold
+            kv = at + 1
+            counts.window_blocks_attended += int(
+                (-(-kv // bs) - np.maximum(kv - self._window, 0) // bs).sum())
+            counts.window_blocks_resident_sum += self.pool.window.n_used
+            counts.window_blocks_one_table_sum += self.pool.n_used
+        if self._linear:
+            counts.linear_state_rows_advanced += len(at)
+        if self._snapshots:
+            counts.state_snapshots_written += int(((at + 1) % bs == 0).sum())
+        for row, req in stepped:
+            self._positions[row] += 1
+            self._tokens[row] = FEED
+            self._owed[row] += 1
+            if len(req.tokens) + self._owed[row] >= req.max_new:
+                self._active[row] = False
 
     def _paged_evict(self, row: int, cache_prefix: bool = True) -> None:
         """Natural eviction (EOS / max-tokens / cancel): donate the
         clean KV chain to the prefix index, then release the row."""
         req = self._slot_req[row]
         if cache_prefix and not req.cancelled:
-            valid = (int(self._positions[row]) if self._active[row]
-                     else self._prefilling.get(row, 0))
-            seq = np.concatenate(
-                [req.prompt,
-                 np.asarray(req.tokens[req._consumed:], np.int32)])
-            self._insert_prefix(row, seq[:valid])
+            self._insert_prefix(
+                row, self._sequence(req)[:self._cached(row, req)])
         self._release_row(row)
         req._finish()
         self._note_done(req)
@@ -2105,6 +2421,18 @@ class InferenceEngine:
         self._fr_note(req)
 
     def _fail_all(self, e: BaseException) -> None:
+        # what was launched BEFORE the program that failed (the pass in
+        # flight; this pass's chunks, where its step failed) and is
+        # owed is the streams', if its fetch still brings it
+        this, self._pass = self._pass, _Pass()
+        flight, self._flight = self._flight, None
+        this.launched = self._launched
+        for owing in (flight, this):
+            if owing is not None and owing.owes:
+                try:
+                    self._land(owing)
+                except Exception:
+                    pass
         # a failed chunk/step may have invalidated the DONATED pool
         # buffers; reallocate the pool so the engine keeps serving, drop
         # every reference, and — critically — clear the prefix index:
@@ -2113,9 +2441,11 @@ class InferenceEngine:
         failed = [self._slot_req.pop(row)
                   for row in list(self._slot_req)]
         self._active[:] = False
+        self._owed[:] = 0
+        self._sampling = 0
         self._prefilling.clear()
-        self._first_pending.clear()
         self._load.clear()
+        self._feed = self._zero_feed()  # donated, as the pools are
         self._acct.in_flight = 0        # what was launched has failed
         self._row_blocks.clear()
         self._row_wblocks.clear()
@@ -2364,6 +2694,7 @@ class InferenceEngine:
             pool_generation=pool["generation"],
             loop_account={**self._acct.snapshot(),
                           "passes": self._passes,
+                          "drained_by": dict(self._drained_by),
                           "t_made_ns": self._acct.t_made_ns,
                           "t_ns": self._acct.t_ns},
             speculate=self._spec,

@@ -31,10 +31,13 @@ supplied from the pools the cache manager owns
     carries the state a later request needs to go on from it.
 
 A program takes ``pools``, the tuple of block-pool arrays
-(``BlockPool.pools``: K and V, or the one latent pool), and ``state``,
+(``BlockPool.pools``: K and V, or the one latent pool), ``state``,
 the tuple of state arrays (``StatePool.arrays``: ``(conv, ssm)``,
 ``(conv, snap)`` of a state with a snapshot form, or ``()`` for a model
-without recurrent layers), all donated and updated in place.  Besides
+without recurrent layers), and ``feed``, the token each decode row
+feeds next (decode.py's token array: read where the packed array says
+``decode.FEED``, written with the program's greedy tokens), all donated
+and updated in place.  Besides
 the logits each program returns ``load`` int32 — held expert
 assignments, all assignments, the busiest held expert's assignments and
 the held experts touched, summed over the experts sublayers, of the
@@ -88,7 +91,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.inference.cache import PoolLayout, snapshot_geometry
-from ray_tpu.inference.decode import (_cached, _step_indices, latent_attend,
+from ray_tpu.inference.decode import (_cached, _step_indices, feed_chunk,
+                                      feed_step, latent_attend,
                                       paged_attend, unpack_chunk,
                                       unpack_step)
 from ray_tpu.models import hybrid
@@ -99,10 +103,14 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
                                n_table: int):
     """jitted one-token step over the whole row batch.
 
-    (params, pools, state, packed [b, T + 3] int32
+    (params, pools, state, feed [b] int32, packed [b, T + 3] int32
      (``pack_step``: tables | tokens | positions | active))
         -> (logits [b, vocab] f32, load + greedy [4 + b] int32,
-            pools, state)
+            pools, state, feed)
+
+    ``feed``: the token each decode row feeds next, donated like the
+    pools, read where a row's token column says ``decode.FEED`` and
+    written with every stepped row's greedy token (decode.py's step).
 
     A model with window layers has two tables a row, side by side in
     ``packed`` [b, 2 T + 3]: the full layers' and the window layers'
@@ -115,9 +123,9 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
     bs, T = int(block_size), int(n_table) * (2 if cfg.n_window else 1)
 
     def build():
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def step(params, pools, state, packed):
-            tables, tokens, positions, active = unpack_step(packed, T)
+        @partial(jax.jit, donate_argnums=(1, 2, 3))
+        def step(params, pools, state, feed, packed):
+            tables, tokens, positions, active = unpack_step(packed, T, feed)
             _, off, kv_len = _step_indices(tables, positions, active, bs)
             attend_for, window_for, pools_out = _two_groups(
                 cfg, pools, tables,
@@ -159,7 +167,7 @@ def make_recurrent_decode_step(cfg: HybridConfig, *, block_size: int,
                 state = (jnp.stack(held["conv"]), held["ssm"]) if state \
                     else ()
             return (logits, jnp.concatenate([load, greedy]), pools_out(),
-                    state)
+                    state, feed_step(feed, active, greedy))
 
         return step
 
@@ -335,10 +343,12 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
                             block_size: int, n_table: int):
     """jitted fixed-width prefill chunk of ONE row.
 
-    (params, pools, state, packed [T + C + 3] int32
+    (params, pools, state, feed [b] int32, packed [T + C + 3] int32
      (``pack_chunk``: table | tokens | start, row, n_valid))
         -> (logits [C, vocab] f32, load + greedy [5] int32,
-            pools, state)
+            pools, state, feed)
+
+    (``feed[row]`` takes the greedy token, as decode.py's chunk's.)
 
     (Two tables, [2 T + C + 3], of a model with window layers: as the
     decode step.)
@@ -357,8 +367,8 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
     T = int(n_table) * (2 if cfg.n_window else 1)
 
     def build():
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def chunk_fn(params, pools, state, packed):
+        @partial(jax.jit, donate_argnums=(1, 2, 3))
+        def chunk_fn(params, pools, state, feed, packed):
             table, tokens, start, row, n_valid = unpack_chunk(packed, T, C)
             pos, _, off = _chunk_window(table[:int(n_table)], start, C, bs)
             attend_for, window_for, pools_out = _two_groups(
@@ -392,7 +402,8 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
                                 ).astype(jnp.int32)
             return (logits, jnp.append(load, greedy), pools_out(),
                     keep.done(row=row, marked=marked) if keep else
-                    tuple(held[k] for k in ("conv", "ssm")[:len(state)]))
+                    tuple(held[k] for k in ("conv", "ssm")[:len(state)]),
+                    feed_chunk(feed, row, n_valid, greedy))
 
         return chunk_fn
 
@@ -416,12 +427,13 @@ def make_recurrent_step_chunk(cfg: HybridConfig, *, chunk: int,
     back, so that each layer's weights stream from HBM once a pass, not
     twice (``decode.make_paged_step_chunk``, for this family).
 
-    (params, pools, state, packed [b * (T + 3) + T + C + 3] int32
+    (params, pools, state, feed [b] int32,
+     packed [b * (T + 3) + T + C + 3] int32
      (``pack_step_chunk``: a ``pack_step`` array, flat, then a
      ``pack_chunk`` array))
         -> (logits [b + 1, vocab] f32,
             the step's load + greedy [4 + b], then the chunk's [5] int32,
-            pools, state)
+            pools, state, feed)
 
     The ``b`` rows' tokens and the chunk's ``C`` are ONE window ``[1, b
     + C]`` of the layer function in its two-part form (``hybrid.block``
@@ -447,11 +459,11 @@ def make_recurrent_step_chunk(cfg: HybridConfig, *, chunk: int,
     bs, C, T = int(block_size), int(chunk), int(n_table)
 
     def build():
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def step_chunk(params, pools, state, packed):
+        @partial(jax.jit, donate_argnums=(1, 2, 3))
+        def step_chunk(params, pools, state, feed, packed):
             b = (packed.shape[0] - (T + C + 3)) // (T + 3)
             tables, tokens, positions, active = unpack_step(
-                packed[:b * (T + 3)].reshape(b, T + 3), T)
+                packed[:b * (T + 3)].reshape(b, T + 3), T, feed)
             table, chunk_tokens, start, row, n_valid = unpack_chunk(
                 packed[b * (T + 3):], T, C)
             lay = PoolLayout.of(cfg, pools[0])
@@ -499,7 +511,9 @@ def make_recurrent_step_chunk(cfg: HybridConfig, *, chunk: int,
             return (logits,
                     jnp.concatenate([load[0], greedy[:b], load[1],
                                      greedy[b:]]),
-                    kv["pools"], state)
+                    kv["pools"], state,
+                    feed_chunk(feed_step(feed, active, greedy[:b]), row,
+                               n_valid, greedy[b]))
 
         return step_chunk
 

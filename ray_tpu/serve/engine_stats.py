@@ -321,6 +321,17 @@ ROWS: tuple[Row, ...] = (
            "ray_tpu_inference_weight_bytes_cast_per_pass",
            "Bytes of weights a program casts to its compute dtype every "
            "pass (0 = each is stored in it)"),
+    # ---- the loop one pass ahead of what it has read: over the loop
+    # account's ``passes``, the first is the mechanism's hit share; the
+    # account's ``drained_by`` says why a pass in flight was landed early
+    _counter("passes_launched_ahead",
+             "ray_tpu_inference_passes_launched_ahead_total",
+             "Passes dispatched while the pass before them was still "
+             "unread (queued behind it on the device)"),
+    _counter("passes_drained", "ray_tpu_inference_passes_drained_total",
+             "Passes in flight that were read before the next could be "
+             "launched (a sampled row, a preemption, a cancelled row, a "
+             "cross-thread op, a full-width prefill, shutdown)"),
 )
 
 ROW = {row.key: row for row in ROWS}

@@ -401,9 +401,15 @@ class Account:
 
     Starved time: ``in_flight`` counts the programs the thread has
     launched and not yet seen the end of: one more where a phase named
-    in ``launch`` ends, none where one named in ``land`` ends.  Time
-    that passes while it is 0 is ALSO added to the phase's
-    ``starved_ns``: the device had nothing queued then.
+    in ``launch`` ends (``launched`` counts them all); where one named
+    in ``land`` ends, those launched after the newest one the thread
+    said that wait saw the end of (``landed(upto)``, inside the phase,
+    ``upto`` being ``launched`` as it stood behind that program's
+    launch: a thread that launches ahead of what it reads still has the
+    newer programs queued), and none where it said nothing (a wait that
+    everything launched ended before).  Time that passes while it is 0
+    is ALSO added to the phase's ``starved_ns``: the device had nothing
+    queued then.
 
     ``profiled`` is set where a phase starts inside a ``jax.profiler``
     session (``interval_profiled`` reads and resets it): such time is
@@ -430,7 +436,7 @@ class Account:
 
     __slots__ = ("phases", "phase", "unaccounted", "in_flight", "profiled",
                  "t_made_ns", "t_ns", "_open", "by_kind", "gaps", "_waits",
-                 "_unit")
+                 "_unit", "launched", "_landed")
 
     def __init__(self, phases: dict, launch=(), land=(), waits=()):
         # one reusable entry a phase: it owns the phase's counters, and
@@ -441,7 +447,10 @@ class Account:
             for name, span_name in phases.items()}
         self.phase = self.phases.__getitem__
         self.unaccounted = _Phase(self, UNACCOUNTED, None, 0)
-        self.in_flight = 0
+        self.in_flight = self.launched = 0
+        # the newest launch the ``land`` phase in progress saw the end
+        # of, as ``launched`` counted it (None: of everything)
+        self._landed = None
         self.profiled = False
         # the open phases, innermost last, each followed by the span it
         # yielded
@@ -453,6 +462,12 @@ class Account:
         # where the unit in progress starts, and the ``waits`` phases'
         # time so far, there
         self._unit = (self.t_made_ns, 0)
+
+    def landed(self, upto: int) -> None:
+        """Inside a ``land`` phase: the wait saw the end of the first
+        ``upto`` programs launched (the newest such claim of the phase
+        counts); what was launched after them stays in flight."""
+        self._landed = max(self._landed or 0, upto)
 
     def pass_done(self, kind: str, weight: int = 0, **counted: int) -> None:
         """A unit of ``kind`` ends at the newest stamp (no clock is
@@ -488,7 +503,7 @@ class Account:
     def snapshot(self) -> dict:
         """The counters, copied (from another thread: see the module
         docstring); they cover ``t_made_ns`` to ``t_ns``, the rows by
-        kind and ``gaps`` to the last unit's end."""
+        kind and ``gaps`` to the last unit's end, ``unit_t_ns``."""
         rest = self.unaccounted
         return {"ns": {n: p.ns for n, p in self.phases.items()},
                 "starved_ns": {n: p.starved_ns
@@ -498,6 +513,7 @@ class Account:
                 "unaccounted_starved_ns": rest.starved_ns,
                 "by_kind": {kind: dict(row)
                             for kind, row in list(self.by_kind.items())},
+                "unit_t_ns": self._unit[0],
                 "gaps": self.gaps.snapshot()}
 
 
@@ -550,8 +566,11 @@ class _Phase:
             self.starved_ns += dt
         if self.launches > 0:
             acct.in_flight += 1
+            acct.launched += 1
         elif self.launches:
-            acct.in_flight = 0
+            upto, acct._landed = acct._landed, None
+            acct.in_flight = (0 if upto is None else min(
+                acct.in_flight, max(acct.launched - upto, 0)))
         if sp:
             sp.close(now, exc_type, exc, tb)
         return False

@@ -103,13 +103,14 @@ def test_programs_chunks_then_decode_through_both_pools(cfg, params):
         rows = pool.window.layout.rows(np.arange(4), 0)
         pools[i] = pools[i].at[rows].set(jnp.nan)
     pools = tuple(pools)
+    feed = jnp.zeros(n_rows, jnp.int32)     # the rows' next tokens
     for row, n_prompt in prompts.items():
         for pos in range(0, n_prompt, C):
             n_q = min(C, n_prompt - pos)
             toks = np.zeros(C, np.int32)
             toks[:n_q] = seqs[row][pos:pos + n_q]
-            logits, load, pools, _ = chunk(
-                params, pools, (),
+            logits, load, pools, _, feed = chunk(
+                params, pools, (), feed,
                 pack_chunk(behind(row, pos), toks, pos, row, n_q))
             np.testing.assert_allclose(np.asarray(logits)[:n_q],
                                        want[row][pos:pos + n_q], atol=ATOL)
@@ -125,8 +126,9 @@ def test_programs_chunks_then_decode_through_both_pools(cfg, params):
             tokens[row], positions[row] = (seqs[row][n_prompt + t],
                                            n_prompt + t)
             now[row] = behind(row, n_prompt + t)
-        logits, load, pools, _ = step(
-            params, pools, (), pack_step(now, tokens, positions, active))
+        logits, load, pools, _, feed = step(
+            params, pools, (), feed,
+            pack_step(now, tokens, positions, active))
         for row, n_prompt in prompts.items():
             np.testing.assert_allclose(np.asarray(logits)[row],
                                        want[row][n_prompt + t], atol=ATOL)

@@ -256,6 +256,37 @@ def _assert_pool_stays_put(compiled, lay, n_pools=2, own=()):
     assert mem.alias_size_in_bytes >= n_pools * int(np.prod(lay.shape)) * 2
 
 
+def _entry_parameters(text):
+    """The entry computation's parameters, as its layout lists them."""
+    inside = re.search(r"entry_computation_layout=\{\((.*)\)->", text).group(1)
+    parts, depth, at = [], 0, 0
+    for i, ch in enumerate(inside):
+        depth += (ch in "[{(") - (ch in "]})")
+        if ch == "," and not depth:
+            parts.append(inside[at:i].strip())
+            at = i + 1
+    return parts + [inside[at:].strip()]
+
+
+def _assert_token_array_donated(compiled, rows):
+    """ISSUE 55: the token each row feeds next, int32 ``[rows]``, is the
+    program's operand before the packed array, resident on the device,
+    and donated like the pools: the compiled program aliases it to a
+    result (the loop launches the next pass on the array this one
+    returns, with no copy and no transfer)."""
+    text = compiled.as_text()
+    params = [re.sub(r"^/\*index=\d+\*/", "", p)
+              for p in _entry_parameters(text)]
+    assert params[-2].startswith(f"s32[{rows}]"), params[-2:]
+    # ``input_output_alias={ {2}: (16, {}, may-alias), .. }``: result
+    # index -> (parameter, index in it, kind)
+    head = text[:text.index("\n")]
+    aliased = {int(n) for n in re.findall(
+        r"\{[\d, ]*\}: \((\d+), \{[\d, ]*\}, \w+-alias\)",
+        head[head.index("input_output_alias="):])}
+    assert len(params) - 2 in aliased, (len(params), sorted(aliased))
+
+
 @pytest.fixture(scope="module")
 def xl(one_chip):
     """The serve cell's shapes: gpt2-xl, 32 rows, 768 + 1 blocks of 16,
@@ -278,6 +309,7 @@ def xl_compiled(xl):
     params = _params_of(cfg, on_chip)
     assert params["layers"]["w_up"].dtype == cfg.dtype
     assert params["wte"].dtype == jnp.float32       # added, THEN rounded
+    feed = on_chip((rows,), jnp.int32)      # the rows' next tokens
     done = {}
 
     def compiled(program, out_info=False):
@@ -285,17 +317,19 @@ def xl_compiled(xl):
             if program == "decode":
                 lowered = make_paged_decode_step(
                     cfg, block_size=lay.block_size, n_table=T).lower(
-                    params, pool, pool, on_chip((rows, T + 3), jnp.int32))
+                    params, pool, pool, feed,
+                    on_chip((rows, T + 3), jnp.int32))
             elif program == "chunk":
                 lowered = make_chunk_prefill_fn(
                     cfg, chunk=32, block_size=lay.block_size,
                     n_table=T).lower(
-                    params, pool, pool, on_chip((T + 32 + 3,), jnp.int32))
+                    params, pool, pool, feed,
+                    on_chip((T + 32 + 3,), jnp.int32))
             else:                   # the two as ONE program (ISSUE 41)
                 lowered = make_paged_step_chunk(
                     cfg, chunk=32, block_size=lay.block_size,
                     n_table=T).lower(
-                    params, pool, pool,
+                    params, pool, pool, feed,
                     on_chip((rows * (T + 3) + T + 32 + 3,), jnp.int32))
             done[program] = (lowered.compile(), lowered.out_info)
         return done[program][int(out_info)]
@@ -304,14 +338,17 @@ def xl_compiled(xl):
 
 def test_xl_decode_step_moves_no_pool(xl, xl_compiled):
     _assert_pool_stays_put(xl_compiled("decode"), xl[3])
+    _assert_token_array_donated(xl_compiled("decode"), 32)
 
 
 def test_xl_chunk_prefill_moves_no_pool(xl, xl_compiled):
     _assert_pool_stays_put(xl_compiled("chunk"), xl[3])
+    _assert_token_array_donated(xl_compiled("chunk"), 32)
 
 
 def test_xl_step_chunk_moves_no_pool(xl, xl_compiled):
     _assert_pool_stays_put(xl_compiled("step_chunk"), xl[3])
+    _assert_token_array_donated(xl_compiled("step_chunk"), 32)
 
 
 @pytest.mark.parametrize("program", ["chunk", "step_chunk"])
@@ -425,20 +462,23 @@ def test_xl_program_returns_its_own_greedy_tokens(xl, xl_compiled, program,
     uploaded them again for an argmax program of its own.  The same
     compiled programs still move no pool and cast no weight (above)."""
     cfg = xl[0]
-    logits, greedy, k, v = xl_compiled(program, out_info=True)
+    logits, greedy, k, v, feed = xl_compiled(program, out_info=True)
     # (the step that runs a chunk: its 32 rows, then the chunk's last
     # real position, whose token a prompt's first is)
     assert (logits.dtype, logits.shape) == (jnp.float32,
                                             (rows, cfg.vocab_size))
     assert (greedy.dtype, greedy.shape) == (jnp.int32, (n,))
     assert k.shape == v.shape == xl[3].shape
+    # ... and the token each of the 32 rows feeds next (ISSUE 55)
+    assert (feed.dtype, feed.shape) == (jnp.int32, (32,))
     entry = re.search(r"entry_computation_layout=.*",
                       xl_compiled(program).as_text()).group(0)
     results = entry.split("->", 1)[1]
     assert f"s32[{n}]" in results \
         and f"f32[{rows},{cfg.vocab_size}]" in results
-    # one packed int32 array in: no other integer argument
-    assert entry.split("->", 1)[0].count("s32[") == 1
+    # one packed int32 array in, behind the token array: no other
+    # integer argument
+    assert entry.split("->", 1)[0].count("s32[") == 2
 
 
 def test_xl_write_blocks_moves_no_pool(xl):
@@ -486,7 +526,7 @@ def test_paged_decode_step_keeps_its_pool_shard_under_dp_tp(mesh_2x2,
         packed = on_mesh((rows * (n_table + 3) + n_table + 32 + 3,),
                          jnp.int32)
     compiled = step.lower(_params_of(cfg, on_mesh), pool, pool,
-                          packed).compile()
+                          on_mesh((rows,), jnp.int32), packed).compile()
     shard = PoolLayout(lay.n_layers, lay.n_rows, bs, cfg.n_heads // 2,
                        cfg.head_dim)
     assert shard.shape == (*lay.shape[:2], 384)
@@ -513,9 +553,10 @@ def test_paged_decode_step_compiles_at_124m(one_chip):
     assert lay.width == cfg.d_model
     step = make_paged_decode_step(cfg, block_size=bs, n_table=n_table)
     compiled = step.lower(
-        _params_of(cfg, on_chip), pool, pool,
+        _params_of(cfg, on_chip), pool, pool, on_chip((rows,), jnp.int32),
         on_chip((rows, n_table + 3), jnp.int32)).compile()
     _assert_pool_stays_put(compiled, lay)
+    _assert_token_array_donated(compiled, rows)
     assert len(_kernel_calls(compiled.as_text())) == 1
 
 
@@ -570,6 +611,7 @@ def hybrid_decode_compiled(hybrid_cell):
     step = make_recurrent_decode_step(cfg, block_size=lay.block_size,
                                       n_table=T)
     return step.lower(params, (pool, pool), (conv, ssm),
+                      on_chip((rows,), jnp.int32),
                       on_chip((rows, T + 3), jnp.int32)).compile()
 
 
@@ -577,6 +619,7 @@ def test_hybrid_decode_step_fits_and_moves_no_pool(hybrid_cell,
                                                    hybrid_decode_compiled):
     _assert_pool_stays_put(hybrid_decode_compiled, hybrid_cell[4])
     _assert_state_stays_put(hybrid_decode_compiled, hybrid_cell[6])
+    _assert_token_array_donated(hybrid_decode_compiled, hybrid_cell[7])
 
 
 def test_hybrid_decode_updates_the_state_in_one_kernel_a_layer(
@@ -623,8 +666,9 @@ def test_hybrid_chunk_prefill_fits_and_moves_no_pool(hybrid_cell):
     chunk = make_recurrent_chunk_fn(cfg, chunk=cfg.ssm_chunk,
                                     block_size=lay.block_size, n_table=T)
     compiled = chunk.lower(
-        params, (pool, pool), (conv, ssm),
+        params, (pool, pool), (conv, ssm), on_chip((rows,), jnp.int32),
         on_chip((T + cfg.ssm_chunk + 3,), jnp.int32)).compile()
+    _assert_token_array_donated(compiled, rows)
     _assert_pool_stays_put(compiled, lay)
     _assert_state_stays_put(compiled, ssm)
 
@@ -704,6 +748,7 @@ def nano_decode_compiled(nano_cell):
     step = make_recurrent_decode_step(cfg, block_size=lay.block_size,
                                       n_table=T)
     return step.lower(params, (pool, pool), (conv, ssm),
+                      on_chip((rows,), jnp.int32),
                       on_chip((rows, T + 3), jnp.int32)).compile()
 
 
@@ -711,6 +756,7 @@ def test_nano_decode_step_fits_and_moves_no_pool(nano_cell,
                                                  nano_decode_compiled):
     _assert_pool_stays_put(nano_decode_compiled, nano_cell[4])
     _assert_state_stays_put(nano_decode_compiled, nano_cell[6])
+    _assert_token_array_donated(nano_decode_compiled, nano_cell[7])
     _no_expert_stack_is_copied(nano_decode_compiled.as_text(), nano_cell[2])
 
 
@@ -766,7 +812,9 @@ def test_nano_chunk_prefill_fits_and_moves_no_pool(nano_cell):
     chunk = make_recurrent_chunk_fn(cfg, chunk=C, block_size=lay.block_size,
                                     n_table=T)
     compiled = chunk.lower(params, (pool, pool), (conv, ssm),
+                           on_chip((rows,), jnp.int32),
                            on_chip((T + C + 3,), jnp.int32)).compile()
+    _assert_token_array_donated(compiled, rows)
     _assert_pool_stays_put(compiled, lay)
     _assert_state_stays_put(compiled, ssm)
     _no_expert_stack_is_copied(compiled.as_text(), params)
@@ -810,6 +858,7 @@ def fused_cells(hybrid_cell, nano_cell):
             fused = make_recurrent_step_chunk(
                 cfg, chunk=C, block_size=lay.block_size, n_table=T)
             args = (params, (pool, pool), (conv, ssm),
+                    on_chip((rows,), jnp.int32),
                     on_chip((rows * (T + 3) + T + C + 3,), jnp.int32))
             done[name] = (cfg, params, lay, ssm, rows, C,
                           fused.lower(*args).compile(),
@@ -827,6 +876,7 @@ def test_hybrid_step_chunk_fits_and_moves_no_pool(fused_cells, name):
     cfg, params, lay, ssm, rows, C, compiled, _ = fused_cells(name)
     _assert_pool_stays_put(compiled, lay)
     _assert_state_stays_put(compiled, ssm)
+    _assert_token_array_donated(compiled, rows)
     _no_expert_stack_is_copied(compiled.as_text(), params)
 
 
@@ -954,7 +1004,9 @@ def _latent_program(latent_cell, which):
         fn = make_recurrent_chunk_fn(cfg, chunk=C,
                                      block_size=lay.block_size, n_table=T)
         packed = on_chip((T + C + 3,), jnp.int32)
-    return fn.lower(params, (pool,), (), packed).compile()
+    return fn.lower(params, (pool,), (),
+                    on_chip((engine["max_slots"],), jnp.int32),
+                    packed).compile()
 
 
 def _no_table_span_by_heads(text, cfg, lay, engine):
@@ -1067,7 +1119,9 @@ def _olmo_program(olmo_cell, which):
         fn = make_recurrent_chunk_fn(cfg, chunk=C,
                                      block_size=lay.block_size, n_table=T)
         packed = on_chip((T + C + 3,), jnp.int32)
-    return fn.lower(params, (pool, pool), (conv, matrix), packed).compile()
+    return fn.lower(params, (pool, pool), (conv, matrix),
+                    on_chip((engine["max_slots"],), jnp.int32),
+                    packed).compile()
 
 
 @pytest.mark.parametrize("which", ["step", "chunk"])
@@ -1192,7 +1246,9 @@ def _afmoe_program(afmoe_cell, which):
         fn = make_recurrent_chunk_fn(cfg, chunk=C,
                                      block_size=full.block_size, n_table=T)
         packed = on_chip((2 * T + C + 3,), jnp.int32)
-    return fn.lower(params, pools, (), packed).compile()
+    return fn.lower(params, pools, (),
+                    on_chip((engine["max_slots"],), jnp.int32),
+                    packed).compile()
 
 
 @pytest.mark.parametrize("which", ["step", "chunk"])
@@ -1283,6 +1339,7 @@ def lfm2_cell(one_chip):
             kw = {} if which == "step" else {"chunk": C}
             fn = make(cfg, block_size=bs, n_table=T, **kw)
             done[which] = fn.lower(params, pools, state,
+                                   on_chip((rows,), jnp.int32),
                                    on_chip(n, jnp.int32)).compile()
         return done[which]
     return cfg, lay, state, engine, compiled
@@ -1304,6 +1361,7 @@ def test_lfm2_programs_fit_and_move_no_pool_and_no_snapshot(lfm2_cell,
     assert has_step_chunk(cfg)
     program = compiled(which)
     _assert_pool_stays_put(program, lay)
+    _assert_token_array_donated(program, engine["max_slots"])
     text = program.as_text()
     snap = ",".join(map(str, state[1].shape))
     assert not re.findall(rf"= \(?bf16\[{snap}\]\S* copy(?:-start)?\(", text)

@@ -143,10 +143,11 @@ def test_first_token_behind_a_running_decode(params, cfg, sampling_calls,
     """A prompt that ends while other rows decode: its first token is
     its program's own argmax — the last integer of the step that ran
     the chunk (ISSUE 41), or the chunk program's, not waited for before
-    the pass's decode step is dispatched (it is read in that step's
-    fetch) — the row joins the batch a pass later, and a request that
-    its first token ends never decodes.  Streams are the oracle's,
-    token for token."""
+    the pass's decode step is dispatched (it is read in that pass's
+    fetch, which since ISSUE 55 comes after the NEXT pass's dispatch) —
+    the row joins the batch a pass later, its token on the device, and
+    a request that its first token ends never decodes.  Streams are the
+    oracle's, token for token."""
     eng = _engine(params, cfg)
     if not fused:
         eng._step_chunk = None
@@ -165,7 +166,8 @@ def test_first_token_behind_a_running_decode(params, cfg, sampling_calls,
         outs = [r.result(timeout=300) for r in reqs]
         whole = head + list(it)
         st, calls = eng.stats(), list(sampling_calls)
-        assert eng._first_pending == [] and st["active_slots"] == 0
+        assert eng._flight is None and not eng._pass.owes
+        assert st["active_slots"] == 0
     finally:
         tracing.disable_tracing()
         eng.shutdown()
@@ -177,11 +179,16 @@ def test_first_token_behind_a_running_decode(params, cfg, sampling_calls,
         == 40 + sum(m for _, m in plan)
     spans = {s["span_id"]: s for s in tracing.get_finished_spans()}
     tracing.clear()
-    # every decode step fetched its rows' integers, nothing else ...
+    # every decode step's fetch brought its rows' integers, nothing
+    # else ...  (the fetch of a pass comes a pass later, inside the
+    # span of the step dispatched meanwhile, or the pass's own where
+    # nothing was left to dispatch: it says itself what it read)
     steps = [s for s in spans.values() if s["name"] == "engine.fetch"
-             and spans.get(s["parent_id"], {}).get("name") == "engine.decode"]
+             and s["attributes"].get("stepped")]
+    assert {spans[s["parent_id"]]["name"] for s in steps} \
+        <= {"engine.decode", "engine.pass"}
     def rode(s):          # a chunk ran inside the step: one integer more
-        return spans[s["parent_id"]]["attributes"]["chunk_tokens"] > 0
+        return s["attributes"]["rode"]
     assert steps and all(
         s["attributes"]["bytes"]
         == 4 * (4 + rode(s)) + 4 * s["attributes"]["first_tokens"]
@@ -198,10 +205,13 @@ def test_first_token_behind_a_running_decode(params, cfg, sampling_calls,
         assert sum(s["attributes"]["first_tokens"] for s in steps) >= 1
     assert not [s for s in spans.values() if s["name"] == "engine.fetch"
                 and spans.get(s["parent_id"], {}).get("name") == "engine.fetch"]
-    # and no chunk waited for its own token while a row decoded
+    # and no chunk waited for its own token while a row decoded (a
+    # chunk PROGRAM does wait, before it is launched, for the unread
+    # pass's chunk programs to have ended: a fetch of no bytes)
     for s in spans.values():
-        if s["name"] == "engine.fetch" and spans.get(
-                s["parent_id"], {}).get("name") == "engine.prefill_chunk":
+        if s["name"] == "engine.fetch" and s["attributes"]["bytes"] \
+                and spans.get(s["parent_id"], {}).get(
+                    "name") == "engine.prefill_chunk":
             chunk = spans[s["parent_id"]]
             assert spans[chunk["parent_id"]]["attributes"]["active"] == 0
 
@@ -223,12 +233,13 @@ def test_row_preempted_between_its_chunk_and_the_step_re_prefills(
 
     def hunted(ride=None):
         # what ``_grow_row`` -> ``_take_block`` does when the pool is dry
-        if eng._first_pending and not took:
-            row, req = eng._first_pending[0][:2]
+        if eng._pass.joining and not took:
+            row, req = eng._pass.joining[0]
             assert not req.tokens           # nothing emitted yet
             took.append(req)
+            eng._drain("preempt")           # as ``_take_block`` does
             eng._preempt_row(row)
-        sound(ride)
+        return sound(ride)
     eng._paged_decode_iteration = hunted
     try:
         first = eng.submit(long_, max_new=30)
@@ -238,7 +249,8 @@ def test_row_preempted_between_its_chunk_and_the_step_re_prefills(
         out = victim.result(timeout=300)
         whole = head + list(it)
         st = eng.stats()
-        assert eng._first_pending == [] and st["active_slots"] == 0
+        assert eng._flight is None and not eng._pass.owes
+        assert st["active_slots"] == 0
     finally:
         del eng._paged_decode_iteration     # the instance's reference cycle
         eng.shutdown()

@@ -115,9 +115,14 @@ def test_one_program_gives_what_the_two_give(cfg, params):
     rng = np.random.default_rng(0)
     tables = np.zeros((b, T), np.int32)
     tables[0, :3], tables[1, :2], tables[3, :1] = [1, 2, 3], [4, 5], [6]
+    tokens = rng.integers(0, cfg.vocab_size, b).astype(np.int32)
+    # the token array: row 1's input token is the device's (``FEED`` in
+    # its token column), the other rows' the host's
+    feed0 = np.array([40, tokens[1], 41, 42], np.int32)
+    tokens[1] = decode.FEED
     packed_step = decode.pack_step(
-        tables, rng.integers(0, cfg.vocab_size, b).astype(np.int32),
-        np.array([17, 9, 0, 3], np.int32), np.array([1, 1, 0, 1], bool))
+        tables, tokens, np.array([17, 9, 0, 3], np.int32),
+        np.array([1, 1, 0, 1], bool))
     table = np.zeros(T, np.int32)
     table[:3] = [7, 8, 9]
     toks = np.zeros(C, np.int32)
@@ -125,10 +130,25 @@ def test_one_program_gives_what_the_two_give(cfg, params):
     # positions 8 .. 12 of row 2's prompt: a partial chunk
     packed_chunk = decode.pack_chunk(table, toks, 8, 2, 5)
 
-    l_c, g_c, k, v = chunk(served, *pools(), packed_chunk)
-    l_s, g_s, k, v = step(served, k, v, packed_step)
-    l_f, g_f, k_f, v_f = fused(
-        served, *pools(), decode.pack_step_chunk(packed_step, packed_chunk))
+    l_c, g_c, k, v, feed = chunk(served, *pools(), jnp.asarray(feed0),
+                                 packed_chunk)
+    # the chunk's greedy token at its own row, nothing else moved
+    assert feed.tolist() == [40, int(feed0[1]), int(g_c[0]), 42]
+    l_s, g_s, k, v, feed = step(served, k, v, feed, packed_step)
+    # every stepped row's greedy token; the chunk's row sat the step out
+    assert feed.tolist() == [int(g_s[0]), int(g_s[1]), int(g_c[0]),
+                             int(g_s[3])]
+    l_f, g_f, k_f, v_f, feed_f = fused(
+        served, *pools(), jnp.asarray(feed0),
+        decode.pack_step_chunk(packed_step, packed_chunk))
+    assert feed_f.tolist() == feed.tolist() and feed_f.dtype == jnp.int32
+    # ... and handed row 1's token by the host, the step gives the same
+    by_host = packed_step.copy()
+    by_host[1, T] = feed0[1]
+    l_h, g_h, *_ = step(served, *pools(), jnp.zeros(b, jnp.int32), by_host)
+    np.testing.assert_allclose(np.asarray(l_h)[[0, 1, 3]],
+                               np.asarray(l_s)[[0, 1, 3]],
+                               rtol=1e-5, atol=1e-5)
     assert l_f.shape == (b + 1, cfg.vocab_size) and l_f.dtype == jnp.float32
     assert g_f.shape == (b + 1,) and g_f.dtype == jnp.int32
     np.testing.assert_allclose(l_f[:b], l_s, rtol=1e-5, atol=1e-5)
@@ -223,11 +243,21 @@ def test_hybrid_program_gives_what_the_two_give(layout, window):
     packed_chunk = decode.pack_chunk(table, toks, 8 if n_valid else 0, row,
                                      n_valid)
 
-    l_c, i_c, pools, state = chunk(params, *fresh(), packed_chunk)
-    l_s, i_s, pools, state = step(params, pools, state, packed_step)
-    l_f, i_f, pools_f, state_f = fused(
-        params, *fresh(), decode.pack_step_chunk(packed_step, packed_chunk))
+    feed0 = jnp.arange(50, 50 + b, dtype=jnp.int32)    # the token array
+    l_c, i_c, pools, state, feed = chunk(params, *fresh(), feed0,
+                                         packed_chunk)
+    l_s, i_s, pools, state, feed = step(params, pools, state, feed,
+                                        packed_step)
+    l_f, i_f, pools_f, state_f, feed_f = fused(
+        params, *fresh(), jnp.arange(50, 50 + b, dtype=jnp.int32),
+        decode.pack_step_chunk(packed_step, packed_chunk))
     n = hybrid.N_LOAD
+    # the token array: a stepped row's greedy token, the chunk's at its
+    # row (a chunk of nothing writes nothing), the rest as they were
+    want_feed = [int(i_s[n + r]) if active[r] else 50 + r for r in range(b)]
+    if n_valid:
+        want_feed[row] = int(i_c[n])
+    assert feed.tolist() == want_feed == feed_f.tolist()
     assert l_f.shape == (b + 1, cfg.vocab_size) and l_f.dtype == jnp.float32
     assert i_f.shape == (n + b + n + 1,) and i_f.dtype == jnp.int32
     live = np.flatnonzero(active)
@@ -359,7 +389,7 @@ def _serve_mix_once(params, cfg, fused: bool):
         outs = [r.result(timeout=300) for r in reqs]
         assert not first.done                         # ... and still is
         whole = head + list(it)
-        assert eng._first_pending == [] \
+        assert eng._flight is None and not eng._pass.owes \
             and eng.stats()["active_slots"] == 0
     finally:
         eng.shutdown()

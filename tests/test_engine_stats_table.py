@@ -70,6 +70,8 @@ STATS_TYPES = {
     # the recurrent state kept at block ends (ISSUE 52)
     "state_snapshot_bytes": int, "state_snapshots_written": int,
     "state_snapshots_restored": int,
+    # the loop one pass ahead of what it has read (ISSUE 55)
+    "passes_launched_ahead": int, "passes_drained": int,
 }
 LOOP_ACCOUNT_TYPES = {
     "ns": dict, "starved_ns": dict, "count": dict, "unaccounted_ns": int,
@@ -77,6 +79,8 @@ LOOP_ACCOUNT_TYPES = {
     "t_ns": int,
     # ISSUE 54: the account by kind of pass
     "by_kind": dict, "gaps": dict,
+    # ISSUE 55: why a pass in flight was landed early, by reason
+    "drained_by": dict, "unit_t_ns": int,
 }
 
 SERIES = [
@@ -216,6 +220,13 @@ SERIES = [
     ("ray_tpu_inference_weight_bytes_cast_per_pass", "gauge",
      "Bytes of weights a program casts to its compute dtype every "
      "pass (0 = each is stored in it)"),
+    ("ray_tpu_inference_passes_launched_ahead_total", "counter",
+     "Passes dispatched while the pass before them was still unread "
+     "(queued behind it on the device)"),
+    ("ray_tpu_inference_passes_drained_total", "counter",
+     "Passes in flight that were read before the next could be launched "
+     "(a sampled row, a preemption, a cancelled row, a cross-thread op, "
+     "a full-width prefill, shutdown)"),
     ("ray_tpu_inference_loop_seconds_total", "counter",
      "The engine loop thread's wall time by phase (self time; "
      "`wait` is the wait for the device, `parked` an engine with no "

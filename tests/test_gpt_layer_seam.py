@@ -126,21 +126,25 @@ def _programs_and_forward():
     got = {}
     chunk = make_chunk_prefill_fn(CFG, chunk=CHUNK, **kw)
     rows = []
+    feed = jnp.zeros(ROWS, jnp.int32)       # the rows' next tokens
     for r in range(ROWS):
-        logits, first, k, v = chunk(params, k, v, pack_chunk(
+        logits, first, k, v, feed = chunk(params, k, v, feed, pack_chunk(
             tables[r], toks[r, :CHUNK], 0, r, CHUNK))
-        # the program's own greedy token: its last real position's
+        # the program's own greedy token: its last real position's, and
+        # what the row feeds its first decode step
         assert first.dtype == jnp.int32 \
-            and first.tolist() == [int(logits[CHUNK - 1].argmax())]
+            and first.tolist() == [int(logits[CHUNK - 1].argmax())] \
+            == [int(feed[r])]
         rows.append(logits)
     got["chunk"] = jnp.stack(rows)
     at = jnp.full((ROWS,), CHUNK, jnp.int32)
     live = jnp.ones((ROWS,), bool)
     want_all = jnp.full((ROWS,), WIDTH, jnp.int32)
-    got["decode"], greedy, k, v = make_paged_decode_step(CFG, **kw)(
-        params, k, v, pack_step(tables, toks[:, CHUNK], at, live))
+    got["decode"], greedy, k, v, feed = make_paged_decode_step(
+        CFG, **kw)(
+        params, k, v, feed, pack_step(tables, toks[:, CHUNK], at, live))
     assert greedy.dtype == jnp.int32 and greedy.tolist() \
-        == got["decode"].argmax(-1).tolist()
+        == got["decode"].argmax(-1).tolist() == feed.tolist()
     got["verify"], k, v = make_spec_verify_step(CFG, width=WIDTH, **kw)(
         params, k, v, tables, toks[:, CHUNK:CHUNK + WIDTH], at, live,
         want_all)

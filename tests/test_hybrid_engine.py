@@ -81,7 +81,8 @@ def test_first_token_behind_a_running_decode(fam):
     reqs = [eng.submit(p, max_new=m) for p, (_, m) in zip(prompts, plan)]
     outs = [r.result(timeout=300) for r in reqs]
     whole = head + list(it)
-    assert eng._first_pending == [] and eng.stats()["active_slots"] == 0
+    assert eng._flight is None and not eng._pass.owes
+    assert eng.stats()["active_slots"] == 0
     eng.shutdown()
     assert len(whole) == 40 and _margins(fam, long_, whole).max() <= fam.atol
     for p, o, (_, m) in zip(prompts, outs, plan):

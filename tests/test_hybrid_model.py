@@ -302,12 +302,13 @@ def test_programs_chunks_then_decode_equal_reference_logits(fam):
     k, v, conv, s_ = pool.k, pool.v, pool.state.conv, pool.state.ssm
     # neighbours' state must come back untouched
     s_ = s_.at[:, 0].set(1.5)
+    feed = jnp.zeros(n_rows, jnp.int32)     # the rows' next tokens
     for pos in range(0, n_prompt, C):
         n_q = min(C, n_prompt - pos)
         toks = np.zeros(C, np.int32)
         toks[:n_q] = seq[pos:pos + n_q]
-        logits, load, (k, v), (conv, s_) = chunk(
-            params, (k, v), (conv, s_),
+        logits, load, (k, v), (conv, s_), feed = chunk(
+            params, (k, v), (conv, s_), feed,
             pack_chunk(table, toks, pos, row, n_q))
         np.testing.assert_allclose(np.asarray(logits)[:n_q],
                                    want[pos:pos + n_q], atol=fam.atol)
@@ -317,7 +318,7 @@ def test_programs_chunks_then_decode_equal_reference_logits(fam):
             n_q * per_token, 8 * fam.expert_layers)
         # the last REAL position's greedy token rides with the load
         assert int(load[hybrid.N_LOAD]) == int(
-            np.asarray(logits)[n_q - 1].argmax())
+            np.asarray(logits)[n_q - 1].argmax()) == int(feed[row])
     tables = np.zeros((n_rows, T), np.int32)
     tables[row] = table
     active = np.zeros(n_rows, bool)
@@ -326,8 +327,8 @@ def test_programs_chunks_then_decode_equal_reference_logits(fam):
         tokens = np.zeros(n_rows, np.int32)
         positions = np.zeros(n_rows, np.int32)
         tokens[row], positions[row] = seq[pos], pos
-        logits, load, (k, v), (conv, s_) = step(
-            params, (k, v), (conv, s_),
+        logits, load, (k, v), (conv, s_), feed = step(
+            params, (k, v), (conv, s_), feed,
             pack_step(tables, tokens, positions, active))
         np.testing.assert_allclose(np.asarray(logits)[row], want[pos],
                                    atol=fam.atol)

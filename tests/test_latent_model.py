@@ -325,12 +325,14 @@ def test_programs_chunks_then_decode_equal_reference_logits(cfg, params):
     table = np.zeros(T, np.int32)
     table[:4] = [3, 7, 2, 9]
     pools, state = pool.pools, ()
+    feed = jnp.zeros(n_rows, jnp.int32)     # the rows' next tokens
     for pos in range(0, n_prompt, C):
         n_q = min(C, n_prompt - pos)
         toks = np.zeros(C, np.int32)
         toks[:n_q] = seq[pos:pos + n_q]
-        logits, load, pools, state = chunk(
-            params, pools, state, pack_chunk(table, toks, pos, row, n_q))
+        logits, load, pools, state, feed = chunk(
+            params, pools, state, feed,
+            pack_chunk(table, toks, pos, row, n_q))
         assert state == () and len(pools) == 1
         np.testing.assert_allclose(np.asarray(logits)[:n_q],
                                    want[pos:pos + n_q], atol=ATOL)
@@ -346,9 +348,9 @@ def test_programs_chunks_then_decode_equal_reference_logits(cfg, params):
         tokens = np.zeros(n_rows, np.int32)
         positions = np.zeros(n_rows, np.int32)
         tokens[row], positions[row] = seq[pos], pos
-        logits, load, pools, state = step(
-            params, pools, state, pack_step(tables, tokens, positions,
-                                            active))
+        logits, load, pools, state, feed = step(
+            params, pools, state, feed,
+            pack_step(tables, tokens, positions, active))
         np.testing.assert_allclose(np.asarray(logits)[row], want[pos],
                                    atol=ATOL)
         assert load.tolist()[:2] == [6, 6]

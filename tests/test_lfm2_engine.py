@@ -129,23 +129,25 @@ def test_programs_chunks_then_decode_and_their_snapshots(cfg, params,
     tables[0, :8] = [3, 7, 2, 9, 11, 4, 13, 15]
     tables[2, :7] = [5, 1, 8, 6, 10, 12, 14]
     pools, state = pool.pools, pool.state.arrays
+    feed = jnp.zeros(n_rows, jnp.int32)     # the rows' next tokens
     tokens = np.zeros(n_rows, np.int32)
     positions = np.zeros(n_rows, np.int32)
     active = np.zeros(n_rows, bool)
 
     def run_chunk(row, pos, ride=False):
-        nonlocal pools, state
+        nonlocal pools, state, feed
         n_q = min(C, prompts[row] - pos)
         toks = np.zeros(C, np.int32)
         toks[:n_q] = seqs[row][pos:pos + n_q]
         packed = pack_chunk(tables[row], toks, pos, row, n_q)
         if not ride:
-            logits, _, pools, state = chunk(params, pools, state, packed)
+            logits, _, pools, state, feed = chunk(params, pools, state,
+                                                  feed, packed)
             np.testing.assert_allclose(np.asarray(logits)[:n_q],
                                        want[row][pos:pos + n_q], atol=ATOL)
             return
-        logits, _, pools, state = both(
-            params, pools, state, pack_step_chunk(
+        logits, _, pools, state, feed = both(
+            params, pools, state, feed, pack_step_chunk(
                 pack_step(tables, tokens, positions, active), packed))
         np.testing.assert_allclose(np.asarray(logits)[n_rows],
                                    want[row][pos + n_q - 1], atol=ATOL)
@@ -162,9 +164,9 @@ def test_programs_chunks_then_decode_and_their_snapshots(cfg, params,
             tokens[0], positions[0] = seqs[0][41], 41
     tokens[2], positions[2], active[2] = seqs[2][37], 37, True
     for _ in range(12):
-        logits, _, pools, state = step(
-            params, pools, state, pack_step(tables, tokens, positions,
-                                            active))
+        logits, _, pools, state, feed = step(
+            params, pools, state, feed,
+            pack_step(tables, tokens, positions, active))
         for r in (0, 2):
             np.testing.assert_allclose(np.asarray(logits)[r],
                                        want[r][positions[r]], atol=ATOL)

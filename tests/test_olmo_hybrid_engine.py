@@ -73,13 +73,14 @@ def test_programs_chunks_then_decode_equal_reference_logits(cfg, params):
     tables[0, :7] = [3, 7, 2, 9, 11, 4, 13]
     tables[2, :6] = [5, 1, 8, 6, 10, 12]
     pools, state = pool.pools, (pool.state.conv, pool.state.ssm)
+    feed = jnp.zeros(n_rows, jnp.int32)     # the rows' next tokens
     for row, n_prompt in prompts.items():
         for pos in range(0, n_prompt, C):
             n_q = min(C, n_prompt - pos)
             toks = np.zeros(C, np.int32)
             toks[:n_q] = seqs[row][pos:pos + n_q]
-            logits, load, pools, state = chunk(
-                params, pools, state,
+            logits, load, pools, state, feed = chunk(
+                params, pools, state, feed,
                 pack_chunk(tables[row], toks, pos, row, n_q))
             np.testing.assert_allclose(np.asarray(logits)[:n_q],
                                        want[row][pos:pos + n_q], atol=ATOL)
@@ -93,9 +94,9 @@ def test_programs_chunks_then_decode_equal_reference_logits(cfg, params):
         for row, n_prompt in prompts.items():
             tokens[row], positions[row] = (seqs[row][n_prompt + t],
                                            n_prompt + t)
-        logits, load, pools, state = step(
-            params, pools, state, pack_step(tables, tokens, positions,
-                                            active))
+        logits, load, pools, state, feed = step(
+            params, pools, state, feed,
+            pack_step(tables, tokens, positions, active))
         for row, n_prompt in prompts.items():
             np.testing.assert_allclose(np.asarray(logits)[row],
                                        want[row][n_prompt + t], atol=ATOL)
@@ -177,13 +178,15 @@ def test_chunk_program_by_head_equals_the_packed_form(cfg, params,
         pools = tuple(jnp.zeros_like(p) for p in pool.pools)
         state = (jnp.zeros_like(pool.state.conv),
                  jnp.zeros_like(pool.state.ssm))
+        feed = jnp.zeros(pool.state.conv.shape[1], jnp.int32)
         outs = []
         for p in range(0, 40, C):
             n_q = min(C, 40 - p)
             toks = np.zeros(C, np.int32)
             toks[:n_q] = seq[p:p + n_q]
-            logits, _, pools, state = chunk(
-                params, pools, state, pack_chunk(table, toks, p, 0, n_q))
+            logits, _, pools, state, feed = chunk(
+                params, pools, state, feed,
+                pack_chunk(table, toks, p, 0, n_q))
             outs.append(np.asarray(logits)[:n_q])
         return np.concatenate(outs)
 

@@ -128,15 +128,16 @@ def test_dense_no_mesh_builds_are_annotation_free(cfg, params):
                          jnp.float32)
 
     pool = zeros_pool(1)
+    feed = jnp.zeros(2, jnp.int32)          # the rows' next tokens
     jaxpr = str(jax.make_jaxpr(step)(
-        params, pool, pool, jnp.zeros((2, 8 + 3), jnp.int32)))
+        params, pool, pool, feed, jnp.zeros((2, 8 + 3), jnp.int32)))
     for prim in ("sharding_constraint", "psum", "all_gather",
                  "all_to_all"):
         assert prim not in jaxpr, \
             f"mesh=None decode step grew a {prim} equation"
     chunk = make_chunk_prefill_fn(cfg, chunk=16, block_size=8, n_table=8)
     jaxpr_c = str(jax.make_jaxpr(chunk)(
-        params, pool, pool, jnp.zeros(8 + 16 + 3, jnp.int32)))
+        params, pool, pool, feed, jnp.zeros(8 + 16 + 3, jnp.int32)))
     assert "sharding_constraint" not in jaxpr_c
     # positive control: the SAME builder with a mesh is annotated (the
     # assertion above is meaningful, not vacuously matching a renamed
@@ -146,7 +147,7 @@ def test_dense_no_mesh_builds_are_annotation_free(cfg, params):
                                      mesh=mesh)
     sh_pool = zeros_pool(2)
     jaxpr_sh = str(jax.make_jaxpr(step_sh)(
-        params, sh_pool, sh_pool, jnp.zeros((2, 8 + 3), jnp.int32)))
+        params, sh_pool, sh_pool, feed, jnp.zeros((2, 8 + 3), jnp.int32)))
     assert "sharding_constraint" in jaxpr_sh
 
 
@@ -281,8 +282,10 @@ def test_in_program_argmax_over_sharded_logits_dp_tp(cfg, params):
     seen = {"step": 0, "chunk": 0, "step_chunk": 0}
 
     def checked(kind, program):
-        def run(params_, k, v, packed):
-            logits, greedy, k, v = program(params_, k, v, packed)
+        def run(params_, k, v, feed, packed):
+            logits, greedy, k, v, feed = program(params_, k, v, feed,
+                                                 packed)
+            assert feed.sharding.is_fully_replicated
             assert "tp" in str(logits.sharding.spec[-1])
             host = np.asarray(logits)
             np.testing.assert_array_equal(host[:, :half], host[:, half:])
@@ -293,7 +296,7 @@ def test_in_program_argmax_over_sharded_logits_dp_tp(cfg, params):
                 == host.argmax(-1).tolist()
             assert (np.asarray(greedy) < half).all()
             seen[kind] += 1
-            return logits, greedy, k, v
+            return logits, greedy, k, v, feed
         return run
     for kind in seen:
         setattr(eng, "_" + kind, checked(kind, getattr(eng, "_" + kind)))
