@@ -34,7 +34,10 @@ def report(obs: dict, client: dict) -> dict:
     wrote = pass_ledger.front_gaps(obs)
     firsts = pass_ledger.first_tokens(obs)
     out = {"passes": led and led["passes"], "kinds": {}, "gaps_ms": {}}
-    for key in ("passes_launched_ahead", "passes_drained"):
+    # (``chunks_in_step`` over ``chunk_passes``: the share of the
+    # window's chunks that rode a decode step as ONE program)
+    for key in ("passes_launched_ahead", "passes_drained", "chunk_passes",
+                "chunks_in_step"):
         out[key] = led["counters"].get(key) if led else None
     # why passes were read early, since the engine was made (the newest
     # ``engine.account`` span's own table)

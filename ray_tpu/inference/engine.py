@@ -73,9 +73,9 @@ one (``_step_chunk``: the weights stream once a pass; the GPT family's
 decode.make_paged_step_chunk without speculation or routed experts,
 the hybrid family's recurrent.make_recurrent_step_chunk for the layouts
 whose every sublayer kind takes a window in two parts — Mamba-2, the
-short convolution, attention over K/V blocks, routed experts, the dense
-MLP; not latent attention, the delta rule or window layers — which each
-seam's ``build`` derives from the configuration): the pass's last chunk
+short convolution, the delta rule, attention over K/V blocks, routed
+experts, the dense MLP; not latent attention or window layers — which
+each seam's ``build`` derives from the configuration): the pass's last chunk
 is prepared and packed, and launched by the decode step.  A prompt's
 greedy first token is its last
 chunk's own argmax, read inside the pass's fetch: the fused
@@ -657,9 +657,9 @@ class _KVAndState(_Seam):
             n_table=eng.pool.blocks_per_seq)
         # the two as ONE program where every sublayer kind of the model
         # takes a window in two parts (Mamba-2, the short convolution,
-        # attention over K/V blocks, routed experts, the dense MLP); a
-        # latent, delta-rule or window-attention sublayer: the two back
-        # to back
+        # the delta rule, attention over K/V blocks, routed experts, the
+        # dense MLP); a latent or window-attention sublayer: the two
+        # back to back
         eng._step_chunk = (
             make_recurrent_step_chunk(
                 cfg, chunk=ec.prefill_chunk, block_size=bs,

@@ -69,15 +69,16 @@ deployment (experts over an ``ep`` axis are future work).
 A pass that holds a prompt chunk AND decoding rows runs the two as ONE
 program, ``step_chunk`` (``make_recurrent_step_chunk``; ``jit_step_chunk``
 in a trace, as the GPT family's), where the model's every sublayer kind
-takes a window in two parts: Mamba-2, attention over K/V blocks gathered
-by table, routed experts (dropless: a part's tokens are dropped by
-nobody), the dense MLP — the granite and nemotron layouts.  Every
+takes a window in two parts: Mamba-2, the short convolution, the delta
+rule, attention over K/V blocks gathered by table, routed experts
+(dropless: a part's tokens are dropped by nobody), the dense MLP — the
+granite, nemotron, lfm2 and olmo layouts.  Every
 matrix then streams from HBM once a pass, for ``rows + chunk`` tokens,
 and only the recurrences and the attention run a part at a time, in the
 forms the two programs give them (the chunk's queries walk their row's
 table or attend it packed, as ``paged_attend`` derives from the table's
 span: the fused pass does not depend on which).  A latent-attention
-sublayer, a delta-rule sublayer or a window layer has no such form yet,
+sublayer or a window layer has no such form yet,
 and a model with one keeps the pass of two programs
 (``has_step_chunk``: derived from the sublayer kinds, by the engine,
 where it builds the programs).
@@ -413,9 +414,9 @@ def make_recurrent_chunk_fn(cfg: HybridConfig, *, chunk: int,
 def has_step_chunk(cfg: HybridConfig) -> bool:
     """Whether ``make_recurrent_step_chunk`` has a program for this
     model: every sublayer kind must have the two-part form
-    (``hybrid.TWO_PART``: Mamba-2, the short convolution, attention over
-    K/V blocks — whichever form attends its window of queries —, routed
-    experts, the dense MLP; not latent attention, not the delta rule,
+    (``hybrid.TWO_PART``: Mamba-2, the short convolution, the delta
+    rule, attention over K/V blocks — whichever form attends its window
+    of queries —, routed experts, the dense MLP; not latent attention,
     not a window layer)."""
     return {kind for _, kind in cfg.sublayers} <= hybrid.TWO_PART
 
@@ -446,8 +447,8 @@ def make_recurrent_step_chunk(cfg: HybridConfig, *, chunk: int,
     and attends the first ``b`` queries as one-token rows and the last
     ``C`` over the chunk row's table in the form the chunk program
     gives them (the walk over key blocks, or packed under the chunk's
-    mask: ``paged_attend``); Mamba-2
-    advances the ``b`` rows' state where it lies in the pool, and the
+    mask: ``paged_attend``); Mamba-2 and the delta rule
+    advance the ``b`` rows' state where it lies in the pool, and the
     chunk's row's from a slice of the pool that goes back there (that
     row is inactive in the step: the two touch different rows).  The
     head runs over ``b + 1`` rows: the decode rows and the chunk's last

@@ -132,7 +132,7 @@ WINDOW = "window_attention"     # attention over the last ``window`` keys
 SHORT_CONV = "short_conv"       # the gated short convolution
 N_LOAD = 4      # numbers in ``run_layers``' load vector (its text names them)
 # the sublayer kinds that take a window in two parts (``block``'s ``rows``)
-TWO_PART = frozenset({MAMBA, SHORT_CONV, ATTENTION, EXPERTS, DENSE})
+TWO_PART = frozenset({MAMBA, SHORT_CONV, LINEAR, ATTENTION, EXPERTS, DENSE})
 # the sublayer kinds that carry a per-row state (``state_geometry``)
 RECURRENT = (MAMBA, LINEAR, SHORT_CONV)
 # the sublayer kinds of a ``nemotron_h`` pattern string
@@ -1011,13 +1011,45 @@ def _unit(x, eps: float = 1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def _linear_mixer(cfg, lp, h, state, n_valid):
+def _linear_core(cfg, lp, qkv, ab, state, n_valid):
+    """The delta-rule mixer between its projections, on ``wqkv``'s and
+    ``wab``'s outputs for the rows of ``state``.  -> (o [b, w, H, V]
+    float32, state)."""
+    conv_state, (pool, layer) = state
+    b, w, _ = qkv.shape
+    H, K, V = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
+    f32 = jnp.float32
+    qkv, conv_state = ssm.causal_conv(qkv, conv_state, lp["conv_w"],
+                                      None, n_valid)
+    # (one materialisation again: q's, k's and v's consumers each
+    # computed the taps over the whole [chunk, 11520] otherwise, 30
+    # evaluations in 12 layers, compiled for the chip, PR 45)
+    qkv = jax.lax.optimization_barrier(qkv)
+    q, k, v = jnp.split(qkv, [H * K, 2 * H * K], axis=-1)
+    q = _unit(q.reshape(b, w, H, K).astype(f32)) * K ** -0.5
+    k = _unit(k.reshape(b, w, H, K).astype(f32))
+    a, beta = ab[..., :H], 2.0 * jax.nn.sigmoid(ab[..., H:])
+    g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(a + lp["dt_bias"])
+    o, pool = delta_rule.delta_rule(q, k, v.reshape(b, w, H, V), g,
+                                    beta, pool, layer, n_valid)
+    return o, (conv_state, (pool, layer))
+
+
+def _linear_mixer(cfg, lp, h, state, n_valid, rows: int = 0):
     """h [b, w, d]; state (conv [b, K-1, C], matrix): the rows' matrix
     state as ``ops/delta_rule.delta_rule`` addresses it, (pool [L, b,
-    keys, heads * values] f32, layer).  -> (out [b, w, d], state)."""
-    conv_state, (pool, layer) = state
-    b, w, _ = h.shape
-    H, K, V = cfg.lin_heads, cfg.lin_key_dim, cfg.lin_value_dim
+    keys, heads * values] f32, layer).  -> (out [b, w, d], state).
+
+    With ``rows`` the window is in two parts (``block``): h [1, rows +
+    w, d], ``n_valid`` [rows + 1], and ``state`` (conv, matrix, row) as
+    ``_mamba_mixer`` takes it.  The four projections are one product
+    each over the whole window, the gated norm runs over both parts at
+    once; between them the one-token kernel advances the rows' state
+    where it lies in the pool (the window's row sits it out: ``n_valid``
+    0, not visited), THEN the window kernel that row's state, read from
+    the pool the step left and written back there: the pool stays ONE
+    chain of updates in place (``_mamba_mixer``)."""
+    H, V = cfg.lin_heads, cfg.lin_value_dim
     f32 = jnp.float32
     with jax.named_scope("mixer_linear_proj"):
         qkv = jnp.dot(h, lp["wqkv"].astype(h.dtype))
@@ -1029,24 +1061,33 @@ def _linear_mixer(cfg, lp, h, state, n_valid):
         # the splits: 4-5 x 0.47 ms a layer, read on the chip, PR 44)
         qkv, gate = jax.lax.optimization_barrier((qkv, gate))
     with jax.named_scope("mixer_linear_attention"):
-        qkv, conv_state = ssm.causal_conv(qkv, conv_state, lp["conv_w"],
-                                          None, n_valid)
-        # (one materialisation again: q's, k's and v's consumers each
-        # computed the taps over the whole [chunk, 11520] otherwise, 30
-        # evaluations in 12 layers, compiled for the chip, PR 45)
-        qkv = jax.lax.optimization_barrier(qkv)
-        q, k, v = jnp.split(qkv, [H * K, 2 * H * K], axis=-1)
-        q = _unit(q.reshape(b, w, H, K).astype(f32)) * K ** -0.5
-        k = _unit(k.reshape(b, w, H, K).astype(f32))
-        a, beta = ab[..., :H], 2.0 * jax.nn.sigmoid(ab[..., H:])
-        g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(a + lp["dt_bias"])
-        o, pool = delta_rule.delta_rule(q, k, v.reshape(b, w, H, V), g,
-                                        beta, pool, layer, n_valid)
-        y = _rms_norm(o, lp["gnorm"], cfg.rms_eps).reshape(b, w, H * V) \
-            * jax.nn.silu(gate.astype(f32))
+        if rows:
+            conv0, matrix0, row = state
+            # [1, rows + w, .] -> [rows, 1, .] and [1, w, .]
+            o1, (conv, (pool, layer)) = _linear_core(
+                cfg, lp, qkv[0, :rows, None], ab[0, :rows, None],
+                (conv0, matrix0), n_valid[:rows])
+            at = (layer, row, 0, 0)
+            own = jax.lax.dynamic_slice(pool, at, (1, 1) + pool.shape[2:])
+            ow, (own_conv, (own, _)) = _linear_core(
+                cfg, lp, qkv[:, rows:], ab[:, rows:],
+                (jax.lax.dynamic_slice_in_dim(conv0, row, 1), (own, 0)),
+                n_valid[rows:])
+            state = (conv.at[row].set(own_conv[0]),
+                     (jax.lax.dynamic_update_slice(pool, own, at), layer),
+                     row)
+            o = jnp.concatenate([o1[None, :, 0], ow], axis=1)
+        else:
+            o, state = _linear_core(cfg, lp, qkv, ab, state, n_valid)
+        y = _rms_norm(o, lp["gnorm"], cfg.rms_eps).reshape(
+            *o.shape[:2], H * V) * jax.nn.silu(gate.astype(f32))
     with jax.named_scope("mixer_linear_proj"):
         out = jnp.dot(y.astype(h.dtype), lp["wo"].astype(h.dtype))
-    return out, (conv_state, (pool, layer))
+    return out, state
+
+
+# the two-part window's: equal layers traced once (``_ssm_core_once``)
+_linear_once = jax.jit(_linear_mixer, static_argnums=(0, 5))
 
 
 def _attention_mixer(cfg, ap, h, attend, tables=None,
@@ -1208,15 +1249,14 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
     one-token rows (a decode step's) and then ONE row's window of ``w``
     tokens (a prefill chunk's) — with ``n_valid`` [rows + 1]: 0 / 1 a
     one-token row, then the window's real tokens.  Every product over
-    ``d`` is then one product for both parts.  A Mamba sublayer's
-    ``past`` is the one-token rows' state and which of them the window
-    belongs to, (conv, ssm, row) — a short convolution's (conv, marks,
-    row); an attention sublayer's ``attend``
+    ``d`` is then one product for both parts.  A Mamba or linear
+    sublayer's ``past`` is the one-token rows' state and which of them
+    the window belongs to, (conv, ssm or matrix, row) — a short
+    convolution's (conv, marks, row); an attention sublayer's ``attend``
     treats the two parts apart itself (``decode.paged_attend`` with a
     length a row AND the window's positions); experts count the parts
-    apart: counts [2,
-    E_held], total [2].  Linear and latent attention have no such
-    form."""
+    apart: counts [2, E_held], total [2].  Latent and window attention
+    have no such form."""
     h = x if cfg.norm_output else _rms_norm(x, lp["norm"], cfg.rms_eps)
     load = None
     if rows and kind not in TWO_PART:
@@ -1224,7 +1264,8 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
     if kind == MAMBA:
         mix, past = _mamba_mixer(cfg, lp, h, past, n_valid, rows)
     elif kind == LINEAR:
-        mix, past = _linear_mixer(cfg, lp, h, past, n_valid)
+        mix, past = (_linear_once if rows else _linear_mixer)(
+            cfg, lp, h, past, n_valid, rows)
     elif kind == SHORT_CONV:
         mix, past = _short_conv_mixer(cfg, lp, h, past, n_valid, rows)
     elif kind == ATTENTION:

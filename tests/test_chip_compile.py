@@ -1105,23 +1105,30 @@ def olmo_cell(one_chip):
             on_chip((layers, rows, *matrix), jnp.float32), engine)
 
 
-def _olmo_program(olmo_cell, which):
+def _olmo_program(olmo_cell, which, traced=False):
     from ray_tpu.inference.recurrent import (make_recurrent_chunk_fn,
-                                             make_recurrent_decode_step)
+                                             make_recurrent_decode_step,
+                                             make_recurrent_step_chunk)
     cfg, on_chip, params, pool, lay, conv, matrix, engine = olmo_cell
     T = -(-engine["max_seq"] // lay.block_size)
+    rows, C = engine["max_slots"], engine["prefill_chunk"]
     if which == "step":
         fn = make_recurrent_decode_step(cfg, block_size=lay.block_size,
                                         n_table=T)
-        packed = on_chip((engine["max_slots"], T + 3), jnp.int32)
-    else:
-        C = engine["prefill_chunk"]
+        packed = on_chip((rows, T + 3), jnp.int32)
+    elif which == "chunk":
         fn = make_recurrent_chunk_fn(cfg, chunk=C,
                                      block_size=lay.block_size, n_table=T)
         packed = on_chip((T + C + 3,), jnp.int32)
-    return fn.lower(params, (pool, pool), (conv, matrix),
-                    on_chip((engine["max_slots"],), jnp.int32),
-                    packed).compile()
+    else:
+        fn = make_recurrent_step_chunk(cfg, chunk=C,
+                                       block_size=lay.block_size, n_table=T)
+        packed = on_chip((rows * (T + 3) + T + C + 3,), jnp.int32)
+    args = (params, (pool, pool), (conv, matrix),
+            on_chip((rows,), jnp.int32), packed)
+    if traced:
+        return jax.make_jaxpr(fn)(*args)
+    return fn.lower(*args).compile()
 
 
 @pytest.mark.parametrize("which", ["step", "chunk"])
@@ -1179,6 +1186,73 @@ def test_olmo_programs_fit_and_move_no_pool(olmo_cell, which):
         assert olmo_hybrid_trace.label_of(line, marks) == "delta_step"
     # the one-token attention kernel at 3,840 stored lanes, once a layer
     assert len(_kernel_calls(text)) == cfg.n_attention
+
+
+def test_olmo_step_chunk_fits_moves_no_pool_and_reads_weights_once(
+        olmo_cell):
+    """What ISSUE 58 bought: the delta rule takes a window in two parts,
+    so the pass that holds a chunk and decoding rows is ONE program for
+    this layout too.  Compiled for the described chip at the cell's
+    shapes it fits, moves neither K/V pool nor the state pool (which has
+    TWO readers a layer now: the one-token kernel, then the slice of the
+    chunk's row, taken from the pool the kernel left), names every
+    matrix of every layer in ONE product ``rows + chunk`` lanes wide,
+    and runs the recurrence a part at a time: one ``delta_step`` a
+    linear layer on the pool as stored, one ``delta_window`` on the
+    chunk row's own state."""
+    from chipbench import olmo_hybrid_trace
+    from ray_tpu.inference.recurrent import has_step_chunk
+    cfg, _, params, _, lay, _, matrix, engine = olmo_cell
+    assert has_step_chunk(cfg)
+    rows, C = engine["max_slots"], engine["prefill_chunk"]
+    compiled = _olmo_program(olmo_cell, "step_chunk")
+    _assert_pool_stays_put(compiled, lay)
+    _assert_state_stays_put(compiled, matrix)
+    _assert_token_array_donated(compiled, rows)
+    text = compiled.as_text()
+    state = "f32[" + ",".join(map(str, matrix.shape)) + "]"
+    marks = olmo_hybrid_trace.marks_of(cfg_keys(cfg), rows, C)
+    steps = _kernel_calls(text, "delta_step")
+    assert len(steps) == cfg.n_linear == 12
+    for line in steps:
+        result, operands = line.split(" custom-call(", 1)
+        assert state in result and state in operands
+        assert olmo_hybrid_trace.label_of(line, marks) == "delta_step"
+    windows = _kernel_calls(text, "delta_window")
+    assert len(windows) == cfg.n_linear
+    for line in windows:
+        assert state not in line and "f32[1,30,96,192]" in line
+        assert olmo_hybrid_trace.label_of(line, marks) \
+            == "mixer_linear_attention"
+    # both forms of attention a full layer: the rows walk their tables
+    # in the one-token kernel, the chunk's queries their row's key blocks
+    assert len(_kernel_calls(text)) == cfg.n_attention == 4
+    assert len(_kernel_calls(text, "head_window_attention")) == 4
+    # as traced: every matrix of every layer is the operand of ONE
+    # equation (the parameters are the program's first inputs)
+    program = _olmo_program(olmo_cell, "step_chunk",
+                            traced=True).jaxpr.eqns[0].params["jaxpr"].jaxpr
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    matrices = 0
+    for (path, leaf), var in zip(leaves, program.invars):
+        where = jax.tree_util.keystr(path)
+        if "layers" in where and leaf.ndim >= 2 and "conv_w" not in where:
+            matrices += 1
+            assert sum(var in eqn.invars for eqn in program.eqns) == 1, where
+    assert matrices == 12 * 6 + 4 * 4       # wqkv wg wab wo | wqkv wo, MLP
+    # the projections are as wide as the whole window, evaluated once a
+    # layer (``wqkv``'s product has consumers in both parts)
+    w, d = rows + C, cfg.d_model
+    wide = 2 * cfg.lin_heads * cfg.lin_key_dim \
+        + cfg.lin_heads * cfg.lin_value_dim
+    assert wide == 11520
+    products = [line for line in re.findall(
+        rf"^\s*%\S+ = bf16\[(?:1,)?{w},{wide}\]\S* (?:dot|convolution)"
+        rf"\(.*$", text, re.M) if "mixer_linear_proj" in line]
+    assert len(products) == cfg.n_linear, len(products)
+    for part in (rows, C):
+        assert not re.findall(
+            rf"bf16\[(?:1,)?{part},{d}\]\S* (?:dot|convolution)\(", text)
 
 
 def cfg_keys(cfg):

@@ -7,10 +7,10 @@ the step's own integers.  ``eng._step_chunk = None`` is the only switch
 there is: the engine then takes the pass of two programs.  Since ISSUE 46
 the hybrid family has the program too (``recurrent.
 make_recurrent_step_chunk``) for the layouts whose every sublayer kind
-takes a window in two parts, Mamba-2's recurrent state among them; a
-latent, delta-rule or head-by-head attention sublayer keeps the pass of
-two programs (``recurrent.has_step_chunk``, derived from the sublayer
-kinds and the pool's layout).
+takes a window in two parts, Mamba-2's recurrent state among them and,
+since ISSUE 58, the delta rule's matrix state; a latent or
+window-attention sublayer keeps the pass of two programs
+(``recurrent.has_step_chunk``, derived from the sublayer kinds).
 
 Tiny CPU models at f32 (greedy parity must not hinge on bf16 ties), both
 seams where the case applies."""
@@ -182,9 +182,20 @@ def _no_experts():
     return hybrid.HybridConfig.tiny(dense_layers=3, dense_width=32)
 
 
+def _olmo_like():
+    """Two delta-rule layers and a full-attention one, each followed by
+    the dense MLP, every sublayer's norm on its output, an untied
+    head."""
+    return hybrid.HybridConfig.tiny(
+        layer_types=(hybrid.LINEAR, hybrid.LINEAR, hybrid.ATTENTION),
+        lin_heads=2, lin_key_dim=8, lin_value_dim=16, dense_layers=3,
+        dense_width=32, norm_output=True, qk_norm=True, tied_head=False)
+
+
 HYBRID_LAYOUTS = {"granite_like": hybrid.HybridConfig.tiny,
                   "nemotron_like": _nemotron_like,
-                  "no_experts": _no_experts}
+                  "no_experts": _no_experts,
+                  "olmo_like": _olmo_like}
 # (the step's active rows, the chunk's decode row, its real tokens)
 WINDOWS = {"partial_chunk": ([1, 1, 0, 1], 2, 5),
            "whole_chunk": ([1, 0, 0, 1], 1, C),
@@ -204,7 +215,8 @@ def test_hybrid_program_gives_what_the_two_give(layout, window):
     the same pools, state and packed inputs: the active rows' logits
     and greedy tokens, the chunk's last real position's, the experts'
     load of each part, the K/V pools (the scratch block aside) and every
-    row's convolution and SSM state, within float32 tolerance.  An
+    row's convolution and SSM (or matrix) state, within float32
+    tolerance.  An
     inactive row's logits are nobody's: the two programs hand the
     chunk's row to the step with the chunk's state, the fused one with
     the state before it."""
@@ -290,17 +302,19 @@ def test_hybrid_program_gives_what_the_two_give(layout, window):
      dict(dense_layers=2, dense_width=32), True),
     ((hybrid.MAMBA, hybrid.ATTENTION, hybrid.MAMBA),
      dict(experts_in_every_layer=False), True),
-    ((hybrid.LINEAR, hybrid.ATTENTION), {}, False),
-    ((hybrid.LATENT,), {}, False)],
+    ((hybrid.LINEAR, hybrid.ATTENTION), {}, True),
+    ((hybrid.LATENT,), {}, False),
+    ((hybrid.WINDOW, hybrid.ATTENTION), {}, False)],
     ids=["mamba_attention", "attention_alone", "dense_mlp_no_experts",
-         "mixers_alone", "linear_attention", "latent"])
+         "mixers_alone", "linear_attention", "latent", "window_attention"])
 def test_which_layouts_get_the_program_follows_from_the_sublayer_kinds(
         kinds, kw, fused):
-    """Every sublayer kind must take a window in two parts: Mamba-2,
-    attention over K/V blocks, routed experts and the dense MLP do, with
-    or without an experts sublayer among them; a delta-rule or a latent
-    sublayer keeps the pass of two programs.  No engine option and no
-    model name says so.  ``warm_up`` brings up and runs what there is."""
+    """Every sublayer kind must take a window in two parts: Mamba-2, the
+    delta rule, attention over K/V blocks, routed experts and the dense
+    MLP do, with or without an experts sublayer among them; a latent or
+    a window-attention sublayer keeps the pass of two programs.  No
+    engine option and no model name says so.  ``warm_up`` brings up and
+    runs what there is."""
     kw = dict(layer_types=kinds, **kw)
     if hybrid.LINEAR in kinds:
         kw.update(lin_heads=2, lin_key_dim=8, lin_value_dim=16,
@@ -308,6 +322,8 @@ def test_which_layouts_get_the_program_follows_from_the_sublayer_kinds(
     if hybrid.LATENT in kinds:
         kw.update(q_rank=16, kv_rank=32, rope_dim=8, v_head_dim=16,
                   n_kv_heads=1, yarn=hybrid.Yarn())
+    if hybrid.WINDOW in kinds:
+        kw.update(window=16, rope_theta=10000.0)
     cfg = hybrid.HybridConfig.tiny(**kw)
     eng = InferenceEngine(
         hybrid.init_params(cfg, jax.random.PRNGKey(0)), cfg,

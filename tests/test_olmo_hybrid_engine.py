@@ -218,7 +218,8 @@ def test_engine_derives_what_a_recurrent_state_forbids(cfg, params):
     try:
         assert eng.trie is None                 # no radix index
         assert eng.pool.state is not None and eng.pool.v is not None
-        assert eng._step_chunk is None and eng._prefill is None
+        # since ISSUE 58 the delta rule takes a window in two parts
+        assert eng._step_chunk is not None and eng._prefill is None
         st = eng.stats()
         assert st["state_bytes"] == eng.pool.state.bytes_total() > 0
     finally:
@@ -253,6 +254,50 @@ def test_engine_rows_admitted_at_different_times(cfg, params):
         n for n, _ in plan)
     assert st["linear_state_rows_advanced"] == sum(m - 1 for _, m in plan)
     assert st["prefix_hit_tokens"] == 0 and st["state_rows_in_use"] == 0
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_engine_chunks_ride_the_steps_of_a_long_answer(cfg, params, fused):
+    """One long answer decodes while prompts of one to three chunks
+    (partial last ones among them) wait for its rows, admitted as rows
+    come free: the last chunk of every pass rides the step as ONE
+    program, the rows' matrix state advanced in the pool and the chunk
+    row's behind it, and every stream is still the reference's argmax;
+    without the program (``_step_chunk = None``, the only switch) the
+    same streams."""
+    eng = _engine(cfg, params, max_slots=4, n_blocks=40)
+    if not fused:
+        eng._step_chunk = None
+    rng = np.random.default_rng(5)
+    plan = [(19, 6), (33, 4), (16, 5), (3, 7), (41, 3)]
+    prompts = [rng.integers(0, 256, n).tolist() for n, _ in plan]
+    long_ = rng.integers(0, 256, 6).tolist()
+    try:
+        first = eng.submit(long_, max_new=80)
+        it = first.stream(timeout=300)
+        head = [next(it) for _ in range(2)]           # it is decoding now
+        reqs = [eng.submit(p, max_new=m) for p, (_, m) in zip(prompts, plan)]
+        outs = [r.result(timeout=300) for r in reqs]
+        assert not first.done                         # ... and still is
+        whole = head + list(it)
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    assert len(whole) == 80
+    assert _margins(params, long_, whole).max() <= ATOL
+    for p, o, (_, m) in zip(prompts, outs, plan):
+        assert len(o) == m
+        assert _margins(params, p, o).max() <= ATOL
+    chunks = sum(-(-n // 16) for n, _ in plan) + 1
+    assert st["chunk_passes"] == chunks
+    # every prompt but the first met a decoding row: its last chunk rode
+    assert (st["chunks_in_step"] >= len(plan)) if fused \
+        else st["chunks_in_step"] == 0
+    assert st["linear_chunk_tokens"] == st["prefill_tokens"] == len(
+        long_) + sum(n for n, _ in plan)
+    assert st["linear_state_rows_advanced"] == 79 + sum(
+        m - 1 for _, m in plan)
+    assert st["tokens_greedy_on_device"] == st["generated_tokens"]
 
 
 def test_engine_preempted_row_reprefills_to_the_same_logits(cfg, params):
