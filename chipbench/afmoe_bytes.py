@@ -32,6 +32,7 @@ from __future__ import annotations
 from chipbench.deepseek_v2_bytes import (least_seconds,      # noqa: F401
                                          per_chunk, per_decode)
 # held experts touched a decode pass, from the engine's counters
+from chipbench.hybrid_bytes import traced                    # noqa: F401
 from chipbench.nemotron_bytes import touched_per_decode      # noqa: F401
 
 BF16, F32 = 2, 4
@@ -87,14 +88,3 @@ def gated_expert_bytes_per_decode(published: dict,
     n = expert_layers(published)
     return BF16 * (touched_per_pass * one + n * (shared + d * e)) \
         + F32 * n * e
-
-
-def traced(obs: dict) -> dict:
-    """``obs`` with the counters of the TRACED seconds in place of the
-    whole window's, where the kind took them (``traced_counters``): the
-    work a roofline share credits is then that of the passes whose time
-    it divides by.  With prompts of 128 to 32,768 tokens in one queue
-    the keys a pass attends in 4 s of 51 are not the window's mean."""
-    return {**obs, "counters": obs.get("traced_counters")
-            or obs.get("counters")}
-

@@ -1,6 +1,7 @@
 """Operations the algorithm needs, from shapes (kept with the benchmark).
 
-``train_flops_per_token`` is the arithmetic of ``bench.py``: 6 per
+``train_flops_per_token`` is the usual model-FLOPs count (the repo's
+first training bench had it; that file is gone since PR 47): 6 per
 parameter per token for the matrix multiplications of forward and
 backward, plus the attention score and value products, 12 * L * d * s.
 Recomputed operations (remat) do not count.

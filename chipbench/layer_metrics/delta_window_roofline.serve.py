@@ -1,6 +1,7 @@
 """Share of its roofline the window form of the delta rule reaches in a
-program that holds a chunk (%): ``delta_prefill_roofline.serve``'s least
-time (``olmo_hybrid_bytes.window_work`` of the engine's
+program that holds a chunk (%): the least time it could take, the
+larger of its products over the peak arithmetic and its bytes over the
+peak bandwidth (``olmo_hybrid_bytes.window_work`` of the engine's
 ``linear_chunk_tokens`` over the window's chunk passes, which count a
 riding chunk too) over the traced time of
 ``delta_window_ms_per_chunk.serve``, the chunk program's runs and the

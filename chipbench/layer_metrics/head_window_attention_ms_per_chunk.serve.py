@@ -2,9 +2,8 @@
 holds a chunk of the ``olmo_hybrid`` layout (ms): self time of the ops
 ``olmo_hybrid_trace`` labels ``window_attention`` inside ``jit_chunk_fn``
 AND ``jit_step_chunk`` runs, over their count
-(``chipbench/olmo_hybrid_chunks.py``).  What
-``window_attention_ms_per_chunk.serve`` read while every chunk had a
-program of its own."""
+(``chipbench/olmo_hybrid_chunks.py``): one Pallas kernel a key block of
+1,024 keys, a grid step a head (``ops/attention.head_window_attention``)."""
 
 from chipbench import olmo_hybrid_chunks as c
 from chipbench import olmo_hybrid_trace as t

@@ -1,6 +1,8 @@
 """Share of its roofline the head-wise window attention reaches in a
-program that holds a chunk (%): ``window_attention_roofline.serve``'s
-least time (``olmo_hybrid_bytes.attention_work`` from the engine's
+program that holds a chunk (%): the least time it could take, the
+larger of the causal pairs' products over the peak arithmetic and the
+reached keys' bytes over the peak bandwidth
+(``olmo_hybrid_bytes.attention_work`` from the engine's
 ``chunk_query_keys`` and ``chunk_keys`` over the window's chunk passes,
 which count a riding chunk too) over the traced time of
 ``head_window_attention_ms_per_chunk.serve``."""

@@ -14,7 +14,8 @@ that really advance.  The zero columns ``w_in`` is stored with (1856 ->
 
 from __future__ import annotations
 
-from chipbench.hybrid_bytes import BF16, F32, mean_active_rows  # noqa: F401
+from chipbench.hybrid_bytes import (BF16, F32,            # noqa: F401
+                                   mean_active_rows, touched_per_decode)
 
 
 def relu2_expert_bytes_per_decode(published: dict, expert_layers: int,
@@ -42,12 +43,3 @@ def grouped_ssm_state_bytes_per_decode(published: dict,
         :published["num_hidden_layers"]]
     per_row = pattern.count("M") * (h * p * n * F32 + conv * BF16)
     return 2.0 * active_rows * per_row
-
-
-def touched_per_decode(obs: dict):
-    """Held experts touched a decode pass, summed over expert layers,
-    from the engine's counters over the window, or None."""
-    c = obs.get("counters") or {}
-    if not c.get("decode_iterations") or "expert_touched_held_decode" not in c:
-        return None
-    return c["expert_touched_held_decode"] / c["decode_iterations"]

@@ -1,7 +1,7 @@
 """Operations and bytes the ``olmo_hybrid`` layout's mechanisms must do,
 from shapes and the window's counters (the roofline shares of
-``layer_metrics/delta_step_roofline.serve.py``, ``delta_prefill_
-roofline.serve.py`` and ``window_attention_roofline.serve.py`` divide
+``layer_metrics/delta_step_roofline.serve.py``, ``delta_window_
+roofline.serve.py`` and ``head_window_attention_roofline.serve.py`` divide
 the least time they take at ``peaks.json``'s rates by the traced time).
 
 Only what the ALGORITHM needs is counted, whatever implements it:

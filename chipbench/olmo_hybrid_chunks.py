@@ -5,11 +5,11 @@ Since the delta rule has its two-part form (``ray_tpu/models/hybrid.py``
 ``jit_step_chunk`` runs the window form of the delta rule and the
 head-wise window attention over the chunk's lanes beside the rows' one
 token, and ``jit_chunk_fn`` is left with the chunks no row waits behind.
-The readers that name ``jit_chunk_fn`` alone lose sight of the kernels
+A reader that names ``jit_chunk_fn`` alone loses sight of the kernels
 then; these sum a label over BOTH programs' runs, by
 ``olmo_hybrid_trace``'s table as ``obs["scoped"]`` holds it.  A program
 without the fused one (a parent commit) is read through ``jit_chunk_fn``
-alone and gives what ``delta_prefill_*`` / ``window_attention_*`` give.
+alone.
 
 In the fused program ``mixer_linear_attention`` also holds the decode
 rows' convolution and gated norm (their kernel is ``delta_step``, another
@@ -22,7 +22,6 @@ from __future__ import annotations
 # ray_tpu/inference/decode.py, recurrent.py: a prefill window alone, and
 # one together with the pass's decode rows
 CHUNK_PROGRAMS = ("jit_chunk_fn", "jit_step_chunk")
-FUSED = "jit_step_chunk"
 
 
 def ms_per_chunk(obs: dict, labels: tuple):

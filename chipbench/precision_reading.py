@@ -2,7 +2,7 @@
 
     python3 chipbench/precision_reading.py <config> [seed ...]   (on the chip)
 
-``<config>`` is ``granite-4.0-h-small-10L-e36`` (mix ``chat2k-r80``),
+``<config>`` is ``granite-4.0-h-small-10L-e36`` (mix ``chat2k-r50``),
 ``gpt2-xl`` (mix ``chat-r80-v2``) or ``gpt2-124m`` (the training cell:
 the loss on its first batch, see ``loss_readings``).  The plain reference computed in the
 nearest precision BELOW the one the configuration states (float8 e4m3

@@ -60,6 +60,9 @@ def main() -> int:
                 "failed": line["failed"], "process_s": round(took, 1),
                 "metrics": {k: v["value"] for k, v in
                             line["metrics"].items()},
+                "untraced": line["notes"].get("untraced_per_layer", {}),
+                "compiles_in_window": line["notes"].get(
+                    "compiles_in_window"),
                 "peak_gb": line["device"]["memory_peak_bytes"] / 1e9,
                 "checks": {k: v["value"] for k, v in
                            line["checks"].items()},

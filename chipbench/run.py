@@ -7,7 +7,10 @@ outputs, and prints as the LAST line of its standard output one JSON
 object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``
 and, with ``--trace 1``, ``breakdown``.  With ``--trace 0`` the metrics
 are the cell's end-to-end metrics, with ``--trace 1`` its per-layer
-metrics.
+metrics.  An untraced line's ``notes.untraced_per_layer`` also holds the
+cell's per-layer metrics whose reader says ``UNTRACED = True`` (it reads
+what the program keeps with no profiler on): for ``sweep.py`` and
+``spreads.py``, not for the driver.
 
 Everything that belongs to one cell is found by name, nothing is listed
 here: the cell's entry in ``BENCHMARK.json`` names its configuration
@@ -161,18 +164,23 @@ def main() -> int:
         log(f"traffic kind {mix['kind']!r} gave no value for {missing}")
         return 1
     summary = res["obs"].get("trace")
-    metrics = {}
-    if args.trace:
-        obs = {**res["obs"], "end_to_end": values, "peaks": peaks.get(kind),
-               "config": config, "mix": mix}
-        from chipbench.readers import load_reader
-        for m in metrics_of_cell(bench, "per_layer", cell["name"]):
-            value = load_reader(m["name"]).read(obs)
+    obs = {**res["obs"], "end_to_end": values, "peaks": peaks.get(kind),
+           "config": config, "mix": mix}
+    from chipbench.readers import load_reader
+    # a traced run reads every per-layer metric of the cell; an untraced
+    # one those whose reader says ``UNTRACED`` (it reads what the program
+    # keeps with no profiler on), into the notes: the line's metrics are
+    # the end-to-end ones
+    read = {}
+    for m in metrics_of_cell(bench, "per_layer", cell["name"]):
+        reader = load_reader(m["name"])
+        if args.trace or getattr(reader, "UNTRACED", False):
+            value = reader.read(obs)
             if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    else:
-        metrics = {m["name"]: {"value": values[m["name"]],
-                               "unit": m["unit"]} for m in declared_e2e}
+                read[m["name"]] = {"value": value, "unit": m["unit"]}
+    metrics = read if args.trace else {
+        m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
+        for m in declared_e2e}
 
     line = {"correct": bool(res["correct"]) and not args.rehearse,
             "attempted": res["attempted"], "failed": res["failed"],
@@ -180,6 +188,8 @@ def main() -> int:
             "device": device_report(devs, summary, res["memory_peak_bytes"]),
             "notes": {**res.get("notes", {}),
                       "mix_override": args.mix_override,
+                      "untraced_per_layer": {} if args.trace else {
+                          k: v["value"] for k, v in read.items()},
                       "memory_stats": devs[0].memory_stats()}}
     if args.rehearse:
         line["rehearsal_metrics_not_device_numbers"] = metrics

@@ -66,37 +66,73 @@ def test_cell_finds_its_files_readers_and_traffic(cell):
         assert all(0 <= t < vocab for r in reqs for t in r["prompt"])
 
 
-def test_retired_cell_is_gone_and_the_new_one_reports_what_it_did():
-    assert RETIRED not in {w["name"] for w in BENCH["workloads"]}
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
-        assert RETIRED not in m.get("workloads", [])
-    assert not os.path.exists(os.path.join(ROOT, "chipbench", "traffic",
-                                           "chat-r80.json"))
-    new = next(w for w in BENCH["workloads"] if w["name"] == NEW)
-    assert (new["config"], new["traffic"], new["chips"]) == (
-        "gpt2-xl", "chat-r80-v2", 1)
-    assert [w["config"] for w in BENCH["workloads"]].count("gpt2-xl") == 1
-    names = {m["name"] for m in BENCH["per_layer"]
-             if NEW in m.get("workloads", [])}
-    # every per-layer metric the old cell had (PR 35's BENCHMARK.json)
-    assert names == {
+# a cell re-based at a newly swept knee: (the retired name and mix, the
+# new name, config and mix, the per-layer metrics the old cell had and
+# the new one still reports, the mix keys that carried over)
+REBASED = {
+    # PR 37
+    NEW: (RETIRED, "chat-r80", "gpt2-xl", "chat-r80-v2", {
         "device_idle_share.serve", "decode_step_ms.serve",
         "batch_occupancy.serve", "prefix_hit_rate.serve",
         "queue_wait_p90_ms.serve", "prefill_p90_ms.serve",
         "front_overhead_p90_ms.serve", "decode_pass_ms.serve",
         "prefill_pass_share.serve", "engine_host_ms_per_pass.serve",
-        "decode_program_ms.serve", "chunk_program_ms.serve"}
-    mix = load("chipbench", "traffic", "chat-r80-v2.json")
+        "decode_program_ms.serve", "chunk_program_ms.serve"}, {
+        "prompt_len": {"lo": 32, "hi": 768, "median": 200, "sigma": 0.8},
+        "output_len": {"lo": 16, "hi": 128, "median": 64, "sigma": 0.7},
+        "shared_heads": {"n": 4, "len": 128, "share": 0.5, "zipf_a": 1.0},
+        "max_total": 1024, "order_seed": 0, "checked_requests": 4,
+        "trace_s": 4.0}),
+    # PR 59: the rate alone moved, off the edge the p95's rank lay on
+    "serve-granite-h-chat2k-r50": (
+        "serve-granite-h-chat2k-r80", "chat2k-r80",
+        "granite-4.0-h-small-10L-e36", "chat2k-r50", {
+            "device_idle_share.serve", "decode_step_ms.serve",
+            "batch_occupancy.serve", "decode_program_ms.serve",
+            "expert_ms_per_decode.serve", "ssm_ms_per_decode.serve",
+            "expert_roofline_share.serve",
+            "ssm_update_roofline_share.serve",
+            "expert_load_max_over_mean.serve", "state_rows_share.serve",
+            "step_chunk_pass_ms.serve", "step_pass_ms.serve",
+            "engine_gap_p95_ms.serve", "front_gap_p95_ms.serve",
+            "chunk_key_blocks_per_chunk.serve",
+            "step_chunk_program_ms.serve", "chunk_pass_gap_share.serve"}, {
+            "kind": "open_loop_http_recurrent",
+            "prompt_len": {"lo": 32, "hi": 2048, "median": 256,
+                           "sigma": 0.9},
+            "output_len": {"lo": 32, "hi": 256, "median": 96,
+                           "sigma": 0.7},
+            "shared_heads": {"n": 0, "len": 0, "share": 0.0,
+                             "zipf_a": 1.0},
+            "max_total": 2304, "lead_s": 30, "drain_s": 70,
+            "order_seed": 0, "checked_requests": 4, "trace_s": 4.0,
+            "tie_tolerance": 0.003}),
+}
+
+
+@pytest.mark.parametrize("new", sorted(REBASED))
+def test_retired_cell_is_gone_and_the_new_one_reports_what_it_did(new):
+    retired, old_mix, config, traffic, kept, carried = REBASED[new]
+    assert retired not in {w["name"] for w in BENCH["workloads"]}
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert retired not in m.get("workloads", [])
+    assert not os.path.exists(os.path.join(ROOT, "chipbench", "traffic",
+                                           old_mix + ".json"))
+    cell = next(w for w in BENCH["workloads"] if w["name"] == new)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        config, traffic, 1)
+    assert [w["config"] for w in BENCH["workloads"]].count(config) == 1
+    names = {m["name"] for m in BENCH["per_layer"]
+             if new in m.get("workloads", [])}
+    assert kept <= names, kept - names
+    assert {m["name"] for m in BENCH["end_to_end"]
+            if new in m.get("workloads", [new])} == {
+        "ttft_p90_ms", "itl_p95_ms", "serve_tokens_per_s", "setup_s"}
+    mix = load("chipbench", "traffic", traffic + ".json")
     assert round(mix["rate_per_s"] * BENCH["run_seconds"]) >= 120
-    # the chat mix as the retired file had it
-    assert mix["prompt_len"] == {"lo": 32, "hi": 768, "median": 200,
-                                 "sigma": 0.8}
-    assert mix["output_len"] == {"lo": 16, "hi": 128, "median": 64,
-                                 "sigma": 0.7}
-    assert mix["shared_heads"] == {"n": 4, "len": 128, "share": 0.5,
-                                   "zipf_a": 1.0}
-    assert (mix["max_total"], mix["order_seed"], mix["checked_requests"],
-            mix["trace_s"]) == (1024, 0, 4, 4.0)
+    # the mix as the retired file had it, but for the rate and its note
+    assert {k: mix[k] for k in carried} == carried
+    assert mix["rate_note"] and str(mix["rate_per_s"]) in cell["why"]
 
 
 def test_bounds_follow_the_contract():
@@ -197,7 +233,7 @@ sound = engine._KVOnly.greedy
 engine._KVOnly.greedy = staticmethod(
     lambda eng, logits: altered(sound(eng, logits), logits.shape[-1]))
 """,
-    "serve-granite-h-chat2k-r80": """
+    "serve-granite-h-chat2k-r50": """
 sound = engine._KVAndState.greedy
 engine._KVAndState.greedy = staticmethod(
     lambda eng, logits: altered(sound(eng, logits), logits.shape[-1]))

@@ -25,6 +25,7 @@ sys.path.insert(0, ROOT)
 
 from chipbench import lfm2_bytes, lfm2_trace                   # noqa: E402
 from chipbench.readers import load_reader                     # noqa: E402
+from chipbench.tests import by_name                           # noqa: E402
 
 CELL = "serve-lfm2-agent4k-r80"
 CONFIG = "lfm2-8b-a1b-12L"
@@ -46,22 +47,18 @@ def test_cell_is_found_by_name_with_its_files():
     bench = load("BENCHMARK.json")
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
-    assert bench["workloads"][-1] is cell            # appended, not inserted
     config = next(c for c in bench["configs"] if c["name"] == cell["config"])
     assert config["file"] == f"chipbench/configs/{CONFIG}.json"
     assert config["reduced"] == ["num_hidden_layers"]
     mix = load("chipbench", "traffic", cell["traffic"] + ".json")
     assert os.path.exists(os.path.join(
         ROOT, "chipbench", "traffic", mix["kind"] + ".py"))
-    per_layer = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"][-len(NEW):]] == list(NEW)
-    for name, moves in NEW.items():
-        m = per_layer[name]
-        assert m["workloads"] == [CELL] and m["moves"] == moves
+    # by name: the cell may be listed under more, a metric may list more
+    by_name.check_listed(bench, CELL, NEW)
+    for name in NEW:
         assert load_reader(name).read({}) is None    # a parent: nothing
-    for m in bench["end_to_end"]:
-        if m["name"] in ("ttft_p90_ms", "itl_p95_ms", "serve_tokens_per_s"):
-            assert m["workloads"][-1] == CELL
+    assert set(by_name.metrics_of(bench, CELL, "end_to_end")) == {
+        "ttft_p90_ms", "itl_p95_ms", "serve_tokens_per_s", "setup_s"}
 
 
 def test_configuration_is_the_catalog_row_cut_in_depth_alone():

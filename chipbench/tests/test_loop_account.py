@@ -25,7 +25,7 @@ METRICS = ("loop_host_ms_per_pass.serve", "device_starved_share.serve",
            "loop_unaccounted_share.serve")
 PHASES = ("parked", "admit", "grow", "prefill_host", "pack", "dispatch",
           "wait", "emit")
-CELLS = ("serve-xl-chat-r80-v2", "serve-granite-h-chat2k-r80",
+CELLS = ("serve-xl-chat-r80-v2", "serve-granite-h-chat2k-r50",
          "serve-nemotron3-nano-reason1k-r80")
 
 
@@ -196,17 +196,17 @@ def test_no_account_no_window_no_metric(name):
 
 
 def test_benchmark_json_gives_the_five_to_the_three_serve_cells():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    tail = bench["per_layer"][-len(METRICS):]
-    assert tuple(m["name"] for m in tail) == METRICS
-    engine_layer = next(m for m in bench["per_layer"] if m["name"]
-                        == "engine_host_ms_per_pass.serve")
-    for m in tail:
-        assert m["workloads"] == list(CELLS)
-        assert {k: m[k] for k in ("layer", "source", "moves", "better")} \
-            == {k: engine_layer[k] for k in ("layer", "source", "moves",
-                                             "better")}
+    """By name, wherever in ``per_layer`` they stand and whichever cells
+    they have come to list since."""
+    from chipbench.tests import by_name
+    bench = by_name.bench()
+    engine_layer = by_name.metric(bench, "engine_host_ms_per_pass.serve")
+    for cell in CELLS:
+        by_name.check_listed(
+            bench, cell, METRICS,
+            **{k: engine_layer[k] for k in ("layer", "source", "moves")})
+    for name in METRICS:
+        assert by_name.metric(bench, name)["better"] == "lower"
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -21,6 +21,7 @@ sys.path.insert(0, ROOT)
 
 from chipbench import nemotron_bytes, nemotron_trace      # noqa: E402
 from chipbench.readers import load_reader                 # noqa: E402
+from chipbench.tests import by_name                       # noqa: E402
 
 CELL = "serve-nemotron3-nano-reason1k-r80"
 CONFIG = "nemotron-3-nano-30b-a3b-13L-e64"
@@ -33,11 +34,7 @@ SHAPE_FREE = (
     "prefill_p90_ms.serve", "front_overhead_p90_ms.serve",
     "decode_pass_ms.serve", "prefill_pass_share.serve",
     "engine_host_ms_per_pass.serve", "decode_program_ms.serve",
-    "chunk_program_ms.serve")
-# ``expert_load_max_over_mean.serve`` and ``state_rows_share.serve`` would
-# read here too, but ``test_recurrent_cell.py`` (an accepted file, not
-# this PR's to edit) holds their ``workloads`` to the first hybrid cell
-# alone: the counters they read are in this cell's ``notes``.
+    "step_chunk_program_ms.serve")
 
 
 def load(*parts):
@@ -70,13 +67,16 @@ def test_cell_is_found_by_name_with_its_files():
            if "workloads" not in m or CELL in m["workloads"]}
     assert e2e == {"ttft_p90_ms", "itl_p95_ms", "serve_tokens_per_s",
                    "setup_s"}
-    per_layer = {m["name"] for m in bench["per_layer"]
-                 if CELL in m.get("workloads", [CELL])}
-    assert per_layer == set(NEW) | set(SHAPE_FREE)
-    for m in bench["per_layer"]:
-        if m["name"] in NEW:
-            assert m["workloads"] == [CELL] and m["moves"] == "itl_p95_ms"
-            assert callable(load_reader(m["name"]).read)
+    # by name: the cell may be listed under more, a metric may list more
+    by_name.check_listed(bench, CELL, NEW, moves="itl_p95_ms")
+    by_name.check_listed(bench, CELL, SHAPE_FREE)
+    for name in NEW:
+        assert callable(load_reader(name).read)
+    # no lone chunk program runs in this cell's traced seconds since its
+    # chunks ride the step (PR 46): the metric that reads one does not
+    # list the cell (PR 59), the fused program's does
+    assert CELL not in by_name.metric(bench, "chunk_program_ms.serve")[
+        "workloads"]
     # traffic as the issue gives it; enough requests for a tail
     assert mix["prompt_len"] == {"lo": 64, "hi": 1024, "median": 256,
                                  "sigma": 0.8}

@@ -22,22 +22,26 @@ sys.path.insert(0, ROOT)
 
 from chipbench import olmo_hybrid_bytes, olmo_hybrid_trace    # noqa: E402
 from chipbench.readers import load_reader                     # noqa: E402
+from chipbench.tests import by_name                           # noqa: E402
 
 CELL = "serve-olmo-hybrid-doc3k-r80"
 CONFIG = "olmo-hybrid-7b-16L"
 NEW = {"delta_step_ms_per_decode.serve": "itl_p95_ms",
        "delta_step_roofline.serve": "itl_p95_ms",
-       "delta_prefill_ms_per_chunk.serve": "ttft_p90_ms",
-       "delta_prefill_roofline.serve": "ttft_p90_ms",
-       "window_attention_ms_per_chunk.serve": "ttft_p90_ms",
-       "window_attention_roofline.serve": "ttft_p90_ms"}
+       "delta_window_ms_per_chunk.serve": "ttft_p90_ms",
+       "delta_window_roofline.serve": "ttft_p90_ms",
+       "head_window_attention_ms_per_chunk.serve": "ttft_p90_ms",
+       "head_window_attention_roofline.serve": "ttft_p90_ms"}
+# (until PR 59 the four chunk-side ones were ``delta_prefill_*`` and
+# ``window_attention_*``, which read ``jit_chunk_fn`` alone and fell
+# silent when PR 58 let the chunks ride the step)
 SHAPE_FREE = (
     "device_idle_share.serve", "decode_step_ms.serve",
     "batch_occupancy.serve", "queue_wait_p90_ms.serve",
     "prefill_p90_ms.serve", "front_overhead_p90_ms.serve",
     "decode_pass_ms.serve", "prefill_pass_share.serve",
     "engine_host_ms_per_pass.serve", "decode_program_ms.serve",
-    "chunk_program_ms.serve", "loop_host_ms_per_pass.serve",
+    "step_chunk_program_ms.serve", "loop_host_ms_per_pass.serve",
     "device_starved_share.serve", "block_hunt_ms_per_pass.serve",
     "emit_ms_per_pass.serve", "loop_unaccounted_share.serve")
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
@@ -50,7 +54,6 @@ def load(*parts):
 
 def test_cell_is_found_by_name_with_its_files():
     bench = load("BENCHMARK.json")
-    assert len(bench["workloads"]) == 6
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
     assert (cell["config"], cell["traffic"]) == (CONFIG, "doc3k-r80")
@@ -70,14 +73,12 @@ def test_cell_is_found_by_name_with_its_files():
            if "workloads" not in m or CELL in m["workloads"]}
     assert e2e == {"ttft_p90_ms", "itl_p95_ms", "serve_tokens_per_s",
                    "setup_s"}
-    per_layer = {m["name"] for m in bench["per_layer"]
-                 if CELL in m.get("workloads", [CELL])}
-    assert per_layer == set(NEW) | set(SHAPE_FREE)
-    for m in bench["per_layer"]:
-        if m["name"] in NEW:
-            assert m["workloads"] == [CELL] and m["moves"] == NEW[m["name"]]
-            # a reader that finds nothing to read gives nothing
-            assert load_reader(m["name"]).read({}) is None
+    # by name: the cell may be listed under more, a metric may list more
+    by_name.check_listed(bench, CELL, NEW)
+    by_name.check_listed(bench, CELL, SHAPE_FREE)
+    for name in NEW:
+        # a reader that finds nothing to read gives nothing
+        assert load_reader(name).read({}) is None
     # the catalog's numbers, every one under its own key
     catalog = {"vocab_size": 100352, "hidden_size": 3840,
                "intermediate_size": 11008, "num_attention_heads": 30,
@@ -225,8 +226,8 @@ def test_labels_from_an_ops_text():
         "dense_mlp": 0.02}}, "jit_chunk_fn": {"runs": 2, "label_seconds": {
             "mixer_linear_attention": 0.03, "window_attention": 0.01}}}}
     assert load_reader("delta_step_ms_per_decode.serve").read(obs) == 1.5
-    assert load_reader("delta_prefill_ms_per_chunk.serve").read(obs) == 15.0
-    assert load_reader("window_attention_ms_per_chunk.serve").read(
+    assert load_reader("delta_window_ms_per_chunk.serve").read(obs) == 15.0
+    assert load_reader("head_window_attention_ms_per_chunk.serve").read(
         obs) == 5.0
     full = {**obs, "published": pub, "peaks": PEAKS,
             "counters": {"decode_iterations": 4,
@@ -236,8 +237,8 @@ def test_labels_from_an_ops_text():
     share = load_reader("delta_step_roofline.serve").read(full)
     assert abs(share - 100 * (12 * 10 * 2 * 2_280_960 / 819e9) / 1.5e-3) \
         < 1e-9
-    for name in ("delta_prefill_roofline.serve",
-                 "window_attention_roofline.serve"):
+    for name in ("delta_window_roofline.serve",
+                 "head_window_attention_roofline.serve"):
         assert 0 < load_reader(name).read(full) < 100
 
 
@@ -266,8 +267,9 @@ def test_the_six_metrics_read_a_decode_program_recorded_on_the_chip():
     assert abs(load_reader("delta_step_ms_per_decode.serve").read(obs)
                - want) < 1e-6
     # no chunk program in the record: its readers find nothing
-    assert load_reader("delta_prefill_ms_per_chunk.serve").read(obs) is None
-    assert load_reader("window_attention_roofline.serve").read(obs) is None
+    assert load_reader("delta_window_ms_per_chunk.serve").read(obs) is None
+    assert load_reader("head_window_attention_roofline.serve").read(
+        obs) is None
 
 
 def _rehearse(tmp_path, code=None):

@@ -19,21 +19,22 @@ sys.path.insert(0, ROOT)
 
 from chipbench import pass_ledger             # noqa: E402
 from chipbench.readers import load_reader     # noqa: E402
+from chipbench.tests import by_name           # noqa: E402
 from ray_tpu.util.tracing import Histogram    # noqa: E402
 
 MS = 1_000_000
 XL, GRANITE, NEMOTRON, DEEPSEEK, OLMO, TRINITY, LFM2 = (
-    "serve-xl-chat-r80-v2", "serve-granite-h-chat2k-r80",
+    "serve-xl-chat-r80-v2", "serve-granite-h-chat2k-r50",
     "serve-nemotron3-nano-reason1k-r80", "serve-deepseek-v2-docqa8k-r80",
     "serve-olmo-hybrid-doc3k-r80", "serve-trinity-large-mixlen32k-r80",
     "serve-lfm2-agent4k-r80")
 SERVING = [XL, GRANITE, NEMOTRON, DEEPSEEK, OLMO, TRINITY, LFM2]
-# metric -> (its cells, the end-to-end metric it moves)
+FUSED = [XL, GRANITE, NEMOTRON, LFM2, OLMO]     # olmo since PR 58
+# metric -> (cells it lists, beside whichever others; the end-to-end
+# metric it moves)
 METRICS = {
-    "step_chunk_pass_ms.serve": ([XL, GRANITE, NEMOTRON, LFM2],
-                                 "itl_p95_ms"),
-    "chunk_then_step_pass_ms.serve": ([DEEPSEEK, OLMO, TRINITY],
-                                      "itl_p95_ms"),
+    "step_chunk_pass_ms.serve": (FUSED, "itl_p95_ms"),
+    "chunk_then_step_pass_ms.serve": ([DEEPSEEK, TRINITY], "itl_p95_ms"),
     "step_pass_ms.serve": (SERVING, "itl_p95_ms"),
     "engine_gap_p95_ms.serve": (SERVING, "itl_p95_ms"),
     "front_gap_p95_ms.serve": (SERVING, "itl_p95_ms"),
@@ -42,13 +43,11 @@ METRICS = {
     "first_token_write_p90_ms.serve": (SERVING, "ttft_p90_ms"),
     "chunk_key_blocks_per_chunk.serve": (
         [GRANITE, NEMOTRON, OLMO, TRINITY, LFM2], "itl_p95_ms"),
-    "step_chunk_pass_host_ms.serve": ([XL, GRANITE, NEMOTRON, LFM2],
-                                      "itl_p95_ms"),
-    "step_chunk_pass_wait_ms.serve": ([XL, GRANITE, NEMOTRON, LFM2],
-                                      "itl_p95_ms"),
-    "chunk_then_step_pass_host_ms.serve": ([DEEPSEEK, OLMO, TRINITY],
+    "step_chunk_pass_host_ms.serve": (FUSED, "itl_p95_ms"),
+    "step_chunk_pass_wait_ms.serve": (FUSED, "itl_p95_ms"),
+    "chunk_then_step_pass_host_ms.serve": ([DEEPSEEK, TRINITY],
                                            "itl_p95_ms"),
-    "chunk_then_step_pass_wait_ms.serve": ([DEEPSEEK, OLMO, TRINITY],
+    "chunk_then_step_pass_wait_ms.serve": ([DEEPSEEK, TRINITY],
                                            "itl_p95_ms"),
 }
 FIELDS = ("count", "ns", "host_ns", "wait_ns", "tokens")
@@ -338,14 +337,93 @@ def test_the_report_prints_what_the_ledger_read():
     assert report(a_ring(keys=False), {})["kinds"] == {}
 
 
-def test_benchmark_json_ends_with_the_thirteen_and_each_has_its_reader():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    last = bench["per_layer"][-len(METRICS):]
-    assert [m["name"] for m in last] == list(METRICS)
-    for m in last:
-        cells, moves = METRICS[m["name"]]
-        assert m["workloads"] == cells and m["moves"] == moves
-        assert (m["source"], m["better"]) == ("program_counter", "lower")
-        assert m["unit"] == ("blocks" if "blocks" in m["name"] else "ms")
-        assert callable(load_reader(m["name"]).read)
+def test_benchmark_json_has_the_thirteen_by_name_each_with_its_reader():
+    bench = by_name.bench()
+    for name, (cells, moves) in METRICS.items():
+        for cell in cells:
+            by_name.check_listed(
+                bench, cell, [name], moves=moves, source="program_counter",
+                better="lower", unit="blocks" if "blocks" in name else "ms")
+        assert callable(load_reader(name).read)
+    # one pass a window runs a chunk and THEN a step at olmo since its
+    # chunks ride (PR 58): that kind's metrics do not list the cell
+    assert OLMO not in by_name.metric(
+        bench, "chunk_then_step_pass_ms.serve")["workloads"]
+
+
+SHARE = "chunk_pass_gap_share.serve"
+
+
+def test_chunk_pass_gap_share_is_what_the_kinds_with_prompt_work_emitted():
+    # a plain second of the ring: 60 x 8 gaps behind a step, 10 x 8
+    # behind a fused pass, 2 x 4 behind a chunk and then a step
+    assert read(SHARE, a_ring()) == pytest.approx(100 * 88 / 568)
+    assert read(SHARE, a_ring(keys=False)) is None     # a parent commit
+    assert read(SHARE, serve_obs([])) is None
+    by_name.check_listed(by_name.bench(), XL, [SHARE], moves="itl_p95_ms",
+                         source="program_counter", unit="%")
+    assert set(by_name.metric(by_name.bench(), SHARE)["workloads"]) \
+        >= set(SERVING)
+    assert load_reader(SHARE).UNTRACED is True
+    # the boundary between ANY two kinds nearest the p95's 5 %: 1.4 %
+    # of the gaps lie behind the slowest kind (a chunk and then a step),
+    # 15.5 % behind it and the fused pass
+    nearest = "p95_edge_gap_share.serve"
+    assert read(nearest, a_ring()) == pytest.approx(100 * 8 / 568)
+    assert read(nearest, a_ring(keys=False)) is None
+    assert load_reader(nearest).UNTRACED is True
+
+
+@pytest.mark.parametrize("kinds,gaps,want,marked", [
+    # granite at 2.4 req/s: 207 fused passes of ~4 rows among 4,600 steps
+    ({"step": 12_400, "step_chunk": 890, "idle": 0}, 13_168, 5.83, True),
+    # the same engine with the fused passes' share well past the rank
+    ({"step": 22_000, "step_chunk": 3_150, "chunk": 150}, 25_000, 12.0,
+     False),
+    ({"step": 9_900, "spec": 50, "host": 0, "prefill": 60}, 10_000, 0.5,
+     False),
+    ({"step": 0}, 0, None, False),
+])
+def test_the_edge_is_marked_between_two_and_a_half_and_eight(
+        kinds, gaps, want, marked):
+    """``tokens`` of a kind with prompt work hold its first tokens too
+    (122 + the gaps here): the share is taken from the kinds WITHOUT,
+    whose tokens are gaps alone."""
+    from chipbench import edge
+    by_kind = {k: {"count": 1, "tokens": n} for k, n in kinds.items()}
+    share = edge.prompt_gap_share(by_kind, {bucket(10.0): gaps})
+    assert share == (None if want is None else pytest.approx(want, abs=0.01))
+    assert edge.on_an_edge(share) is marked
+    assert bool(edge.mark(share)) is marked
+    for kind in ("step_chunk", "chunk+step", "chunk+step_chunk", "chunk",
+                 "prefill"):
+        assert edge.holds_prompt_work(kind)
+    for kind in ("step", "spec", "host", "idle"):
+        assert not edge.holds_prompt_work(kind)
+
+
+def test_a_rank_between_one_chunk_and_two_is_seen_by_the_nearest_boundary():
+    """The granite cell at 11.2 req/s as the account read it (my chip
+    run, PR 59, call 6, seed 3000059301): 46 % of the gaps behind a pass
+    with prompt work, far from 5 %, and 4.4 % behind the passes that ran
+    a lone chunk and THEN the fused step: the rank lay between those and
+    the fused pass, and six seeds read 29.6-33.3 ms."""
+    from chipbench import edge
+    by_kind = {
+        "chunk+step_chunk": {"count": 100, "ns": 100 * 50_460_000,
+                             "tokens": 3_100},
+        "step_chunk": {"count": 874, "ns": 874 * 26_410_000,
+                       "tokens": 27_200},
+        "step": {"count": 1190, "ns": 1190 * 18_100_000, "tokens": 34_600},
+        "idle": {"count": 3, "ns": 9_000_000, "tokens": 0}}
+    gaps = {bucket(20.0): 34_600 + 3_100 + 27_200 - 571}
+    assert edge.prompt_gap_share(by_kind, gaps) == pytest.approx(46.2,
+                                                                 abs=0.1)
+    assert not edge.on_an_edge(edge.prompt_gap_share(by_kind, gaps))
+    nearest = edge.nearest_boundary(by_kind, gaps)
+    assert nearest == pytest.approx(4.73, abs=0.05)
+    assert edge.on_an_edge(nearest)
+    kinds = [level[0] for level in edge.levels(by_kind, gaps)]
+    assert kinds == ["chunk+step_chunk", "step_chunk", "step"]
+    assert edge.levels(by_kind, gaps)[-1][3] == pytest.approx(100.0)
+    assert edge.nearest_boundary({"step": by_kind["step"]}, gaps) is None

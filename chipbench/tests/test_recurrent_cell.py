@@ -1,4 +1,5 @@
-"""The cell ``serve-granite-h-chat2k-r80`` and what it brought: found by
+"""The cell ``serve-granite-h-chat2k-r50`` (until PR 59, at a stale
+rate, ``serve-granite-h-chat2k-r80``) and what it brought: found by
 name with no edit, its readers on a recorded excerpt, its bytes
 functions, and a CPU rehearsal at a fixture of its own
 (``rehearse_recurrent.json``).  ``python -m pytest chipbench/tests -q``;
@@ -20,8 +21,9 @@ sys.path.insert(0, ROOT)
 
 from chipbench import hybrid_bytes, scoped_trace        # noqa: E402
 from chipbench.readers import load_reader               # noqa: E402
+from chipbench.tests import by_name                     # noqa: E402
 
-CELL = "serve-granite-h-chat2k-r80"
+CELL = "serve-granite-h-chat2k-r50"
 NEW = ("expert_ms_per_decode.serve", "ssm_ms_per_decode.serve",
        "expert_roofline_share.serve", "ssm_update_roofline_share.serve",
        "expert_load_max_over_mean.serve", "state_rows_share.serve")
@@ -52,14 +54,11 @@ def test_cell_is_found_by_name_with_its_files():
            if "workloads" not in m or CELL in m["workloads"]}
     assert e2e == {"ttft_p90_ms", "itl_p95_ms", "serve_tokens_per_s",
                    "setup_s"}
-    per_layer = {m["name"] for m in bench["per_layer"]
-                 if CELL in m.get("workloads", [CELL])}
-    assert set(NEW) <= per_layer
-    assert "prefix_hit_rate.serve" not in per_layer
-    for m in bench["per_layer"]:
-        if m["name"] in NEW:
-            assert m["workloads"] == [CELL] and m["moves"] == "itl_p95_ms"
-            assert callable(load_reader(m["name"]).read)
+    # by name: the cell may be listed under more, a metric may list more
+    by_name.check_listed(bench, CELL, NEW, moves="itl_p95_ms")
+    assert "prefix_hit_rate.serve" not in by_name.metrics_of(bench, CELL)
+    for name in NEW:
+        assert callable(load_reader(name).read)
     # traffic as the issue gives it; enough requests for a p90
     assert mix["prompt_len"] == {"lo": 32, "hi": 2048, "median": 256,
                                  "sigma": 0.9}
@@ -94,15 +93,26 @@ def test_configuration_holds_the_published_widths_and_the_share():
 def test_bytes_functions_by_hand():
     config = load("chipbench", "configs", "granite-4.0-h-small-10L-e36.json")
     pub = {**config, "num_local_experts": 72}
-    # every held expert touched (many rows): 10 layers x (36 experts x
-    # 9,437,184 + shared 18,874,368 + router 294,912) x 2 bytes
-    full = hybrid_bytes.expert_bytes_per_decode(pub, (0, 36), 10_000)
+    # every held expert touched in every layer (360 a pass): 10 layers x
+    # (36 experts x 9,437,184 + shared 18,874,368 + router 294,912) x 2
+    # bytes
+    full = hybrid_bytes.expert_bytes_per_decode(pub, 360)
     assert full == pytest.approx(10 * 2 * (36 * 9_437_184 + 18_874_368
                                            + 294_912))
-    one = hybrid_bytes.expert_bytes_per_decode(pub, (0, 36), 1)
-    # one row touches 10 of 72 experts, half of them held on average
+    # one row touches 10 of 72 experts, half of them held on average:
+    # 50 a pass over the 10 layers
+    one = hybrid_bytes.expert_bytes_per_decode(pub, 50)
     assert one == pytest.approx(10 * 2 * (5 * 9_437_184 + 18_874_368
                                           + 294_912))
+    # the touched experts are the engine's count, not an estimate from
+    # the rows: the mean of a concave curve lies under the curve at the
+    # mean, so the old estimate at the MEAN rows read over 100 %
+    counted = {"counters": {"decode_iterations": 10,
+                            "expert_touched_held_decode": 1370}}
+    assert hybrid_bytes.touched_per_decode(counted) == 137.0
+    assert hybrid_bytes.touched_per_decode({"counters": {
+        "decode_iterations": 10}}) is None
+    assert hybrid_bytes.touched_per_decode({"counters": {}}) is None
     # a row: 9 layers x (8192 x 128 x 4 + 3 x 8448 x 2), read + written
     assert hybrid_bytes.ssm_state_bytes_per_decode(pub, 1) == 2 * 9 * (
         4_194_304 + 50_688)
@@ -171,14 +181,32 @@ def test_new_readers_on_the_recorded_excerpt():
                "TPU v5 lite"], "max_slots": 64, "state_rows_mean": 16.0,
            "counters": {"decode_iterations": 100, "row_steps": 2000,
                         "expert_assignments_held": 36_000,
+                        "expert_touched_held_decode": 33_000,
                         "expert_load_max": 1_500}}
     got = {name: load_reader(name).read(obs) for name in NEW}
     assert got["expert_ms_per_decode.serve"] == pytest.approx(
         rec["expect"]["expert_ms"], rel=1e-3)
     assert got["ssm_ms_per_decode.serve"] == pytest.approx(
         rec["expect"]["ssm_ms"], rel=1e-3)
-    assert 0 < got["expert_roofline_share.serve"] <= 105
+    assert 0 < got["expert_roofline_share.serve"] <= 100
+    # 330 of the 360 held experts touched a pass, their bytes at the
+    # peak bandwidth over the recorded time
+    assert got["expert_roofline_share.serve"] == pytest.approx(
+        100 * hybrid_bytes.expert_bytes_per_decode(obs["published"], 330)
+        / obs["peaks"]["hbm_bytes_per_s"]
+        / (rec["expect"]["expert_ms"] / 1e3), rel=1e-3)
     assert 0 < got["ssm_update_roofline_share.serve"] <= 105
+    # the work is that of the passes whose time it is: where the kind
+    # took the traced seconds' own counters, those are read (the same
+    # cycle's same 4 s every run: not the window's mean rows)
+    thin = {**obs, "traced_counters": {
+        "decode_iterations": 10, "row_steps": 100,
+        "expert_touched_held_decode": 1_650}}
+    for name in ("expert_roofline_share.serve",
+                 "ssm_update_roofline_share.serve"):
+        assert load_reader(name).read(thin) == pytest.approx(
+            got[name] / 2, rel=0.2)
+        assert load_reader(name).read(thin) < got[name]
     assert got["expert_load_max_over_mean.serve"] == pytest.approx(1.5)
     assert got["state_rows_share.serve"] == 25.0
     # a program without the spans or counters (a parent commit): nothing

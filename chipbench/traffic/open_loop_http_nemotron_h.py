@@ -48,10 +48,7 @@ from chipbench.traffic.open_loop_http import (COUNTERS, ROUTE,    # noqa: E402
                                               engine_counters, pick_checked,
                                               run_loadgen, verdict)
 from chipbench.traffic.open_loop_http_recurrent import (     # noqa: E402
-    EXPERT_COUNTERS as LOAD_COUNTERS)
-
-EXPERT_COUNTERS = LOAD_COUNTERS + ("expert_touched_held",
-                                   "expert_touched_held_decode")
+    EXPERT_COUNTERS)
 
 
 def model_config(config: dict):

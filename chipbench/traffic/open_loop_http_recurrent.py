@@ -39,8 +39,12 @@ from chipbench.traffic.open_loop_http import (COUNTERS, ROUTE,    # noqa: E402
                                               engine_counters, pick_checked,
                                               run_loadgen, verdict)
 
+# the load of every pass, and the held experts that a pass's tokens (of
+# its decode rows alone: ``_decode``) really chose, summed over the
+# expert layers (the roofline shares count the touched experts' bytes)
 EXPERT_COUNTERS = ("expert_assignments_held", "expert_assignments_total",
-                   "expert_load_max")
+                   "expert_load_max", "expert_touched_held",
+                   "expert_touched_held_decode")
 
 
 def model_config(config: dict):
@@ -157,8 +161,16 @@ def run(ctx) -> dict:
             if ctx.trace:
                 sleep_until(t0 + 0.45 * ctx.seconds)
                 trace["dir"] = tempfile.mkdtemp(prefix="chipbench_trace_")
+                # the counters over the traced seconds themselves: a
+                # roofline share divides work by the time of the SAME
+                # passes.  Read INSIDE the session: ``stop_trace`` holds
+                # the interpreter for seconds while it writes, and the
+                # passes that catch up carry more rows than any traced
+                # one (``open_loop_http_lfm2`` has the readings)
                 jax.profiler.start_trace(trace["dir"])
+                trace["traced_from"] = counters()
                 time.sleep(min(mix["trace_s"], 0.4 * ctx.seconds))
+                trace["traced_to"] = counters()
                 jax.profiler.stop_trace()
             sleep_until(t0 + ctx.seconds)
             trace["at_window_end"] = counters()
@@ -227,6 +239,9 @@ def run(ctx) -> dict:
            "held": held}
     if ctx.trace and "dir" in trace:
         import shutil
+        obs["traced_counters"] = {
+            k: trace["traced_to"][k] - trace["traced_from"][k]
+            for k in COUNTERS + EXPERT_COUNTERS}
         path = trace_reduce.find_xplane(trace["dir"])
         obs["trace"] = trace_reduce.summarize(trace_reduce.load_events(path))
         k = published["num_experts_per_tok"]
