@@ -26,7 +26,8 @@ CELLS = {"granite-4.0-h-small-10L-e36": "open_loop_http_recurrent",
          "olmo-hybrid-7b-16L": "open_loop_http_olmo_hybrid",
          "trinity-large-preview-5L-e32": "open_loop_http_afmoe",
          "deepseek-v2-7L-e20": "open_loop_http_deepseek_v2",
-         "lfm2-8b-a1b-12L": "open_loop_http_lfm2"}
+         "lfm2-8b-a1b-12L": "open_loop_http_lfm2",
+         "xing4.0-29b-a4b-6L": "open_loop_http_xing4"}
 
 
 def digest(text: str) -> str:

@@ -238,6 +238,14 @@ class HybridConfig:
     rotary_full: bool = False        # ... and of the ATTENTION layers
     sigmoid_router: Any = None       # None: the relu^2 experts' (above)
     route_eps: float = 0.0           # added to the chosen scores' sum
+    # the ``xing4_0`` layout's residual of ``hc_mult`` streams round every
+    # sublayer (ops/hyper_connections.py; 0: the one-stream residual) and
+    # its multi-token-prediction modules (``forward`` alone runs them)
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 0.0
+    hc_res_clamp: tuple = ()         # (min, max) of the residual map's A
+    mtp_layers: int = 0
     # the first family's four multipliers
     embedding_multiplier: float = 12.0
     attention_multiplier: float = 1.0 / 128
@@ -281,6 +289,9 @@ class HybridConfig:
         if self.n_window and (self.n_latent or self.state_geometry):
             raise ValueError("window layers beside latent or recurrent "
                              "layers: no cache holds the three kinds")
+        if self.mtp_layers and set(self.layer_types) != {LATENT}:
+            raise ValueError("mtp_layers: a prediction module's layer is "
+                             "latent attention then experts")
         if self.route_groups and self.n_experts % self.route_groups[0]:
             raise ValueError(f"{self.n_experts} experts in "
                              f"{self.route_groups[0]} groups")
@@ -511,14 +522,23 @@ def _refuse_unless(*checks) -> None:
 
 
 def _latent_keys(c: dict) -> dict:
-    """``HybridConfig`` fields from ``deepseek_v2`` keys.  What the layer
-    function has no form for is refused here, by name."""
+    """``HybridConfig`` fields from ``deepseek_v2`` keys, and from
+    ``xing4_0``'s, which are those plus sigmoid scores chosen by ``score
+    + bias`` (``noaux_tc``, one group), ``hc_mult`` residual streams and
+    ``num_nextn_predict_layers``.  What the layer function has no form
+    for is refused here, by name."""
     rs = c.get("rope_scaling") or {}
+    sigmoid = c.get("scoring_func", "softmax") == "sigmoid"
     _refuse_unless(
         ("rope_scaling.type", rs.get("type"), ("yarn",)),
-        ("topk_method", c.get("topk_method", "greedy"),
-         ("group_limited_greedy", "greedy")),
-        ("scoring_func", c.get("scoring_func", "softmax"), ("softmax",)),
+        ("scoring_func / topk_method",
+         (c.get("scoring_func", "softmax"), c.get("topk_method", "greedy")),
+         (("softmax", "greedy"), ("softmax", "group_limited_greedy"),
+          ("sigmoid", "noaux_tc"))),
+        *((("n_group", c.get("n_group", 1), (1,)),
+           ("topk_group", c.get("topk_group", 1), (1,)),
+           ("norm_topk_prob", c["norm_topk_prob"], (True,)))
+          if sigmoid else ()),
         ("moe_layer_freq", c.get("moe_layer_freq", 1), (1,)),
         ("tie_word_embeddings", c.get("tie_word_embeddings", False),
          (False,)),
@@ -530,9 +550,22 @@ def _latent_keys(c: dict) -> dict:
                 original_max=rs["original_max_position_embeddings"],
                 beta_fast=rs["beta_fast"], beta_slow=rs["beta_slow"],
                 mscale=rs["mscale"], mscale_all_dim=rs["mscale_all_dim"])
-    grouped = c.get("topk_method") == "group_limited_greedy"
     qk = c["qk_nope_head_dim"] + c["qk_rope_head_dim"]
+    if sigmoid:
+        # (the no-group sigmoid form is ``afmoe``'s: its own eps)
+        more = dict(sigmoid_router=True, route_eps=1e-20, route_groups=())
+    elif c.get("topk_method") == "group_limited_greedy":
+        more = dict(route_groups=(c["n_group"], c["topk_group"]))
+    else:
+        more = dict(route_groups=(1, 1))
+    if c.get("hc_mult", 1) > 1:
+        more.update(hc_mult=c["hc_mult"],
+                    hc_sinkhorn_iters=c["hc_sinkhorn_iters"],
+                    hc_eps=c["hc_eps"],
+                    hc_res_clamp=(c["mhc_h_res_clamp_min"],
+                                  c["mhc_h_res_clamp_max"]))
     return dict(
+        **more, mtp_layers=c.get("num_nextn_predict_layers", 0),
         vocab_size=c["vocab_size"], d_model=c["hidden_size"],
         layer_types=(LATENT,) * c["num_hidden_layers"],
         n_heads=c["num_attention_heads"], n_kv_heads=1,
@@ -546,8 +579,6 @@ def _latent_keys(c: dict) -> dict:
         experts_held=(0, c["n_routed_experts"]),
         experts_in_every_layer=True, gated_experts=True,
         routed_scale=c["routed_scaling_factor"],
-        route_groups=((c["n_group"], c["topk_group"]) if grouped
-                      else (1, 1)),
         norm_topk=c["norm_topk_prob"],
         dense_layers=min(c["first_k_dense_replace"],
                          c["num_hidden_layers"]),
@@ -808,11 +839,22 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
                 next(k), (cfg.n_experts,)) * 0.02
         return ffn
 
+    def with_maps(sub, k):
+        """A sublayer of the widened residual holds its three maps."""
+        if cfg.hc_mult:
+            from ray_tpu.ops import hyper_connections
+            sub["hc"] = hyper_connections.init(next(k), cfg.hc_mult, d, pd)
+        return sub
+
+    def stream(key):
+        return iter(jax.random.split(key, 16 if cfg.hc_mult else 12))
+
     keys = jax.random.split(rng, cfg.n_layers + 1)
-    streams = [iter(jax.random.split(key, 12)) for key in keys[1:]]
+    streams = [stream(key) for key in keys[1:]]
     layers = [{} for _ in cfg.layer_types]
     for i, kind in cfg.sublayers:
-        layers[i][slot_of(kind)] = sublayer(kind, streams[i])
+        layers[i][slot_of(kind)] = with_maps(sublayer(kind, streams[i]),
+                                             streams[i])
         if cfg.sandwich_norm:
             layers[i][slot_of(kind)]["post_norm"] = jnp.ones((d,), pd)
     params = {
@@ -825,6 +867,20 @@ def init_params(cfg: HybridConfig, rng: jax.Array):
         params["head"] = (jax.random.normal(jax.random.fold_in(rng, 1),
                                             (d, cfg.vocab_size))
                           * 0.02).astype(pd)
+    if cfg.mtp_layers:
+        # a multi-token-prediction module: two norms and a projection
+        # [2 d, d] in front of one more whole layer (its mixer, then
+        # experts), a norm behind it; embedding and head are the model's
+        params["mtp"] = []
+        for j in range(cfg.mtp_layers):
+            k = stream(jax.random.fold_in(rng, 2 + j))
+            params["mtp"].append({
+                "enorm": jnp.ones((d,), pd), "hnorm": jnp.ones((d,), pd),
+                "eh_proj": (jax.random.normal(next(k), (2 * d, d))
+                            * 0.02).astype(pd),
+                "mixer": with_maps(sublayer(LATENT, k), k),
+                "ffn": with_maps(sublayer(EXPERTS, k), k),
+                "norm": jnp.ones((d,), pd)})
     return params
 
 
@@ -1257,6 +1313,13 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
     length a row AND the window's positions); experts count the parts
     apart: counts [2, E_held], total [2].  Latent and window attention
     have no such form."""
+    carried = None
+    if cfg.hc_mult:
+        # x [b, w, n, d]: the sublayer sees ONE stream, mixed from the n
+        from ray_tpu.ops import hyper_connections
+        x, carried = hyper_connections.mix_in(
+            x, lp["hc"], iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+            clamp=cfg.hc_res_clamp)
     h = x if cfg.norm_output else _rms_norm(x, lp["norm"], cfg.rms_eps)
     load = None
     if rows and kind not in TWO_PART:
@@ -1295,17 +1358,43 @@ def block(cfg: HybridConfig, kind: str, lp, x, past, n_valid,
         mix = _rms_norm(mix, lp["norm"], cfg.rms_eps)
     elif cfg.sandwich_norm:
         mix = _rms_norm(mix, lp["post_norm"], cfg.rms_eps)
+    if carried is not None:
+        return hyper_connections.mix_out(carried, mix), past, load
     return x + cfg.residual_multiplier * mix, past, load
 
 
-def embed(cfg: HybridConfig, params, tokens):
+def _embedding(cfg: HybridConfig, params, tokens):
     return (params["wte"][tokens].astype(jnp.float32)
             * cfg.embedding_multiplier).astype(cfg.dtype)
 
 
-def head(cfg: HybridConfig, params, x):
-    """x [..., d] -> logits [..., V] float32 over the held vocabulary."""
-    h = _rms_norm(x, params["norm_f"], cfg.rms_eps)
+def split_streams(cfg: HybridConfig, x):
+    """x [.., d] as what enters the first layer: itself, or under
+    ``hc_mult`` n streams [.., n, d], each a copy of it."""
+    if not cfg.hc_mult:
+        return x
+    return jnp.broadcast_to(x[..., None, :],
+                            (*x.shape[:-1], cfg.hc_mult, x.shape[-1]))
+
+
+def embed(cfg: HybridConfig, params, tokens):
+    return split_streams(cfg, _embedding(cfg, params, tokens))
+
+
+def merge_streams(cfg: HybridConfig, x):
+    """What leaves the last layer as ONE stream [..., d]: under
+    ``hc_mult`` the n streams' sum."""
+    if not cfg.hc_mult:
+        return x
+    return x.astype(jnp.float32).sum(-2).astype(x.dtype)
+
+
+def head(cfg: HybridConfig, params, x, norm=None):
+    """x [..., d] ([..., n, d] under ``hc_mult``) -> logits [..., V]
+    float32 over the held vocabulary; ``norm``: the final norm's weight
+    where it is not the model's own (a prediction module's)."""
+    h = _rms_norm(merge_streams(cfg, x),
+                  params["norm_f"] if norm is None else norm, cfg.rms_eps)
     w = params["wte"].T if cfg.tied_head else params["head"]
     logits = jnp.dot(h, w.astype(h.dtype),
                      preferred_element_type=jnp.float32)
@@ -1413,9 +1502,11 @@ def causal_attend(cfg: HybridConfig, window: int = 0):
     return attend
 
 
-def forward(params, tokens, cfg: HybridConfig):
+def forward(params, tokens, cfg: HybridConfig, mtp: bool = False):
     """tokens [b, s] -> logits [b, s, V] float32: the layer function on
-    one window of the whole sequence, from zero state."""
+    one window of the whole sequence, from zero state.  ``mtp``: ->
+    (logits, the prediction modules' logits: module k's [b, s - k, V],
+    position t's guess at token t + k + 1)."""
     b, s = tokens.shape
     attend = causal_attend(cfg)
     within = causal_attend(cfg, cfg.window) if cfg.n_window else None
@@ -1426,4 +1517,27 @@ def forward(params, tokens, cfg: HybridConfig):
         state_out=lambda mi, state: None,
         attend_for=lambda ai: attend, window_for=lambda wi: within,
         positions=jnp.broadcast_to(jnp.arange(s), (b, s)))
-    return head(cfg, params, x)
+    logits = head(cfg, params, x)
+    if not mtp:
+        return logits
+    # DeepSeek-V3's form: module k joins the embedding of token t + k to
+    # the stream the module before it (the model, for k = 1) left at t,
+    # runs one more layer over the s - k positions and reads the SAME
+    # head behind a norm of its own
+    more = []
+    for k, mp in enumerate(params.get("mtp", ()), 1):
+        own = jnp.concatenate(
+            [_rms_norm(_embedding(cfg, params, tokens[:, k:]), mp["enorm"],
+                       cfg.rms_eps),
+             _rms_norm(merge_streams(cfg, x)[:, :s - k], mp["hnorm"],
+                       cfg.rms_eps)], axis=-1)
+        x = split_streams(cfg, jnp.dot(own,
+                                       mp["eh_proj"].astype(own.dtype)))
+        n_valid = jnp.full((b,), s - k, jnp.int32)
+        tables = rotary_tables(
+            cfg, jnp.broadcast_to(jnp.arange(s - k), (b, s - k)))
+        x, _, _ = block(cfg, LATENT, mp["mixer"], x, (attend, tables),
+                        n_valid)
+        x, _, _ = block(cfg, EXPERTS, mp["ffn"], x, None, n_valid)
+        more.append(head(cfg, params, x, mp["norm"]))
+    return logits, tuple(more)

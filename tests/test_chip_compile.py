@@ -1485,3 +1485,113 @@ def test_lfm2_step_chunk_multiplies_by_in_proj_once_a_layer(lfm2_cell):
     for part in (engine["max_slots"], engine["prefill_chunk"]):
         assert not re.findall(
             rf"bf16\[(?:1,)?{part},6144\]\S* (?:dot|convolution)\(", text)
+
+
+# the xing4_0 layout: four residual streams round every sublayer of the
+# latent layout, 64 held experts top-4 by sigmoid + bias, one shared
+
+
+@pytest.fixture(scope="module")
+def xing4_cell(one_chip):
+    """The shapes of ``serve-xing4-code4k``, from the cell's own
+    configuration file through its traffic kind's ``model_config``: 6
+    layers (the first dense) at published widths, four streams of 3,584
+    lanes, all 64 experts and the whole vocabulary held, 32 rows, 24,576
+    + 1 blocks of 16 x 640 lanes, 1,040-block tables."""
+    import json
+    import os
+    from chipbench.traffic.open_loop_http_xing4 import model_config
+    from ray_tpu.models import hybrid
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "xing4.0-29b-a4b-6L.json")) as f:
+        config = json.load(f)
+    cfg, published, _ = model_config(config)
+    engine = config["engine"]
+    on_chip = _on(one_chip)
+    lay = PoolLayout(*cfg.kv_geometry[:1], engine["n_blocks"] + 1,
+                     engine["kv_block_size"], *cfg.kv_geometry[1:], 1,
+                     cfg.value_lanes)
+    assert lay.shape == (6 * 24577, 16, 640)
+    params = jax.tree.map(
+        lambda s: on_chip(s.shape, s.dtype),
+        jax.eval_shape(lambda: hybrid.init_params(cfg,
+                                                  jax.random.PRNGKey(0))))
+    return (cfg, on_chip, params, on_chip(lay.shape, cfg.dtype), lay,
+            engine), published
+
+
+def _materialised(text):
+    """The result shapes of every instruction outside a fused
+    computation: the arrays a program writes to memory."""
+    out, fused = [], False
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")) and line.rstrip().endswith("{"):
+            fused = line.startswith("%fused_computation")
+        elif not fused and " = " in line:
+            out.append(line.split(" = ", 1)[1].split(" ", 1)[0])
+    return out
+
+
+@pytest.mark.parametrize("which", ["step", "chunk"])
+def test_xing4_programs_fit_move_no_pool_and_carry_the_streams_flat(
+        xing4_cell, which):
+    """Both programs of the cell, compiled for the described chip: they
+    fit beside the weights and the pool, the pool is no program's copy,
+    no expert stack is re-laid out; the one-token kernel takes 32 heads
+    on the pool as stored; the four streams ride between the sublayers
+    as ONE array ``[tokens, 14336]`` (no ``[tokens, 4, 4, 3584]``
+    product of the residual map, no float32 copy of the streams); the
+    maps' product with ``Phi`` is float32 on both sides; and the trace's
+    table finds all four parts of the mix in the program's own ops."""
+    cell, published = xing4_cell
+    cfg, _, params, _, lay, engine = cell
+    compiled = _latent_program(cell, which)
+    _assert_pool_stays_put(compiled, lay, n_pools=1)
+    text = compiled.as_text()
+    _no_table_span_by_heads(text, cfg, lay, engine)
+    _no_expert_stack_is_copied(text, params)
+    tokens = engine["max_slots"] if which == "step" \
+        else engine["prefill_chunk"]
+    n, d = cfg.hc_mult, cfg.d_model
+    # what the program MATERIALISES: the results of the instructions
+    # outside its fused computations
+    results = " ".join(_materialised(text))
+    assert f"bf16[{tokens},{n * d}]" in results
+    # ([tokens, 4, 3584] is also the routed experts' [tokens, top-4, d])
+    for bad in (f"[{tokens},{n},{n},{d}]", f"f32[{tokens},{n * d}]"):
+        assert bad not in results, bad
+    # Phi enters a float32 product (never a bfloat16 copy of it)
+    assert f"f32[{2 * n + n * n},{n * d}]" in text
+    assert f"bf16[{2 * n + n * n},{n * d}]" not in text
+    calls = _kernel_calls(text, "latent_decode_attention")
+    if which == "chunk":
+        assert not calls
+        assert len(_kernel_calls(text, "latent_window_attention")) \
+            == cfg.n_latent == 6
+    else:
+        assert len(calls) == 6
+        pool = "bf16[" + ",".join(map(str, lay.shape)) + "]"
+        for line in calls:
+            assert pool in line.split(" custom-call(", 1)[1]
+    from chipbench import xing4_trace
+    marks = xing4_trace.marks_of(published, engine["max_slots"],
+                                 engine["prefill_chunk"])
+    # the text a trace's event has: the operands with their shapes
+    from jax._src.lib import xla_client
+    how = xla_client._xla.HloPrintOptions()
+    how.print_operand_shape, how.print_metadata = True, False
+    how.print_backend_config = False
+    verbose = compiled.runtime_executable().hlo_modules()[0].to_string(how)
+    labels, fused = {}, False
+    for line in verbose.splitlines():
+        if line.startswith(("%", "ENTRY")) and line.rstrip().endswith("{"):
+            fused = line.startswith("%fused_computation")
+        elif not fused and re.search(r" (fusion|convolution|custom-call)\(",
+                                     line):
+            label = xing4_trace.label_of(line.strip(), marks)
+            labels[label] = labels.get(label, 0) + 1
+    assert set(xing4_trace.MHC) <= set(labels), labels
+    for label in ("routed_experts", "shared_expert", "dense_mlp",
+                  "mixer_latent_proj"):
+        assert labels.get(label), (label, labels)
